@@ -733,13 +733,21 @@ def mode_serve(args) -> int:
 
 
 def mode_serve_fleet(args) -> int:
-    from .fleet import serve_fleet_cli
+    from .fleet import keep_launcher_off_chip, serve_fleet_cli
+    # before anything below asks JAX for a backend (the dtype default, the
+    # manifest's device query, the seeded weight init): the chips belong
+    # to the replicas
+    keep_launcher_off_chip()
     config = _make_config(args)
     _start_run_log(args, config)
     return serve_fleet_cli(args, config, _load_params)
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None):
+    """argv -> the Namespace every mode handler expects: argparse plus the
+    defaults that depend on other flags.  One function, so an in-process
+    driver of the real entry points (chip_smoke.py) builds exactly what
+    ``python -m raft_tpu.cli`` builds."""
     args = _build_parser().parse_args(argv)
     if args.demo_train:
         args.mode = "train"
@@ -764,6 +772,13 @@ def main(argv=None) -> int:
     if args.cpu:
         import jax
         jax.config.update("jax_platforms", "cpu")
+    return args
+
+
+def main(argv=None) -> int:
+    from .compile_cache import configure_compile_cache
+    configure_compile_cache()
+    args = parse_args(argv)
     if args.mode == "train":
         # must run before anything touches a device: jax.distributed connects
         # the processes and makes jax.devices() span every host (env

@@ -174,7 +174,7 @@ class RAFTConfig:
     # CPU backend: train step +17% (tools/bench_train.py, quiet-core
     # round-4 sweep), inference +7.7% (round-3, PERF.md); a pure FLOP cut,
     # so it can only help more where the gate convs dominate (round-2 TPU
-    # attribution).  TPU confirmation stage queued in tools/hw_queue.sh.
+    # attribution).  Not measured on the chip yet.
     gru_ctx_hoist: bool = True
     # Which implementation executes the SepConvGRU iteration (full model
     # only — the small variant's 3x3 ConvGRU has no hand kernel yet):

@@ -241,8 +241,7 @@ def main(argv=None) -> int:
         results = {"metric": "input_shm_decode_only_pairs_per_s",
                    "value": None, "unit": "pairs/sec",
                    "error": f"{type(e).__name__}: {e}",
-                   "manifest": run_manifest(mode="loader_bench",
-                                            probe_device=False)}
+                   "manifest": run_manifest(mode="loader_bench")}
         print(json.dumps(results), flush=True)
         raise
     finally:

@@ -396,8 +396,12 @@ class MPSampleLoader:
             if self._faults is not None and self._faults.roll("worker_kill"):
                 victims = [w for w in self._workers if w.is_alive()]
                 if victims:
-                    os.kill(victims[self._faults.pick(len(victims))].pid,
-                            signal.SIGKILL)
+                    victim = victims[self._faults.pick(len(victims))]
+                    os.kill(victim.pid, signal.SIGKILL)
+                    # SIGKILL is asynchronous; wait for the death so the
+                    # sentinel check below sees it BEFORE the queue is read
+                    # (a kill mid-put leaves a torn frame — see below)
+                    victim.join(timeout=30)
             while True:
                 if self._requeued:
                     # samples salvaged from the pre-respawn result queue

@@ -16,7 +16,7 @@ time).  Entry point: ``python -m raft_tpu.cli -m serve_fleet``.
 from .config import FleetConfig
 from .controller import Autoscaler, RollingUpdater, fleet_signals
 from .launch import build_fleet, serve_fleet_cli
-from .manager import Replica, ReplicaManager
+from .manager import Replica, ReplicaManager, keep_launcher_off_chip
 from .metrics import make_fleet_metrics
 from .router import FleetRouter, FleetSession, FleetSessionMap
 
@@ -32,5 +32,6 @@ __all__ = [
     "fleet_signals",
     "make_fleet_metrics",
     "build_fleet",
+    "keep_launcher_off_chip",
     "serve_fleet_cli",
 ]

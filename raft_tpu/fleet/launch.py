@@ -10,6 +10,11 @@ The replicas must share ONE set of weights (a migrated session's flow
 must equal pairwise no matter which replica computes it), so when no
 ``--load`` is given the launcher initializes once, writes
 ``<out>/weights_init.npz``, and hands that to every replica.
+
+One process for each chip: the launcher itself stays on the CPU platform
+(``manager.keep_launcher_off_chip``, called by the CLI before anything asks
+JAX for a backend — the launcher needs numpy weights only), and the
+manager shows every replica exactly one chip.
 """
 
 from __future__ import annotations

@@ -25,6 +25,7 @@ mutation does.
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import subprocess
@@ -43,6 +44,54 @@ from .config import FleetConfig
 _log = get_logger("fleet")
 
 _BANNER = "[serve] listening on "
+
+
+# -- one process for each chip ---------------------------------------------
+# A chip belongs to one process at a time, and a process that initialises
+# the TPU backend takes every chip it can see.  So the launcher never
+# touches the accelerator (keep_launcher_off_chip), and every replica is
+# shown exactly one chip through its environment (chip_env).
+
+def local_chip_count() -> int:
+    """TPU chips attached to this host, counted from their device files
+    (``/dev/accel<N>`` or, for VFIO-attached generations such as v5e,
+    ``/dev/vfio/<N>``) — without loading libtpu, which would take them."""
+    vfio = [p for p in glob.glob("/dev/vfio/*")
+            if os.path.basename(p).isdigit()]
+    return len(glob.glob("/dev/accel[0-9]*")) + len(vfio)
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """The libtpu variables that show a child process exactly one chip —
+    the ``chip``-th of the host — as a 1x1x1 topology of its own.
+    Established on a four-chip v5e host (PERF.md "Chip bring-up"): four
+    children started with these held four different chips at once, each
+    seeing one ``TpuDevice(id=0)``."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+def fleet_chips(base_args: List[str]) -> int:
+    """How many chips the fleet hands out, one per replica: the host's
+    chips, or 0 (no assignment) when the replicas run on the CPU —
+    ``--cpu`` forwarded to them, or ``JAX_PLATFORMS`` naming the CPU
+    first."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if "--cpu" in base_args or platforms.split(",")[0].strip() == "cpu":
+        return 0
+    return local_chip_count()
+
+
+def keep_launcher_off_chip() -> None:
+    """Pin THIS process's JAX to the CPU platform.  The fleet launcher and
+    the fleet bench need JAX for numpy-side work only (seeded weight init,
+    tree maps); were they to initialise the TPU backend they would hold the
+    chips their replicas need.  Must run before any backend initialisation;
+    the replicas are unaffected (they read the environment, not this
+    process's ``jax.config``)."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
 
 def parse_prom_text(text: str) -> Dict[str, float]:
@@ -87,6 +136,7 @@ class Replica:
         self.prom: Optional[Dict[str, float]] = None  # last /metrics parse
         self.started_at = time.monotonic()
         self.updating = False             # rolling hot-swap soft-drain flag
+        self.chip: Optional[int] = None   # the one chip this process owns
 
     @property
     def routable(self) -> bool:
@@ -131,6 +181,8 @@ def _default_spawn(replica: Replica, base_args: List[str],
         os.path.abspath(__file__))))
     env["PYTHONPATH"] = pkg_root + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    if replica.chip is not None:
+        env.update(chip_env(replica.chip))
     preexec = None
     if cores and hasattr(os, "sched_setaffinity"):
         def preexec():                    # runs in the child, pre-exec
@@ -191,6 +243,14 @@ class ReplicaManager:
         self._death_cbs: List[Callable] = []
         self._poll_cbs: List[Callable] = []
         self._cores = os.cpu_count() or 1
+        # chips handed out one per replica (0 = CPU replicas, no
+        # assignment); injected spawn_fns (tests) place nothing
+        self._chips = fleet_chips(self.base_args) if spawn_fn is None else 0
+        if self._chips and config.replicas > self._chips:
+            raise ValueError(
+                f"replicas={config.replicas} but this host has "
+                f"{self._chips} TPU chip(s): a chip serves one process at "
+                f"a time, so a fleet is at most one replica per chip")
         self.restarts = 0                 # respawns after unplanned deaths
 
     # -- spawn / stop ------------------------------------------------------
@@ -218,6 +278,7 @@ class ReplicaManager:
             idx = self._next_idx
             self._next_idx += 1
             rep = Replica(idx, os.path.join(self.out_dir, f"replica-{idx}"))
+            rep.chip = self._free_chip()
             self._replicas[idx] = rep
         t0 = time.monotonic()
         try:
@@ -234,6 +295,24 @@ class ReplicaManager:
         self._event("fleet_replica_ready", idx=idx, url=url,
                     spawn_s=round(time.monotonic() - t0, 2))
         return rep
+
+    def _free_chip(self) -> Optional[int]:
+        """The lowest chip no live process holds (caller holds ``_lock``);
+        None when the fleet places no chips.  A replica holds its chip
+        from allocation until its process has exited — state alone is not
+        enough, a draining or hung process still owns the device."""
+        if not self._chips:
+            return None
+        held = {r.chip for r in self._replicas.values()
+                if r.chip is not None and (
+                    r.state == "starting"
+                    or (r.proc is not None and r.proc.poll() is None))}
+        free = [c for c in range(self._chips) if c not in held]
+        if not free:
+            raise RuntimeError(
+                f"no free TPU chip for another replica: all {self._chips} "
+                f"chip(s) of this host are held by live replicas")
+        return free[0]
 
     def start(self) -> None:
         """Bring up the initial fleet (staggered by default) and start
@@ -454,12 +533,20 @@ class ReplicaManager:
             if rep.state == "dead":
                 return
             rep.state = "dead"
+            # declared dead on failed polls but still running: it holds
+            # its chip, which its replacement needs (killed below, outside
+            # the lock, before the respawn is started)
+            holds_chip = (rep.chip is not None and rep.proc is not None
+                          and rep.proc.poll() is None)
             live = sum(r.state in ("starting", "ready", "degraded")
                        for r in self._replicas.values())
             respawn = (self.config.restart_dead and not self._stop.is_set()
                        and live < self._desired)
             if respawn:
                 self.restarts += 1
+        if holds_chip:
+            rep.proc.kill()
+            rep.proc.wait()
         _log.error(f"replica {rep.idx} dead: {why}")
         self._event("fleet_replica_dead", idx=rep.idx, why=why)
         for cb in self._death_cbs:

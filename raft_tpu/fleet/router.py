@@ -82,7 +82,7 @@ class ForwardError(Exception):
 
 def status_class(status: int) -> str:
     """HTTP status -> the raft_fleet_requests_total / trace status
-    taxonomy (matches the replica's own request statuses)."""
+    classification (matches the replica's own request statuses)."""
     if status == 200:
         return "ok"
     if status in (429, 503):

@@ -42,9 +42,17 @@ from typing import Dict, List, Optional, Sequence, Tuple
 LANE = 128
 #: TPU sublane width: the second-minor dim of a float32 tile pads to this.
 SUBLANE = 8
-#: Usable VMEM per TensorCore (~16 MB on v4/v5e — the Pallas guide's
-#: planning number; the compiler reserves a slice, so treat as a ceiling).
-VMEM_BYTES = 16 * 1024 * 1024
+#: Scoped-VMEM limit the Pallas kernels ask the compiler for
+#: (``vmem_limit_bytes`` of every pallas_call) and the ceiling the static
+#: envelopes below are checked against.  The compiler's DEFAULT scoped limit
+#: is 16 MiB, and the kernels at their default blocks sit right on it: the
+#: level-0 corr lookup (q_blk 128, p_blk 4096, C 256) is accepted at 16 MiB
+#: standalone and refused inside the chairs train step ("Scoped allocation
+#: with size 16.84M and limit 16.00M"), the fused GRU at f32 I/O, 8 rows x
+#: 128 columns is refused ("17.03M").  A v5e TensorCore has 128 MiB of VMEM,
+#: so the default is a compiler setting, not the hardware: the kernels
+#: request 32 MiB and keep their measured block plan.
+VMEM_BYTES = 32 * 1024 * 1024
 
 #: Fused-GRU kernel geometry (ops/gru_pallas.py imports these): the pass-1
 #: recompute halo rows, and the separable tap count (1x5 / 5x1 gates).
@@ -59,6 +67,27 @@ DEVICE_BUDGETS: Dict[str, Dict[str, int]] = {
     "tpu-v5e": {"hbm_bytes": 16 * 1024**3, "vmem_bytes": VMEM_BYTES},
     "cpu":     {"hbm_bytes": 8 * 1024**3,  "vmem_bytes": VMEM_BYTES},
 }
+
+#: ``jax`` ``device_kind`` substring -> DEVICE_BUDGETS key (a v5e reports
+#: itself as "TPU v5 lite").
+_KIND_KEYS = (("v5 lite", "tpu-v5e"), ("v5e", "tpu-v5e"), ("v4", "tpu-v4"))
+
+
+def budget_key(device_kind: str) -> str:
+    """The DEVICE_BUDGETS key of a device as JAX reports it
+    (``jax.devices()[0].device_kind``).  A device that is not in the table
+    is an error, not a default: a capacity report against the wrong
+    chip's HBM is worse than none."""
+    kind = device_kind.lower()
+    if kind == "cpu":
+        return "cpu"
+    for sub, key in _KIND_KEYS:
+        if "tpu" in kind and sub in kind:
+            return key
+    raise ValueError(f"no capacity budget known for device_kind "
+                     f"{device_kind!r}; add it to lint/budget.py "
+                     f"DEVICE_BUDGETS / _KIND_KEYS with its source")
+
 
 #: Engine-cache key: (kind, bucket H, bucket W, padded batch, iters policy).
 Key = Tuple[str, int, int, int, str]
@@ -229,13 +258,27 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
                                p_blk_target=config.pallas_p_blk,
                                pack_rows=config.pallas_pack)
         pblk = plan.h2_blk * plan.w2p
-        floats = (plan.t * c                 # f1 block
-                  + plan.t * 2               # coords block
-                  + pblk * c                 # f2 row block
-                  + plan.t * n * n           # output window block
-                  + plan.t * pblk            # corr tile (the MXU product)
-                  + plan.t * n * plan.h2_blk     # A_y one-hot
-                  + 2 * plan.t * n * plan.w2p)   # A_x + win_y
+        # Calibrated against the chip compiler's own scoped-allocation
+        # figures (v5e, jax 0.9.0): this model gives 16.45 MiB for the
+        # default plan at Pblk 4096, where Mosaic reports 16.84M inside the
+        # chairs train step and just under 16.00M standalone at 440x1024.
+        # The pipeline double-buffers every grid-indexed block; the body
+        # keeps the MXU product and its [T, h2_blk, W2p] view, and builds
+        # each one-hot from two `where` terms over int32 iotas.
+        io_blocks = (plan.t * c              # f1 block
+                     + plan.t * 2            # coords block
+                     + pblk * c              # f2 row block
+                     + plan.t * n * n)       # output window block
+        a_y = plan.t * n * plan.h2_blk
+        a_x = plan.t * n * plan.w2p
+        floats = (2 * io_blocks              # double-buffered pipeline
+                  + 2 * plan.t * pblk        # corr tile + its 3-D view
+                  + 3 * (a_y + a_x)          # one-hots + their where terms
+                  + 2 * (a_y + a_x)          # int32 index iotas
+                  + a_x                      # win_y
+                  + plan.t * n * n)          # win
+        # (the 'vpu' lookup style is not priced separately: the compiler
+        # reports 19.18M for it at the default plan, 14% over this model)
         bytes_ = 4 * floats
         worst = max(worst, bytes_)
         levels.append({"level": level, "shape": [h2, w2],
@@ -495,17 +538,21 @@ def config_signature(config, sconfig, stream: bool, chaos: bool) -> dict:
     }
 
 
-def analyze(config, sconfig, device_kind: str = "tpu-v4",
+def analyze(config, sconfig, device_kind: Optional[str] = None,
             stream: Optional[bool] = None, chaos: Optional[bool] = None,
             donation: Optional[bool] = None) -> dict:
     """The full static capacity report (the BUDGET.json payload).
 
-    ``donation`` defaults to the device kind's behavior: the engine turns
+    ``device_kind`` is a DEVICE_BUDGETS key; left None it is derived from
+    the device this process runs on (:func:`budget_key`).  ``donation``
+    defaults to the device kind's behavior: the engine turns
     buffer donation off on the CPU backend, so the cpu model counts the
     scatter outputs as real copies.
     """
-    import jax  # noqa: F401 — fail here, loudly, if jax is unavailable
+    import jax  # fail here, loudly, if jax is unavailable
 
+    if device_kind is None:
+        device_kind = budget_key(jax.devices()[0].device_kind)
     if device_kind not in DEVICE_BUDGETS:
         raise ValueError(f"unknown device kind {device_kind!r}; "
                          f"options: {sorted(DEVICE_BUDGETS)}")

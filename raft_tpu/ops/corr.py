@@ -245,8 +245,8 @@ def _gather_feature_windows(fmap: jax.Array, ix0: jax.Array, iy0: jax.Array, win
     An earlier two-stage version (row gather then column gather)
     materialized a [B, T*win, W, C] intermediate — ~W/win x larger than the
     output, hundreds of MB at chunk 1024 — which made the gather-lookup
-    blockwise path the one degenerate CPU config in BENCH_r05 (0.515 vs
-    1.898 pairs/s for its one-hot sibling).
+    blockwise path the one degenerate config of a CPU sweep (3.7x slower
+    than its one-hot sibling).
     """
     B, H, W, C = fmap.shape
     offs = jnp.arange(win, dtype=jnp.int32)
@@ -277,9 +277,8 @@ def lookup_ondemand(fmap1: jax.Array, fmap2_levels: Sequence[jax.Array],
     size: the live window buffer is B * chunk * (2r+2)^2 * C floats, and a
     round-6 CPU sweep showed time tracking that buffer, not the chunk count
     — ~7-13 MB is the sweet spot at the bench shapes while the old fixed
-    chunk=1024 ran buffers of 100-400 MB for a 3-5x slowdown (the
-    BENCH_r05 'blockwise+bf16' anomaly, 0.515 vs 1.898 pairs/s for the
-    one-hot sibling).  The path stays gather-BOUND by construction either
+    chunk=1024 ran buffers of 100-400 MB for a 3-5x slowdown on the CPU.
+    The path stays gather-BOUND by construction either
     way — it is the reference SampleCorr semantics twin and the fused
     kernel's backward-gradient oracle, not a fast path;
     ``lookup_blockwise_onehot`` replaces the gathers with matmuls and is

@@ -43,7 +43,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..lint.budget import corr_level_plan
+from ..lint.budget import VMEM_BYTES, corr_level_plan
 from ..lint.contracts import contract
 from .corr import (fmap2_pyramid, lookup_blockwise_onehot, mask_ragged_rows,
                    ragged_pyramid)
@@ -51,6 +51,25 @@ from .corr import (fmap2_pyramid, lookup_blockwise_onehot, mask_ragged_rows,
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+# The scoped-VMEM limit every pallas_call here requests: the compiler's
+# 16 MiB default refuses the default block plan inside the train step
+# (lint/budget.py VMEM_BYTES has the figures); the static envelope is
+# checked against the same number.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES)
+
+
+# Row packing passes every interpret-mode test and is REFUSED by the chip's
+# compiler (v5e, jax 0.9.0 / libtpu 0.0.34; tests/test_tpu_compile.py holds
+# the refusal as a strict xfail).  Until repaired (ROADMAP A6/C3) asking for
+# it on the chip is a ValueError raised while the model is traced — before
+# Mosaic, with the reason — never a fallback.
+_PACK_REFUSAL = (
+    "pallas_pack=True (row-packed f2 lanes) does not compile for the TPU: "
+    "MosaicError 'infer-vector-layout: unsupported shape cast' on "
+    "_packed_body's [T, n] -> [T, n, 1, 1] expands (\"tpu.reshape\" "
+    "(vector<128x9xi1>) -> vector<128x9x1x1xi1>)")
 
 
 def _window_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
@@ -102,7 +121,9 @@ def _window_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
     if lookup_style == "vpu":
         # win_y[t,j,w] = sum_h a_y[t,j,h] * corr3[t,h,w]; the f32 multiply
         # keeps the exact bilinear weights (same numerics as the HIGHEST-
-        # precision dots below), and Mosaic fuses multiply into reduce
+        # precision dots below).  At the default plan (T=128, h2_blk=32,
+        # W2p=128) this style needs 19.18M of scoped VMEM: refused under
+        # the compiler's 16 MiB default, accepted under _COMPILER_PARAMS
         win_y = jnp.sum(a_y[:, :, :, None] * corr3[:, None, :, :], axis=2)
         win = jnp.sum(a_x[:, :, None, :] * win_y[:, None, :, :], axis=3)
     else:
@@ -344,6 +365,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Qp, n, n), jnp.float32),
             interpret=interpret,
+            compiler_params=_COMPILER_PARAMS,
         )(S, f1, coords, f2)
     else:
         out = pl.pallas_call(
@@ -357,6 +379,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
             out_specs=pl.BlockSpec((1, T, n, n), lambda b, j, k: (b, j, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((B, Qp, n, n), jnp.float32),
             interpret=interpret,
+            compiler_params=_COMPILER_PARAMS,
         )(f1, coords, f2)
     out = out.reshape(B, Qp, n * n)
     return out[:, :Q] if Qp != Q else out
@@ -388,6 +411,8 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
         raise ValueError(f"p_select must be 'all' or 'window', "
                          f"got {p_select!r}")
     interp = _use_interpret() if interpret is None else interpret
+    if pack_rows and not interp:
+        raise ValueError(_PACK_REFUSAL)
     f1 = fmap1.reshape(B, Q, C)
     cf = coords.reshape(B, Q, 2)
     outs = [
@@ -606,6 +631,7 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, B * Qp, n, n), jnp.float32),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(S, f1s, cs, f2s)
     out = out.reshape(B, Qp, n * n)
     return out[:, :Q] if Qp != Q else out

@@ -44,14 +44,9 @@ def spatial_axis() -> Optional[str]:
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a mapped axis, across jax versions: ``jax.lax
-    .axis_size`` where it exists (jax >= 0.5), else the classic
-    ``psum(1, axis)`` idiom — on a Python literal it constant-folds to the
-    axis size as a plain int, so callers can use it in static control
-    flow either way."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a mapped axis as a plain int, usable in static
+    control flow."""
+    return jax.lax.axis_size(axis_name)
 
 
 def halo_exchange(x: jax.Array, halo: int, axis_name: Optional[str] = None) -> jax.Array:

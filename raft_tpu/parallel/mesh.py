@@ -31,18 +31,12 @@ def make_mesh(axes: Sequence[str] = (DATA_AXIS,),
 
 
 def compat_shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the top-level API (jax >= 0.5,
-    replication checking via ``check_vma``) vs ``jax.experimental.shard_map``
-    (0.4.x, same knob named ``check_rep``).  Checking is disabled either way
-    — this stack's specs replicate params explicitly and the check rejects
-    some valid psum patterns on older jax.  One wrapper so every shard_map
-    call site in parallel/ survives a jax upgrade or downgrade."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking off (``check_vma=False``):
+    this stack's specs replicate params explicitly and the check rejects
+    some valid psum patterns.  One wrapper so every shard_map call site in
+    parallel/ sets the knob the same way."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def batch_sharding(mesh: Mesh, axis: str = DATA_AXIS) -> NamedSharding:

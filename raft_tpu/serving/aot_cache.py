@@ -20,7 +20,9 @@ Layout (SERVING.md "Cold start & cache")::
 
     <root>/<config_hash>-<device_kind>-<jax_version>/
         manifest.json          identity + warmup-grid signature
-        pair-432x1024-b4-<policyhash>.bin    one pickle per engine key
+        pair-432x1024-b4-<policyhash>.bin    one pickle per engine key:
+                               the serialized executable, its pytrees, and
+                               the ids of the devices it was compiled for
 
 Invalidation is whole-directory: the manifest's identity fields
 (config_hash / device_kind / jax_version / jaxlib_version) must ALL match
@@ -28,6 +30,13 @@ the running process or the directory is treated cold and warmup falls back
 to compiling — a stale cache can cost time, never correctness.  A corrupt
 or unreadable entry is skipped with a warning (load counted, miss
 counted), again falling back to compile.
+
+An executable is loaded onto the devices it was compiled for
+(``execution_devices``), never onto "every local device": on a host with
+several chips (or eight virtual CPU devices) a one-device executable loaded
+without them spans them all and fails at its first call.  Device ids are
+process-local — each one-chip fleet replica sees its chip as id 0 — so a
+shared directory stays valid across replicas.
 """
 
 from __future__ import annotations
@@ -45,7 +54,7 @@ from typing import Optional
 
 _log = logging.getLogger("raft_tpu.serving.aot_cache")
 
-MANIFEST_VERSION = 1
+MANIFEST_VERSION = 2      # 2: entries carry their execution device ids
 MANIFEST_NAME = "manifest.json"
 
 # The engine's executable-cache key, in order.  raftlint B5 checks this
@@ -72,6 +81,20 @@ def cache_identity(config) -> dict:
         "jaxlib_version": getattr(__import__("jaxlib"), "__version__",
                                   jax.__version__),
     }
+
+
+def execution_devices(compiled) -> list:
+    """The devices ``compiled`` (a ``jax.stages.Compiled``) runs on, in
+    assignment order.  Every sharding of one executable shares the device
+    assignment, so the first one — input, or output for the no-argument
+    ``szero`` kind — names it."""
+    import jax
+    s = jax.tree.leaves((compiled.input_shardings,
+                         compiled.output_shardings))[0]
+    mesh = getattr(s, "mesh", None)
+    if mesh is not None:
+        return list(mesh.devices.flat)
+    return sorted(s.device_set, key=lambda d: d.id)
 
 
 def key_filename(key) -> str:
@@ -199,10 +222,14 @@ class EngineCache:
         self.stats.loads += 1
         t0 = time.monotonic()
         try:
-            with open(path, "rb") as f:
-                payload, in_tree, out_tree = pickle.load(f)
+            import jax
             from jax.experimental import serialize_executable as _se
-            ex = _se.deserialize_and_load(payload, in_tree, out_tree)
+            with open(path, "rb") as f:
+                payload, in_tree, out_tree, device_ids = pickle.load(f)
+            by_id = {d.id: d for d in jax.devices()}
+            ex = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:
             _log.warning(f"engine cache: corrupt entry {path.name} "
                          f"({type(e).__name__}: {e}); recompiling")
@@ -222,10 +249,11 @@ class EngineCache:
         try:
             from jax.experimental import serialize_executable as _se
             payload, in_tree, out_tree = _se.serialize(compiled)
+            device_ids = [d.id for d in execution_devices(compiled)]
             self.dir.mkdir(parents=True, exist_ok=True)
             tmp = path.with_suffix(f".tmp.{os.getpid()}")
             with open(tmp, "wb") as f:
-                pickle.dump((payload, in_tree, out_tree), f)
+                pickle.dump((payload, in_tree, out_tree, device_ids), f)
             os.replace(tmp, path)
         except Exception as e:
             _log.warning(f"engine cache: could not export {key}: "

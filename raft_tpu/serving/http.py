@@ -92,7 +92,7 @@ MAX_BODY_BYTES = 256 * 2**20   # one 4K pair is ~100 MB as float32 JSON
 
 class BadRequest(Exception):
     # the client's mistake, not the replica's: no SLO burn, no seat in
-    # the error-trace ring (telemetry/spans.py status taxonomy)
+    # the error-trace ring (telemetry/spans.py status classification)
     trace_status = tlm_spans.BAD_REQUEST
 
 

@@ -31,7 +31,7 @@ class RejectedError(Exception):
     ``Retry-After`` header on 429/503 responses — the docstrings always
     promised "retry with backoff"; now the wire says when.
     ``trace_status`` is the request-trace disposition this rejection maps
-    to (telemetry/spans.py status taxonomy)."""
+    to (telemetry/spans.py status classification)."""
     http_status = 500
     retry_after: Optional[float] = None
     trace_status = "shed"

@@ -193,7 +193,7 @@ class FlowServer:
             manifest = None
             if sconfig.history_path:
                 manifest = tlm_events.run_manifest(
-                    config, mode="serve", probe_device=False)
+                    config, mode="serve")
             self.history = MetricHistory(
                 self.registry, interval_s=sconfig.history_interval_s,
                 window=sconfig.history_window,
@@ -672,10 +672,12 @@ class FlowServer:
         return res
 
 
-def serve_cli(args, config: RAFTConfig, load_params) -> int:
-    """-m serve: build, warm, serve until SIGINT/SIGTERM, drain, exit 0."""
+def build_server(args, config: RAFTConfig, load_params) -> "FlowServer":
+    """The FlowServer ``-m serve`` runs, built from its parsed argv (not
+    yet started).  One construction for the CLI and for in-process drivers
+    of the real server (chip_smoke.py); a bad flag combination raises the
+    ServeConfig's ValueError."""
     import os
-    import signal
 
     from .config import parse_buckets
 
@@ -692,50 +694,46 @@ def serve_cli(args, config: RAFTConfig, load_params) -> int:
     if history_path is None:
         history_path = os.path.join(getattr(args, "out", None) or ".",
                                     "metrics_ts.jsonl")
-    try:
-        sconfig = ServeConfig(
-            buckets=parse_buckets(args.buckets),
-            max_batch=args.max_batch,
-            max_wait_ms=args.max_wait_ms,
-            queue_depth=args.queue_depth,
-            default_deadline_ms=args.deadline_ms,
-            host=args.host, port=args.port,
-            dp_devices=args.serve_dp or 1,
-            warmup=not args.no_warmup,
-            iters_policy=getattr(args, "iters_policy", None),
-            trace_sample=getattr(args, "trace_sample", 1.0),
-            slo_pair_ms=getattr(args, "slo_pair_ms", 1000.0),
-            slo_stream_ms=getattr(args, "slo_stream_ms", 500.0),
-            flightrec_path=flightrec or None,
-            # argparse owns the defaults; `or`-style fallbacks would
-            # silently turn an (invalid) explicit 0 into the default
-            # instead of letting ServeConfig raise on it
-            max_sessions=getattr(args, "max_sessions", 64),
-            session_ttl_s=getattr(args, "session_ttl_s", 300.0),
-            ragged=getattr(args, "ragged", False),
-            ragged_batch_pixels=getattr(args, "ragged_batch_pixels", 0),
-            engine_cache_dir=getattr(args, "engine_cache_dir", None),
-            history_interval_s=getattr(args, "history_interval_s", 1.0),
-            history_window=getattr(args, "history_window", 600),
-            history_path=history_path or None,
-            anomaly=not getattr(args, "no_anomaly", False),
-            anomaly_window_s=getattr(args, "anomaly_window_s", 15.0),
-            anomaly_baseline_s=getattr(args, "anomaly_baseline_s", 60.0),
-            # chaos drills: the CLI flag wins, the env var arms CI/ops.
-            # breaker knobs use None-checks, not `or`: --breaker-window 0
-            # is the documented breaker-off switch and must survive
-            chaos=(getattr(args, "chaos", None)
-                   or os.environ.get("RAFT_TPU_CHAOS") or None),
-            **{k: v for k, v in {
-                "breaker_window": getattr(args, "breaker_window", None),
-                "breaker_threshold": getattr(args, "breaker_threshold",
-                                             None),
-                "breaker_cooldown_s": getattr(args, "breaker_cooldown_s",
-                                              None),
-            }.items() if v is not None})
-    except ValueError as e:
-        print(f"ERROR: {e}")
-        return 2
+    sconfig = ServeConfig(
+        buckets=parse_buckets(args.buckets),
+        max_batch=args.max_batch,
+        max_wait_ms=args.max_wait_ms,
+        queue_depth=args.queue_depth,
+        default_deadline_ms=args.deadline_ms,
+        host=args.host, port=args.port,
+        dp_devices=args.serve_dp or 1,
+        warmup=not args.no_warmup,
+        iters_policy=getattr(args, "iters_policy", None),
+        trace_sample=getattr(args, "trace_sample", 1.0),
+        slo_pair_ms=getattr(args, "slo_pair_ms", 1000.0),
+        slo_stream_ms=getattr(args, "slo_stream_ms", 500.0),
+        flightrec_path=flightrec or None,
+        # argparse owns the defaults; `or`-style fallbacks would
+        # silently turn an (invalid) explicit 0 into the default
+        # instead of letting ServeConfig raise on it
+        max_sessions=getattr(args, "max_sessions", 64),
+        session_ttl_s=getattr(args, "session_ttl_s", 300.0),
+        ragged=getattr(args, "ragged", False),
+        ragged_batch_pixels=getattr(args, "ragged_batch_pixels", 0),
+        engine_cache_dir=getattr(args, "engine_cache_dir", None),
+        history_interval_s=getattr(args, "history_interval_s", 1.0),
+        history_window=getattr(args, "history_window", 600),
+        history_path=history_path or None,
+        anomaly=not getattr(args, "no_anomaly", False),
+        anomaly_window_s=getattr(args, "anomaly_window_s", 15.0),
+        anomaly_baseline_s=getattr(args, "anomaly_baseline_s", 60.0),
+        # chaos drills: the CLI flag wins, the env var arms CI/ops.
+        # breaker knobs use None-checks, not `or`: --breaker-window 0
+        # is the documented breaker-off switch and must survive
+        chaos=(getattr(args, "chaos", None)
+               or os.environ.get("RAFT_TPU_CHAOS") or None),
+        **{k: v for k, v in {
+            "breaker_window": getattr(args, "breaker_window", None),
+            "breaker_threshold": getattr(args, "breaker_threshold",
+                                         None),
+            "breaker_cooldown_s": getattr(args, "breaker_cooldown_s",
+                                          None),
+        }.items() if v is not None})
     params = load_params(args, config)
     server = FlowServer(config, params, sconfig, iters=args.iters,
                         verbose=True,
@@ -744,6 +742,19 @@ def serve_cli(args, config: RAFTConfig, load_params) -> int:
     out = getattr(args, "out", None)
     if out:
         server.profile_dir = os.path.join(out, "profiles")
+    return server
+
+
+def serve_cli(args, config: RAFTConfig, load_params) -> int:
+    """-m serve: build, warm, serve until SIGINT/SIGTERM, drain, exit 0."""
+    import signal
+
+    try:
+        server = build_server(args, config, load_params)
+    except ValueError as e:
+        print(f"ERROR: {e}")
+        return 2
+    sconfig = server.sconfig
     t0 = time.monotonic()
     server.start()
     print(f"[serve] listening on {server.url}  "

@@ -52,7 +52,7 @@ from typing import Dict, List, Optional, Tuple
 
 from . import events as _events
 
-# The trace-status taxonomy (SERVING.md): terminal disposition of one
+# The trace-status classification (SERVING.md): terminal disposition of one
 # request.  ``degraded`` is a SUCCESS whose warm path faulted (the stream
 # cold-restart heal) — retained by the recorder like an error, answered
 # like an ok.  ``bad_request`` is the CLIENT's mistake (400): it neither

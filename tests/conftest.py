@@ -12,6 +12,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from _cpu_backend import force_cpu_backend
 
+# Tests count real compiles (RecompileWatch, the AOT-cache "loads without
+# compiling" cases), so JAX's persistent compilation cache is off for the
+# test process and, through the environment, for every child it starts.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
 force_cpu_backend(8)
 
 

@@ -105,7 +105,7 @@ def test_parse_prom_text_labels_and_comments():
     assert "# HELP raft_serving_queue_depth d" not in out
 
 
-def test_status_class_taxonomy():
+def test_status_class_classification():
     assert status_class(200) == "ok"
     assert status_class(429) == "shed"
     assert status_class(503) == "shed"

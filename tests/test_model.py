@@ -369,11 +369,27 @@ def test_scan_unroll_equivalence():
 
 # ------------------------------------------- streaming feature-reuse path --
 
+def _assert_close_rel(got, ref, rel=1e-4):
+    """max|got - ref| within ``rel`` of the reference's magnitude.
+
+    The streaming path computes what the pairwise path computes, but encodes
+    the two frames in separate batch-1 passes where raft_forward runs one
+    batched 2B pass, and XLA fuses (and so orders the f32 reductions of)
+    the two differently.  With untrained weights the flows are ~190 px, so
+    a round-off of 2.5e-5 RELATIVE is 5e-3 px: an absolute 1e-5 gate tests
+    the compiler's fusion choices, not the model.  1e-4 of the magnitude
+    still fails any bf16 path (~1e-2) or a wrong operand."""
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    scale = max(float(np.abs(ref).max()), 1.0)
+    diff = float(np.abs(got - ref).max())
+    assert diff / scale < rel, (diff, scale)
+
+
 def test_forward_from_features_matches_pairwise():
     """The streaming path's contract: encode_frame + forward_from_features
     must reproduce raft_forward on the same frames — the cached-feature
     advance IS the pairwise computation, just with the encoders factored
-    out.  Batch-identical ops -> exact match."""
+    out (equal up to f32 reduction order, see _assert_close_rel)."""
     from raft_tpu.models import encode_frame, forward_from_features
 
     config = RAFTConfig.small_model(iters=3)
@@ -383,11 +399,8 @@ def test_forward_from_features_matches_pairwise():
     fmap1, cnet1 = encode_frame(params, im1, config)
     fmap2, _ = encode_frame(params, im2, config)
     out = forward_from_features(params, fmap1, fmap2, cnet1, config)
-    np.testing.assert_allclose(np.asarray(out.flow), np.asarray(ref.flow),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(out.flow_lr),
-                               np.asarray(ref.flow_lr),
-                               rtol=1e-5, atol=1e-5)
+    _assert_close_rel(out.flow, ref.flow)
+    _assert_close_rel(out.flow_lr, ref.flow_lr)
 
 
 def test_forward_from_features_flow_init_matches():
@@ -404,8 +417,7 @@ def test_forward_from_features_flow_init_matches():
     fmap2, _ = encode_frame(params, im2, config)
     out = forward_from_features(params, fmap1, fmap2, cnet1, config,
                                 flow_init=init)
-    np.testing.assert_allclose(np.asarray(out.flow), np.asarray(ref.flow),
-                               rtol=1e-5, atol=1e-5)
+    _assert_close_rel(out.flow, ref.flow)
 
 
 def test_stream_step_fn_jits_and_matches():
@@ -423,15 +435,11 @@ def test_stream_step_fn_jits_and_matches():
     step = jax.jit(make_stream_step_fn(config))
     zeros = jnp.zeros((1, 4, 6, 2), jnp.float32)
     flow, flow_lr, fmap2, cnet2, = step(params, im2, fmap1, cnet1, zeros)
-    scale = max(float(np.abs(np.asarray(ref.flow)).max()), 1.0)
-    diff = float(np.abs(np.asarray(flow) - np.asarray(ref.flow)).max())
-    assert diff / scale < 1e-4, (diff, scale)
+    _assert_close_rel(flow, ref.flow)
     # the returned current-frame maps equal a direct encode (cacheable)
     fmap2_ref, cnet2_ref = encode_frame(params, im2, config)
-    np.testing.assert_allclose(np.asarray(fmap2), np.asarray(fmap2_ref),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(cnet2), np.asarray(cnet2_ref),
-                               rtol=1e-5, atol=1e-5)
+    _assert_close_rel(fmap2, fmap2_ref)
+    _assert_close_rel(cnet2, cnet2_ref)
 
 
 def test_stream_step_fn_counted_under_converge():

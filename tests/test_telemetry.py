@@ -129,10 +129,17 @@ def test_run_manifest_provenance_fields():
     assert man["schema"] == 1 and man["argv"]
 
 
-def test_run_manifest_probe_device_false_never_touches_jax():
-    man = run_manifest(mode="bench", probe_device=False)
-    assert man["device_kind"] is None and man["backend"] is None
-    assert man["git_sha"]          # provenance survives without a device
+def test_run_manifest_does_not_hide_a_failed_device_query(monkeypatch):
+    """A backend that fails to come up raises out of the manifest: there
+    is no device-less stamp to carry on with."""
+    import jax
+
+    def down():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", down)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        run_manifest(mode="bench")
 
 
 def test_runlog_roundtrip_and_partial_line_tolerance(tmp_path):
@@ -559,7 +566,7 @@ def _load_tlm():
 def _fake_run(tmp_path, name, sha, epe):
     d = tmp_path / name
     d.mkdir()
-    man = run_manifest(mode="train", probe_device=False)
+    man = run_manifest(mode="train")
     man["git_sha"] = sha
     man["config_hash"] = "cafe" * 4
     lines = [
@@ -602,7 +609,7 @@ def test_tlm_handles_bench_json_and_missing_manifest(tmp_path):
     bench.write_text(json.dumps({
         "metric": "inference throughput", "value": 3.25,
         "unit": "pairs/sec/chip",
-        "manifest": run_manifest(mode="bench", probe_device=False)}))
+        "manifest": run_manifest(mode="bench")}))
     out = "\n".join(tlm.summary_lines(bench))
     assert "3.25" in out and "git_sha" in " ".join(tlm.MANIFEST_FIELDS) \
         or "git_sha" in out
@@ -734,7 +741,7 @@ def test_tlm_summary_highlights_fleet_cache_and_anomalies(tmp_path):
     tlm = _load_tlm()
     d = tmp_path / "run"
     d.mkdir()
-    man = run_manifest(mode="serve", probe_device=False)
+    man = run_manifest(mode="serve")
     lines = [
         {"t": 1.0, "event": "manifest", **man},
         {"t": 2.0, "event": "anomaly", "rule": "p95_drift", "edge": "fire",

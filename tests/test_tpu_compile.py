@@ -1,0 +1,172 @@
+"""The main path's Pallas kernels, compiled for a TPU v5e that is DESCRIBED,
+not attached (on-chip-measurement guide §2, rehearsal 3).
+
+Interpret mode cannot see what Mosaic refuses — a slice off the tiling, a
+shape cast it has no layout for, more scoped VMEM than a kernel may use.
+These cases ask the chip's own compiler, at the real widths (Sintel 440x1024:
+55x128 queries, C=256; hidden 128), and cost ~2 s each and no chip time.
+They call the kernels' own entry points with ``interpret=False`` /
+``impl='kernel'`` — no program option exists for this.
+
+All of them live in THIS file: the worker that runs it loads the TPU library
+and keeps it; a second file could land on another worker, whose fixture
+would then skip.  The topology is described inside a module-scoped fixture
+(never at import), and JAX's persistent compilation cache is off around the
+compiles (an entry written for a described device cannot be read back here
+and only warns).
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from raft_tpu.ops import corr_pallas
+from raft_tpu.ops.corr_pallas import _lookup_level, _ragged_lookup_level
+
+P = jax.lax.Precision
+H, W, C = 55, 128, 256          # Sintel bucket 440x1024 at the 1/8 grid
+RADIUS = 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *specs):
+    """Compile for the described chip; returns the optimized HLO text."""
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _corr_specs(sd, level: int, batch: int = 1):
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=sd)
+    return (s((batch, H * W, C), jnp.float32),
+            s((batch, H // 2 ** level, W // 2 ** level, C), jnp.float32),
+            s((batch, H * W, 2), jnp.float32))
+
+
+@pytest.mark.parametrize("name,level,kw", [
+    ("highest", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096)),
+    ("default", 0, dict(corr_precision=P.DEFAULT, p_blk_target=4096)),
+    ("window", 0, dict(corr_precision=P.DEFAULT, p_blk_target=1024,
+                       p_select="window")),
+    ("coarsest-level", 3, dict(corr_precision=P.HIGHEST,
+                               p_blk_target=4096)),
+    # 19.18M of scoped VMEM: refused under the compiler's 16 MiB default,
+    # accepted under the limit the kernels request (lint/budget.VMEM_BYTES)
+    ("vpu", 0, dict(corr_precision=P.DEFAULT, p_blk_target=4096,
+                    lookup_style="vpu")),
+])
+def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw):
+    fn = functools.partial(_lookup_level, radius=RADIUS, level=level,
+                           q_blk=128, interpret=False, **kw)
+    assert "tpu_custom_call" in _compile(fn, *_corr_specs(one_chip, level))
+
+
+def test_ragged_corr_kernel_compiles_for_v5e(one_chip):
+    """The ``sizes``-operand (mixed-resolution) kernel at batch 2."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    f1, f2, coords = _corr_specs(one_chip, 0, batch=2)
+    fn = functools.partial(_ragged_lookup_level, radius=RADIUS, level=0,
+                           q_blk=128, p_blk_target=4096, interpret=False)
+    text = _compile(fn, f1, f2, coords, s((2, H * W), jnp.bool_),
+                    s((2,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32-io", "bf16-io"])
+def test_gru_kernel_compiles_for_v5e(one_chip, dtype):
+    """Fused SepConvGRU at 55x128x128, ``gru_block_rows=8``.  The f32 case
+    needs 17.03M of scoped VMEM — over the compiler's 16 MiB default, which
+    is why the kernel asks for lint/budget.VMEM_BYTES."""
+    from raft_tpu.models.update import init_sep_conv_gru, precompute_gru_ctx
+    from raft_tpu.ops.gru_pallas import sep_conv_gru_pallas
+
+    def spec(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, dtype if jnp.issubdtype(a.dtype, jnp.floating)
+                else a.dtype, sharding=one_chip), tree)
+
+    hid = 128
+    p = spec(jax.eval_shape(
+        lambda: init_sep_conv_gru(jax.random.PRNGKey(0), hid, 256)))
+    h = spec(jax.ShapeDtypeStruct((1, H, W, hid), dtype))
+    ctx = spec(jax.eval_shape(
+        lambda pp, i: precompute_gru_ctx(pp, i, hid), p, h))
+    fn = functools.partial(sep_conv_gru_pallas, block_rows=8,
+                           interpret=False, impl="kernel")
+    assert "tpu_custom_call" in _compile(fn, p, h, h, ctx)
+
+
+def test_whole_inference_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The one whole-program compile: the served pair executable's model —
+    raft-things, bf16, both kernels, 12 iterations, 1x440x1024.  The
+    backend check is steered here, in the test (this process's default
+    backend is the CPU), not through a program option."""
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.models import init_raft
+    from raft_tpu.models.raft import make_inference_fn
+
+    # both kernels ask jax.default_backend() whether to interpret (corr)
+    # or to run the XLA twin (GRU, impl='auto')
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = RAFTConfig.full(iters=12, compute_dtype="bfloat16",
+                             corr_impl="pallas", gru_impl="pallas")
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config)))
+    img = jax.ShapeDtypeStruct((1, 440, 1024, 3), jnp.float32,
+                               sharding=one_chip)
+    compiled = jax.jit(make_inference_fn(config)).lower(
+        params, img, img).compile()
+    # 4 pyramid-level corr kernels + the fused GRU, inside the scan body
+    assert compiled.as_text().count("tpu_custom_call") >= 5
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+
+
+# --- what the chip's compiler refuses today (ROADMAP A6/C3) ---------------
+# strict: the day a JAX upgrade or a repair makes it compile, the xfail
+# fails, and ops/corr_pallas._PACK_REFUSAL comes out.
+
+@pytest.mark.xfail(strict=True, raises=Exception,
+                   reason="MosaicError: infer-vector-layout: unsupported "
+                          "shape cast ... \"tpu.reshape\" (vector<128x9xi1>)"
+                          " -> vector<128x9x1x1xi1> (_packed_body)")
+def test_pallas_pack_is_refused_by_the_compiler(one_chip):
+    fn = functools.partial(_lookup_level, radius=RADIUS, level=1, q_blk=128,
+                           p_blk_target=4096, interpret=False,
+                           pack_rows=True)
+    _compile(fn, *_corr_specs(one_chip, 1))
+
+
+def test_pallas_pack_raises_before_mosaic():
+    """Asked for on the chip path (``interpret=False``) row packing raises
+    a ValueError that names the compiler's refusal — while tracing, needing
+    no topology; in interpret mode it still runs (its parity tests in
+    test_corr_pallas.py)."""
+    f1 = jnp.zeros((1, 8, 16, 32), jnp.float32)
+    coords = jnp.zeros((1, 8, 16, 2), jnp.float32)
+    with pytest.raises(ValueError, match="pallas_pack=True"):
+        corr_pallas._fused_lookup_impl(f1, (f1,), coords, RADIUS,
+                                       interpret=False, pack_rows=True)

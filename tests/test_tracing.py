@@ -58,7 +58,7 @@ def test_status_escalation_and_exception_mapping():
     tr.set_status(spans.DEGRADED)
     tr.set_status(spans.OK)                # cannot de-escalate
     assert tr.finish()["status"] == "degraded"
-    # exception -> status taxonomy (the classes carry trace_status)
+    # exception -> status classification (the classes carry trace_status)
     assert spans.status_of(QueueFull("x")) == "shed"
     assert spans.status_of(BreakerOpen("x")) == "shed"
     assert spans.status_of(DeadlineExceeded("x")) == "timeout"
@@ -251,7 +251,7 @@ def test_cobatched_requests_share_one_execute_span():
 
 def test_failure_paths_close_traces_with_the_right_status():
     """Poisoned (single-request bisection terminus), shed (breaker), and
-    timeout (queue purge) each close their trace with the taxonomy status
+    timeout (queue purge) each close their trace with the classification status
     — and no trace leaks open."""
     eng = StubEngine(fail=True)
     server = _server(eng, breaker_window=8, breaker_threshold=0.5,

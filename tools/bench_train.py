@@ -56,19 +56,18 @@ def main() -> int:
     from raft_tpu.models import init_raft
     from raft_tpu.training import Batch, TrainState, make_optimizer, make_train_step
 
+    from raft_tpu.compile_cache import configure_compile_cache
+    configure_compile_cache()
+
     dev = jax.devices()[0]
     impl = args.impl
-    if jax.default_backend() != "tpu" and impl.startswith("pallas"):
-        # interpret mode would swamp the timing — fall back to blockwise,
-        # but KEEP the composable non-pallas tokens (e.g. -ctx) so a CPU
-        # run of 'pallas-bf16corr-ctx' still measures gru_ctx_hoist rather
-        # than silently timing the plain config (kernel-only tokens like
-        # -win/-pack/bf16corr have no blockwise meaning and are dropped;
-        # use --precision to override corr precision explicitly).
-        kept = [t for t in impl.split("-")[1:] if t in ("ctx", "onehot")]
-        impl = "-".join(["blockwise"] + kept)
-        print(f"# non-TPU backend: measuring {impl!r} instead of "
-              f"{args.impl!r}", file=sys.stderr)
+    if dev.platform != "tpu" and impl.startswith("pallas"):
+        # the Pallas kernels run in interpret mode off the chip: a timing
+        # of that is no measurement, and another impl is another result
+        print(f"ERROR: --impl {impl!r} needs the TPU (found {dev.platform}:"
+              f"{dev.device_kind}); name a non-Pallas impl for a CPU run, "
+              f"e.g. --impl blockwise", file=sys.stderr)
+        return 2
     H, W = args.size
     # candidate names share bench.py's mapping (-win/-pack/-winpack etc.);
     # explicit --precision and the training iteration count then override

@@ -234,7 +234,7 @@ def budget_crosscheck(server, prom):
             problems.append(
                 f"{len(extra)} live executable(s) the static enumeration "
                 f"missed: {extra[:4]}")
-    device_kind = "tpu-v4" if jax.default_backend() == "tpu" else "cpu"
+    device_kind = lint_budget.budget_key(jax.devices()[0].device_kind)
     report = lint_budget.analyze(engine.config, engine.sconfig,
                                  device_kind=device_kind,
                                  stream=engine.stream,
@@ -1312,7 +1312,12 @@ def run_fleet_bench(args) -> int:
     from raft_tpu.config import RAFTConfig, init_rng
     from raft_tpu.convert.weights import save_params_npz
     from raft_tpu.fleet import (FleetConfig, FleetRouter, ReplicaManager,
-                                RollingUpdater)
+                                RollingUpdater, keep_launcher_off_chip)
+
+    # one process for each chip: this process drives load and seeds the
+    # shared weights — numpy-side work — and must not hold the chips its
+    # replicas need (the manager shows each replica one chip)
+    keep_launcher_off_chip()
     from raft_tpu.models import init_raft
     from raft_tpu.telemetry.watchdogs import lock_validator, \
         lock_watch_enabled
@@ -2129,7 +2134,11 @@ def main() -> int:
         # its counter stays 0 with the policy on
         os.environ["RAFT_TPU_WATCHDOGS"] = "1"
     if args.cpu:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        # assignment, not setdefault: a TPU host exports JAX_PLATFORMS
+        # itself, and --cpu must win here and in every child
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    from raft_tpu.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     if args.coldstart:
         if args.smoke:
