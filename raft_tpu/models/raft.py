@@ -181,13 +181,14 @@ def raft_forward(params: Dict[str, dict], image1: jax.Array, image2: jax.Array,
         image1 = mask_ragged_rows(image1, sizes)
         image2 = mask_ragged_rows(image2, sizes)
 
-    x1 = _preprocess(image1, config)
-    x2 = _preprocess(image2, config)
-
     rngs = jax.random.split(rng, 2) if rng is not None else (None, None)
-    # Shared-weight feature encoder on both frames (reference RAFT.py:79-80):
-    # batch the two frames through one encoder call so XLA sees 2B-sized convs.
-    x12 = jnp.concatenate([x1, x2], axis=0)
+    with stage("raft/preprocess"):
+        x1 = _preprocess(image1, config)
+        x2 = _preprocess(image2, config)
+        # Shared-weight feature encoder on both frames (reference
+        # RAFT.py:79-80): batch the two frames through one encoder call so
+        # XLA sees 2B-sized convs.
+        x12 = jnp.concatenate([x1, x2], axis=0)
     with stage("raft/fnet"):
         fmaps, _ = apply_encoder(params["fnet"], x12, "instance",
                                  small=config.small,
@@ -201,8 +202,8 @@ def raft_forward(params: Dict[str, dict], image1: jax.Array, image2: jax.Array,
             params["cnet"], x1, cnet_norm, small=config.small, train=train,
             axis_name=axis_name, dropout=config.dropout, rng=rngs[1],
             bn_train=train and not freeze_bn)
-    net = jnp.tanh(cnet[..., :config.hidden_dim])
-    inp = jax.nn.relu(cnet[..., config.hidden_dim:])
+        net = jnp.tanh(cnet[..., :config.hidden_dim])
+        inp = jax.nn.relu(cnet[..., config.hidden_dim:])
 
     sizes8 = None if sizes is None else sizes.astype(jnp.int32) // 8
     out = _iterate_flow(params, fmap1, fmap2, net, inp, config,
@@ -350,14 +351,14 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
             raise NotImplementedError(
                 "corr_impl='pallas' requires ops/corr_pallas.py (the fused "
                 "TPU kernel); use 'dense' or 'blockwise'.") from e
-        lookup = make_fused_lookup(fmap1c, fmap2c, config.corr_levels,
-                                   config.corr_radius,
-                                   corr_precision=corr_prec,
-                                   q_blk=config.pallas_q_blk,
-                                   p_blk_target=config.pallas_p_blk,
-                                   lookup_style=config.pallas_lookup_style,
-                                   p_select=config.pallas_p_select,
-                                   pack_rows=config.pallas_pack)
+        with stage("raft/corr_pyramid"):      # fmap2's pooled levels
+            lookup = make_fused_lookup(
+                fmap1c, fmap2c, config.corr_levels, config.corr_radius,
+                corr_precision=corr_prec, q_blk=config.pallas_q_blk,
+                p_blk_target=config.pallas_p_blk,
+                lookup_style=config.pallas_lookup_style,
+                p_select=config.pallas_p_select,
+                pack_rows=config.pallas_pack)
     else:
         raise ValueError(config.corr_impl)
 
@@ -381,8 +382,10 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
         # each here instead of a third of every in-loop gate contraction.
         # gru_impl='pallas' requires them regardless of the hoist flag (the
         # fused kernel never contracts the context channels in-loop).
-        gru_ctx = precompute_gru_ctx(params["update_block"]["gru"], inp,
-                                     config.hidden_dim, small=config.small)
+        with stage("raft/gru_ctx"):
+            gru_ctx = precompute_gru_ctx(params["update_block"]["gru"], inp,
+                                         config.hidden_dim,
+                                         small=config.small)
 
     def gru_step(net, coords1):
         """One GRU update — shared by every loop form below.  Returns the

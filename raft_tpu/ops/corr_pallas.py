@@ -45,6 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..lint.budget import VMEM_BYTES, corr_level_plan
 from ..lint.contracts import contract
+from ..telemetry.trace import stage
 from .corr import (fmap2_pyramid, lookup_blockwise_onehot, mask_ragged_rows,
                    ragged_pyramid)
 
@@ -415,14 +416,20 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
         raise ValueError(_PACK_REFUSAL)
     f1 = fmap1.reshape(B, Q, C)
     cf = coords.reshape(B, Q, 2)
-    outs = [
-        _lookup_level(f1, f2l, cf, radius, i, q_blk=q_blk,
-                      p_blk_target=p_blk_target, interpret=interp,
-                      corr_precision=corr_precision,
-                      lookup_style=lookup_style, p_select=p_select,
-                      pack_rows=pack_rows)
-        for i, f2l in enumerate(f2_levels)
-    ]
+    outs = []
+    for i, f2l in enumerate(f2_levels):
+        # a scope per pyramid level (.../raft/corr_lookup/l<i>/...): each
+        # level is one kernel launch of its own size, and a trace reader can
+        # then tell them apart through the engine's instruction -> stage
+        # map.  The compiler names a kernel's instruction after the
+        # INNERMOST scope (%corr_lookup.<n>, which the benchmark's roofline
+        # reader finds it by), so the scope ends as the enclosing one does.
+        with stage(f"l{i}/corr_lookup"):
+            outs.append(_lookup_level(
+                f1, f2l, cf, radius, i, q_blk=q_blk,
+                p_blk_target=p_blk_target, interpret=interp,
+                corr_precision=corr_precision, lookup_style=lookup_style,
+                p_select=p_select, pack_rows=pack_rows))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
 
@@ -660,13 +667,13 @@ def _ragged_fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
     live = ((iy < sizes8[:, 0, None, None])
             & (ix < sizes8[:, 1, None, None])).reshape(B, Q)
     rows = sizes8[:, 0]
-    outs = [
-        _ragged_lookup_level(f1, f2l, cf, live, rows // (2 ** i), radius, i,
-                             q_blk=q_blk, p_blk_target=p_blk_target,
-                             interpret=interp, corr_precision=corr_precision,
-                             lookup_style=lookup_style)
-        for i, f2l in enumerate(f2_levels)
-    ]
+    outs = []
+    for i, f2l in enumerate(f2_levels):
+        with stage(f"l{i}/corr_lookup"):      # as in _fused_lookup_impl
+            outs.append(_ragged_lookup_level(
+                f1, f2l, cf, live, rows // (2 ** i), radius, i, q_blk=q_blk,
+                p_blk_target=p_blk_target, interpret=interp,
+                corr_precision=corr_precision, lookup_style=lookup_style))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
 

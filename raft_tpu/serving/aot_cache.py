@@ -23,6 +23,11 @@ Layout (SERVING.md "Cold start & cache")::
         pair-432x1024-b4-<policyhash>.bin    one pickle per engine key:
                                the serialized executable, its pytrees, and
                                the ids of the devices it was compiled for
+        pair-432x1024-b4-<policyhash>.stages.json   instruction name ->
+                               stage() path of that executable
+                               (telemetry/trace.instruction_stages): the
+                               chip's trace names an operation by its
+                               instruction, this names its stage
 
 Invalidation is whole-directory: the manifest's identity fields
 (config_hash / device_kind / jax_version / jaxlib_version) must ALL match
@@ -122,7 +127,6 @@ class CacheStats:
     misses: int = 0
     loads: int = 0
     saves: int = 0
-    load_seconds: list = dataclasses.field(default_factory=list)
 
     def as_dict(self) -> dict:
         return {"hits": self.hits, "misses": self.misses,
@@ -220,7 +224,6 @@ class EngineCache:
             self.stats.misses += 1
             return None
         self.stats.loads += 1
-        t0 = time.monotonic()
         try:
             import jax
             from jax.experimental import serialize_executable as _se
@@ -235,9 +238,33 @@ class EngineCache:
                          f"({type(e).__name__}: {e}); recompiling")
             self.stats.misses += 1
             return None
-        self.stats.load_seconds.append(time.monotonic() - t0)
         self.stats.hits += 1
         return ex
+
+    def save_stages(self, key, compiled) -> bool:
+        """Write the instruction -> ``stage()`` map of ``compiled`` beside its
+        entry, unless it is there (so a warm boot pays nothing): taken from
+        the ``op_name`` metadata of the executable's own text, which a
+        loaded executable carries as a compiled one does.  Best effort — a
+        map is telemetry, never worth failing a boot for."""
+        path = self.dir / (key_filename(key)[:-len(".bin")] + ".stages.json")
+        if path.exists():
+            return True
+        try:
+            from ..telemetry.trace import (STAGE_MAP_VERSION,
+                                           instruction_stages)
+            doc = {"version": STAGE_MAP_VERSION,
+                   "key": dict(zip(KEY_FIELDS, key)),
+                   "instructions": instruction_stages(compiled.as_text())}
+            self.dir.mkdir(parents=True, exist_ok=True)
+            tmp = path.with_suffix(f".tmp.{os.getpid()}")
+            tmp.write_text(json.dumps(doc, default=str))
+            os.replace(tmp, path)
+        except Exception as e:
+            _log.warning(f"engine cache: no stage map for {key}: "
+                         f"{type(e).__name__}: {e}")
+            return False
+        return True
 
     def save(self, key, compiled) -> bool:
         """Export a ``jax.stages.Compiled`` under ``key`` (atomic rename;

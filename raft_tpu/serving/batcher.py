@@ -64,6 +64,7 @@ import numpy as np
 from ..data.pipeline import unpad
 from ..telemetry import events as tlm_events
 from ..telemetry import spans as tlm_spans
+from ..telemetry.trace import host_stage, set_batch
 from .queue import DeadlineExceeded, RequestQueue
 
 
@@ -140,6 +141,8 @@ class MicroBatcher:
         self.retry_backoff_s = retry_backoff_s
         self.on_crash = on_crash          # supervisor hook: (exception) ->
         self.batches = 0
+        self.device_batches = 0           # ordinal of the last device batch
+        self._stage_children: Dict = {}   # stage label -> its counter child
         self.served = 0
         self.timed_out = 0
         self._inflight_batch = None       # the popped-but-unresolved batch
@@ -174,6 +177,51 @@ class MicroBatcher:
             m.labels(args[0]).inc(args[1])
         elif hasattr(m, "inc"):
             m.inc(*args)
+
+    def _stage_seconds(self, label: str, seconds: float) -> None:
+        """``raft_serving_stage_seconds_total{stage=label}``.  The labelled
+        child is looked up once: ``labels()`` takes the family's lock, which
+        the 64 handler threads' stages take too, and a batcher that blocks
+        on a lock gives up the GIL to all of them."""
+        child = self._stage_children.get(label)
+        if child is None:
+            family = self.metrics.get("stage_seconds")
+            if family is None:
+                return
+            child = self._stage_children[label] = family.labels(label)
+        child.inc(seconds)
+
+    def _stage_done(self, st) -> None:
+        """Sink of the batcher thread's own host stages: stage seconds."""
+        self._stage_seconds(st.label, st.t1 - st.t0)
+
+    def _next_device_batch(self) -> None:
+        """A device batch begins: its ordinal rides on every host stage this
+        thread opens from here on (``batch=<n>`` of the annotations), and the
+        slot for the engine's stages is opened."""
+        self.device_batches += 1
+        set_batch(self.device_batches)
+        tlm_spans.set_device_slot([])
+
+    def _device_call(self, real: int, padded: int) -> None:
+        """One device call of ``padded`` rows, ``real`` of them requests."""
+        self._observe("device_calls")
+        self._observe("device_rows", "real", real)
+        self._observe("device_rows", "padded", padded)
+
+    def _take_device_stages(self) -> list:
+        """The engine's stages of this batch's device calls (h2d, dispatch,
+        wait, fetch), counted into the stage seconds once; the callers turn
+        them into child spans of ``execute`` on each traced request."""
+        calls = tlm_spans.take_device_slot() or []
+        for _kind, _span, label, c0, c1 in calls:
+            self._stage_seconds(label, c1 - c0)
+        return calls
+
+    @staticmethod
+    def _device_spans(tr, calls, parent: str) -> None:
+        for kind, span, _label, c0, c1 in calls:
+            tr.span(span, c0, c1, parent=parent, call=kind)
 
     def _observe_waste(self, group, padded: int) -> None:
         """raft_batch_padding_waste_ratio: the fraction of one device
@@ -243,8 +291,8 @@ class MicroBatcher:
         tr = r.trace
         if tr is not None:
             tr.span("queue_wait", r.enqueued_at, r.dequeued_at)
-            tlm_spans.set_device_slot([])
-        self._observe("inflight", 1)
+        self._next_device_batch()
+        self._device_call(1, 1)
         t0 = time.monotonic()
         err, flow, iters_used = None, None, None
         try:
@@ -255,10 +303,8 @@ class MicroBatcher:
             # shutdown signal: fail the request, then let KeyboardInterrupt
             # / SystemExit keep propagating.
             err = e
-        calls = tlm_spans.take_device_slot()
+        calls = self._take_device_stages()
         t1 = time.monotonic()
-        self._observe("inflight", -1)
-        self._observe("batch_latency", t1 - t0)
         self._observe("stream_steps")
         self._observe("stream_step_seconds", t1 - t0)
         self._observe("stream_step_batch", 1.0)
@@ -270,9 +316,7 @@ class MicroBatcher:
                           status=(tlm_spans.OK if err is None
                                   else tlm_spans.status_of(err)),
                           batch_real=1, batch_padded=1)
-            for kind, c0, c1, c2 in calls or ():
-                tr.span("execute_dispatch", c0, c1, parent=eid, call=kind)
-                tr.span("execute_block", c1, c2, parent=eid, call=kind)
+            self._device_spans(tr, calls, eid)
         if err is not None:
             if self.breaker is not None:
                 self.breaker.record(False)
@@ -287,9 +331,7 @@ class MicroBatcher:
         if iters_used is not None:
             r.iters_used = int(np.asarray(iters_used).reshape(-1)[0])
             self._observe("iters_used", float(r.iters_used))
-        now = time.monotonic()
-        self._observe("queue_latency", r.dequeued_at - r.enqueued_at)
-        self._observe("request_latency", now - r.enqueued_at)
+        self._observe("request_latency", time.monotonic() - r.enqueued_at)
         self._observe("requests", "ok", 1)
         self.served += 1
         if flow is None:                 # session open: no pair yet
@@ -329,9 +371,8 @@ class MicroBatcher:
             r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
             r.trace.span("batch_form", r.dequeued_at, t_form1, group=n)
         self._observe_waste(group, padded)
-        self._observe("inflight", 1)
-        if traced:
-            tlm_spans.set_device_slot([])
+        self._next_device_batch()
+        self._device_call(n, padded)
         t0 = time.monotonic()
         err, outcomes = None, None
         try:
@@ -343,10 +384,8 @@ class MicroBatcher:
             # layer stamps per-request trace ids), then let
             # KeyboardInterrupt / SystemExit keep propagating
             err = e
-        calls = tlm_spans.take_device_slot() if traced else ()
+        calls = self._take_device_stages()
         t1 = time.monotonic()
-        self._observe("inflight", -1)
-        self._observe("batch_latency", t1 - t0)
         self._observe("stream_step_seconds", t1 - t0)
         if err is None:
             # honest device-step accounting: only rows whose result came
@@ -373,11 +412,7 @@ class MicroBatcher:
         def _exec_span(tr, status):
             tr.span("execute", t0, t1, status=status, span_id=exec_sid,
                     batch_real=n, batch_padded=padded)
-            for kind, c0, c1, c2 in calls or ():
-                tr.span("execute_dispatch", c0, c1, parent=exec_sid,
-                        call=kind)
-                tr.span("execute_block", c1, c2, parent=exec_sid,
-                        call=kind)
+            self._device_spans(tr, calls, exec_sid)
 
         if err is not None:
             if self.breaker is not None:
@@ -393,7 +428,6 @@ class MicroBatcher:
         now = time.monotonic()
         served = 0
         for r, (flow, iters_used, rerr) in zip(group, outcomes):
-            self._observe("queue_latency", r.dequeued_at - r.enqueued_at)
             self._observe("request_latency", now - r.enqueued_at)
             r.batch_real, r.batch_padded = n, padded
             if rerr is not None:
@@ -434,23 +468,13 @@ class MicroBatcher:
                 for r in batch:
                     self._execute_stream(r)
             return
-        for r in batch:
-            if r.trace is not None:
-                r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
-        for group in self._chunks(batch):
-            n = len(group)
-            padded = self.pad_batch_to(min(n, self.max_batch))
-            self._observe("batch_size", float(n))
-            self._observe("batch_occupancy", n / padded)
-            self._observe_waste(group, padded)
-            self._observe("inflight", 1)
-            t0 = time.monotonic()
-            try:
-                budget = [self._bisect_budget(n)]
-                self._run_group(group, budget)
-            finally:
-                self._observe("inflight", -1)
-                self._observe("batch_latency", time.monotonic() - t0)
+        with host_stage("raft.batch.form", self._stage_done):
+            for r in batch:
+                if r.trace is not None:
+                    r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
+            groups = self._chunks(batch)
+        for group in groups:
+            self._run_group(group, [self._bisect_budget(len(group))])
 
     def _run_group(self, group, budget, formed: bool = False) -> None:
         """Run one same-bucket group; on persistent engine failure, split
@@ -458,27 +482,34 @@ class MicroBatcher:
         is the batch-wide engine-call allowance (mutable 1-list);
         ``formed`` marks bisection sub-groups (the batch_form span is
         recorded once, on the original group)."""
-        n = len(group)
-        padded = self.pad_batch_to(min(n, self.max_batch))
-        traced = [r for r in group if r.trace is not None]
-        t_form1 = time.monotonic()
+        self._next_device_batch()
+        with host_stage("raft.batch.form", self._stage_done) as st:
+            n = len(group)
+            padded = self.pad_batch_to(min(n, self.max_batch))
+            traced = [r for r in group if r.trace is not None]
+            if not formed:
+                self._observe("batch_size", float(n))
+                self._observe("batch_occupancy", n / padded)
+                self._observe_waste(group, padded)
         if not formed:
+            # a request's batch_form runs from ITS dequeue to here: the
+            # form stage is everything between take and pad (the loop's
+            # bookkeeping and _execute's are under the same annotation)
             for r in traced:
-                r.trace.span("batch_form", r.dequeued_at, t_form1, group=n)
-        im1 = np.concatenate([r.image1 for r in group]
-                             + [group[-1].image1] * (padded - n))
-        im2 = np.concatenate([r.image2 for r in group]
-                             + [group[-1].image2] * (padded - n))
-        t_pad1 = time.monotonic()
+                r.trace.span(st.span, r.dequeued_at, st.t1, group=n)
+        with host_stage("raft.batch.pad", self._stage_done) as st:
+            im1 = np.concatenate([r.image1 for r in group]
+                                 + [group[-1].image1] * (padded - n))
+            im2 = np.concatenate([r.image2 for r in group]
+                                 + [group[-1].image2] * (padded - n))
         for r in traced:
-            r.trace.span("pad", t_form1, t_pad1, padded=padded)
+            r.trace.span(st.span, st.t0, st.t1, padded=padded)
         out, err, attempts = None, None, 0
         t_exec0 = time.monotonic()
-        if traced:
-            tlm_spans.set_device_slot([])
         while attempts <= self.retries and budget[0] > 0:
             attempts += 1
             budget[0] -= 1
+            self._device_call(n, padded)
             try:
                 if self.ragged:
                     # per-row live sizes from each request's routed
@@ -505,7 +536,7 @@ class MicroBatcher:
                 # it here would eat Ctrl-C.  Same type per waiter, but a
                 # FRESH instance each (_fresh_error)
                 t_x = time.monotonic()
-                tlm_spans.take_device_slot()
+                self._take_device_stages()
                 sid = tlm_spans.new_span_id()
                 for r in group:
                     if r.trace is not None:
@@ -519,7 +550,7 @@ class MicroBatcher:
                 self.breaker.record(True)
             err = None
             break
-        calls = tlm_spans.take_device_slot() if traced else ()
+        calls = self._take_device_stages()
         t_exec1 = time.monotonic()
         # co-batched requests SHARE one execute span id (the join key
         # across their traces); each trace holds its own copy with its
@@ -530,11 +561,7 @@ class MicroBatcher:
             tr.span("execute", t_exec0, t_exec1, status=status,
                     span_id=exec_sid, batch_real=n, batch_padded=padded,
                     attempts=attempts)
-            for kind, c0, c1, c2 in calls or ():
-                tr.span("execute_dispatch", c0, c1, parent=exec_sid,
-                        call=kind)
-                tr.span("execute_block", c1, c2, parent=exec_sid,
-                        call=kind)
+            self._device_spans(tr, calls, exec_sid)
 
         if out is None and err is None:
             # budget ran dry before this sub-group got a single attempt
@@ -575,6 +602,23 @@ class MicroBatcher:
             self._run_group(group[:mid], budget, formed=True)
             self._run_group(group[mid:], budget, formed=True)
             return
+        with host_stage("raft.batch.deliver", self._stage_done) as st:
+            # the padded batch is dead since the engine returned: dropped
+            # HERE, under a stage, and not when this frame dies between two
+            # of them — unmapping a third of a GB is not free
+            del im1, im2
+            served = self._deliver(group, out, padded, t_exec1, st.span,
+                                   _exec_span)
+            if served:
+                self._observe("pairs", float(served))
+
+    def _deliver(self, group, out, padded: int, t_exec1: float,
+                 span: str, exec_span) -> int:
+        """Sentinel, unpad and resolve the rows of a finished device batch,
+        one after another; returns how many were served.  A request's
+        ``deliver`` span runs from the end of ``execute`` to its OWN resolve:
+        the rows before it are part of what it waited for."""
+        n = len(group)
         # converge-policy engines return (flows, per-row iters_used); only
         # REAL rows are accounted — padding rows repeat the last request
         # and would skew the raft_iters_used distribution
@@ -587,25 +631,30 @@ class MicroBatcher:
         # edge, so a NaN/Inf row here is the engine's failure — fail that
         # row alone, its neighbors are fine (per-sample independence)
         row_ok = np.isfinite(flows[:n].reshape(n, -1)).all(axis=1)
-        now = time.monotonic()
         served = 0
         for i, r in enumerate(group):
             r.batch_real, r.batch_padded = n, padded
             if iters_used is not None:
                 r.iters_used = int(iters_used[i])
                 self._observe("iters_used", float(iters_used[i]))
-            self._observe("queue_latency", r.dequeued_at - r.enqueued_at)
-            self._observe("request_latency", now - r.enqueued_at)
             if row_ok[i]:
+                flow = unpad(flows[i:i + 1], r.pads)[0]
+                now = time.monotonic()
+                self._observe("request_latency", now - r.enqueued_at)
                 if r.trace is not None:
-                    _exec_span(r.trace, tlm_spans.OK)
+                    # spans BEFORE resolve: the handler wakes on it and
+                    # finishes the trace — a late span would hit it closed
+                    exec_span(r.trace, tlm_spans.OK)
+                    r.trace.span(span, t_exec1, now, row=i)
                 self._observe("requests", "ok", 1)
                 self.served += 1
                 served += 1
-                r.resolve(unpad(flows[i:i + 1], r.pads)[0])
+                r.resolve(flow)
             else:
+                self._observe("request_latency",
+                              time.monotonic() - r.enqueued_at)
                 if r.trace is not None:
-                    _exec_span(r.trace, tlm_spans.POISONED)
+                    exec_span(r.trace, tlm_spans.POISONED)
                 self._observe("nonfinite")
                 self._observe("requests", "poisoned", 1)
                 log = tlm_events.current()
@@ -618,31 +667,35 @@ class MicroBatcher:
                 r.fail(NonFiniteOutput(
                     f"non-finite flow output for request {r.id} "
                     f"(poisoned row in an otherwise-healthy batch)"))
-        if served:
-            self._observe("pairs", float(served))
+        return served
 
     # -- the loop + its crash surface --------------------------------------
 
     def _loop(self) -> None:
         while True:
-            batch, expired = self.queue.take_batch(self.max_batch,
-                                                   self.max_wait)
-            self._fail_expired(expired)
+            set_batch(self.device_batches + 1)    # the batch being waited for
+            with host_stage("raft.batch.take", self._stage_done):
+                batch, expired = self.queue.take_batch(self.max_batch,
+                                                       self.max_wait)
+            with host_stage("raft.batch.form", self._stage_done):
+                self._fail_expired(expired)
+                if batch:
+                    self.batches += 1
+                    # cleared only on the success path: an exception
+                    # escaping here must leave the batch visible to
+                    # _thread_main's crash handler (it fails whatever is
+                    # not yet done)
+                    self._inflight_batch = batch
+                    # ambient trace ids for this batch: out-of-band
+                    # diagnostics fired from under here (fault_injected,
+                    # lock_violation, the non-finite sentinel) become
+                    # joinable to the request traces they hit
+                    tlm_spans.set_current_trace_ids(tuple(
+                        r.trace.trace_id for r in batch
+                        if r.trace is not None))
             if batch is None:        # queue closed and empty: drained
                 return
             if batch:
-                self.batches += 1
-                # cleared only on the success path: an exception escaping
-                # here must leave the batch visible to _thread_main's
-                # crash handler (it fails whatever is not yet done)
-                self._inflight_batch = batch
-                # ambient trace ids for this batch: out-of-band
-                # diagnostics fired from under here (fault_injected,
-                # lock_violation, the non-finite sentinel) become
-                # joinable to the request traces they hit
-                tlm_spans.set_current_trace_ids(tuple(
-                    r.trace.trace_id for r in batch
-                    if r.trace is not None))
                 try:
                     if self.faults is not None:
                         self.faults.maybe_kill()   # chaos: thread-death arm

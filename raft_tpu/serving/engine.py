@@ -17,6 +17,7 @@ count by ServeConfig construction.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, Optional, Tuple
 
@@ -27,6 +28,7 @@ from ..lint.budget import enumerate_warmup_grid
 from ..lint.concurrency import guarded_by
 from ..telemetry import spans as tlm_spans
 from ..telemetry.log import get_logger
+from ..telemetry.trace import host_stage
 from ..telemetry.watchdogs import watched_lock
 from .config import ServeConfig
 
@@ -366,6 +368,10 @@ class InferenceEngine:
                 ex = self._compile(key)
                 if self.cache is not None:
                     self.cache.save(key, ex)
+            if self.cache is not None:
+                # instruction -> stage() map beside the entry (a no-op once
+                # it is there): what lets a trace reader name device time
+                self.cache.save_stages(key, ex)
             with self._lock:
                 self._exec.setdefault(key, ex)
             n += 1
@@ -391,6 +397,8 @@ class InferenceEngine:
         with self._lock:
             items = list(self._exec.items())
         exported = sum(1 for key, ex in items if self.cache.save(key, ex))
+        for key, ex in items:
+            self.cache.save_stages(key, ex)
         grid = enumerate_warmup_grid(self.config, self.sconfig,
                                      stream=self.stream,
                                      chaos=self.faults is not None)
@@ -509,6 +517,41 @@ class InferenceEngine:
 
     # -- the device call --------------------------------------------------
 
+    def _call(self, kind: str, ex, args: tuple, put: tuple = (),
+              wait: bool = True):
+        """One call of a warm executable under its host stages, timed at the
+        only place that can tell them apart (the executable call returns as
+        soon as the work is enqueued — wall clock at the call site lies):
+        ``h2d`` places the host arrays ``args[i], i in put`` on the device,
+        the runtime's host relayout included, and waits for them (what the
+        call would transfer implicitly before the device can start);
+        ``dispatch`` is then the enqueue alone; ``wait`` blocks until the
+        outputs are ready — the device's run as the host sees it.  Each
+        stage is a ``raft.engine.*`` profiler annotation and, inside a
+        batch, a child span of ``execute`` plus stage seconds."""
+        import jax
+        sink = functools.partial(tlm_spans.record_device_stage, kind)
+        if put:
+            with host_stage("raft.engine.h2d", sink, call=kind):
+                shardings = ex.input_shardings[0]
+                placed = jax.block_until_ready(jax.device_put(
+                    [args[i] for i in put], [shardings[i] for i in put]))
+            args = list(args)
+            for i, a in zip(put, placed):
+                args[i] = a
+        with host_stage("raft.engine.dispatch", sink, call=kind):
+            out = ex(*args)
+        if wait:
+            with host_stage("raft.engine.wait", sink, call=kind):
+                jax.block_until_ready(out)
+        return out
+
+    def _fetch(self, kind: str, *arrays) -> tuple:
+        """Ready device arrays -> host numpy (``raft.engine.fetch``)."""
+        sink = functools.partial(tlm_spans.record_device_stage, kind)
+        with host_stage("raft.engine.fetch", sink, call=kind):
+            return tuple(np.asarray(a) for a in arrays)
+
     def _sizes_arg(self, n: int, sizes) -> np.ndarray:
         """Per-row [n, 2] int32 live-size metadata for a ragged device
         call.  None = every row live on the full max box (direct engine
@@ -534,29 +577,17 @@ class InferenceEngine:
             self.pair_calls += 1
         if self.faults is not None:
             self.faults.pre_engine_call()
-        # dispatch vs block-until-ready, timed at the only place that can
-        # tell them apart: the executable call returns as soon as the work
-        # is enqueued (async dispatch — wall clock at the call site lies),
-        # np.asarray is what actually waits for the device
-        t0 = time.monotonic()
+        args = (self.params, im1, im2)
         if self.ragged:
-            out = ex(self.params, im1, im2, self._sizes_arg(n, sizes))
-        else:
-            out = ex(self.params, im1, im2)
-        t1 = time.monotonic()
+            args += (self._sizes_arg(n, sizes),)
+        out = self._call("pair", ex, args, put=(1, 2))
         if self.adaptive:
-            flow, iters_used = out
-            flow = np.asarray(flow)
-            iters_used = np.asarray(iters_used)
-            tlm_spans.record_device_call("pair", t0, t1, time.monotonic())
-            if self.faults is not None:
-                flow = self.faults.corrupt_rows(flow)
-            return flow, iters_used
-        flow = np.asarray(out)
-        tlm_spans.record_device_call("pair", t0, t1, time.monotonic())
+            flow, iters_used = self._fetch("pair", *out)
+        else:
+            (flow,), iters_used = self._fetch("pair", out), None
         if self.faults is not None:
             flow = self.faults.corrupt_rows(flow)
-        return flow
+        return flow if iters_used is None else (flow, iters_used)
 
     def run_encode(self, bucket: Tuple[int, int], image: np.ndarray):
         """[1, BH, BW, 3] float32 frame -> DEVICE-resident (fmap, cnet)
@@ -569,13 +600,9 @@ class InferenceEngine:
             self.encode_calls += 1
         if self.faults is not None:
             self.faults.pre_engine_call()
-        t0 = time.monotonic()
-        out = ex(self.params, image)
-        t1 = time.monotonic()
         # outputs stay device-resident (they are the session cache), so
         # there is no block-until-ready here — dispatch only
-        tlm_spans.record_device_call("encode", t0, t1, t1)
-        return out
+        return self._call("encode", ex, (self.params, image), wait=False)
 
     def run_stream(self, bucket: Tuple[int, int], image: np.ndarray,
                    fmap_prev, cnet_prev, flow_init: np.ndarray,
@@ -592,25 +619,16 @@ class InferenceEngine:
             self.stream_calls += 1
         if self.faults is not None:
             self.faults.pre_engine_call()
-        t0 = time.monotonic()
+        args = (self.params, image, fmap_prev, cnet_prev, flow_init)
         if self.ragged:
-            out = ex(self.params, image, fmap_prev, cnet_prev, flow_init,
-                     self._sizes_arg(n, sizes))
-        else:
-            out = ex(self.params, image, fmap_prev, cnet_prev, flow_init)
-        t1 = time.monotonic()
-        if self.adaptive:
-            flow, flow_lr, fmap, cnet, iters_used = out
-            iters_used = np.asarray(iters_used)
-        else:
-            flow, flow_lr, fmap, cnet = out
-            iters_used = None
-        flow = np.asarray(flow)
-        flow_lr = np.asarray(flow_lr)
-        tlm_spans.record_device_call("stream", t0, t1, time.monotonic())
+            args += (self._sizes_arg(n, sizes),)
+        out = self._call("stream", ex, args)
+        flow, flow_lr, fmap, cnet = out[:4]
+        flow, flow_lr, *iters = self._fetch("stream", flow, flow_lr,
+                                            *out[4:])
         if self.faults is not None:
             flow = self.faults.corrupt_rows(flow)
-        return flow, flow_lr, fmap, cnet, iters_used
+        return flow, flow_lr, fmap, cnet, iters[0] if iters else None
 
     # -- the continuous-batched stream path (slot pool) --------------------
 
@@ -635,24 +653,15 @@ class InferenceEngine:
         if self.faults is not None:
             self.faults.pre_engine_call()
         fbuf, cbuf, flbuf = self.pool.buffers(bucket)
-        t0 = time.monotonic()
+        args = (self.params, images, fbuf, cbuf, flbuf,
+                np.asarray(slots, np.int32), np.asarray(active, bool))
         if self.ragged:
-            out = ex(self.params, images, fbuf, cbuf, flbuf,
-                     np.asarray(slots, np.int32), np.asarray(active, bool),
-                     self._sizes_arg(b, sizes))
-        else:
-            out = ex(self.params, images, fbuf, cbuf, flbuf,
-                     np.asarray(slots, np.int32), np.asarray(active, bool))
-        t1 = time.monotonic()
-        if self.adaptive:
-            flow, flow_lr, fmap_rows, cnet_rows, iters_used = out
-            iters_used = np.asarray(iters_used)
-        else:
-            flow, flow_lr, fmap_rows, cnet_rows = out
-            iters_used = None
-        flow = np.asarray(flow)
-        flow_lr = np.asarray(flow_lr)
-        tlm_spans.record_device_call("stream", t0, t1, time.monotonic())
+            args += (self._sizes_arg(b, sizes),)
+        out = self._call("stream", ex, args)
+        flow, flow_lr, fmap_rows, cnet_rows = out[:4]
+        flow, flow_lr, *iters = self._fetch("stream", flow, flow_lr,
+                                            *out[4:])
+        iters_used = iters[0] if iters else None
         if self.faults is not None:
             # chaos must poison a REAL row: padding rows (the suffix, by
             # the coordinator's construction) are discarded before the
@@ -680,18 +689,17 @@ class InferenceEngine:
         self._ensure_slot_buffers(bucket)
         ex = self._get_executable(self._key(h, w, b, "scommit"))
         fbuf, cbuf, flbuf = self.pool.buffers(bucket)
-        t0 = time.monotonic()
         try:
-            out = ex(fbuf, cbuf, flbuf, np.asarray(slots, np.int32),
-                     fmap_rows, cnet_rows, np.asarray(seeds, np.float32),
-                     np.asarray(mask, bool))
+            # commit is dispatch-only: the rows stay device-resident
+            out = self._call(
+                "commit", ex,
+                (fbuf, cbuf, flbuf, np.asarray(slots, np.int32), fmap_rows,
+                 cnet_rows, np.asarray(seeds, np.float32),
+                 np.asarray(mask, bool)), wait=False)
         except Exception:
             self.reset_slots(bucket)
             raise
         self.pool.install(bucket, out)
-        # commit is dispatch-only: the rows stay device-resident
-        tlm_spans.record_device_call("commit", t0, time.monotonic(),
-                                     time.monotonic())
 
     def commit_row(self, bucket: Tuple[int, int], slot: int, fmap, cnet,
                    seed: np.ndarray) -> None:

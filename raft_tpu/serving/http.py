@@ -33,9 +33,12 @@ Request tracing (OBSERVABILITY.md): every traced request carries a
 request header — returned in the response (``meta.trace_id`` + the
 ``X-Raft-Trace-Id`` header) along with the server-side latency breakdown:
 ``meta.timings`` / the ``X-Raft-Timings`` header, per-span milliseconds
-(admit, queue_wait, batch_form, pad, execute, execute_dispatch,
-execute_block).  Error responses carry the trace id too when the request
-got far enough to mint one.
+(decode, admit, queue_wait, batch_form, pad, execute with its execute_h2d /
+execute_dispatch / execute_block / execute_fetch children, deliver, the
+wake-up half of respond, encode: everything up to the socket write).  A
+/v1/flow trace is minted before the body is read, so decode is in it.
+Error responses carry the trace id too when the request got far enough to
+mint one.
 
 ``/v1/flow`` accepts two encodings:
 
@@ -83,6 +86,7 @@ import numpy as np
 
 from ..telemetry import spans as tlm_spans
 from ..telemetry.log import get_logger
+from ..telemetry.trace import host_stage
 from .queue import RejectedError
 
 _log = get_logger("serve")
@@ -207,25 +211,29 @@ def parse_stream_request(body: bytes, content_type: str):
 
 
 @contextlib.contextmanager
-def _traced_send(tr, t_resp0: float):
+def _traced_send(app, tr):
     """One definition of stamping a trace onto a 200 response (both
     endpoints, both encodings): yields ``(headers, timings)`` — the
     X-Raft-* response headers and the per-span milliseconds for
-    ``meta.timings`` (both None untraced) — and on exit, even if the
-    client disconnected mid-write, records the respond span from
-    ``t_resp0`` and finishes the trace so it cannot leak open."""
+    ``meta.timings`` (both None untraced) — with the body of the ``with``
+    as the ``raft.http.respond`` stage, and on exit, even if the client
+    disconnected mid-write, records that stage (the write half of the
+    respond span) and finishes the trace so it cannot leak open."""
     headers = timings = None
     if tr is not None:
-        # timings snapshot BEFORE the respond span lands: the span is
-        # still being written while the body goes out
+        # timings snapshot BEFORE the write lands: it is still being
+        # written while the body goes out
         timings = tr.timings_ms()
         headers = {"X-Raft-Trace-Id": tr.trace_id,
                    "X-Raft-Timings": json.dumps(timings)}
+    st = None
     try:
-        yield headers, timings
+        with host_stage("raft.http.respond") as st:
+            yield headers, timings
     finally:
+        if st is not None:
+            app.stage_done(st, tr, part="write")
         if tr is not None:
-            tr.span("respond", t_resp0, time.monotonic())
             tr.finish()
 
 
@@ -422,23 +430,34 @@ class _Handler(BaseHTTPRequestHandler):
         if path != "/v1/flow":
             self._send_json(404, {"error": f"no handler for {path}"})
             return
-        body = self._read_body()
-        if body is None:
+        # the trace is minted BEFORE the body is read: decode is a span
+        tr = app.tracer.start("pair", self.headers.get("X-Raft-Trace-Id"))
+        bad = None
+        with host_stage("raft.http.decode") as st:
+            body = self._read_body()
+            if body is not None:
+                try:
+                    im1, im2, deadline_ms = parse_flow_request(
+                        body, self.headers.get("Content-Type",
+                                               "application/json"))
+                    if im1.shape != im2.shape:
+                        raise BadRequest(
+                            f"image shapes differ: {list(im1.shape)} "
+                            f"vs {list(im2.shape)}")
+                except BadRequest as e:
+                    bad = e
+        app.stage_done(st, tr)
+        if body is None or bad is not None:
+            if tr is not None:
+                tr.finish(tlm_spans.BAD_REQUEST)
+            if bad is not None:
+                app.count_request("bad_request")
+                bad.trace_id = tr.trace_id if tr is not None else None
+                self._send_error(400, str(bad), bad)
             return
         try:
-            im1, im2, deadline_ms = parse_flow_request(
-                body, self.headers.get("Content-Type", "application/json"))
-            if im1.shape != im2.shape:
-                raise BadRequest(f"image shapes differ: {list(im1.shape)} "
-                                 f"vs {list(im2.shape)}")
-        except BadRequest as e:
-            app.count_request("bad_request")
-            self._send_json(400, {"error": str(e)})
-            return
-        try:
-            req = app.infer(im1, im2, deadline_ms,
-                            trace_id=self.headers.get("X-Raft-Trace-Id"),
-                            finish_trace=False)
+            req = app.infer(im1, im2, deadline_ms, finish_trace=False,
+                            trace=tr)
         except RejectedError as e:
             # rejected/timeout accounting happens where the decision is
             # made (submit / batcher purge / wait timeout / breaker);
@@ -461,25 +480,32 @@ class _Handler(BaseHTTPRequestHandler):
         }
         if req.iters_used is not None:     # converge policy: compute spent
             meta["iters_used"] = req.iters_used
-        tr = req.trace
-        # the respond span starts when the batcher resolved the request:
-        # event-wake + marshal + socket write are all response delivery
-        t_resp0 = req.finished_at or time.monotonic()
-        with _traced_send(tr, t_resp0) as (headers, timings):
-            if timings is not None:
-                # meta.timings (SERVING.md); npz clients read the header
-                meta["trace_id"] = tr.trace_id
-                meta["timings"] = timings
-            if "application/octet-stream" in (self.headers.get("Accept")
-                                              or ""):
+        # encode BEFORE the timings snapshot, so it reaches the header
+        npz = "application/octet-stream" in (self.headers.get("Accept")
+                                             or "")
+        with host_stage("raft.http.encode") as st:
+            if npz:
                 buf = io.BytesIO()
                 np.savez(buf, flow=req.result,
                          bucket=np.asarray(req.bucket, np.int32))
-                self._send(200, buf.getvalue(), "application/octet-stream",
+                payload = buf.getvalue()
+            else:
+                payload = json.dumps(req.result.tolist())
+        app.stage_done(st, tr)
+        with _traced_send(app, tr) as (headers, timings):
+            if npz:
+                self._send(200, payload, "application/octet-stream",
                            headers=headers)
             else:
-                self._send_json(200, {"flow": req.result.tolist(),
-                                      "meta": meta}, headers=headers)
+                if timings is not None:
+                    # meta.timings (SERVING.md); npz clients read the header
+                    meta["trace_id"] = tr.trace_id
+                    meta["timings"] = timings
+                # the flow was serialised in the encode stage; meta, which
+                # holds that stage's own time, is joined on here
+                self._send(200, ('{"flow": %s, "meta": %s}' % (
+                    payload, json.dumps(meta))).encode(),
+                    "application/json", headers=headers)
 
     def _post_admin_reload(self):
         """Weight hot-swap: npz body -> engine.reload (stage + probe +
@@ -595,9 +621,11 @@ class _Handler(BaseHTTPRequestHandler):
             self._send_error(500, f"inference failed: {e}", e)
             return
         tr = res.pop("_trace", None)
-        t_resp0 = res.pop("_finished_at", None) or time.monotonic()
+        t_resp0 = res.pop("_finished_at", None)
         flow = res.pop("flow", None)
-        with _traced_send(tr, t_resp0) as (headers, timings):
+        if tr is not None and t_resp0 is not None:
+            tr.span("respond", t_resp0, time.monotonic(), part="wake")
+        with _traced_send(app, tr) as (headers, timings):
             if timings is not None:
                 meta = res.get("meta")
                 if meta is not None:

@@ -44,9 +44,6 @@ def make_serving_metrics(registry: Registry, config,
             "raft_serving_queue_depth",
             "Requests currently waiting in the admission queue",
             fn=queue_depth_fn),
-        "inflight": registry.gauge(
-            "raft_serving_inflight_batches",
-            "Device batches currently executing"),
         "batch_size": registry.histogram(
             "raft_serving_batch_size",
             "Real (unpadded) requests per device batch",
@@ -65,15 +62,26 @@ def make_serving_metrics(registry: Registry, config,
         "request_latency": registry.histogram(
             "raft_serving_request_latency_seconds",
             "End-to-end request latency (enqueue to result)"),
-        "queue_latency": registry.histogram(
-            "raft_serving_queue_latency_seconds",
-            "Time spent waiting for a batch slot"),
-        "batch_latency": registry.histogram(
-            "raft_serving_batch_latency_seconds",
-            "Device execution time per batch"),
-        "compile_hits": registry.counter(
-            "raft_serving_compile_cache_hits_total",
-            "Device calls served by a warm executable"),
+        # host stages (telemetry/trace.host_stage): every site that times a
+        # stage for a request span also counts its seconds here, sampled or
+        # not, so a window's host time per device batch needs no profiler
+        "stage_seconds": registry.counter(
+            "raft_serving_stage_seconds_total",
+            "Host seconds by stage of the serving path (the raft.* "
+            "profiler annotation less its prefix: http.decode, http.admit, "
+            "batch.take, batch.form, batch.pad, engine.h2d, "
+            "engine.dispatch, engine.wait, engine.fetch, batch.deliver, "
+            "http.encode, http.respond)",
+            labelnames=("stage",)),
+        "device_calls": registry.counter(
+            "raft_serving_device_calls_total",
+            "Device calls issued by the batcher (pairwise batches, retries "
+            "and bisection halves, stream steps)"),
+        "device_rows": registry.counter(
+            "raft_serving_device_rows_total",
+            "Rows carried by those device calls: kind=real are requests, "
+            "kind=padded the batch step they were padded up to",
+            labelnames=("kind",)),
         "compile_misses": registry.counter(
             "raft_serving_compile_cache_misses_total",
             "Device calls that had to compile (0 after warmup = the "
@@ -252,9 +260,7 @@ def make_engine_cache_metrics(registry: Registry) -> Dict[str, _Metric]:
     """AOT executable-cache families (serving/aot_cache.py) — registered
     only when --engine-cache-dir attaches a cache, so a cacheless server's
     /metrics exposition is untouched.  The counters are bulk-filled from
-    the cache's warmup stats after start() and incremented on later
-    export/prestage activity; the histogram prices deserialize time (the
-    thing that replaced a multi-second XLA compile)."""
+    the cache's warmup stats after start()."""
     return {
         "hits": registry.counter(
             "raft_engine_cache_hits_total",
@@ -264,14 +270,6 @@ def make_engine_cache_metrics(registry: Registry) -> Dict[str, _Metric]:
             "raft_engine_cache_misses_total",
             "Warmup keys that fell back to compiling (absent, corrupt, "
             "or stale cache directory)"),
-        "loads": registry.counter(
-            "raft_engine_cache_loads_total",
-            "Serialized-executable deserialize attempts"),
-        "load_seconds": registry.histogram(
-            "raft_engine_cache_load_seconds",
-            "Deserialize time per cached executable (the cold-start cost "
-            "that replaced an XLA compile)",
-            buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0)),
     }
 
 

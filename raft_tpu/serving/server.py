@@ -31,7 +31,7 @@ from ..telemetry import events as tlm_events
 from ..telemetry import spans as tlm_spans
 from ..telemetry import watchdogs as tlm_watchdogs
 from ..telemetry.log import get_logger
-from ..telemetry.trace import TraceWindow, stage
+from ..telemetry.trace import HOST_STAGES, TraceWindow, host_stage, stage
 from .batcher import MicroBatcher
 from .breaker import BreakerOpen, CircuitBreaker
 from .config import ServeConfig
@@ -134,6 +134,12 @@ class FlowServer:
         self.queue = RequestQueue(sconfig.queue_depth)
         self.metrics = make_serving_metrics(
             self.registry, sconfig, queue_depth_fn=lambda: len(self.queue))
+        # one labelled child per host stage, made up front: every stage
+        # shows in /metrics from the start, and no stage's increment takes
+        # the family's lock (the batcher must never queue behind handlers)
+        self._stage_children = {
+            name[len("raft."):]: self.metrics["stage_seconds"].labels(
+                name[len("raft."):]) for name in HOST_STAGES}
         self.registry.gauge("raft_serving_queue_limit",
                             "Admission queue capacity (backpressure bound)"
                             ).set(sconfig.queue_depth)
@@ -280,59 +286,39 @@ class FlowServer:
         self._gauges_wired = False
 
     # -- engine bridge (compile-cache accounting lives server-side so a
-    #    stub engine still produces hit/miss metrics when it exposes them) -
+    #    stub engine still produces miss metrics when it exposes them) -----
+
+    def _device_step(self, scope: str, fn, *args):
+        """One device step of the batcher, whatever its kind: the trace
+        window's tick, the named scope (so a compile is attributable), and
+        the serve-time compile misses it caused."""
+        self._trace_window.on_step(self._device_batches)
+        self._device_batches += 1
+        before = getattr(self.engine, "compile_misses", None)
+        with stage(scope):
+            out = fn(*args)
+        if before is not None and self.engine.compile_misses > before:
+            self.metrics["compile_misses"].inc(
+                self.engine.compile_misses - before)
+        return out
 
     def _run_engine(self, bucket, im1, im2, sizes=None):
-        self._trace_window.on_step(self._device_batches)
-        self._device_batches += 1
-        before = getattr(self.engine, "compile_misses", None)
-        with stage("serve/batch"):
-            # sizes (ragged per-row extents) only flows when the batcher
-            # passes it, so dense-mode stub engines keep their 3-arg run()
-            if sizes is not None:
-                out = self.engine.run(bucket, im1, im2, sizes)
-            else:
-                out = self.engine.run(bucket, im1, im2)
-        if before is not None:
-            after = self.engine.compile_misses
-            if after > before:
-                self.metrics["compile_misses"].inc(after - before)
-            else:
-                self.metrics["compile_hits"].inc()
-        return out
+        # sizes (ragged per-row extents) only flows when the batcher passes
+        # it, so dense-mode stub engines keep their 3-arg run()
+        args = (bucket, im1, im2) if sizes is None else (bucket, im1, im2,
+                                                         sizes)
+        return self._device_step("serve/batch", self.engine.run, *args)
 
     def _run_stream(self, req):
-        """Stream-step twin of _run_engine: same trace window, same
-        compile-cache accounting, one session step per call."""
-        self._trace_window.on_step(self._device_batches)
-        self._device_batches += 1
-        before = getattr(self.engine, "compile_misses", None)
-        with stage("serve/stream"):
-            out = self.streams.execute(req, self.engine)
-        if before is not None:
-            after = self.engine.compile_misses
-            if after > before:
-                self.metrics["compile_misses"].inc(after - before)
-            else:
-                self.metrics["compile_hits"].inc()
-        return out
+        """One solo session step (open, or the no-group fallback)."""
+        return self._device_step("serve/stream", self.streams.execute, req,
+                                 self.engine)
 
     def _run_stream_group(self, group):
         """Continuous-batched stream step (coalesced same-bucket
-        advances): one device batch, same trace window and compile-cache
-        accounting as the pairwise path."""
-        self._trace_window.on_step(self._device_batches)
-        self._device_batches += 1
-        before = getattr(self.engine, "compile_misses", None)
-        with stage("serve/stream"):
-            out = self.streams.execute_group(group, self.engine)
-        if before is not None:
-            after = self.engine.compile_misses
-            if after > before:
-                self.metrics["compile_misses"].inc(after - before)
-            else:
-                self.metrics["compile_hits"].inc()
-        return out
+        advances): one device batch."""
+        return self._device_step("serve/stream", self.streams.execute_group,
+                                 group, self.engine)
 
     def engine_executables(self) -> int:
         return getattr(self.engine, "executables", 0)
@@ -359,7 +345,10 @@ class FlowServer:
         ``capture_profile`` holds a process-wide lock; a concurrent
         request gets CaptureBusy → 409) and side-effect-free on the
         engine: profiling must never perturb the warm compile grid, which
-        serve_bench asserts by diffing compile misses across a capture."""
+        serve_bench asserts by diffing compile misses across a capture.
+        Captured with ``trace.profile_options()`` (Python tracer off): the
+        XPlane holds the device's operations, the runtime's host events and
+        this server's ``raft.*`` host-stage annotations on one clock."""
         from ..telemetry.trace import capture_profile
         info = capture_profile(self.profile_dir, ms, log_fn=_log.info)
         run_log = tlm_events.current()
@@ -489,12 +478,10 @@ class FlowServer:
             from .metrics import make_engine_cache_metrics
             fam = make_engine_cache_metrics(self.registry)
             st = self.engine_cache.stats
-            for name in ("hits", "misses", "loads"):
+            for name in ("hits", "misses"):
                 count = getattr(st, name)
                 if count:
                     fam[name].inc(count)
-            for sec in st.load_seconds:
-                fam["load_seconds"].observe(sec)
         if self._recompile_watch is not None:
             self._recompile_watch.arm()
         if self.history is not None:
@@ -552,62 +539,39 @@ class FlowServer:
 
     # -- request path ------------------------------------------------------
 
+    def stage_done(self, st, trace=None, **attrs) -> None:
+        """Sink of the handler threads' host stages (trace.host_stage): the
+        stage's seconds, and its span on ``trace`` when the request has
+        one."""
+        self._stage_children[st.label].inc(st.t1 - st.t0)
+        if trace is not None:
+            trace.span(st.span, st.t0, st.t1, **attrs)
+
     def infer(self, im1: np.ndarray, im2: np.ndarray,
               deadline_ms: Optional[float] = None,
               trace_id: Optional[str] = None,
-              finish_trace: bool = True) -> Request:
+              finish_trace: bool = True, trace=None) -> Request:
         """Route, pad, enqueue, block until resolved.  Called from HTTP
         handler threads (and directly by tests/the in-process bench).
 
-        Trace lifecycle: a trace is minted here (or adopts the client's
-        ``trace_id``) and CLOSES here on every failure path, with the
-        status the exception maps to — shed, timeout, poisoned, error —
-        and the exception carries ``.trace_id`` out to the HTTP layer.
-        On success the HTTP handler finishes it after the respond span
-        (``finish_trace=False``); direct callers let this method close it.
+        Trace lifecycle: the HTTP handler mints the trace before it reads
+        the body and hands it in (``trace``); for a direct caller it is
+        minted here (or adopts the client's ``trace_id``).  It CLOSES here
+        on every failure path, with the status the exception maps to —
+        shed, timeout, poisoned, error — and the exception carries
+        ``.trace_id`` out to the HTTP layer.  On success the HTTP handler
+        finishes it after the respond span (``finish_trace=False``); direct
+        callers let this method close it.
         """
-        tr = self.tracer.start("pair", trace_id)
-        t0 = time.monotonic()
+        tr = trace if trace is not None else self.tracer.start("pair",
+                                                               trace_id)
         try:
-            if self.draining:
-                self.count_request("draining")
-                raise Draining("server is draining; not accepting requests")
-            self._admit()                 # breaker gate: shed 503 while open
-            h, w = im1.shape[0], im1.shape[1]
-            bucket = self.sconfig.route(h, w)
-            if bucket is None:
-                raise BadRequest(
-                    f"no declared bucket fits ({h}, {w}); buckets: "
-                    f"{[f'{bh}x{bw}' for bh, bw in self.sconfig.buckets]}")
-            dl = self.sconfig.default_deadline_ms if deadline_ms is None \
-                else min(deadline_ms, self.sconfig.default_deadline_ms)
-            if dl <= 0:
-                raise BadRequest(f"deadline_ms must be positive, got {dl}")
-            im1p, pads = pad_to_shape(im1[None].astype(np.float32), bucket)
-            im2p, _ = pad_to_shape(im2[None].astype(np.float32), bucket)
-            rbucket = None
-            if self.sconfig.ragged:
-                # ragged: zero-embed the routed-bucket pair corner-
-                # anchored into the shared max box and queue it UNDER the
-                # max box, so requests of every resolution share one FIFO
-                # (cross-resolution coalescing) and one executable.  The
-                # embedding folds into pads so unpad() recovers (h, w)
-                # straight from the max-box flow; the routed bucket rides
-                # in rbucket — the batcher turns it into the row's sizes.
-                rbucket = bucket
-                (bh, bw), (mh, mw) = bucket, self.sconfig.max_box
-                im1p = embed_to_shape(im1p, self.sconfig.max_box)
-                im2p = embed_to_shape(im2p, self.sconfig.max_box)
-                t, b_, l_, r_ = pads
-                pads = (t, b_ + mh - bh, l_, r_ + mw - bw)
-                bucket = self.sconfig.max_box
-            req = Request(im1p, im2p, bucket, pads,
-                          deadline=time.monotonic() + dl / 1000.0,
-                          rbucket=rbucket)
-            req.trace = tr
-            if tr is not None:
-                tr.span("admit", t0, time.monotonic(),
-                        bucket=f"{bucket[0]}x{bucket[1]}")
+            with host_stage("raft.http.admit") as st:
+                req, dl = self._admit_pair(im1, im2, deadline_ms, tr)
+            self.stage_done(st, tr,
+                            bucket=f"{req.bucket[0]}x{req.bucket[1]}")
+            # the enqueue lies outside the stage: it wakes the batcher, and
+            # whatever that does before this thread runs again is not admit
             try:
                 self.queue.submit(req)
             except Draining:
@@ -627,6 +591,12 @@ class FlowServer:
                     # stalled) — the batcher's purge never saw this one
                     self.count_request("timeout")
                 raise
+            if tr is not None:
+                # resolve -> this thread runs again: the wake-up half of
+                # respond (the handler adds the socket write under the same
+                # name), so the spans tile the request to its end
+                tr.span("respond", req.finished_at, time.monotonic(),
+                        part="wake")
         except BaseException as e:
             if tr is not None:
                 # stamp-if-absent: a group-wide failure can share ONE
@@ -641,6 +611,48 @@ class FlowServer:
         if finish_trace and tr is not None:
             tr.finish()
         return req
+
+    def _admit_pair(self, im1: np.ndarray, im2: np.ndarray,
+                    deadline_ms: Optional[float], tr):
+        """The admit stage of :meth:`infer`: gate, route, cast, pad to the
+        bucket.  Returns the request to enqueue and its deadline (ms)."""
+        if self.draining:
+            self.count_request("draining")
+            raise Draining("server is draining; not accepting requests")
+        self._admit()                 # breaker gate: shed 503 while open
+        h, w = im1.shape[0], im1.shape[1]
+        bucket = self.sconfig.route(h, w)
+        if bucket is None:
+            raise BadRequest(
+                f"no declared bucket fits ({h}, {w}); buckets: "
+                f"{[f'{bh}x{bw}' for bh, bw in self.sconfig.buckets]}")
+        dl = self.sconfig.default_deadline_ms if deadline_ms is None \
+            else min(deadline_ms, self.sconfig.default_deadline_ms)
+        if dl <= 0:
+            raise BadRequest(f"deadline_ms must be positive, got {dl}")
+        im1p, pads = pad_to_shape(im1[None].astype(np.float32), bucket)
+        im2p, _ = pad_to_shape(im2[None].astype(np.float32), bucket)
+        rbucket = None
+        if self.sconfig.ragged:
+            # ragged: zero-embed the routed-bucket pair corner-
+            # anchored into the shared max box and queue it UNDER the
+            # max box, so requests of every resolution share one FIFO
+            # (cross-resolution coalescing) and one executable.  The
+            # embedding folds into pads so unpad() recovers (h, w)
+            # straight from the max-box flow; the routed bucket rides
+            # in rbucket — the batcher turns it into the row's sizes.
+            rbucket = bucket
+            (bh, bw), (mh, mw) = bucket, self.sconfig.max_box
+            im1p = embed_to_shape(im1p, self.sconfig.max_box)
+            im2p = embed_to_shape(im2p, self.sconfig.max_box)
+            t, b_, l_, r_ = pads
+            pads = (t, b_ + mh - bh, l_, r_ + mw - bw)
+            bucket = self.sconfig.max_box
+        req = Request(im1p, im2p, bucket, pads,
+                      deadline=time.monotonic() + dl / 1000.0,
+                      rbucket=rbucket)
+        req.trace = tr
+        return req, dl
 
     def stream_call(self, op: str, session_id, image, deadline_ms,
                     trace_id: Optional[str] = None,

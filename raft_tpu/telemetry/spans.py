@@ -4,11 +4,12 @@
 profiles are readable; this module extends that to **per-request
 attribution**: every request through the serving stack carries a
 ``trace_id`` (minted server-side or accepted from an ``X-Raft-Trace-Id``
-header) and accumulates timed **spans** — ``admit``, ``queue_wait``,
-``batch_form``, ``pad``, ``execute`` (with ``execute_dispatch`` /
-``execute_block`` children: async dispatch means wall-clock at the call
-site lies about device time), ``respond`` — each with parent links and a
-status (``ok`` / ``poisoned`` / ``shed`` / ``degraded`` / ``timeout`` /
+header) and accumulates timed **spans** — ``decode``, ``admit``,
+``queue_wait``, ``batch_form``, ``pad``, ``execute`` (with ``execute_h2d``
+/ ``execute_dispatch`` / ``execute_block`` / ``execute_fetch`` children:
+async dispatch means wall-clock at the call site lies about device time),
+``deliver``, ``encode``, ``respond`` — top-level spans that tile the
+request, each with parent links and a status (``ok`` / ``poisoned`` / ``shed`` / ``degraded`` / ``timeout`` /
 ``error``).  Co-batched requests share ONE ``execute`` span id (the join
 key) with their own queue spans, so a slow p99 is attributable: queue
 wait vs batch formation vs device vs response, per request.
@@ -41,6 +42,7 @@ No jax anywhere: pure stdlib, importable by ``tools/tlm.py``.
 
 from __future__ import annotations
 
+import itertools
 import json
 import re
 import threading
@@ -74,8 +76,19 @@ def new_trace_id() -> str:
     return uuid.uuid4().hex
 
 
+# A span id is this process's random prefix and a counter, not a uuid4 of its
+# own: uuid4 reads os.urandom, a system call made with the GIL RELEASED, and
+# the batcher records a handful of spans per row while the handlers it has
+# just woken all want the GIL — every release there is a wait for a turn.
+# (On the chip, counter ids alone took 0.16 s out of a 2.68 s batch cycle of
+# the benchmark's cell: PERF.md §6, PR 24.)  Unique within the process by
+# the counter, across a fleet's processes by the prefix.
+_span_prefix = uuid.uuid4().hex[:8]
+_span_seq = itertools.count(1)
+
+
 def new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return f"{_span_prefix}{next(_span_seq) & 0xffffffff:08x}"
 
 
 def clean_trace_id(tid: Optional[str]) -> str:
@@ -100,9 +113,10 @@ def status_of(exc: BaseException) -> str:
 # the span objects themselves:
 #
 # * the DEVICE SLOT: the batcher opens a list before an engine call; the
-#   engine appends (kind, t0, t_dispatched, t_blocked) per device call —
-#   dispatch and block-until-ready separated at the only place that can
-#   tell them apart — and the batcher turns them into child spans.
+#   engine's host stages (trace.host_stage: h2d, dispatch, wait, fetch —
+#   timed at the only place that can tell them apart) land in it as
+#   (kind, span name, counter label, t0, t1), and the batcher turns them
+#   into child spans of ``execute`` and stage-seconds increments.
 # * the CURRENT TRACE IDS: the trace ids of the batch being executed, so
 #   out-of-band diagnostics (fault_injected, lock_violation, non-finite
 #   sentinel run-log events) are joinable to their request traces.
@@ -120,13 +134,12 @@ def take_device_slot() -> Optional[list]:
     return slot
 
 
-def record_device_call(kind: str, t0: float, t_dispatched: float,
-                       t_blocked: float) -> None:
-    """Engine-side hook: one device call's dispatch/block timing.  A
-    single thread-local read when tracing is off."""
+def record_device_stage(kind: str, st) -> None:
+    """Engine-side sink of ``trace.host_stage``: one finished stage of a
+    device call of ``kind``.  A single thread-local read outside a batch."""
     slot = getattr(_tls, "device_slot", None)
     if slot is not None:
-        slot.append((kind, t0, t_dispatched, t_blocked))
+        slot.append((kind, st.span, st.label, st.t0, st.t1))
 
 
 def set_current_trace_ids(ids: Tuple[str, ...]) -> None:

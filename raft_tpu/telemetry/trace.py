@@ -15,7 +15,13 @@ construct with a trace dir + window, call ``on_step(i)`` once per step, and
 the jax.profiler trace starts/stops itself; ``stop()`` in a finally block
 covers early exits.
 
-Stages name *code*; :mod:`spans` extends this layer to name *requests* —
+Stages name *device code*; ``host_stage(name)`` names what the *host* does
+between device calls (decode, pad, h2d, fetch, deliver …): one call site
+yields the request span, a ``jax.profiler.TraceAnnotation`` on the device
+trace's clock and a stage-seconds counter.  ``instruction_stages`` turns
+the ``op_name`` metadata of a compiled executable's text into an
+instruction -> ``stage()`` path map, because the chip's trace events carry
+the instruction's name and no scope.  :mod:`spans` names *requests* —
 ID-carrying spans with parent links and status threaded through the
 serving plane (queue wait vs device execute vs respond, per request).
 """
@@ -23,9 +29,10 @@ serving plane (queue wait vs device execute vs respond, per request).
 from __future__ import annotations
 
 import contextlib
+import re
 import threading
 import time
-from typing import Optional
+from typing import Callable, Dict, Optional
 
 _stack = threading.local()
 
@@ -66,6 +73,236 @@ def stage(name: str):
         names.pop()
 
 
+# -- host stages ------------------------------------------------------------
+#
+# annotation name -> request span name (OBSERVABILITY.md "Host stages").
+# The counter label is the annotation name less its ``raft.`` prefix.  A
+# stage with no span (``take``: the batcher waits for requests, which belongs
+# to no request) still writes its annotation and counter.
+HOST_STAGES: Dict[str, Optional[str]] = {
+    "raft.http.decode": "decode",
+    "raft.http.admit": "admit",
+    "raft.batch.take": None,
+    "raft.batch.form": "batch_form",
+    "raft.batch.pad": "pad",
+    "raft.engine.h2d": "execute_h2d",
+    "raft.engine.dispatch": "execute_dispatch",
+    "raft.engine.wait": "execute_block",
+    "raft.engine.fetch": "execute_fetch",
+    "raft.batch.deliver": "deliver",
+    "raft.http.encode": "encode",
+    "raft.http.respond": "respond",
+}
+
+
+def set_batch(ordinal: Optional[int]) -> None:
+    """The device batch this thread is working on (set by the batcher): every
+    ``host_stage`` opened on the thread carries it as ``batch=<n>``."""
+    _stack.batch = ordinal
+
+
+class HostStage:
+    """One timed host stage; ``t0``/``t1`` are ``time.monotonic()`` at entry
+    and exit, ``span`` the request-span name of the stage (or None)."""
+
+    __slots__ = ("name", "span", "t0", "t1")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.span = HOST_STAGES[name]
+        self.t0 = self.t1 = 0.0
+
+    @property
+    def label(self) -> str:
+        return self.name[5:]              # less 'raft.'
+
+
+@contextlib.contextmanager
+def host_stage(name: str, sink: Optional[Callable] = None, **attrs):
+    """Time one host stage of ``HOST_STAGES`` under a profiler annotation.
+
+    Opens ``jax.profiler.TraceAnnotation(name, batch=<n>, **attrs)`` — with
+    no profiler session that is one atomic check — and on exit, also when the
+    body raised, stamps ``t1`` and hands the :class:`HostStage` to ``sink``
+    (the caller's span-and-counter recorder), so one site yields all three
+    records.  Yields the stage for callers that place the span themselves."""
+    st = HostStage(name)
+    batch = getattr(_stack, "batch", None)
+    if batch is not None:
+        attrs.setdefault("batch", batch)
+    try:
+        import jax
+        ann = jax.profiler.TraceAnnotation(name, **attrs)
+    except ImportError:
+        ann = contextlib.nullcontext()
+    with ann:
+        st.t0 = time.monotonic()
+        try:
+            yield st
+        finally:
+            st.t1 = time.monotonic()
+            if sink is not None:
+                sink(st)
+
+
+# -- instruction -> stage map -----------------------------------------------
+
+# op_name segments that say how the code was traced, not which stage() it is in
+_STRUCTURAL = re.compile(
+    r"^(jit|pjit|vmap|jvp|transpose|remat|checkpoint|custom_jvp_call|"
+    r"custom_vjp_call|custom_vjp_call_jaxpr|closed_call|core_call|while|"
+    r"body|cond|body_fun|cond_fun|scan|branch_\d+(_fun)?|shard_map)(\(.*\))?$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OPERAND = re.compile(r"%?([\w.\-]+)$")
+STAGE_MAP_VERSION = 1
+TEXT_HEAD = 120          # characters of an instruction's text kept in the map
+
+
+def stage_path(op_name: str) -> str:
+    """``jit(fn)/while/body/closed_call/raft/corr_lookup/l0/dot_general`` ->
+    ``raft/corr_lookup/l0``: the ``stage()`` scopes around the primitive."""
+    parts = op_name.split("/")[:-1]
+    return "/".join(p for p in parts if not _STRUCTURAL.match(p))
+
+
+def _operands(rest: str) -> list:
+    """Names of an instruction's operands from ``shape opcode(a, b), ...``."""
+    m = re.search(r" [a-z][\w\-]*\(", rest)
+    if not m:
+        return []
+    depth, out, cur = 1, [], []
+    for ch in rest[m.end():]:
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                break
+        if ch == "," and depth == 1:
+            out.append("".join(cur))
+            cur = []
+        else:
+            cur.append(ch)
+    out.append("".join(cur))
+    names = []
+    for tok in out:
+        m = _OPERAND.search(tok.strip())
+        if m:
+            names.append(m.group(1))
+    return names
+
+
+_OPCODE = re.compile(r" ([a-z][\w\-]*)\(")
+_TARGETS = re.compile(
+    r"\b(calls|to_apply|body|condition|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def instruction_stages(hlo_text: str) -> Dict[str, dict]:
+    """``{instruction name: {"stage": path, "loop": depth, "text": head of
+    its line}}`` for every instruction of a compiled module's text that can
+    run as an operation of its own: those of the entry computation, of loop
+    bodies and conditions, of branches and called computations — not those
+    inside fused computations and reducers (a fusion's ``calls=``, a
+    reduce's ``to_apply=``), which the device runs as part of their caller.
+
+    The stage is the ``stage_path`` of the instruction's ``op_name``.  An
+    instruction the compiler made and gave no ``op_name`` takes a stage from
+    what it belongs to: a fusion its root's, anything else (a layout
+    ``convert``, a ``copy``, a ``bitcast``) the stage of its first operand
+    that has one.  ``loop`` is how many ``while`` bodies enclose the
+    instruction: it runs once per program run at 0, once per iteration at
+    1."""
+    comps: Dict[str, list] = {}
+    cur = None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        if cur is None:
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if m:
+            cur.append((bool(m.group(1)), m.group(2), m.group(3)))
+    fused = {}                            # fusion -> its fused computation
+    inner = set()                         # computations run inside a caller
+    runs_in: Dict[str, tuple] = {}        # computation -> (caller's, +depth)
+    for comp, insts in comps.items():
+        for _root, name, rest in insts:
+            op = _OPCODE.search(rest)
+            op = op.group(1) if op else ""
+            for kind, target, branches in _TARGETS.findall(rest):
+                if branches:
+                    for t in branches.split(","):
+                        runs_in[t.strip().lstrip("%")] = (comp, 0)
+                elif op == "while":
+                    runs_in[target] = (comp, 1)
+                elif op in ("call", "conditional"):
+                    runs_in[target] = (comp, 0)
+                else:
+                    inner.add(target)
+                    if kind == "calls":
+                        fused[name] = target
+
+    def depth(comp: str, seen=()) -> int:
+        if comp not in runs_in or comp in seen:
+            return 0
+        parent, add = runs_in[comp]
+        return add + depth(parent, seen + (comp,))
+
+    stages: Dict[str, str] = {}
+
+    def resolve(comp: str) -> None:
+        for _root, name, rest in comps.get(comp, ()):
+            m = _OP_NAME.search(rest)
+            if m:
+                st = stage_path(m.group(1))
+            elif name in fused:
+                target = fused[name]
+                if target in comps and not any(
+                        n in stages for _r, n, _t in comps[target]):
+                    resolve(target)
+                st = next((stages.get(n, "") for r, n, _t
+                           in comps.get(target, ()) if r), "")
+            else:
+                st = next((stages[o] for o in _operands(rest)
+                           if stages.get(o)), "")
+            stages[name] = st
+
+    for comp in comps:
+        if comp not in inner:
+            resolve(comp)
+    return {name: {"stage": stages.get(name, ""), "loop": depth(comp),
+                   "text": f"%{name} = {rest}"[:TEXT_HEAD]}
+            for comp, insts in comps.items() if comp not in inner
+            for _root, name, rest in insts}
+
+
+# -- profiler captures ------------------------------------------------------
+
+def profile_options():
+    """The options of every profiler capture this program starts
+    (``TraceWindow``, ``POST /debug/profile``).  Python tracer OFF: it
+    records every call of every thread, and the program's own stages are in
+    the trace as ``raft.*`` annotations.  Host tracer level 2; level 1 costs
+    the same on the chip (8 s of the served raft-things: 214 MB and 130-139 s
+    of ``stop_trace`` under either, PERF.md PR 24), because what fills a
+    capture is the runtime's own events — four million ``Transpose`` chunk
+    events of the float32 batch's relayout in 5 s — which both levels
+    hold."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    return opts
+
+
 class TraceWindow:
     """Start/stop a jax.profiler trace over a step window.
 
@@ -90,7 +327,8 @@ class TraceWindow:
             return False
         if not self._tracing and self.first <= step < self.last:
             import jax
-            jax.profiler.start_trace(self.trace_dir)
+            jax.profiler.start_trace(self.trace_dir,
+                                     profiler_options=profile_options())
             self._tracing = True
         elif self._tracing and step >= self.last:
             self.stop()
@@ -147,7 +385,7 @@ def capture_profile(trace_dir: Optional[str], duration_ms: float,
                 "%Y%m%dT%H%M%S", time.gmtime(started)))
             os.makedirs(dest, exist_ok=True)
         import jax
-        jax.profiler.start_trace(dest)
+        jax.profiler.start_trace(dest, profiler_options=profile_options())
         try:
             time.sleep(duration_ms / 1000.0)
         finally:

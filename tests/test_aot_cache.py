@@ -91,6 +91,122 @@ def test_cached_warmup_loads_bit_identical_without_compiling(warm_cache):
     np.testing.assert_array_equal(np.asarray(cold), np.asarray(warm))
 
 
+# -- the instruction -> stage() map beside each entry -----------------------
+
+def _stage_maps(cache):
+    return {p.name: json.loads(p.read_text())
+            for p in sorted(cache.dir.glob("*.stages.json"))}
+
+
+def _runnable_instructions(text):
+    """Names of the instructions of an HLO module's text outside fused
+    computations and reducers, found without the program's parser: what is
+    called by ``calls=`` or ``to_apply=`` runs inside its caller."""
+    import re
+    inner = set(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", text))
+    names, comp = set(), None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            comp = head.group(1)
+        elif line.startswith("}"):
+            comp = None
+        elif comp is not None and comp not in inner:
+            inst = re.match(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", line)
+            if inst:
+                names.add(inst.group(1))
+    return names
+
+
+def test_stage_map_covers_every_instruction_of_the_executable(warm_cache):
+    wc = warm_cache
+    maps = _stage_maps(wc.cache)
+    # one map beside each serialized executable, named after it
+    assert sorted(maps) == sorted(
+        key_filename(k)[:-len(".bin")] + ".stages.json"
+        for k in wc.engine.keys())
+    key = wc.engine.keys()[0]
+    doc = maps[key_filename(key)[:-len(".bin")] + ".stages.json"]
+    assert doc["key"] == dict(zip(KEY_FIELDS, key))
+    text = wc.engine._exec[key].as_text()
+    insts = doc["instructions"]
+    assert set(insts) == _runnable_instructions(text)
+    for name, rec in insts.items():
+        assert rec["text"].startswith(f"%{name} = ")
+    # the model's stage() scopes are what the map's stages are made of
+    stages = {rec["stage"] for rec in insts.values()}
+    for scope in ("raft/fnet", "raft/cnet", "raft/corr_lookup",
+                  "raft/update", "raft/upsample"):
+        assert any(st == scope or st.startswith(scope + "/")
+                   for st in stages), (scope, sorted(stages))
+    # per-level scopes exist only where the lookup is the fused kernel;
+    # no stage names how the code was traced
+    assert not any(seg in st.split("/") for st in stages
+                   for seg in ("while", "body", "closed_call"))
+
+
+@pytest.mark.parametrize("case", ["op_name", "fusion_root", "operand",
+                                  "structural_only", "fused_left_out"])
+def test_instruction_stages_rules(case):
+    from raft_tpu.telemetry.trace import instruction_stages, stage_path
+    text = """HloModule jit_fn, is_scheduled=true
+
+%fused_computation.1 (p0: f32[4]) -> f32[4] {
+  %p0 = f32[4]{0} parameter(0)
+  ROOT %mul.1 = f32[4]{0} multiply(%p0, %p0), metadata={op_name="jit(fn)/while/body/raft/corr_lookup/l2/mul"}
+}
+
+%body.2 (arg: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %arg = (s32[], f32[4]{0}) parameter(0)
+  %gte.1 = f32[4]{0} get-tuple-element(%arg), index=1
+  %fusion.1 = f32[4]{0} fusion(%gte.1), kind=kLoop, calls=%fused_computation.1
+  %convert.9 = bf16[4]{0} convert(%fusion.1)
+  %add.3 = f32[4]{0} add(%gte.1, %gte.1), metadata={op_name="jit(fn)/while/body/closed_call/add"}
+  ROOT %tuple.1 = (s32[], f32[4]{0}) tuple(%gte.0, %add.3)
+}
+
+ENTRY %main.3 (x: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0)
+  %tanh.1 = f32[4]{0} tanh(%x), metadata={op_name="jit(fn)/jit(main)/raft/fnet/encoder/stem/tanh" source_file="a.py"}
+  %while.1 = (s32[], f32[4]{0}) while(%tuple.0), condition=%cond.1, body=%body.2, metadata={op_name="jit(fn)/while"}
+  ROOT %gte.2 = f32[4]{0} get-tuple-element(%while.1), index=1
+}
+"""
+    m = instruction_stages(text)
+    if case == "op_name":
+        assert m["tanh.1"]["stage"] == "raft/fnet/encoder/stem"
+        assert stage_path("jit(fn)/while/body/closed_call/raft/update/"
+                          "update/gru/dot_general") == "raft/update/update/gru"
+    elif case == "fusion_root":
+        # no op_name of its own: a fusion takes its root's stage
+        assert m["fusion.1"]["stage"] == "raft/corr_lookup/l2"
+    elif case == "operand":
+        # a compiler-made convert belongs to what produced its input
+        assert m["convert.9"]["stage"] == "raft/corr_lookup/l2"
+    elif case == "structural_only":
+        # code outside every stage() stays unmapped; a container too
+        assert m["add.3"]["stage"] == "" and m["while.1"]["stage"] == ""
+    else:
+        assert "mul.1" not in m and "p0" not in m
+        assert set(m) == {"arg", "gte.1", "fusion.1", "convert.9", "add.3",
+                          "tuple.1", "x", "tanh.1", "while.1", "gte.2"}
+
+
+def test_loaded_executable_with_no_map_gets_one(warm_cache):
+    wc = warm_cache
+    before = _stage_maps(wc.cache)
+    for p in wc.cache.dir.glob("*.stages.json"):
+        p.unlink()
+    cache2 = EngineCache(wc.root, wc.config)
+    engine2 = InferenceEngine(wc.config, wc.params, _sconfig(),
+                              cache=cache2)
+    engine2._compile = _boom
+    assert engine2.warmup(verbose=False) == wc.n
+    assert engine2.warmup_loaded == wc.n
+    # the loaded executable's own text gives the same map
+    assert _stage_maps(cache2) == before
+
+
 def test_stale_identity_field_invalidates_whole_directory(warm_cache):
     wc = warm_cache
     path = wc.cache.dir / MANIFEST_NAME

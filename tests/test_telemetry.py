@@ -214,7 +214,7 @@ def test_trace_window_none_dir_is_noop_and_window_fires(monkeypatch):
     calls = []
     import jax
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
+                        lambda d, **kw: calls.append(("start", d)))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append(("stop", None)))
 

@@ -119,7 +119,8 @@ def test_gru_kernel_compiles_for_v5e(one_chip, dtype):
     assert "tpu_custom_call" in _compile(fn, p, h, h, ctx)
 
 
-def test_whole_inference_program_compiles_for_v5e(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def served_program(one_chip):
     """The one whole-program compile: the served pair executable's model —
     raft-things, bf16, both kernels, 12 iterations, 1x440x1024.  The
     backend check is steered here, in the test (this process's default
@@ -128,9 +129,6 @@ def test_whole_inference_program_compiles_for_v5e(one_chip, monkeypatch):
     from raft_tpu.models import init_raft
     from raft_tpu.models.raft import make_inference_fn
 
-    # both kernels ask jax.default_backend() whether to interpret (corr)
-    # or to run the XLA twin (GRU, impl='auto')
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     config = RAFTConfig.full(iters=12, compute_dtype="bfloat16",
                              corr_impl="pallas", gru_impl="pallas")
     params = jax.tree.map(
@@ -138,11 +136,57 @@ def test_whole_inference_program_compiles_for_v5e(one_chip, monkeypatch):
         jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config)))
     img = jax.ShapeDtypeStruct((1, 440, 1024, 3), jnp.float32,
                                sharding=one_chip)
-    compiled = jax.jit(make_inference_fn(config)).lower(
-        params, img, img).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        # both kernels ask jax.default_backend() whether to interpret (corr)
+        # or to run the XLA twin (GRU, impl='auto')
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return jax.jit(make_inference_fn(config)).lower(
+            params, img, img).compile()
+
+
+def test_whole_inference_program_compiles_for_v5e(served_program):
     # 4 pyramid-level corr kernels + the fused GRU, inside the scan body
-    assert compiled.as_text().count("tpu_custom_call") >= 5
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+    assert served_program.as_text().count("tpu_custom_call") >= 5
+    assert served_program.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+
+
+@pytest.mark.parametrize("pattern,launches", [(r"^corr_lookup\.", 4),
+                                              (r"^gru\.", 1)])
+def test_kernels_keep_the_names_the_benchmark_finds_them_by(
+        served_program, pattern, launches):
+    """The chip's trace names an operation by its instruction, and the
+    compiler names a kernel's instruction after the innermost ``stage()``
+    around it: ``benchmark/layer_metrics/{corr_lookup,gru}_roofline.json``
+    match these patterns, so a renamed scope would silently read nothing."""
+    import re
+    from raft_tpu.telemetry.trace import instruction_stages
+    insts = instruction_stages(served_program.as_text())
+    kernels = [n for n, rec in insts.items() if re.search(pattern, n)
+               and " custom-call(" in rec["text"]]
+    assert len(kernels) == launches, kernels
+
+
+def test_stage_map_of_the_served_program(served_program):
+    """What the engine writes beside its AOT cache entry, for the program
+    the benchmark's cell serves: each pyramid level's lookup under a scope
+    of its own, the converts of its output with it, and every model stage
+    that a per-layer metric reads."""
+    from raft_tpu.telemetry.trace import instruction_stages
+    insts = instruction_stages(served_program.as_text())
+    stages = {rec["stage"] for rec in insts.values()}
+    for level in range(4):
+        scope = f"raft/corr_lookup/l{level}/corr_lookup"
+        under = [n for n, rec in insts.items() if rec["stage"] == scope]
+        assert any(n.startswith("corr_lookup.") for n in under), scope
+        # the f32 -> bf16 convert of the level's output: made by the
+        # compiler with no op_name, so it takes its operand's stage
+        assert any(n.startswith("convert.") and "bf16[1,7040,9,9]"
+                   in insts[n]["text"] for n in under), scope
+    for scope in ("raft/preprocess", "raft/fnet", "raft/cnet",
+                  "raft/corr_pyramid", "raft/gru_ctx", "raft/update",
+                  "raft/upsample"):
+        assert any(st == scope or st.startswith(scope + "/")
+                   for st in stages), scope
 
 
 # --- what the chip's compiler refuses today (ROADMAP A6/C3) ---------------

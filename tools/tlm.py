@@ -328,8 +328,9 @@ def summary_lines(path) -> List[str]:
 # ------------------------------------------------------- request traces --
 
 SPAN_ORDER = ("route", "forward", "retry", "migrate",
-              "admit", "queue_wait", "batch_form", "pad", "execute",
-              "execute_dispatch", "execute_block", "respond")
+              "decode", "admit", "queue_wait", "batch_form", "pad",
+              "execute", "execute_h2d", "execute_dispatch", "execute_block",
+              "execute_fetch", "deliver", "encode", "respond")
 
 
 def _join_traces(recs: List[dict]) -> dict:
@@ -425,7 +426,7 @@ def attribution_lines(records: List[dict]) -> List[str]:
     for name in names:
         vals = sorted(per[name])
         share = (sum(vals) / len(traces)) / mean_e2e * 100 if mean_e2e else 0
-        nested = name in ("execute_dispatch", "execute_block")
+        nested = name.startswith("execute_")
         out.append(f"    {name:<18} p50 {_pctl(vals, 0.50):9.2f}ms  "
                    f"p95 {_pctl(vals, 0.95):9.2f}ms  "
                    f"{share:5.1f}% of e2e"
