@@ -106,10 +106,14 @@ class RAFTConfig:
     # TPU v5e 18.09 vs 11.42 pairs/s (round-2 bench table, PERF.md) and
     # CPU +12% (round-4 A/B); identical values (parity-tested vs gather).
     corr_lookup: str = "onehot"
-    # MXU precision of the fused kernel's correlation matmul ('highest' =
-    # true-f32 multi-pass, honoring the fp32-corr policy; 'default' = bf16
-    # MXU inputs, matching the dense/blockwise einsum default and ~1.6x
-    # faster). Bilinear-interpolation matmuls always run at highest.
+    # MXU precision of the fused kernel's correlation matmul.  'highest' =
+    # every product of the operands' values, summed in float32: for
+    # bfloat16 feature maps one MXU pass at level 0 and three at the pooled
+    # levels, for float32 maps the MXU's six-pass matmul
+    # (ops/corr_pallas.corr_terms counts the terms from the dtypes).
+    # 'default' = one pass over operands the MXU rounds to bf16, matching
+    # the dense/blockwise einsum default.  Bilinear-interpolation matmuls
+    # always run at highest.
     corr_precision: str = "highest"
     # Fused-kernel block sizes (corr_impl='pallas'): queries per program and
     # target level-0 tile width (rows of fmap2 per program x padded W2).

@@ -237,10 +237,17 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
 
     Per level: the pallas_call's resident blocks (f1/coords/f2 in, window
     out) plus the program's dominant intermediates (the [T, Pblk] corr
-    tile and the one-hot interpolation matrices), all float32 — the
-    kernel casts everything to f32 at entry (its dtype-policy contract),
-    so the envelope is compute-dtype-independent.
+    tile and the one-hot interpolation matrices).  The maps are priced in
+    the dtypes the kernel holds them in, asked of the function that hands
+    them to it (``ops.corr_pallas.f2_terms``): for bfloat16 maps at
+    'highest' a bfloat16 f1 block and one (level 0) or three (pooled
+    levels) bfloat16 planes of the f2 block, else float32 blocks.
+    Everything else is float32.
     """
+    import jax
+    from ..ops.corr_pallas import f2_terms
+
+    map_dtype = config.compute_dtype     # what the feature encoder returns
     h, w = bucket
     h0, w0 = h // 8, w // 8
     q = h0 * w0
@@ -265,13 +272,18 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
         # The pipeline double-buffers every grid-indexed block; the body
         # keeps the MXU product and its [T, h2_blk, W2p] view, and builds
         # each one-hot from two `where` terms over int32 iotas.
-        io_blocks = (plan.t * c              # f1 block
-                     + plan.t * 2            # coords block
-                     + pblk * c              # f2 row block
-                     + plan.t * n * n)       # output window block
+        planes = jax.eval_shape(
+            lambda x: f2_terms(map_dtype, x, config.corr_precision),
+            jax.ShapeDtypeStruct(
+                (1, h2, w2, c), map_dtype if level == 0 else "float32"))
+        n_terms, map_bytes = planes.shape[0], planes.dtype.itemsize
+        map_blocks = map_bytes * (plan.t * c             # f1 block
+                                  + n_terms * pblk * c)  # f2 row block
+        f32_blocks = (plan.t * 2             # coords block
+                      + plan.t * n * n)      # output window block
         a_y = plan.t * n * plan.h2_blk
         a_x = plan.t * n * plan.w2p
-        floats = (2 * io_blocks              # double-buffered pipeline
+        floats = (2 * f32_blocks             # double-buffered pipeline
                   + 2 * plan.t * pblk        # corr tile + its 3-D view
                   + 3 * (a_y + a_x)          # one-hots + their where terms
                   + 2 * (a_y + a_x)          # int32 index iotas
@@ -279,9 +291,10 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
                   + plan.t * n * n)          # win
         # (the 'vpu' lookup style is not priced separately: the compiler
         # reports 19.18M for it at the default plan, 14% over this model)
-        bytes_ = 4 * floats
+        bytes_ = 2 * map_blocks + 4 * floats
         worst = max(worst, bytes_)
         levels.append({"level": level, "shape": [h2, w2],
+                       "f2_planes": [n_terms, str(planes.dtype)],
                        "block_bytes": bytes_,
                        "fits": bytes_ <= vmem_bytes,
                        "plan": dataclasses.asdict(plan)})

@@ -30,8 +30,9 @@ from ..config import RAFTConfig, parse_iters_policy
 from ..lint.contracts import contract
 from ..ops import spmd
 from ..ops.coords import coords_grid, upflow8
-from ..ops.corr import (build_pyramid, fmap2_pyramid, lookup_blockwise_onehot,
-                        lookup_dense, lookup_dense_onehot, lookup_ondemand,
+from ..ops.corr import (as_precision, build_pyramid, fmap2_pyramid,
+                        lookup_blockwise_onehot, lookup_dense,
+                        lookup_dense_onehot, lookup_ondemand,
                         mask_ragged_rows, ragged_pyramid)
 from ..ops.upsample import convex_upsample_flow
 from ..telemetry.trace import stage
@@ -238,7 +239,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
     streaming serving path caches the previous frame's maps so each new
     frame costs one encoder pass).  ``params`` must already carry the
     compute-dtype cast; ``fmap1``/``fmap2`` are fnet outputs in any dtype
-    (correlation always casts to float32), ``net``/``inp`` the split
+    (correlation accumulates in float32), ``net``/``inp`` the split
     context activations at the 1/8 grid.  ``policy_spec`` is the parsed
     ``(policy, eps, min_iters)`` from :func:`_validate_loop_config` —
     public entries validate once, before their encoders, and pass it
@@ -269,12 +270,14 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
     cdt = jnp.bfloat16 if config.compute_dtype == "bfloat16" else jnp.float32
     B, h, w, _ = fmap1.shape
 
-    # correlation always in float32 (numerics policy)
+    # correlation products exact, accumulated in float32 (numerics policy):
+    # the XLA paths multiply float32 casts; the Pallas kernels take the maps
+    # in the dtype the encoder produced and choose their MXU passes from it
+    # (ops/corr_pallas.corr_terms)
     fmap1c = fmap1.astype(jnp.float32)
     fmap2c = fmap2.astype(jnp.float32)
 
-    corr_prec = (jax.lax.Precision.HIGHEST if config.corr_precision == "highest"
-                 else jax.lax.Precision.DEFAULT)
+    corr_prec = as_precision(config.corr_precision)
 
     if sizes8 is not None:
         # ragged mixed-resolution batch: ONE lookup closure serves every
@@ -293,7 +296,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                     "corr_impl='pallas' requires ops/corr_pallas.py (the "
                     "fused TPU kernel); use 'dense' or 'blockwise'.") from e
             lookup = make_ragged_fused_lookup(
-                fmap1c, fmap2c, sizes8, config.corr_levels,
+                fmap1, fmap2, sizes8, config.corr_levels,
                 config.corr_radius, corr_precision=corr_prec,
                 q_blk=config.pallas_q_blk, p_blk_target=config.pallas_p_blk,
                 lookup_style=config.pallas_lookup_style)
@@ -353,7 +356,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                 "TPU kernel); use 'dense' or 'blockwise'.") from e
         with stage("raft/corr_pyramid"):      # fmap2's pooled levels
             lookup = make_fused_lookup(
-                fmap1c, fmap2c, config.corr_levels, config.corr_radius,
+                fmap1, fmap2, config.corr_levels, config.corr_radius,
                 corr_precision=corr_prec, q_blk=config.pallas_q_blk,
                 p_blk_target=config.pallas_p_blk,
                 lookup_style=config.pallas_lookup_style,

@@ -28,7 +28,7 @@ TPU-first design, not a translation:
 from __future__ import annotations
 
 import functools
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,55 @@ import jax.numpy as jnp
 from ..lint.contracts import contract
 from ..telemetry.trace import stage
 from .conv import avg_pool2d
+
+
+def as_precision(corr_precision) -> jax.lax.Precision:
+    """``RAFTConfig.corr_precision`` ('highest' / 'default') or a
+    ``jax.lax.Precision`` -> the ``jax.lax.Precision``."""
+    if isinstance(corr_precision, jax.lax.Precision):
+        return corr_precision
+    return (jax.lax.Precision.HIGHEST if corr_precision == "highest"
+            else jax.lax.Precision.DEFAULT)
+
+
+def corr_terms(f1_dtype, f2_dtype, precision) -> Tuple[int, int]:
+    """How many bfloat16 terms ``(n1, n2)`` the fused kernel's correlation
+    matmul (``ops/corr_pallas.py``) multiplies its operands as — the ONE
+    place that decides the kernel's MXU passes
+    (:func:`corr_mxu_passes`), from what it can observe of its input.
+
+    A bfloat16 value is one term.  A float32 value is three (hi + mid + lo,
+    8 + 8 + 8 significand bits, exact).  With a bfloat16 ``f1`` the kernel is
+    handed exactly the terms ``f2`` needs (``corr_pallas.f2_terms``) and sums
+    one single-pass dot per term: every product of the float32 ``HIGHEST``
+    matmul that is not zero.  A float32 ``f1`` keeps that ``HIGHEST`` matmul
+    (the MXU's own split, ``(3, 3)``: six passes); ``DEFAULT`` keeps meaning
+    one pass over operands the MXU rounds itself.
+    """
+    if as_precision(precision) != jax.lax.Precision.HIGHEST:
+        return 1, 1
+    if jnp.dtype(f1_dtype) != jnp.bfloat16:
+        return 3, 3
+    return 1, (1 if jnp.dtype(f2_dtype) == jnp.bfloat16 else 3)
+
+
+def corr_mxu_passes(n1: int, n2: int) -> int:
+    """MXU passes of one correlation tile: a dot per term of ``f2`` against a
+    one-term ``f1``; the float32 ``HIGHEST`` matmul (3, 3) runs six."""
+    return 6 if (n1, n2) == (3, 3) else n1 * n2
+
+
+def level_mxu_passes(map_dtype, num_levels: int,
+                     corr_precision) -> Tuple[int, ...]:
+    """MXU passes of each pyramid level's correlation tile for encoder maps
+    of ``map_dtype``: level 0 is the map itself, the pooled levels are
+    float32 (1/3/3/3 for bfloat16 maps at 'highest', 6/6/6/6 for float32).
+    What the serving engine logs and exports per level."""
+    return tuple(
+        corr_mxu_passes(*corr_terms(
+            map_dtype, map_dtype if level == 0 else jnp.float32,
+            corr_precision))
+        for level in range(num_levels))
 
 
 def fmap2_pyramid(fmap2: jax.Array, num_levels: int = 4) -> List[jax.Array]:

@@ -27,9 +27,17 @@ Design (flash-attention-style, MXU-first):
   (``coords`` is ``stop_gradient``'d upstream anyway — models/raft.py
   step(), mirroring reference RAFT.py:93.)
 
-Numerics: everything float32 (the bf16-with-fp32-corr policy; outputs match
-``ops.corr.lookup_dense`` to float32 round-off). Off-TPU backends run the
-kernel in Pallas interpret mode so CPU tests exercise identical code.
+Numerics: correlation products exact, accumulated in float32 (outputs match
+``ops.corr.lookup_dense`` on the same values to float32 round-off).  At
+``corr_precision='highest'`` the MXU multiplies bfloat16 terms, and how many
+terms an operand needs is a fact of its dtype (:func:`corr_terms`): a
+bfloat16 map is one term, a float32 map pooled from it is three (hi + mid +
+lo, split once where the pyramid is built), so bfloat16 maps cost one MXU
+pass at level 0 and three at the pooled levels; float32 maps take the MXU's
+own six-pass ``HIGHEST`` matmul.  Same products, same float32 sums — the
+passes left out multiplied zeros.  Interpolation, scaling and the output are
+float32 throughout.  Off-TPU backends run the kernel in Pallas interpret mode
+so CPU tests exercise identical code.
 """
 
 from __future__ import annotations
@@ -46,8 +54,12 @@ from jax.experimental.pallas import tpu as pltpu
 from ..lint.budget import VMEM_BYTES, corr_level_plan
 from ..lint.contracts import contract
 from ..telemetry.trace import stage
-from .corr import (fmap2_pyramid, lookup_blockwise_onehot, mask_ragged_rows,
-                   ragged_pyramid)
+# corr_terms and its two readers live in ops/corr.py, which imports no
+# Pallas: a server that loads its executables from the AOT cache asks for the
+# pass counts without paying this module's import (1.0-1.2 s of set-up)
+from .corr import (as_precision, corr_mxu_passes, corr_terms,  # noqa: F401
+                   fmap2_pyramid, level_mxu_passes, lookup_blockwise_onehot,
+                   mask_ragged_rows, ragged_pyramid)
 
 
 def _use_interpret() -> bool:
@@ -73,6 +85,56 @@ _PACK_REFUSAL = (
     "(vector<128x9xi1>) -> vector<128x9x1x1xi1>)")
 
 
+def split_bf16_terms(x: jax.Array, n: int) -> jax.Array:
+    """float32 ``x`` -> ``[n, *x.shape]`` bfloat16 planes, largest first, whose
+    float32 sum is ``x`` bit for bit at ``n`` = 3 (a bfloat16-valued ``x``
+    needs 1).  Each plane rounds what the planes before it left;
+    ``reduce_precision`` does the rounding in float32, where no compiler
+    pass may elide it as a convert pair."""
+    planes, rest = [], x.astype(jnp.float32)
+    for _ in range(n):
+        term = jax.lax.reduce_precision(rest, exponent_bits=8,
+                                        mantissa_bits=7)
+        planes.append(term.astype(jnp.bfloat16))
+        rest = rest - term
+    return jnp.stack(planes)
+
+
+def f2_terms(f1_dtype, f2_level: jax.Array, precision) -> jax.Array:
+    """``[B, H2, W2, C]`` -> the ``[n, B, H2, W2, C]`` planes the kernel
+    multiplies (:func:`corr_terms`): the bfloat16 terms of ``f2_level`` when
+    ``f1`` is bfloat16 at ``HIGHEST``, else its one float32 plane."""
+    n1, n2 = corr_terms(f1_dtype, f2_level.dtype, precision)
+    if as_precision(precision) != jax.lax.Precision.HIGHEST or n1 != 1:
+        return f2_level.astype(jnp.float32)[None]
+    return f2_level[None] if n2 == 1 else split_bf16_terms(f2_level, n2)
+
+
+def _kernel_operands(f1: jax.Array, f2_level: jax.Array, precision):
+    """(f1, f2 planes, dot precision) as the kernel holds them.  A bfloat16
+    stack IS the exact-terms form: ``f1`` stays bfloat16 and each dot is one
+    pass.  Anything else is the float32 matmul at ``precision``."""
+    if f2_level.ndim == 4:          # not split by the caller, outside its loop
+        f2_level = f2_terms(f1.dtype, f2_level, precision)
+    if f2_level.dtype == jnp.bfloat16:
+        return f1, f2_level, jax.lax.Precision.DEFAULT
+    return f1.astype(jnp.float32), f2_level, precision
+
+
+def _corr_tile(f1_ref, f2_ref, corr_precision) -> jax.Array:
+    """[T, Pblk] float32 correlation of the query block with the f2 block:
+    one dot per term plane, summed smallest term first."""
+    f1 = f1_ref[0]                                   # [T, C]
+    corr = None
+    for t in reversed(range(f2_ref.shape[0])):
+        part = jax.lax.dot_general(
+            f1, f2_ref[t, 0], (((1,), (1,)), ((), ())),   # [Pblk, C]
+            precision=corr_precision,
+            preferred_element_type=jnp.float32)
+        corr = part if corr is None else corr + part
+    return corr
+
+
 def _window_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
                  corr_scale: float, radius: int, h2_blk: int, w2: int,
                  corr_precision, lookup_style: str):
@@ -87,13 +149,8 @@ def _window_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
     Both produce identical values.
     """
     n = 2 * radius + 1
-    f1 = f1_ref[0]                                   # [T, C]
-    f2 = f2_ref[0]                                   # [Pblk, C]
-    T = f1.shape[0]
-    corr = jax.lax.dot_general(
-        f1, f2, (((1,), (1,)), ((), ())),
-        precision=corr_precision,
-        preferred_element_type=jnp.float32) * corr_scale        # [T, Pblk]
+    T = f1_ref.shape[1]
+    corr = _corr_tile(f1_ref, f2_ref, corr_precision) * corr_scale  # [T, Pblk]
     corr3 = corr.reshape(T, h2_blk, w2)
 
     c = coords_ref[0] * level_scale                  # [T, 2] (x, y)
@@ -166,16 +223,11 @@ def _packed_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
     neighboring packed column ([0 <= tx < W2] guard).
     """
     n = 2 * radius + 1
-    f1 = f1_ref[0]                                   # [T, C]
-    f2 = f2_ref[0]                                   # [h2_blk*w2, C] packed
-    T = f1.shape[0]
+    T = f1_ref.shape[1]
     W2 = w2_real                                     # real row width (padded
     # cols beyond pack*W2 hold zeros and are never matched)
-    corr = jax.lax.dot_general(
-        f1, f2, (((1,), (1,)), ((), ())),
-        precision=corr_precision,
-        preferred_element_type=jnp.float32) * corr_scale
-    corr3 = corr.reshape(T, h2_blk, w2)
+    corr = _corr_tile(f1_ref, f2_ref, corr_precision) * corr_scale
+    corr3 = corr.reshape(T, h2_blk, w2)              # f2 block: packed rows
 
     c = coords_ref[0] * level_scale                  # [T, 2] (x, y)
     cx, cy = c[:, 0], c[:, 1]
@@ -287,14 +339,17 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                   lookup_style: str = "matmul",
                   p_select: str = "all",
                   pack_rows: bool = False) -> jax.Array:
-    """f1 [B,Q,C], f2_level [B,H2,W2,C], coords [B,Q,2] -> [B,Q,(2r+1)^2]."""
+    """f1 [B,Q,C], f2_level [B,H2,W2,C] (or its [n,B,H2,W2,C] term planes,
+    :func:`f2_terms`), coords [B,Q,2] -> [B,Q,(2r+1)^2] float32."""
     B, Q, C = f1.shape
-    _, H2, W2, _ = f2_level.shape
+    H2, W2 = f2_level.shape[-3:-1]
     n = 2 * radius + 1
     if H2 == 0 or W2 == 0:
         # degenerate pyramid level (map pooled away to nothing): every window
         # is fully out of bounds -> zeros padding
         return jnp.zeros((B, Q, n * n), jnp.float32)
+    f1, f2, corr_precision = _kernel_operands(f1, f2_level, corr_precision)
+    n_terms = f2.shape[0]
 
     # All padding/blocking arithmetic lives in lint/budget.py — the static
     # VMEM budget analyzer checks the very plan this call executes.
@@ -307,7 +362,6 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
         # inside the real queries' row range, so the window schedule of the
         # tail block is not dragged down to row-block 0
         coords = jnp.pad(coords, ((0, 0), (0, Qp - Q), (0, 0)), mode="edge")
-    f2 = f2_level
 
     # Row packing: when the real row width W2 uses at most half the 128
     # lanes, lay `pack` consecutive rows side by side in one packed row so
@@ -316,10 +370,12 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
     n_pblocks = plan.n_pblocks
     if pack > 1:
         H2pkp = plan.rows_padded             # packed rows, block-padded
-        f2 = jnp.pad(f2, ((0, 0), (0, H2pkp * pack - H2), (0, 0), (0, 0)))
-        f2 = f2.reshape(B, H2pkp, pack * W2, C)
+        f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2pkp * pack - H2), (0, 0),
+                          (0, 0)))
+        f2 = f2.reshape(n_terms, B, H2pkp, pack * W2, C)
         if W2p != pack * W2:
-            f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, W2p - pack * W2), (0, 0)))
+            f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, 0),
+                              (0, W2p - pack * W2), (0, 0)))
         body = functools.partial(
             _packed_body, level_scale=1.0 / (2.0 ** level),
             corr_scale=1.0 / (C ** 0.5), radius=radius, h2_blk=h2_blk,
@@ -334,17 +390,17 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
         if H2p != H2 or W2p != W2:
             # zero rows/cols correlate to zero -> identical to zeros padding
             # at the image boundary.
-            f2 = jnp.pad(f2, ((0, 0), (0, H2p - H2), (0, W2p - W2), (0, 0)))
+            f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2p - H2), (0, W2p - W2),
+                              (0, 0)))
         body = functools.partial(
             _window_body, level_scale=1.0 / (2.0 ** level),
             corr_scale=1.0 / (C ** 0.5), radius=radius, h2_blk=h2_blk,
             w2=W2p, corr_precision=corr_precision, lookup_style=lookup_style)
-    f2 = f2.reshape(B, -1, C)
+    f2 = f2.reshape(n_terms, B, -1, C)
 
     grid = (B, Qp // T, n_pblocks)
-    f1 = f1.astype(jnp.float32)
     coords = coords.astype(jnp.float32)
-    f2 = f2.astype(jnp.float32)
+    f2_block = (n_terms, 1, h2_blk * W2p, C)     # every term of one row-block
 
     if p_select == "window":
         S = _window_schedule(coords, 1.0 / (2.0 ** level), radius, T,
@@ -355,8 +411,8 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
             in_specs=[
                 pl.BlockSpec((1, T, C), lambda b, j, k, S: (b, j, 0)),
                 pl.BlockSpec((1, T, 2), lambda b, j, k, S: (b, j, 0)),
-                pl.BlockSpec((1, h2_blk * W2p, C),
-                             lambda b, j, k, S: (b, S[b, j, k], 0)),
+                pl.BlockSpec(f2_block,
+                             lambda b, j, k, S: (0, b, S[b, j, k], 0)),
             ],
             out_specs=pl.BlockSpec((1, T, n, n),
                                    lambda b, j, k, S: (b, j, 0, 0)),
@@ -375,7 +431,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
             in_specs=[
                 pl.BlockSpec((1, T, C), lambda b, j, k: (b, j, 0)),
                 pl.BlockSpec((1, T, 2), lambda b, j, k: (b, j, 0)),
-                pl.BlockSpec((1, h2_blk * W2p, C), lambda b, j, k: (b, k, 0)),
+                pl.BlockSpec(f2_block, lambda b, j, k: (0, b, k, 0)),
             ],
             out_specs=pl.BlockSpec((1, T, n, n), lambda b, j, k: (b, j, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((B, Qp, n, n), jnp.float32),
@@ -386,12 +442,13 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
     return out[:, :Q] if Qp != Q else out
 
 
-# Dtype audit (raftlint R4 / contracts): the kernel is float32 END TO END —
-# inputs are cast at _lookup_level, the corr matmul accumulates f32
-# (preferred_element_type), and every scale factor (corr_scale, level_scale)
-# is a weak-typed Python float, so nothing promotes to f64 even under
-# jax_enable_x64 on the CPU backend.  The contract pins that intent.
-@contract(fmap1="f32[B,H,W,C]", coords="f32[B,H,W,2]",
+# Dtype audit (raftlint R4 / contracts): the maps enter in the dtype the
+# encoder produced (bfloat16 or float32 — corr_terms reads it); coords, the
+# corr tile (preferred_element_type), every scale factor (corr_scale,
+# level_scale: weak-typed Python floats, so nothing promotes to f64 even
+# under jax_enable_x64 on the CPU backend) and the output are float32.  The
+# contract pins that intent.
+@contract(fmap1="f32|bf16[B,H,W,C]", coords="f32[B,H,W,2]",
           _returns="f32[B,H,W,N]")
 def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                        coords: jax.Array, radius: int,
@@ -440,40 +497,54 @@ def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
                  q_blk: int = 128, p_blk_target: int = 4096,
                  lookup_style: str = "matmul",
                  p_select: str = "all",
-                 pack_rows: bool = False) -> jax.Array:
+                 pack_rows: bool = False,
+                 f2_planes: Optional[Tuple[jax.Array, ...]] = None
+                 ) -> jax.Array:
     """Pallas-fused correlation lookup.
 
     fmap1 [B,H,W,C], f2_levels tuple of [B,H/2^i,W/2^i,C], coords [B,H,W,2]
     -> [B, H, W, L*(2r+1)^2], matching ``ops.corr.lookup_dense`` exactly.
+
+    ``f2_planes`` (optional): ``f2_levels`` as the kernel multiplies them
+    (:func:`f2_terms` of each level), built once by a caller that looks up
+    many times (:func:`make_fused_lookup`).  The forward then reads only
+    these; ``f2_levels`` stay the differentiable maps the backward uses.
     """
-    return _fused_lookup_impl(fmap1, f2_levels, coords, radius,
-                              q_blk=q_blk, p_blk_target=p_blk_target,
-                              corr_precision=corr_precision,
-                              lookup_style=lookup_style, p_select=p_select,
-                              pack_rows=pack_rows)
+    return _fused_lookup_impl(
+        fmap1, f2_levels if f2_planes is None else f2_planes, coords, radius,
+        q_blk=q_blk, p_blk_target=p_blk_target,
+        corr_precision=corr_precision, lookup_style=lookup_style,
+        p_select=p_select, pack_rows=pack_rows)
 
 
 def _fused_lookup_fwd(fmap1, f2_levels, coords, radius, corr_precision,
-                      q_blk, p_blk_target, lookup_style, p_select, pack_rows):
-    return _fused_lookup_impl(fmap1, f2_levels, coords, radius,
-                              q_blk=q_blk, p_blk_target=p_blk_target,
-                              corr_precision=corr_precision,
-                              lookup_style=lookup_style,
-                              p_select=p_select, pack_rows=pack_rows), (
-        fmap1, f2_levels, coords)
+                      q_blk, p_blk_target, lookup_style, p_select, pack_rows,
+                      f2_planes):
+    return fused_lookup(fmap1, f2_levels, coords, radius, corr_precision,
+                        q_blk, p_blk_target, lookup_style, p_select,
+                        pack_rows, f2_planes), (fmap1, f2_levels, coords)
+
+
+def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
+    """Cotangents of (fmap1, f2_levels, coords) through the matmul-only XLA
+    twin (no gathers in the backward), on float32 maps whatever dtype the
+    forward multiplied: the configured corr precision applies to the
+    backward matmuls too — 'highest' must not silently degrade to bf16 MXU
+    inputs in training.  Each cotangent comes back in its primal's dtype,
+    which is where an ``astype(float32)`` before the lookup would put it."""
+    primals = (fmap1, tuple(f2_levels), coords)
+    _, vjp = jax.vjp(
+        lambda a, b, c: lookup_blockwise_onehot(a, tuple(b), c, radius,
+                                                precision=corr_precision),
+        *jax.tree.map(lambda x: x.astype(jnp.float32), primals))
+    return jax.tree.map(lambda ct, x: ct.astype(x.dtype), vjp(g), primals)
 
 
 def _fused_lookup_bwd(radius, corr_precision, q_blk, p_blk_target,
                       lookup_style, p_select, pack_rows, residuals, g):
-    # gradients via the matmul-only XLA twin (no gathers in the backward);
-    # the configured corr precision applies to the backward matmuls too —
-    # 'highest' must not silently degrade to bf16 MXU inputs in training
-    fmap1, f2_levels, coords = residuals
-    _, vjp = jax.vjp(
-        lambda a, b, c: lookup_blockwise_onehot(a, tuple(b), c, radius,
-                                                precision=corr_precision),
-        fmap1, tuple(f2_levels), coords)
-    return vjp(g)
+    # the planes are a function of f2_levels that the forward precomputed:
+    # their cotangent is zero (None), f2_levels carry the gradient
+    return (*_twin_vjp(*residuals, radius, corr_precision, g), None)
 
 
 fused_lookup.defvjp(_fused_lookup_fwd, _fused_lookup_bwd)
@@ -487,23 +558,24 @@ def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
                       pack_rows: bool = False):
     """Build the per-iteration lookup closure used by models/raft.py.
 
-    Pools the fmap2 pyramid once; each GRU iteration then runs the fused
-    kernel — recomputing correlation tiles on the MXU instead of re-reading a
-    ~254 MB volume from HBM (or, at resolutions where that volume could not
-    even be allocated, running where the dense path cannot).
+    Pools the fmap2 pyramid once (in float32) and splits it once into the
+    planes the kernel multiplies (:func:`f2_terms`: level 0 is ``fmap2``
+    itself in the dtype it came in); each GRU iteration then runs the fused
+    kernel — recomputing correlation tiles on the MXU instead of re-reading
+    a ~254 MB volume from HBM (or, at resolutions where that volume could
+    not even be allocated, running where the dense path cannot).
     """
+    prec = as_precision(corr_precision)
     f2_levels = tuple(fmap2_pyramid(fmap2.astype(jnp.float32), num_levels))
-    fmap1 = fmap1.astype(jnp.float32)
-    if isinstance(corr_precision, jax.lax.Precision):
-        prec = corr_precision
-    else:
-        prec = (jax.lax.Precision.HIGHEST if corr_precision == "highest"
-                else jax.lax.Precision.DEFAULT)
+    f2_planes = tuple(f2_terms(fmap1.dtype, lvl, prec)
+                      for lvl in (fmap2,) + f2_levels[1:])
+    # f1 as the kernel holds it, cast here once and not in every lookup
+    fmap1 = _kernel_operands(fmap1, f2_planes[0], prec)[0]
 
     def lookup(coords: jax.Array) -> jax.Array:
         return fused_lookup(fmap1, f2_levels, coords, radius, prec,
                             q_blk, p_blk_target, lookup_style, p_select,
-                            pack_rows)
+                            pack_rows, f2_planes)
 
     return lookup
 
@@ -578,14 +650,16 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
                          q_blk: int, p_blk_target: int, interpret: bool,
                          corr_precision=jax.lax.Precision.HIGHEST,
                          lookup_style: str = "matmul") -> jax.Array:
-    """f1 [B,Q,C] (dead rows zero), f2_level [B,H2,W2,C] (pre-masked),
-    coords [B,Q,2], live [B,Q] bool, rows_crop [B] int32 live rows at this
-    level -> [B,Q,(2r+1)^2]."""
+    """f1 [B,Q,C] (dead rows zero), f2_level [B,H2,W2,C] (pre-masked; or its
+    [n,B,H2,W2,C] term planes), coords [B,Q,2], live [B,Q] bool, rows_crop
+    [B] int32 live rows at this level -> [B,Q,(2r+1)^2] float32."""
     B, Q, C = f1.shape
-    _, H2, W2, _ = f2_level.shape
+    H2, W2 = f2_level.shape[-3:-1]
     n = 2 * radius + 1
     if H2 == 0 or W2 == 0:
         return jnp.zeros((B, Q, n * n), jnp.float32)
+    f1, f2, corr_precision = _kernel_operands(f1, f2_level, corr_precision)
+    n_terms = f2.shape[0]
 
     # identical padding/blocking plan to the dense path (lint/budget.py
     # prices exactly this); row packing does not compose with per-item page
@@ -602,9 +676,9 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
     W2p, h2_blk = plan.w2p, plan.h2_blk
     n_pb = plan.n_pblocks
     H2p = plan.rows_padded
-    f2 = f2_level
     if H2p != H2 or W2p != W2:
-        f2 = jnp.pad(f2, ((0, 0), (0, H2p - H2), (0, W2p - W2), (0, 0)))
+        f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2p - H2), (0, W2p - W2),
+                          (0, 0)))
 
     body = functools.partial(
         _window_body, level_scale=1.0 / (2.0 ** level),
@@ -614,9 +688,9 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
     # flatten to per-item-page streams: query block j serves item j // (Qp/T)
     # (Qp is uniform across items, so blocks never straddle an item), and
     # item b's plane occupies absolute pages [b*n_pb, (b+1)*n_pb).
-    f1s = f1.astype(jnp.float32).reshape(1, B * Qp, C)
+    f1s = f1.reshape(1, B * Qp, C)
     cs = coords.astype(jnp.float32).reshape(1, B * Qp, 2)
-    f2s = f2.astype(jnp.float32).reshape(1, B * H2p * W2p, C)
+    f2s = f2.reshape(n_terms, 1, B * H2p * W2p, C)
     grid = (B * Qp // T, n_pb)
     S = _ragged_schedule(coords.astype(jnp.float32), live, rows_crop,
                          1.0 / (2.0 ** level), radius, T, h2_blk,
@@ -627,8 +701,8 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
         in_specs=[
             pl.BlockSpec((1, T, C), lambda j, k, S: (0, j, 0)),
             pl.BlockSpec((1, T, 2), lambda j, k, S: (0, j, 0)),
-            pl.BlockSpec((1, h2_blk * W2p, C),
-                         lambda j, k, S: (0, S[j, k], 0)),
+            pl.BlockSpec((n_terms, 1, h2_blk * W2p, C),
+                         lambda j, k, S: (0, 0, S[j, k], 0)),
         ],
         out_specs=pl.BlockSpec((1, T, n, n),
                                lambda j, k, S: (0, j, 0, 0)),
@@ -644,8 +718,8 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
     return out[:, :Q] if Qp != Q else out
 
 
-@contract(fmap1="f32[B,H,W,C]", coords="f32[B,H,W,2]", sizes8="i32[B,2]",
-          _returns="f32[B,H,W,N]")
+@contract(fmap1="f32|bf16[B,H,W,C]", coords="f32[B,H,W,2]",
+          sizes8="i32[B,2]", _returns="f32[B,H,W,N]")
 def _ragged_fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                               coords: jax.Array, sizes8: jax.Array,
                               radius: int, q_blk: int = 128,
@@ -682,7 +756,9 @@ def ragged_fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
                         coords: jax.Array, sizes8: jax.Array, radius: int,
                         corr_precision=jax.lax.Precision.HIGHEST,
                         q_blk: int = 128, p_blk_target: int = 4096,
-                        lookup_style: str = "matmul") -> jax.Array:
+                        lookup_style: str = "matmul",
+                        f2_planes: Optional[Tuple[jax.Array, ...]] = None
+                        ) -> jax.Array:
     """Ragged Pallas-fused correlation lookup.
 
     fmap1 [B,Hm,Wm,C] with dead regions zeroed (:func:`mask_ragged_rows`),
@@ -692,22 +768,20 @@ def ragged_fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
     equals ``fused_lookup`` run standalone on that crop; dead queries are
     exact zeros.  ``sizes8`` is a regular (traced) argument so ONE
     executable serves every declared resolution — it carries a float0
-    cotangent (integer metadata has no gradient)."""
-    return _ragged_fused_lookup_impl(fmap1, f2_levels, coords, sizes8,
-                                     radius, q_blk=q_blk,
-                                     p_blk_target=p_blk_target,
-                                     corr_precision=corr_precision,
-                                     lookup_style=lookup_style)
+    cotangent (integer metadata has no gradient).  ``f2_planes`` as in
+    :func:`fused_lookup`."""
+    return _ragged_fused_lookup_impl(
+        fmap1, f2_levels if f2_planes is None else f2_planes, coords, sizes8,
+        radius, q_blk=q_blk, p_blk_target=p_blk_target,
+        corr_precision=corr_precision, lookup_style=lookup_style)
 
 
 def _ragged_fused_lookup_fwd(fmap1, f2_levels, coords, sizes8, radius,
                              corr_precision, q_blk, p_blk_target,
-                             lookup_style):
-    return _ragged_fused_lookup_impl(fmap1, f2_levels, coords, sizes8,
-                                     radius, q_blk=q_blk,
-                                     p_blk_target=p_blk_target,
-                                     corr_precision=corr_precision,
-                                     lookup_style=lookup_style), (
+                             lookup_style, f2_planes):
+    return ragged_fused_lookup(fmap1, f2_levels, coords, sizes8, radius,
+                               corr_precision, q_blk, p_blk_target,
+                               lookup_style, f2_planes), (
         fmap1, f2_levels, coords, sizes8)
 
 
@@ -718,12 +792,9 @@ def _ragged_fused_lookup_bwd(radius, corr_precision, q_blk, p_blk_target,
     # reference, so its vjp is the exact ragged backward (dead-region
     # gradients die at the upstream mask).
     fmap1, f2_levels, coords, sizes8 = residuals
-    _, vjp = jax.vjp(
-        lambda a, b, c: lookup_blockwise_onehot(a, tuple(b), c, radius,
-                                                precision=corr_precision),
-        fmap1, tuple(f2_levels), coords)
-    da, db, dc = vjp(g)
-    return da, db, dc, np.zeros(sizes8.shape, jax.dtypes.float0)
+    da, db, dc = _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision,
+                           g)
+    return da, db, dc, np.zeros(sizes8.shape, jax.dtypes.float0), None
 
 
 ragged_fused_lookup.defvjp(_ragged_fused_lookup_fwd, _ragged_fused_lookup_bwd)
@@ -737,21 +808,24 @@ def make_ragged_fused_lookup(fmap1: jax.Array, fmap2: jax.Array,
                              lookup_style: str = "matmul"):
     """Ragged twin of :func:`make_fused_lookup` for mixed-resolution batches
     sharing one max box: masks frame-1 features and builds the re-masked
-    pyramid once, then every GRU iteration runs the page-scheduled ragged
-    kernel.  ``p_select``/``pack_rows`` do not apply — page scheduling IS the
-    window selection, and row packing does not compose with per-item pages.
+    pyramid and its kernel planes once, then every GRU iteration runs the
+    page-scheduled ragged kernel.  ``p_select``/``pack_rows`` do not apply —
+    page scheduling IS the window selection, and row packing does not
+    compose with per-item pages.
     """
+    prec = as_precision(corr_precision)
     f2_levels = tuple(ragged_pyramid(fmap2.astype(jnp.float32), sizes8,
                                      num_levels))
-    fmap1 = mask_ragged_rows(fmap1.astype(jnp.float32), sizes8)
-    if isinstance(corr_precision, jax.lax.Precision):
-        prec = corr_precision
-    else:
-        prec = (jax.lax.Precision.HIGHEST if corr_precision == "highest"
-                else jax.lax.Precision.DEFAULT)
+    fmap1 = mask_ragged_rows(fmap1, sizes8)
+    f2_planes = tuple(
+        f2_terms(fmap1.dtype, lvl, prec)
+        for lvl in (mask_ragged_rows(fmap2, sizes8),) + f2_levels[1:])
+    # f1 as the kernel holds it, cast here once and not in every lookup
+    fmap1 = _kernel_operands(fmap1, f2_planes[0], prec)[0]
 
     def lookup(coords: jax.Array) -> jax.Array:
         return ragged_fused_lookup(fmap1, f2_levels, coords, sizes8, radius,
-                                   prec, q_blk, p_blk_target, lookup_style)
+                                   prec, q_blk, p_blk_target, lookup_style,
+                                   f2_planes)
 
     return lookup

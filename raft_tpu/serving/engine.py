@@ -34,6 +34,10 @@ from .config import ServeConfig
 
 _log = get_logger("serve")
 
+# executable kinds that run the recurrent core, and with it the correlation
+# lookup (encode / scommit / szero / spoison do not)
+_LOOKUP_KINDS = ("pair", "stream", "sbatch")
+
 
 class ReloadMismatch(ValueError):
     """New params don't match the serving template (tree structure or a
@@ -115,6 +119,16 @@ class InferenceEngine:
         # aot_cache.EngineCache or None: warmup load-or-compiles through
         # it, export_cache() populates it for the fleet's shared dir
         self.cache = cache
+        # MXU passes of each pyramid level's correlation matmul in this
+        # engine's executables (None off the Pallas kernel).  The kernel
+        # decides them while a program is traced, from the dtype of the
+        # encoder's maps: 1/3/3/3 for bfloat16 maps, 6 for float32 ones
+        self.corr_mxu_terms = None
+        if config.corr_impl == "pallas":
+            from ..ops.corr import level_mxu_passes
+            self.corr_mxu_terms = level_mxu_passes(
+                config.compute_dtype, config.corr_levels,
+                config.corr_precision)
         if config.quant_weights:
             # quant='bf16w': the encoder weights live on device in bf16
             # (half the encoder param HBM); reload() applies the same cast
@@ -357,6 +371,10 @@ class InferenceEngine:
         grid = enumerate_warmup_grid(self.config, self.sconfig,
                                      stream=self.stream,
                                      chaos=self.faults is not None)
+        corr_terms = ""
+        if self.corr_mxu_terms:
+            corr_terms = ("corr terms "
+                          + "/".join(map(str, self.corr_mxu_terms)) + " ")
         for (kind, h, w, b, _policy) in grid:
             key = self._key(h, w, b, kind)
             with self._lock:
@@ -378,7 +396,8 @@ class InferenceEngine:
             loaded += int(from_cache)
             if verbose:
                 verb = "loaded" if from_cache else "warmed"
-                _log.info(f"{verb} {kind} bucket {h}x{w} batch {b} "
+                terms = corr_terms if kind in _LOOKUP_KINDS else ""
+                _log.info(f"{verb} {kind} bucket {h}x{w} batch {b} {terms}"
                           f"({time.monotonic() - t0:.1f}s elapsed)")
         if self.cache is not None:
             self.cache.write_manifest(grid)

@@ -438,6 +438,18 @@ class FlowServer:
             self.registry.gauge("raft_serving_compile_cache_entries",
                                 "Warm executables resident",
                                 fn=self.engine_executables)
+            terms = getattr(self.engine, "corr_mxu_terms", None)
+            if terms:
+                # registered only on the Pallas kernel, so every other
+                # server's /metrics exposition stays as it was
+                fam = self.registry.gauge(
+                    "raft_serving_corr_mxu_terms",
+                    "MXU passes of the correlation matmul per pyramid "
+                    "level in the warmed executables (bfloat16 maps: 1 at "
+                    "level 0, 3 at the pooled levels; float32 maps: 6)",
+                    labelnames=("level",))
+                for level, n in enumerate(terms):
+                    fam.labels(str(level)).set(n)
             if tlm_watchdogs.lock_watch_enabled():
                 # runtime lock-order validator (RAFT_TPU_LOCK_WATCH=1):
                 # the serving locks were created through watched_lock, so

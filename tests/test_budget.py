@@ -175,6 +175,27 @@ def test_vmem_envelopes(config):
     assert not env["fits"] and env["active"] and env["checks"]
 
 
+@pytest.mark.parametrize("kw,planes,mib", [
+    # float32 maps: one float32 plane a level, the figures of PR 21
+    (dict(compute_dtype="float32"), [[1, "float32"]] * 4,
+     [16.45, 14.46, 8.91, 6.13]),
+    # bfloat16 maps at 'highest': level 0 holds one bfloat16 plane (4 MB of
+    # double-buffered f2 less), the pooled levels three (up to 4 MB more)
+    (dict(compute_dtype="bfloat16"),
+     [[1, "bfloat16"]] + [[3, "bfloat16"]] * 3, [12.32, 17.71, 10.41, 6.75]),
+    # 'default' keeps the float32 blocks the MXU rounds itself
+    (dict(compute_dtype="bfloat16", corr_precision="default"),
+     [[1, "float32"]] * 4, [16.45, 14.46, 8.91, 6.13]),
+])
+def test_corr_envelope_prices_the_dtypes_the_kernel_holds(kw, planes, mib):
+    full = RAFTConfig.full(corr_impl="pallas", **kw)
+    env = budget.corr_vmem_envelope(full, (440, 1024))
+    assert [lv["f2_planes"] for lv in env["levels"]] == planes
+    got = [round(lv["block_bytes"] / 2 ** 20, 2) for lv in env["levels"]]
+    assert got == mib
+    assert env["fits"] and env["worst_block_bytes"] < budget.VMEM_BYTES
+
+
 def test_gru_vmem_envelope_scales_with_block_rows():
     full = RAFTConfig.full()
     small_rows = budget.gru_vmem_envelope(full, (432, 1024), 128)

@@ -263,3 +263,159 @@ def test_window_schedule_invariants():
                         touched.add(row // h2_blk)
             assert touched <= set(S[b, j].tolist()), (
                 b, j, touched, S[b, j].tolist())
+
+
+# --- MXU passes from the operands' dtypes (corr_terms) ----------------------
+
+HIGHEST, DEFAULT = jax.lax.Precision.HIGHEST, jax.lax.Precision.DEFAULT
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.mark.parametrize("f1,f2,precision,terms,passes", [
+    (BF16, BF16, HIGHEST, (1, 1), 1),    # level 0 of a bfloat16 encoder
+    (BF16, F32, HIGHEST, (1, 3), 3),     # its pooled levels
+    (F32, F32, HIGHEST, (3, 3), 6),      # float32 training / serving
+    (F32, BF16, HIGHEST, (3, 3), 6),     # any float32 f1: today's program
+    (BF16, BF16, DEFAULT, (1, 1), 1),    # DEFAULT: one pass, MXU's rounding
+    (F32, F32, DEFAULT, (1, 1), 1),
+])
+def test_corr_terms_truth_table(f1, f2, precision, terms, passes):
+    from raft_tpu.ops.corr_pallas import corr_mxu_passes, corr_terms, f2_terms
+
+    assert corr_terms(f1, f2, precision) == terms
+    assert corr_mxu_passes(*terms) == passes
+    # what the kernel is handed follows: bfloat16 planes only for exact terms
+    planes = f2_terms(f1, jnp.ones((1, 2, 2, 8), f2), precision)
+    exact = precision == HIGHEST and f1 == BF16
+    assert planes.dtype == (BF16 if exact else F32)
+    assert planes.shape == ((terms[1] if exact else 1), 1, 2, 2, 8)
+
+
+def _bf16_case(key, B, H, W, C, levels):
+    """bfloat16 maps, their float32-pooled pyramid (level 0 the map itself,
+    as make_fused_lookup hands it), coords with out-of-map windows."""
+    fmap1, fmap2, coords = _random_case(key, B, H, W, C, dtype=BF16)
+    pooled = fmap2_pyramid(fmap2.astype(F32), levels)
+    return fmap1, [fmap2] + pooled[1:], coords
+
+
+def test_split_reproduces_a_pooled_level_bit_for_bit():
+    from raft_tpu.ops.corr_pallas import split_bf16_terms
+
+    _, f2_levels, _ = _bf16_case(jax.random.PRNGKey(21), 2, 16, 24, 32, 4)
+    for level in f2_levels[1:]:
+        x = np.asarray(level)
+        # the test means something: most pooled values are not bfloat16
+        assert (x != x.astype(BF16).astype(np.float32)).mean() > 0.3
+        planes = split_bf16_terms(level, 3)
+        assert planes.dtype == BF16 and planes.shape == (3,) + x.shape
+        p = np.asarray(planes.astype(F32))
+        back = (p[2] + p[1]) + p[0]                    # smallest term first
+        np.testing.assert_array_equal(back.view(np.uint32),
+                                      x.view(np.uint32))
+    # a bfloat16-valued map is its own single term
+    one = split_bf16_terms(f2_levels[0], 1)
+    np.testing.assert_array_equal(np.asarray(one[0].astype(F32)),
+                                  np.asarray(f2_levels[0].astype(F32)))
+
+
+@pytest.mark.parametrize("level", [0, 1, 2, 3])
+@pytest.mark.parametrize("kernel", ["all", "window", "ragged"])
+def test_bf16_maps_equal_the_float32_highest_program(kernel, level):
+    """bfloat16 maps through the one-pass (level 0) / three-pass (pooled
+    levels) form against the six-pass float32 program on the same values:
+    the same products summed in float32, so float32 round-off apart (1e-6 of
+    max |corr|, the band the kernel keeps against ``lookup_dense``) — with
+    zero padding (20x28 pools to 10x14, 5x7, 2x3) and out-of-map windows."""
+    from raft_tpu.ops.corr import mask_ragged_rows, ragged_pyramid
+    from raft_tpu.ops.corr_pallas import _lookup_level, _ragged_lookup_level
+
+    B, H, W, C, radius = 2, 20, 28, 32, 4
+    fmap1, f2_levels, coords = _bf16_case(jax.random.PRNGKey(20 + level),
+                                          B, H, W, C, 4)
+    f1 = fmap1.reshape(B, H * W, C)
+    cf = coords.reshape(B, H * W, 2)
+    if kernel == "ragged":
+        sizes = jnp.array([[H, W], [13, 17]], jnp.int32)
+        pooled = ragged_pyramid(f2_levels[0].astype(F32), sizes, 4)
+        f2l = (mask_ragged_rows(f2_levels[0], sizes) if level == 0
+               else pooled[level])
+        live = mask_ragged_rows(jnp.ones((B, H, W), bool), sizes)
+        f1 = mask_ragged_rows(fmap1, sizes).reshape(B, H * W, C)
+        fn = lambda a, b, prec: _ragged_lookup_level(     # noqa: E731
+            a, b, cf, live.reshape(B, H * W), sizes[:, 0] // 2 ** level,
+            radius, level, q_blk=64, p_blk_target=256, interpret=True,
+            corr_precision=prec)
+    else:
+        f2l = f2_levels[level]
+        fn = lambda a, b, prec: _lookup_level(            # noqa: E731
+            a, b, cf, radius, level, q_blk=64, p_blk_target=256,
+            interpret=True, corr_precision=prec, p_select=kernel)
+    assert f2l.dtype == (BF16 if level == 0 else F32)
+    got = np.asarray(fn(f1, f2l, HIGHEST))
+    want = np.asarray(fn(f1.astype(F32), f2l.astype(F32), HIGHEST))
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+    # and the exact form is what ran: bfloat16 operands, 1 or 3 one-pass dots
+    text = str(jax.make_jaxpr(lambda a, b: fn(a, b, HIGHEST))(f1, f2l))
+    n_dots = text.count("dot_general") - 2               # less a_y, a_x
+    assert n_dots == (1 if level == 0 else 3), text
+    assert f"bf16[{1 if level == 0 else 3},1," in text
+
+
+def test_float32_maps_keep_the_six_pass_program():
+    """float32 maps that are NOT bfloat16 values: one float32 plane, one
+    correlation dot at HIGHEST (the MXU's own six passes), nothing bfloat16
+    anywhere in the program, the dense oracle's values."""
+    from raft_tpu.ops.corr_pallas import _lookup_level, f2_terms
+
+    B, H, W, C, radius = 1, 12, 16, 16, 3
+    fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(30), B, H, W, C)
+    assert (np.asarray(fmap2)
+            != np.asarray(fmap2.astype(BF16).astype(F32))).mean() > 0.9
+    planes = f2_terms(F32, fmap2, HIGHEST)
+    np.testing.assert_array_equal(np.asarray(planes[0]), np.asarray(fmap2))
+    fn = lambda a, b: _lookup_level(                      # noqa: E731
+        a.reshape(B, H * W, C), b, coords.reshape(B, H * W, 2), radius, 0,
+        q_blk=64, p_blk_target=256, interpret=True)
+    text = str(jax.make_jaxpr(fn)(fmap1, fmap2))
+    assert "bf16" not in text
+    assert text.count("dot_general") == 3                # corr, a_y, a_x
+    assert text.count("Precision.HIGHEST") >= 3
+    want = lookup_dense(build_pyramid(fmap1, fmap2, 1), coords, radius)
+    np.testing.assert_allclose(
+        np.asarray(fn(fmap1, fmap2)).reshape(want.shape), np.asarray(want),
+        rtol=0, atol=2e-6 * float(jnp.abs(want).max()))
+
+
+def test_bf16_gradients_match_blockwise_twin():
+    """bfloat16 maps: the forward rides the exact-terms kernel, the backward
+    the float32 XLA twin at the configured precision, and each cotangent
+    comes back in its primal's dtype — what ``astype(float32)`` before the
+    lookup gave (the twin differentiated through that cast)."""
+    from raft_tpu.ops.corr import lookup_blockwise_onehot
+
+    B, H, W, C, levels, radius = 1, 8, 10, 16, 2, 2
+    fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(3), B, H, W, C,
+                                        dtype=BF16)
+    cot = jax.random.normal(jax.random.PRNGKey(4),
+                            (B, H, W, levels * (2 * radius + 1) ** 2))
+
+    def loss_fused(f1, f2, c):
+        return jnp.sum(make_fused_lookup(f1, f2, levels, radius)(c) * cot)
+
+    def loss_twin(f1, f2, c):
+        f2l = tuple(fmap2_pyramid(f2.astype(F32), levels))
+        return jnp.sum(lookup_blockwise_onehot(
+            f1.astype(F32), f2l, c, radius, precision=HIGHEST) * cot)
+
+    np.testing.assert_allclose(loss_fused(fmap1, fmap2, coords),
+                               loss_twin(fmap1, fmap2, coords), rtol=1e-5)
+    g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(fmap1, fmap2, coords)
+    g_twin = jax.grad(loss_twin, argnums=(0, 1, 2))(fmap1, fmap2, coords)
+    for a, b, x in zip(g_fused, g_twin, (fmap1, fmap2, coords)):
+        assert a.dtype == b.dtype == x.dtype
+        np.testing.assert_allclose(np.asarray(a.astype(F32)),
+                                   np.asarray(b.astype(F32)),
+                                   rtol=1e-2, atol=1e-4)

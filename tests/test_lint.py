@@ -1048,8 +1048,10 @@ def test_contracts_survive_jit_tracing():
 
 
 def test_fused_kernel_contract_pins_float32():
-    """Satellite audit (ops/corr_pallas.py): the fused lookup is f32 end to
-    end on the CPU (interpret) backend — enforced by its contract."""
+    """Satellite audit (ops/corr_pallas.py): the fused lookup takes the
+    feature maps in float32 or bfloat16 (its MXU passes follow from the
+    dtype) and keeps coords and the output float32 on the CPU (interpret)
+    backend — enforced by its contract."""
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
 
@@ -1064,9 +1066,15 @@ def test_fused_kernel_contract_pins_float32():
     try:
         out = _fused_lookup_impl(f1, fmap2_pyramid(f2, 2), coords, 2)
         assert out.dtype == jnp.float32
+        out = _fused_lookup_impl(f1.astype(jnp.bfloat16),
+                                 fmap2_pyramid(f2, 2), coords, 2)
+        assert out.dtype == jnp.float32
         with pytest.raises(contracts.ContractError):
-            _fused_lookup_impl(f1.astype(jnp.bfloat16),
+            _fused_lookup_impl(f1.astype(jnp.float16),
                                fmap2_pyramid(f2, 2), coords, 2)
+        with pytest.raises(contracts.ContractError):
+            _fused_lookup_impl(f1, fmap2_pyramid(f2, 2),
+                               coords.astype(jnp.bfloat16), 2)
     finally:
         contracts.enable_checking(False)
 

@@ -466,6 +466,86 @@ def test_live_healthz_and_metrics(live_server):
     assert "raft_serving_compile_cache_misses_total 0" in text
 
 
+@pytest.mark.parametrize("kw,terms", [
+    (dict(corr_impl="pallas", compute_dtype="bfloat16"), (1, 3, 3, 3)),
+    (dict(corr_impl="pallas", compute_dtype="float32"), (6, 6, 6, 6)),
+    (dict(corr_impl="pallas", compute_dtype="bfloat16",
+          corr_precision="default"), (1, 1, 1, 1)),
+    (dict(corr_impl="dense", compute_dtype="bfloat16"), None),
+])
+def test_engine_reports_corr_mxu_terms(kw, terms):
+    """The counter that says the kernel's exact-terms form engaged: per
+    pyramid level, the MXU passes of the correlation matmul in the engine's
+    executables (None off the Pallas kernel) — in the per-executable
+    warm-up log line and as ``raft_serving_corr_mxu_terms{level=}``."""
+    import logging
+
+    from raft_tpu.config import RAFTConfig, init_rng
+    from raft_tpu.models import init_raft
+    from raft_tpu.serving.engine import InferenceEngine
+
+    config = RAFTConfig.small_model(iters=1, **kw)
+    params = init_raft(init_rng(), config)
+    sconfig = ServeConfig(buckets=((32, 48),), max_batch=1, port=0,
+                          max_sessions=0)
+    engine = InferenceEngine(config, params, sconfig)
+    assert engine.corr_mxu_terms == terms
+
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda rec: lines.append(rec.getMessage())
+    logging.getLogger("raft.serve").addHandler(handler)
+    try:
+        server = FlowServer(None, None, sconfig, engine=engine,
+                            verbose=True)
+        server.start()
+    finally:
+        logging.getLogger("raft.serve").removeHandler(handler)
+    try:
+        with urllib.request.urlopen(server.url + "/metrics") as r:
+            text = r.read().decode()
+    finally:
+        server.stop()
+    warmed = [ln for ln in lines if ln.startswith("warmed pair bucket")]
+    assert len(warmed) == 1, lines
+    if terms is None:
+        assert "corr terms" not in warmed[0]
+        assert "raft_serving_corr_mxu_terms" not in text
+    else:
+        want = "/".join(map(str, terms))
+        assert f"batch 1 corr terms {want} (" in warmed[0], warmed
+        for level, n in enumerate(terms):
+            assert (f'raft_serving_corr_mxu_terms{{level="{level}"}} {n}'
+                    in text), text
+
+
+def test_engine_start_does_not_import_pallas():
+    """A server that loads its executables from the AOT cache traces
+    nothing, so it must not pay the Pallas import (1.0-1.2 s of the
+    benchmark's ``setup_s`` on the chip's host) just to report the
+    kernel's pass counts: they come from ops/corr.py."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from raft_tpu.config import RAFTConfig, init_rng\n"
+        "from raft_tpu.models import init_raft\n"
+        "from raft_tpu.serving import ServeConfig\n"
+        "from raft_tpu.serving.engine import InferenceEngine\n"
+        "config = RAFTConfig.small_model(iters=1, corr_impl='pallas',\n"
+        "                                compute_dtype='bfloat16')\n"
+        "engine = InferenceEngine(config, init_raft(init_rng(), config),\n"
+        "                         ServeConfig(buckets=((32, 48),),\n"
+        "                                     max_batch=1, max_sessions=0))\n"
+        "assert engine.corr_mxu_terms == (1, 3, 3, 3)\n"
+        "assert 'jax.experimental.pallas' not in sys.modules\n"
+        "assert 'raft_tpu.ops.corr_pallas' not in sys.modules\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
 def test_http_engine_failure_returns_500_not_dropped_socket():
     """A persistent engine exception must surface as HTTP 500 JSON — a
     lone request is its own bisection terminus, so it is counted as

@@ -57,36 +57,60 @@ def _compile(fn, *specs):
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
-def _corr_specs(sd, level: int, batch: int = 1):
+def _corr_specs(sd, level: int, batch: int = 1, f1=jnp.float32,
+                f2=jnp.float32):
     s = functools.partial(jax.ShapeDtypeStruct, sharding=sd)
-    return (s((batch, H * W, C), jnp.float32),
-            s((batch, H // 2 ** level, W // 2 ** level, C), jnp.float32),
+    return (s((batch, H * W, C), f1),
+            s((batch, H // 2 ** level, W // 2 ** level, C), f2),
             s((batch, H * W, 2), jnp.float32))
 
 
-@pytest.mark.parametrize("name,level,kw", [
-    ("highest", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096)),
-    ("default", 0, dict(corr_precision=P.DEFAULT, p_blk_target=4096)),
+BF16_L0 = dict(f1=jnp.bfloat16, f2=jnp.bfloat16)   # the encoder's own maps
+BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
+
+
+@pytest.mark.parametrize("name,level,kw,dtypes", [
+    ("highest", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096), {}),
+    ("default", 0, dict(corr_precision=P.DEFAULT, p_blk_target=4096), {}),
     ("window", 0, dict(corr_precision=P.DEFAULT, p_blk_target=1024,
-                       p_select="window")),
+                       p_select="window"), {}),
     ("coarsest-level", 3, dict(corr_precision=P.HIGHEST,
-                               p_blk_target=4096)),
+                               p_blk_target=4096), {}),
     # 19.18M of scoped VMEM: refused under the compiler's 16 MiB default,
     # accepted under the limit the kernels request (lint/budget.VMEM_BYTES)
     ("vpu", 0, dict(corr_precision=P.DEFAULT, p_blk_target=4096,
-                    lookup_style="vpu")),
+                    lookup_style="vpu"), {}),
+    # bfloat16 maps at HIGHEST (ops/corr_pallas.corr_terms): a bfloat16 NT
+    # matmul over one (16,128)-tiled f2 plane at level 0, three planes split
+    # from a float32 pooled level at the others
+    ("bf16-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096),
+     BF16_L0),
+    ("bf16x3-level1", 1, dict(corr_precision=P.HIGHEST, p_blk_target=4096),
+     BF16_X3),
+    ("bf16x3-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096),
+     BF16_X3),
+    ("bf16-window", 0, dict(corr_precision=P.HIGHEST, p_blk_target=1024,
+                            p_select="window"), BF16_L0),
 ])
-def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw):
+def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
     fn = functools.partial(_lookup_level, radius=RADIUS, level=level,
                            q_blk=128, interpret=False, **kw)
-    assert "tpu_custom_call" in _compile(fn, *_corr_specs(one_chip, level))
+    text = _compile(fn, *_corr_specs(one_chip, level, **dtypes))
+    assert "tpu_custom_call" in text
+    if dtypes:
+        # the kernel was handed bfloat16 planes: nothing widened them first
+        planes = 1 if dtypes is BF16_L0 else 3
+        assert f"bf16[{planes},1," in text, name
 
 
-def test_ragged_corr_kernel_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("level,dtypes", [(0, {}), (0, BF16_L0),
+                                          (1, BF16_X3)],
+                         ids=["f32", "bf16-level0", "bf16x3-level1"])
+def test_ragged_corr_kernel_compiles_for_v5e(one_chip, level, dtypes):
     """The ``sizes``-operand (mixed-resolution) kernel at batch 2."""
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    f1, f2, coords = _corr_specs(one_chip, 0, batch=2)
-    fn = functools.partial(_ragged_lookup_level, radius=RADIUS, level=0,
+    f1, f2, coords = _corr_specs(one_chip, level, batch=2, **dtypes)
+    fn = functools.partial(_ragged_lookup_level, radius=RADIUS, level=level,
                            q_blk=128, p_blk_target=4096, interpret=False)
     text = _compile(fn, f1, f2, coords, s((2, H * W), jnp.bool_),
                     s((2,), jnp.int32))

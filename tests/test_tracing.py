@@ -595,7 +595,10 @@ def test_batcher_crash_closes_trace_and_dumps_flightrec(tmp_path):
         while not path.exists() and time.monotonic() < deadline:
             time.sleep(0.02)
         assert path.exists()
-        recs = [json.loads(ln) for ln in path.read_text().splitlines()]
+        # the file exists from the moment the dump opens it: read it only
+        # once that dump has let go of its lock (an empty file otherwise)
+        with server.flightrec._dump_lock:
+            recs = [json.loads(ln) for ln in path.read_text().splitlines()]
         assert recs[0]["event"] == "flightrec_dump"
         assert recs[0]["reason"] == "batcher_crash"
         assert any(r.get("event") == "trace" and r["status"] == "error"
