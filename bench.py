@@ -103,9 +103,11 @@ def _cfg_for(name: str):
         impl = ("pallas" if name.startswith("pallas")
                 else "dense" if name.startswith("dense")
                 else "blockwise" if name.startswith("blockwise") else name)
-    # pallas suffixes compose: -win (window schedule), -pack (row packing),
-    # -winpack (both); they apply to any pallas candidate name, not just
-    # the bf16corr family
+    # pallas suffixes compose: -win (fine key row-blocks of 1024, so that
+    # the kernel's own rule schedules more levels and each tile leaves more
+    # out: lint/budget.corr_level_scheduled), -pack (row
+    # packing), -winpack (both); they apply to any pallas candidate name,
+    # not just the bf16corr family
     window = any(t in ("win", "winpack") for t in tokens)
     pack = any(t in ("pack", "winpack") for t in tokens)
     # -ctx: hoisted GRU context terms (implied by the fused GRU kernel)
@@ -118,8 +120,6 @@ def _cfg_for(name: str):
                                   or name.startswith("pallas-gru"))
                      else "gather"),
         pallas_lookup_style="vpu" if "vpu" in tokens else "matmul",
-        # window schedule wants fine row-blocks so there is something to skip
-        pallas_p_select="window" if window else "all",
         pallas_p_blk=1024 if window else RAFTConfig.full().pallas_p_blk,
         pallas_pack=pack,
         gru_ctx_hoist=ctx,

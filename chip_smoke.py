@@ -55,7 +55,11 @@ class Sizes:
     # serve: the Sintel pair as committed, padded by the server's own
     # padder (data.pipeline.pad_to_shape) into the one declared bucket
     bucket: tuple = (440, 1024)
-    kernel_hw: tuple = (55, 128)      # bucket / 8: the kernels' query grid
+    # the kernels' query grids: the bucket / 8 (Sintel: level 0 is two key
+    # row-blocks, the others one), and 1080x1920 / 8, whose levels 0 to 2
+    # run under the key-block schedule (nine, three and two blocks) and
+    # whose GRU rows are 244 stored columns wide (a VMEM limit of its own)
+    kernel_hws: tuple = ((55, 128), (135, 240))
     # train: the chairs recipe's crop and global batch (config.py
     # TrainConfig.for_stage("chairs")).  One micro-batch of 10 does not fit
     # a 16 GB chip, so the fit knob is --accum, chosen from the chip
@@ -269,7 +273,8 @@ GRU_F32_TOL = 5e-3
 
 
 def phase_kernels(meter, sz: Sizes) -> None:
-    """Both Pallas kernels at Sintel width, compiled by Mosaic
+    """Both Pallas kernels at every grid of ``sz.kernel_hws`` (Sintel's
+    and 1080p's: a changed kernel meets both shapes), compiled by Mosaic
     (``interpret=False`` / ``impl='kernel'``), executed on the chip and
     compared with their XLA oracles: the corr kernel at HIGHEST precision
     against ``lookup_dense`` at HIGHEST, 1e-4 (both exact f32, only the
@@ -285,7 +290,8 @@ def phase_kernels(meter, sz: Sizes) -> None:
     from raft_tpu.models.update import init_sep_conv_gru, precompute_gru_ctx
     from raft_tpu.ops.coords import coords_grid
     from raft_tpu.ops.corr import build_pyramid, fmap2_pyramid, lookup_dense
-    from raft_tpu.ops.corr_pallas import _fused_lookup_impl
+    from raft_tpu.ops.corr_pallas import (_fused_lookup_impl, level_shapes,
+                                          lookup_schedules)
     from raft_tpu.ops.gru_pallas import sep_conv_gru_pallas, sep_conv_gru_xla
 
     def run(fn, *args):
@@ -297,8 +303,7 @@ def phase_kernels(meter, sz: Sizes) -> None:
                   "no tpu_custom_call in the lowered kernel program")
         return np.asarray(lowered.compile()(*args), np.float32)
 
-    with Phase(meter, "kernels") as ph:
-        h, w = sz.kernel_hw
+    def one_grid(h: int, w: int) -> dict:
         C, levels, radius = 256, 4, 4
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
         f1 = jax.random.normal(k1, (1, h, w, C), jnp.float32)
@@ -313,15 +318,29 @@ def phase_kernels(meter, sz: Sizes) -> None:
             coords, radius))
         f2_levels = tuple(fmap2_pyramid(f2, levels))
         errs = {}
-        for p_select, p_blk in (("all", 4096), ("window", 1024)):
-            got = run(functools.partial(
+        # the launches as the kernel's own rule schedules them at this grid
+        # and at finer blocks (every level scheduled), and every block walked
+        got = {}
+        for name, p_blk, sched in (("rule", 4096, None),
+                                   ("rule-fine", 1024, None),
+                                   ("all", 4096, (None,) * levels)):
+            got[name] = run(functools.partial(
                 _fused_lookup_impl, radius=radius, q_blk=128,
                 p_blk_target=p_blk, interpret=sz.interpret,
-                p_select=p_select), f1, f2_levels, coords)
-            errs[f"corr/{p_select}"] = float(np.abs(got - want).max())
-            check(errs[f"corr/{p_select}"] < 1e-4,
-                  f"corr kernel ({p_select}) vs lookup_dense: max|err| "
-                  f"{errs[f'corr/{p_select}']:.3e} >= 1e-4")
+                schedules=sched), f1, f2_levels, coords)
+            errs[f"corr/{name}"] = float(np.abs(got[name] - want).max())
+            check(errs[f"corr/{name}"] < 1e-4,
+                  f"corr kernel ({name}) at {h}x{w} vs lookup_dense: "
+                  f"max|err| {errs[f'corr/{name}']:.3e} >= 1e-4")
+        errs["corr/rule_vs_all"] = float(np.abs(got["rule"]
+                                                - got["all"]).max())
+        check(errs["corr/rule_vs_all"] == 0.0,
+              f"the scheduled launches at {h}x{w} differ from the all-blocks "
+              f"ones by {errs['corr/rule_vs_all']:.3e}: a skipped block adds "
+              f"exact zeros")
+        sched = lookup_schedules(coords, level_shapes(f2_levels), radius)
+        errs["corr/scheduled_levels"] = [i for i, s in enumerate(sched)
+                                         if s is not None]
 
         hid = mdim = ctxd = 128                    # full-model channel plan
         ks = jax.random.split(jax.random.PRNGKey(1), 4)
@@ -344,9 +363,13 @@ def phase_kernels(meter, sz: Sizes) -> None:
             errs[name] = float(np.abs(got - want).max())
             errs[name + "_vs_highest_oracle"] = float(
                 np.abs(got - exact).max())      # information, not a gate
-            check(errs[name] < tol, f"GRU kernel ({name}) vs "
+            check(errs[name] < tol, f"GRU kernel ({name}) at {h}x{w} vs "
                   f"sep_conv_gru_xla: max|err| {errs[name]:.3e} >= {tol}")
-        ph.note(query_grid=[h, w], max_abs_err=errs)
+        return errs
+
+    with Phase(meter, "kernels") as ph:
+        ph.note(max_abs_err={f"{h}x{w}": one_grid(h, w)
+                             for h, w in sz.kernel_hws})
 
 
 # ------------------------------------------------------------------- serve

@@ -126,14 +126,6 @@ class RAFTConfig:
     # values; relative speed is hardware-dependent (tools/tune_pallas.py
     # --style sweeps it).
     pallas_lookup_style: str = "matmul"
-    # Which f2 row-blocks each program grid visits: 'all' iterates every
-    # block (flash-style full pass), 'window' prefetches a per-query-block
-    # schedule of only the row-blocks its bilinear windows can touch —
-    # repeated schedule entries skip the DMA and the compute.  Identical
-    # values; 'window' wins when the lookup window covers a small fraction
-    # of the map (use a smaller pallas_p_blk, e.g. 1024, so blocks are fine
-    # enough to skip).
-    pallas_p_select: str = "all"
     # Row-packed f2 layout for narrow pyramid levels: lays 128//W2
     # consecutive rows side by side in the 128-lane width so the corr tile
     # covers pack x more of the real map (removes lane-padding waste at

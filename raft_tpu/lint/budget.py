@@ -54,6 +54,11 @@ SUBLANE = 8
 #: request 32 MiB and keep their measured block plan.
 VMEM_BYTES = 32 * 1024 * 1024
 
+#: What a kernel whose row blocks outgrow ``VMEM_BYTES`` may ask for instead
+#: (:func:`gru_vmem_limit`): most of the 128 MiB a v5e TensorCore has, the
+#: rest left to the compiler's own use around the kernel.
+VMEM_CEILING_BYTES = 100 * 1024 * 1024
+
 #: Fused-GRU kernel geometry (ops/gru_pallas.py imports these): the pass-1
 #: recompute halo rows, and the separable tap count (1x5 / 5x1 gates).
 GRU_HALO = 4
@@ -209,6 +214,21 @@ def corr_level_plan(q: int, h2: int, w2: int, *, q_blk: int,
                          n_pblocks=rows_padded // h2_blk)
 
 
+def corr_level_scheduled(plan: CorrLevelPlan) -> bool:
+    """THE rule for the lookup's key-block schedule, read from the level's
+    plan alone: a level whose map is cut into more than one row-block is
+    visited through a per-tile schedule of the blocks its windows touch; a
+    level of one block has nothing to leave out and pays for no schedule.
+    A (2r+2)-row window band lies in one or two blocks of a plan's 8 to 32
+    rows, so even at two blocks a tile leaves one out more often than not:
+    on the v5e one launch at batch 32 of 440x1024's level 0 (two blocks;
+    tiles visit 61 % of them) fell from 25.9 to 19.6 ms, and at 1080x1920
+    (batch 8) level 0 (nine blocks, 19.5 %) from 213 to 56, level 1 (three)
+    from 63 to 38, level 2 (two) from 46 to 31 (TUNING.md, PR 26).  No
+    level with more than one block lost."""
+    return plan.n_pblocks > 1
+
+
 @dataclasses.dataclass(frozen=True)
 class GruRowPlan:
     """Row-block geometry of one fused-GRU pallas_call."""
@@ -229,6 +249,40 @@ def gru_row_plan(h: int, w: int, block_rows: int) -> GruRowPlan:
     wc = round_up(w, SUBLANE)
     wp = wc + (GRU_TAPS - 1)
     return GruRowPlan(hp=hp, wc=wc, wp=wp, n_rb=hp // block_rows)
+
+
+#: Scoped VMEM the fused GRU's program takes per (pass-1 row, stored column)
+#: at the full model's 128 hidden + 128 motion channels, by the itemsize of
+#: its I/O: its float32 intermediates are all live at once, on top of the
+#: row blocks it is handed.  An upper envelope of the chip compiler's own
+#: figures (v5e, jax 0.9.0) at 16 pass-1 rows: bfloat16 I/O, 244 stored
+#: columns, 53.23M inside the 1080x1920 pair program (13.96 KiB a position;
+#: the parent's first run of that program on the chip, PR 26, and the same
+#: figure from the compiler here) and 39.63M alone; float32 I/O, 244 columns,
+#: 78.74M alone (20.65 KiB); float32, 132 columns, 17.03M inside the 440x1024
+#: program (8.3 KiB: narrow rows cost less a position, so this over-asks
+#: there, which costs nothing).
+GRU_SCOPED_BYTES_PER_POSITION = {2: 14 * 1024, 4: 24 * 1024}
+
+
+def gru_scoped_bytes(plan: GruRowPlan, block_rows: int, itemsize: int) -> int:
+    """What the fused GRU's program is expected to need of scoped VMEM."""
+    return ((block_rows + 2 * GRU_HALO) * plan.wp
+            * GRU_SCOPED_BYTES_PER_POSITION[itemsize])
+
+
+def gru_vmem_limit(plan: GruRowPlan, block_rows: int, itemsize: int) -> int:
+    """The scoped-VMEM limit the fused GRU kernel asks the compiler for at
+    this row plan and I/O itemsize: ``VMEM_BYTES`` wherever its program is
+    expected to fit that (bfloat16 up to 146 stored columns at 8 rows: the
+    440x1024 program is unchanged), else what it is expected to need and a
+    sixth more, up to ``VMEM_CEILING_BYTES``.  The kernel holds whole rows,
+    so a wider frame is a larger program: 1080x1920 (244 stored columns)
+    needs 53.23M."""
+    need = gru_scoped_bytes(plan, block_rows, itemsize)
+    if need <= VMEM_BYTES:
+        return VMEM_BYTES
+    return min(round_up(need + need // 6, 1024 * 1024), VMEM_CEILING_BYTES)
 
 
 def corr_vmem_envelope(config, bucket: Tuple[int, int],
@@ -350,8 +404,18 @@ def gru_vmem_envelope(config, bucket: Tuple[int, int], motion_dim: int,
     if bytes_ > vmem_bytes:
         checks.append(f"gru kernel row blocks need {bytes_} B of VMEM "
                       f"(> {vmem_bytes}); shrink gru_block_rows")
+    # the program around those blocks: what it is expected to need, and what
+    # the kernel will therefore ask the compiler for at this width
+    scoped = gru_scoped_bytes(plan, t, act_itemsize)
+    limit = gru_vmem_limit(plan, t, act_itemsize)
+    if scoped > VMEM_CEILING_BYTES:
+        checks.append(f"gru kernel's program needs about {scoped} B of "
+                      f"scoped VMEM (> {VMEM_CEILING_BYTES}); shrink "
+                      f"gru_block_rows")
     return {"active": active, "block_bytes": bytes_,
-            "vmem_bytes": vmem_bytes, "fits": bytes_ <= vmem_bytes,
+            "scoped_bytes": scoped, "vmem_limit": limit,
+            "vmem_bytes": vmem_bytes,
+            "fits": bytes_ <= vmem_bytes and scoped <= VMEM_CEILING_BYTES,
             "motion_dim": motion_dim, "plan": dataclasses.asdict(plan),
             "checks": checks}
 
@@ -534,6 +598,27 @@ def kind_footprint(config, pspecs, key: Key, capacity: int,
             else 0}
 
 
+#: Temporaries of one pair executable per input pixel and pair: what the
+#: compiler keeps in HBM while the program runs (encoder activations, the
+#: update loop's carries, the upsampling mask), for the full model in
+#: bfloat16 with both Pallas kernels, which never build a correlation
+#: volume.  From the chip compiler's ``memory_analysis()`` of the served
+#: programs (sandbox compiles for a described v5e): 5.63 GB at 32 x 440x1024
+#: (PR 23) and 6.48 GB at 8 x 1080x1920 (PR 26) are 390.5 and 390.6 bytes.
+PAIR_TEMP_BYTES_PER_PIXEL = 391
+
+
+def pair_temp_bytes(config, h: int, w: int, b: int) -> Optional[int]:
+    """HBM temporaries of the ``pair`` executable at ``b`` x ``h`` x ``w``,
+    or None for a program the figure was not taken from (another model, a
+    float32 one, a lookup that stores its volume): not priced beats priced
+    wrong."""
+    if (config.small or config.compute_dtype != "bfloat16"
+            or config.corr_impl != "pallas" or config.gru_impl != "pallas"):
+        return None
+    return PAIR_TEMP_BYTES_PER_PIXEL * b * h * w
+
+
 def config_signature(config, sconfig, stream: bool, chaos: bool) -> dict:
     """What the committed-baseline comparison keys on: every knob that
     changes the compile surface or the footprint model."""
@@ -592,6 +677,7 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
     buckets = []
     resident = params_b
     peak_transient = 0
+    peak_pair_temp = 0        # stays 0 where pair_temp_bytes prices nothing
     session_row_b = 0
     violations: List[str] = []
     # ragged: exactly ONE pool arena (and one executable family) exists,
@@ -609,6 +695,10 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
                  for k in keys if (k[1], k[2]) == (bh, bw)]
         bucket_peak = max((f["transient_bytes"] for f in kinds), default=0)
         peak_transient = max(peak_transient, bucket_peak)
+        temps = [pair_temp_bytes(rconfig, bh, bw, k[3]) for k in keys
+                 if k[0] == "pair" and (k[1], k[2]) == (bh, bw)]
+        if temps and None not in temps:
+            peak_pair_temp = max(peak_pair_temp, max(temps))
         if stream:
             resident += pool_b
             session_row_b += row_b
@@ -664,6 +754,12 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
             "resident_bytes": resident,
             "peak_transient_bytes": peak_transient,
             "peak_bytes": peak,
+            # with the largest pair executable's temporaries on top, which
+            # is what a batch server's chip holds at its fullest (7.04 GB
+            # against 7.14-7.20 read at 1080x1920, batch 8; 6.12 against
+            # 6.20-6.28 at 440x1024, batch 32: PERF.md, PR 26)
+            "peak_with_pair_temps_bytes": (peak + peak_pair_temp
+                                           if peak_pair_temp else None),
             "hbm_budget_bytes": budget["hbm_bytes"],
             "headroom_bytes": headroom,
             "per_session_bytes": session_row_b or None,

@@ -53,6 +53,11 @@ class RAFTOutput(NamedTuple):
     # repeat sample b's frozen flow — never stale intermediates — so the
     # sequence loss and --dump-flow stay correct.
     iters_used: Optional[jax.Array] = None
+    # int32 [visited, possible]: over every iteration run, the (query tile,
+    # key row-block) steps the fused correlation kernel did work in and the
+    # steps of walking every block (ops/corr_pallas.schedule_keyblocks) —
+    # None off the dense Pallas lookup.
+    corr_keyblocks: Optional[jax.Array] = None
 
 
 def _validate_loop_config(config: RAFTConfig):
@@ -278,6 +283,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
     fmap2c = fmap2.astype(jnp.float32)
 
     corr_prec = as_precision(config.corr_precision)
+    counts_keyblocks = False      # only the dense Pallas lookup has them
 
     if sizes8 is not None:
         # ragged mixed-resolution batch: ONE lookup closure serves every
@@ -328,7 +334,6 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
             pallas_opts=dict(q_blk=config.pallas_q_blk,
                              p_blk_target=config.pallas_p_blk,
                              lookup_style=config.pallas_lookup_style,
-                             p_select=config.pallas_p_select,
                              pack_rows=config.pallas_pack))
     elif config.corr_impl == "dense":
         lookup_fn = (lookup_dense_onehot if config.corr_lookup == "onehot"
@@ -360,8 +365,8 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                 corr_precision=corr_prec, q_blk=config.pallas_q_blk,
                 p_blk_target=config.pallas_p_blk,
                 lookup_style=config.pallas_lookup_style,
-                p_select=config.pallas_p_select,
                 pack_rows=config.pallas_pack)
+        counts_keyblocks = True
     else:
         raise ValueError(config.corr_impl)
 
@@ -390,13 +395,23 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                                          config.hidden_dim,
                                          small=config.small)
 
+    kb0 = jnp.zeros((2,), jnp.int32)
+
     def gru_step(net, coords1):
         """One GRU update — shared by every loop form below.  Returns the
-        updated (net, coords1, mask) plus the per-sample mean L2 norm of
-        the flow update at the 1/8 grid, the converge-policy criterion."""
+        updated (net, coords1, mask), the per-sample mean L2 norm of the
+        flow update at the 1/8 grid (the converge-policy criterion) and the
+        lookup's key-block counts (zeros where it has none)."""
         coords1 = jax.lax.stop_gradient(coords1)   # reference RAFT.py:93 / official
+        kb = kb0
         with stage("raft/corr_lookup"):
-            corr = lookup(coords=coords1).astype(cdt)
+            if counts_keyblocks:
+                # the schedules the kernels are given are the ones counted
+                sched = lookup.schedules(coords1)
+                corr = lookup(coords1, sched).astype(cdt)
+                kb = lookup.keyblocks(sched)
+            else:
+                corr = lookup(coords=coords1).astype(cdt)
         corr = nan_guard(corr, "raft/corr_lookup")
         flow = (coords1 - coords0).astype(cdt)
         with stage("raft/update"):
@@ -406,7 +421,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
         coords1 = coords1 + delta_flow.astype(jnp.float32)
         dn = jnp.sqrt(jnp.sum(jnp.square(delta_flow.astype(jnp.float32)),
                               axis=-1)).mean(axis=(1, 2))        # [B]
-        return net, coords1, mask, dn
+        return net, coords1, mask, dn, kb
 
     def emit(coords1, mask):
         if not all_flows:
@@ -419,15 +434,15 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
     if not adaptive:
         # -- fixed policy: the plain scan, structurally unchanged ---------
         def step(carry, _):
-            net, coords1, _ = carry
-            net, coords1, mask, _ = gru_step(net, coords1)
-            return (net, coords1, mask), emit(coords1, mask)
+            net, coords1, _, kbs = carry
+            net, coords1, mask, _, kb = gru_step(net, coords1)
+            return (net, coords1, mask, kbs + kb), emit(coords1, mask)
 
         if config.remat_iters and train:
             step = jax.checkpoint(step)
 
-        (net, coords1, mask), ys = jax.lax.scan(
-            step, (net, coords1, mask0), None, length=iters,
+        (net, coords1, mask, kbs), ys = jax.lax.scan(
+            step, (net, coords1, mask0, kb0), None, length=iters,
             unroll=min(config.scan_unroll, iters))
         iters_used = jnp.full((B,), iters, jnp.int32)
         if active is not None:             # padding rows spent nothing real
@@ -439,9 +454,9 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
         # remaining iterations, so later emitted flows repeat the frozen
         # flow exactly.  Shapes never depend on the data — one executable
         # serves every difficulty mix (raftlint R2 discipline).
-        def masked_iter(i, net, coords1, mask, converged, nused):
+        def masked_iter(i, net, coords1, mask, converged, nused, kbs):
             active = ~converged                                    # [B]
-            net2, coords2, mask2, dn = gru_step(net, coords1)
+            net2, coords2, mask2, dn, kb = gru_step(net, coords1)
 
             def keep(new, old):
                 a = active.reshape((B,) + (1,) * (new.ndim - 1))
@@ -454,7 +469,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
             converged = converged | (active & (dn < eps)
                                      & (i + 1 >= min_iters))
             nused = nused + active.astype(jnp.int32)
-            return net, coords1, mask, converged, nused
+            return net, coords1, mask, converged, nused, kbs + kb
 
         # padding rows of a slot-batched step start converged: they can
         # never extend the while_loop past the hardest REAL sample, and
@@ -468,17 +483,14 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
             # samples cost no numerics change, and remat/unroll compose
             # exactly as for 'fixed'
             def step(carry, i):
-                net, coords1, mask, converged, nused = carry
-                net, coords1, mask, converged, nused = masked_iter(
-                    i, net, coords1, mask, converged, nused)
-                return (net, coords1, mask, converged, nused), \
-                    emit(coords1, mask)
+                carry = masked_iter(i, *carry)
+                return carry, emit(carry[1], carry[2])
 
             if config.remat_iters and train:
                 step = jax.checkpoint(step)
 
-            (net, coords1, mask, _, iters_used), ys = jax.lax.scan(
-                step, (net, coords1, mask0, conv0, used0),
+            (net, coords1, mask, _, iters_used, kbs), ys = jax.lax.scan(
+                step, (net, coords1, mask0, conv0, used0, kb0),
                 jnp.arange(iters), unroll=min(config.scan_unroll, iters))
         else:
             # inference fast path: whole-batch early exit — the loop stops
@@ -491,14 +503,11 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                 return (i < iters) & ~jnp.all(converged)
 
             def w_body(carry):
-                i, net, coords1, mask, converged, nused = carry
-                net, coords1, mask, converged, nused = masked_iter(
-                    i, net, coords1, mask, converged, nused)
-                return (i + 1, net, coords1, mask, converged, nused)
+                return (carry[0] + 1, *masked_iter(*carry))
 
-            (_, net, coords1, mask, _, iters_used) = jax.lax.while_loop(
+            (_, net, coords1, mask, _, iters_used, kbs) = jax.lax.while_loop(
                 w_cond, w_body,
-                (jnp.int32(0), net, coords1, mask0, conv0, used0))
+                (jnp.int32(0), net, coords1, mask0, conv0, used0, kb0))
             ys = None
 
     flow_lr = coords1 - coords0
@@ -511,7 +520,8 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
             flow = upsample(flow_lr, mask)
 
     return RAFTOutput(flow=flow, flow_iters=flow_iters, flow_lr=flow_lr,
-                      iters_used=iters_used)
+                      iters_used=iters_used,
+                      corr_keyblocks=kbs if counts_keyblocks else None)
 
 
 def _cast_params(params: Dict[str, dict], config: RAFTConfig):
@@ -722,24 +732,31 @@ def make_stream_batch_step_fn(config: RAFTConfig,
     return fn
 
 
-def make_inference_fn(config: RAFTConfig, iters: Optional[int] = None):
-    """A jittable (params, image1, image2) -> final flow function."""
+def make_inference_fn(config: RAFTConfig, iters: Optional[int] = None,
+                      keyblocks: bool = False):
+    """A jittable (params, image1, image2) -> final flow function; with
+    ``keyblocks`` -> (flow, ``RAFTOutput.corr_keyblocks``), for the serving
+    engine's key-block counters (dense Pallas lookup only)."""
     def fn(params, image1, image2):
         out, _ = raft_forward(params, image1, image2, config, iters=iters,
                               train=False, all_flows=False)
-        return out.flow
+        return (out.flow, out.corr_keyblocks) if keyblocks else out.flow
     return fn
 
 
 def make_counted_inference_fn(config: RAFTConfig,
-                              iters: Optional[int] = None):
+                              iters: Optional[int] = None,
+                              keyblocks: bool = False):
     """A jittable (params, image1, image2) -> (flow, iters_used) function —
     the serving/bench twin of :func:`make_inference_fn` that also returns
     the per-sample GRU iteration count ([B] int32), the adaptive-compute
-    observable behind the ``raft_iters_used`` histogram."""
+    observable behind the ``raft_iters_used`` histogram.  ``keyblocks``
+    appends ``RAFTOutput.corr_keyblocks`` as in :func:`make_inference_fn`."""
     def fn(params, image1, image2):
         out, _ = raft_forward(params, image1, image2, config, iters=iters,
                               train=False, all_flows=False)
+        if keyblocks:
+            return out.flow, out.iters_used, out.corr_keyblocks
         return out.flow, out.iters_used
     return fn
 

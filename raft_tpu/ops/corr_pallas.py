@@ -51,7 +51,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..lint.budget import VMEM_BYTES, corr_level_plan
+from ..lint.budget import (VMEM_BYTES, corr_level_plan,
+                           corr_level_scheduled)
 from ..lint.contracts import contract
 from ..telemetry.trace import stage
 # corr_terms and its two readers live in ops/corr.py, which imports no
@@ -290,16 +291,16 @@ def _level_kernel(f1_ref, coords_ref, f2_ref, out_ref, *, body):
 
 def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, *, body):
     """Window-scheduled program: identical math to ``_level_kernel`` but the
-    k-th grid step visits f2 row-block ``S[b, j, k]`` instead of row-block
+    k-th grid step visits f2 row-block ``S[b, j*K + k]`` instead of row-block
     ``k``.  The schedule repeats its last needed block to fill the static
     grid; a repeated index means the pipeline skips the DMA refetch and this
     body skips the compute, so only row-blocks actually overlapped by the
     query block's bilinear windows do work."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
     k = pl.program_id(2)
-    sel = S_ref[b, j, k]
-    prev = S_ref[b, j, jnp.maximum(k - 1, 0)]
+    at = pl.program_id(1) * pl.num_programs(2) + k
+    sel = S_ref[b, at]
+    prev = S_ref[b, at - jnp.minimum(k, 1)]      # step 0 has no previous
 
     @pl.when((k == 0) | (sel != prev))
     def _():
@@ -332,15 +333,96 @@ def _window_schedule(coords: jax.Array, level_scale: float, radius: int,
             + jnp.minimum(ks, (b_hi - b_lo)[..., None])).astype(jnp.int32)
 
 
+def _pad_queries(plan, f1: Optional[jax.Array], coords: jax.Array):
+    """(f1, coords) padded to the plan's ``qp`` queries.  Coords are
+    edge-padded (not zeros): padded queries' windows then stay inside the
+    real queries' row range, so the window schedule of the tail block is not
+    dragged down to row-block 0."""
+    pad = plan.qp - coords.shape[1]
+    if pad:
+        if f1 is not None:
+            f1 = jnp.pad(f1, ((0, 0), (0, pad), (0, 0)))
+        coords = jnp.pad(coords, ((0, 0), (0, pad), (0, 0)), mode="edge")
+    return f1, coords.astype(jnp.float32)
+
+
+def level_schedule(coords: jax.Array, plan, H2: int, level: int,
+                   radius: int) -> jax.Array:
+    """The ``[B, Qp/T, n_pblocks]`` key-block schedule of one level for
+    ``coords`` [B, Q, 2], as :func:`_lookup_level` takes it."""
+    _, coords = _pad_queries(plan, None, coords)
+    return _window_schedule(coords, 1.0 / (2.0 ** level), radius, plan.t,
+                            plan.h2_blk, H2, plan.n_pblocks, pack=plan.pack)
+
+
+def level_shapes(f2_levels: Sequence[jax.Array]):
+    """``[(H2, W2), ...]`` of f2 levels given as maps ``[B,H2,W2,C]`` or as
+    term planes ``[n,B,H2,W2,C]``."""
+    return [tuple(lvl.shape[-3:-1]) for lvl in f2_levels]
+
+
+def _level_plans(Q: int, shapes, q_blk: int, p_blk_target: int,
+                 pack_rows: bool):
+    """One block plan per pyramid level; None where the map is pooled away
+    to nothing (the kernel short-circuits those to zeros)."""
+    return [corr_level_plan(Q, h2, w2, q_blk=q_blk,
+                            p_blk_target=p_blk_target, pack_rows=pack_rows)
+            if h2 > 0 and w2 > 0 else None for h2, w2 in shapes]
+
+
+def lookup_schedules(coords: jax.Array, shapes, radius: int,
+                     q_blk: int = 128, p_blk_target: int = 4096,
+                     pack_rows: bool = False) -> Tuple:
+    """Per level, the key-block schedule its launch runs under, or None
+    where it walks every block: coords [B, H, W, 2], ``shapes`` the
+    ``(H2, W2)`` of each f2 level (:func:`level_shapes`).  Which levels get one is decided here
+    and nowhere else, from each level's block plan
+    (``lint/budget.corr_level_scheduled``): no flag selects it."""
+    B, H, W, _ = coords.shape
+    cf = coords.reshape(B, H * W, 2)
+    plans = _level_plans(H * W, shapes, q_blk, p_blk_target, pack_rows)
+    return tuple(
+        level_schedule(cf, plan, h2, i, radius)
+        if plan is not None and corr_level_scheduled(plan) else None
+        for i, (plan, (h2, _)) in enumerate(zip(plans, shapes)))
+
+
+def schedule_keyblocks(schedules, batch: int, queries: int, shapes,
+                       q_blk: int = 128, p_blk_target: int = 4096,
+                       pack_rows: bool = False) -> jax.Array:
+    """int32 ``[visited, possible]``: the (query tile, key row-block) steps
+    one lookup of ``batch`` x ``queries`` does work in, and the steps of
+    walking every block.  A scheduled level's count is reduced from the
+    schedule its kernel is given (a tile's distinct blocks are its last
+    entry less its first, plus one: entries run up from the first block and
+    then repeat the last); an unscheduled level visits all it has."""
+    visited = jnp.int32(0)
+    possible = 0
+    for plan, S in zip(_level_plans(queries, shapes, q_blk,
+                                    p_blk_target, pack_rows), schedules):
+        if plan is None:
+            continue
+        steps = batch * (plan.qp // plan.t) * plan.n_pblocks
+        possible += steps
+        visited = visited + (steps if S is None else
+                             jnp.sum(S[..., -1] - S[..., 0] + 1))
+    return jnp.stack([visited, jnp.int32(possible)])
+
+
 def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                   radius: int, level: int, *, q_blk: int,
                   p_blk_target: int, interpret: bool,
                   corr_precision=jax.lax.Precision.HIGHEST,
                   lookup_style: str = "matmul",
-                  p_select: str = "all",
+                  schedule: Optional[jax.Array] = None,
                   pack_rows: bool = False) -> jax.Array:
     """f1 [B,Q,C], f2_level [B,H2,W2,C] (or its [n,B,H2,W2,C] term planes,
-    :func:`f2_terms`), coords [B,Q,2] -> [B,Q,(2r+1)^2] float32."""
+    :func:`f2_terms`), coords [B,Q,2] -> [B,Q,(2r+1)^2] float32.
+
+    ``schedule`` (:func:`level_schedule` of the same coords and plan): the
+    key row-blocks each query tile visits; None walks every block.  The
+    values are the same either way, bit for bit: a block left out added
+    exact zeros, and the visited ones keep their order."""
     B, Q, C = f1.shape
     H2, W2 = f2_level.shape[-3:-1]
     n = 2 * radius + 1
@@ -356,12 +438,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
     plan = corr_level_plan(Q, H2, W2, q_blk=q_blk,
                            p_blk_target=p_blk_target, pack_rows=pack_rows)
     T, Qp = plan.t, plan.qp
-    if Qp != Q:
-        f1 = jnp.pad(f1, ((0, 0), (0, Qp - Q), (0, 0)))
-        # edge-pad coords (not zeros): padded queries' windows then stay
-        # inside the real queries' row range, so the window schedule of the
-        # tail block is not dragged down to row-block 0
-        coords = jnp.pad(coords, ((0, 0), (0, Qp - Q), (0, 0)), mode="edge")
+    f1, coords = _pad_queries(plan, f1, coords)
 
     # Row packing: when the real row width W2 uses at most half the 128
     # lanes, lay `pack` consecutive rows side by side in one packed row so
@@ -399,12 +476,18 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
     f2 = f2.reshape(n_terms, B, -1, C)
 
     grid = (B, Qp // T, n_pblocks)
-    coords = coords.astype(jnp.float32)
     f2_block = (n_terms, 1, h2_blk * W2p, C)     # every term of one row-block
 
-    if p_select == "window":
-        S = _window_schedule(coords, 1.0 / (2.0 ** level), radius, T,
-                             h2_blk, H2, grid[2], pack=pack)
+    if schedule is not None:
+        if schedule.shape != grid:
+            raise ValueError(f"level {level}: schedule {schedule.shape} is "
+                             f"not this plan's grid {grid}")
+        # SMEM pads an array's last two dims to (8, 128) words: as [B, Qb, K]
+        # eight pairs' schedule of 254 tiles x 3 blocks took the whole 1 MiB
+        # (the chip compiler's refusal at 1080x1920, batch 8: PR 26), as
+        # [B, Qb*K] it takes 73 KB
+        K = n_pblocks
+        S = schedule.reshape(B, -1)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -412,7 +495,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                 pl.BlockSpec((1, T, C), lambda b, j, k, S: (b, j, 0)),
                 pl.BlockSpec((1, T, 2), lambda b, j, k, S: (b, j, 0)),
                 pl.BlockSpec(f2_block,
-                             lambda b, j, k, S: (0, b, S[b, j, k], 0)),
+                             lambda b, j, k, S: (0, b, S[b, j * K + k], 0)),
             ],
             out_specs=pl.BlockSpec((1, T, n, n),
                                    lambda b, j, k, S: (b, j, 0, 0)),
@@ -456,8 +539,8 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                        interpret: Optional[bool] = None,
                        corr_precision=jax.lax.Precision.HIGHEST,
                        lookup_style: str = "matmul",
-                       p_select: str = "all",
-                       pack_rows: bool = False) -> jax.Array:
+                       pack_rows: bool = False,
+                       schedules: Optional[Tuple] = None) -> jax.Array:
     B, H, W, C = fmap1.shape
     Q = H * W
     if lookup_style not in ("matmul", "vpu"):
@@ -465,16 +548,17 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
         # must not quietly run the other formulation
         raise ValueError(f"lookup_style must be 'matmul' or 'vpu', "
                          f"got {lookup_style!r}")
-    if p_select not in ("all", "window"):
-        raise ValueError(f"p_select must be 'all' or 'window', "
-                         f"got {p_select!r}")
     interp = _use_interpret() if interpret is None else interpret
     if pack_rows and not interp:
         raise ValueError(_PACK_REFUSAL)
+    if schedules is None:       # a caller that does not count key blocks
+        schedules = lookup_schedules(
+            coords, level_shapes(f2_levels), radius, q_blk=q_blk,
+            p_blk_target=p_blk_target, pack_rows=pack_rows)
     f1 = fmap1.reshape(B, Q, C)
     cf = coords.reshape(B, Q, 2)
     outs = []
-    for i, f2l in enumerate(f2_levels):
+    for i, (f2l, sched) in enumerate(zip(f2_levels, schedules)):
         # a scope per pyramid level (.../raft/corr_lookup/l<i>/...): each
         # level is one kernel launch of its own size, and a trace reader can
         # then tell them apart through the engine's instruction -> stage
@@ -486,20 +570,19 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                 f1, f2l, cf, radius, i, q_blk=q_blk,
                 p_blk_target=p_blk_target, interpret=interp,
                 corr_precision=corr_precision, lookup_style=lookup_style,
-                p_select=p_select, pack_rows=pack_rows))
+                schedule=sched, pack_rows=pack_rows))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
                  coords: jax.Array, radius: int,
                  corr_precision=jax.lax.Precision.HIGHEST,
                  q_blk: int = 128, p_blk_target: int = 4096,
                  lookup_style: str = "matmul",
-                 p_select: str = "all",
                  pack_rows: bool = False,
-                 f2_planes: Optional[Tuple[jax.Array, ...]] = None
-                 ) -> jax.Array:
+                 f2_planes: Optional[Tuple[jax.Array, ...]] = None,
+                 schedules: Optional[Tuple] = None) -> jax.Array:
     """Pallas-fused correlation lookup.
 
     fmap1 [B,H,W,C], f2_levels tuple of [B,H/2^i,W/2^i,C], coords [B,H,W,2]
@@ -509,20 +592,27 @@ def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
     (:func:`f2_terms` of each level), built once by a caller that looks up
     many times (:func:`make_fused_lookup`).  The forward then reads only
     these; ``f2_levels`` stay the differentiable maps the backward uses.
+
+    ``schedules`` (optional): :func:`lookup_schedules` of these coords, from
+    a caller that also counts them (:class:`FusedLookup`); None computes the
+    same here.  Either way the rule of ``lint/budget.corr_level_scheduled``
+    decides, per level, whether a launch walks every key row-block or only
+    those its tiles' windows touch.
     """
     return _fused_lookup_impl(
         fmap1, f2_levels if f2_planes is None else f2_planes, coords, radius,
         q_blk=q_blk, p_blk_target=p_blk_target,
         corr_precision=corr_precision, lookup_style=lookup_style,
-        p_select=p_select, pack_rows=pack_rows)
+        pack_rows=pack_rows, schedules=schedules)
 
 
 def _fused_lookup_fwd(fmap1, f2_levels, coords, radius, corr_precision,
-                      q_blk, p_blk_target, lookup_style, p_select, pack_rows,
-                      f2_planes):
+                      q_blk, p_blk_target, lookup_style, pack_rows,
+                      f2_planes, schedules):
     return fused_lookup(fmap1, f2_levels, coords, radius, corr_precision,
-                        q_blk, p_blk_target, lookup_style, p_select,
-                        pack_rows, f2_planes), (fmap1, f2_levels, coords)
+                        q_blk, p_blk_target, lookup_style, pack_rows,
+                        f2_planes, schedules), (fmap1, f2_levels, coords,
+                                                schedules)
 
 
 def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
@@ -541,22 +631,21 @@ def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
 
 
 def _fused_lookup_bwd(radius, corr_precision, q_blk, p_blk_target,
-                      lookup_style, p_select, pack_rows, residuals, g):
+                      lookup_style, pack_rows, residuals, g):
     # the planes are a function of f2_levels that the forward precomputed:
-    # their cotangent is zero (None), f2_levels carry the gradient
-    return (*_twin_vjp(*residuals, radius, corr_precision, g), None)
+    # their cotangent is zero (None), f2_levels carry the gradient; the
+    # schedules are integer metadata (float0, as the ragged sizes are)
+    *primals, schedules = residuals
+    return (*_twin_vjp(*primals, radius, corr_precision, g), None,
+            jax.tree.map(lambda s: np.zeros(s.shape, jax.dtypes.float0),
+                         schedules))
 
 
 fused_lookup.defvjp(_fused_lookup_fwd, _fused_lookup_bwd)
 
 
-@contract(fmap1="*[B,H,W,C]", fmap2="*[B,H2,W2,C]")
-def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
-                      radius: int, corr_precision="highest",
-                      q_blk: int = 128, p_blk_target: int = 4096,
-                      lookup_style: str = "matmul", p_select: str = "all",
-                      pack_rows: bool = False):
-    """Build the per-iteration lookup closure used by models/raft.py.
+class FusedLookup:
+    """The per-iteration lookup of models/raft.py: ``lookup(coords)``.
 
     Pools the fmap2 pyramid once (in float32) and splits it once into the
     planes the kernel multiplies (:func:`f2_terms`: level 0 is ``fmap2``
@@ -564,20 +653,53 @@ def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
     kernel — recomputing correlation tiles on the MXU instead of re-reading
     a ~254 MB volume from HBM (or, at resolutions where that volume could
     not even be allocated, running where the dense path cannot).
+
+    A caller that counts key blocks asks for the iteration's
+    :meth:`schedules` itself, hands them back to the call, and reduces the
+    same arrays with :meth:`keyblocks`: what is counted is what the kernels
+    were given.
     """
-    prec = as_precision(corr_precision)
-    f2_levels = tuple(fmap2_pyramid(fmap2.astype(jnp.float32), num_levels))
-    f2_planes = tuple(f2_terms(fmap1.dtype, lvl, prec)
-                      for lvl in (fmap2,) + f2_levels[1:])
-    # f1 as the kernel holds it, cast here once and not in every lookup
-    fmap1 = _kernel_operands(fmap1, f2_planes[0], prec)[0]
 
-    def lookup(coords: jax.Array) -> jax.Array:
-        return fused_lookup(fmap1, f2_levels, coords, radius, prec,
-                            q_blk, p_blk_target, lookup_style, p_select,
-                            pack_rows, f2_planes)
+    def __init__(self, fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
+                 radius: int, corr_precision="highest", q_blk: int = 128,
+                 p_blk_target: int = 4096, lookup_style: str = "matmul",
+                 pack_rows: bool = False):
+        self.radius, self.prec = radius, as_precision(corr_precision)
+        self.opts = (q_blk, p_blk_target, lookup_style, pack_rows)
+        self.plan_args = dict(q_blk=q_blk, p_blk_target=p_blk_target,
+                              pack_rows=pack_rows)
+        self.f2_levels = tuple(fmap2_pyramid(fmap2.astype(jnp.float32),
+                                             num_levels))
+        self.f2_planes = tuple(f2_terms(fmap1.dtype, lvl, self.prec)
+                               for lvl in (fmap2,) + self.f2_levels[1:])
+        # f1 as the kernel holds it, cast here once and not in every lookup
+        self.fmap1 = _kernel_operands(fmap1, self.f2_planes[0], self.prec)[0]
 
-    return lookup
+    def schedules(self, coords: jax.Array) -> Tuple:
+        return lookup_schedules(coords, level_shapes(self.f2_levels),
+                                self.radius, **self.plan_args)
+
+    def keyblocks(self, schedules: Tuple) -> jax.Array:
+        B, H, W, _ = self.fmap1.shape
+        return schedule_keyblocks(schedules, B, H * W,
+                                  level_shapes(self.f2_levels),
+                                  **self.plan_args)
+
+    def __call__(self, coords: jax.Array,
+                 schedules: Optional[Tuple] = None) -> jax.Array:
+        return fused_lookup(self.fmap1, self.f2_levels, coords, self.radius,
+                            self.prec, *self.opts, self.f2_planes, schedules)
+
+
+@contract(fmap1="*[B,H,W,C]", fmap2="*[B,H2,W2,C]")
+def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
+                      radius: int, corr_precision="highest",
+                      q_blk: int = 128, p_blk_target: int = 4096,
+                      lookup_style: str = "matmul",
+                      pack_rows: bool = False) -> FusedLookup:
+    """Build the per-iteration lookup closure used by models/raft.py."""
+    return FusedLookup(fmap1, fmap2, num_levels, radius, corr_precision,
+                       q_blk, p_blk_target, lookup_style, pack_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -590,7 +712,7 @@ def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
 # the absolute f2 page S[j, k] — item base page + the relative row-block its
 # live bilinear windows overlap — so the kernel never iterates a dense
 # [B, H, W] box and dead tails cost neither DMA nor compute (a repeated
-# schedule entry skips both, exactly like the dense p_select='window' path).
+# schedule entry skips both, exactly like the dense kernel's scheduled levels).
 # Per-level masking (ops.corr.ragged_pyramid) makes every out-of-crop feature
 # row/column zero, so out-of-crop one-hot matches contribute 0 — identical to
 # each crop's own zeros-padding lookup — and the differentiable XLA twin is
@@ -809,9 +931,9 @@ def make_ragged_fused_lookup(fmap1: jax.Array, fmap2: jax.Array,
     """Ragged twin of :func:`make_fused_lookup` for mixed-resolution batches
     sharing one max box: masks frame-1 features and builds the re-masked
     pyramid and its kernel planes once, then every GRU iteration runs the
-    page-scheduled ragged kernel.  ``p_select``/``pack_rows`` do not apply —
-    page scheduling IS the window selection, and row packing does not
-    compose with per-item pages.
+    page-scheduled ragged kernel.  ``pack_rows`` does not apply: row packing
+    does not compose with per-item pages (and page scheduling IS the
+    key-block schedule).
     """
     prec = as_precision(corr_precision)
     f2_levels = tuple(ragged_pyramid(fmap2.astype(jnp.float32), sizes8,

@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..lint.budget import GRU_HALO, GRU_TAPS, VMEM_BYTES, gru_row_plan
+from ..lint.budget import GRU_HALO, GRU_TAPS, gru_row_plan, gru_vmem_limit
 from ..lint.contracts import contract
 from ..telemetry.trace import stage
 from .conv import conv2d
@@ -223,7 +223,8 @@ def _gru_kernel(hm_p, hm_c, hm_n, c1_p, c1_c, c1_n, c2_p, c2_c, c2_n,
 
 
 def _pallas_gru(hm: jax.Array, c1: jax.Array, c2: jax.Array, fw: dict,
-                hidden: int, T: int, H: int, interpret: bool) -> jax.Array:
+                hidden: int, T: int, H: int, interpret: bool,
+                vmem_limit: int) -> jax.Array:
     """hm/c1/c2 [B, Hp, Wp, *] (row/width pre-padded) -> [B, Hp, Wc, hidden]."""
     B, Hp, Wp, _ = hm.shape
     n_rb = Hp // T
@@ -252,8 +253,9 @@ def _pallas_gru(hm: jax.Array, c1: jax.Array, c2: jax.Array, fw: dict,
         out_shape=jax.ShapeDtypeStruct((B, Hp, Wc, hidden), hm.dtype),
         interpret=interpret,
         # f32 I/O at 8 rows x 128 columns needs 17.03M of scoped VMEM, over
-        # the compiler's 16 MiB default (lint/budget.py VMEM_BYTES)
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
+        # the compiler's 16 MiB default; whole rows of a wider frame need
+        # more (lint/budget.gru_vmem_limit reads it from the row plan)
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
     )(hm, hm, hm, c1, c1, c1, c2, c2, c2, *weights)
 
 
@@ -329,7 +331,8 @@ def _gru_fused_impl(p, h, motion, ctx, block_rows, interpret, impl):
     c2 = jnp.pad(_ctx_cat(ctx, "2").astype(io_dtype), pad)
 
     interp = _use_interpret() if interpret is None else interpret
-    out = _pallas_gru(hm, c1, c2, fw, hidden, T, H, interp)
+    out = _pallas_gru(hm, c1, c2, fw, hidden, T, H, interp,
+                      gru_vmem_limit(plan, T, jnp.dtype(io_dtype).itemsize))
     return out[:, :H, :W]
 
 

@@ -80,6 +80,7 @@ class InferenceEngine:
     compile_hits = guarded_by("_lock")
     compile_misses = guarded_by("_lock")
     pair_calls = guarded_by("_lock")
+    corr_keyblocks = guarded_by("_lock")
     encode_calls = guarded_by("_lock")
     stream_calls = guarded_by("_lock")
     weight_version = guarded_by("_lock")
@@ -124,6 +125,7 @@ class InferenceEngine:
         # decides them while a program is traced, from the dtype of the
         # encoder's maps: 1/3/3/3 for bfloat16 maps, 6 for float32 ones
         self.corr_mxu_terms = None
+        self._counts_keyblocks = False
         if config.corr_impl == "pallas":
             from ..ops.corr import level_mxu_passes
             self.corr_mxu_terms = level_mxu_passes(
@@ -158,7 +160,11 @@ class InferenceEngine:
                                        make_inference_fn)
             make = (make_counted_inference_fn if self.adaptive
                     else make_inference_fn)
-            self._fn = jax.jit(make(config, iters=iters))
+            # the dense Pallas lookup's key-block counts ride out of the
+            # pair executable beside the flow, in the one fetch
+            self._counts_keyblocks = config.corr_impl == "pallas"
+            self._fn = jax.jit(make(config, iters=iters,
+                                    keyblocks=self._counts_keyblocks))
         self.stream = stream
         self.pool = pool                  # session.SlotPool (stream servers)
         if stream:
@@ -205,6 +211,10 @@ class InferenceEngine:
         self.encode_calls = 0     # fnet-pass accounting: 1 per encode call,
         self.stream_calls = 0     # 1 per stream step (the acceptance
         self.pair_calls = 0       # criterion's counters), 2 per pair row
+        # [visited, possible] key row-block steps of the pair batches run so
+        # far (RAFTOutput.corr_keyblocks; the server turns their growth into
+        # raft_serving_corr_keyblocks_*_total)
+        self.corr_keyblocks = [0, 0]
         self.weight_version = 1   # bumped by reload(); healthz reports it
         self.weight_tag = None
         self.warmup_seconds = 0.0
@@ -600,10 +610,15 @@ class InferenceEngine:
         if self.ragged:
             args += (self._sizes_arg(n, sizes),)
         out = self._call("pair", ex, args, put=(1, 2))
-        if self.adaptive:
-            flow, iters_used = self._fetch("pair", *out)
-        else:
-            (flow,), iters_used = self._fetch("pair", out), None
+        # flow[, iters_used][, key-block counts]: a bare array when alone
+        outs = out if isinstance(out, (tuple, list)) else (out,)
+        flow, *rest = self._fetch("pair", *outs)
+        if self._counts_keyblocks:
+            visited, possible = (int(v) for v in rest.pop())
+            with self._lock:
+                self.corr_keyblocks[0] += visited
+                self.corr_keyblocks[1] += possible
+        iters_used = rest[0] if self.adaptive else None
         if self.faults is not None:
             flow = self.faults.corrupt_rows(flow)
         return flow if iters_used is None else (flow, iters_used)

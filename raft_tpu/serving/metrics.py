@@ -86,6 +86,18 @@ def make_serving_metrics(registry: Registry, config,
             "raft_serving_compile_cache_misses_total",
             "Device calls that had to compile (0 after warmup = the "
             "no-recompile-storm guarantee)"),
+        # the fused correlation lookup's key-block schedule (ops/corr_pallas
+        # .schedule_keyblocks), reduced on the device from the schedules the
+        # kernels were given and fetched with the flow: visited / possible
+        # is the share of the all-blocks walk a batch still does
+        "keyblocks_visited": registry.counter(
+            "raft_serving_corr_keyblocks_visited_total",
+            "(query tile, key row-block) steps of the correlation lookup "
+            "that did work, over levels, iterations and pair batches"),
+        "keyblocks_possible": registry.counter(
+            "raft_serving_corr_keyblocks_possible_total",
+            "The same steps had every tile visited every key row-block of "
+            "every level"),
         "iters_used": (iters_used := registry.histogram(
             "raft_iters_used",
             "GRU iterations spent per request — fills only under "

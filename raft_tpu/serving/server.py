@@ -295,11 +295,18 @@ class FlowServer:
         self._trace_window.on_step(self._device_batches)
         self._device_batches += 1
         before = getattr(self.engine, "compile_misses", None)
+        blocks = tuple(getattr(self.engine, "corr_keyblocks", ()))
         with stage(scope):
             out = fn(*args)
         if before is not None and self.engine.compile_misses > before:
             self.metrics["compile_misses"].inc(
                 self.engine.compile_misses - before)
+        # (an engine without the counts, a stub's say, zips to nothing)
+        for name, was, now in zip(("keyblocks_visited", "keyblocks_possible"),
+                                  blocks, getattr(self.engine,
+                                                  "corr_keyblocks", ())):
+            if now > was:
+                self.metrics[name].inc(now - was)
         return out
 
     def _run_engine(self, bucket, im1, im2, sizes=None):

@@ -15,28 +15,27 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from bench import _cfg_for, _peak_flops
 
 
-@pytest.mark.parametrize("name,impl,precision,lookup,style,p_select,pack", [
-    ("pallas-bf16corr",         "pallas",    "default", "gather", "matmul", "all",    False),
-    ("pallas-bf16corr-win",     "pallas",    "default", "gather", "matmul", "window", False),
-    ("pallas-bf16corr-winpack", "pallas",    "default", "gather", "matmul", "window", True),
-    ("pallas-bf16corr-pack",    "pallas",    "default", "gather", "matmul", "all",    True),
-    ("pallas-bf16corr-vpu",     "pallas",    "default", "gather", "vpu",    "all",    False),
-    ("pallas",                  "pallas",    "highest", "gather", "matmul", "all",    False),
-    ("dense-onehot",            "dense",     "highest", "onehot", "matmul", "all",    False),
-    ("dense",                   "dense",     "highest", "gather", "matmul", "all",    False),
-    ("blockwise-onehot",        "blockwise", "highest", "onehot", "matmul", "all",    False),
-    ("blockwise",               "blockwise", "highest", "gather", "matmul", "all",    False),
+@pytest.mark.parametrize("name,impl,precision,lookup,style,p_blk,pack", [
+    ("pallas-bf16corr",         "pallas",    "default", "gather", "matmul", 4096, False),
+    ("pallas-bf16corr-win",     "pallas",    "default", "gather", "matmul", 1024, False),
+    ("pallas-bf16corr-winpack", "pallas",    "default", "gather", "matmul", 1024, True),
+    ("pallas-bf16corr-pack",    "pallas",    "default", "gather", "matmul", 4096, True),
+    ("pallas-bf16corr-vpu",     "pallas",    "default", "gather", "vpu",    4096, False),
+    ("pallas",                  "pallas",    "highest", "gather", "matmul", 4096, False),
+    ("dense-onehot",            "dense",     "highest", "onehot", "matmul", 4096, False),
+    ("dense",                   "dense",     "highest", "gather", "matmul", 4096, False),
+    ("blockwise-onehot",        "blockwise", "highest", "onehot", "matmul", 4096, False),
+    ("blockwise",               "blockwise", "highest", "gather", "matmul", 4096, False),
 ])
-def test_candidate_config_mapping(name, impl, precision, lookup, style, p_select, pack):
+def test_candidate_config_mapping(name, impl, precision, lookup, style, p_blk, pack):
     cfg = _cfg_for(name)
     assert cfg.corr_impl == impl
     assert cfg.corr_precision == precision
     assert cfg.corr_lookup == lookup
     assert cfg.pallas_lookup_style == style
-    assert cfg.pallas_p_select == p_select
     assert cfg.pallas_pack == pack
-    if p_select == "window":    # fine blocks so there is something to skip
-        assert cfg.pallas_p_blk == 1024
+    # "-win": blocks fine enough that the kernel's rule schedules them
+    assert cfg.pallas_p_blk == p_blk
     assert cfg.compute_dtype == "bfloat16"
     assert cfg.gru_impl == "xla"
     assert not cfg.small

@@ -321,7 +321,7 @@ def test_ring_lookup_via_fused_kernel_matches_dense():
             f1l, f2l, levels, radius, SPATIAL_AXIS,
             precision=jax.lax.Precision.HIGHEST, kernel="pallas",
             pallas_opts=dict(q_blk=64, p_blk_target=1024,
-                             p_select="window", pack_rows=True))
+                             pack_rows=True))
         return lk(cl)
 
     f = jax.jit(compat_shard_map(

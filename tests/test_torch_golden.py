@@ -118,7 +118,7 @@ def test_full_model_torch_parity_pallas_winpack():
     an official-RAFT edge case, not a lookup bug; this framework returns
     zeros for degenerate levels instead."""
     tflows, jflows = _run_pair(False, B=1, H=128, W=128, iters=2,
-                               corr_impl="pallas", pallas_p_select="window",
+                               corr_impl="pallas",
                                pallas_p_blk=1024, pallas_pack=True)
     err = np.abs(tflows[-1] - jflows[-1]).max()
     scale = np.abs(tflows[-1]).max()
@@ -133,7 +133,7 @@ def test_full_model_torch_parity_pallas_winpack_160():
     (128-lane tiles over widths 20/10/5/2), and Q = 400 not a multiple of
     the 128 query block."""
     tflows, jflows = _run_pair(False, B=1, H=160, W=160, iters=2,
-                               corr_impl="pallas", pallas_p_select="window",
+                               corr_impl="pallas",
                                pallas_p_blk=1024, pallas_pack=True)
     err = np.abs(tflows[-1] - jflows[-1]).max()
     scale = np.abs(tflows[-1]).max()

@@ -4,7 +4,8 @@ not attached (on-chip-measurement guide §2, rehearsal 3).
 Interpret mode cannot see what Mosaic refuses — a slice off the tiling, a
 shape cast it has no layout for, more scoped VMEM than a kernel may use.
 These cases ask the chip's own compiler, at the real widths (Sintel 440x1024:
-55x128 queries, C=256; hidden 128), and cost ~2 s each and no chip time.
+55x128 queries, and full HD 1080x1920: 135x240; C=256; hidden 128), and cost
+~2 s each and no chip time.
 They call the kernels' own entry points with ``interpret=False`` /
 ``impl='kernel'`` — no program option exists for this.
 
@@ -27,10 +28,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from raft_tpu.ops import corr_pallas
-from raft_tpu.ops.corr_pallas import _lookup_level, _ragged_lookup_level
+from raft_tpu.lint.budget import corr_level_plan, corr_level_scheduled
+from raft_tpu.ops.corr_pallas import (_lookup_level, _ragged_lookup_level,
+                                      level_schedule)
 
 P = jax.lax.Precision
 H, W, C = 55, 128, 256          # Sintel bucket 440x1024 at the 1/8 grid
+HD = (135, 240)                 # 1080x1920 at the 1/8 grid
 RADIUS = 4
 
 
@@ -58,11 +62,23 @@ def _compile(fn, *specs):
 
 
 def _corr_specs(sd, level: int, batch: int = 1, f1=jnp.float32,
-                f2=jnp.float32):
+                f2=jnp.float32, grid=(H, W)):
+    h, w = grid
     s = functools.partial(jax.ShapeDtypeStruct, sharding=sd)
-    return (s((batch, H * W, C), f1),
-            s((batch, H // 2 ** level, W // 2 ** level, C), f2),
-            s((batch, H * W, 2), jnp.float32))
+    return (s((batch, h * w, C), f1),
+            s((batch, h // 2 ** level, w // 2 ** level, C), f2),
+            s((batch, h * w, 2), jnp.float32))
+
+
+def _scheduled_level(f1, f2_level, coords, *, level, p_blk_target, **kw):
+    """``_lookup_level`` under the key-block schedule of its own coords."""
+    h2, w2 = f2_level.shape[-3:-1]
+    plan = corr_level_plan(f1.shape[1], h2, w2, q_blk=128,
+                           p_blk_target=p_blk_target)
+    return _lookup_level(
+        f1, f2_level, coords, RADIUS, level, q_blk=128,
+        p_blk_target=p_blk_target, interpret=False,
+        schedule=level_schedule(coords, plan, h2, level, RADIUS), **kw)
 
 
 BF16_L0 = dict(f1=jnp.bfloat16, f2=jnp.bfloat16)   # the encoder's own maps
@@ -72,8 +88,8 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
 @pytest.mark.parametrize("name,level,kw,dtypes", [
     ("highest", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096), {}),
     ("default", 0, dict(corr_precision=P.DEFAULT, p_blk_target=4096), {}),
-    ("window", 0, dict(corr_precision=P.DEFAULT, p_blk_target=1024,
-                       p_select="window"), {}),
+    ("scheduled", 0, dict(corr_precision=P.DEFAULT, p_blk_target=1024,
+                          scheduled=True), {}),
     ("coarsest-level", 3, dict(corr_precision=P.HIGHEST,
                                p_blk_target=4096), {}),
     # 19.18M of scoped VMEM: refused under the compiler's 16 MiB default,
@@ -89,13 +105,34 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
      BF16_X3),
     ("bf16x3-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096),
      BF16_X3),
-    ("bf16-window", 0, dict(corr_precision=P.HIGHEST, p_blk_target=1024,
-                            p_select="window"), BF16_L0),
+    ("bf16-scheduled", 0, dict(corr_precision=P.HIGHEST, p_blk_target=1024,
+                               scheduled=True), BF16_L0),
+    # 1080x1920: the levels the kernel's rule schedules at the served plan
+    # (nine blocks of 16 rows x 256 lanes at level 0, three at level 1, two
+    # at level 2), and level 3, which is one block
+    ("hd-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                          scheduled=True, grid=HD), BF16_L0),
+    ("hd-level1", 1, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                          scheduled=True, grid=HD), BF16_X3),
+    ("hd-level2", 2, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                          scheduled=True, grid=HD), BF16_X3),
+    ("hd-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                          grid=HD), BF16_X3),
 ])
 def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
-    fn = functools.partial(_lookup_level, radius=RADIUS, level=level,
-                           q_blk=128, interpret=False, **kw)
-    text = _compile(fn, *_corr_specs(one_chip, level, **dtypes))
+    kw = dict(kw)
+    grid = kw.pop("grid", (H, W))
+    if kw.pop("scheduled", False):
+        fn = functools.partial(_scheduled_level, level=level, **kw)
+    else:
+        fn = functools.partial(_lookup_level, radius=RADIUS, level=level,
+                               q_blk=128, interpret=False, **kw)
+    if grid == HD:      # the case is the program's: the rule gives the same
+        plan = corr_level_plan(grid[0] * grid[1], grid[0] >> level,
+                               grid[1] >> level, q_blk=128,
+                               p_blk_target=4096)
+        assert corr_level_scheduled(plan) == (level < 3)
+    text = _compile(fn, *_corr_specs(one_chip, level, grid=grid, **dtypes))
     assert "tpu_custom_call" in text
     if dtypes:
         # the kernel was handed bfloat16 planes: nothing widened them first
@@ -117,12 +154,18 @@ def test_ragged_corr_kernel_compiles_for_v5e(one_chip, level, dtypes):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32-io", "bf16-io"])
-def test_gru_kernel_compiles_for_v5e(one_chip, dtype):
-    """Fused SepConvGRU at 55x128x128, ``gru_block_rows=8``.  The f32 case
-    needs 17.03M of scoped VMEM — over the compiler's 16 MiB default, which
-    is why the kernel asks for lint/budget.VMEM_BYTES."""
+@pytest.mark.parametrize("grid,dtype", [((H, W), jnp.float32),
+                                        ((H, W), jnp.bfloat16),
+                                        (HD, jnp.float32),
+                                        (HD, jnp.bfloat16)],
+                         ids=["f32-io", "bf16-io", "hd-f32-io", "hd-bf16-io"])
+def test_gru_kernel_compiles_for_v5e(one_chip, grid, dtype):
+    """Fused SepConvGRU at 55x128x128 and 135x240x128, ``gru_block_rows=8``.
+    The f32 case at 55x128 needs 17.03M of scoped VMEM — over the compiler's
+    16 MiB default; whole rows of 244 stored columns need 39.63M (bf16 I/O,
+    alone; 53.23M inside the served 1080x1920 program, which the chip's
+    compiler refused under 32 MiB: PR 26).  The kernel asks for what its row
+    plan needs (lint/budget.gru_vmem_limit)."""
     from raft_tpu.models.update import init_sep_conv_gru, precompute_gru_ctx
     from raft_tpu.ops.gru_pallas import sep_conv_gru_pallas
 
@@ -135,7 +178,7 @@ def test_gru_kernel_compiles_for_v5e(one_chip, dtype):
     hid = 128
     p = spec(jax.eval_shape(
         lambda: init_sep_conv_gru(jax.random.PRNGKey(0), hid, 256)))
-    h = spec(jax.ShapeDtypeStruct((1, H, W, hid), dtype))
+    h = spec(jax.ShapeDtypeStruct((2, *grid, hid), dtype))
     ctx = spec(jax.eval_shape(
         lambda pp, i: precompute_gru_ctx(pp, i, hid), p, h))
     fn = functools.partial(sep_conv_gru_pallas, block_rows=8,

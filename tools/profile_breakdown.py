@@ -176,7 +176,6 @@ def main():
                                    corr_precision=prec, q_blk=cfg.pallas_q_blk,
                                    p_blk_target=cfg.pallas_p_blk,
                                    lookup_style=cfg.pallas_lookup_style,
-                                   p_select=cfg.pallas_p_select,
                                    pack_rows=cfg.pallas_pack)
             return fn(coords=coords)
 

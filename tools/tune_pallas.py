@@ -122,9 +122,6 @@ def main() -> int:
                         "MXU inputs, the bench winner's setting)")
     p.add_argument("--style", default="matmul", choices=["matmul", "vpu"],
                    help="window-lookup formulation inside the kernel")
-    p.add_argument("--p-select", default="all", choices=["all", "window"],
-                   help="row-block schedule: full pass or the prefetched "
-                        "window schedule (skips non-overlapping blocks)")
     p.add_argument("--pack", action="store_true",
                    help="row-packed f2 lanes for narrow levels (packed "
                         "levels use their own fixed contraction; --style "
@@ -150,7 +147,8 @@ def main() -> int:
     prec = (jax.lax.Precision.HIGHEST if args.precision == "highest"
             else jax.lax.Precision.DEFAULT)
     print(f"# device: {dev.device_kind}  corr precision: {args.precision}  "
-          f"lookup style: {args.style}  p_select: {args.p_select}  "
+          f"lookup style: {args.style}  key-block schedule: by the "
+          f"kernel's rule (fine p_blk targets get it)  "
           f"pack: {args.pack}")
 
     # (label, B, full-res H, W); fmaps are at os=8, C=256 (full model)
@@ -177,7 +175,7 @@ def main() -> int:
             fn = jax.jit(functools.partial(
                 _fused_lookup_impl, radius=args.radius, q_blk=q_blk,
                 p_blk_target=p_blk, interpret=False, corr_precision=prec,
-                lookup_style=args.style, p_select=args.p_select,
+                lookup_style=args.style,
                 pack_rows=args.pack))
             try:
                 dt = _measure(fn, (fmap1, f2_levels, coords),
