@@ -517,7 +517,11 @@ def kind_footprint(config, pspecs, key: Key, capacity: int,
     non-resident inputs plus its outputs, with donated buffers aliased
     away (a scommit's output pool buffers reuse the donated inputs'
     memory off-CPU; on the CPU backend donation is off and the scatter
-    really is a copy — pass ``donation=False`` to model that).
+    really is a copy — pass ``donation=False`` to model that).  A ``pair``
+    call is priced with a second set of inputs and outputs
+    (``staged_bytes``) beside the running one: the batcher places the next
+    batch while this one runs, and dispatches it before it has fetched
+    this one's flow (serving/batcher.py).
     """
     import jax
     import jax.numpy as jnp
@@ -588,12 +592,14 @@ def kind_footprint(config, pspecs, key: Key, capacity: int,
     in_b = sum(bytes_of(s) for s in jax.tree.leaves(list(inputs)))
     out_b = tree_bytes(out)
     don_b = tree_bytes(list(donated))
+    staged = in_b + out_b if kind == "pair" else 0
     if kind == "szero":
         transient = 0
     else:
-        transient = in_b + max(0, out_b - don_b)
+        transient = in_b + max(0, out_b - don_b) + staged
     return {"key": list(key), "input_bytes": in_b, "output_bytes": out_b,
-            "donated_bytes": don_b, "transient_bytes": transient,
+            "donated_bytes": don_b, "staged_bytes": staged,
+            "transient_bytes": transient,
             "pool_bytes": pool_b if resident_inputs or kind == "szero"
             else 0}
 
@@ -755,9 +761,9 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
             "peak_transient_bytes": peak_transient,
             "peak_bytes": peak,
             # with the largest pair executable's temporaries on top, which
-            # is what a batch server's chip holds at its fullest (7.04 GB
-            # against 7.14-7.20 read at 1080x1920, batch 8; 6.12 against
-            # 6.20-6.28 at 440x1024, batch 32: PERF.md, PR 26)
+            # is what a batch server's chip holds at its fullest (7.57 GB
+            # against 7.54-7.60 read at 1080x1920, batch 8; 6.58 against
+            # 6.54-6.58 at 440x1024, batch 32: PERF.md, PR 27)
             "peak_with_pair_temps_bytes": (peak + peak_pair_temp
                                            if peak_pair_temp else None),
             "hbm_budget_bytes": budget["hbm_bytes"],

@@ -8,9 +8,19 @@ independent; repetition keeps values finite for the instance norms), runs
 the warm engine, slices real rows back out, unpads each to its request's
 original resolution, and resolves the waiting handler threads.
 
-The engine is injected as a callable ``run(bucket, im1, im2) -> flow`` so
-tests can drive the batching policy with a stub (slow / counting / failing)
-engine and never touch a compile.
+Pairwise batches go through the device two deep (SERVING.md "The batcher's
+pipeline"): with batch n running the thread takes, forms, pads and places
+n+1; when n is ready it dispatches n+1 first and then fetches and delivers
+n.  At most one batch runs and one is staged.  While a batch runs a take
+waits for a FULL bucket or for that batch to be ready; a finished batch is
+never held for a batch that is not there, so a lightly loaded server's
+requests take the path, and the time, they always took.
+
+The engine is injected: an object with the phases of a device call (``place,
+dispatch, ready, wait, fetch``: serving/engine.py), or a callable
+``run(bucket, im1, im2) -> flow`` standing for all five, so tests can drive
+the batching policy with a stub (slow / counting / failing) engine and
+never touch a compile.
 
 Failure containment (SERVING.md "Failure modes & degradation ladder"):
 
@@ -45,7 +55,8 @@ holds **no lock of its own** — single ownership IS its synchronization.
 ``batches``/``served``/``timed_out`` and ``_inflight_batch`` are written
 only on the loop thread (``restart()`` builds a new thread only after
 the old one has died, so single-writer holds across restarts); other
-threads only ever read them (serve_cli's exit line, /healthz, tests),
+threads only ever read them (serve_cli's exit line, /healthz, tests); the
+same holds for ``_running`` and the pad buffers,
 which is why raftlint's C1/C6 — scoped to lock-HOLDING classes — do not
 apply here.  Everything shared it touches synchronizes on the owner's
 lock: the queue's (take_batch), the breaker's (record), the store's
@@ -101,6 +112,50 @@ def _fresh_error(e: BaseException) -> BaseException:
         return e
 
 
+class _BlockingCall:
+    """A plain ``run(bucket, im1, im2[, sizes]) -> flow`` callable as the
+    phases of a device call: ``dispatch`` is the whole call, so nothing is
+    ever running while the batcher looks for the next batch, and it walks
+    the serial path by itself."""
+
+    def __init__(self, run_fn: Callable):
+        self.run_fn = run_fn
+
+    def place(self, *args) -> list:
+        return [args, None]
+
+    def dispatch(self, call: list) -> None:
+        call[1] = self.run_fn(*call[0])
+
+    def ready(self, call: list) -> bool:
+        return True
+
+    def wait(self, call: list) -> None:
+        pass
+
+    def fetch(self, call: list):
+        return call[1]
+
+
+class _PairJob:
+    """One pairwise device batch between its form and its deliver."""
+
+    __slots__ = ("group", "n", "padded", "ordinal", "budget", "traced",
+                 "stages", "images", "call", "out", "err", "attempts",
+                 "t_exec0", "ahead")
+
+    def __init__(self, group, ordinal: int, budget: list):
+        self.group, self.n = group, len(group)
+        self.ordinal = ordinal            # MicroBatcher.device_batches
+        self.budget = budget              # engine calls left, a 1-list
+        self.traced = [r for r in group if r.trace is not None]
+        self.stages: list = []            # the engine's stages of its calls
+        self.images = self.call = self.out = self.err = None
+        self.attempts = 0
+        # placed before the batch in front of it was ready
+        self.ahead = False
+
+
 class MicroBatcher:
     def __init__(self, queue: RequestQueue, run_fn: Callable,
                  pad_batch_to: Callable[[int], int], max_batch: int,
@@ -111,7 +166,11 @@ class MicroBatcher:
                  retry_backoff_s: float = 0.02, on_crash=None,
                  ragged: bool = False, ragged_batch_pixels: int = 0):
         self.queue = queue
-        self.run_fn = run_fn
+        # the pair engine: an object with the phases of a device call
+        # (place, dispatch, ready, wait, fetch: serving/engine.py), which
+        # the loop overlaps two deep, or one blocking callable
+        self.engine = (run_fn if hasattr(run_fn, "dispatch")
+                       else _BlockingCall(run_fn))
         # ragged mixed-resolution mode (SERVING.md "Ragged serving"):
         # every pairwise request is queued under the shared max-box
         # bucket (so the FIFO coalesces across resolutions for free) and
@@ -146,6 +205,10 @@ class MicroBatcher:
         self.served = 0
         self.timed_out = 0
         self._inflight_batch = None       # the popped-but-unresolved batch
+        self._running: Optional[_PairJob] = None   # dispatched, not fetched
+        # the padded batch's two host buffers, grown to the largest batch
+        # seen and viewed at each batch's shape (made on first use)
+        self._pad_bytes = [np.empty(0, np.uint8), np.empty(0, np.uint8)]
         self._thread = self._new_thread()
 
     def _new_thread(self) -> threading.Thread:
@@ -217,6 +280,12 @@ class MicroBatcher:
         for _kind, _span, label, c0, c1 in calls:
             self._stage_seconds(label, c1 - c0)
         return calls
+
+    def _count_stages(self, job: "_PairJob") -> None:
+        """:meth:`_take_device_stages` for a pairwise batch, whose stages
+        have gathered in its own slot while another batch's interleaved."""
+        tlm_spans.set_device_slot(job.stages)
+        self._take_device_stages()
 
     @staticmethod
     def _device_spans(tr, calls, parent: str) -> None:
@@ -462,6 +531,8 @@ class MicroBatcher:
     def _execute(self, batch) -> None:
         op = getattr(batch[0], "stream_op", None)
         if op is not None:
+            # a session's step comes after everything dequeued before it
+            self._finish_running()
             if op == "advance" and self.stream_group_fn is not None:
                 self._execute_stream_group(batch)
             else:
@@ -474,83 +545,200 @@ class MicroBatcher:
                     r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
             groups = self._chunks(batch)
         for group in groups:
-            self._run_group(group, [self._bisect_budget(len(group))])
+            self._pipeline(group)
 
-    def _run_group(self, group, budget, formed: bool = False) -> None:
-        """Run one same-bucket group; on persistent engine failure, split
-        and retry halves so only the guilty request(s) fail.  ``budget``
-        is the batch-wide engine-call allowance (mutable 1-list);
-        ``formed`` marks bisection sub-groups (the batch_form span is
-        recorded once, on the original group)."""
-        self._next_device_batch()
-        with host_stage("raft.batch.form", self._stage_done) as st:
-            n = len(group)
-            padded = self.pad_batch_to(min(n, self.max_batch))
-            traced = [r for r in group if r.trace is not None]
+    def _pipeline(self, group) -> None:
+        """The good path of a pairwise group, two deep: form, pad and place
+        it while the batch before it runs, dispatch it the moment that one
+        is ready, and only then fetch and deliver that one.  A call that
+        failed is recovered (:meth:`_settle`) with the device to itself:
+        the batch before it before this one is dispatched, this one after
+        the batch before it is delivered."""
+        job = self._form(group, [self._bisect_budget(len(group))])
+        self._place(job)
+        running = self._running
+        if running is not None:
+            job.ahead = job.err is None \
+                and not self.engine.ready(running.call)
+            self._wait(running)
+            if running.err is not None:
+                self._settle(running)
+                self._running = running = None
+        self._dispatch(job)
+        if running is not None:
+            self._fetch(running)
+            if running.err is not None:
+                self._wait(job)
+            self._settle(running)
+        self._running = job if job.err is None else None
+        if job.err is not None:
+            self._settle(job)
+
+    def _finish_running(self) -> None:
+        """Wait for the running batch, if there is one, and deliver it."""
+        running = self._running
+        if running is not None:
+            self._wait(running)
+            self._fetch(running)
+            self._settle(running)
+            self._running = None
+
+    def _run_group(self, group, budget) -> None:
+        """One half of a bisected group from pad to deliver, nothing beside
+        it.  ``budget`` is the batch-wide engine-call allowance (mutable
+        1-list)."""
+        job = self._form(group, budget, formed=True)
+        self._call(job)
+        self._settle(job)
+
+    def _form(self, group, budget, formed: bool = False) -> "_PairJob":
+        """A device batch begins: its ordinal, its form and pad stages.
+        ``formed`` marks a bisection's sub-group (batch size and the
+        batch_form span are recorded once, on the original group)."""
+        self.device_batches += 1
+        job = _PairJob(group, self.device_batches, budget)
+        job.padded = self.pad_batch_to(min(job.n, self.max_batch))
+        self._work_on(job)
+        with host_stage("raft.batch.form", self._stage_done) as form:
             if not formed:
-                self._observe("batch_size", float(n))
-                self._observe("batch_occupancy", n / padded)
-                self._observe_waste(group, padded)
-        if not formed:
-            # a request's batch_form runs from ITS dequeue to here: the
-            # form stage is everything between take and pad (the loop's
-            # bookkeeping and _execute's are under the same annotation)
-            for r in traced:
-                r.trace.span(st.span, r.dequeued_at, st.t1, group=n)
+                self._observe("batch_size", float(job.n))
+                self._observe("batch_occupancy", job.n / job.padded)
+                self._observe_waste(group, job.padded)
+        pad = self._pad(job)
+        # the spans after both stages, so that nothing lies between them: a
+        # request's batch_form runs from ITS dequeue to the form stage's end
+        # (everything between take and pad: the loop's bookkeeping and
+        # _execute's are under the same annotation), and its execute begins
+        # where its pad ends
+        for r in job.traced:
+            if not formed:
+                r.trace.span(form.span, r.dequeued_at, form.t1, group=job.n)
+            r.trace.span(pad.span, pad.t0, pad.t1, padded=job.padded)
+        job.t_exec0 = pad.t1
+        return job
+
+    def _pad(self, job: "_PairJob"):
+        """Write the group's pairs, and the last one again up to the batch
+        step, into the two buffers this batcher keeps: fresh ones of a
+        third of a GB a batch cost more in page faults than the copy.  They
+        hold a batch from here until its ``place`` has returned.
+
+        The buffers are channel-planar ([n, 3, H, W]) and the engine gets
+        their [n, H, W, 3] views: planar is how the chip keeps an image
+        (W on the lanes, no padded channel), so the runtime only tiles what
+        it is handed.  From an interleaved array it gathers every third
+        float in chunks, and writes an event for each chunk into a profiler
+        capture: 2.3 M a batch, minutes of ``stop_trace`` (PERF.md §5)."""
         with host_stage("raft.batch.pad", self._stage_done) as st:
-            im1 = np.concatenate([r.image1 for r in group]
-                                 + [group[-1].image1] * (padded - n))
-            im2 = np.concatenate([r.image2 for r in group]
-                                 + [group[-1].image2] * (padded - n))
-        for r in traced:
-            r.trace.span(st.span, st.t0, st.t1, padded=padded)
-        out, err, attempts = None, None, 0
-        t_exec0 = time.monotonic()
-        while attempts <= self.retries and budget[0] > 0:
-            attempts += 1
-            budget[0] -= 1
-            self._device_call(n, padded)
-            try:
-                if self.ragged:
-                    # per-row live sizes from each request's routed
-                    # bucket; filler rows repeat the last request's, to
-                    # match its repeated pixels
-                    rb = ([r.rbucket for r in group]
-                          + [group[-1].rbucket] * (padded - n))
-                    out = self.run_fn(group[0].bucket, im1, im2,
-                                      np.asarray(rb, np.int32))
-                else:
-                    out = self.run_fn(group[0].bucket, im1, im2)
-            except Exception as e:
-                # transient device errors heal under a short backoff;
-                # persistent ones fall through to bisection below
-                if self.breaker is not None:
-                    self.breaker.record(False)
-                err = e
-                if attempts <= self.retries and budget[0] > 0:
-                    time.sleep(self.retry_backoff_s)
-                continue
-            except BaseException as e:
-                # shutdown (KeyboardInterrupt/SystemExit): fail the group
-                # so no handler hangs, then keep propagating — swallowing
-                # it here would eat Ctrl-C.  Same type per waiter, but a
-                # FRESH instance each (_fresh_error)
-                t_x = time.monotonic()
-                self._take_device_stages()
-                sid = tlm_spans.new_span_id()
-                for r in group:
-                    if r.trace is not None:
-                        r.trace.span("execute", t_exec0, t_x,
-                                     status=tlm_spans.ERROR, span_id=sid,
-                                     batch_real=n, batch_padded=padded)
-                    self._observe("requests", "error", 1)
-                    r.fail(_fresh_error(e))
-                raise
+            h, w, c = job.group[0].image1.shape[1:]
+            dtype = job.group[0].image1.dtype
+            nbytes = job.padded * c * h * w * dtype.itemsize
+            job.images = []
+            for i, attr in enumerate(("image1", "image2")):
+                if self._pad_bytes[i].size < nbytes:
+                    self._pad_bytes[i] = np.empty(nbytes, np.uint8)
+                buf = self._pad_bytes[i][:nbytes].view(dtype).reshape(
+                    job.padded, c, h, w)
+                for k, r in enumerate(job.group):
+                    np.copyto(buf[k], getattr(r, attr)[0].transpose(2, 0, 1))
+                buf[job.n:] = buf[job.n - 1]
+                job.images.append(buf.transpose(0, 2, 3, 1))
+        return st
+
+    def _work_on(self, job: "_PairJob") -> None:
+        """This thread's stages are ``job``'s from here on: its ordinal
+        rides on every host stage opened (``batch=<n>`` of the
+        annotations), and the engine's stages land in its slot."""
+        set_batch(job.ordinal)
+        tlm_spans.set_device_slot(job.stages)
+
+    def _phase(self, job: "_PairJob", fn, *args):
+        """One phase of ``job``'s device call, skipped once the call has
+        failed.  An exception fails the call, not the thread."""
+        if job.err is not None:
+            return None
+        self._work_on(job)
+        try:
+            return fn(*args)
+        except Exception as e:
+            # transient device errors heal under a short backoff;
+            # persistent ones fall through to bisection (_settle)
             if self.breaker is not None:
-                self.breaker.record(True)
-            err = None
-            break
-        calls = self._take_device_stages()
+                self.breaker.record(False)
+            job.err = e
+        except BaseException as e:
+            # shutdown (KeyboardInterrupt/SystemExit): fail the group
+            # so no handler hangs, then keep propagating — swallowing
+            # it here would eat Ctrl-C.  Same type per waiter, but a
+            # FRESH instance each (_fresh_error)
+            t_x = time.monotonic()
+            self._count_stages(job)
+            sid = tlm_spans.new_span_id()
+            for r in job.group:
+                if r.trace is not None:
+                    r.trace.span("execute", job.t_exec0, t_x,
+                                 status=tlm_spans.ERROR, span_id=sid,
+                                 batch_real=job.n, batch_padded=job.padded)
+                self._observe("requests", "error", 1)
+                r.fail(_fresh_error(e))
+            raise
+        return None
+
+    def _place(self, job: "_PairJob") -> None:
+        """An attempt begins: the padded pair goes to the device."""
+        if job.budget[0] <= 0:
+            job.err = RuntimeError("bisection budget exhausted before this "
+                                   "sub-group could execute")
+            return
+        job.attempts += 1
+        job.budget[0] -= 1
+        self._device_call(job.n, job.padded)
+        args = (job.group[0].bucket, *job.images)
+        if self.ragged:
+            # per-row live sizes from each request's routed bucket;
+            # filler rows repeat the last request's, to match its
+            # repeated pixels
+            rb = ([r.rbucket for r in job.group]
+                  + [job.group[-1].rbucket] * (job.padded - job.n))
+            args += (np.asarray(rb, np.int32),)
+        job.call = self._phase(job, self.engine.place, *args)
+
+    def _dispatch(self, job: "_PairJob") -> None:
+        self._phase(job, self.engine.dispatch, job.call)
+        if job.err is None:
+            self._observe("batches_staged",
+                          "ahead" if job.ahead else "late", 1)
+
+    def _wait(self, job: "_PairJob") -> None:
+        self._phase(job, self.engine.wait, job.call)
+
+    def _fetch(self, job: "_PairJob") -> None:
+        job.out = self._phase(job, self.engine.fetch, job.call)
+        if job.err is None and self.breaker is not None:
+            self.breaker.record(True)
+
+    def _call(self, job: "_PairJob") -> None:
+        """One attempt with nothing beside it: every phase in turn."""
+        self._place(job)
+        self._dispatch(job)
+        self._wait(job)
+        self._fetch(job)
+
+    def _settle(self, job: "_PairJob") -> None:
+        """The end of a device batch: deliver its rows — after the retries
+        of a failed call, and the bisection of one that keeps failing, so
+        that only the guilty request(s) fail."""
+        group, n, padded, budget = job.group, job.n, job.padded, job.budget
+        while (job.err is not None and 0 < job.attempts <= self.retries
+               and budget[0] > 0):
+            time.sleep(self.retry_backoff_s)
+            job.err, job.ahead = None, False
+            self._work_on(job)
+            self._pad(job)            # the buffers have held a batch since
+            self._call(job)
+        err, attempts = job.err, job.attempts
+        self._work_on(job)
+        self._count_stages(job)
         t_exec1 = time.monotonic()
         # co-batched requests SHARE one execute span id (the join key
         # across their traces); each trace holds its own copy with its
@@ -558,15 +746,11 @@ class MicroBatcher:
         exec_sid = tlm_spans.new_span_id()
 
         def _exec_span(tr, status):
-            tr.span("execute", t_exec0, t_exec1, status=status,
+            tr.span("execute", job.t_exec0, t_exec1, status=status,
                     span_id=exec_sid, batch_real=n, batch_padded=padded,
                     attempts=attempts)
-            self._device_spans(tr, calls, exec_sid)
+            self._device_spans(tr, job.stages, exec_sid)
 
-        if out is None and err is None:
-            # budget ran dry before this sub-group got a single attempt
-            err = RuntimeError("bisection budget exhausted before this "
-                               "sub-group could execute")
         if err is not None:
             if n == 1 and attempts:
                 # bisected down to the guilty request: the 'poisoned'
@@ -596,18 +780,14 @@ class MicroBatcher:
                 return
             # the failed attempt stays visible in every trace (status
             # "retry"); the sub-groups record their own execute spans
-            for r in traced:
+            for r in job.traced:
                 _exec_span(r.trace, "retry")
             mid = n // 2
-            self._run_group(group[:mid], budget, formed=True)
-            self._run_group(group[mid:], budget, formed=True)
+            self._run_group(group[:mid], budget)
+            self._run_group(group[mid:], budget)
             return
         with host_stage("raft.batch.deliver", self._stage_done) as st:
-            # the padded batch is dead since the engine returned: dropped
-            # HERE, under a stage, and not when this frame dies between two
-            # of them — unmapping a third of a GB is not free
-            del im1, im2
-            served = self._deliver(group, out, padded, t_exec1, st.span,
+            served = self._deliver(group, job.out, padded, t_exec1, st.span,
                                    _exec_span)
             if served:
                 self._observe("pairs", float(served))
@@ -673,36 +853,47 @@ class MicroBatcher:
 
     def _loop(self) -> None:
         while True:
+            running = self._running
+            busy = None
+            if running is not None:
+                # a part batch waits for its mates while the device is busy
+                busy = lambda: not self.engine.ready(running.call)  # noqa: E731
             set_batch(self.device_batches + 1)    # the batch being waited for
             with host_stage("raft.batch.take", self._stage_done):
-                batch, expired = self.queue.take_batch(self.max_batch,
-                                                       self.max_wait)
+                batch, expired = self.queue.take_batch(
+                    self.max_batch, self.max_wait, busy)
+            if not batch:
+                # nothing to stage: a finished batch is never held for a
+                # batch that is not there.  (None: the queue is closed and
+                # empty; []: deadlines passed, or the running batch is ready)
+                self._fail_expired(expired)
+                if batch is None or (running is not None and not busy()):
+                    self._finish_running()
+                if batch is None:
+                    return
+                continue
             with host_stage("raft.batch.form", self._stage_done):
                 self._fail_expired(expired)
-                if batch:
-                    self.batches += 1
-                    # cleared only on the success path: an exception
-                    # escaping here must leave the batch visible to
-                    # _thread_main's crash handler (it fails whatever is
-                    # not yet done)
-                    self._inflight_batch = batch
-                    # ambient trace ids for this batch: out-of-band
-                    # diagnostics fired from under here (fault_injected,
-                    # lock_violation, the non-finite sentinel) become
-                    # joinable to the request traces they hit
-                    tlm_spans.set_current_trace_ids(tuple(
-                        r.trace.trace_id for r in batch
-                        if r.trace is not None))
-            if batch is None:        # queue closed and empty: drained
-                return
-            if batch:
-                try:
-                    if self.faults is not None:
-                        self.faults.maybe_kill()   # chaos: thread-death arm
-                    self._execute(batch)
-                finally:
-                    tlm_spans.set_current_trace_ids(())
-                self._inflight_batch = None
+                self.batches += 1
+                # cleared only on the success path: an exception
+                # escaping here must leave the batch visible to
+                # _thread_main's crash handler (it fails whatever is
+                # not yet done, of this batch and of the running one)
+                self._inflight_batch = batch
+                # ambient trace ids for this batch: out-of-band
+                # diagnostics fired from under here (fault_injected,
+                # lock_violation, the non-finite sentinel) become
+                # joinable to the request traces they hit
+                tlm_spans.set_current_trace_ids(tuple(
+                    r.trace.trace_id for r in batch
+                    if r.trace is not None))
+            try:
+                if self.faults is not None:
+                    self.faults.maybe_kill()   # chaos: thread-death arm
+                self._execute(batch)
+            finally:
+                tlm_spans.set_current_trace_ids(())
+            self._inflight_batch = None
 
     def _thread_main(self) -> None:
         try:
@@ -712,7 +903,8 @@ class MicroBatcher:
             # (handler threads must never hang on a dead batcher), then
             # hand an Exception to the supervisor for restart; shutdown
             # signals propagate — threading's excepthook reports them
-            for r in (self._inflight_batch or []):
+            running = self._running.group if self._running else []
+            for r in running + (self._inflight_batch or []):
                 if not r.done:
                     self._observe("requests", "error", 1)
                     if r.trace is not None:
@@ -725,7 +917,7 @@ class MicroBatcher:
                     r.fail(BatcherCrashed(
                         f"batcher thread died mid-batch ({e!r}); "
                         f"the supervisor restarts it — retry"))
-            self._inflight_batch = None
+            self._inflight_batch = self._running = None
             if self.on_crash is not None and isinstance(e, Exception):
                 self.on_crash(e)
             else:

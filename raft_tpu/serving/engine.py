@@ -45,6 +45,16 @@ class ReloadMismatch(ValueError):
     the swap is rejected and the engine keeps serving the old weights."""
 
 
+class PairCall:
+    """One call of a ``pair`` executable between its phases: the executable
+    and its placed arguments until the dispatch, the outputs after it."""
+
+    __slots__ = ("ex", "args", "out")
+
+    def __init__(self, ex, args: tuple):
+        self.ex, self.args, self.out = ex, args, None
+
+
 class InferenceEngine:
     """(kind, bucket, batch, iters-policy) -> compiled executable, with
     hit/miss accounting.  ``kind`` is ``"pair"`` (the /v1/flow two-frame
@@ -546,34 +556,27 @@ class InferenceEngine:
 
     # -- the device call --------------------------------------------------
 
-    def _call(self, kind: str, ex, args: tuple, put: tuple = (),
-              wait: bool = True):
+    def _call(self, kind: str, ex, args: tuple, wait: bool = True):
         """One call of a warm executable under its host stages, timed at the
         only place that can tell them apart (the executable call returns as
         soon as the work is enqueued — wall clock at the call site lies):
-        ``h2d`` places the host arrays ``args[i], i in put`` on the device,
-        the runtime's host relayout included, and waits for them (what the
-        call would transfer implicitly before the device can start);
-        ``dispatch`` is then the enqueue alone; ``wait`` blocks until the
-        outputs are ready — the device's run as the host sees it.  Each
-        stage is a ``raft.engine.*`` profiler annotation and, inside a
-        batch, a child span of ``execute`` plus stage seconds."""
-        import jax
+        ``dispatch`` is the enqueue alone; ``wait`` blocks until the outputs
+        are ready — the device's run as the host sees it.  Each stage is a
+        ``raft.engine.*`` profiler annotation and, inside a batch, a child
+        span of ``execute`` plus stage seconds."""
         sink = functools.partial(tlm_spans.record_device_stage, kind)
-        if put:
-            with host_stage("raft.engine.h2d", sink, call=kind):
-                shardings = ex.input_shardings[0]
-                placed = jax.block_until_ready(jax.device_put(
-                    [args[i] for i in put], [shardings[i] for i in put]))
-            args = list(args)
-            for i, a in zip(put, placed):
-                args[i] = a
         with host_stage("raft.engine.dispatch", sink, call=kind):
             out = ex(*args)
         if wait:
-            with host_stage("raft.engine.wait", sink, call=kind):
-                jax.block_until_ready(out)
+            self._block(kind, out)
         return out
+
+    def _block(self, kind: str, out) -> None:
+        """Until ``out`` is ready (``raft.engine.wait``)."""
+        import jax
+        sink = functools.partial(tlm_spans.record_device_stage, kind)
+        with host_stage("raft.engine.wait", sink, call=kind):
+            jax.block_until_ready(out)
 
     def _fetch(self, kind: str, *arrays) -> tuple:
         """Ready device arrays -> host numpy (``raft.engine.fetch``)."""
@@ -590,15 +593,21 @@ class InferenceEngine:
             return np.tile(np.asarray([[h, w]], np.int32), (n, 1))
         return np.asarray(sizes, np.int32)
 
-    def run(self, bucket: Tuple[int, int], im1: np.ndarray,
-            im2: np.ndarray, sizes=None):
-        """[n, BH, BW, 3] float32 pair -> [n, BH, BW, 2] float32 flow.
-        ``n`` must be a declared batch step (the batcher pads to one).
-        Under a converge policy returns (flow, iters_used [n] int32) —
-        the batcher passes per-row counts through to each request.
-        ``sizes`` ([n, 2] int32) is required-by-convention in ragged mode:
-        per-row live extents inside the max-box ``bucket`` (None = all rows
-        full box); ignored in dense mode."""
+    # -- a pair call, one phase at a time ----------------------------------
+    #
+    # ``run`` is place -> dispatch -> wait -> fetch.  The batcher calls the
+    # phases apart (serving/batcher.py): it places batch n+1 while batch n
+    # runs, and dispatches it before it fetches n.
+
+    def place(self, bucket: Tuple[int, int], im1: np.ndarray,
+              im2: np.ndarray, sizes=None) -> "PairCall":
+        """``h2d``: put the padded pair on the device, the runtime's host
+        relayout included, and wait for it (what the call would transfer
+        implicitly before the device can start).  Once this returns the
+        caller may rewrite ``im1`` / ``im2``.  The runtime reads them by
+        their strides: the batcher hands over views of channel-planar
+        buffers, which the relayout has only to tile."""
+        import jax
         h, w = bucket
         n = im1.shape[0]
         ex = self._get_executable(self._key(h, w, n))
@@ -606,10 +615,39 @@ class InferenceEngine:
             self.pair_calls += 1
         if self.faults is not None:
             self.faults.pre_engine_call()
+        sink = functools.partial(tlm_spans.record_device_stage, "pair")
+        with host_stage("raft.engine.h2d", sink, call="pair"):
+            if jax.default_backend() == "cpu":
+                # the CPU client aliases a host array it finds aligned
+                # instead of copying it: give it one nobody rewrites
+                im1, im2 = np.array(im1), np.array(im2)
+            shardings = ex.input_shardings[0]
+            im1, im2 = jax.block_until_ready(jax.device_put(
+                [im1, im2], [shardings[1], shardings[2]]))
         args = (self.params, im1, im2)
         if self.ragged:
             args += (self._sizes_arg(n, sizes),)
-        out = self._call("pair", ex, args, put=(1, 2))
+        return PairCall(ex, args)
+
+    def dispatch(self, call: "PairCall") -> None:
+        """Enqueue a placed call; returns before the device has run it."""
+        call.out = self._call("pair", call.ex, call.args, wait=False)
+        call.args = None              # the device holds what it needs
+
+    def ready(self, call: "PairCall") -> bool:
+        """Has a dispatched call finished?  Never blocks."""
+        import jax
+        return all(a.is_ready() for a in jax.tree.leaves(call.out))
+
+    def wait(self, call: "PairCall") -> None:
+        """Block until a dispatched call's outputs are ready."""
+        self._block("pair", call.out)
+
+    def fetch(self, call: "PairCall"):
+        """A finished call's outputs on the host: the flow, or (flow,
+        iters_used [n] int32) under a converge policy; the key-block counts
+        that ride beside them go to ``corr_keyblocks``."""
+        out = call.out
         # flow[, iters_used][, key-block counts]: a bare array when alone
         outs = out if isinstance(out, (tuple, list)) else (out,)
         flow, *rest = self._fetch("pair", *outs)
@@ -622,6 +660,24 @@ class InferenceEngine:
         if self.faults is not None:
             flow = self.faults.corrupt_rows(flow)
         return flow if iters_used is None else (flow, iters_used)
+
+    def run(self, bucket: Tuple[int, int], im1: np.ndarray,
+            im2: np.ndarray, sizes=None):
+        """[n, BH, BW, 3] float32 pair -> [n, BH, BW, 2] float32 flow.
+        ``n`` must be a declared batch step (the batcher pads to one).
+        Under a converge policy returns (flow, iters_used [n] int32) —
+        the batcher passes per-row counts through to each request.
+        ``sizes`` ([n, 2] int32) is required-by-convention in ragged mode:
+        per-row live extents inside the max-box ``bucket`` (None = all rows
+        full box); ignored in dense mode."""
+        call = self.place(bucket, im1, im2, sizes)
+        self.dispatch(call)
+        self.wait(call)
+        return self.fetch(call)
+
+    # the server pipelines an engine through its phases only where ``run``
+    # is this composition of them (server.FlowServer._pair_engine)
+    run.composes_phases = True
 
     def run_encode(self, bucket: Tuple[int, int], image: np.ndarray):
         """[1, BH, BW, 3] float32 frame -> DEVICE-resident (fmap, cnet)

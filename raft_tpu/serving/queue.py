@@ -139,6 +139,10 @@ class RequestQueue:
     _size = guarded_by("_lock")
     _closed = guarded_by("_lock")
 
+    # how often a take that a running device batch holds back looks whether
+    # the batch has finished
+    BUSY_POLL_S = 0.001
+
     def __init__(self, depth: int):
         self.depth = depth
         self._lock = watched_lock("RequestQueue._lock")
@@ -191,7 +195,7 @@ class RequestQueue:
         self._size -= len(expired)
         return expired
 
-    def take_batch(self, max_batch: int, max_wait: float):
+    def take_batch(self, max_batch: int, max_wait: float, busy=None):
         """Batcher side: block until a batch is ready, then pop it.
 
         Returns (batch, expired) where ``batch`` is a same-bucket FIFO run
@@ -201,6 +205,16 @@ class RequestQueue:
         when some bucket holds max_batch requests, when the oldest waiting
         request has aged ``max_wait`` seconds, or when the queue is closed
         (drain: flush immediately, ignore max_wait).
+
+        ``busy`` (a callable, or None for an idle device) says whether the
+        device is still running the batch before this one.  While it is,
+        waiting costs the device nothing, so an aged part batch stays
+        queued for its mates (a full bucket and a drain pop as ever); the
+        moment ``busy()`` turns false the call returns ``([], expired)``
+        without popping a part batch, and the batcher, having delivered
+        what finished, takes again under the rule above.  There
+        is no event to wait on for a device, so ``busy`` is polled every
+        ``BUSY_POLL_S`` between submissions.
         """
         with self._lock:
             while True:
@@ -213,11 +227,12 @@ class RequestQueue:
                     head = fifo[0].enqueued_at
                     if best is None or head < best_head:
                         best, best_head = bucket, head
+                held = busy is not None and busy()
                 if best is not None:
                     fifo = self._by_bucket[best]
                     full = len(fifo) >= max_batch
                     aged = now - best_head >= max_wait
-                    if full or aged or self._closed:
+                    if full or self._closed or (aged and busy is None):
                         batch = fifo[:max_batch]
                         rest = fifo[len(batch):]
                         if rest:
@@ -233,10 +248,11 @@ class RequestQueue:
                     return None, expired
                 else:
                     timeout = None
-                if expired:
-                    # deliver timeouts promptly rather than after the wait
+                if expired or (busy is not None and not held):
+                    # deliver timeouts promptly rather than after the wait;
+                    # and what the device has finished
                     return [], expired
-                self._cond.wait(timeout)
+                self._cond.wait(self.BUSY_POLL_S if held else timeout)
 
     def drain_remaining(self) -> List[Request]:
         """Pop everything still queued (used on hard shutdown)."""

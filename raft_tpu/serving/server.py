@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import threading
 import time
+import types
 from typing import Optional
 
 import numpy as np
@@ -265,7 +266,7 @@ class FlowServer:
             pool=self.streams.pool if self.streams else None,
             cache=self.engine_cache)
         self.batcher = MicroBatcher(
-            self.queue, self._run_engine, sconfig.pad_batch_to,
+            self.queue, self._pair_engine(), sconfig.pad_batch_to,
             sconfig.max_batch, sconfig.max_wait_ms, metrics=self.metrics,
             stream_fn=self._run_stream if self.streams else None,
             stream_group_fn=(self._run_stream_group if self.streams
@@ -288,12 +289,14 @@ class FlowServer:
     # -- engine bridge (compile-cache accounting lives server-side so a
     #    stub engine still produces miss metrics when it exposes them) -----
 
-    def _device_step(self, scope: str, fn, *args):
+    def _device_step(self, scope: str, fn, *args, tick: bool = True):
         """One device step of the batcher, whatever its kind: the trace
         window's tick, the named scope (so a compile is attributable), and
-        the serve-time compile misses it caused."""
-        self._trace_window.on_step(self._device_batches)
-        self._device_batches += 1
+        the serve-time compile misses it caused.  A step walked one phase
+        at a time ticks once, with its dispatch."""
+        if tick:
+            self._trace_window.on_step(self._device_batches)
+            self._device_batches += 1
         before = getattr(self.engine, "compile_misses", None)
         blocks = tuple(getattr(self.engine, "corr_keyblocks", ()))
         with stage(scope):
@@ -315,6 +318,25 @@ class FlowServer:
         args = (bucket, im1, im2) if sizes is None else (bucket, im1, im2,
                                                          sizes)
         return self._device_step("serve/batch", self.engine.run, *args)
+
+    def _pair_engine(self):
+        """What the batcher runs pairwise batches with: the engine's phases,
+        each behind :meth:`_device_step`, which it overlaps two deep — or
+        ``run`` as one blocking call, where ``run`` is not the engine's own
+        composition of those phases (a stub's, a subclass's, a test's
+        patch: whoever put it there means it to be on the path)."""
+        engine = self.engine
+        if not getattr(getattr(engine, "run", None), "composes_phases",
+                       False):
+            return self._run_engine
+
+        def step(phase, tick=False):
+            return lambda *args: self._device_step("serve/batch", phase,
+                                                   *args, tick=tick)
+
+        return types.SimpleNamespace(
+            place=step(engine.place), dispatch=step(engine.dispatch, True),
+            ready=engine.ready, wait=engine.wait, fetch=step(engine.fetch))
 
     def _run_stream(self, req):
         """One solo session step (open, or the no-group fallback)."""

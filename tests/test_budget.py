@@ -241,6 +241,45 @@ def test_pair_footprint_scales_with_batch(config, pspecs):
                                capacity=1)
     assert f2["input_bytes"] == 2 * f1["input_bytes"]
     assert f2["transient_bytes"] > f1["transient_bytes"]
+    # the batcher keeps a second batch's inputs on the device beside the
+    # running one, and the running one's flow beside the next one's
+    for f in (f1, f2):
+        assert f["staged_bytes"] == f["input_bytes"] + f["output_bytes"]
+        assert f["transient_bytes"] == 2 * f["staged_bytes"]
+    enc = budget.kind_footprint(config, pspecs, ("encode", h, w, 1, "fixed"),
+                                capacity=1)
+    assert enc["staged_bytes"] == 0       # only pair calls are pipelined
+
+
+# ``peak_hbm_gb`` of the benchmark's two cells on the v5e with the batcher's
+# second batch staged: memory_stats()'s peak_bytes_in_use +
+# peak_bytes_reserved after the window, the medians of 6.544-6.581 over six
+# runs and of 7.536-7.595 over five (my chip runs, PR 27: PERF.md section
+# 5; the parent read 6.20-6.24 and 7.14-7.20).  The analyzer gives 6.58 and
+# 7.57; peak_bytes_in_use alone read 0.917 and 1.061 GB where two sets of
+# inputs and outputs are 0.923 and 1.062.
+@pytest.mark.parametrize("name,measured_gb", [
+    ("raft-things", 6.56), ("raft-things-1080p", 7.54)])
+def test_analyzer_prices_the_benchmark_configurations(name, measured_gb):
+    """The chip at its fullest under the pipelined batcher: params, the
+    largest pair program's temporaries, its inputs and outputs and a second
+    set of both — under the v5e's 16 GB and within 0.1 GB of what the chip
+    reported."""
+    from raft_tpu import cli
+    from raft_tpu.serving.config import parse_buckets
+    conf = json.loads((REPO / "benchmark" / "configs" / f"{name}.json")
+                      .read_text())
+    args = cli.parse_args(["-m", "serve"]
+                          + [str(a) for a in conf["serve_args"]])
+    sconfig = ServeConfig(buckets=parse_buckets(args.buckets),
+                          max_batch=args.max_batch,
+                          max_sessions=args.max_sessions)
+    report = budget.analyze(cli._make_config(args), sconfig,
+                            device_kind="tpu-v5e")
+    assert not report["violations"]
+    priced = report["totals"]["peak_with_pair_temps_bytes"]
+    assert priced < report["totals"]["hbm_budget_bytes"] == 16 * 1024 ** 3
+    assert abs(priced / 1e9 - measured_gb) < 0.1, priced
 
 
 def test_analyze_report_shape_and_headroom_monotone(config):

@@ -26,7 +26,8 @@ from raft_tpu.serving import (BatcherCrashed, BreakerOpen, ChaosSpec,
 from raft_tpu.serving.batcher import MicroBatcher
 from raft_tpu.serving.metrics import make_serving_metrics
 
-from test_serving import BUCKET, StubEngine, make_request
+from test_serving import (BUCKET, PhasedEngine, StubEngine,
+                          make_phased_stack, make_request)
 
 
 # ------------------------------------------------------------ faults.py --
@@ -374,6 +375,115 @@ def test_nan_output_row_fails_alone_neighbors_succeed():
         "poisoned").value == 1
     q.close()
     b.join(5)
+
+
+# ------------------------------------ the pipeline's two batches in chaos --
+
+def _two_batches(eng, first=None, **kw):
+    """One batch dispatched and held on the fake device, the next placed
+    behind it: (queue, batcher, registry, running requests, staged)."""
+    q, b, reg = make_phased_stack(eng, max_batch=2, max_wait_ms=10_000.0,
+                                  **kw)
+    running = first or [make_request(), make_request()]
+    for r in running:
+        q.submit(r)
+    eng.saw("dispatch", 0)
+    staged = [make_request(), make_request()]
+    for r in staged:
+        q.submit(r)
+    eng.saw("h2d", 1)
+    return q, b, reg, running, staged
+
+
+def _chaos_crash_fails_running_and_staged():
+    """A crash of the thread with one batch running and one staged fails
+    both sets of requests; no handler hangs on either."""
+    class Dying(PhasedEngine):
+        def ready(self, call):              # outside every phase's guard
+            if self.has("h2d", 1):
+                raise RuntimeError("the loop itself dies")
+            return super().ready(call)
+
+    crashes = []
+    eng = Dying(hold=(0,))
+    q, b, reg, running, staged = _two_batches(eng, on_crash=crashes.append)
+    for r in running + staged:
+        with pytest.raises(BatcherCrashed):
+            r.wait(timeout=5)
+    b.join(5)
+    assert not b.alive and len(crashes) == 1
+    assert b._running is None and b._inflight_batch is None
+    assert reg.get("raft_serving_requests_total").labels("error").value == 4
+    q.close()
+
+
+def _chaos_poisoned_row_bisected_while_the_staged_batch_waits():
+    """A poisoned row in n is retried and bisected with the device to
+    itself while n+1 stays placed; n+1 is then dispatched and served whole."""
+    eng = PhasedEngine(hold=(0,))
+    innocent, guilty = make_request(), _poison_request()
+    q, b, reg, running, staged = _two_batches(
+        eng, first=[innocent, guilty], retries=1, retry_backoff_s=0.001)
+    eng.finish(0)
+    assert innocent.wait(timeout=10).shape == (32, 48, 2)
+    with pytest.raises(PoisonedRequest, match="poisons its batch"):
+        guilty.wait(timeout=10)
+    for r in staged:
+        assert r.wait(timeout=10).shape == (32, 48, 2)
+        assert (r.batch_real, r.batch_padded) == (2, 2)
+    # call 1 is the staged batch: placed second, dispatched after the
+    # retry of call 0 (call 2: re-padded, so it still holds the poison),
+    # the innocent half (3) and the guilty one's two attempts (4, 5)
+    assert [n for _, n in eng.calls] == [2, 2, 2, 1, 1, 1]
+    assert [c.poisoned for c in eng.issued] == [True, False, True, False,
+                                                True, True]
+    t_dispatch1 = eng.saw("dispatch", 1)
+    assert all(eng.saw("wait", i) < t_dispatch1 for i in (0, 2, 3, 4, 5))
+    assert reg.get("raft_serving_requests_total").labels("ok").value == 3
+    q.close()
+    b.join(5)
+
+
+def _chaos_stream_step_behind_a_running_batch():
+    """A streaming batch popped behind a running pairwise batch sees it
+    delivered first."""
+    import types
+
+    from raft_tpu.serving.stream import StreamRequest
+    eng = PhasedEngine(run_s=0.15)
+    seen = []
+
+    def stream_group(group):
+        seen.append([r.done for r in pairs])
+        flow = np.zeros((1, 32, 48, 2), np.float32)
+        return [(flow, None, None) for _ in group]
+
+    q, b, reg = make_phased_stack(eng, max_batch=2, max_wait_ms=10_000.0,
+                                  stream_group_fn=stream_group)
+    pairs = [make_request(), make_request()]
+    for r in pairs:
+        q.submit(r)
+    eng.saw("dispatch", 0)
+    im = np.zeros((1, 32, 48, 3), np.float32)
+    steps = [StreamRequest(types.SimpleNamespace(id=i, bucket=BUCKET),
+                           "advance", im, (0, 0, 0, 0),
+                           time.monotonic() + 30.0) for i in range(2)]
+    for r in steps:
+        q.submit(r)                         # a full bucket: popped at once
+    for r in steps + pairs:
+        r.wait(timeout=10)
+    assert seen == [[True, True]]
+    q.close()
+    b.join(5)
+
+
+@pytest.mark.parametrize("case", [
+    _chaos_crash_fails_running_and_staged,
+    _chaos_poisoned_row_bisected_while_the_staged_batch_waits,
+    _chaos_stream_step_behind_a_running_batch],
+    ids=lambda f: f.__name__[7:])
+def test_pipeline_contains_failures(case):
+    case()
 
 
 # ------------------------------------------------- breaker integration ---

@@ -434,6 +434,35 @@ def test_live_http_error_statuses(live_server):
     assert st == 404
 
 
+def test_live_engine_is_driven_through_its_phases(live_server, monkeypatch):
+    """The server pipelines the real engine through place .. fetch, whose
+    composition ``run`` is (the same flow either way, no compile); an
+    engine whose ``run`` was replaced is called through that ``run``."""
+    from raft_tpu.serving.batcher import _BlockingCall
+    from raft_tpu.serving.engine import InferenceEngine
+    server, _, _ = live_server
+    eng = server.batcher.engine
+    assert not isinstance(eng, _BlockingCall)
+    assert eng.ready == server.engine.ready
+    rng = np.random.RandomState(3)
+    im1, im2 = (rng.rand(2, 32, 48, 3).astype(np.float32) for _ in "12")
+    misses, calls = server.engine.compile_misses, server.engine.pair_calls
+    whole = server.engine.run((32, 48), im1, im2)
+    call = eng.place((32, 48), im1.copy(), im2.copy())
+    eng.dispatch(call)
+    eng.wait(call)
+    assert eng.ready(call)
+    np.testing.assert_array_equal(eng.fetch(call), whole)
+    assert server.engine.pair_calls == calls + 2
+    assert server.engine.compile_misses == misses
+    sound = InferenceEngine.run
+    monkeypatch.setattr(InferenceEngine, "run",
+                        lambda self, *a, **kw: sound(self, *a, **kw) + 0.25)
+    replaced = server._pair_engine()
+    assert replaced == server._run_engine
+    np.testing.assert_array_equal(replaced((32, 48), im1, im2), whole + 0.25)
+
+
 def test_live_healthz_and_metrics(live_server):
     server, _, _ = live_server
     with urllib.request.urlopen(server.url + "/healthz") as r:
@@ -669,6 +698,247 @@ def test_live_converge_policy_end_to_end():
         assert server.engine.compile_misses == 0
     finally:
         server.stop()
+
+
+# ----------------------------------- the two-deep pipeline (fake phases) --
+
+class PhasedEngine:
+    """A recording fake of the engine's phases of a pair call (place,
+    dispatch, ready, wait, fetch: serving/engine.py), no device: a call
+    "runs" from its dispatch for ``run_s`` seconds, or, if its index (the
+    order of the places) is in ``hold``, until the test ``finish``es it.
+    ``log`` is the (phase, call index, time) record the order assertions
+    read.  A row whose first pixel is >= 1.0 fails every call it is in
+    (at ``wait``, where a device error surfaces)."""
+
+    def __init__(self, run_s=0.0, hold=()):
+        self.run_s, self.hold = run_s, set(hold)
+        self.calls = []                   # (bucket, rows) per place
+        self.issued = []
+        self.log = []
+        self.cv = threading.Condition()
+
+    def _note(self, phase, i):
+        with self.cv:
+            self.log.append((phase, i, time.monotonic()))
+            self.cv.notify_all()
+
+    def saw(self, phase, i, timeout=10.0):
+        """Block until ``phase`` of call ``i`` has ended; its time."""
+        with self.cv:
+            assert self.cv.wait_for(lambda: any(
+                e[:2] == (phase, i) for e in self.log), timeout), (phase, i)
+            return next(e[2] for e in self.log if e[:2] == (phase, i))
+
+    def has(self, phase, i):
+        with self.cv:
+            return any(e[:2] == (phase, i) for e in self.log)
+
+    def place(self, bucket, im1, im2, sizes=None):
+        import types
+        call = types.SimpleNamespace(
+            i=len(self.calls), shape=im1.shape, done=threading.Event(),
+            poisoned=bool((im1[:, 0, 0, 0] >= 1.0).any()))
+        self.calls.append((bucket, im1.shape[0]))
+        self.issued.append(call)
+        self._note("h2d", call.i)
+        return call
+
+    def dispatch(self, call):
+        if call.i not in self.hold:
+            if self.run_s:
+                threading.Timer(self.run_s, call.done.set).start()
+            else:
+                call.done.set()
+        self._note("dispatch", call.i)
+
+    def ready(self, call):
+        return call.done.is_set()
+
+    def wait(self, call):
+        assert call.done.wait(30)
+        self._note("wait", call.i)
+        if call.poisoned:
+            raise RuntimeError("device rejected the poisoned row")
+
+    def fetch(self, call):
+        self._note("fetch", call.i)
+        return np.zeros(call.shape[:3] + (2,), np.float32)
+
+    def finish(self, i):
+        self.issued[i].done.set()
+
+
+def make_phased_stack(eng, max_batch=2, max_wait_ms=5.0, **kw):
+    from raft_tpu.serving.metrics import make_serving_metrics
+    q = RequestQueue(64)
+    reg = Registry()
+    sc = ServeConfig(buckets=(BUCKET,), max_batch=max_batch,
+                     max_wait_ms=max_wait_ms)
+    b = MicroBatcher(q, eng, sc.pad_batch_to, max_batch, max_wait_ms,
+                     metrics=make_serving_metrics(reg, sc), **kw)
+    b.start()
+    return q, b, reg
+
+
+def _staged(reg, when):
+    return reg.get("raft_serving_batches_staged_total").labels(when).value
+
+
+def _submit(q, n):
+    reqs = [make_request() for _ in range(n)]
+    for r in reqs:
+        q.submit(r)
+    return reqs
+
+
+def _pipeline_stages_ahead():
+    """With n running: h2d of n+1 ends before wait of n returns, dispatch
+    of n+1 precedes fetch and deliver of n, and with nothing staged behind
+    it n+1 is delivered the moment it is ready, without a take."""
+    eng = PhasedEngine(hold=(0, 1))
+    q, b, reg = make_phased_stack(eng, max_batch=2, max_wait_ms=10_000.0)
+    first = _submit(q, 2)
+    eng.saw("dispatch", 0)
+    second = _submit(q, 2)
+    t_h2d1 = eng.saw("h2d", 1)              # placed while call 0 "runs"
+    assert not eng.has("wait", 0) and not any(r.done for r in first)
+    eng.finish(0)
+    for r in first:
+        r.wait(timeout=10)
+    order = [e[:2] for e in eng.log]
+    assert order == [("h2d", 0), ("dispatch", 0), ("h2d", 1), ("wait", 0),
+                     ("dispatch", 1), ("fetch", 0)]
+    assert t_h2d1 < eng.saw("wait", 0) <= eng.saw("dispatch", 1) \
+        <= min(r.finished_at for r in first)
+    # nothing is queued and max_wait is 10 s: only "the running batch is
+    # ready" can end the take that is waiting now
+    assert not any(r.done for r in second)
+    eng.finish(1)
+    for r in second:
+        assert r.wait(timeout=5).shape == (32, 48, 2)
+    assert (_staged(reg, "late"), _staged(reg, "ahead")) == (1, 1)
+    assert eng.calls == [(BUCKET, 2), (BUCKET, 2)]
+    return q, b
+
+
+def _pipeline_part_batch_waits_for_its_mates():
+    """A take during a run does not return a part batch before the run is
+    ready, and returns at once when the bucket fills."""
+    eng = PhasedEngine(hold=(0,))
+    q, b, reg = make_phased_stack(eng, max_batch=4, max_wait_ms=5.0)
+    first = _submit(q, 4)
+    eng.saw("dispatch", 0)
+    part = _submit(q, 1)
+    time.sleep(0.1)                         # twenty times max_wait
+    assert not eng.has("h2d", 1) and len(q) == 1
+    t0 = time.monotonic()
+    part += _submit(q, 3)                   # the bucket fills
+    assert eng.saw("h2d", 1) - t0 < 0.5 and not eng.has("wait", 0)
+    eng.finish(0)
+    for r in first + part:
+        r.wait(timeout=10)
+    assert eng.calls == [(BUCKET, 4), (BUCKET, 4)]
+    assert all(r.batch_real == 4 for r in part)
+    return q, b
+
+
+def _pipeline_ready_run_releases_the_part_batch():
+    """When the running batch comes ready before the bucket fills it is
+    delivered at once, and the part batch goes under the idle rule."""
+    eng = PhasedEngine(hold=(0,))
+    q, b, reg = make_phased_stack(eng, max_batch=4, max_wait_ms=5.0)
+    first = _submit(q, 4)
+    eng.saw("dispatch", 0)
+    part = _submit(q, 1)
+    time.sleep(0.05)
+    eng.finish(0)
+    for r in first:
+        r.wait(timeout=5)
+    assert part[0].wait(timeout=5).shape == (32, 48, 2)
+    assert first[0].finished_at < eng.saw("h2d", 1)    # not held for it
+    assert (part[0].batch_real, part[0].batch_padded) == (1, 1)
+    assert (_staged(reg, "late"), _staged(reg, "ahead")) == (2, 0)
+    return q, b
+
+
+def _pipeline_idle_request_pays_nothing():
+    """On an idle batcher one request is answered within max_wait + the
+    run: the pipeline adds no latency where there is nothing to overlap."""
+    eng = PhasedEngine(run_s=0.1)
+    q, b, reg = make_phased_stack(eng, max_batch=4, max_wait_ms=50.0)
+    for _ in range(3):
+        t0 = time.monotonic()
+        [r] = _submit(q, 1)
+        r.wait(timeout=5)
+        took = r.finished_at - t0
+        assert 0.05 + 0.1 <= took < 0.05 + 0.1 + 0.1, took
+    assert (_staged(reg, "late"), _staged(reg, "ahead")) == (3, 0)
+    return q, b
+
+
+def _pipeline_every_client_in_one_batch():
+    """32 closed-loop clients against max_batch 32: there is never a second
+    batch to stage, and the loop makes progress all the same."""
+    eng = PhasedEngine(run_s=0.01)
+    q, b, reg = make_phased_stack(eng, max_batch=32, max_wait_ms=5.0)
+
+    def client(_):
+        for _ in range(4):
+            [r] = _submit(q, 1)
+            r.wait(timeout=10)
+        return True
+
+    with ThreadPoolExecutor(32) as pool:
+        assert all(pool.map(client, range(32)))
+    assert b.served == 128 and sum(n for _, n in eng.calls) >= 128
+    return q, b
+
+
+def _pipeline_places_channel_planar_views():
+    """What the engine is handed reads as the [n, H, W, 3] batch (the rows
+    in order, the last one again up to the batch step) and lies in memory
+    as [n, 3, H, W]: the chip's layout, which the runtime only has to
+    tile.  A buffer is rewritten once its place has returned, so the fake
+    copies what it is shown."""
+    placed = []
+
+    class Keeps(PhasedEngine):
+        def place(self, bucket, im1, im2, sizes=None):
+            placed.append([(a.copy(), a.strides) for a in (im1, im2)])
+            return super().place(bucket, im1, im2, sizes)
+
+    q, b, reg = make_phased_stack(Keeps(), max_batch=4, max_wait_ms=5.0)
+    h, w = BUCKET
+    rng = np.random.default_rng(0)
+    for n in (3, 1):
+        rows = [rng.random((2, 1, h, w, 3), dtype=np.float32) * 0.5
+                for _ in range(n)]
+        reqs = [Request(r[0], r[1], BUCKET, (0, 0, 0, 0),
+                        deadline=time.monotonic() + 30.0) for r in rows]
+        for r in reqs:
+            q.submit(r)
+        for r in reqs:
+            r.wait(timeout=10)
+        padded = rows + [rows[-1]] * (b.pad_batch_to(n) - n)
+        for k, (got, strides) in enumerate(placed.pop()):
+            assert np.array_equal(got, np.concatenate([r[k] for r in padded]))
+            assert strides == (3 * h * w * 4, w * 4, 4, h * w * 4)
+        assert not placed
+    return q, b
+
+
+@pytest.mark.parametrize("case", [
+    _pipeline_stages_ahead, _pipeline_part_batch_waits_for_its_mates,
+    _pipeline_ready_run_releases_the_part_batch,
+    _pipeline_idle_request_pays_nothing,
+    _pipeline_every_client_in_one_batch,
+    _pipeline_places_channel_planar_views], ids=lambda f: f.__name__[10:])
+def test_pipeline(case):
+    q, b = case()
+    q.close()
+    b.join(5)
+    assert not b.alive and b._running is None
 
 
 # ----------------------------------------------- streaming: session store --

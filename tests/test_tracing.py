@@ -21,7 +21,8 @@ from raft_tpu.serving.batcher import BatcherCrashed
 from raft_tpu.serving.metrics import make_slo_metrics
 from raft_tpu.telemetry import spans
 
-from test_serving import BUCKET, StubEngine, make_request  # noqa: F401
+from test_serving import (BUCKET, PhasedEngine, StubEngine,  # noqa: F401
+                          make_request)
 
 
 # ------------------------------------------------------ span primitives --
@@ -393,10 +394,13 @@ def test_profiler_capture_holds_every_host_stage_with_its_batch(
             if name.startswith(("raft.batch.", "raft.engine.")):
                 # the batcher's stages of batch 1 and batch 2; the take
                 # that waited for batch 1 began before the capture, and
-                # the one waiting for a batch 3 has not ended.  form is
+                # the one waiting for a batch 3 has not ended.  A take
+                # that finds the batch before it finished returns at once
+                # to have it delivered, and is then made again (this stub's
+                # call is over when its dispatch returns).  form is
                 # three annotations a batch: everything between take and
-                # pad, in the loop, in _execute and in _run_group
-                want = {"raft.batch.take": [2],
+                # pad, in the loop, in _execute and in _form
+                want = {"raft.batch.take": [2, 2, 3],
                         "raft.batch.form": [1, 1, 1, 2, 2, 2]}.get(
                             name, [1, 2])
                 assert ann[name] == want, (name, ann[name])
@@ -411,6 +415,135 @@ def test_profiler_capture_holds_every_host_stage_with_its_batch(
             assert server.tracer.start("pair") is None
         else:
             assert _finished_trace(server, "aa02")["status"] == "ok"
+    finally:
+        server.stop()
+
+
+class PhasedDeviceStub(PhasedEngine):
+    """The phased fake timing its stages as the real engine does, behind a
+    ``run`` that composes them, so that a FlowServer pipelines it.  The
+    h2d of the first call takes 10 ms and of the second 60: a span's length
+    says whose it is; every annotation says it too (``index``)."""
+
+    def _stage(self, name, index):
+        from raft_tpu.telemetry.trace import host_stage
+        sink = lambda st: spans.record_device_stage("pair", st)  # noqa: E731
+        return host_stage(f"raft.engine.{name}", sink, call="pair",
+                          index=index)
+
+    def place(self, *args):
+        index = len(self.calls) + 1
+        with self._stage("h2d", index):
+            time.sleep(0.01 + 0.05 * (index - 1))
+            return super().place(*args)
+
+    def dispatch(self, call):
+        with self._stage("dispatch", call.i + 1):
+            super().dispatch(call)
+
+    def wait(self, call):
+        with self._stage("wait", call.i + 1):
+            super().wait(call)
+
+    def fetch(self, call):
+        with self._stage("fetch", call.i + 1):
+            return super().fetch(call)
+
+    def run(self, *args):
+        call = self.place(*args)
+        self.dispatch(call)
+        self.wait(call)
+        return self.fetch(call)
+
+    run.composes_phases = True
+
+
+def test_pipelined_batches_keep_their_spans_and_their_ordinals(tmp_path):
+    """Two requests in each of two consecutive device batches, the second
+    placed while the first runs: every request's spans still tile it, the
+    co-batched share one execute span, the engine's stages under an
+    execute are those of ITS batch, and every annotation of the batcher
+    thread carries the ordinal of its own batch although the stages of
+    the two interleave."""
+    import jax
+    from raft_tpu.telemetry.trace import profile_options
+    eng = PhasedDeviceStub(hold=(0,))
+    server = _server(eng, max_batch=2, batch_steps=(1, 2),
+                     max_wait_ms=10_000.0)
+    try:
+        im = np.zeros((32, 48, 3), np.float32)
+        timings = {}
+
+        def post(tid):
+            timings[tid] = _post_npz(server, im, tid)[1]
+
+        jax.profiler.start_trace(str(tmp_path),
+                                 profiler_options=profile_options())
+        try:
+            ts = [threading.Thread(target=post, args=(t,))
+                  for t in ("a1", "a2", "b1", "b2")]
+            for t in ts[:2]:
+                t.start()
+            eng.saw("dispatch", 0)
+            for t in ts[2:]:
+                t.start()
+            eng.saw("h2d", 1)               # placed under call 0's run
+            eng.finish(0)
+            for t in ts:
+                t.join(10)
+        finally:
+            jax.profiler.stop_trace()
+        assert [e[:2] for e in eng.log] == [
+            ("h2d", 0), ("dispatch", 0), ("h2d", 1), ("wait", 0),
+            ("dispatch", 1), ("fetch", 0), ("wait", 1), ("fetch", 1)]
+        exec_ids = {}
+        for tid in timings:
+            rec = _finished_trace(server, tid)
+            assert rec["status"] == "ok"
+            root_ms, top_ms = _tiles(rec)     # four handlers and a capture
+            assert abs(root_ms - top_ms) < 2 * TILE_SLACK_MS, tid
+            assert set(timings[tid]) == set(TOP_LEVEL) | set(DEVICE_CHILDREN)
+            [ex] = [s for s in rec["spans"] if s["name"] == "execute"]
+            exec_ids.setdefault(ex["span"], set()).add(tid[0])
+            kids = [s for s in rec["spans"] if s["parent"] == ex["span"]]
+            assert [s["name"] for s in kids] == list(DEVICE_CHILDREN)
+            for s in kids:                  # inside their execute
+                assert ex["start_ms"] - 0.01 <= s["start_ms"] and \
+                    s["start_ms"] + s["dur_ms"] \
+                    <= ex["start_ms"] + ex["dur_ms"] + 0.01
+            if tid[0] == "a":
+                assert 10.0 <= kids[0]["dur_ms"] < 60.0, tid
+            else:
+                assert 60.0 <= kids[0]["dur_ms"], tid
+        assert sorted(map(sorted, exec_ids.values())) == [["a"], ["b"]]
+        found = {}
+        import glob
+        from jax.profiler import ProfileData
+        [path] = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                           recursive=True)
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines if plane.name.startswith("/host:") \
+                    else ():
+                for ev in line.events:
+                    if ev.name.startswith(("raft.batch.", "raft.engine.")):
+                        stats = dict(ev.stats)
+                        found.setdefault(ev.name, []).append(
+                            (ev.start_ns, stats["batch"],
+                             stats.get("index")))
+        for name, evs in found.items():
+            ordinals = [b for _, b, _ in sorted(evs)]
+            if name.startswith("raft.engine."):
+                assert all(b == i for _, b, i in evs), (name, evs)
+                assert ordinals == [1, 2], (name, ordinals)
+        assert [b for _, b, _ in sorted(found["raft.batch.pad"])] == [1, 2]
+        assert [b for _, b, _ in sorted(found["raft.batch.deliver"])] \
+            == [1, 2]
+        # batch 2's take and form lie between batch 1's dispatch and its
+        # deliver, under their own ordinal
+        t_deliver1 = min(found["raft.batch.deliver"])[0]
+        early = sorted(e for e in found["raft.batch.form"]
+                       + found["raft.batch.take"] if e[0] < t_deliver1)
+        assert [b for _, b, _ in early][-4:] == [2, 2, 2, 2], early
     finally:
         server.stop()
 
