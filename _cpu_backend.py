@@ -6,8 +6,8 @@ backend initialization) for entry points that take a ``--cpu`` flag, and
 adds the virtual-device count for multi-device-on-CPU testing, which rides
 XLA_FLAGS and is read lazily by the CPU client at backend creation.
 
-Used by tests/conftest.py, __graft_entry__.dryrun_multichip, bench.py and
-the tools' ``--cpu`` flags.
+Used by tests/conftest.py, __graft_entry__.dryrun_multichip and the tools'
+``--cpu`` flags.
 """
 
 from __future__ import annotations

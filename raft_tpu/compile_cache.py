@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule, applied first thing by every entry point that compiles for the
-chip (``raft_tpu.cli.main``, ``bench.py``, ``chip_smoke.py``, the chip-side
+chip (``raft_tpu.cli.main``, ``chip_smoke.py``, the chip-side
 ``tools/`` scripts): when ``JAX_COMPILATION_CACHE_DIR`` is set the caller
 has placed the cache and JAX reads the variable itself — nothing is set in
 code; otherwise the cache is ``<checkout>/.jax_cache``, resolved from this

@@ -102,9 +102,9 @@ class RAFTConfig:
     # Window-lookup formulation for the dense impl: 'gather'
     # (take_along_axis, the reference's SampleCorr semantics) or 'onehot'
     # (separable one-hot interpolation matmuls — MXU work instead of
-    # gathers).  Default 'onehot' from measured data on BOTH backends:
-    # TPU v5e 18.09 vs 11.42 pairs/s (round-2 bench table, PERF.md) and
-    # CPU +12% (round-4 A/B); identical values (parity-tested vs gather).
+    # gathers).  Default 'onehot' from measured data: TPU v5e 18.09 vs
+    # 11.42 pairs/s (round 2, TUNING.md); identical values (parity-tested
+    # vs gather).
     corr_lookup: str = "onehot"
     # MXU precision of the fused kernel's correlation matmul.  'highest' =
     # every product of the operands' values, summed in float32: for
@@ -126,12 +126,6 @@ class RAFTConfig:
     # values; relative speed is hardware-dependent (tools/tune_pallas.py
     # --style sweeps it).
     pallas_lookup_style: str = "matmul"
-    # Row-packed f2 layout for narrow pyramid levels: lays 128//W2
-    # consecutive rows side by side in the 128-lane width so the corr tile
-    # covers pack x more of the real map (removes lane-padding waste at
-    # coarse levels, and at level 0 for training-crop widths like 496/8=62).
-    # Identical values (parity-tested); measured knob, default off.
-    pallas_pack: bool = False
     # Compute dtype for conv/matmul-heavy paths ('float32' or 'bfloat16');
     # the correlation itself always accumulates in float32.  The library
     # default stays float32 (numerics-first; bf16 is emulated and slower on
@@ -166,11 +160,9 @@ class RAFTConfig:
     # FLOPs inside the loop (~26% for the small variant).  XLA does not do
     # this itself (loop-invariant code motion moves whole ops, not partial
     # contractions).  Identical values (forward + gradient torch-oracle
-    # parity tested).  Default ON from measured A/Bs on the compute-bound
-    # CPU backend: train step +17% (tools/bench_train.py, quiet-core
-    # round-4 sweep), inference +7.7% (round-3, PERF.md); a pure FLOP cut,
-    # so it can only help more where the gate convs dominate (round-2 TPU
-    # attribution).  Not measured on the chip yet.
+    # parity tested).  Default ON: a pure FLOP cut, so it can only help
+    # where the gate convs dominate (round-2 TPU attribution).  Not timed
+    # on the chip against the un-hoisted form.
     gru_ctx_hoist: bool = True
     # Which implementation executes the SepConvGRU iteration (full model
     # only — the small variant's 3x3 ConvGRU has no hand kernel yet):

@@ -22,7 +22,7 @@ multi-octave texture synthesis + remap — is the same order as PNG decode of
 a Sintel frame, and the FlowAugmentor on top is identical to real training.
 
 Provenance: the JSON report (``--out BENCH_input.json``) embeds a telemetry
-run manifest (bench.py's schema: metric/value/unit/error + ``manifest``)
+run manifest (schema: metric/value/unit/error + ``manifest``)
 and the run appends stage events to ``events.jsonl`` (``--run-log``).
 """
 

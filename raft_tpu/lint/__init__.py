@@ -15,9 +15,10 @@ Four halves:
   (request-derived shapes into jit, unwarmed engine-cache kinds, hot-path
   device allocation, hardcoded VMEM/HBM constants).
 * :mod:`raft_tpu.lint.budget` — the static capacity analyzer behind
-  ``raftlint --budget``: exact warmup-grid enumeration (consumed by the
-  engine's warmup itself), ``jax.eval_shape`` HBM pricing, and the Pallas
-  block plans / VMEM envelopes the kernels import.
+  ``raftlint --budget``: it reads the warmup grid the engine's warmup
+  iterates (``serving/config.py``) and the block plans the Pallas kernels
+  execute (``raft_tpu/kernel_plans.py``), and prices them: ``jax.eval_shape``
+  HBM footprints and VMEM envelopes.
 * :mod:`raft_tpu.lint.concurrency` — the ``guarded_by`` annotation layer
   and the shared class/lock analysis the C rules, the SERVING.md
   threading-model generated check, and the runtime lock-order validator

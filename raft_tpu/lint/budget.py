@@ -4,26 +4,27 @@ Given a (RAFTConfig, ServeConfig) pair — no device, no compile — this module
 answers the three questions nothing else in the repo could before a replica
 boots:
 
-* **What will the engine compile?**  :func:`enumerate_warmup_grid` produces
-  the exact ``(kind, h, w, b, policy)`` key list ``serving/engine.py``
-  warmup builds.  It is not a parallel reimplementation that could drift:
-  the engine's own ``warmup()`` consumes THIS function, and the parity
-  test pins analyzer enumeration == live warm-engine key set exactly.
+* **What will the engine compile?**  It reads the warm-up grid where the
+  engine keeps it (``serving/config.enumerate_warmup_grid``, the list
+  ``InferenceEngine.warmup`` itself iterates), so the report's key list is
+  the engine's, not a second enumeration that could drift.
 * **Does the config fit HBM, and how many sessions per chip?**
   :func:`analyze` computes per-executable and aggregate footprints via
   ``jax.eval_shape`` abstract evaluation (params, per-bucket SlotPool
   buffers, peak live call buffers per kind — donation-aware: the commit
   scatter's donated pool buffers are not double-counted off-CPU) and
   solves max-sessions headroom against the per-device-kind budget.
-* **Do the Pallas kernels fit VMEM?**  The block-planning arithmetic of
-  ``ops/corr_pallas.py`` and ``ops/gru_pallas.py`` lives HERE
-  (:func:`corr_level_plan` / :func:`gru_row_plan`) and the kernels import
-  it, so the VMEM envelope the analyzer checks is the same math the
-  kernels execute — a hardcoded constant bypassing this module is what
-  lint rule B4 exists to catch.
+* **Do the Pallas kernels fit VMEM?**  The kernels' block plans are their
+  own (``raft_tpu/kernel_plans.py``: :func:`corr_level_plan` /
+  :func:`gru_row_plan`, which ``ops/corr_pallas.py`` and
+  ``ops/gru_pallas.py`` execute); the envelopes here price those plans, so
+  the VMEM that is checked is the tiling that runs — a hardcoded constant
+  bypassing ``kernel_plans`` is what lint rule B4 exists to catch.
 
-Layering: module import is pure stdlib (the linter must run without jax);
-jax is imported lazily inside the eval_shape functions only.  The byte
+Layering: this is the top of the stack and reads downward only.  Module
+import is pure stdlib plus ``kernel_plans`` (the linter must run without
+jax); whatever brings jax in (``models``, ``serving``, ``ops.corr_pallas``)
+is imported lazily inside the functions that evaluate shapes.  The byte
 accounting is an I/O-resident lower bound — XLA's internal temporaries
 (convolution scratch, fusion buffers) ride on top, so headroom numbers are
 optimistic by design and say "cannot fit", never "will surely fit".
@@ -32,37 +33,14 @@ optimistic by design and say "cannot fit", never "will surely fit".
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
-# ---------------------------------------------------------------------------
-# Shared hardware-layout constants (the "budget model" of lint rule B4).
-# ---------------------------------------------------------------------------
+from ..kernel_plans import (GRU_HALO, GRU_TAPS, SUBLANE, VMEM_BYTES,
+                            VMEM_CEILING_BYTES, corr_level_plan,
+                            gru_row_plan, gru_scoped_bytes, gru_vmem_limit)
 
-#: TPU vector-lane width: the last dim of every VMEM tile pads to this.
-LANE = 128
-#: TPU sublane width: the second-minor dim of a float32 tile pads to this.
-SUBLANE = 8
-#: Scoped-VMEM limit the Pallas kernels ask the compiler for
-#: (``vmem_limit_bytes`` of every pallas_call) and the ceiling the static
-#: envelopes below are checked against.  The compiler's DEFAULT scoped limit
-#: is 16 MiB, and the kernels at their default blocks sit right on it: the
-#: level-0 corr lookup (q_blk 128, p_blk 4096, C 256) is accepted at 16 MiB
-#: standalone and refused inside the chairs train step ("Scoped allocation
-#: with size 16.84M and limit 16.00M"), the fused GRU at f32 I/O, 8 rows x
-#: 128 columns is refused ("17.03M").  A v5e TensorCore has 128 MiB of VMEM,
-#: so the default is a compiler setting, not the hardware: the kernels
-#: request 32 MiB and keep their measured block plan.
-VMEM_BYTES = 32 * 1024 * 1024
-
-#: What a kernel whose row blocks outgrow ``VMEM_BYTES`` may ask for instead
-#: (:func:`gru_vmem_limit`): most of the 128 MiB a v5e TensorCore has, the
-#: rest left to the compiler's own use around the kernel.
-VMEM_CEILING_BYTES = 100 * 1024 * 1024
-
-#: Fused-GRU kernel geometry (ops/gru_pallas.py imports these): the pass-1
-#: recompute halo rows, and the separable tap count (1x5 / 5x1 gates).
-GRU_HALO = 4
-GRU_TAPS = 5
+if TYPE_CHECKING:       # serving/ brings jax in: a name for annotations only
+    from ..serving.config import Key
 
 #: Per-device-kind capacity budgets the analyzer solves against.  HBM
 #: figures are per-chip; "cpu" is a nominal planning budget so the same
@@ -92,197 +70,6 @@ def budget_key(device_kind: str) -> str:
     raise ValueError(f"no capacity budget known for device_kind "
                      f"{device_kind!r}; add it to lint/budget.py "
                      f"DEVICE_BUDGETS / _KIND_KEYS with its source")
-
-
-#: Engine-cache key: (kind, bucket H, bucket W, padded batch, iters policy).
-Key = Tuple[str, int, int, int, str]
-
-
-def round_up(x: int, m: int) -> int:
-    """Smallest multiple of ``m`` >= ``x``."""
-    return -(-x // m) * m
-
-
-# ---------------------------------------------------------------------------
-# Compile-surface enumeration (pure; no jax).
-# ---------------------------------------------------------------------------
-
-def resolved_policy(config, sconfig) -> str:
-    """The iteration policy the engine actually serves under: the serving
-    tier's declaration overrides the model config (engine.__init__ applies
-    the same ``dataclasses.replace``)."""
-    if sconfig.iters_policy is not None:
-        return sconfig.iters_policy
-    return config.iters_policy
-
-
-def enumerate_warmup_grid(config, sconfig, stream: Optional[bool] = None,
-                          chaos: Optional[bool] = None) -> List[Key]:
-    """Every engine-cache key ``warmup()`` will build, in insertion order,
-    deduplicated — the engine's compile surface as a value.
-
-    ``stream`` defaults to the server's wiring (``max_sessions > 0``);
-    ``chaos`` (the ``spoison`` drill executable) to whether a chaos spec is
-    armed.  Pass them explicitly to mirror a hand-constructed engine.
-
-    This IS the warmup grid, not a copy of it: ``InferenceEngine.warmup``
-    iterates this list, so analyzer and engine cannot disagree.
-    """
-    if stream is None:
-        stream = sconfig.max_sessions > 0
-    if chaos is None:
-        chaos = sconfig.chaos is not None
-    policy = resolved_policy(config, sconfig)
-    # ragged mixed-resolution serving (SERVING.md "Ragged serving"): the
-    # bucket axis of the grid COLLAPSES to the single max-box arena —
-    # per-row live sizes are a runtime argument, so one executable per
-    # (kind, batch-step, policy) serves every declared resolution and the
-    # compile surface shrinks from O(buckets x steps) to O(steps).
-    buckets = ((tuple(sconfig.max_box),)
-               if getattr(sconfig, "ragged", False)
-               else tuple(tuple(b) for b in sconfig.buckets))
-    grid = [(h, w, b, "pair") for (h, w) in buckets
-            for b in sconfig.batch_steps]
-    if stream:
-        # encode covers session open + cold restart; "stream" is the cold
-        # batch-1 step; the continuous-batched step + its commit scatter
-        # warm at every declared batch width — PLUS width 1 for "scommit"
-        # (commit_row always runs at width 1, and under --serve-dp the
-        # declared steps are multiples of N, never 1); "szero" builds the
-        # pool buffers; "spoison" only exists for chaos drills.
-        grid += [(h, w, 1, kind) for (h, w) in buckets
-                 for kind in ("encode", "stream", "szero", "scommit")]
-        grid += [(h, w, b, kind) for (h, w) in buckets
-                 for b in sconfig.batch_steps
-                 for kind in ("sbatch", "scommit")]
-        if chaos:
-            grid += [(h, w, 1, "spoison") for (h, w) in buckets]
-    keys: List[Key] = []
-    seen = set()
-    for (h, w, b, kind) in grid:
-        key = (kind, h, w, b, policy)
-        if key not in seen:
-            seen.add(key)
-            keys.append(key)
-    return keys
-
-
-# ---------------------------------------------------------------------------
-# Pallas block planning (pure; shared with ops/corr_pallas.py and
-# ops/gru_pallas.py — the kernels import these so envelope math and
-# executed math are one function).
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class CorrLevelPlan:
-    """Block geometry of one ``_lookup_level`` pallas_call.
-
-    ``rows``/``rows_padded`` are in the PACKED row frame when ``pack > 1``
-    (``pack`` real map rows laid side by side per packed row)."""
-
-    t: int              # queries per program ([T, C] f1 block)
-    qp: int             # padded query count (multiple of t)
-    pack: int           # real rows packed side by side per stored row
-    w2p: int            # stored row width, lane-padded (multiple of LANE)
-    h2_blk: int         # stored rows per f2 block
-    rows: int           # stored rows before padding
-    rows_padded: int    # stored rows after padding (multiple of h2_blk)
-    n_pblocks: int      # f2 row-block count (the k grid dimension)
-
-
-def corr_level_plan(q: int, h2: int, w2: int, *, q_blk: int,
-                    p_blk_target: int,
-                    pack_rows: bool = False) -> CorrLevelPlan:
-    """The fused correlation kernel's block plan for one pyramid level —
-    the exact padding/blocking arithmetic ``_lookup_level`` executes."""
-    if h2 <= 0 or w2 <= 0:
-        raise ValueError(f"degenerate level {h2}x{w2}: the kernel "
-                         f"short-circuits these to zeros before planning")
-    t = q_blk if q >= q_blk else round_up(q, SUBLANE)
-    qp = round_up(q, t)
-    pack = max(1, LANE // w2) if pack_rows else 1
-    if pack > 1:
-        rows = -(-h2 // pack)                    # packed rows
-        w2p = round_up(pack * w2, LANE)          # = LANE
-    else:
-        rows = h2
-        w2p = round_up(w2, LANE)
-    h2_blk = max(1, min(rows, p_blk_target // w2p))
-    rows_padded = round_up(rows, h2_blk)
-    return CorrLevelPlan(t=t, qp=qp, pack=pack, w2p=w2p, h2_blk=h2_blk,
-                         rows=rows, rows_padded=rows_padded,
-                         n_pblocks=rows_padded // h2_blk)
-
-
-def corr_level_scheduled(plan: CorrLevelPlan) -> bool:
-    """THE rule for the lookup's key-block schedule, read from the level's
-    plan alone: a level whose map is cut into more than one row-block is
-    visited through a per-tile schedule of the blocks its windows touch; a
-    level of one block has nothing to leave out and pays for no schedule.
-    A (2r+2)-row window band lies in one or two blocks of a plan's 8 to 32
-    rows, so even at two blocks a tile leaves one out more often than not:
-    on the v5e one launch at batch 32 of 440x1024's level 0 (two blocks;
-    tiles visit 61 % of them) fell from 25.9 to 19.6 ms, and at 1080x1920
-    (batch 8) level 0 (nine blocks, 19.5 %) from 213 to 56, level 1 (three)
-    from 63 to 38, level 2 (two) from 46 to 31 (TUNING.md, PR 26).  No
-    level with more than one block lost."""
-    return plan.n_pblocks > 1
-
-
-@dataclasses.dataclass(frozen=True)
-class GruRowPlan:
-    """Row-block geometry of one fused-GRU pallas_call."""
-
-    hp: int     # padded height (multiple of block_rows)
-    wc: int     # conv-output width (aligned row merges: multiple of 8)
-    wp: int     # stored width: wc + tap radius of zeros each side
-    n_rb: int   # row-block count (the k grid dimension)
-
-
-def gru_row_plan(h: int, w: int, block_rows: int) -> GruRowPlan:
-    """The fused GRU kernel's padding plan — the exact arithmetic
-    ``_gru_fused_impl`` executes before its pallas_call."""
-    if block_rows < GRU_HALO:
-        raise ValueError(f"block_rows must be >= {GRU_HALO} (the pass-1 "
-                         f"recompute halo), got {block_rows}")
-    hp = round_up(h, block_rows)
-    wc = round_up(w, SUBLANE)
-    wp = wc + (GRU_TAPS - 1)
-    return GruRowPlan(hp=hp, wc=wc, wp=wp, n_rb=hp // block_rows)
-
-
-#: Scoped VMEM the fused GRU's program takes per (pass-1 row, stored column)
-#: at the full model's 128 hidden + 128 motion channels, by the itemsize of
-#: its I/O: its float32 intermediates are all live at once, on top of the
-#: row blocks it is handed.  An upper envelope of the chip compiler's own
-#: figures (v5e, jax 0.9.0) at 16 pass-1 rows: bfloat16 I/O, 244 stored
-#: columns, 53.23M inside the 1080x1920 pair program (13.96 KiB a position;
-#: the parent's first run of that program on the chip, PR 26, and the same
-#: figure from the compiler here) and 39.63M alone; float32 I/O, 244 columns,
-#: 78.74M alone (20.65 KiB); float32, 132 columns, 17.03M inside the 440x1024
-#: program (8.3 KiB: narrow rows cost less a position, so this over-asks
-#: there, which costs nothing).
-GRU_SCOPED_BYTES_PER_POSITION = {2: 14 * 1024, 4: 24 * 1024}
-
-
-def gru_scoped_bytes(plan: GruRowPlan, block_rows: int, itemsize: int) -> int:
-    """What the fused GRU's program is expected to need of scoped VMEM."""
-    return ((block_rows + 2 * GRU_HALO) * plan.wp
-            * GRU_SCOPED_BYTES_PER_POSITION[itemsize])
-
-
-def gru_vmem_limit(plan: GruRowPlan, block_rows: int, itemsize: int) -> int:
-    """The scoped-VMEM limit the fused GRU kernel asks the compiler for at
-    this row plan and I/O itemsize: ``VMEM_BYTES`` wherever its program is
-    expected to fit that (bfloat16 up to 146 stored columns at 8 rows: the
-    440x1024 program is unchanged), else what it is expected to need and a
-    sixth more, up to ``VMEM_CEILING_BYTES``.  The kernel holds whole rows,
-    so a wider frame is a larger program: 1080x1920 (244 stored columns)
-    needs 53.23M."""
-    need = gru_scoped_bytes(plan, block_rows, itemsize)
-    if need <= VMEM_BYTES:
-        return VMEM_BYTES
-    return min(round_up(need + need // 6, 1024 * 1024), VMEM_CEILING_BYTES)
 
 
 def corr_vmem_envelope(config, bucket: Tuple[int, int],
@@ -316,8 +103,7 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
                            "degenerate": True})
             continue
         plan = corr_level_plan(q, h2, w2, q_blk=config.pallas_q_blk,
-                               p_blk_target=config.pallas_p_blk,
-                               pack_rows=config.pallas_pack)
+                               p_blk_target=config.pallas_p_blk)
         pblk = plan.h2_blk * plan.w2p
         # Calibrated against the chip compiler's own scoped-allocation
         # figures (v5e, jax 0.9.0): this model gives 16.45 MiB for the
@@ -628,6 +414,7 @@ def pair_temp_bytes(config, h: int, w: int, b: int) -> Optional[int]:
 def config_signature(config, sconfig, stream: bool, chaos: bool) -> dict:
     """What the committed-baseline comparison keys on: every knob that
     changes the compile surface or the footprint model."""
+    from ..serving.config import resolved_policy
     return {
         "small": config.small,
         "compute_dtype": config.compute_dtype,
@@ -654,6 +441,8 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
     scatter outputs as real copies.
     """
     import jax  # fail here, loudly, if jax is unavailable
+
+    from ..serving.config import enumerate_warmup_grid
 
     if device_kind is None:
         device_kind = budget_key(jax.devices()[0].device_kind)

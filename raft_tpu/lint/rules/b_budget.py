@@ -2,7 +2,10 @@
 
 The static capacity analyzer (``lint/budget.py``) makes the engine's
 compile surface and device-memory footprint knowable before a replica
-boots — these rules keep the code shaped so the analyzer stays TRUE:
+boots, from the warm-up grid the server declares
+(``serving/config.enumerate_warmup_grid``) and the block plans the kernels
+execute (``raft_tpu/kernel_plans.py``) — these rules keep the code shaped so
+the analyzer stays TRUE:
 
 * B1 — a request-derived value reaching a jitted entry point directly.
   Every wire-derived shape must pass through bucket routing (or any
@@ -12,9 +15,10 @@ boots — these rules keep the code shaped so the analyzer stays TRUE:
   shapes).
 * B2 — an engine-cache ``kind`` that is dispatched on but never covered
   by warmup.  Warmup coverage is the union of the string literals in
-  every ``warmup()`` body plus, when warmup consumes the analyzer's
-  ``enumerate_warmup_grid``, the literals of that function — so the
-  enumeration refactor doesn't hide coverage from the rule.  Since the
+  every ``warmup()`` body plus, when warmup consumes
+  ``enumerate_warmup_grid`` (found by its name, wherever in the scan set
+  it is defined), the literals of that function — so the enumeration
+  refactor doesn't hide coverage from the rule.  Since the
   AOT cache (serving/aot_cache.py) made warming a load-or-compile,
   ``export_cache()`` bodies count as warmup surfaces too: a kind
   serialized into the cache is warmed (deserialized) at the next boot.
@@ -24,13 +28,15 @@ boots — these rules keep the code shaped so the analyzer stays TRUE:
   path outside the engine/SlotPool.  Per-request device allocation
   bypasses the budgeted resident set: stage on the host with numpy and
   let the warmed executables own device memory.
-* B4 — a hardcoded VMEM/HBM byte constant outside ``lint/budget.py``.
-  The budget model is shared by construction (the Pallas kernels import
-  their block plans from it); a local ``VMEM_LIMIT = 16 * 1024 * 1024``
-  re-derives what the analyzer can then no longer see.
+* B4 — a hardcoded VMEM/HBM byte constant outside the two files where
+  they live: ``raft_tpu/kernel_plans.py`` (what the kernels ask the
+  compiler for) and ``lint/budget.py`` (the devices' capacities).  The
+  analyzer reads the kernels' own plans; a local
+  ``VMEM_LIMIT = 16 * 1024 * 1024`` re-derives what it can then no longer
+  see.
 * B5 — the serialized engine-cache key schema
   (``serving/aot_cache.KEY_FIELDS``) drifting out of sync with the key
-  tuples ``lint/budget.enumerate_warmup_grid`` builds.  The manifest of
+  tuples ``serving/config.enumerate_warmup_grid`` builds.  The manifest of
   a cache directory pins the field names/order every ``.bin`` filename
   encodes; a grid-side reorder or new field would silently make every
   persisted cache stale (or worse, collide) — the two definitions must
@@ -57,8 +63,8 @@ _DEVICE_ALLOCS = frozenset(
 
 _VMEM_NAME_RE = re.compile(r"(?i)vmem|hbm")
 
-#: The shared budget model itself is the one place byte constants live.
-_BUDGET_MODEL_SUFFIXES = ("lint/budget.py", "lint\\budget.py")
+#: Where byte constants live: the kernels' plans and the devices' budgets.
+_BUDGET_MODEL_SUFFIXES = ("raft_tpu/kernel_plans.py", "lint/budget.py")
 
 
 def _root_name(node: ast.AST) -> Optional[str]:
@@ -171,7 +177,7 @@ class B2UnwarmedKind(GlobalRule):
 
     def check_all(self, ctxs: Sequence[FileContext]) -> Iterable[Finding]:
         # warmup coverage: literals in every warmup() body; when warmup
-        # consumes the analyzer's enumeration, the literals of every
+        # consumes the grid enumeration, the literals of every
         # enumerate_warmup_grid definition in the scan set count too
         provider: Set[str] = set()
         for ctx in ctxs:
@@ -216,7 +222,7 @@ class B2UnwarmedKind(GlobalRule):
                             ctx, node,
                             f"engine-cache kind {kind!r} is dispatched "
                             f"here but no warmup covers it — add it to "
-                            f"the warmup grid (lint/budget."
+                            f"the warmup grid (serving/config."
                             f"enumerate_warmup_grid) or it cold-compiles "
                             f"at serve time")
 
@@ -281,12 +287,10 @@ class B4HardcodedVmemBudget(Rule):
     rule_id = "B4"
     severity = "error"
     description = ("hardcoded VMEM/HBM byte constant bypasses the shared "
-                   "budget model (lint/budget.py)")
+                   "budget model (kernel_plans.py, lint/budget.py)")
 
     def check(self, ctx: FileContext) -> Iterable[Finding]:
-        norm = ctx.path.replace("\\", "/")
-        if norm.endswith(_BUDGET_MODEL_SUFFIXES[0]) \
-                or norm.endswith(_BUDGET_MODEL_SUFFIXES[1]):
+        if ctx.path.replace("\\", "/").endswith(_BUDGET_MODEL_SUFFIXES):
             return      # the model itself is where the numbers live
         for node in ast.walk(ctx.tree):
             if isinstance(node, ast.Assign):
@@ -303,9 +307,10 @@ class B4HardcodedVmemBudget(Rule):
                     yield self.finding(
                         ctx, node,
                         f"{tgt.id!r} hardcodes a device-memory budget — "
-                        f"import it from raft_tpu.lint.budget "
-                        f"(VMEM_BYTES / DEVICE_BUDGETS) so the static "
-                        f"analyzer and the code agree on one number")
+                        f"import it from raft_tpu.kernel_plans "
+                        f"(VMEM_BYTES) or raft_tpu.lint.budget "
+                        f"(DEVICE_BUDGETS) so the static analyzer and "
+                        f"the code agree on one number")
 
 
 @register
@@ -314,7 +319,7 @@ class B5CacheKeySchemaDrift(GlobalRule):
     severity = "error"
     description = ("serialized engine-cache key schema (aot_cache."
                    "KEY_FIELDS) out of sync with the key tuple "
-                   "lint/budget.enumerate_warmup_grid builds")
+                   "serving/config.enumerate_warmup_grid builds")
 
     def check_all(self, ctxs: Sequence[FileContext]) -> Iterable[Finding]:
         # side 1: the persisted schema — KEY_FIELDS = ("kind", ...) in the

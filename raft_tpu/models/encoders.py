@@ -159,14 +159,8 @@ def init_encoder(key, output_dim: int, norm_fn: str, small: bool = False) -> dic
 def apply_encoder(p: dict, x: jax.Array, norm_fn: str, small: bool = False,
                   train: bool = False, axis_name: Optional[str] = None,
                   dropout: float = 0.0, rng: Optional[jax.Array] = None,
-                  stages: Optional[int] = None,
                   bn_train: Optional[bool] = None) -> Tuple[jax.Array, dict]:
     """Returns (features at 1/8 resolution, params-with-updated-BN-stats).
-
-    ``stages`` truncates the network for per-stage profiling (0 = stem only,
-    1..3 = through layer<stages>, skipping the output conv); None runs it
-    all.  Keeping the truncation here means profilers measure exactly the
-    layer structure the model runs (tools/profile_breakdown.py).
 
     ``bn_train`` overrides ``train`` for the normalization layers only
     (None = follow ``train``): the official finetune recipe freezes BN —
@@ -181,10 +175,7 @@ def apply_encoder(p: dict, x: jax.Array, norm_fn: str, small: bool = False,
         y, n1 = _apply_norm(norm_fn, p.get("norm1"), y, bn_train, axis_name)
         _maybe(p, "norm1", n1)
         y = jax.nn.relu(y)
-    layer_plan = list(zip((1, 2, 3), (1, 2, 2)))
-    if stages is not None:
-        layer_plan = layer_plan[:stages]
-    for li, stride in layer_plan:
+    for li, stride in zip((1, 2, 3), (1, 2, 2)):
         layer = dict(p[f"layer{li}"])
         with stage(f"encoder/layer{li}"):
             y, layer["0"] = block_apply(layer["0"], y, norm_fn, stride,
@@ -192,8 +183,6 @@ def apply_encoder(p: dict, x: jax.Array, norm_fn: str, small: bool = False,
             y, layer["1"] = block_apply(layer["1"], y, norm_fn, 1,
                                         bn_train, axis_name)
         p[f"layer{li}"] = layer
-    if stages is not None:
-        return y, p
     y = apply_conv(p["conv2"], y)
     if train and dropout > 0.0 and rng is not None:
         # channel dropout (torch nn.Dropout2d): zero whole channels per sample

@@ -333,8 +333,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
             kernel="pallas" if config.corr_impl == "pallas" else "onehot",
             pallas_opts=dict(q_blk=config.pallas_q_blk,
                              p_blk_target=config.pallas_p_blk,
-                             lookup_style=config.pallas_lookup_style,
-                             pack_rows=config.pallas_pack))
+                             lookup_style=config.pallas_lookup_style))
     elif config.corr_impl == "dense":
         lookup_fn = (lookup_dense_onehot if config.corr_lookup == "onehot"
                      else lookup_dense)
@@ -364,8 +363,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                 fmap1, fmap2, config.corr_levels, config.corr_radius,
                 corr_precision=corr_prec, q_blk=config.pallas_q_blk,
                 p_blk_target=config.pallas_p_blk,
-                lookup_style=config.pallas_lookup_style,
-                pack_rows=config.pallas_pack)
+                lookup_style=config.pallas_lookup_style)
         counts_keyblocks = True
     else:
         raise ValueError(config.corr_impl)

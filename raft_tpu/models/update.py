@@ -235,7 +235,7 @@ def apply_basic_update_block(p: dict, net: jax.Array, inp: jax.Array,
                              gru_block_rows: int = 8
                              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     if gru_impl not in ("xla", "pallas"):
-        # public entry point (models/__init__, tools/profile_breakdown):
+        # public entry point (models/__init__):
         # a typo must not quietly run the other GRU implementation
         raise ValueError(f"gru_impl must be 'xla' or 'pallas', "
                          f"got {gru_impl!r}")

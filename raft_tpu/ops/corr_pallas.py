@@ -51,8 +51,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..lint.budget import (VMEM_BYTES, corr_level_plan,
-                           corr_level_scheduled)
+from ..kernel_plans import VMEM_BYTES, corr_level_plan, corr_level_scheduled
 from ..lint.contracts import contract
 from ..telemetry.trace import stage
 # corr_terms and its two readers live in ops/corr.py, which imports no
@@ -69,21 +68,9 @@ def _use_interpret() -> bool:
 
 # The scoped-VMEM limit every pallas_call here requests: the compiler's
 # 16 MiB default refuses the default block plan inside the train step
-# (lint/budget.py VMEM_BYTES has the figures); the static envelope is
+# (kernel_plans.VMEM_BYTES has the figures); the static envelope is
 # checked against the same number.
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES)
-
-
-# Row packing passes every interpret-mode test and is REFUSED by the chip's
-# compiler (v5e, jax 0.9.0 / libtpu 0.0.34; tests/test_tpu_compile.py holds
-# the refusal as a strict xfail).  Until repaired (ROADMAP A6/C3) asking for
-# it on the chip is a ValueError raised while the model is traced — before
-# Mosaic, with the reason — never a fallback.
-_PACK_REFUSAL = (
-    "pallas_pack=True (row-packed f2 lanes) does not compile for the TPU: "
-    "MosaicError 'infer-vector-layout: unsupported shape cast' on "
-    "_packed_body's [T, n] -> [T, n, 1, 1] expands (\"tpu.reshape\" "
-    "(vector<128x9xi1>) -> vector<128x9x1x1xi1>)")
 
 
 def split_bf16_terms(x: jax.Array, n: int) -> jax.Array:
@@ -202,75 +189,6 @@ def _window_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
     return win
 
 
-def _packed_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
-                 corr_scale: float, radius: int, h2_blk: int, w2: int,
-                 w2_real: int, pack: int, corr_precision):
-    """Program body for row-packed f2 layouts.
-
-    Narrow pyramid levels (W2 < 128 lanes) waste most of the MXU tile on
-    lane padding; here ``pack`` consecutive real rows are laid side by side
-    in one packed row of width pack*W2 (w2 = padded lane width), so the corr
-    matmul covers ``pack``x more of the real map per tile.
-
-    This body has a single, fixed lookup formulation (one-hot y-matmul +
-    parity-aware VPU x-reduction) — ``lookup_style`` does not apply to
-    packed levels; levels too wide to pack still honor it via
-    ``_window_body``.  The bilinear
-    window lookup then needs, per window row i, real rows ty_i (weight 1-fy)
-    and ty_i+1 (weight fy), each living at packed position
-    (ty // pack, (ty % pack) * W2 + x).  Each term is a one-hot y-matmul
-    over packed rows followed by a parity-aware one-hot x reduction; x
-    indices are masked to their own sub-row so windows never wrap into a
-    neighboring packed column ([0 <= tx < W2] guard).
-    """
-    n = 2 * radius + 1
-    T = f1_ref.shape[1]
-    W2 = w2_real                                     # real row width (padded
-    # cols beyond pack*W2 hold zeros and are never matched)
-    corr = _corr_tile(f1_ref, f2_ref, corr_precision) * corr_scale
-    corr3 = corr.reshape(T, h2_blk, w2)              # f2 block: packed rows
-
-    c = coords_ref[0] * level_scale                  # [T, 2] (x, y)
-    cx, cy = c[:, 0], c[:, 1]
-    cx0 = jnp.floor(cx)
-    cy0 = jnp.floor(cy)
-    fx = cx - cx0                                    # [T]
-    fy = cy - cy0                                    # [T]
-    ix0 = cx0.astype(jnp.int32) - radius
-    iy0 = cy0.astype(jnp.int32) - radius
-
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, (T, n), 1)
-    ty_base = iy0[:, None] + iota_n                  # [T, n]  y-window rows
-    tx = ix0[:, None] + iota_n                       # [T, n]  x-window taps
-    h_ids = (jax.lax.broadcasted_iota(jnp.int32, (T, n, h2_blk), 2)
-             + sel * h2_blk)                         # packed rows of this blk
-    u_ids = jax.lax.broadcasted_iota(jnp.int32, (T, n, n, w2), 3)
-    fx4 = fx[:, None, None, None]
-    x_ok0 = ((tx >= 0) & (tx < W2))[:, :, None, None]       # [T, n(j), 1, 1]
-    x_ok1 = ((tx + 1 >= 0) & (tx + 1 < W2))[:, :, None, None]
-
-    win = None
-    for wy, row_delta in ((1.0 - fy, 0), (fy, 1)):   # the two y taps
-        ty = ty_base + row_delta                     # [T, n]
-        prow = jnp.floor_divide(ty, pack)            # packed row of the tap
-        parity = ty - prow * pack                    # sub-row within the pack
-        a_y = jnp.where(h_ids == prow[:, :, None], wy[:, None, None], 0.0)
-        win_y = jax.lax.dot_general(                 # [T, n(y), w2]
-            a_y, corr3, (((2,), (1,)), ((0,), (0,))),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        # parity-aware x one-hot: tap (i, j) lives at u = parity_i*W2 + tx_j,
-        # masked to its own sub-row so windows never wrap into a neighboring
-        # packed column; per-(i,j) u targets differ, so the x contraction is
-        # a broadcast-multiply-reduce over u (VPU work, j-major output)
-        u0 = (parity[:, None, :] * W2 + tx[:, :, None])[..., None]
-        a_x = (jnp.where((u_ids == u0) & x_ok0, 1.0 - fx4, 0.0)
-               + jnp.where((u_ids == u0 + 1) & x_ok1, fx4, 0.0))
-        term = jnp.sum(a_x * win_y[:, None, :, :], axis=3)  # [T, n(x), n(y)]
-        win = term if win is None else win + term
-    return win
-
-
 def _accumulate(out_ref, win, k):
     @pl.when(k == 0)
     def _():
@@ -309,14 +227,12 @@ def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, *, body):
 
 
 def _window_schedule(coords: jax.Array, level_scale: float, radius: int,
-                     T: int, h2_blk: int, H2: int, K: int,
-                     pack: int = 1) -> jax.Array:
+                     T: int, h2_blk: int, H2: int, K: int) -> jax.Array:
     """Per (batch, query-block) contiguous range of f2 row-blocks its bilinear
     windows can touch, as a [B, Qb, K] block-index schedule.  Entries past
     the needed range repeat the last needed block (skip marker).  Fully
     out-of-map windows contribute zeros via the one-hot construction, so
-    pointing them at block 0 is safe.  ``h2_blk`` counts *packed* rows when
-    ``pack`` > 1 (each packed row holds ``pack`` real rows)."""
+    pointing them at block 0 is safe."""
     B, Qp, _ = coords.shape
     n = 2 * radius + 1
     cy = coords[..., 1] * level_scale                     # [B, Qp]
@@ -325,9 +241,8 @@ def _window_schedule(coords: jax.Array, level_scale: float, radius: int,
     lo = iyb.min(axis=2)
     hi = iyb.max(axis=2) + n                              # inclusive last row
     any_rows = (hi >= 0) & (lo < H2)
-    rows_per_blk = h2_blk * pack
-    b_lo = jnp.where(any_rows, jnp.clip(lo, 0, H2 - 1) // rows_per_blk, 0)
-    b_hi = jnp.where(any_rows, jnp.clip(hi, 0, H2 - 1) // rows_per_blk, 0)
+    b_lo = jnp.where(any_rows, jnp.clip(lo, 0, H2 - 1) // h2_blk, 0)
+    b_hi = jnp.where(any_rows, jnp.clip(hi, 0, H2 - 1) // h2_blk, 0)
     ks = jnp.arange(K, dtype=jnp.int32)[None, None, :]
     return (b_lo[..., None]
             + jnp.minimum(ks, (b_hi - b_lo)[..., None])).astype(jnp.int32)
@@ -352,7 +267,7 @@ def level_schedule(coords: jax.Array, plan, H2: int, level: int,
     ``coords`` [B, Q, 2], as :func:`_lookup_level` takes it."""
     _, coords = _pad_queries(plan, None, coords)
     return _window_schedule(coords, 1.0 / (2.0 ** level), radius, plan.t,
-                            plan.h2_blk, H2, plan.n_pblocks, pack=plan.pack)
+                            plan.h2_blk, H2, plan.n_pblocks)
 
 
 def level_shapes(f2_levels: Sequence[jax.Array]):
@@ -361,26 +276,23 @@ def level_shapes(f2_levels: Sequence[jax.Array]):
     return [tuple(lvl.shape[-3:-1]) for lvl in f2_levels]
 
 
-def _level_plans(Q: int, shapes, q_blk: int, p_blk_target: int,
-                 pack_rows: bool):
+def _level_plans(Q: int, shapes, q_blk: int, p_blk_target: int):
     """One block plan per pyramid level; None where the map is pooled away
     to nothing (the kernel short-circuits those to zeros)."""
-    return [corr_level_plan(Q, h2, w2, q_blk=q_blk,
-                            p_blk_target=p_blk_target, pack_rows=pack_rows)
+    return [corr_level_plan(Q, h2, w2, q_blk=q_blk, p_blk_target=p_blk_target)
             if h2 > 0 and w2 > 0 else None for h2, w2 in shapes]
 
 
 def lookup_schedules(coords: jax.Array, shapes, radius: int,
-                     q_blk: int = 128, p_blk_target: int = 4096,
-                     pack_rows: bool = False) -> Tuple:
+                     q_blk: int = 128, p_blk_target: int = 4096) -> Tuple:
     """Per level, the key-block schedule its launch runs under, or None
     where it walks every block: coords [B, H, W, 2], ``shapes`` the
     ``(H2, W2)`` of each f2 level (:func:`level_shapes`).  Which levels get one is decided here
     and nowhere else, from each level's block plan
-    (``lint/budget.corr_level_scheduled``): no flag selects it."""
+    (``kernel_plans.corr_level_scheduled``): no flag selects it."""
     B, H, W, _ = coords.shape
     cf = coords.reshape(B, H * W, 2)
-    plans = _level_plans(H * W, shapes, q_blk, p_blk_target, pack_rows)
+    plans = _level_plans(H * W, shapes, q_blk, p_blk_target)
     return tuple(
         level_schedule(cf, plan, h2, i, radius)
         if plan is not None and corr_level_scheduled(plan) else None
@@ -388,8 +300,8 @@ def lookup_schedules(coords: jax.Array, shapes, radius: int,
 
 
 def schedule_keyblocks(schedules, batch: int, queries: int, shapes,
-                       q_blk: int = 128, p_blk_target: int = 4096,
-                       pack_rows: bool = False) -> jax.Array:
+                       q_blk: int = 128,
+                       p_blk_target: int = 4096) -> jax.Array:
     """int32 ``[visited, possible]``: the (query tile, key row-block) steps
     one lookup of ``batch`` x ``queries`` does work in, and the steps of
     walking every block.  A scheduled level's count is reduced from the
@@ -398,8 +310,8 @@ def schedule_keyblocks(schedules, batch: int, queries: int, shapes,
     then repeat the last); an unscheduled level visits all it has."""
     visited = jnp.int32(0)
     possible = 0
-    for plan, S in zip(_level_plans(queries, shapes, q_blk,
-                                    p_blk_target, pack_rows), schedules):
+    for plan, S in zip(_level_plans(queries, shapes, q_blk, p_blk_target),
+                       schedules):
         if plan is None:
             continue
         steps = batch * (plan.qp // plan.t) * plan.n_pblocks
@@ -414,8 +326,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                   p_blk_target: int, interpret: bool,
                   corr_precision=jax.lax.Precision.HIGHEST,
                   lookup_style: str = "matmul",
-                  schedule: Optional[jax.Array] = None,
-                  pack_rows: bool = False) -> jax.Array:
+                  schedule: Optional[jax.Array] = None) -> jax.Array:
     """f1 [B,Q,C], f2_level [B,H2,W2,C] (or its [n,B,H2,W2,C] term planes,
     :func:`f2_terms`), coords [B,Q,2] -> [B,Q,(2r+1)^2] float32.
 
@@ -433,46 +344,29 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
     f1, f2, corr_precision = _kernel_operands(f1, f2_level, corr_precision)
     n_terms = f2.shape[0]
 
-    # All padding/blocking arithmetic lives in lint/budget.py — the static
-    # VMEM budget analyzer checks the very plan this call executes.
-    plan = corr_level_plan(Q, H2, W2, q_blk=q_blk,
-                           p_blk_target=p_blk_target, pack_rows=pack_rows)
+    # All padding/blocking arithmetic is the plan's (kernel_plans.py); the
+    # static VMEM budget analyzer prices the very plan this call executes.
+    plan = corr_level_plan(Q, H2, W2, q_blk=q_blk, p_blk_target=p_blk_target)
     T, Qp = plan.t, plan.qp
     f1, coords = _pad_queries(plan, f1, coords)
 
-    # Row packing: when the real row width W2 uses at most half the 128
-    # lanes, lay `pack` consecutive rows side by side in one packed row so
-    # the corr tile covers pack x more of the map (no lane-padding waste).
-    pack, W2p, h2_blk = plan.pack, plan.w2p, plan.h2_blk
+    # pad W2 to lane width so the in-kernel [T, Pblk] -> [T, h2_blk, W2p]
+    # reshape is a supported Mosaic shape cast; padded zero columns
+    # correlate to zero, so any one-hot match on them contributes 0
+    # (= zeros padding) — and the vector unit would have padded the
+    # lanes anyway.
+    W2p, h2_blk = plan.w2p, plan.h2_blk
     n_pblocks = plan.n_pblocks
-    if pack > 1:
-        H2pkp = plan.rows_padded             # packed rows, block-padded
-        f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2pkp * pack - H2), (0, 0),
+    H2p = plan.rows_padded
+    if H2p != H2 or W2p != W2:
+        # zero rows/cols correlate to zero -> identical to zeros padding
+        # at the image boundary.
+        f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2p - H2), (0, W2p - W2),
                           (0, 0)))
-        f2 = f2.reshape(n_terms, B, H2pkp, pack * W2, C)
-        if W2p != pack * W2:
-            f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, 0),
-                              (0, W2p - pack * W2), (0, 0)))
-        body = functools.partial(
-            _packed_body, level_scale=1.0 / (2.0 ** level),
-            corr_scale=1.0 / (C ** 0.5), radius=radius, h2_blk=h2_blk,
-            w2=W2p, w2_real=W2, pack=pack, corr_precision=corr_precision)
-    else:
-        # pad W2 to lane width so the in-kernel [T, Pblk] -> [T, h2_blk, W2p]
-        # reshape is a supported Mosaic shape cast; padded zero columns
-        # correlate to zero, so any one-hot match on them contributes 0
-        # (= zeros padding) — and the vector unit would have padded the
-        # lanes anyway.
-        H2p = plan.rows_padded
-        if H2p != H2 or W2p != W2:
-            # zero rows/cols correlate to zero -> identical to zeros padding
-            # at the image boundary.
-            f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2p - H2), (0, W2p - W2),
-                              (0, 0)))
-        body = functools.partial(
-            _window_body, level_scale=1.0 / (2.0 ** level),
-            corr_scale=1.0 / (C ** 0.5), radius=radius, h2_blk=h2_blk,
-            w2=W2p, corr_precision=corr_precision, lookup_style=lookup_style)
+    body = functools.partial(
+        _window_body, level_scale=1.0 / (2.0 ** level),
+        corr_scale=1.0 / (C ** 0.5), radius=radius, h2_blk=h2_blk,
+        w2=W2p, corr_precision=corr_precision, lookup_style=lookup_style)
     f2 = f2.reshape(n_terms, B, -1, C)
 
     grid = (B, Qp // T, n_pblocks)
@@ -539,7 +433,6 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                        interpret: Optional[bool] = None,
                        corr_precision=jax.lax.Precision.HIGHEST,
                        lookup_style: str = "matmul",
-                       pack_rows: bool = False,
                        schedules: Optional[Tuple] = None) -> jax.Array:
     B, H, W, C = fmap1.shape
     Q = H * W
@@ -549,12 +442,10 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
         raise ValueError(f"lookup_style must be 'matmul' or 'vpu', "
                          f"got {lookup_style!r}")
     interp = _use_interpret() if interpret is None else interpret
-    if pack_rows and not interp:
-        raise ValueError(_PACK_REFUSAL)
     if schedules is None:       # a caller that does not count key blocks
         schedules = lookup_schedules(
             coords, level_shapes(f2_levels), radius, q_blk=q_blk,
-            p_blk_target=p_blk_target, pack_rows=pack_rows)
+            p_blk_target=p_blk_target)
     f1 = fmap1.reshape(B, Q, C)
     cf = coords.reshape(B, Q, 2)
     outs = []
@@ -570,17 +461,16 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                 f1, f2l, cf, radius, i, q_blk=q_blk,
                 p_blk_target=p_blk_target, interpret=interp,
                 corr_precision=corr_precision, lookup_style=lookup_style,
-                schedule=sched, pack_rows=pack_rows))
+                schedule=sched))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
                  coords: jax.Array, radius: int,
                  corr_precision=jax.lax.Precision.HIGHEST,
                  q_blk: int = 128, p_blk_target: int = 4096,
                  lookup_style: str = "matmul",
-                 pack_rows: bool = False,
                  f2_planes: Optional[Tuple[jax.Array, ...]] = None,
                  schedules: Optional[Tuple] = None) -> jax.Array:
     """Pallas-fused correlation lookup.
@@ -595,7 +485,7 @@ def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
 
     ``schedules`` (optional): :func:`lookup_schedules` of these coords, from
     a caller that also counts them (:class:`FusedLookup`); None computes the
-    same here.  Either way the rule of ``lint/budget.corr_level_scheduled``
+    same here.  Either way the rule of ``kernel_plans.corr_level_scheduled``
     decides, per level, whether a launch walks every key row-block or only
     those its tiles' windows touch.
     """
@@ -603,16 +493,15 @@ def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
         fmap1, f2_levels if f2_planes is None else f2_planes, coords, radius,
         q_blk=q_blk, p_blk_target=p_blk_target,
         corr_precision=corr_precision, lookup_style=lookup_style,
-        pack_rows=pack_rows, schedules=schedules)
+        schedules=schedules)
 
 
 def _fused_lookup_fwd(fmap1, f2_levels, coords, radius, corr_precision,
-                      q_blk, p_blk_target, lookup_style, pack_rows,
-                      f2_planes, schedules):
+                      q_blk, p_blk_target, lookup_style, f2_planes,
+                      schedules):
     return fused_lookup(fmap1, f2_levels, coords, radius, corr_precision,
-                        q_blk, p_blk_target, lookup_style, pack_rows,
-                        f2_planes, schedules), (fmap1, f2_levels, coords,
-                                                schedules)
+                        q_blk, p_blk_target, lookup_style, f2_planes,
+                        schedules), (fmap1, f2_levels, coords, schedules)
 
 
 def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
@@ -631,7 +520,7 @@ def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
 
 
 def _fused_lookup_bwd(radius, corr_precision, q_blk, p_blk_target,
-                      lookup_style, pack_rows, residuals, g):
+                      lookup_style, residuals, g):
     # the planes are a function of f2_levels that the forward precomputed:
     # their cotangent is zero (None), f2_levels carry the gradient; the
     # schedules are integer metadata (float0, as the ragged sizes are)
@@ -662,12 +551,10 @@ class FusedLookup:
 
     def __init__(self, fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
                  radius: int, corr_precision="highest", q_blk: int = 128,
-                 p_blk_target: int = 4096, lookup_style: str = "matmul",
-                 pack_rows: bool = False):
+                 p_blk_target: int = 4096, lookup_style: str = "matmul"):
         self.radius, self.prec = radius, as_precision(corr_precision)
-        self.opts = (q_blk, p_blk_target, lookup_style, pack_rows)
-        self.plan_args = dict(q_blk=q_blk, p_blk_target=p_blk_target,
-                              pack_rows=pack_rows)
+        self.opts = (q_blk, p_blk_target, lookup_style)
+        self.plan_args = dict(q_blk=q_blk, p_blk_target=p_blk_target)
         self.f2_levels = tuple(fmap2_pyramid(fmap2.astype(jnp.float32),
                                              num_levels))
         self.f2_planes = tuple(f2_terms(fmap1.dtype, lvl, self.prec)
@@ -695,11 +582,10 @@ class FusedLookup:
 def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
                       radius: int, corr_precision="highest",
                       q_blk: int = 128, p_blk_target: int = 4096,
-                      lookup_style: str = "matmul",
-                      pack_rows: bool = False) -> FusedLookup:
+                      lookup_style: str = "matmul") -> FusedLookup:
     """Build the per-iteration lookup closure used by models/raft.py."""
     return FusedLookup(fmap1, fmap2, num_levels, radius, corr_precision,
-                       q_blk, p_blk_target, lookup_style, pack_rows)
+                       q_blk, p_blk_target, lookup_style)
 
 
 # ---------------------------------------------------------------------------
@@ -783,11 +669,9 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
     f1, f2, corr_precision = _kernel_operands(f1, f2_level, corr_precision)
     n_terms = f2.shape[0]
 
-    # identical padding/blocking plan to the dense path (lint/budget.py
-    # prices exactly this); row packing does not compose with per-item page
-    # addressing, so ragged levels always run unpacked.
-    plan = corr_level_plan(Q, H2, W2, q_blk=q_blk,
-                           p_blk_target=p_blk_target, pack_rows=False)
+    # identical padding/blocking plan to the dense path (kernel_plans.py;
+    # lint/budget.py prices exactly this)
+    plan = corr_level_plan(Q, H2, W2, q_blk=q_blk, p_blk_target=p_blk_target)
     T, Qp = plan.t, plan.qp
     if Qp != Q:
         f1 = jnp.pad(f1, ((0, 0), (0, Qp - Q), (0, 0)))
@@ -931,9 +815,8 @@ def make_ragged_fused_lookup(fmap1: jax.Array, fmap2: jax.Array,
     """Ragged twin of :func:`make_fused_lookup` for mixed-resolution batches
     sharing one max box: masks frame-1 features and builds the re-masked
     pyramid and its kernel planes once, then every GRU iteration runs the
-    page-scheduled ragged kernel.  ``pack_rows`` does not apply: row packing
-    does not compose with per-item pages (and page scheduling IS the
-    key-block schedule).
+    page-scheduled ragged kernel (page scheduling IS the key-block
+    schedule).
     """
     prec = as_precision(corr_precision)
     f2_levels = tuple(ragged_pyramid(fmap2.astype(jnp.float32), sizes8,

@@ -67,13 +67,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..lint.budget import GRU_HALO, GRU_TAPS, gru_row_plan, gru_vmem_limit
+from ..kernel_plans import GRU_HALO, GRU_TAPS, gru_row_plan, gru_vmem_limit
 from ..lint.contracts import contract
 from ..telemetry.trace import stage
 from .conv import conv2d
 
-# Kernel geometry constants live in lint/budget.py so the static VMEM
-# analyzer and the kernel agree by construction (lint rule B4).
+# Kernel geometry constants live in kernel_plans.py, where the static VMEM
+# analyzer reads them too (lint rule B4).
 _HALO = GRU_HALO   # pass-1 recompute halo rows: q2 reads r2*h1 at +-2,
 #                    r2's conv +-2
 _K = GRU_TAPS      # separable tap count (1x5 / 5x1)
@@ -254,7 +254,7 @@ def _pallas_gru(hm: jax.Array, c1: jax.Array, c2: jax.Array, fw: dict,
         interpret=interpret,
         # f32 I/O at 8 rows x 128 columns needs 17.03M of scoped VMEM, over
         # the compiler's 16 MiB default; whole rows of a wider frame need
-        # more (lint/budget.gru_vmem_limit reads it from the row plan)
+        # more (kernel_plans.gru_vmem_limit reads it from the row plan)
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
     )(hm, hm, hm, c1, c1, c1, c2, c2, c2, *weights)
 
@@ -320,7 +320,8 @@ def _gru_fused_impl(p, h, motion, ctx, block_rows, interpret, impl):
     fw = jax.tree.map(lambda a: a.astype(jnp.float32),
                       fuse_gru_weights(p, hidden, ctx_dim))
 
-    # padding plan shared with the static VMEM analyzer (lint/budget.py):
+    # the kernel's padding plan (kernel_plans.py; the static VMEM analyzer
+    # prices the same one):
     # Hp multiple of T, Wc the aligned conv-output width, Wp = Wc + the
     # tap radius of zeros each side
     plan = gru_row_plan(H, W, T)

@@ -63,8 +63,8 @@ MANIFEST_VERSION = 2      # 2: entries carry their execution device ids
 MANIFEST_NAME = "manifest.json"
 
 # The engine's executable-cache key, in order.  raftlint B5 checks this
-# literal stays arity-synced with the tuples lint/budget.enumerate_warmup_grid
-# emits — a key-schema drift between the compiler and the cache would
+# literal stays arity-synced with the tuples
+# serving/config.enumerate_warmup_grid emits — a key-schema drift between the compiler and the cache would
 # silently mis-key every entry.
 KEY_FIELDS = ("kind", "h", "w", "b", "policy")
 
@@ -188,7 +188,7 @@ class EngineCache:
 
     def write_manifest(self, grid) -> None:
         """Stamp the directory with identity + the warmup-grid signature
-        (lint/budget.enumerate_warmup_grid output) — the authoritative
+        (serving/config.enumerate_warmup_grid output) — the authoritative
         list of keys a warm directory is expected to hold."""
         self.dir.mkdir(parents=True, exist_ok=True)
         manifest = {

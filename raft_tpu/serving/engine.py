@@ -24,13 +24,12 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from ..config import RAFTConfig, adaptive_iters
-from ..lint.budget import enumerate_warmup_grid
 from ..lint.concurrency import guarded_by
 from ..telemetry import spans as tlm_spans
 from ..telemetry.log import get_logger
 from ..telemetry.trace import host_stage
 from ..telemetry.watchdogs import watched_lock
-from .config import ServeConfig
+from .config import ServeConfig, enumerate_warmup_grid
 
 _log = get_logger("serve")
 
@@ -124,7 +123,7 @@ class InferenceEngine:
         # box, so ONE (kind, b, policy) executable serves every declared
         # bucket — the cache key keeps its 5-tuple schema, but only max-box
         # (h, w) values ever appear in it (the warmup grid collapses to
-        # O(batch-steps), lint/budget.enumerate_warmup_grid)
+        # O(batch-steps), serving/config.enumerate_warmup_grid)
         self.ragged = bool(sconfig.ragged)
         self.max_box = sconfig.max_box
         # aot_cache.EngineCache or None: warmup load-or-compiles through
@@ -384,10 +383,11 @@ class InferenceEngine:
         t0 = time.monotonic()
         n = 0
         loaded = 0
-        # the grid is enumerated by the static budget analyzer
-        # (lint/budget.py) and consumed here, so `raftlint --budget`
-        # capacity reports and the live compile surface are one list by
-        # construction — the parity test pins it anyway
+        # the grid is enumerated beside ServeConfig (serving/config.py) and
+        # the static budget analyzer (lint/budget.py) reads the same list,
+        # so `raftlint --budget` capacity reports and the live compile
+        # surface are one list by construction — the parity test pins it
+        # anyway
         grid = enumerate_warmup_grid(self.config, self.sconfig,
                                      stream=self.stream,
                                      chaos=self.faults is not None)

@@ -1,8 +1,8 @@
 """Serving metric set + compat shim over the shared telemetry registry.
 
 The Counter / Gauge / Histogram / Registry primitives were born here and
-now live in :mod:`raft_tpu.telemetry.registry`, where the training loop,
-``bench.py`` and the data loaders count with the same classes (one
+now live in :mod:`raft_tpu.telemetry.registry`, where the training loop
+and the data loaders count with the same classes (one
 observability spine — OBSERVABILITY.md).  This module re-exports them
 unchanged (``from raft_tpu.serving.metrics import Counter`` keeps working
 and *is* the telemetry class) and keeps the serving-specific part: the

@@ -7,7 +7,7 @@ dependencies.  All mutation is lock-guarded; ``observe``/``inc`` are a dict
 update and an add, cheap enough to sit on the request path.
 
 Grown out of ``raft_tpu/serving/metrics.py`` (which keeps a compat shim +
-the serving-specific metric set): the training loop, ``bench.py`` and the
+the serving-specific metric set): the training loop and the
 data loaders count with the same primitives, so ``tools/tlm.py`` and the
 run-event log (:mod:`raft_tpu.telemetry.events`) consume one format
 everywhere.

@@ -3,8 +3,8 @@
 RAFT's forward pass is ~10 structurally identical GRU iterations — without
 names, an xprof trace is a wall of indistinguishable fusions and nobody can
 say *which* stage regressed or recompiled.  ``stage(name)`` wraps
-``jax.named_scope`` so the op names XLA emits (and tools/profile_breakdown
-reports) carry ``raft/fnet``, ``raft/corr_lookup``, ``update/gru`` …
+``jax.named_scope`` so the op names XLA emits (and the engine's stage map
+records) carry ``raft/fnet``, ``raft/corr_lookup``, ``update/gru`` …
 prefixes; it also maintains a thread-local stage stack that
 :mod:`watchdogs` reads to attribute recompiles and NaN events to the stage
 that produced them.

@@ -2,7 +2,7 @@
 multi-device sharding paths are exercised without TPU hardware (SURVEY.md §4).
 
 The force-CPU recipe lives in _cpu_backend.py at the repo root (shared with
-__graft_entry__.dryrun_multichip and bench.py).
+__graft_entry__.dryrun_multichip and the tools' ``--cpu`` flags).
 """
 
 import os

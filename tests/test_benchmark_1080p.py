@@ -156,21 +156,23 @@ def test_serve_warms_four_executables_and_the_analyzer_prices_the_chip(cell):
     one bucket (the engine's warm-up iterates this very list), and
     ``lint/budget.analyze``'s peak with the pair program's temporaries
     within 15 % of the chip's own ``peak_hbm_gb`` in the cell."""
+    from raft_tpu.kernel_plans import VMEM_BYTES
     from raft_tpu.lint import budget
-    from raft_tpu.serving.config import ServeConfig, parse_buckets
+    from raft_tpu.serving.config import (ServeConfig, enumerate_warmup_grid,
+                                         parse_buckets)
 
     rconfig, args = _serve(cell["config"])
     sconfig = ServeConfig(buckets=parse_buckets(args.buckets),
                           max_batch=args.max_batch,
                           max_sessions=args.max_sessions)
-    keys = budget.enumerate_warmup_grid(rconfig, sconfig)
+    keys = enumerate_warmup_grid(rconfig, sconfig)
     assert keys == [("pair", 1080, 1920, b, "fixed") for b in (1, 2, 4, 8)]
     report = budget.analyze(rconfig, sconfig, device_kind="tpu-v5e")
     assert report["grid"]["size"] == 4 and not report["violations"]
     gru = report["buckets"][0]["pallas"]["gru"]
     # 53.23M is what the chip's compiler asked for inside this program
     assert gru["fits"] and gru["vmem_limit"] > 53.23 * 2 ** 20
-    assert gru["vmem_limit"] > budget.VMEM_BYTES
+    assert gru["vmem_limit"] > VMEM_BYTES
     priced = report["totals"]["peak_with_pair_temps_bytes"] / 1e9
     assert abs(priced - PEAK_HBM_GB) / PEAK_HBM_GB < 0.15, priced
 

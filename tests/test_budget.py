@@ -2,7 +2,8 @@
 
 Covers the ISSUE-16 acceptance surface: eval_shape byte accounting,
 SlotPool sizing and donation accounting, the Pallas block-plan arithmetic
-(shared with the kernels — identity-checked, not just value-checked),
+(raft_tpu/kernel_plans.py, the kernels' own — identity-checked, not just
+value-checked),
 headroom monotonicity, EXACT grid-enumeration parity against a live warm
 engine, and the CLI gate (JSON output, oversized-config strict failure,
 grid-size regression vs a committed baseline).
@@ -24,8 +25,10 @@ jax = pytest.importorskip("jax")
 import numpy as np  # noqa: E402
 
 from raft_tpu.config import RAFTConfig, init_rng  # noqa: E402
+from raft_tpu import kernel_plans  # noqa: E402
 from raft_tpu.lint import budget  # noqa: E402
-from raft_tpu.serving.config import ServeConfig  # noqa: E402
+from raft_tpu.serving.config import (ServeConfig,  # noqa: E402
+                                     enumerate_warmup_grid)
 
 BUCKET = (32, 48)
 
@@ -77,27 +80,27 @@ def test_slot_specs_shapes(config, pspecs):
 
 def test_enumeration_pairwise_only(config):
     sconfig = small_serve(max_sessions=0)
-    keys = budget.enumerate_warmup_grid(config, sconfig)
+    keys = enumerate_warmup_grid(config, sconfig)
     assert {k[0] for k in keys} == {"pair"}
     assert len(keys) == len(sconfig.batch_steps)
 
 
 def test_enumeration_stream_kinds_and_dedup(config):
     sconfig = small_serve(max_batch=1)   # batch_steps == (1,)
-    keys = budget.enumerate_warmup_grid(config, sconfig)
+    keys = enumerate_warmup_grid(config, sconfig)
     # scommit@1 appears in both the width-1 block and the per-step block:
     # deduplicated exactly like the engine's `if key in self._exec` skip
     assert len(keys) == len(set(keys))
     assert {k[0] for k in keys} == {"pair", "encode", "stream", "szero",
                                     "scommit", "sbatch"}
     assert ("spoison", *BUCKET, 1, "fixed") not in keys
-    chaos_keys = budget.enumerate_warmup_grid(config, sconfig, chaos=True)
+    chaos_keys = enumerate_warmup_grid(config, sconfig, chaos=True)
     assert ("spoison", *BUCKET, 1, "fixed") in chaos_keys
 
 
 def test_enumeration_policy_resolution(config):
     sconfig = small_serve(iters_policy="converge:1e-2")
-    keys = budget.enumerate_warmup_grid(config, sconfig)
+    keys = enumerate_warmup_grid(config, sconfig)
     assert {k[4] for k in keys} == {"converge:1e-2"}
 
 
@@ -110,8 +113,8 @@ def test_grid_parity_with_live_warm_engine(config):
     params = init_raft(init_rng(0), config)
     eng = InferenceEngine(config, params, sconfig, stream=True)
     eng.warmup(verbose=False)
-    expected = budget.enumerate_warmup_grid(config, sconfig, stream=True,
-                                            chaos=False)
+    expected = enumerate_warmup_grid(config, sconfig, stream=True,
+                                     chaos=False)
     assert sorted(expected) == list(eng.keys())
     assert len(expected) == eng.executables
 
@@ -120,44 +123,71 @@ def test_grid_parity_with_live_warm_engine(config):
 
 
 def test_corr_level_plan_values():
-    plan = budget.corr_level_plan(24, 4, 6, q_blk=128, p_blk_target=4096)
-    assert (plan.t, plan.qp, plan.pack) == (24, 24, 1)
+    plan = kernel_plans.corr_level_plan(24, 4, 6, q_blk=128,
+                                        p_blk_target=4096)
+    assert (plan.t, plan.qp) == (24, 24)
     assert plan.w2p == 128                       # lane padding
     assert plan.h2_blk == 4 and plan.n_pblocks == 1
     # full-scale level 0 at 432x1024: Q = 54*128, map 54x128
-    plan = budget.corr_level_plan(54 * 128, 54, 128, q_blk=128,
-                                  p_blk_target=4096)
+    plan = kernel_plans.corr_level_plan(54 * 128, 54, 128, q_blk=128,
+                                        p_blk_target=4096)
     assert plan.t == 128 and plan.w2p == 128
     assert plan.h2_blk == 32 and plan.rows_padded == 64
     assert plan.n_pblocks == 2
 
 
-def test_corr_level_plan_packing():
-    # 8-wide rows pack 16 per lane row
-    plan = budget.corr_level_plan(64, 32, 8, q_blk=128, p_blk_target=4096,
-                                  pack_rows=True)
-    assert plan.pack == 16
-    assert plan.rows == 2                        # ceil(32 / 16)
-    assert plan.w2p == 128
-    with pytest.raises(ValueError):
-        budget.corr_level_plan(64, 0, 8, q_blk=128, p_blk_target=4096)
+def test_corr_level_plan_refuses_a_degenerate_level():
+    # a map pooled away to nothing has no plan: the kernel returns zeros
+    # for it before it asks for one
+    with pytest.raises(ValueError, match="degenerate level 0x8"):
+        kernel_plans.corr_level_plan(64, 0, 8, q_blk=128, p_blk_target=4096)
+
+
+@pytest.mark.parametrize("w2,w2p", [(40, 128), (62, 128), (100, 128),
+                                    (240, 256)])
+def test_corr_level_plan_pads_rows_to_whole_lanes(w2, w2p):
+    """A width that is no multiple of 128 (320, 496, 800 and 1920 pixels at
+    the 1/8 grid) is stored lane-padded, and ``rows`` is the map's rows:
+    nothing lays rows side by side."""
+    h2 = 46
+    plan = kernel_plans.corr_level_plan(h2 * w2, h2, w2, q_blk=128,
+                                        p_blk_target=4096)
+    assert plan.w2p == w2p and plan.w2p % kernel_plans.LANE == 0
+    assert plan.rows == h2
+    assert plan.h2_blk == 4096 // w2p
+    assert plan.rows_padded == plan.n_pblocks * plan.h2_blk >= h2
+
+
+def test_kernel_plans_imports_no_jax():
+    """The leaf every layer reads: the linter's AST rules and a server that
+    loads its executables from the AOT cache import it, and must not pay
+    for (or need) jax to know a plan."""
+    import subprocess
+    code = ("import sys; import raft_tpu.kernel_plans; "
+            "bad = [m for m in ('jax', 'jaxlib', 'numpy') "
+            "if m in sys.modules]; assert not bad, bad")
+    r = subprocess.run([sys.executable, "-c", code], cwd=str(REPO),
+                       capture_output=True, text=True)
+    assert r.returncode == 0, r.stderr
 
 
 def test_gru_row_plan_halo_arithmetic():
-    plan = budget.gru_row_plan(30, 41, 8)
+    plan = kernel_plans.gru_row_plan(30, 41, 8)
     assert (plan.hp, plan.wc, plan.wp, plan.n_rb) == (32, 48, 52, 4)
     with pytest.raises(ValueError):
-        budget.gru_row_plan(30, 41, budget.GRU_HALO - 1)
+        kernel_plans.gru_row_plan(30, 41, kernel_plans.GRU_HALO - 1)
 
 
 def test_kernels_share_the_budget_plan_helpers():
-    # identity, not equality: the kernels must execute the SAME functions
-    # the analyzer budgets with (lint rule B4's structural guarantee)
+    # identity, not equality: the analyzer must budget with the SAME
+    # functions the kernels execute (lint rule B4's structural guarantee)
     from raft_tpu.ops import corr_pallas, gru_pallas
-    assert corr_pallas.corr_level_plan is budget.corr_level_plan
-    assert gru_pallas.gru_row_plan is budget.gru_row_plan
-    assert gru_pallas._HALO == budget.GRU_HALO
-    assert gru_pallas._K == budget.GRU_TAPS
+    assert (corr_pallas.corr_level_plan is budget.corr_level_plan
+            is kernel_plans.corr_level_plan)
+    assert (gru_pallas.gru_row_plan is budget.gru_row_plan
+            is kernel_plans.gru_row_plan)
+    assert gru_pallas._HALO == kernel_plans.GRU_HALO
+    assert gru_pallas._K == kernel_plans.GRU_TAPS
 
 
 def test_vmem_envelopes(config):
@@ -167,7 +197,7 @@ def test_vmem_envelopes(config):
     assert len(corr["levels"]) == config.corr_levels
     full = RAFTConfig.full()
     env = budget.corr_vmem_envelope(full, (432, 1024))
-    assert env["fits"] and env["worst_block_bytes"] < budget.VMEM_BYTES
+    assert env["fits"] and env["worst_block_bytes"] < kernel_plans.VMEM_BYTES
     # a huge Q-block makes the [T, Pblk] corr tile alone blow VMEM — the
     # envelope must overflow and say so
     fat = RAFTConfig.full(pallas_q_blk=4096, corr_impl="pallas")
@@ -193,7 +223,7 @@ def test_corr_envelope_prices_the_dtypes_the_kernel_holds(kw, planes, mib):
     assert [lv["f2_planes"] for lv in env["levels"]] == planes
     got = [round(lv["block_bytes"] / 2 ** 20, 2) for lv in env["levels"]]
     assert got == mib
-    assert env["fits"] and env["worst_block_bytes"] < budget.VMEM_BYTES
+    assert env["fits"] and env["worst_block_bytes"] < kernel_plans.VMEM_BYTES
 
 
 def test_gru_vmem_envelope_scales_with_block_rows():

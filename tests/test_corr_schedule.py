@@ -1,5 +1,5 @@
 """The lookup kernel's key-block schedule: the rule that gives a level one
-(``lint/budget.corr_level_scheduled``, read from the level's block plan
+(``kernel_plans.corr_level_scheduled``, read from the level's block plan
 alone), its values against the all-blocks walk (bit for bit: a skipped block
 added exact zeros, the visited ones keep their order), its counts, and the
 whole model under it against the benchmark's plain reference.  The kernel
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from raft_tpu.lint.budget import corr_level_plan, corr_level_scheduled
+from raft_tpu.kernel_plans import corr_level_plan, corr_level_scheduled
 from raft_tpu.ops.coords import coords_grid
 from raft_tpu.ops.corr import build_pyramid, fmap2_pyramid, lookup_dense
 from raft_tpu.ops.corr_pallas import (_fused_lookup_impl, _lookup_level,

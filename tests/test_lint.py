@@ -581,7 +581,7 @@ VMEM_LIMIT = 16 * 1024 * 1024
 def fits(nbytes):
     return nbytes <= VMEM_LIMIT
 """, """
-from raft_tpu.lint.budget import VMEM_BYTES
+from raft_tpu.kernel_plans import VMEM_BYTES
 
 def fits(nbytes):
     return nbytes <= VMEM_BYTES
@@ -689,6 +689,17 @@ def f(coords):
     return coords
 """
     assert "R9" in ids(scan_source(src))
+
+
+@pytest.mark.parametrize("path,fires", [
+    ("raft_tpu/kernel_plans.py", False),    # what the kernels ask for
+    ("raft_tpu/lint/budget.py", False),     # what the devices hold
+    ("raft_tpu/ops/corr_pallas.py", True),  # a kernel: it reads its plan
+    ("raft_tpu/serving/engine.py", True),
+])
+def test_b4_byte_constants_have_two_homes(path, fires):
+    src = "VMEM_BYTES = 32 * 1024 * 1024\n"
+    assert ("B4" in ids(scan_source(src, path=path))) == fires
 
 
 def test_r10_cli_surfaces_exempt():

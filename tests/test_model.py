@@ -73,13 +73,27 @@ def test_jit_and_iters_override():
                                atol=2e-2, rtol=1e-3)
 
 
-@pytest.mark.parametrize("impl", ["blockwise"])
-def test_corr_impls_agree(impl):
-    base = RAFTConfig.full(iters=3)
-    other = RAFTConfig.full(iters=3, corr_impl=impl)
+@pytest.fixture(scope="module")
+def dense_gather_flow():
+    """The reference path's flow (stored volume, take_along_axis lookup)."""
+    base = RAFTConfig.full(iters=3, corr_lookup="gather")
     params, im1, im2 = _params_and_images(base)
-    out_a, _ = raft_forward(params, im1, im2, base)
-    out_b, _ = raft_forward(params, im1, im2, other)
+    return params, im1, im2, raft_forward(params, im1, im2, base)[0]
+
+
+@pytest.mark.parametrize("path", [
+    dict(corr_impl="dense", corr_lookup="onehot"),
+    dict(corr_impl="blockwise", corr_lookup="gather"),
+    dict(corr_impl="blockwise", corr_lookup="onehot"),
+    dict(corr_impl="pallas", pallas_lookup_style="matmul"),
+    dict(corr_impl="pallas", pallas_lookup_style="vpu"),
+], ids=lambda p: "-".join(p.values()))
+def test_corr_impls_agree(dense_gather_flow, path):
+    """Every correlation path ``_iterate_flow`` has, by value, against the
+    stored volume with the gather lookup."""
+    params, im1, im2, out_a = dense_gather_flow
+    out_b, _ = raft_forward(params, im1, im2,
+                            RAFTConfig.full(iters=3, **path))
     # the raw lookups agree to ~1e-6 (test_corr); recurrence amplifies the
     # different-summation-order noise, so compare relative to flow magnitude
     scale = np.abs(np.asarray(out_a.flow)).mean()

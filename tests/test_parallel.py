@@ -297,8 +297,8 @@ def test_shard_inference_halo_wider_than_slab():
 
 def test_ring_lookup_via_fused_kernel_matches_dense():
     """The ring pass riding the fused Pallas kernel per slab (global coords
-    shifted by the slab start row; window schedule + row packing on) must
-    equal the single-device dense lookup — the sequence-parallel path and
+    shifted by the slab start row; window schedule on) must equal the
+    single-device dense lookup — the sequence-parallel path and
     the first-party kernel composing."""
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -320,8 +320,9 @@ def test_ring_lookup_via_fused_kernel_matches_dense():
         lk = make_ring_lookup_local(
             f1l, f2l, levels, radius, SPATIAL_AXIS,
             precision=jax.lax.Precision.HIGHEST, kernel="pallas",
-            pallas_opts=dict(q_blk=64, p_blk_target=1024,
-                             pack_rows=True))
+            # 256: each 4-row slab is two row-blocks at level 0, so the
+            # slab's launch runs under a key-block schedule
+            pallas_opts=dict(q_blk=64, p_blk_target=256))
         return lk(cl)
 
     f = jax.jit(compat_shard_map(

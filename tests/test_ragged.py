@@ -311,7 +311,8 @@ def test_budget_grid_collapses_under_ragged():
     --ragged: >= 3x fewer warmup keys at 3 declared buckets, every key at
     the arena shape, and the budget baseline signature records the mode."""
     from raft_tpu.config import RAFTConfig
-    from raft_tpu.lint.budget import config_signature, enumerate_warmup_grid
+    from raft_tpu.lint.budget import config_signature
+    from raft_tpu.serving.config import enumerate_warmup_grid
 
     mconfig = RAFTConfig.small_model(iters=1)
     mk = lambda ragged: ServeConfig(
@@ -354,7 +355,7 @@ def test_ragged_warmup_one_executable_family(ragged_server):
     serves every declared resolution — the warmup grid holds ONLY max-box
     keys, exactly the set the lint budget enumerated, and its dense twin
     would have been 3x larger."""
-    from raft_tpu.lint.budget import enumerate_warmup_grid
+    from raft_tpu.serving.config import enumerate_warmup_grid
 
     server, config, _ = ragged_server
     eng = server.engine

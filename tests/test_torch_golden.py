@@ -108,9 +108,10 @@ def test_full_model_torch_parity_dense_onehot_default():
     assert err <= 1e-3 + 1e-3 * scale, (err, scale)
 
 
-def test_full_model_torch_parity_pallas_winpack():
-    """The fused kernel's window schedule + row packing must match the
-    official model end-to-end (W=128 -> fmap width 16: pack 8 at level 0).
+def test_full_model_torch_parity_pallas_window():
+    """The fused kernel under its key-block schedule must match the
+    official model end-to-end (W=128 -> fmap width 16, two row-blocks of 8
+    at level 0).
 
     Note the oracle constraint: sizes where a pyramid level collapses to
     1 px (e.g. W=120 -> level-3 width 1) make the torch/official
@@ -118,23 +119,20 @@ def test_full_model_torch_parity_pallas_winpack():
     an official-RAFT edge case, not a lookup bug; this framework returns
     zeros for degenerate levels instead."""
     tflows, jflows = _run_pair(False, B=1, H=128, W=128, iters=2,
-                               corr_impl="pallas",
-                               pallas_p_blk=1024, pallas_pack=True)
+                               corr_impl="pallas", pallas_p_blk=1024)
     err = np.abs(tflows[-1] - jflows[-1]).max()
     scale = np.abs(tflows[-1]).max()
     assert err <= 1e-3 + 1e-3 * scale, (err, scale)
 
 
-def test_full_model_torch_parity_pallas_winpack_160():
-    """Second geometry for the window/pack parity claim (VERDICT r2 item 7):
-    160x160 -> fmap 20x20, pyramid widths 20/10/5/2 — every level odd or
-    non-power-of-two but none degenerate (the oracle's align_corners
-    normalization stays finite), row packing >1 at several levels
-    (128-lane tiles over widths 20/10/5/2), and Q = 400 not a multiple of
-    the 128 query block."""
+def test_full_model_torch_parity_pallas_window_160():
+    """Second geometry for the window-schedule parity claim (VERDICT r2
+    item 7): 160x160 -> fmap 20x20, pyramid widths 20/10/5/2 — every level
+    odd or non-power-of-two but none degenerate (the oracle's align_corners
+    normalization stays finite), each lane-padded to 128, and Q = 400 not
+    a multiple of the 128 query block."""
     tflows, jflows = _run_pair(False, B=1, H=160, W=160, iters=2,
-                               corr_impl="pallas",
-                               pallas_p_blk=1024, pallas_pack=True)
+                               corr_impl="pallas", pallas_p_blk=1024)
     err = np.abs(tflows[-1] - jflows[-1]).max()
     scale = np.abs(tflows[-1]).max()
     assert err <= 1e-3 + 1e-3 * scale, (err, scale)

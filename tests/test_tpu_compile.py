@@ -27,14 +27,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from raft_tpu.ops import corr_pallas
-from raft_tpu.lint.budget import corr_level_plan, corr_level_scheduled
+from raft_tpu.kernel_plans import corr_level_plan, corr_level_scheduled
 from raft_tpu.ops.corr_pallas import (_lookup_level, _ragged_lookup_level,
                                       level_schedule)
 
 P = jax.lax.Precision
 H, W, C = 55, 128, 256          # Sintel bucket 440x1024 at the 1/8 grid
 HD = (135, 240)                 # 1080x1920 at the 1/8 grid
+CROP = (46, 62)                 # the 368x496 training crop at the 1/8 grid
 RADIUS = 4
 
 
@@ -93,7 +93,7 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
     ("coarsest-level", 3, dict(corr_precision=P.HIGHEST,
                                p_blk_target=4096), {}),
     # 19.18M of scoped VMEM: refused under the compiler's 16 MiB default,
-    # accepted under the limit the kernels request (lint/budget.VMEM_BYTES)
+    # accepted under the limit the kernels request (kernel_plans.VMEM_BYTES)
     ("vpu", 0, dict(corr_precision=P.DEFAULT, p_blk_target=4096,
                     lookup_style="vpu"), {}),
     # bfloat16 maps at HIGHEST (ops/corr_pallas.corr_terms): a bfloat16 NT
@@ -118,20 +118,28 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
                           scheduled=True, grid=HD), BF16_X3),
     ("hd-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
                           grid=HD), BF16_X3),
+    # 368x496, float32 as the train step holds its maps: rows of 62 and 31
+    # queries, neither a multiple of the 128 lanes they are stored in; two
+    # row-blocks at level 0, one at level 1
+    ("crop-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                            scheduled=True, grid=CROP), {}),
+    ("crop-level1", 1, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                            grid=CROP), {}),
 ])
 def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
     kw = dict(kw)
     grid = kw.pop("grid", (H, W))
-    if kw.pop("scheduled", False):
+    scheduled = kw.pop("scheduled", False)
+    if scheduled:
         fn = functools.partial(_scheduled_level, level=level, **kw)
     else:
         fn = functools.partial(_lookup_level, radius=RADIUS, level=level,
                                q_blk=128, interpret=False, **kw)
-    if grid == HD:      # the case is the program's: the rule gives the same
+    if grid != (H, W):  # the case is the program's: the rule gives the same
         plan = corr_level_plan(grid[0] * grid[1], grid[0] >> level,
                                grid[1] >> level, q_blk=128,
                                p_blk_target=4096)
-        assert corr_level_scheduled(plan) == (level < 3)
+        assert corr_level_scheduled(plan) == scheduled
     text = _compile(fn, *_corr_specs(one_chip, level, grid=grid, **dtypes))
     assert "tpu_custom_call" in text
     if dtypes:
@@ -165,7 +173,7 @@ def test_gru_kernel_compiles_for_v5e(one_chip, grid, dtype):
     16 MiB default; whole rows of 244 stored columns need 39.63M (bf16 I/O,
     alone; 53.23M inside the served 1080x1920 program, which the chip's
     compiler refused under 32 MiB: PR 26).  The kernel asks for what its row
-    plan needs (lint/budget.gru_vmem_limit)."""
+    plan needs (kernel_plans.gru_vmem_limit)."""
     from raft_tpu.models.update import init_sep_conv_gru, precompute_gru_ctx
     from raft_tpu.ops.gru_pallas import sep_conv_gru_pallas
 
@@ -254,30 +262,3 @@ def test_stage_map_of_the_served_program(served_program):
                   "raft/upsample"):
         assert any(st == scope or st.startswith(scope + "/")
                    for st in stages), scope
-
-
-# --- what the chip's compiler refuses today (ROADMAP A6/C3) ---------------
-# strict: the day a JAX upgrade or a repair makes it compile, the xfail
-# fails, and ops/corr_pallas._PACK_REFUSAL comes out.
-
-@pytest.mark.xfail(strict=True, raises=Exception,
-                   reason="MosaicError: infer-vector-layout: unsupported "
-                          "shape cast ... \"tpu.reshape\" (vector<128x9xi1>)"
-                          " -> vector<128x9x1x1xi1> (_packed_body)")
-def test_pallas_pack_is_refused_by_the_compiler(one_chip):
-    fn = functools.partial(_lookup_level, radius=RADIUS, level=1, q_blk=128,
-                           p_blk_target=4096, interpret=False,
-                           pack_rows=True)
-    _compile(fn, *_corr_specs(one_chip, 1))
-
-
-def test_pallas_pack_raises_before_mosaic():
-    """Asked for on the chip path (``interpret=False``) row packing raises
-    a ValueError that names the compiler's refusal — while tracing, needing
-    no topology; in interpret mode it still runs (its parity tests in
-    test_corr_pallas.py)."""
-    f1 = jnp.zeros((1, 8, 16, 32), jnp.float32)
-    coords = jnp.zeros((1, 8, 16, 2), jnp.float32)
-    with pytest.raises(ValueError, match="pallas_pack=True"):
-        corr_pallas._fused_lookup_impl(f1, (f1,), coords, RADIUS,
-                                       interpret=False, pack_rows=True)

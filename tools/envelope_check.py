@@ -15,10 +15,9 @@ prove the design point:
    sequential vs multi-process — the feed-vs-step crossover at the real
    shape.
 
-On CPU the step time is not a TPU forecast (use tools/bench_train.py on
-hardware for that); the memory analysis and the accum/loader structure
-transfer.  Writes one JSON line per stage; run with --out to also append
-to a log file.
+On CPU the step time is not a TPU forecast; the memory analysis and the
+accum/loader structure transfer.  Writes one JSON line per stage; run with
+--out to also append to a log file.
 """
 
 from __future__ import annotations

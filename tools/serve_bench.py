@@ -214,10 +214,11 @@ def budget_crosscheck(server, prom):
     import jax
 
     from raft_tpu.lint import budget as lint_budget
+    from raft_tpu.serving.config import enumerate_warmup_grid
 
     engine = server.engine
     problems = []
-    expected = lint_budget.enumerate_warmup_grid(
+    expected = enumerate_warmup_grid(
         engine.config, engine.sconfig, stream=engine.stream,
         chaos=engine.faults is not None)
     live = list(engine.keys())

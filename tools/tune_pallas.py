@@ -119,13 +119,9 @@ def main() -> int:
     p.add_argument("--precision", default="highest",
                    choices=["highest", "default"],
                    help="corr-matmul precision to tune for ('default' = bf16 "
-                        "MXU inputs, the bench winner's setting)")
+                        "MXU inputs)")
     p.add_argument("--style", default="matmul", choices=["matmul", "vpu"],
                    help="window-lookup formulation inside the kernel")
-    p.add_argument("--pack", action="store_true",
-                   help="row-packed f2 lanes for narrow levels (packed "
-                        "levels use their own fixed contraction; --style "
-                        "only affects levels too wide to pack)")
     args = p.parse_args()
 
     from raft_tpu.compile_cache import configure_compile_cache
@@ -148,8 +144,7 @@ def main() -> int:
             else jax.lax.Precision.DEFAULT)
     print(f"# device: {dev.device_kind}  corr precision: {args.precision}  "
           f"lookup style: {args.style}  key-block schedule: by the "
-          f"kernel's rule (fine p_blk targets get it)  "
-          f"pack: {args.pack}")
+          f"kernel's rule (fine p_blk targets get it)")
 
     # (label, B, full-res H, W); fmaps are at os=8, C=256 (full model)
     shapes = [("eval 1x432x1024", 1, 432, 1024),
@@ -175,8 +170,7 @@ def main() -> int:
             fn = jax.jit(functools.partial(
                 _fused_lookup_impl, radius=args.radius, q_blk=q_blk,
                 p_blk_target=p_blk, interpret=False, corr_precision=prec,
-                lookup_style=args.style,
-                pack_rows=args.pack))
+                lookup_style=args.style))
             try:
                 dt = _measure(fn, (fmap1, f2_levels, coords),
                               reps=8 if args.quick else 20)
