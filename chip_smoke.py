@@ -278,8 +278,10 @@ def phase_kernels(meter, sz: Sizes) -> None:
     (``interpret=False`` / ``impl='kernel'``), executed on the chip and
     compared with their XLA oracles: the corr kernel at HIGHEST precision
     against ``lookup_dense`` at HIGHEST, 1e-4 (both exact f32, only the
-    summation order differs — the retired tools/hw_smoke.py's gate); the
-    GRU kernel against ``sep_conv_gru_xla`` at GRU_F32_TOL for f32 I/O and
+    summation order differs — the retired tools/hw_smoke.py's gate), and
+    its bfloat16 output against its float32 output rounded, bit for bit (the
+    kernel rounds the same float32 sums as it writes them); the GRU kernel
+    against ``sep_conv_gru_xla`` at GRU_F32_TOL for f32 I/O and
     5e-2 for bf16 I/O (the kernel rounds to bf16 at its boundary)."""
     import functools
 
@@ -341,6 +343,27 @@ def phase_kernels(meter, sz: Sizes) -> None:
         sched = lookup_schedules(coords, level_shapes(f2_levels), radius)
         errs["corr/scheduled_levels"] = [i for i, s in enumerate(sched)
                                          if s is not None]
+        # the window as the update block consumes it: float32 maps as above,
+        # and bfloat16 maps over a float32-pooled pyramid as the served
+        # program hands them over (level 0 one plane, the others three)
+        bf = jnp.bfloat16
+        served = (f1.astype(bf), (f2.astype(bf),) + tuple(
+            fmap2_pyramid(f2.astype(bf).astype(jnp.float32), levels)[1:]))
+        for name, maps, f32_out in (("f32-maps", (f1, f2_levels), got["rule"]),
+                                    ("bf16-maps", served, None)):
+            written = lambda dt: run(functools.partial(   # noqa: E731
+                _fused_lookup_impl, radius=radius, interpret=sz.interpret,
+                out_dtype=dt), *maps, coords)
+            if f32_out is None:
+                f32_out = written(jnp.float32)
+            bf16_out = written(bf)
+            rounded = f32_out.astype(bf).astype(np.float32)
+            key = f"corr/bf16_out_vs_f32_out_rounded/{name}"
+            errs[key] = int((bf16_out.view(np.uint32)
+                             != rounded.view(np.uint32)).sum())
+            check(errs[key] == 0,
+                  f"{errs[key]} values of the lookup written in bfloat16 at "
+                  f"{h}x{w} ({name}) are not the float32 output rounded")
 
         hid = mdim = ctxd = 128                    # full-model channel plan
         ks = jax.random.split(jax.random.PRNGKey(1), 4)

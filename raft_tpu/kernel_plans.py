@@ -79,6 +79,17 @@ def corr_level_plan(q: int, h2: int, w2: int, *, q_blk: int,
                          n_pblocks=rows_padded // h2_blk)
 
 
+def corr_window_vmem(plan: CorrLevelPlan, n: int, out_itemsize: int) -> int:
+    """VMEM bytes of how a lookup launch hands over its ``n`` x ``n`` windows
+    (``ops/corr_pallas._accumulate``), at the size of their tiles: the
+    float32 scratch ``[T, n, n]`` the visited row-blocks are summed in (each
+    window pads to whole (8, 128) tiles: 8 KiB at n = 9) and the output block
+    ``[T, n*n]`` in the consumer's dtype, which the pipeline holds twice."""
+    scratch = plan.t * round_up(n, SUBLANE) * LANE * 4
+    out_block = plan.t * round_up(n * n, LANE) * out_itemsize
+    return scratch + 2 * out_block
+
+
 def corr_level_scheduled(plan: CorrLevelPlan) -> bool:
     """THE rule for the lookup's key-block schedule, read from the level's
     plan alone: a level whose map is cut into more than one row-block is

@@ -37,7 +37,8 @@ from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 from ..kernel_plans import (GRU_HALO, GRU_TAPS, SUBLANE, VMEM_BYTES,
                             VMEM_CEILING_BYTES, corr_level_plan,
-                            gru_row_plan, gru_scoped_bytes, gru_vmem_limit)
+                            corr_window_vmem, gru_row_plan, gru_scoped_bytes,
+                            gru_vmem_limit)
 
 if TYPE_CHECKING:       # serving/ brings jax in: a name for annotations only
     from ..serving.config import Key
@@ -77,13 +78,17 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
     """Static VMEM envelope of the fused correlation kernel at ``bucket``.
 
     Per level: the pallas_call's resident blocks (f1/coords/f2 in, window
-    out) plus the program's dominant intermediates (the [T, Pblk] corr
-    tile and the one-hot interpolation matrices).  The maps are priced in
-    the dtypes the kernel holds them in, asked of the function that hands
-    them to it (``ops.corr_pallas.f2_terms``): for bfloat16 maps at
-    'highest' a bfloat16 f1 block and one (level 0) or three (pooled
-    levels) bfloat16 planes of the f2 block, else float32 blocks.
-    Everything else is float32.
+    out), the float32 scratch the windows are summed in, plus the program's
+    dominant intermediates (the [T, Pblk] corr tile and the one-hot
+    interpolation matrices).  The maps are priced in the dtypes the kernel
+    holds them in, asked of the function that hands them to it
+    (``ops.corr_pallas.f2_terms``): for bfloat16 maps at 'highest' a
+    bfloat16 f1 block and one (level 0) or three (pooled levels) bfloat16
+    planes of the f2 block, else float32 blocks.  The output block is
+    ``[T, n*n]`` in the compute dtype (what ``models/raft.py`` asks the
+    kernel to write), the scratch ``[T, n, n]`` float32, both at the size
+    of their VMEM tiles (``kernel_plans.corr_window_vmem``).  Everything
+    else is float32.
     """
     import jax
     from ..ops.corr_pallas import f2_terms
@@ -119,8 +124,9 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
         n_terms, map_bytes = planes.shape[0], planes.dtype.itemsize
         map_blocks = map_bytes * (plan.t * c             # f1 block
                                   + n_terms * pblk * c)  # f2 row block
-        f32_blocks = (plan.t * 2             # coords block
-                      + plan.t * n * n)      # output window block
+        f32_blocks = plan.t * 2              # coords block
+        window = corr_window_vmem(      # scratch + double-buffered output
+            plan, n, 2 if config.compute_dtype == "bfloat16" else 4)
         a_y = plan.t * n * plan.h2_blk
         a_x = plan.t * n * plan.w2p
         floats = (2 * f32_blocks             # double-buffered pipeline
@@ -131,7 +137,7 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
                   + plan.t * n * n)          # win
         # (the 'vpu' lookup style is not priced separately: the compiler
         # reports 19.18M for it at the default plan, 14% over this model)
-        bytes_ = 2 * map_blocks + 4 * floats
+        bytes_ = 2 * map_blocks + window + 4 * floats
         worst = max(worst, bytes_)
         levels.append({"level": level, "shape": [h2, w2],
                        "f2_planes": [n_terms, str(planes.dtype)],
@@ -397,6 +403,10 @@ def kind_footprint(config, pspecs, key: Key, capacity: int,
 #: volume.  From the chip compiler's ``memory_analysis()`` of the served
 #: programs (sandbox compiles for a described v5e): 5.63 GB at 32 x 440x1024
 #: (PR 23) and 6.48 GB at 8 x 1080x1920 (PR 26) are 390.5 and 390.6 bytes.
+#: Re-read in PR 29, whose lookup no longer returns a padded float32 window
+#: (57.7 MB a pair and level at 440x1024): 5,631,585,280 and 6,479,531,008
+#: bytes, 390.60 both.  The figure did not move because the program's peak
+#: lies in the encoders; the update loop's buffers fit under it.
 PAIR_TEMP_BYTES_PER_PIXEL = 391
 
 

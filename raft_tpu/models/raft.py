@@ -305,7 +305,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                 fmap1, fmap2, sizes8, config.corr_levels,
                 config.corr_radius, corr_precision=corr_prec,
                 q_blk=config.pallas_q_blk, p_blk_target=config.pallas_p_blk,
-                lookup_style=config.pallas_lookup_style)
+                lookup_style=config.pallas_lookup_style, out_dtype=cdt)
         else:
             # 'dense' and 'blockwise' share the masked blockwise twin — the
             # dense (HW)^2 volume has no ragged form worth building, and the
@@ -363,7 +363,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                 fmap1, fmap2, config.corr_levels, config.corr_radius,
                 corr_precision=corr_prec, q_blk=config.pallas_q_blk,
                 p_blk_target=config.pallas_p_blk,
-                lookup_style=config.pallas_lookup_style)
+                lookup_style=config.pallas_lookup_style, out_dtype=cdt)
         counts_keyblocks = True
     else:
         raise ValueError(config.corr_impl)
@@ -404,7 +404,9 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
         kb = kb0
         with stage("raft/corr_lookup"):
             if counts_keyblocks:
-                # the schedules the kernels are given are the ones counted
+                # the schedules the kernels are given are the ones counted;
+                # the kernels write cdt themselves (out_dtype above), so the
+                # cast is for the lookups that do not
                 sched = lookup.schedules(coords1)
                 corr = lookup(coords1, sched).astype(cdt)
                 kb = lookup.keyblocks(sched)

@@ -35,9 +35,14 @@ bfloat16 map is one term, a float32 map pooled from it is three (hi + mid +
 lo, split once where the pyramid is built), so bfloat16 maps cost one MXU
 pass at level 0 and three at the pooled levels; float32 maps take the MXU's
 own six-pass ``HIGHEST`` matmul.  Same products, same float32 sums — the
-passes left out multiplied zeros.  Interpolation, scaling and the output are
-float32 throughout.  Off-TPU backends run the kernel in Pallas interpret mode
-so CPU tests exercise identical code.
+passes left out multiplied zeros.  Interpolation, scaling and the sums over
+key row-blocks are float32 throughout, held in a VMEM scratch; a launch
+writes its windows once, rounded to the dtype its consumer states
+(``out_dtype``) and lane-dense (``[B, Q, n*n]``): a ``[.., 9, 9]`` float32
+array pads each query's 324 B to 8 KiB of HBM tiles, and converting and
+reshaping that cost a fifth of the served program's run (PR 29).  Off-TPU
+backends run the kernel in Pallas interpret mode so CPU tests exercise
+identical code.
 """
 
 from __future__ import annotations
@@ -184,30 +189,59 @@ def _window_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
             a_x, win_y, (((2,), (2,)), ((0,), (0,))),
             precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
-    # x-offset-major [T, n, n]; the flatten to n^2 happens outside the kernel
-    # (Mosaic has no shape cast merging two unaligned minor dims)
-    return win
+    return win          # x-offset-major [T, n, n]; _accumulate flattens it
 
 
-def _accumulate(out_ref, win, k):
-    @pl.when(k == 0)
+def _accumulate(acc_ref, out_ref, k, last, visit, window):
+    """The one way a lookup launch hands over its result.  Where ``visit``
+    (None: every step) holds, grid step ``k`` adds its key row-block's
+    ``window()`` ([T, n, n] float32) into the float32 scratch ``acc_ref``;
+    the step a query tile ends on (``last``), visited or not, writes the
+    output block once: the sums rounded to the output's dtype, a query's
+    n*n values side by side in the lanes of one row (x-offset-major)."""
+    def add():
+        win = window()
+
+        @pl.when(k == 0)
+        def _():
+            acc_ref[...] = win
+
+        @pl.when(k > 0)
+        def _():
+            acc_ref[...] = acc_ref[...] + win
+
+    if visit is None:
+        add()
+    else:
+        pl.when(visit)(add)
+
+    @pl.when(last)
     def _():
-        out_ref[0] = win
+        # Mosaic has no shape cast merging [T, n, n]'s two unaligned minor
+        # dims, so the n rows of a window are placed one by one: a strided
+        # sublane read of the scratch, a masked store at a static lane offset
+        n = acc_ref.shape[1]
+        for i in range(n):
+            out_ref[0, :, i * n:(i + 1) * n] = (
+                acc_ref[:, i, :].astype(out_ref.dtype))
 
-    @pl.when(k > 0)
-    def _():
-        out_ref[0] = out_ref[0] + win
+
+def _sums_scratch(T: int, n: int) -> list:
+    """``scratch_shapes`` of every lookup launch: :func:`_accumulate`'s
+    float32 sums (``kernel_plans.corr_window_vmem`` prices them)."""
+    return [pltpu.VMEM((T, n, n), jnp.float32)]
 
 
-def _level_kernel(f1_ref, coords_ref, f2_ref, out_ref, *, body):
+def _level_kernel(f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *, body):
     """One (batch, query-block, p-block) program: the k-th grid step visits
     f2 row-block k (full pass over the map)."""
     k = pl.program_id(2)
-    win = body(k, f1_ref, coords_ref, f2_ref)
-    _accumulate(out_ref, win, k)
+    _accumulate(acc_ref, out_ref, k, k == pl.num_programs(2) - 1, None,
+                lambda: body(k, f1_ref, coords_ref, f2_ref))
 
 
-def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, *, body):
+def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *,
+                   body):
     """Window-scheduled program: identical math to ``_level_kernel`` but the
     k-th grid step visits f2 row-block ``S[b, j*K + k]`` instead of row-block
     ``k``.  The schedule repeats its last needed block to fill the static
@@ -219,11 +253,9 @@ def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, *, body):
     at = pl.program_id(1) * pl.num_programs(2) + k
     sel = S_ref[b, at]
     prev = S_ref[b, at - jnp.minimum(k, 1)]      # step 0 has no previous
-
-    @pl.when((k == 0) | (sel != prev))
-    def _():
-        win = body(sel, f1_ref, coords_ref, f2_ref)
-        _accumulate(out_ref, win, k)
+    _accumulate(acc_ref, out_ref, k, k == pl.num_programs(2) - 1,
+                (k == 0) | (sel != prev),
+                lambda: body(sel, f1_ref, coords_ref, f2_ref))
 
 
 def _window_schedule(coords: jax.Array, level_scale: float, radius: int,
@@ -326,9 +358,11 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                   p_blk_target: int, interpret: bool,
                   corr_precision=jax.lax.Precision.HIGHEST,
                   lookup_style: str = "matmul",
-                  schedule: Optional[jax.Array] = None) -> jax.Array:
+                  schedule: Optional[jax.Array] = None,
+                  out_dtype=jnp.float32) -> jax.Array:
     """f1 [B,Q,C], f2_level [B,H2,W2,C] (or its [n,B,H2,W2,C] term planes,
-    :func:`f2_terms`), coords [B,Q,2] -> [B,Q,(2r+1)^2] float32.
+    :func:`f2_terms`), coords [B,Q,2] -> [B,Q,(2r+1)^2] in ``out_dtype``:
+    the float32 sums, rounded once where the kernel writes them.
 
     ``schedule`` (:func:`level_schedule` of the same coords and plan): the
     key row-blocks each query tile visits; None walks every block.  The
@@ -340,7 +374,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
     if H2 == 0 or W2 == 0:
         # degenerate pyramid level (map pooled away to nothing): every window
         # is fully out of bounds -> zeros padding
-        return jnp.zeros((B, Q, n * n), jnp.float32)
+        return jnp.zeros((B, Q, n * n), out_dtype)
     f1, f2, corr_precision = _kernel_operands(f1, f2_level, corr_precision)
     n_terms = f2.shape[0]
 
@@ -371,6 +405,8 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
 
     grid = (B, Qp // T, n_pblocks)
     f2_block = (n_terms, 1, h2_blk * W2p, C)     # every term of one row-block
+    out_shape = jax.ShapeDtypeStruct((B, Qp, n * n), out_dtype)
+    acc = _sums_scratch(T, n)
 
     if schedule is not None:
         if schedule.shape != grid:
@@ -391,13 +427,14 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                 pl.BlockSpec(f2_block,
                              lambda b, j, k, S: (0, b, S[b, j * K + k], 0)),
             ],
-            out_specs=pl.BlockSpec((1, T, n, n),
-                                   lambda b, j, k, S: (b, j, 0, 0)),
+            out_specs=pl.BlockSpec((1, T, n * n),
+                                   lambda b, j, k, S: (b, j, 0)),
+            scratch_shapes=acc,
         )
         out = pl.pallas_call(
             functools.partial(_window_kernel, body=body),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, Qp, n, n), jnp.float32),
+            out_shape=out_shape,
             interpret=interpret,
             compiler_params=_COMPILER_PARAMS,
         )(S, f1, coords, f2)
@@ -410,12 +447,12 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                 pl.BlockSpec((1, T, 2), lambda b, j, k: (b, j, 0)),
                 pl.BlockSpec(f2_block, lambda b, j, k: (0, b, k, 0)),
             ],
-            out_specs=pl.BlockSpec((1, T, n, n), lambda b, j, k: (b, j, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, Qp, n, n), jnp.float32),
+            out_specs=pl.BlockSpec((1, T, n * n), lambda b, j, k: (b, j, 0)),
+            out_shape=out_shape,
+            scratch_shapes=acc,
             interpret=interpret,
             compiler_params=_COMPILER_PARAMS,
         )(f1, coords, f2)
-    out = out.reshape(B, Qp, n * n)
     return out[:, :Q] if Qp != Q else out
 
 
@@ -423,17 +460,21 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
 # encoder produced (bfloat16 or float32 — corr_terms reads it); coords, the
 # corr tile (preferred_element_type), every scale factor (corr_scale,
 # level_scale: weak-typed Python floats, so nothing promotes to f64 even
-# under jax_enable_x64 on the CPU backend) and the output are float32.  The
-# contract pins that intent.
+# under jax_enable_x64 on the CPU backend) and the accumulator are float32;
+# the output is the float32 sum rounded once to the dtype its consumer
+# states (``out_dtype``: the update block's compute dtype, or float32 for a
+# caller that goes on adding, as the ring lookup does).  The contract pins
+# that intent.
 @contract(fmap1="f32|bf16[B,H,W,C]", coords="f32[B,H,W,2]",
-          _returns="f32[B,H,W,N]")
+          _returns="f32|bf16[B,H,W,N]")
 def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                        coords: jax.Array, radius: int,
                        q_blk: int = 128, p_blk_target: int = 4096,
                        interpret: Optional[bool] = None,
                        corr_precision=jax.lax.Precision.HIGHEST,
                        lookup_style: str = "matmul",
-                       schedules: Optional[Tuple] = None) -> jax.Array:
+                       schedules: Optional[Tuple] = None,
+                       out_dtype=jnp.float32) -> jax.Array:
     B, H, W, C = fmap1.shape
     Q = H * W
     if lookup_style not in ("matmul", "vpu"):
@@ -461,22 +502,28 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                 f1, f2l, cf, radius, i, q_blk=q_blk,
                 p_blk_target=p_blk_target, interpret=interp,
                 corr_precision=corr_precision, lookup_style=lookup_style,
-                schedule=sched))
+                schedule=sched, out_dtype=out_dtype))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 10))
 def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
                  coords: jax.Array, radius: int,
                  corr_precision=jax.lax.Precision.HIGHEST,
                  q_blk: int = 128, p_blk_target: int = 4096,
                  lookup_style: str = "matmul",
                  f2_planes: Optional[Tuple[jax.Array, ...]] = None,
-                 schedules: Optional[Tuple] = None) -> jax.Array:
+                 schedules: Optional[Tuple] = None,
+                 out_dtype=jnp.float32) -> jax.Array:
     """Pallas-fused correlation lookup.
 
     fmap1 [B,H,W,C], f2_levels tuple of [B,H/2^i,W/2^i,C], coords [B,H,W,2]
     -> [B, H, W, L*(2r+1)^2], matching ``ops.corr.lookup_dense`` exactly.
+
+    ``out_dtype``: the dtype the caller consumes the windows in.  The
+    kernel sums in float32 and rounds once, as it writes: bit for bit
+    ``fused_lookup(...).astype(out_dtype)`` of the float32 result, without
+    the float32 array.  A caller that adds results up keeps the default.
 
     ``f2_planes`` (optional): ``f2_levels`` as the kernel multiplies them
     (:func:`f2_terms` of each level), built once by a caller that looks up
@@ -493,15 +540,16 @@ def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
         fmap1, f2_levels if f2_planes is None else f2_planes, coords, radius,
         q_blk=q_blk, p_blk_target=p_blk_target,
         corr_precision=corr_precision, lookup_style=lookup_style,
-        schedules=schedules)
+        schedules=schedules, out_dtype=out_dtype)
 
 
 def _fused_lookup_fwd(fmap1, f2_levels, coords, radius, corr_precision,
                       q_blk, p_blk_target, lookup_style, f2_planes,
-                      schedules):
+                      schedules, out_dtype):
     return fused_lookup(fmap1, f2_levels, coords, radius, corr_precision,
                         q_blk, p_blk_target, lookup_style, f2_planes,
-                        schedules), (fmap1, f2_levels, coords, schedules)
+                        schedules, out_dtype), (fmap1, f2_levels, coords,
+                                                schedules)
 
 
 def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
@@ -509,18 +557,21 @@ def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
     twin (no gathers in the backward), on float32 maps whatever dtype the
     forward multiplied: the configured corr precision applies to the
     backward matmuls too — 'highest' must not silently degrade to bf16 MXU
-    inputs in training.  Each cotangent comes back in its primal's dtype,
-    which is where an ``astype(float32)`` before the lookup would put it."""
+    inputs in training.  ``g`` arrives in the forward's ``out_dtype`` and is
+    raised to float32 like the primals; each cotangent comes back in its
+    primal's dtype, which is where an ``astype(float32)`` before the lookup
+    would put it."""
     primals = (fmap1, tuple(f2_levels), coords)
     _, vjp = jax.vjp(
         lambda a, b, c: lookup_blockwise_onehot(a, tuple(b), c, radius,
                                                 precision=corr_precision),
         *jax.tree.map(lambda x: x.astype(jnp.float32), primals))
-    return jax.tree.map(lambda ct, x: ct.astype(x.dtype), vjp(g), primals)
+    return jax.tree.map(lambda ct, x: ct.astype(x.dtype),
+                        vjp(g.astype(jnp.float32)), primals)
 
 
 def _fused_lookup_bwd(radius, corr_precision, q_blk, p_blk_target,
-                      lookup_style, residuals, g):
+                      lookup_style, out_dtype, residuals, g):
     # the planes are a function of f2_levels that the forward precomputed:
     # their cotangent is zero (None), f2_levels carry the gradient; the
     # schedules are integer metadata (float0, as the ragged sizes are)
@@ -551,9 +602,11 @@ class FusedLookup:
 
     def __init__(self, fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
                  radius: int, corr_precision="highest", q_blk: int = 128,
-                 p_blk_target: int = 4096, lookup_style: str = "matmul"):
+                 p_blk_target: int = 4096, lookup_style: str = "matmul",
+                 out_dtype=jnp.float32):
         self.radius, self.prec = radius, as_precision(corr_precision)
         self.opts = (q_blk, p_blk_target, lookup_style)
+        self.out_dtype = out_dtype      # what the caller consumes windows in
         self.plan_args = dict(q_blk=q_blk, p_blk_target=p_blk_target)
         self.f2_levels = tuple(fmap2_pyramid(fmap2.astype(jnp.float32),
                                              num_levels))
@@ -575,17 +628,20 @@ class FusedLookup:
     def __call__(self, coords: jax.Array,
                  schedules: Optional[Tuple] = None) -> jax.Array:
         return fused_lookup(self.fmap1, self.f2_levels, coords, self.radius,
-                            self.prec, *self.opts, self.f2_planes, schedules)
+                            self.prec, *self.opts, self.f2_planes, schedules,
+                            self.out_dtype)
 
 
 @contract(fmap1="*[B,H,W,C]", fmap2="*[B,H2,W2,C]")
 def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
                       radius: int, corr_precision="highest",
                       q_blk: int = 128, p_blk_target: int = 4096,
-                      lookup_style: str = "matmul") -> FusedLookup:
-    """Build the per-iteration lookup closure used by models/raft.py."""
+                      lookup_style: str = "matmul",
+                      out_dtype=jnp.float32) -> FusedLookup:
+    """Build the per-iteration lookup closure used by models/raft.py, whose
+    update block consumes the windows in ``out_dtype``."""
     return FusedLookup(fmap1, fmap2, num_levels, radius, corr_precision,
-                       q_blk, p_blk_target, lookup_style)
+                       q_blk, p_blk_target, lookup_style, out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -606,8 +662,8 @@ def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
 # ---------------------------------------------------------------------------
 
 
-def _ragged_window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, *,
-                          body, n_pb):
+def _ragged_window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref,
+                          acc_ref, *, body, n_pb):
     """Page-scheduled program over the flattened query stream: grid
     ``(B*Qp/T, K)``; step k of query block j visits absolute f2 page
     ``S[j, k]`` (= item * n_pb + relative row-block).  The body needs the
@@ -618,11 +674,9 @@ def _ragged_window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, *,
     k = pl.program_id(1)
     sel = S_ref[j, k]
     prev = S_ref[j, jnp.maximum(k - 1, 0)]
-
-    @pl.when((k == 0) | (sel != prev))
-    def _():
-        win = body(sel % n_pb, f1_ref, coords_ref, f2_ref)
-        _accumulate(out_ref, win, k)
+    _accumulate(acc_ref, out_ref, k, k == pl.num_programs(1) - 1,
+                (k == 0) | (sel != prev),
+                lambda: body(sel % n_pb, f1_ref, coords_ref, f2_ref))
 
 
 def _ragged_schedule(coords: jax.Array, live: jax.Array, rows_crop: jax.Array,
@@ -657,15 +711,16 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
                          rows_crop: jax.Array, radius: int, level: int, *,
                          q_blk: int, p_blk_target: int, interpret: bool,
                          corr_precision=jax.lax.Precision.HIGHEST,
-                         lookup_style: str = "matmul") -> jax.Array:
+                         lookup_style: str = "matmul",
+                         out_dtype=jnp.float32) -> jax.Array:
     """f1 [B,Q,C] (dead rows zero), f2_level [B,H2,W2,C] (pre-masked; or its
     [n,B,H2,W2,C] term planes), coords [B,Q,2], live [B,Q] bool, rows_crop
-    [B] int32 live rows at this level -> [B,Q,(2r+1)^2] float32."""
+    [B] int32 live rows at this level -> [B,Q,(2r+1)^2] in ``out_dtype``."""
     B, Q, C = f1.shape
     H2, W2 = f2_level.shape[-3:-1]
     n = 2 * radius + 1
     if H2 == 0 or W2 == 0:
-        return jnp.zeros((B, Q, n * n), jnp.float32)
+        return jnp.zeros((B, Q, n * n), out_dtype)
     f1, f2, corr_precision = _kernel_operands(f1, f2_level, corr_precision)
     n_terms = f2.shape[0]
 
@@ -710,13 +765,13 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
             pl.BlockSpec((n_terms, 1, h2_blk * W2p, C),
                          lambda j, k, S: (0, 0, S[j, k], 0)),
         ],
-        out_specs=pl.BlockSpec((1, T, n, n),
-                               lambda j, k, S: (0, j, 0, 0)),
+        out_specs=pl.BlockSpec((1, T, n * n), lambda j, k, S: (0, j, 0)),
+        scratch_shapes=_sums_scratch(T, n),
     )
     out = pl.pallas_call(
         functools.partial(_ragged_window_kernel, body=body, n_pb=n_pb),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, B * Qp, n, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, B * Qp, n * n), out_dtype),
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
     )(S, f1s, cs, f2s)
@@ -725,14 +780,15 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
 
 
 @contract(fmap1="f32|bf16[B,H,W,C]", coords="f32[B,H,W,2]",
-          sizes8="i32[B,2]", _returns="f32[B,H,W,N]")
+          sizes8="i32[B,2]", _returns="f32|bf16[B,H,W,N]")
 def _ragged_fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                               coords: jax.Array, sizes8: jax.Array,
                               radius: int, q_blk: int = 128,
                               p_blk_target: int = 4096,
                               interpret: Optional[bool] = None,
                               corr_precision=jax.lax.Precision.HIGHEST,
-                              lookup_style: str = "matmul") -> jax.Array:
+                              lookup_style: str = "matmul",
+                              out_dtype=jnp.float32) -> jax.Array:
     B, H, W, C = fmap1.shape
     Q = H * W
     if lookup_style not in ("matmul", "vpu"):
@@ -753,18 +809,19 @@ def _ragged_fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
             outs.append(_ragged_lookup_level(
                 f1, f2l, cf, live, rows // (2 ** i), radius, i, q_blk=q_blk,
                 p_blk_target=p_blk_target, interpret=interp,
-                corr_precision=corr_precision, lookup_style=lookup_style))
+                corr_precision=corr_precision, lookup_style=lookup_style,
+                out_dtype=out_dtype))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 10))
 def ragged_fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
                         coords: jax.Array, sizes8: jax.Array, radius: int,
                         corr_precision=jax.lax.Precision.HIGHEST,
                         q_blk: int = 128, p_blk_target: int = 4096,
                         lookup_style: str = "matmul",
-                        f2_planes: Optional[Tuple[jax.Array, ...]] = None
-                        ) -> jax.Array:
+                        f2_planes: Optional[Tuple[jax.Array, ...]] = None,
+                        out_dtype=jnp.float32) -> jax.Array:
     """Ragged Pallas-fused correlation lookup.
 
     fmap1 [B,Hm,Wm,C] with dead regions zeroed (:func:`mask_ragged_rows`),
@@ -774,25 +831,26 @@ def ragged_fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
     equals ``fused_lookup`` run standalone on that crop; dead queries are
     exact zeros.  ``sizes8`` is a regular (traced) argument so ONE
     executable serves every declared resolution — it carries a float0
-    cotangent (integer metadata has no gradient).  ``f2_planes`` as in
-    :func:`fused_lookup`."""
+    cotangent (integer metadata has no gradient).  ``f2_planes`` and
+    ``out_dtype`` as in :func:`fused_lookup`."""
     return _ragged_fused_lookup_impl(
         fmap1, f2_levels if f2_planes is None else f2_planes, coords, sizes8,
         radius, q_blk=q_blk, p_blk_target=p_blk_target,
-        corr_precision=corr_precision, lookup_style=lookup_style)
+        corr_precision=corr_precision, lookup_style=lookup_style,
+        out_dtype=out_dtype)
 
 
 def _ragged_fused_lookup_fwd(fmap1, f2_levels, coords, sizes8, radius,
                              corr_precision, q_blk, p_blk_target,
-                             lookup_style, f2_planes):
+                             lookup_style, f2_planes, out_dtype):
     return ragged_fused_lookup(fmap1, f2_levels, coords, sizes8, radius,
                                corr_precision, q_blk, p_blk_target,
-                               lookup_style, f2_planes), (
+                               lookup_style, f2_planes, out_dtype), (
         fmap1, f2_levels, coords, sizes8)
 
 
 def _ragged_fused_lookup_bwd(radius, corr_precision, q_blk, p_blk_target,
-                             lookup_style, residuals, g):
+                             lookup_style, out_dtype, residuals, g):
     # gradients via the same matmul-only XLA twin as the dense kernel: the
     # masked max-box streams make lookup_blockwise_onehot the exact ragged
     # reference, so its vjp is the exact ragged backward (dead-region
@@ -811,7 +869,8 @@ def make_ragged_fused_lookup(fmap1: jax.Array, fmap2: jax.Array,
                              sizes8: jax.Array, num_levels: int, radius: int,
                              corr_precision="highest", q_blk: int = 128,
                              p_blk_target: int = 4096,
-                             lookup_style: str = "matmul"):
+                             lookup_style: str = "matmul",
+                             out_dtype=jnp.float32):
     """Ragged twin of :func:`make_fused_lookup` for mixed-resolution batches
     sharing one max box: masks frame-1 features and builds the re-masked
     pyramid and its kernel planes once, then every GRU iteration runs the
@@ -831,6 +890,6 @@ def make_ragged_fused_lookup(fmap1: jax.Array, fmap2: jax.Array,
     def lookup(coords: jax.Array) -> jax.Array:
         return ragged_fused_lookup(fmap1, f2_levels, coords, sizes8, radius,
                                    prec, q_blk, p_blk_target, lookup_style,
-                                   f2_planes)
+                                   f2_planes, out_dtype)
 
     return lookup

@@ -139,6 +139,8 @@ def make_ring_lookup_local(f1_local: jax.Array, f2_local: jax.Array,
                 # scaling then lands on the right slab row at every level
                 shifted = coords.at[..., 1].add(
                     -(src * Hl).astype(coords.dtype))
+                # float32 out (fused_lookup's default out_dtype): the
+                # slabs' partial windows are added up below
                 out = fused_lookup(f1_local, tuple(levels), shifted, radius,
                                    pl_prec, pl_opts["q_blk"],
                                    pl_opts["p_blk_target"],

@@ -8,8 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from raft_tpu.ops.corr import (build_pyramid, fmap2_pyramid, lookup_dense,
-                               lookup_ondemand)
+from raft_tpu.ops.corr import (build_pyramid, dense_corr, fmap2_pyramid,
+                               lookup_dense, lookup_ondemand)
 from raft_tpu.ops.corr_pallas import fused_lookup, make_fused_lookup
 
 
@@ -110,9 +110,14 @@ def test_query_block_padding():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_gradients_match_blockwise_path():
+@pytest.mark.parametrize("out", [jnp.float32, jnp.bfloat16],
+                         ids=["out-f32", "out-bf16"])
+def test_gradients_match_blockwise_path(out):
     """custom_vjp backward (delegating to lookup_ondemand) must match the
-    dense path's gradients w.r.t. fmap1, fmap2 levels, and coords."""
+    dense path's gradients w.r.t. fmap1, fmap2 levels, and coords — also
+    where the kernel writes bfloat16: the cotangent then arrives in bfloat16
+    and is raised to float32 before the twin, which is what differentiating
+    ``.astype(bfloat16)`` of the float32 lookup gives."""
     B, H, W, C, levels, radius = 1, 8, 10, 16, 2, 2
     fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(3), B, H, W, C)
     f2_levels = tuple(fmap2_pyramid(fmap2, levels))
@@ -120,10 +125,13 @@ def test_gradients_match_blockwise_path():
                             (B, H, W, levels * (2 * radius + 1) ** 2))
 
     def loss_fused(f1, f2l, c):
-        return jnp.sum(fused_lookup(f1, f2l, c, radius) * cot)
+        got = fused_lookup(f1, f2l, c, radius, out_dtype=out)
+        assert got.dtype == out
+        return jnp.sum(got * cot)
 
     def loss_dense(f1, f2l, c):
-        return jnp.sum(lookup_ondemand(f1, list(f2l), c, radius) * cot)
+        return jnp.sum(lookup_ondemand(f1, list(f2l), c, radius).astype(out)
+                       * cot)
 
     g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(fmap1, f2_levels, coords)
     g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(fmap1, f2_levels, coords)
@@ -304,6 +312,13 @@ def test_corr_terms_truth_table(f1, f2, precision, terms, passes):
     assert planes.shape == ((terms[1] if exact else 1), 1, 2, 2, 8)
 
 
+def _assert_same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape, (a.dtype, b.dtype)
+    bits = np.uint16 if a.dtype.itemsize == 2 else np.uint32
+    np.testing.assert_array_equal(a.view(bits), b.view(bits))
+
+
 def _bf16_case(key, B, H, W, C, levels):
     """bfloat16 maps, their float32-pooled pyramid (level 0 the map itself,
     as make_fused_lookup hands it), coords with out-of-map windows."""
@@ -360,21 +375,25 @@ def test_bf16_maps_equal_the_float32_highest_program(kernel, level, grid):
                else pooled[level])
         live = mask_ragged_rows(jnp.ones((B, H, W), bool), sizes)
         f1 = mask_ragged_rows(fmap1, sizes).reshape(B, H * W, C)
-        fn = lambda a, b, prec: _ragged_lookup_level(     # noqa: E731
+        fn = lambda a, b, prec, out=F32: _ragged_lookup_level(  # noqa: E731
             a, b, cf, live.reshape(B, H * W), sizes[:, 0] // 2 ** level,
             radius, level, q_blk=64, p_blk_target=256, interpret=True,
-            corr_precision=prec)
+            corr_precision=prec, out_dtype=out)
     else:
         f2l = f2_levels[level]
         h2, w2 = f2l.shape[1:3]
         sched = None if kernel == "all" else level_schedule(
             cf, corr_level_plan(H * W, h2, w2, q_blk=64, p_blk_target=256),
             h2, level, radius)
-        fn = lambda a, b, prec: _lookup_level(            # noqa: E731
+        fn = lambda a, b, prec, out=F32: _lookup_level(   # noqa: E731
             a, b, cf, radius, level, q_blk=64, p_blk_target=256,
-            interpret=True, corr_precision=prec, schedule=sched)
+            interpret=True, corr_precision=prec, schedule=sched,
+            out_dtype=out)
     assert f2l.dtype == (BF16 if level == 0 else F32)
-    got = np.asarray(fn(f1, f2l, HIGHEST))
+    got = fn(f1, f2l, HIGHEST)
+    # written in bfloat16, the launch gives those float32 sums rounded once
+    _assert_same_bits(fn(f1, f2l, HIGHEST, BF16), got.astype(BF16))
+    got = np.asarray(got)
     want = np.asarray(fn(f1.astype(F32), f2l.astype(F32), HIGHEST))
     assert np.abs(want).max() > 0.1
     np.testing.assert_allclose(got, want, rtol=0,
@@ -411,11 +430,13 @@ def test_float32_maps_keep_the_six_pass_program():
         rtol=0, atol=2e-6 * float(jnp.abs(want).max()))
 
 
-def test_bf16_gradients_match_blockwise_twin():
+@pytest.mark.parametrize("out", [F32, BF16], ids=["out-f32", "out-bf16"])
+def test_bf16_gradients_match_blockwise_twin(out):
     """bfloat16 maps: the forward rides the exact-terms kernel, the backward
     the float32 XLA twin at the configured precision, and each cotangent
     comes back in its primal's dtype — what ``astype(float32)`` before the
-    lookup gave (the twin differentiated through that cast)."""
+    lookup gave (the twin differentiated through that cast).  With a
+    bfloat16 output the twin is followed by ``astype(bfloat16)``."""
     from raft_tpu.ops.corr import lookup_blockwise_onehot
 
     B, H, W, C, levels, radius = 1, 8, 10, 16, 2, 2
@@ -425,15 +446,19 @@ def test_bf16_gradients_match_blockwise_twin():
                             (B, H, W, levels * (2 * radius + 1) ** 2))
 
     def loss_fused(f1, f2, c):
-        return jnp.sum(make_fused_lookup(f1, f2, levels, radius)(c) * cot)
+        return jnp.sum(make_fused_lookup(f1, f2, levels, radius,
+                                         out_dtype=out)(c) * cot)
 
     def loss_twin(f1, f2, c):
         f2l = tuple(fmap2_pyramid(f2.astype(F32), levels))
         return jnp.sum(lookup_blockwise_onehot(
-            f1.astype(F32), f2l, c, radius, precision=HIGHEST) * cot)
+            f1.astype(F32), f2l, c, radius,
+            precision=HIGHEST).astype(out) * cot)
 
+    # float32 round-off apart before the rounding: a bfloat16 ulp after it
     np.testing.assert_allclose(loss_fused(fmap1, fmap2, coords),
-                               loss_twin(fmap1, fmap2, coords), rtol=1e-5)
+                               loss_twin(fmap1, fmap2, coords),
+                               rtol=1e-5 if out == F32 else 1e-3)
     g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(fmap1, fmap2, coords)
     g_twin = jax.grad(loss_twin, argnums=(0, 1, 2))(fmap1, fmap2, coords)
     for a, b, x in zip(g_fused, g_twin, (fmap1, fmap2, coords)):
@@ -441,3 +466,162 @@ def test_bf16_gradients_match_blockwise_twin():
         np.testing.assert_allclose(np.asarray(a.astype(F32)),
                                    np.asarray(b.astype(F32)),
                                    rtol=1e-2, atol=1e-4)
+
+
+# ------------------------------------------- the output's dtype and layout
+#
+# A launch adds its visited key row-blocks' windows in a float32 VMEM scratch
+# and writes [B, Q, n*n] once, in the dtype its consumer states: bit for bit
+# ``astype`` of the float32 result, and the float32 result the values the
+# kernel gave when it still accumulated in a [T, n, n] float32 output block
+# (recorded from the parent commit of PR 29, interpret mode, this seed).
+
+_RECORDED = {
+    # name: (maps, B, H, W, C, levels, radius, seed, size, nonzero, sum|x|,
+    #        ((flat index, value), ...))
+    "things-bf16": (BF16, 2, 20, 28, 32, 4, 4, 40, 362880, 151797,
+                    41250.1421002008,
+                    ((0, 0.17217610776424408), (41625, 0.08778263628482819),
+                     (82894, -0.0481746532022953),
+                     (122935, 0.14495036005973816),
+                     (164065, 0.08016001433134079),
+                     (204656, 0.02320902794599533),
+                     (241803, 0.04089152067899704),
+                     (279342, -0.5822199583053589),
+                     (321700, 0.08740711212158203),
+                     (362865, 0.0012531970860436559))),
+    "small-f32": (F32, 2, 12, 16, 16, 3, 3, 41, 56448, 28180,
+                  8011.835166038254,
+                  ((0, -1.170413851737976), (7218, -0.07167434692382812),
+                   (13345, 0.17826677858829498), (19499, -0.0763673484325409),
+                   (25955, 0.03567490726709366), (32061, -0.5320842266082764),
+                   (37634, 0.9011698365211487), (43751, -0.2676534652709961),
+                   (50188, 0.3870690166950226),
+                   (56432, -0.00024574375129304826))),
+}
+
+
+@pytest.mark.parametrize("launches", ["rule", "all-blocks"])
+@pytest.mark.parametrize("name", sorted(_RECORDED))
+def test_float32_result_is_the_parents_and_bfloat16_its_rounding(name,
+                                                                 launches):
+    """raft-things' window (radius 4, bfloat16 maps) and raft-small's
+    (radius 3: 49 values a row, float32 maps), under the kernel's own rule
+    (level 0 scheduled at these blocks) and with every block walked."""
+    from raft_tpu.ops.corr_pallas import _fused_lookup_impl
+
+    (maps, B, H, W, C, levels, radius, seed, size, nonzero, abs_sum,
+     samples) = _RECORDED[name]
+    fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(seed), B, H, W, C,
+                                        dtype=maps,
+                                        coord_span=0.9 * max(H, W))
+    f2_levels = [fmap2] + fmap2_pyramid(fmap2.astype(F32), levels)[1:]
+    run = lambda out: _fused_lookup_impl(                 # noqa: E731
+        fmap1, f2_levels, coords, radius, q_blk=64, p_blk_target=256,
+        interpret=True, out_dtype=out,
+        schedules=None if launches == "rule" else (None,) * levels)
+    got = run(F32)
+    assert got.dtype == F32
+    assert got.shape == (B, H, W, levels * (2 * radius + 1) ** 2)
+    _assert_same_bits(run(BF16), got.astype(BF16))
+    flat = np.asarray(got).ravel()
+    assert flat.size == size and np.count_nonzero(flat) == nonzero
+    np.testing.assert_allclose(np.abs(flat.astype(np.float64)).sum(),
+                               abs_sum, rtol=1e-6)
+    idx, want = zip(*samples)
+    np.testing.assert_allclose(flat[list(idx)], want, rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("grid,level", [
+    ((135, 240), 0),     # 1080x1920: 32,400 queries in 254 tiles (112 padded)
+    ((135, 240), 1),     #   nine, three, two row-blocks, then one (unscheduled)
+    ((135, 240), 3),
+    ((46, 62), 0),       # the 368x496 crop: 2,852 queries in 23 tiles
+    ((46, 62), 2),
+])
+def test_out_dtype_where_queries_do_not_fill_the_tiles(grid, level):
+    """The served block plan (q_blk 128, p_blk 4096) at grids whose query
+    count is no multiple of the tile: the padded tail is written and cut,
+    in both dtypes, and the scheduled launches end on repeated entries."""
+    from raft_tpu.kernel_plans import corr_level_plan, corr_level_scheduled
+    from raft_tpu.ops.coords import coords_grid
+    from raft_tpu.ops.corr_pallas import _lookup_level, level_schedule
+
+    (H, W), B, C, radius = grid, 1, 8, 4
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(50 + level), 3)
+    f1 = jax.random.normal(k1, (B, H * W, C), BF16)
+    f2 = fmap2_pyramid(jax.random.normal(k2, (B, H, W, C), BF16).astype(F32),
+                       4)[level]
+    f2 = f2.astype(BF16) if level == 0 else f2
+    coords = (coords_grid(B, H, W) + jax.random.uniform(
+        k3, (B, H, W, 2), minval=-3.0, maxval=3.0)).reshape(B, H * W, 2)
+    h2, w2 = f2.shape[1:3]
+    plan = corr_level_plan(H * W, h2, w2, q_blk=128, p_blk_target=4096)
+    assert plan.qp != H * W
+    sched = None
+    if corr_level_scheduled(plan):
+        sched = level_schedule(coords, plan, h2, level, radius)
+        S = np.asarray(sched)
+        assert (S[..., -1] == S[..., -2]).any()     # a tile ends on a repeat
+    run = lambda out: _lookup_level(                      # noqa: E731
+        f1, f2, coords, radius, level, q_blk=128, p_blk_target=4096,
+        interpret=True, schedule=sched, out_dtype=out)
+    got = run(F32)
+    assert got.shape == (B, H * W, (2 * radius + 1) ** 2)
+    assert np.abs(np.asarray(got)).max() > 0.1
+    _assert_same_bits(run(BF16), got.astype(BF16))
+    if H * W * h2 * w2 > 5e7:   # a dense volume of a gigabyte and more:
+        return                  # the other cases hold the values
+    want = lookup_dense(
+        [dense_corr(f1.reshape(B, H, W, C).astype(F32), f2.astype(F32),
+                    precision=HIGHEST)],
+        coords.reshape(B, H, W, 2) / 2 ** level, radius)
+    np.testing.assert_allclose(np.asarray(got).reshape(want.shape),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_closures_hand_over_the_dtype_they_were_built_for():
+    """What ``models/raft.py`` builds: the closure states its consumer's
+    dtype once and every call returns it; the default stays float32 (a
+    caller that adds results up, as the ring lookup does)."""
+    fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(5), 1, 8, 12, 16,
+                                        dtype=BF16)
+    f32 = make_fused_lookup(fmap1, fmap2, num_levels=4, radius=4)
+    bf16 = make_fused_lookup(fmap1, fmap2, num_levels=4, radius=4,
+                             out_dtype=BF16)
+    got = f32(coords)
+    assert got.dtype == F32
+    _assert_same_bits(bf16(coords, bf16.schedules(coords)), got.astype(BF16))
+
+
+@pytest.mark.parametrize("small", [False, True], ids=["things", "small"])
+def test_served_flow_is_what_the_converted_float32_lookup_gave(small,
+                                                               monkeypatch):
+    """The whole model in bfloat16 at 216x384, twelve iterations, pallas
+    lookup: with the kernels writing bfloat16 the flow equals, bit for bit,
+    the flow of the program that takes the kernels' float32 output and
+    converts it in ``gru_step`` (PR 29's parent: the factory forced back to
+    float32 here, the cast still in the model)."""
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.models import init_raft, raft_forward
+    from raft_tpu.ops import corr_pallas
+
+    make = RAFTConfig.small_model if small else RAFTConfig.full
+    config = make(iters=12, compute_dtype="bfloat16", corr_impl="pallas")
+    params = init_raft(jax.random.PRNGKey(0), config)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(1))
+    im1 = jax.random.uniform(k1, (1, 216, 384, 3))
+    im2 = jax.random.uniform(k2, (1, 216, 384, 3))
+    seen = []
+    factory = corr_pallas.make_fused_lookup
+
+    def as_the_parent(*args, out_dtype, **kw):
+        seen.append(out_dtype)
+        return factory(*args, **kw)                # float32, the default
+
+    written = raft_forward(params, im1, im2, config)[0].flow
+    monkeypatch.setattr(corr_pallas, "make_fused_lookup", as_the_parent)
+    converted = raft_forward(params, im1, im2, config)[0].flow
+    assert seen == [jnp.bfloat16]          # the model states its compute dtype
+    assert float(jnp.abs(written).max()) > 1.0
+    _assert_same_bits(written, converted)

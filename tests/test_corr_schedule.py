@@ -113,15 +113,18 @@ def _flow_field(kind: str, B: int) -> jax.Array:
                               minval=-8.0, maxval=1.2 * W)
 
 
+@pytest.mark.parametrize("out", [F32, BF16], ids=["out-f32", "out-bf16"])
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("kind", ["zero", "three-blocks", "outside",
                                   "random"])
-def test_scheduled_lookup_equals_all_blocks_bit_for_bit(kind, dtype):
+def test_scheduled_lookup_equals_all_blocks_bit_for_bit(kind, dtype, out):
     """At a grid with 15 key row-blocks at level 0 and 8 at level 1, Q not a
     multiple of the tile: the rule's program (every level scheduled) equals
     the all-blocks program bit for bit, and the reference's lookup
     (``lookup_dense`` on the same values) to ``tests/test_corr_pallas.py``'s
-    tolerance."""
+    tolerance.  Written in bfloat16 (``out``), both are the float32 result
+    rounded once: the write happens at a tile's last grid step, which under
+    a schedule is nearly always a repeated entry that skips the compute."""
     B = 2
     k1, k2 = jax.random.split(jax.random.PRNGKey(4))
     fmap1 = jax.random.normal(k1, (B, H, W, C), dtype)
@@ -133,16 +136,26 @@ def test_scheduled_lookup_equals_all_blocks_bit_for_bit(kind, dtype):
     assert [s is not None for s in sched] == [True] * 4
     plan0 = corr_level_plan(H * W, H, W, q_blk=128, p_blk_target=P_BLK)
     assert plan0.n_pblocks >= 4 and plan0.qp != H * W
-    run = lambda s: np.asarray(_fused_lookup_impl(      # noqa: E731
+    run = lambda s, out=F32: np.asarray(_fused_lookup_impl(   # noqa: E731
         fmap1, f2_levels, coords, RADIUS, q_blk=128, p_blk_target=P_BLK,
-        interpret=True, schedules=s))
-    got, whole = run(sched), run((None,) * 4)
+        interpret=True, schedules=s, out_dtype=out))
+    S = np.asarray(sched[0])
+    if kind in ("zero", "three-blocks"):        # every tile ends on a repeat
+        assert (S[..., -1] == S[..., -2]).all()
+    got = run(sched)
+    if out == BF16:
+        want = np.asarray(jnp.asarray(got).astype(BF16)).view(np.uint16)
+        for s in (sched, (None,) * 4):
+            np.testing.assert_array_equal(run(s, BF16).view(np.uint16), want)
+        if kind == "outside":
+            assert not want[:, :, : W // 2].any()             # +0.0, all of it
+        return
+    whole = run((None,) * 4)
     np.testing.assert_array_equal(got.view(np.uint32), whole.view(np.uint32))
     want = lookup_dense(build_pyramid(fmap1.astype(F32), fmap2.astype(F32),
                                       4), coords, RADIUS)
     np.testing.assert_allclose(got, np.asarray(want), rtol=1e-5, atol=1e-5)
     if kind == "three-blocks":
-        S = np.asarray(sched[0])
         assert (S[..., -1] - S[..., 0] + 1).max() >= 3
     if kind == "outside":
         assert np.abs(got[:, :, : W // 2]).max() == 0.0   # wholly outside

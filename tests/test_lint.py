@@ -1061,8 +1061,10 @@ def test_contracts_survive_jit_tracing():
 def test_fused_kernel_contract_pins_float32():
     """Satellite audit (ops/corr_pallas.py): the fused lookup takes the
     feature maps in float32 or bfloat16 (its MXU passes follow from the
-    dtype) and keeps coords and the output float32 on the CPU (interpret)
-    backend — enforced by its contract."""
+    dtype) and keeps coords float32 on the CPU (interpret) backend; the
+    output is float32 unless its caller states bfloat16 (``out_dtype``: the
+    float32 sums rounded as they are written) and nothing else — enforced
+    by its contract."""
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
 
@@ -1080,6 +1082,12 @@ def test_fused_kernel_contract_pins_float32():
         out = _fused_lookup_impl(f1.astype(jnp.bfloat16),
                                  fmap2_pyramid(f2, 2), coords, 2)
         assert out.dtype == jnp.float32
+        out = _fused_lookup_impl(f1, fmap2_pyramid(f2, 2), coords, 2,
+                                 out_dtype=jnp.bfloat16)
+        assert out.dtype == jnp.bfloat16
+        with pytest.raises(contracts.ContractError):
+            _fused_lookup_impl(f1, fmap2_pyramid(f2, 2), coords, 2,
+                               out_dtype=jnp.float16)
         with pytest.raises(contracts.ContractError):
             _fused_lookup_impl(f1.astype(jnp.float16),
                                fmap2_pyramid(f2, 2), coords, 2)

@@ -84,17 +84,28 @@ def test_ragged_lookup_bf16_inputs():
     sz = jnp.asarray(np.asarray(sizes, np.int32))
     out = np.asarray(make_ragged_fused_lookup(
         jnp.asarray(f1), jnp.asarray(f2), sz, 3, 4)(jnp.asarray(coords)))
-    out_bf = np.asarray(make_ragged_fused_lookup(
-        jnp.asarray(f1).astype(jnp.bfloat16),
-        jnp.asarray(f2).astype(jnp.bfloat16), sz, 3, 4)(jnp.asarray(coords)))
-    assert np.isfinite(out_bf).all()
-    np.testing.assert_allclose(out_bf, out, rtol=0.05, atol=0.05)
+    bf = [jnp.asarray(f).astype(jnp.bfloat16) for f in (f1, f2)]
+    out_bf = make_ragged_fused_lookup(*bf, sz, 3, 4)(jnp.asarray(coords))
+    assert np.isfinite(np.asarray(out_bf)).all()
+    np.testing.assert_allclose(np.asarray(out_bf), out, rtol=0.05, atol=0.05)
+    # written in the update block's dtype: those float32 sums rounded once,
+    # the dead region exact zeros still
+    written = make_ragged_fused_lookup(
+        *bf, sz, 3, 4, out_dtype=jnp.bfloat16)(jnp.asarray(coords))
+    assert written.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(written).view(np.uint16),
+        np.asarray(out_bf.astype(jnp.bfloat16)).view(np.uint16))
+    assert not np.asarray(written)[1, 13:].view(np.uint16).any()
 
 
-def test_ragged_lookup_gradients_masked():
+@pytest.mark.parametrize("out", [jnp.float32, jnp.bfloat16],
+                         ids=["out-f32", "out-bf16"])
+def test_ragged_lookup_gradients_masked(out):
     """The custom_vjp backward must be finite everywhere and EXACTLY zero on
     dead-region fmap rows — the mask sits upstream of the kernel, so no
-    gradient can leak into a crop's embedding."""
+    gradient can leak into a crop's embedding.  With a bfloat16 output the
+    cotangent arrives in bfloat16 and is raised to float32 for the twin."""
     from raft_tpu.ops.corr_pallas import make_ragged_fused_lookup
 
     sizes = [(16, 24), (8, 8), (13, 19)]
@@ -102,8 +113,9 @@ def test_ragged_lookup_gradients_masked():
     sz = jnp.asarray(np.asarray(sizes, np.int32))
 
     def loss(a, c):
-        lk = make_ragged_fused_lookup(a, jnp.asarray(f2), sz, 3, 4)
-        return jnp.sum(jnp.sin(lk(c)))
+        lk = make_ragged_fused_lookup(a, jnp.asarray(f2), sz, 3, 4,
+                                      out_dtype=out)
+        return jnp.sum(jnp.sin(lk(c).astype(jnp.float32)))
 
     g1, gc = jax.grad(loss, argnums=(0, 1))(jnp.asarray(f1),
                                             jnp.asarray(coords))

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,6 +126,30 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
                             scheduled=True, grid=CROP), {}),
     ("crop-level1", 1, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
                             grid=CROP), {}),
+    # the window written in the update block's dtype (every case above
+    # writes float32): a bfloat16 [T, 81] block, (16,128)-tiled, filled by
+    # nine masked stores at static lane offsets; scheduled and not, at the
+    # three grids
+    ("out-bf16-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                                scheduled=True, out_dtype=jnp.bfloat16),
+     BF16_L0),
+    ("out-bf16-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                                out_dtype=jnp.bfloat16), BF16_X3),
+    ("hd-out-bf16-level0", 0, dict(corr_precision=P.HIGHEST,
+                                   p_blk_target=4096, scheduled=True,
+                                   grid=HD, out_dtype=jnp.bfloat16), BF16_L0),
+    ("hd-out-bf16-level1", 1, dict(corr_precision=P.HIGHEST,
+                                   p_blk_target=4096, scheduled=True,
+                                   grid=HD, out_dtype=jnp.bfloat16), BF16_X3),
+    ("hd-out-bf16-level3", 3, dict(corr_precision=P.HIGHEST,
+                                   p_blk_target=4096, grid=HD,
+                                   out_dtype=jnp.bfloat16), BF16_X3),
+    ("crop-out-bf16-level0", 0, dict(corr_precision=P.HIGHEST,
+                                     p_blk_target=4096, scheduled=True,
+                                     grid=CROP, out_dtype=jnp.bfloat16), {}),
+    ("crop-out-bf16-level1", 1, dict(corr_precision=P.HIGHEST,
+                                     p_blk_target=4096, grid=CROP,
+                                     out_dtype=jnp.bfloat16), {}),
 ])
 def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
     kw = dict(kw)
@@ -142,24 +167,35 @@ def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
         assert corr_level_scheduled(plan) == scheduled
     text = _compile(fn, *_corr_specs(one_chip, level, grid=grid, **dtypes))
     assert "tpu_custom_call" in text
+    # the launch itself returns the lane-dense window in the dtype asked for
+    out = "bf16" if kw.get("out_dtype") == jnp.bfloat16 else "f32"
+    qp = corr_level_plan(grid[0] * grid[1], grid[0] >> level,
+                         grid[1] >> level, q_blk=128, p_blk_target=4096).qp
+    assert re.search(rf"= {out}\[1,{qp},81\]\S* custom-call\(", text), name
     if dtypes:
         # the kernel was handed bfloat16 planes: nothing widened them first
         planes = 1 if dtypes is BF16_L0 else 3
         assert f"bf16[{planes},1," in text, name
 
 
-@pytest.mark.parametrize("level,dtypes", [(0, {}), (0, BF16_L0),
-                                          (1, BF16_X3)],
-                         ids=["f32", "bf16-level0", "bf16x3-level1"])
-def test_ragged_corr_kernel_compiles_for_v5e(one_chip, level, dtypes):
+@pytest.mark.parametrize("level,dtypes,out_dtype", [
+    (0, {}, jnp.float32), (0, BF16_L0, jnp.float32),
+    (1, BF16_X3, jnp.float32), (0, BF16_L0, jnp.bfloat16),
+    (1, BF16_X3, jnp.bfloat16)],
+    ids=["f32", "bf16-level0", "bf16x3-level1", "out-bf16-level0",
+         "out-bf16-level1"])
+def test_ragged_corr_kernel_compiles_for_v5e(one_chip, level, dtypes,
+                                             out_dtype):
     """The ``sizes``-operand (mixed-resolution) kernel at batch 2."""
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     f1, f2, coords = _corr_specs(one_chip, level, batch=2, **dtypes)
     fn = functools.partial(_ragged_lookup_level, radius=RADIUS, level=level,
-                           q_blk=128, p_blk_target=4096, interpret=False)
+                           q_blk=128, p_blk_target=4096, interpret=False,
+                           out_dtype=out_dtype)
     text = _compile(fn, f1, f2, coords, s((2, H * W), jnp.bool_),
                     s((2,), jnp.int32))
-    assert "tpu_custom_call" in text
+    out = "bf16" if out_dtype == jnp.bfloat16 else "f32"
+    assert re.search(rf"= {out}\[1,{2 * H * W},81\]\S* custom-call\(", text)
 
 
 @pytest.mark.parametrize("grid,dtype", [((H, W), jnp.float32),
@@ -233,7 +269,6 @@ def test_kernels_keep_the_names_the_benchmark_finds_them_by(
     compiler names a kernel's instruction after the innermost ``stage()``
     around it: ``benchmark/layer_metrics/{corr_lookup,gru}_roofline.json``
     match these patterns, so a renamed scope would silently read nothing."""
-    import re
     from raft_tpu.telemetry.trace import instruction_stages
     insts = instruction_stages(served_program.as_text())
     kernels = [n for n, rec in insts.items() if re.search(pattern, n)
@@ -244,8 +279,7 @@ def test_kernels_keep_the_names_the_benchmark_finds_them_by(
 def test_stage_map_of_the_served_program(served_program):
     """What the engine writes beside its AOT cache entry, for the program
     the benchmark's cell serves: each pyramid level's lookup under a scope
-    of its own, the converts of its output with it, and every model stage
-    that a per-layer metric reads."""
+    of its own and every model stage that a per-layer metric reads."""
     from raft_tpu.telemetry.trace import instruction_stages
     insts = instruction_stages(served_program.as_text())
     stages = {rec["stage"] for rec in insts.values()}
@@ -253,12 +287,29 @@ def test_stage_map_of_the_served_program(served_program):
         scope = f"raft/corr_lookup/l{level}/corr_lookup"
         under = [n for n, rec in insts.items() if rec["stage"] == scope]
         assert any(n.startswith("corr_lookup.") for n in under), scope
-        # the f32 -> bf16 convert of the level's output: made by the
-        # compiler with no op_name, so it takes its operand's stage
-        assert any(n.startswith("convert.") and "bf16[1,7040,9,9]"
-                   in insts[n]["text"] for n in under), scope
     for scope in ("raft/preprocess", "raft/fnet", "raft/cnet",
                   "raft/corr_pyramid", "raft/gru_ctx", "raft/update",
                   "raft/upsample"):
         assert any(st == scope or st.startswith(scope + "/")
                    for st in stages), scope
+
+
+def test_lookup_launches_hand_over_what_the_update_block_consumes(
+        served_program):
+    """The four launches of an iteration return ``bf16[1,7040,81]``: the
+    compute dtype, a query's window side by side in the lanes.  Nothing
+    under ``raft/corr_lookup`` converts or reshapes a ``[.., 9, 9]`` array
+    any more (PR 29: four converts of a 25x-padded float32 array and the
+    reshape of their result were 21-25 % of the served program's run)."""
+    from raft_tpu.telemetry.trace import instruction_stages
+    insts = instruction_stages(served_program.as_text())
+    under = {n: rec["text"] for n, rec in insts.items()
+             if (rec["stage"] or "").startswith("raft/corr_lookup")}
+    launches = [t for n, t in under.items() if n.startswith("corr_lookup.")
+                and " custom-call(" in t]
+    assert len(launches) == 4
+    for text in launches:
+        assert re.search(r"= bf16\[1,7040,81\]\S* custom-call\(", text), text
+    assert not [n for n in under if n.startswith("convert")], under.keys()
+    assert not [n for n, t in under.items() if ",9,9]" in t], under.keys()
+    assert "f32[1,7040,9,9]" not in served_program.as_text()
