@@ -1,14 +1,17 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that raft-tpu still starts on the chip.
 
-    python3 chip_smoke.py             # one chip: device, kernels, serve, train
+    python3 chip_smoke.py             # one chip: device, kernels, serve,
+                                      # small, train
     python3 chip_smoke.py --chips 4   # four chips: fleet, dp=4 step, spatial-4
 
 Drives the system's main path once through the entry points a user calls —
 the real ``-m serve`` server and the real ``-m train`` trainer — at the
 published widths of raft-things (``RAFTConfig.full``: fnet 256, hidden 128,
 context 128, 4 levels, radius 4), with seeded random weights and the
-committed Sintel pair ``assets/frame_0016.png`` / ``frame_0017.png``.
+committed Sintel pair ``assets/frame_0016.png`` / ``frame_0017.png``; the
+``small`` phase runs RAFT-S's served program (hidden 96, radius 3) once at the
+benchmark's 8 x 1080x1920 against ``benchmark/reference.py``.
 
 Contract: one process holds the chip; the first device must be a TPU (no
 probe child, no retry, no CPU); a phase that fails ends the run with a
@@ -69,6 +72,10 @@ class Sizes:
     train_accum: int = 2
     train_corr: str = "pallas"
     train_workers: int = 2
+    # small: RAFT-S as benchmark/configs/raft-small-1080p.json serves it,
+    # at the batch and frame size the cell small-1080p-b8-closed times
+    small_batch: int = 8
+    small_hw: tuple = (1080, 1920)
     # --chips 4
     dp_batch: int = 8                 # global batch of the dp=4 comparison
     # the pjit step cannot carry the Pallas kernel ("Mosaic kernels cannot
@@ -616,6 +623,88 @@ def phase_serve(meter, sz: Sizes) -> None:
             server2.stop()
 
 
+# ------------------------------------------------------------------- small
+
+SMALL_CONFIG = os.path.join(ROOT, "benchmark", "configs",
+                            "raft-small-1080p.json")
+SMALL_SEED = 3_100_000_031
+
+
+def phase_small(meter, sz: Sizes) -> None:
+    """RAFT-S's served pair program (the benchmark configuration's own serve
+    arguments through ``cli.parse_args`` / ``_make_config``; the engine's
+    function, key-block counts beside the flow) ONCE at ``sz.small_batch`` x
+    ``sz.small_hw``, on the benchmark's seeded weights and frames, held to
+    ``benchmark/reference.py`` the way the cell's own check holds its
+    answers: ``precision_ratio`` (check.py) of one row under the
+    configuration's ``check.ratio_limit``.  The radius-3 lookup (a 7x7
+    window, 49 lanes, 128-channel maps) runs through the one lookup body
+    the full model's radius 4 uses: a fault there shows here before the
+    benchmark meets it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from raft_tpu import cli
+    from raft_tpu.models.raft import make_inference_fn
+
+    bench = os.path.join(ROOT, "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import check as bcheck
+        import inputs as binputs
+        import weights as bweights
+    finally:
+        sys.path.remove(bench)
+    with open(SMALL_CONFIG) as f:
+        conf = json.load(f)
+    args = cli.parse_args(["-m", "serve"]
+                          + [str(a) for a in conf["serve_args"]])
+    config = cli._make_config(args)
+    (h, w), b = sz.small_hw, sz.small_batch
+    with Phase(meter, "small") as ph:
+        for key, value in conf["program"].items():
+            check(getattr(config, key) == value,
+                  f"{SMALL_CONFIG} says {key}={value!r}, its serve "
+                  f"arguments give {getattr(config, key)!r}")
+        mcfg = bweights.model_cfg(conf)
+        params = jax.block_until_ready(
+            bweights.make_weights(SMALL_SEED, mcfg))
+        pairs = binputs.make_pairs(SMALL_SEED, b, h, w, 12)
+        im1, im2 = (jnp.asarray(np.stack([p[i] for p in pairs])
+                                .astype(np.float32) / 255.0) for i in (0, 1))
+        lowered = jax.jit(make_inference_fn(
+            config, iters=args.iters, keyblocks=True)).lower(params, im1, im2)
+        if not sz.interpret:
+            check(lowered.as_text().count("tpu_custom_call") >= 4,
+                  "fewer than four lookup launches in the small program")
+        compiled = lowered.compile()
+        t0 = time.monotonic()
+        flow, keyblocks = jax.block_until_ready(compiled(params, im1, im2))
+        ph.note(program=f"{b}x{h}x{w}", iters=args.iters,
+                dtype=config.compute_dtype,
+                run_seconds=round(time.monotonic() - t0, 3))
+        flow = np.asarray(flow, np.float32)
+        visited, possible = (int(v) for v in np.asarray(keyblocks))
+        finite = bool(np.isfinite(flow).all())
+        check(flow.shape == (b, h, w, 2) and finite,
+              f"small flow: shape {flow.shape}, finite {finite}")
+        check(0 < visited <= possible,
+              f"key-block counts at radius 3: {visited} of {possible}")
+        row = b - 1                       # the batch's last row
+        refs = bcheck.reference_flows(params, pairs, [row], mcfg, args.iters)
+        own = bcheck.reference_flows(params, pairs, [row], mcfg, args.iters,
+                                     conf["check"]["own_precision"])
+        limit = float(conf["check"]["ratio_limit"])
+        lines = []
+        verdict = bcheck.compare([(row, row, flow[row])], refs, own, limit,
+                                 lines.append)
+        ph.note(precision_ratio=round(verdict["worst"] or 0.0, 4),
+                limit=limit, keyblock_share=round(visited / possible, 4))
+        check(verdict["correct"], "small program against "
+              "benchmark/reference.py: " + "; ".join(lines))
+
+
 # ------------------------------------------------------------------- train
 
 class ChildWatch(threading.Thread):
@@ -1017,6 +1106,7 @@ def run(chips: int, sz: Sizes) -> dict:
     else:
         phase_kernels(meter, sz)
         phase_serve(meter, sz)
+        phase_small(meter, sz)
         phase_train(meter, sz)
     emit(phase="end", compile_seconds=round(meter.seconds, 2),
          cache_hits=meter.hits, cache_misses=meter.misses,
@@ -1027,9 +1117,9 @@ def run(chips: int, sz: Sizes) -> dict:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                   help="1 (default): device, kernels, serve, train on one "
-                        "chip.  4: only the cross-chip phase — fleet of four "
-                        "one-chip replicas, dp=4 train step, spatial-4 "
+                   help="1 (default): device, kernels, serve, small, train on "
+                        "one chip.  4: only the cross-chip phase — fleet of "
+                        "four one-chip replicas, dp=4 train step, spatial-4 "
                         "forward — and what each is compared with")
     args = p.parse_args(argv)
     try:

@@ -35,7 +35,7 @@ from __future__ import annotations
 import dataclasses
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
-from ..kernel_plans import (GRU_HALO, GRU_TAPS, SUBLANE, VMEM_BYTES,
+from ..kernel_plans import (GRU_HALO, GRU_TAPS, LANE, SUBLANE, VMEM_BYTES,
                             VMEM_CEILING_BYTES, corr_level_plan,
                             corr_window_vmem, gru_row_plan, gru_scoped_bytes,
                             gru_vmem_limit)
@@ -410,13 +410,47 @@ def kind_footprint(config, pspecs, key: Key, capacity: int,
 PAIR_TEMP_BYTES_PER_PIXEL = 391
 
 
+#: The same for RAFT-S (``config.small``; bfloat16, Pallas lookup, the 3x3
+#: ConvGRU as XLA convolutions), per input pixel and pair where nothing is
+#: padded.  Its peak also lies in the feature encoder, at HALF resolution:
+#: the stem's and layer1's ``bf16[2B, H/2, W/2, 32]`` maps of both frames
+#: (and the bottleneck's 8-channel ones), three or so alive at once.  The
+#: chip stores such a map with either its 32 channels or its 2B images in
+#: the 128 lanes (:func:`small_lane_padding`), so the bytes a pixel depend
+#: on the batch: ``memory_analysis().temp_size_in_bytes`` of the served
+#: programs (sandbox compiles for a described v5e, PR 31) read 102.2 B a
+#: pixel at 64 x 440x1024 (128 images fill the lanes: the figure of PERF.md's
+#: older note), 198.6 at 32 x 440x1024, 390.2 at 16 x 1080x1920 and 422.5 at
+#: 8 x 1080x1920 (7,007,982,080 B, the benchmark's cell); 424.1 at 8 x
+#: 440x1024 is the largest of seven readings.  107 prices them all from
+#: above: +4.7, +7.8, +9.7 and +1.3 % for the four named here.
+SMALL_PAIR_TEMP_BYTES_PER_PIXEL = 107
+_SMALL_STEM_CHANNELS = 32
+
+
+def small_lane_padding(b: int) -> float:
+    """How much larger than its contents RAFT-S's widest temporary is on the
+    chip at batch ``b``: the compiler puts the smaller padding of the two in
+    the 128 lanes, the 32 channels (4 x) or the ``2 b`` images of both
+    frames (up to the next multiple of 128)."""
+    images = 2 * b
+    by_batch = -(-images // LANE) * LANE / images
+    return min(LANE / _SMALL_STEM_CHANNELS, by_batch)
+
+
 def pair_temp_bytes(config, h: int, w: int, b: int) -> Optional[int]:
     """HBM temporaries of the ``pair`` executable at ``b`` x ``h`` x ``w``,
-    or None for a program the figure was not taken from (another model, a
-    float32 one, a lookup that stores its volume): not priced beats priced
-    wrong."""
-    if (config.small or config.compute_dtype != "bfloat16"
-            or config.corr_impl != "pallas" or config.gru_impl != "pallas"):
+    or None for a program no figure was taken from (a float32 one, a lookup
+    that stores its volume, the full model without its GRU kernel): not
+    priced beats priced wrong.  Which model it is the code can see
+    (``config.small``); each has the figure read from its own served
+    program."""
+    if config.compute_dtype != "bfloat16" or config.corr_impl != "pallas":
+        return None
+    if config.small:
+        return int(SMALL_PAIR_TEMP_BYTES_PER_PIXEL * small_lane_padding(b)
+                   * b * h * w)
+    if config.gru_impl != "pallas":
         return None
     return PAIR_TEMP_BYTES_PER_PIXEL * b * h * w
 

@@ -315,6 +315,51 @@ def test_analyzer_prices_the_benchmark_configurations(name, measured_gb):
     assert abs(priced / 1e9 - measured_gb) < 0.1, priced
 
 
+# ``memory_analysis().temp_size_in_bytes`` of RAFT-S's served pair program
+# compiled for a described v5e (sandbox compiles, PR 31): the benchmark's
+# batch, PR 30's batch of 16, Sintel's size at 32 and at 64 (where 128
+# images fill the lanes and a pixel costs the 102 B of PERF.md's older note)
+@pytest.mark.parametrize("b,h,w,compiled", [
+    (8, 1080, 1920, 7_007_982_080), (4, 1080, 1920, 3_461_145_600),
+    (16, 1080, 1920, 12_946_424_832), (8, 440, 1024, 1_528_796_160),
+    (32, 440, 1024, 2_863_086_592), (48, 440, 1024, 2_904_888_832),
+    (64, 440, 1024, 2_947_110_400)])
+def test_pair_temp_bytes_prices_the_small_program(b, h, w, compiled):
+    """From above, and by less than a tenth: the constant is the unpadded
+    figure, the factor the lane padding of the half-resolution 32-channel
+    maps (channels or images in the lanes, whichever pads less)."""
+    small = RAFTConfig.small_model(iters=20, compute_dtype="bfloat16",
+                                   corr_impl="pallas")
+    priced = budget.pair_temp_bytes(small, h, w, b)
+    assert compiled <= priced < 1.10 * compiled, priced / compiled
+    assert priced == int(budget.SMALL_PAIR_TEMP_BYTES_PER_PIXEL
+                         * budget.small_lane_padding(b) * b * h * w)
+
+
+@pytest.mark.parametrize("b,want", [(1, 4.0), (8, 4.0), (16, 4.0),
+                                    (32, 2.0), (48, 4 / 3), (64, 1.0),
+                                    (65, 256 / 130)])
+def test_small_lane_padding(b, want):
+    assert budget.small_lane_padding(b) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("small,kw", [
+    (True, {"compute_dtype": "float32"}),
+    (True, {"corr_impl": "dense"}),
+    (False, {"compute_dtype": "float32"}),
+    (False, {"corr_impl": "dense"}),
+    (False, {"gru_impl": "xla"})])
+def test_pair_temp_bytes_prices_no_program_it_was_not_read_from(small, kw):
+    """A float32 program, a lookup that stores its volume, the full model
+    without its GRU kernel: None, for either model (not priced beats priced
+    wrong); the two served programs have a price each."""
+    make = RAFTConfig.small_model if small else RAFTConfig.full
+    base = dict(compute_dtype="bfloat16", corr_impl="pallas",
+                gru_impl="xla" if small else "pallas")
+    assert budget.pair_temp_bytes(make(**base), 440, 1024, 4) is not None
+    assert budget.pair_temp_bytes(make(**{**base, **kw}), 440, 1024, 4) is None
+
+
 def test_analyze_report_shape_and_headroom_monotone(config):
     reports = [budget.analyze(config, small_serve(max_sessions=s),
                               device_kind="cpu")

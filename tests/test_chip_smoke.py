@@ -217,3 +217,25 @@ cli.main(["-m", "serve_fleet", "--small", "--replicas", "2",
     assert proc.returncode == 0, proc.stderr[-800:]
     line = [ln for ln in proc.stdout.splitlines() if "BACKENDS" in ln][-1]
     assert line == "BACKENDS ['cpu'] cpu"
+
+
+def test_small_phase_rehearsed_on_the_cpu(capsys):
+    """``phase_small`` through its own code at a tiny size (2 x 64x96, the
+    lookup in interpret mode, all 20 updates): RAFT-S's served program from
+    ``benchmark/configs/raft-small-1080p.json``'s serve arguments, held to
+    ``benchmark/reference.py`` by the cell's ``precision_ratio``; one JSON
+    line, ``ok``.  A check that does not hold raises ``SmokeFailure``."""
+    import json
+
+    import chip_smoke
+    sz = chip_smoke.Sizes(interpret=True, small_batch=2, small_hw=(64, 96))
+    chip_smoke.phase_small(chip_smoke.CompileMeter(), sz)
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["phase"] == "small" and rec["ok"] is True
+    assert (rec["program"], rec["iters"]) == ("2x64x96", 20)
+    assert rec["dtype"] == "bfloat16"
+    assert 0 < rec["precision_ratio"] < rec["limit"] == 3.0
+    assert 0 < rec["keyblock_share"] <= 1
+    # the real sizes are the cell's
+    real = chip_smoke.Sizes()
+    assert (real.small_batch, real.small_hw) == (8, (1080, 1920))

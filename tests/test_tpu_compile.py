@@ -313,3 +313,106 @@ def test_lookup_launches_hand_over_what_the_update_block_consumes(
     assert not [n for n in under if n.startswith("convert")], under.keys()
     assert not [n for n, t in under.items() if ",9,9]" in t], under.keys()
     assert "f32[1,7040,9,9]" not in served_program.as_text()
+
+
+# ------------------------------------------ the served small program (PR 31)
+
+@pytest.fixture(scope="module")
+def served_small_program(one_chip):
+    """RAFT-S as ``benchmark/configs/raft-small-1080p.json`` serves it: the
+    configuration's own serve arguments through ``cli.parse_args`` /
+    ``_make_config``, the engine's pair function (key-block counts beside
+    the flow) at 8 x 1080x1920 — the batch the cell
+    ``small-1080p-b8-closed`` times.  About a minute."""
+    import json
+
+    from raft_tpu import cli
+    from raft_tpu.models import init_raft
+    from raft_tpu.models.raft import make_inference_fn
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "raft-small-1080p.json")) as f:
+        serve_args = [str(a) for a in json.load(f)["serve_args"]]
+    args = cli.parse_args(["-m", "serve"] + serve_args)
+    config = cli._make_config(args)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config)))
+    img = jax.ShapeDtypeStruct((args.max_batch, 1080, 1920, 3), jnp.float32,
+                               sharding=one_chip)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(make_inference_fn(
+            config, iters=args.iters, keyblocks=True)).lower(
+                params, img, img).compile()
+    return config, compiled
+
+
+def test_small_served_program_launches_the_radius_3_lookup(
+        served_small_program):
+    """Four ``corr_lookup.<n>`` launches an iteration, each returning a
+    query's 7x7 window side by side in the lanes, ``bf16[8,32512,49]``
+    (32,400 queries padded to whole tiles of 128); and no ``%gru.`` launch:
+    the 3x3 ConvGRU runs as XLA convolutions."""
+    from raft_tpu.telemetry.trace import instruction_stages
+    _, compiled = served_small_program
+    insts = instruction_stages(compiled.as_text())
+    launches = [rec["text"] for n, rec in insts.items()
+                if re.search(r"^corr_lookup\.", n)
+                and " custom-call(" in rec["text"]]
+    assert len(launches) == 4, launches
+    for text in launches:
+        assert re.search(r"= bf16\[8,32512,49\]\S* custom-call\(", text), text
+    assert not [n for n in insts if re.search(r"^gru\.", n)]
+    assert all(rec["loop"] == 1 for n, rec in insts.items()
+               if n.startswith("corr_lookup."))
+
+
+@pytest.mark.parametrize("scope", [
+    "raft/update/update/gru", "raft/update/update/motion_encoder",
+    "raft/update/update/heads", "raft/corr_lookup/l0/corr_lookup",
+    "raft/corr_lookup/l1/corr_lookup", "raft/corr_lookup/l2/corr_lookup",
+    "raft/corr_lookup/l3/corr_lookup", "raft/fnet", "raft/cnet",
+    "raft/upsample", "raft/gru_ctx"])
+def test_stage_map_of_the_small_served_program(served_small_program, scope):
+    """What the three metrics of PR 31 and the stage readers the cell shares
+    match on: the ConvGRU, the motion encoder and the flow head each under a
+    scope of their own inside ``raft/update``, each level's lookup, both
+    encoders, the upsampling."""
+    from raft_tpu.telemetry.trace import instruction_stages
+    _, compiled = served_small_program
+    insts = instruction_stages(compiled.as_text())
+    under = [n for n, rec in insts.items()
+             if rec["stage"] == scope or rec["stage"].startswith(scope + "/")]
+    assert under, scope
+    if "corr_lookup" in scope:
+        assert all(insts[n]["loop"] == 1 for n in under), scope
+    if scope.startswith("raft/update/"):
+        # inside the update loop, but for the weights' slices and relayouts
+        # the compiler hoists out of it
+        outside = [insts[n]["text"] for n in under if insts[n]["loop"] != 1]
+        assert len(outside) < len(under), scope
+        assert not [t for t in outside if "135,240" in t], outside
+    if scope.endswith("update/gru"):
+        # the gates' convolutions are XLA fusions that return the hidden
+        # state's shape (z and r together: 192 channels), not a kernel launch
+        texts = [insts[n]["text"] for n in under]
+        assert any(re.search(r"= bf16\[8,135,240,192\]\S* fusion\(", t)
+                   for t in texts), texts
+        assert any(re.search(r"= bf16\[8,135,240,96\]\S* fusion\(", t)
+                   for t in texts), texts
+        assert not any("custom-call(" in t for t in texts)
+
+
+def test_analyzer_prices_the_small_served_programs_temporaries(
+        served_small_program):
+    """``lint/budget.pair_temp_bytes`` against the chip compiler's own
+    ``memory_analysis()`` of this very program: within 5 %, and the program
+    fits a v5e with a second batch staged."""
+    from raft_tpu.lint import budget
+    config, compiled = served_small_program
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    priced = budget.pair_temp_bytes(config, 1080, 1920, 8)
+    assert abs(priced - temp) / temp < 0.05, (priced, temp)
+    assert temp < 12e9
