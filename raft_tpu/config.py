@@ -121,11 +121,6 @@ class RAFTConfig:
     # table in TUNING.md — not guesses.
     pallas_q_blk: int = 128
     pallas_p_blk: int = 4096
-    # Window-lookup formulation inside the fused kernel: 'matmul' (batched
-    # one-hot dot_generals) or 'vpu' (broadcast-multiply-reduce).  Identical
-    # values; relative speed is hardware-dependent (tools/tune_pallas.py
-    # --style sweeps it).
-    pallas_lookup_style: str = "matmul"
     # Compute dtype for conv/matmul-heavy paths ('float32' or 'bfloat16');
     # the correlation itself always accumulates in float32.  The library
     # default stays float32 (numerics-first; bf16 is emulated and slower on

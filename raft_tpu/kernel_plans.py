@@ -79,13 +79,31 @@ def corr_level_plan(q: int, h2: int, w2: int, *, q_blk: int,
                          n_pblocks=rows_padded // h2_blk)
 
 
+#: Lanes a window row takes where the lookup kernel holds a query's taps: a
+#: power of two >= 2r + 2 at the radii the models use (3, 4), so that eight
+#: window rows fill one 128-lane tile.
+TAP_LANES = 16
+
+
+def corr_tap_tiles(n: int) -> int:
+    """128-lane tiles of a query's ``(n+1) x (n+1)`` integer taps
+    (``ops/corr_pallas._window_taps``): window row ``j``, column ``i`` at
+    lane ``j * TAP_LANES + i``, eight rows a tile (two tiles at n = 9, one
+    at n = 7)."""
+    if n + 1 > TAP_LANES:
+        raise ValueError(f"a window row of {n + 1} taps does not fit "
+                         f"{TAP_LANES} lanes")
+    return -(-(n + 1) * TAP_LANES // LANE)
+
+
 def corr_window_vmem(plan: CorrLevelPlan, n: int, out_itemsize: int) -> int:
     """VMEM bytes of how a lookup launch hands over its ``n`` x ``n`` windows
-    (``ops/corr_pallas._accumulate``), at the size of their tiles: the
-    float32 scratch ``[T, n, n]`` the visited row-blocks are summed in (each
-    window pads to whole (8, 128) tiles: 8 KiB at n = 9) and the output block
-    ``[T, n*n]`` in the consumer's dtype, which the pipeline holds twice."""
-    scratch = plan.t * round_up(n, SUBLANE) * LANE * 4
+    (``ops/corr_pallas._accumulate``): the float32 scratch ``[T, tiles *
+    128]`` the visited row-blocks' taps are summed in (1 KiB a query at
+    n = 9; the ``[T, n, n]`` scratch of PRs 29-31 padded to 8 KiB) and the
+    output block ``[T, n*n]`` in the consumer's dtype, which the pipeline
+    holds twice."""
+    scratch = plan.t * corr_tap_tiles(n) * LANE * 4
     out_block = plan.t * round_up(n * n, LANE) * out_itemsize
     return scratch + 2 * out_block
 

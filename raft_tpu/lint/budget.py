@@ -35,10 +35,10 @@ from __future__ import annotations
 import dataclasses
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
-from ..kernel_plans import (GRU_HALO, GRU_TAPS, LANE, SUBLANE, VMEM_BYTES,
-                            VMEM_CEILING_BYTES, corr_level_plan,
-                            corr_window_vmem, gru_row_plan, gru_scoped_bytes,
-                            gru_vmem_limit)
+from ..kernel_plans import (GRU_HALO, GRU_TAPS, LANE, SUBLANE, TAP_LANES,
+                            VMEM_BYTES, VMEM_CEILING_BYTES, corr_level_plan,
+                            corr_tap_tiles, corr_window_vmem, gru_row_plan,
+                            gru_scoped_bytes, gru_vmem_limit)
 
 if TYPE_CHECKING:       # serving/ brings jax in: a name for annotations only
     from ..serving.config import Key
@@ -78,17 +78,17 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
     """Static VMEM envelope of the fused correlation kernel at ``bucket``.
 
     Per level: the pallas_call's resident blocks (f1/coords/f2 in, window
-    out), the float32 scratch the windows are summed in, plus the program's
-    dominant intermediates (the [T, Pblk] corr tile and the one-hot
-    interpolation matrices).  The maps are priced in the dtypes the kernel
-    holds them in, asked of the function that hands them to it
-    (``ops.corr_pallas.f2_terms``): for bfloat16 maps at 'highest' a
+    out), the float32 scratch the windows' taps are summed in, plus the
+    program's dominant intermediates (the [T, Pblk] corr tile and the
+    [T, 128] lane tiles its taps are gathered through).  The maps are priced
+    in the dtypes the kernel holds them in, asked of the function that hands
+    them to it (``ops.corr_pallas.f2_terms``): for bfloat16 maps at 'highest' a
     bfloat16 f1 block and one (level 0) or three (pooled levels) bfloat16
     planes of the f2 block, else float32 blocks.  The output block is
     ``[T, n*n]`` in the compute dtype (what ``models/raft.py`` asks the
-    kernel to write), the scratch ``[T, n, n]`` float32, both at the size
-    of their VMEM tiles (``kernel_plans.corr_window_vmem``).  Everything
-    else is float32.
+    kernel to write), the scratch ``[T, tiles * 128]`` float32, both at the
+    size of their VMEM tiles (``kernel_plans.corr_window_vmem``).
+    Everything else is float32 or int32.
     """
     import jax
     from ..ops.corr_pallas import f2_terms
@@ -110,13 +110,15 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
         plan = corr_level_plan(q, h2, w2, q_blk=config.pallas_q_blk,
                                p_blk_target=config.pallas_p_blk)
         pblk = plan.h2_blk * plan.w2p
-        # Calibrated against the chip compiler's own scoped-allocation
-        # figures (v5e, jax 0.9.0): this model gives 16.45 MiB for the
-        # default plan at Pblk 4096, where Mosaic reports 16.84M inside the
-        # chairs train step and just under 16.00M standalone at 440x1024.
+        # An upper envelope of the least scoped limit the chip's compiler
+        # accepts for a launch (v5e, jax 0.9.0, found by bisection in the
+        # sandbox, PR 32): 15.14 MiB at 1080x1920's levels 1 and 2 (three
+        # bfloat16 planes of 4096 x 256, priced 17.4), 12.93 at 440x1024's
+        # level 1 (priced 15.1), 10.55 for RAFT-S's 128-channel planes.
         # The pipeline double-buffers every grid-indexed block; the body
-        # keeps the MXU product and its [T, h2_blk, W2p] view, and builds
-        # each one-hot from two `where` terms over int32 iotas.
+        # keeps the MXU product and the term being added to it, a packed
+        # tile for every eight rows of the block, the taps and their
+        # candidates, and a dozen [T, 128] index and mask planes.
         planes = jax.eval_shape(
             lambda x: f2_terms(map_dtype, x, config.corr_precision),
             jax.ShapeDtypeStruct(
@@ -127,16 +129,13 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
         f32_blocks = plan.t * 2              # coords block
         window = corr_window_vmem(      # scratch + double-buffered output
             plan, n, 2 if config.compute_dtype == "bfloat16" else 4)
-        a_y = plan.t * n * plan.h2_blk
-        a_x = plan.t * n * plan.w2p
+        tap_tiles = corr_tap_tiles(n)
+        lane_tiles = (-(-plan.h2_blk * TAP_LANES // LANE)   # packed rows
+                      + 2 * tap_tiles + 1    # taps and their candidates
+                      + 12)                  # index and mask planes
         floats = (2 * f32_blocks             # double-buffered pipeline
-                  + 2 * plan.t * pblk        # corr tile + its 3-D view
-                  + 3 * (a_y + a_x)          # one-hots + their where terms
-                  + 2 * (a_y + a_x)          # int32 index iotas
-                  + a_x                      # win_y
-                  + plan.t * n * n)          # win
-        # (the 'vpu' lookup style is not priced separately: the compiler
-        # reports 19.18M for it at the default plan, 14% over this model)
+                  + 2 * plan.t * pblk        # corr tile + a term's product
+                  + plan.t * LANE * lane_tiles)
         bytes_ = 2 * map_blocks + window + 4 * floats
         worst = max(worst, bytes_)
         levels.append({"level": level, "shape": [h2, w2],

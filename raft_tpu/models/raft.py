@@ -305,7 +305,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                 fmap1, fmap2, sizes8, config.corr_levels,
                 config.corr_radius, corr_precision=corr_prec,
                 q_blk=config.pallas_q_blk, p_blk_target=config.pallas_p_blk,
-                lookup_style=config.pallas_lookup_style, out_dtype=cdt)
+                out_dtype=cdt)
         else:
             # 'dense' and 'blockwise' share the masked blockwise twin — the
             # dense (HW)^2 volume has no ragged form worth building, and the
@@ -332,8 +332,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
             spmd.spatial_axis(), precision=corr_prec,
             kernel="pallas" if config.corr_impl == "pallas" else "onehot",
             pallas_opts=dict(q_blk=config.pallas_q_blk,
-                             p_blk_target=config.pallas_p_blk,
-                             lookup_style=config.pallas_lookup_style))
+                             p_blk_target=config.pallas_p_blk))
     elif config.corr_impl == "dense":
         lookup_fn = (lookup_dense_onehot if config.corr_lookup == "onehot"
                      else lookup_dense)
@@ -362,8 +361,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
             lookup = make_fused_lookup(
                 fmap1, fmap2, config.corr_levels, config.corr_radius,
                 corr_precision=corr_prec, q_blk=config.pallas_q_blk,
-                p_blk_target=config.pallas_p_blk,
-                lookup_style=config.pallas_lookup_style, out_dtype=cdt)
+                p_blk_target=config.pallas_p_blk, out_dtype=cdt)
         counts_keyblocks = True
     else:
         raise ValueError(config.corr_impl)

@@ -6,21 +6,36 @@ full (HW)^2 volume in device memory (reference networks/model_utils.py:206-215,
 ~191 MB at 432x1024) and then bilinear-samples 81 points per query from it
 (model_utils.py:224-249). Here the volume never exists in HBM at all.
 
-Design (flash-attention-style, MXU-first):
+Design (flash-attention-style: the matmul on the MXU, the window on the
+vector and cross-lane units):
 
 * Grid ``(B, Q-blocks, P-blocks)``. Each program computes one correlation tile
-  ``f1_block @ f2_block^T / sqrt(C)`` on the MXU — at any instant only a
-  ``[T, Pblk]`` tile lives in VMEM.
-* The (2r+1)^2 bilinear window lookup is *separable*, so it is two more small
-  batched matmuls with one-hot interpolation matrices:
-
-      out[t] = A_x[t] @ (A_y[t] @ corr[t])^T
-
-  where ``A_y[t, j, h] = (1-fy_t)*[h == iy0_t+j] + fy_t*[h == iy0_t+j+1]``
-  (and A_x likewise). Zeros padding outside the map falls out of the one-hot
-  construction for free — an out-of-range index simply never matches — and
-  partial windows straddling a P-block boundary accumulate across the k grid
-  dimension. No per-query scalar loop, no gathers.
+  ``f1_block @ f2_block^T`` on the MXU — at any instant only a ``[T, Pblk]``
+  tile lives in VMEM: 128 queries in the sublanes, a key row-block's
+  positions in the lanes.
+* A query's (2r+1)^2 bilinear window needs the (2r+2)^2 integer taps around
+  it.  A visited block SELECTS the taps that lie in it, exactly
+  (:func:`_window_taps`): each map row of the tile is gathered along its
+  lanes at the query's own columns (``tpu.dynamic_gather``, a different
+  rotation in every sublane), eight rows are packed into one 128-lane tile,
+  and a second gather picks the query's own rows.  Lanes move; nothing is
+  multiplied, so a tap is the float32 correlation sum bit for bit, a tap in
+  another block or off the map is an exact zero (zeros padding for free),
+  and windows that straddle a block boundary add up across the k grid
+  dimension in a float32 VMEM scratch of ``[T, 256]``.
+* The bilinear BLEND runs once a query tile, on its last grid step
+  (:func:`_write_windows`): four products and three adds a value in float32
+  from two lane rotations of the scratch, the only rounding after the
+  correlation sums, then one lane-dense store.
+* Until PR 32 the window was *interpolated* in every visited block by two
+  per-query batched matmuls with weighted one-hot matrices, ``A_x[t] @
+  (A_y[t] @ corr[t])^T`` at ``HIGHEST`` ("these dots are tiny next to the
+  corr matmul").  They were two thirds of every launch: 128 slivers of
+  ``[9, rows] x [rows, lanes]`` a step cost a fixed 5 us (10-12 us at 256
+  lanes) whatever the block held, where the correlation dot itself takes
+  0.3-4 us (TUNING.md).  A broadcast-multiply-reduce form on the VPU, the
+  other value of the option that chose between them, was 1.5-3.7 x slower
+  still; the option went with both.
 * Backward delegates to the differentiable, matmul-only XLA twin
   (``ops.corr.lookup_blockwise_onehot``) via ``custom_vjp``: the forward
   rides the kernel, gradients ride XLA matmul fusions with no gathers.
@@ -35,8 +50,8 @@ bfloat16 map is one term, a float32 map pooled from it is three (hi + mid +
 lo, split once where the pyramid is built), so bfloat16 maps cost one MXU
 pass at level 0 and three at the pooled levels; float32 maps take the MXU's
 own six-pass ``HIGHEST`` matmul.  Same products, same float32 sums — the
-passes left out multiplied zeros.  Interpolation, scaling and the sums over
-key row-blocks are float32 throughout, held in a VMEM scratch; a launch
+passes left out multiplied zeros.  Selection is exact, scaling, the sums over
+key row-blocks and the blend are float32 throughout; a launch
 writes its windows once, rounded to the dtype its consumer states
 (``out_dtype``) and lane-dense (``[B, Q, n*n]``): a ``[.., 9, 9]`` float32
 array pads each query's 324 B to 8 KiB of HBM tiles, and converting and
@@ -56,7 +71,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..kernel_plans import VMEM_BYTES, corr_level_plan, corr_level_scheduled
+from ..kernel_plans import (LANE, TAP_LANES, VMEM_BYTES, corr_level_plan,
+                            corr_level_scheduled, corr_tap_tiles)
 from ..lint.contracts import contract
 from ..telemetry.trace import stage
 # corr_terms and its two readers live in ops/corr.py, which imports no
@@ -128,120 +144,214 @@ def _corr_tile(f1_ref, f2_ref, corr_precision) -> jax.Array:
     return corr
 
 
-def _window_body(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
-                 corr_scale: float, radius: int, h2_blk: int, w2: int,
-                 corr_precision, lookup_style: str):
-    """Shared program body: corr tile against f2 row-block ``sel`` + the
-    separable one-hot window lookup.  Returns the [T, n, n] x-offset-major
-    window contribution of this row-block.
+#: Window rows (map rows, where a block is packed) that share one 128-lane
+#: tile of the tap layout (``kernel_plans.corr_tap_tiles``), and the shifts
+#: that divide by the two powers of two (the vector unit has no divide).
+_ROWS_A_TILE = LANE // TAP_LANES
+_TAP_SHIFT = TAP_LANES.bit_length() - 1
+_ROW_SHIFT = _ROWS_A_TILE.bit_length() - 1
+_LANE_SHIFT = LANE.bit_length() - 1
 
-    ``lookup_style``: how the separable one-hot interpolation contracts —
-    'matmul' (per-query batched dot_generals) or 'vpu' (broadcast-multiply-
-    reduce; per-query matmuls are tiny [n,h2_blk]x[h2_blk,W2] slivers that
-    Mosaic serializes over the T batch dim, so elementwise VPU work can win).
-    Both produce identical values.
-    """
-    n = 2 * radius + 1
-    T = f1_ref.shape[1]
-    corr = _corr_tile(f1_ref, f2_ref, corr_precision) * corr_scale  # [T, Pblk]
-    corr3 = corr.reshape(T, h2_blk, w2)
 
+def _window_origin(coords_ref, level_scale: float, radius: int):
+    """A query tile's windows: ``(ix0, iy0)`` int32 ``[T, 1]``, the first
+    tap's column and row at this level, and ``(fx, fy)`` float32 ``[T, 1]``,
+    how far the query lies past it."""
     c = coords_ref[0] * level_scale                  # [T, 2] (x, y)
-    cx, cy = c[:, 0], c[:, 1]
-    cx0 = jnp.floor(cx)
-    cy0 = jnp.floor(cy)
-    fx = (cx - cx0)[:, None, None]
-    fy = (cy - cy0)[:, None, None]
-    ix0 = cx0.astype(jnp.int32) - radius
-    iy0 = cy0.astype(jnp.int32) - radius
-
-    # A_y [T, n, h2_blk]: rows of the bilinear window that land in this p-block
-    h_ids = (jax.lax.broadcasted_iota(jnp.int32, (T, n, h2_blk), 2)
-             + sel * h2_blk)
-    ty = iy0[:, None, None] + jax.lax.broadcasted_iota(
-        jnp.int32, (T, n, h2_blk), 1)
-    a_y = (jnp.where(h_ids == ty, 1.0 - fy, 0.0)
-           + jnp.where(h_ids == ty + 1, fy, 0.0))
-    # A_x [T, n, W2]
-    w_ids = jax.lax.broadcasted_iota(jnp.int32, (T, n, w2), 2)
-    tx = ix0[:, None, None] + jax.lax.broadcasted_iota(
-        jnp.int32, (T, n, w2), 1)
-    a_x = (jnp.where(w_ids == tx, 1.0 - fx, 0.0)
-           + jnp.where(w_ids == tx + 1, fx, 0.0))
-
-    if lookup_style == "vpu":
-        # win_y[t,j,w] = sum_h a_y[t,j,h] * corr3[t,h,w]; the f32 multiply
-        # keeps the exact bilinear weights (same numerics as the HIGHEST-
-        # precision dots below).  At the default plan (T=128, h2_blk=32,
-        # W2p=128) this style needs 19.18M of scoped VMEM: refused under
-        # the compiler's 16 MiB default, accepted under _COMPILER_PARAMS
-        win_y = jnp.sum(a_y[:, :, :, None] * corr3[:, None, :, :], axis=2)
-        win = jnp.sum(a_x[:, :, None, :] * win_y[:, None, :, :], axis=3)
-    else:
-        # interpolation matmuls always run at HIGHEST precision: the bilinear
-        # weights (1-f, f) must not be rounded to bf16 (subpixel flow
-        # accuracy), and these dots are tiny next to the corr matmul.
-        win_y = jax.lax.dot_general(                  # [T, n(y), W2]
-            a_y, corr3, (((2,), (1,)), ((0,), (0,))),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        win = jax.lax.dot_general(                    # [T, n(x), n(y)]
-            a_x, win_y, (((2,), (2,)), ((0,), (0,))),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-    return win          # x-offset-major [T, n, n]; _accumulate flattens it
+    c0 = jnp.floor(c)
+    i0 = c0.astype(jnp.int32) - radius
+    frac = c - c0
+    return (i0[:, 0:1], i0[:, 1:2]), (frac[:, 0:1], frac[:, 1:2])
 
 
-def _accumulate(acc_ref, out_ref, k, last, visit, window):
+def _tap_lanes(T: int):
+    """int32 ``[T, 128]``: the window row (within its tile of eight) and the
+    window column each lane of the tap layout holds."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (T, LANE), 1)
+    return lane >> _TAP_SHIFT, lane & (TAP_LANES - 1)
+
+
+def _take(x: jax.Array, lanes: jax.Array) -> jax.Array:
+    """``out[t, l] = x[t, lanes[t, l]]``: a gather along the 128 lanes of
+    each query's row (one ``tpu.dynamic_gather`` a vector register)."""
+    return jnp.take_along_axis(x, lanes, axis=1, mode="promise_in_bounds")
+
+
+def _window_taps(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
+                 corr_scale: float, radius: int, h2_blk: int, w2: int,
+                 corr_precision):
+    """Shared program body, the part every visited key row-block runs: the
+    corr tile against f2 row-block ``sel``, and from it the ``(n+1) x
+    (n+1)`` integer taps of each query's window that lie in this block,
+    SELECTED, not interpolated: ``[T, tiles * 128]`` float32, window row
+    ``j`` and column ``i`` at lane ``j * 16 + i``, zero where a tap lies in
+    another block or outside the map.  Each tap is the float32 correlation
+    sum itself, bit for bit: lanes are moved, nothing is multiplied.
+
+    Queries lie in the sublanes and a block's positions in the lanes, so a
+    query's window is a different set of lanes for every sublane: that is a
+    lane gather (``tpu.dynamic_gather``), not a matmul.  Three moves:
+
+    * columns: each map row of the block (a 128-lane chunk of the tile, two
+      where a row takes 256 lanes) is gathered at ``ix0 + i``, the sixteen
+      window columns repeated in every group of sixteen lanes;
+    * pack: eight map rows share one 128-lane tile, row ``y`` keeping group
+      ``y % 8`` (a select on a static lane mask);
+    * rows: a query's window rows ``iy0 + j`` lie in at most three such
+      tiles; they are chosen per query and gathered once more, which rotates
+      the groups so that window row ``j`` lands in group ``j % 8``.
+
+    The cost goes with the rows of the block, and no bilinear weight enters
+    here (TUNING.md has the us a step)."""
+    T = f1_ref.shape[1]
+    corr = _corr_tile(f1_ref, f2_ref, corr_precision)           # [T, Pblk]
+    (ix0, iy0), _ = _window_origin(coords_ref, level_scale, radius)
+    grp, col = _tap_lanes(T)
+    zeros = jnp.zeros((T, LANE), jnp.float32)
+
+    x = ix0 + col                               # the map column a lane wants
+    src, chunk_of = x & (LANE - 1), x >> _LANE_SHIFT
+    packed = []
+    for y in range(h2_blk):
+        row = None
+        for c in range(w2 // LANE):
+            at = y * w2 + c * LANE
+            got = _take(corr[:, at:at + LANE], src)
+            row = got if row is None else jnp.where(chunk_of == c, got, row)
+        if y % _ROWS_A_TILE == 0:
+            packed.append(zeros)
+        packed[-1] = jnp.where(grp == y % _ROWS_A_TILE, row, packed[-1])
+
+    r0 = iy0 - sel * h2_blk                     # first window row, in block rows
+    first_tile = r0 >> _ROW_SHIFT
+
+    def tile_of(which):     # the packed tile each query wants, zeros past the block
+        out = zeros
+        for g, rows in enumerate(packed):
+            out = jnp.where(which == g, rows, out)
+        return out
+
+    # window row j = 8a + jj sits in block row r0 + j: group (r0 + jj) % 8 of
+    # tile first_tile + a, or of the next one once the groups wrap
+    rot = (((r0 + grp) & (_ROWS_A_TILE - 1)) << _TAP_SHIFT) | col
+    wraps = (r0 & (_ROWS_A_TILE - 1)) + grp >= _ROWS_A_TILE
+    in_map = (x >= 0) & (x < w2)    # (columns W2 .. W2p hold zero correlation)
+    tiles = corr_tap_tiles(2 * radius + 1)
+    srcs = [_take(tile_of(first_tile + a), rot) for a in range(tiles + 1)]
+    # the 1/sqrt(C) of the correlation, on the taps alone: the product of a
+    # moved value is the moved product, and a tile has 32 x as many values
+    taps = [jnp.where(in_map, jnp.where(wraps, srcs[a + 1], srcs[a])
+                      * corr_scale, 0.0) for a in range(tiles)]
+    return taps[0] if tiles == 1 else jnp.concatenate(taps, axis=1)
+
+
+def _floordiv_lanes(lane: jax.Array, n: int) -> jax.Array:
+    """``lane // n`` for lanes 0..127 as a multiply and a shift (the vector
+    unit has no integer divide)."""
+    mult, shift = -(-(1 << 12) // n), 12
+    assert all((v * mult) >> shift == v // n for v in range(128)), n
+    return (lane * mult) >> shift
+
+
+def _write_windows(coords_ref, acc_ref, out_ref, *, level_scale: float,
+                   radius: int):
+    """Shared program body, the part a query tile runs once, on its last
+    grid step: the bilinear blend of the summed taps ``acc_ref`` (the layout
+    of :func:`_window_taps`) into the ``n x n`` windows, in float32 on the
+    VPU, rounded to the output's dtype as it is stored — the only rounding
+    after the correlation sums.  With ``t[j, i]`` the tap at window row
+    ``j``, column ``i``:
+
+        bx[j, i]  = (1-fx) * t[j, i] + fx * t[j, i+1]
+        out[j, i] = (1-fy) * bx[j, i] + fy * bx[j+1, i]
+
+    so x is blended first: four products and three adds a value, in that
+    order.  The neighbours come from lane rotations (one lane for ``i+1``,
+    sixteen for ``j+1``, carrying in from the next tile); a last gather
+    with a static index puts ``out[j, i]`` at lane ``i * n + j``, a query's
+    n*n values side by side (x-offset-major), and the row is stored once."""
+    n = 2 * radius + 1
+    T = acc_ref.shape[0]
+    _, (fx, fy) = _window_origin(coords_ref, level_scale, radius)
+    gx, gy = 1.0 - fx, 1.0 - fy
+    lane = jax.lax.broadcasted_iota(jnp.int32, (T, LANE), 1)
+    tiles = corr_tap_tiles(n)
+
+    def ahead(v, k):                     # the value k lanes further on
+        return pltpu.roll(v, LANE - k, axis=1)
+
+    bx = []
+    for a in range(tiles):
+        t = acc_ref[:, a * LANE:(a + 1) * LANE]
+        bx.append(gx * t + fx * ahead(t, 1))
+    i = _floordiv_lanes(lane, n)
+    j = lane - n * i
+    perm = ((j & (_ROWS_A_TILE - 1)) << _TAP_SHIFT) | i
+    perm = jnp.where(lane < n * n, perm, 0)
+    out = None
+    for a in range(tiles):
+        below = ahead(bx[a], TAP_LANES)
+        if a + 1 < tiles:
+            below = jnp.where(lane < LANE - TAP_LANES, below,
+                              ahead(bx[a + 1], TAP_LANES))
+        got = _take(gy * bx[a] + fy * below, perm)
+        out = got if out is None else jnp.where(j >> _ROW_SHIFT == a, got, out)
+    out_ref[0] = out[:, :n * n].astype(out_ref.dtype)
+
+
+def _accumulate(acc_ref, k, last, visit, taps, write):
     """The one way a lookup launch hands over its result.  Where ``visit``
     (None: every step) holds, grid step ``k`` adds its key row-block's
-    ``window()`` ([T, n, n] float32) into the float32 scratch ``acc_ref``;
-    the step a query tile ends on (``last``), visited or not, writes the
-    output block once: the sums rounded to the output's dtype, a query's
-    n*n values side by side in the lanes of one row (x-offset-major)."""
+    ``taps()`` (float32, :func:`_window_taps`' layout) into the float32
+    scratch ``acc_ref``; the step a query tile ends on (``last``), visited
+    or not, calls ``write()`` once (:func:`_write_windows`)."""
     def add():
-        win = window()
+        part = taps()
 
         @pl.when(k == 0)
         def _():
-            acc_ref[...] = win
+            acc_ref[...] = part
 
         @pl.when(k > 0)
         def _():
-            acc_ref[...] = acc_ref[...] + win
+            acc_ref[...] = acc_ref[...] + part
 
     if visit is None:
         add()
     else:
         pl.when(visit)(add)
 
-    @pl.when(last)
-    def _():
-        # Mosaic has no shape cast merging [T, n, n]'s two unaligned minor
-        # dims, so the n rows of a window are placed one by one: a strided
-        # sublane read of the scratch, a masked store at a static lane offset
-        n = acc_ref.shape[1]
-        for i in range(n):
-            out_ref[0, :, i * n:(i + 1) * n] = (
-                acc_ref[:, i, :].astype(out_ref.dtype))
+    pl.when(last)(write)
 
 
 def _sums_scratch(T: int, n: int) -> list:
     """``scratch_shapes`` of every lookup launch: :func:`_accumulate`'s
-    float32 sums (``kernel_plans.corr_window_vmem`` prices them)."""
-    return [pltpu.VMEM((T, n, n), jnp.float32)]
+    float32 tap sums (``kernel_plans.corr_window_vmem`` prices them)."""
+    return [pltpu.VMEM((T, corr_tap_tiles(n) * LANE), jnp.float32)]
 
 
-def _level_kernel(f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *, body):
+def _level_body(C: int, level: int, radius: int, plan, corr_precision):
+    """``(taps, write)`` of one level's launch, the two parts of the shared
+    body with the level's constants bound."""
+    scale = dict(level_scale=1.0 / (2.0 ** level), radius=radius)
+    return (functools.partial(_window_taps, corr_scale=1.0 / (C ** 0.5),
+                              h2_blk=plan.h2_blk, w2=plan.w2p,
+                              corr_precision=corr_precision, **scale),
+            functools.partial(_write_windows, **scale))
+
+
+def _level_kernel(f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *, taps,
+                  write):
     """One (batch, query-block, p-block) program: the k-th grid step visits
     f2 row-block k (full pass over the map)."""
     k = pl.program_id(2)
-    _accumulate(acc_ref, out_ref, k, k == pl.num_programs(2) - 1, None,
-                lambda: body(k, f1_ref, coords_ref, f2_ref))
+    _accumulate(acc_ref, k, k == pl.num_programs(2) - 1, None,
+                lambda: taps(k, f1_ref, coords_ref, f2_ref),
+                lambda: write(coords_ref, acc_ref, out_ref))
 
 
 def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *,
-                   body):
+                   taps, write):
     """Window-scheduled program: identical math to ``_level_kernel`` but the
     k-th grid step visits f2 row-block ``S[b, j*K + k]`` instead of row-block
     ``k``.  The schedule repeats its last needed block to fill the static
@@ -253,9 +363,10 @@ def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *,
     at = pl.program_id(1) * pl.num_programs(2) + k
     sel = S_ref[b, at]
     prev = S_ref[b, at - jnp.minimum(k, 1)]      # step 0 has no previous
-    _accumulate(acc_ref, out_ref, k, k == pl.num_programs(2) - 1,
+    _accumulate(acc_ref, k, k == pl.num_programs(2) - 1,
                 (k == 0) | (sel != prev),
-                lambda: body(sel, f1_ref, coords_ref, f2_ref))
+                lambda: taps(sel, f1_ref, coords_ref, f2_ref),
+                lambda: write(coords_ref, acc_ref, out_ref))
 
 
 def _window_schedule(coords: jax.Array, level_scale: float, radius: int,
@@ -263,7 +374,7 @@ def _window_schedule(coords: jax.Array, level_scale: float, radius: int,
     """Per (batch, query-block) contiguous range of f2 row-blocks its bilinear
     windows can touch, as a [B, Qb, K] block-index schedule.  Entries past
     the needed range repeat the last needed block (skip marker).  Fully
-    out-of-map windows contribute zeros via the one-hot construction, so
+    out-of-map windows select nothing (no position matches their taps), so
     pointing them at block 0 is safe."""
     B, Qp, _ = coords.shape
     n = 2 * radius + 1
@@ -357,7 +468,6 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                   radius: int, level: int, *, q_blk: int,
                   p_blk_target: int, interpret: bool,
                   corr_precision=jax.lax.Precision.HIGHEST,
-                  lookup_style: str = "matmul",
                   schedule: Optional[jax.Array] = None,
                   out_dtype=jnp.float32) -> jax.Array:
     """f1 [B,Q,C], f2_level [B,H2,W2,C] (or its [n,B,H2,W2,C] term planes,
@@ -397,10 +507,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
         # at the image boundary.
         f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2p - H2), (0, W2p - W2),
                           (0, 0)))
-    body = functools.partial(
-        _window_body, level_scale=1.0 / (2.0 ** level),
-        corr_scale=1.0 / (C ** 0.5), radius=radius, h2_blk=h2_blk,
-        w2=W2p, corr_precision=corr_precision, lookup_style=lookup_style)
+    taps, write = _level_body(C, level, radius, plan, corr_precision)
     f2 = f2.reshape(n_terms, B, -1, C)
 
     grid = (B, Qp // T, n_pblocks)
@@ -432,7 +539,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
             scratch_shapes=acc,
         )
         out = pl.pallas_call(
-            functools.partial(_window_kernel, body=body),
+            functools.partial(_window_kernel, taps=taps, write=write),
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,
@@ -440,7 +547,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
         )(S, f1, coords, f2)
     else:
         out = pl.pallas_call(
-            functools.partial(_level_kernel, body=body),
+            functools.partial(_level_kernel, taps=taps, write=write),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, T, C), lambda b, j, k: (b, j, 0)),
@@ -472,16 +579,10 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                        q_blk: int = 128, p_blk_target: int = 4096,
                        interpret: Optional[bool] = None,
                        corr_precision=jax.lax.Precision.HIGHEST,
-                       lookup_style: str = "matmul",
                        schedules: Optional[Tuple] = None,
                        out_dtype=jnp.float32) -> jax.Array:
     B, H, W, C = fmap1.shape
     Q = H * W
-    if lookup_style not in ("matmul", "vpu"):
-        # same silent-fallback hazard as corr_lookup/corr_precision: a typo
-        # must not quietly run the other formulation
-        raise ValueError(f"lookup_style must be 'matmul' or 'vpu', "
-                         f"got {lookup_style!r}")
     interp = _use_interpret() if interpret is None else interpret
     if schedules is None:       # a caller that does not count key blocks
         schedules = lookup_schedules(
@@ -501,17 +602,16 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
             outs.append(_lookup_level(
                 f1, f2l, cf, radius, i, q_blk=q_blk,
                 p_blk_target=p_blk_target, interpret=interp,
-                corr_precision=corr_precision, lookup_style=lookup_style,
-                schedule=sched, out_dtype=out_dtype))
+                corr_precision=corr_precision, schedule=sched,
+                out_dtype=out_dtype))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 9))
 def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
                  coords: jax.Array, radius: int,
                  corr_precision=jax.lax.Precision.HIGHEST,
                  q_blk: int = 128, p_blk_target: int = 4096,
-                 lookup_style: str = "matmul",
                  f2_planes: Optional[Tuple[jax.Array, ...]] = None,
                  schedules: Optional[Tuple] = None,
                  out_dtype=jnp.float32) -> jax.Array:
@@ -539,17 +639,15 @@ def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
     return _fused_lookup_impl(
         fmap1, f2_levels if f2_planes is None else f2_planes, coords, radius,
         q_blk=q_blk, p_blk_target=p_blk_target,
-        corr_precision=corr_precision, lookup_style=lookup_style,
-        schedules=schedules, out_dtype=out_dtype)
+        corr_precision=corr_precision, schedules=schedules,
+        out_dtype=out_dtype)
 
 
 def _fused_lookup_fwd(fmap1, f2_levels, coords, radius, corr_precision,
-                      q_blk, p_blk_target, lookup_style, f2_planes,
-                      schedules, out_dtype):
+                      q_blk, p_blk_target, f2_planes, schedules, out_dtype):
     return fused_lookup(fmap1, f2_levels, coords, radius, corr_precision,
-                        q_blk, p_blk_target, lookup_style, f2_planes,
-                        schedules, out_dtype), (fmap1, f2_levels, coords,
-                                                schedules)
+                        q_blk, p_blk_target, f2_planes, schedules,
+                        out_dtype), (fmap1, f2_levels, coords, schedules)
 
 
 def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
@@ -571,7 +669,7 @@ def _twin_vjp(fmap1, f2_levels, coords, radius, corr_precision, g):
 
 
 def _fused_lookup_bwd(radius, corr_precision, q_blk, p_blk_target,
-                      lookup_style, out_dtype, residuals, g):
+                      out_dtype, residuals, g):
     # the planes are a function of f2_levels that the forward precomputed:
     # their cotangent is zero (None), f2_levels carry the gradient; the
     # schedules are integer metadata (float0, as the ragged sizes are)
@@ -602,10 +700,9 @@ class FusedLookup:
 
     def __init__(self, fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
                  radius: int, corr_precision="highest", q_blk: int = 128,
-                 p_blk_target: int = 4096, lookup_style: str = "matmul",
-                 out_dtype=jnp.float32):
+                 p_blk_target: int = 4096, out_dtype=jnp.float32):
         self.radius, self.prec = radius, as_precision(corr_precision)
-        self.opts = (q_blk, p_blk_target, lookup_style)
+        self.opts = (q_blk, p_blk_target)
         self.out_dtype = out_dtype      # what the caller consumes windows in
         self.plan_args = dict(q_blk=q_blk, p_blk_target=p_blk_target)
         self.f2_levels = tuple(fmap2_pyramid(fmap2.astype(jnp.float32),
@@ -636,12 +733,11 @@ class FusedLookup:
 def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
                       radius: int, corr_precision="highest",
                       q_blk: int = 128, p_blk_target: int = 4096,
-                      lookup_style: str = "matmul",
                       out_dtype=jnp.float32) -> FusedLookup:
     """Build the per-iteration lookup closure used by models/raft.py, whose
     update block consumes the windows in ``out_dtype``."""
     return FusedLookup(fmap1, fmap2, num_levels, radius, corr_precision,
-                       q_blk, p_blk_target, lookup_style, out_dtype)
+                       q_blk, p_blk_target, out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -663,7 +759,7 @@ def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
 
 
 def _ragged_window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref,
-                          acc_ref, *, body, n_pb):
+                          acc_ref, *, taps, write, n_pb):
     """Page-scheduled program over the flattened query stream: grid
     ``(B*Qp/T, K)``; step k of query block j visits absolute f2 page
     ``S[j, k]`` (= item * n_pb + relative row-block).  The body needs the
@@ -674,9 +770,10 @@ def _ragged_window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref,
     k = pl.program_id(1)
     sel = S_ref[j, k]
     prev = S_ref[j, jnp.maximum(k - 1, 0)]
-    _accumulate(acc_ref, out_ref, k, k == pl.num_programs(1) - 1,
+    _accumulate(acc_ref, k, k == pl.num_programs(1) - 1,
                 (k == 0) | (sel != prev),
-                lambda: body(sel % n_pb, f1_ref, coords_ref, f2_ref))
+                lambda: taps(sel % n_pb, f1_ref, coords_ref, f2_ref),
+                lambda: write(coords_ref, acc_ref, out_ref))
 
 
 def _ragged_schedule(coords: jax.Array, live: jax.Array, rows_crop: jax.Array,
@@ -711,7 +808,6 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
                          rows_crop: jax.Array, radius: int, level: int, *,
                          q_blk: int, p_blk_target: int, interpret: bool,
                          corr_precision=jax.lax.Precision.HIGHEST,
-                         lookup_style: str = "matmul",
                          out_dtype=jnp.float32) -> jax.Array:
     """f1 [B,Q,C] (dead rows zero), f2_level [B,H2,W2,C] (pre-masked; or its
     [n,B,H2,W2,C] term planes), coords [B,Q,2], live [B,Q] bool, rows_crop
@@ -741,10 +837,7 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
         f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2p - H2), (0, W2p - W2),
                           (0, 0)))
 
-    body = functools.partial(
-        _window_body, level_scale=1.0 / (2.0 ** level),
-        corr_scale=1.0 / (C ** 0.5), radius=radius, h2_blk=h2_blk,
-        w2=W2p, corr_precision=corr_precision, lookup_style=lookup_style)
+    taps, write = _level_body(C, level, radius, plan, corr_precision)
 
     # flatten to per-item-page streams: query block j serves item j // (Qp/T)
     # (Qp is uniform across items, so blocks never straddle an item), and
@@ -769,7 +862,8 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
         scratch_shapes=_sums_scratch(T, n),
     )
     out = pl.pallas_call(
-        functools.partial(_ragged_window_kernel, body=body, n_pb=n_pb),
+        functools.partial(_ragged_window_kernel, taps=taps, write=write,
+                          n_pb=n_pb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, B * Qp, n * n), out_dtype),
         interpret=interpret,
@@ -787,13 +881,9 @@ def _ragged_fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                               p_blk_target: int = 4096,
                               interpret: Optional[bool] = None,
                               corr_precision=jax.lax.Precision.HIGHEST,
-                              lookup_style: str = "matmul",
                               out_dtype=jnp.float32) -> jax.Array:
     B, H, W, C = fmap1.shape
     Q = H * W
-    if lookup_style not in ("matmul", "vpu"):
-        raise ValueError(f"lookup_style must be 'matmul' or 'vpu', "
-                         f"got {lookup_style!r}")
     interp = _use_interpret() if interpret is None else interpret
     f1 = fmap1.reshape(B, Q, C)
     cf = coords.reshape(B, Q, 2)
@@ -809,17 +899,15 @@ def _ragged_fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
             outs.append(_ragged_lookup_level(
                 f1, f2l, cf, live, rows // (2 ** i), radius, i, q_blk=q_blk,
                 p_blk_target=p_blk_target, interpret=interp,
-                corr_precision=corr_precision, lookup_style=lookup_style,
-                out_dtype=out_dtype))
+                corr_precision=corr_precision, out_dtype=out_dtype))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 9))
 def ragged_fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
                         coords: jax.Array, sizes8: jax.Array, radius: int,
                         corr_precision=jax.lax.Precision.HIGHEST,
                         q_blk: int = 128, p_blk_target: int = 4096,
-                        lookup_style: str = "matmul",
                         f2_planes: Optional[Tuple[jax.Array, ...]] = None,
                         out_dtype=jnp.float32) -> jax.Array:
     """Ragged Pallas-fused correlation lookup.
@@ -836,21 +924,20 @@ def ragged_fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
     return _ragged_fused_lookup_impl(
         fmap1, f2_levels if f2_planes is None else f2_planes, coords, sizes8,
         radius, q_blk=q_blk, p_blk_target=p_blk_target,
-        corr_precision=corr_precision, lookup_style=lookup_style,
-        out_dtype=out_dtype)
+        corr_precision=corr_precision, out_dtype=out_dtype)
 
 
 def _ragged_fused_lookup_fwd(fmap1, f2_levels, coords, sizes8, radius,
-                             corr_precision, q_blk, p_blk_target,
-                             lookup_style, f2_planes, out_dtype):
+                             corr_precision, q_blk, p_blk_target, f2_planes,
+                             out_dtype):
     return ragged_fused_lookup(fmap1, f2_levels, coords, sizes8, radius,
                                corr_precision, q_blk, p_blk_target,
-                               lookup_style, f2_planes, out_dtype), (
+                               f2_planes, out_dtype), (
         fmap1, f2_levels, coords, sizes8)
 
 
 def _ragged_fused_lookup_bwd(radius, corr_precision, q_blk, p_blk_target,
-                             lookup_style, out_dtype, residuals, g):
+                             out_dtype, residuals, g):
     # gradients via the same matmul-only XLA twin as the dense kernel: the
     # masked max-box streams make lookup_blockwise_onehot the exact ragged
     # reference, so its vjp is the exact ragged backward (dead-region
@@ -869,7 +956,6 @@ def make_ragged_fused_lookup(fmap1: jax.Array, fmap2: jax.Array,
                              sizes8: jax.Array, num_levels: int, radius: int,
                              corr_precision="highest", q_blk: int = 128,
                              p_blk_target: int = 4096,
-                             lookup_style: str = "matmul",
                              out_dtype=jnp.float32):
     """Ragged twin of :func:`make_fused_lookup` for mixed-resolution batches
     sharing one max box: masks frame-1 features and builds the re-masked
@@ -889,7 +975,7 @@ def make_ragged_fused_lookup(fmap1: jax.Array, fmap2: jax.Array,
 
     def lookup(coords: jax.Array) -> jax.Array:
         return ragged_fused_lookup(fmap1, f2_levels, coords, sizes8, radius,
-                                   prec, q_blk, p_blk_target, lookup_style,
-                                   f2_planes, out_dtype)
+                                   prec, q_blk, p_blk_target, f2_planes,
+                                   out_dtype)
 
     return lookup

@@ -95,7 +95,7 @@ def make_ring_lookup_local(f1_local: jax.Array, f2_local: jax.Array,
     unchanged kernel produce exactly that slab's partial at EVERY pyramid
     level at once (the shift scales with the level like the coords do, and
     out-of-slab windows one-hot-match nothing = zeros).  ``pallas_opts``
-    forwards q_blk/p_blk_target/lookup_style; each slab's launch
+    forwards q_blk/p_blk_target; each slab's launch
     takes the key-block schedule by the kernel's own rule
     (``kernel_plans.corr_level_scheduled``: more than one row-block), like
     every other caller.
@@ -111,8 +111,7 @@ def make_ring_lookup_local(f1_local: jax.Array, f2_local: jax.Array,
         # public custom_vjp entry point: the ring path stays differentiable
         # (backward rides the XLA twin); hoisted out of the per-slab closure
         from ..ops.corr_pallas import fused_lookup
-        pl_opts = {"q_blk": 128, "p_blk_target": 4096,
-                   "lookup_style": "matmul", **(pallas_opts or {})}
+        pl_opts = {"q_blk": 128, "p_blk_target": 4096, **(pallas_opts or {})}
         # precision=None means backend default — same resolution the onehot
         # branch's dense_corr applies
         pl_prec = (precision if precision is not None
@@ -143,8 +142,7 @@ def make_ring_lookup_local(f1_local: jax.Array, f2_local: jax.Array,
                 # slabs' partial windows are added up below
                 out = fused_lookup(f1_local, tuple(levels), shifted, radius,
                                    pl_prec, pl_opts["q_blk"],
-                                   pl_opts["p_blk_target"],
-                                   pl_opts["lookup_style"])
+                                   pl_opts["p_blk_target"])
                 return out.reshape(B, Q, -1)
             outs = []
             for i, f2l in enumerate(levels):

@@ -206,19 +206,21 @@ def test_vmem_envelopes(config):
 
 
 @pytest.mark.parametrize("kw,planes,mib", [
-    # float32 maps: one float32 plane a level, the figures of PR 21 and, since
-    # PR 29, the window's float32 scratch and lane-dense output block at
-    # the size of their tiles (kernel_plans.corr_window_vmem: 1.125 MiB here)
+    # float32 maps: one float32 plane a level; the window's float32 tap
+    # scratch and lane-dense output block at the size of their tiles
+    # (kernel_plans.corr_window_vmem: 0.25 MiB here since PR 32, whose body
+    # gathers a window's taps through [T, 128] lane tiles where PRs 21-31
+    # priced one-hot matrices of [T, 9, rows] and [T, 9, lanes])
     (dict(compute_dtype="float32"), [[1, "float32"]] * 4,
-     [17.49, 15.51, 9.95, 7.17]),
+     [13.81, 11.94, 6.56, 3.88]),
     # bfloat16 maps at 'highest': level 0 holds one bfloat16 plane (4 MB of
     # double-buffered f2 less), the pooled levels three (up to 4 MB more)
     (dict(compute_dtype="bfloat16"),
-     [[1, "bfloat16"]] + [[3, "bfloat16"]] * 3, [13.31, 18.7, 11.39, 7.74]),
+     [[1, "bfloat16"]] + [[3, "bfloat16"]] * 3, [9.63, 15.13, 8.0, 4.44]),
     # 'default' keeps the float32 blocks the MXU rounds itself; the output
     # block is bfloat16 all the same (the update block's dtype)
     (dict(compute_dtype="bfloat16", corr_precision="default"),
-     [[1, "float32"]] * 4, [17.43, 15.45, 9.89, 7.11]),
+     [[1, "float32"]] * 4, [13.75, 11.88, 6.5, 3.81]),
 ])
 def test_corr_envelope_prices_the_dtypes_the_kernel_holds(kw, planes, mib):
     full = RAFTConfig.full(corr_impl="pallas", **kw)

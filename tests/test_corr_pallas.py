@@ -3,6 +3,8 @@ against the dense XLA oracle (ops/corr.py) — the kernel runs in Pallas
 interpret mode on CPU so the exact kernel code is exercised (SURVEY.md §4:
 multi-device/TPU paths must be testable on the CPU fake backend)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,6 +31,46 @@ def _every_level_scheduled(coords, f2_levels, radius, q_blk, p_blk_target):
                                p_blk_target=p_blk_target)
         out.append(level_schedule(cf, plan, h2, i, radius))
     return tuple(out)
+
+
+HIGHEST, DEFAULT = jax.lax.Precision.HIGHEST, jax.lax.Precision.DEFAULT
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+def _assert_same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape, (a.dtype, b.dtype)
+    bits = np.uint16 if a.dtype.itemsize == 2 else np.uint32
+    np.testing.assert_array_equal(a.view(bits), b.view(bits))
+
+
+def _assert_rounded_once(written_bf16, written_f32):
+    """The launch written in bfloat16 against the float32 launch rounded.
+    The kernel rounds the float32 blend once as it stores it, and on the
+    chip the two are equal bit for bit (``chip_smoke.py``'s kernels phase
+    asserts it at both served grids; Mosaic on a v5e has no fused
+    multiply-add to contract).  Here the two launches are two XLA-CPU
+    programs in interpret mode, and the CPU backend contracts the blend's
+    multiplies and adds into FMAs in one and not in the other: the float32
+    value moves by a rounding of one product, and where it then falls on
+    the other side of a bfloat16 rounding tie (or, where the blend's terms
+    cancel, is itself that small) the two round apart.  So: equal, but for
+    at most 1e-4 of the values, and those are a correct rounding of a
+    float32 value within four float32 ulp (of the largest window value) of
+    the float32 launch's.  (Until PR 32 the last float32 operation before
+    the rounding was a plain add of two windows, which nothing contracts,
+    and the pin was exact here too.)"""
+    got = np.asarray(written_bf16)
+    f32 = np.asarray(written_f32, np.float32)
+    want = np.asarray(jnp.asarray(f32).astype(BF16))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    apart = got.view(np.uint16) != want.view(np.uint16)
+    assert apart.mean() <= 1e-4, (int(apart.sum()), apart.size)
+    if apart.any():
+        half_ulp = np.abs(f32[apart]) * 2.0 ** -8     # bfloat16's, at most
+        room = 4 * np.spacing(np.abs(f32).max())
+        err = np.abs(got[apart].astype(np.float32) - f32[apart])
+        assert (err <= half_ulp + room).all(), (err.max(), room)
 
 
 def _random_case(key, B, H, W, C, dtype=jnp.float32, coord_span=None):
@@ -62,22 +104,148 @@ def test_matches_dense_oracle(B, H, W, C, levels, radius):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("B,H,W,C,levels,radius", [
-    (1, 16, 24, 32, 4, 4),
-    (2, 12, 16, 16, 3, 3),
-])
-def test_vpu_lookup_style_matches_dense_oracle(B, H, W, C, levels, radius):
-    """The broadcast-multiply-reduce lookup formulation (lookup_style='vpu',
-    the MXU-sliver-free variant for TPU) must match the dense oracle too."""
-    from raft_tpu.ops.corr_pallas import _fused_lookup_impl
+# ------------------------------------ select exactly, blend once (PR 32)
+#
+# A visited key row-block hands over the (n+1) x (n+1) integer taps of each
+# window, gathered along the lanes of the float32 correlation tile: each tap
+# IS the float32 correlation sum.  The bilinear blend runs once a query
+# tile, on its last grid step.
 
-    fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(3), B, H, W, C)
-    want = lookup_dense(build_pyramid(fmap1, fmap2, levels), coords, radius)
-    f2_levels = tuple(fmap2_pyramid(fmap2, levels))
-    got = _fused_lookup_impl(fmap1, f2_levels, coords, radius,
-                             lookup_style="vpu")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+def _one_level(fmap1, fmap2, coords, radius, *, q_blk=64, p_blk_target=256,
+               scheduled=False, out_dtype=jnp.float32):
+    """One launch of level 0 and the plan it ran under."""
+    from raft_tpu.kernel_plans import corr_level_plan
+    from raft_tpu.ops.corr_pallas import _lookup_level, level_schedule
+
+    B, H, W, C = fmap1.shape
+    cf = coords.reshape(B, H * W, 2)
+    plan = corr_level_plan(H * W, H, W, q_blk=q_blk,
+                           p_blk_target=p_blk_target)
+    sched = level_schedule(cf, plan, H, 0, radius) if scheduled else None
+    got = _lookup_level(fmap1.reshape(B, H * W, C), fmap2, cf, radius, 0,
+                        q_blk=q_blk, p_blk_target=p_blk_target,
+                        interpret=True, schedule=sched, out_dtype=out_dtype)
+    return got, plan
+
+
+@pytest.mark.parametrize("scheduled", [False, True],
+                         ids=["all-blocks", "scheduled"])
+@pytest.mark.parametrize("radius", [3, 4])
+def test_integer_coordinates_give_the_correlation_sums_bit_for_bit(
+        radius, scheduled):
+    """At ``fx = fy = 0`` the blend multiplies each tap by 1 and its
+    neighbours by 0: the float32 output is the float32 correlation volume's
+    own values (the tile's dot at HIGHEST, scaled), bit for bit — the
+    selection added nothing and rounded nothing — with zeros where a window
+    leaves the map, over several key row-blocks (windows that straddle two
+    and three packed tiles of eight rows among them)."""
+    B, H, W, C = 1, 40, 20, 16
+    # fixed-point maps (ten bits): every product and every partial sum of
+    # a correlation is exact in float32 whatever the order, so the oracle
+    # below is the kernel's tile bit for bit — and a sum carries up to 22
+    # significant bits: a selection that rounded to bfloat16 anywhere, or
+    # kept two bfloat16 terms of three, would show
+    k1, k2 = jax.random.split(jax.random.PRNGKey(60 + radius))
+    i1 = np.asarray(jax.random.randint(k1, (B, H, W, C), -511, 512))
+    i2 = np.asarray(jax.random.randint(k2, (B, H, W, C), -511, 512))
+    fmap1 = jnp.asarray(i1 / 512.0, F32)
+    fmap2 = jnp.asarray(i2 / 512.0, F32)
+    xs = jnp.linspace(-6, W + 6, W).round()
+    ys = jnp.linspace(-6, H + 6, H).round()
+    coords = jnp.stack(jnp.meshgrid(xs, ys, indexing="xy"), -1)[None]
+    got, plan = _one_level(fmap1, fmap2, coords, radius, p_blk_target=2048,
+                           scheduled=scheduled)
+    assert plan.h2_blk == 16 and plan.n_pblocks == 3
+    # the volume in integers, then the scale (a power of two at C = 16)
+    exact = i1.reshape(H * W, C).astype(np.int64) @ i2.reshape(H * W, C).T
+    assert np.abs(exact).max() < 2 ** 24
+    vol = (exact.astype(np.float32) * np.float32(2.0 ** -18 / C ** 0.5)
+           ).reshape(H * W, H, W)
+    lo_plane = vol - vol.astype(BF16).astype(np.float32)
+    lo_plane -= lo_plane.astype(BF16).astype(np.float32)
+    assert (lo_plane != 0).mean() > 0.25      # the last eight bits matter
+    n = 2 * radius + 1
+    cx = np.asarray(coords[0, ..., 0], np.int64).ravel() - radius
+    cy = np.asarray(coords[0, ..., 1], np.int64).ravel() - radius
+    want = np.zeros((H * W, n, n), np.float32)        # x-offset-major
+    for i in range(n):
+        for j in range(n):
+            x, y = cx + i, cy + j
+            ok = (x >= 0) & (x < W) & (y >= 0) & (y < H)
+            q = np.nonzero(ok)[0]
+            want[q, i, j] = vol[q, y[q], x[q]]
+    assert np.count_nonzero(want) > want.size // 4
+    assert (want == 0).any()
+    _assert_same_bits(np.asarray(got)[0], want.reshape(H * W, n * n))
+
+
+@pytest.mark.parametrize("radius", [3, 4])
+def test_subpixel_windows_within_two_ulp_of_the_gather_lookup(radius):
+    """Random subpixel coordinates, windows straddling two key row-blocks
+    and the map's edge: against ``lookup_dense`` (the gather form) on the
+    SAME float32 volume the kernel's tiles hold, the output is within 2
+    float32 ulp of max |corr| — the blend is the only rounding."""
+    B, H, W, C = 1, 16, 24, 32
+    fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(70 + radius),
+                                        B, H, W, C, coord_span=1.1 * W)
+    got, plan = _one_level(fmap1, fmap2, coords, radius, scheduled=True)
+    assert plan.n_pblocks >= 8 and plan.h2_blk == 2   # every window straddles
+    cy = np.asarray(coords[0, ..., 1])
+    assert (cy < radius).any() and (cy > H - radius).any()    # map's edges
+    vol = dense_corr(fmap1, fmap2, precision=HIGHEST)
+    want = np.asarray(lookup_dense([vol], coords, radius))
+    ulp = np.spacing(np.float32(np.abs(np.asarray(vol)).max()))
+    err = np.abs(np.asarray(got).reshape(want.shape) - want).max()
+    assert err <= 2 * ulp, (err, ulp)
+
+
+def test_tiles_whose_windows_lie_off_the_map_write_zeros():
+    """Two whole query tiles far outside the map, one above and one below
+    it: their schedule parks on a block whose one-hots match nothing, the
+    tap scratch is written with exact zeros, and zeros come out — also
+    when the tile before left sums in the scratch."""
+    B, H, W, C, radius = 1, 16, 8, 16, 4
+    fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(80), B, H, W, C,
+                                        coord_span=0.8 * H)
+    off = jnp.zeros((H, W), bool).at[0:4].set(True).at[8:12].set(True)
+    far = jnp.where(jnp.arange(H)[:, None] < 6, -40.0, 90.0)
+    coords = coords.at[0, ..., 1].set(
+        jnp.where(off, far, coords[0, ..., 1]))
+    for scheduled in (False, True):
+        got, plan = _one_level(fmap1, fmap2, coords, radius, q_blk=32,
+                               p_blk_target=256, scheduled=scheduled)
+        assert plan.t == 32 and plan.n_pblocks > 1
+        got = np.asarray(got).reshape(H, W, -1)
+        assert not got[np.asarray(off)].any()
+        assert np.abs(got[~np.asarray(off)]).max() > 0.1
+        _assert_same_bits(got[np.asarray(off)],
+                          np.zeros_like(got[np.asarray(off)]))
+
+
+@pytest.mark.parametrize("name,H,W,C,radius,p_blk", [
+    ("256-lanes", 6, 240, 16, 4, 1024),   # W2 = 240: rows of 256 lanes
+    ("raft-small", 12, 40, 128, 3, 512),  # radius 3 (n + 1 = 8), C = 128
+])
+def test_new_body_by_value(name, H, W, C, radius, p_blk):
+    """A level whose rows take two lane tiles (1080p's level 0: 240 -> 256)
+    and RAFT-S's shape (a 7x7 window, 128 channels), scheduled, against the
+    dense oracle, in both output dtypes."""
+    B = 1
+    fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(90), B, H, W, C,
+                                        dtype=BF16, coord_span=1.1 * W)
+    coords = coords.at[..., 1].multiply(H / W)
+    got, plan = _one_level(fmap1, fmap2, coords, radius, q_blk=128,
+                           p_blk_target=p_blk, scheduled=True)
+    assert plan.w2p == (256 if W == 240 else 128) and plan.n_pblocks > 1
+    want = np.asarray(lookup_dense(
+        [dense_corr(fmap1.astype(F32), fmap2.astype(F32),
+                    precision=HIGHEST)], coords, radius))
+    assert np.abs(want).max() > 0.5
+    np.testing.assert_allclose(np.asarray(got).reshape(want.shape), want,
+                               rtol=0, atol=4e-7 * np.abs(want).max())
+    out, _ = _one_level(fmap1, fmap2, coords, radius, q_blk=128,
+                        p_blk_target=p_blk, scheduled=True, out_dtype=BF16)
+    _assert_rounded_once(out, got)
 
 
 def test_integer_coords_and_oob_zeros_padding():
@@ -288,10 +456,6 @@ def test_window_schedule_invariants():
 
 # --- MXU passes from the operands' dtypes (corr_terms) ----------------------
 
-HIGHEST, DEFAULT = jax.lax.Precision.HIGHEST, jax.lax.Precision.DEFAULT
-BF16, F32 = jnp.bfloat16, jnp.float32
-
-
 @pytest.mark.parametrize("f1,f2,precision,terms,passes", [
     (BF16, BF16, HIGHEST, (1, 1), 1),    # level 0 of a bfloat16 encoder
     (BF16, F32, HIGHEST, (1, 3), 3),     # its pooled levels
@@ -310,13 +474,6 @@ def test_corr_terms_truth_table(f1, f2, precision, terms, passes):
     exact = precision == HIGHEST and f1 == BF16
     assert planes.dtype == (BF16 if exact else F32)
     assert planes.shape == ((terms[1] if exact else 1), 1, 2, 2, 8)
-
-
-def _assert_same_bits(a, b):
-    a, b = np.asarray(a), np.asarray(b)
-    assert a.dtype == b.dtype and a.shape == b.shape, (a.dtype, b.dtype)
-    bits = np.uint16 if a.dtype.itemsize == 2 else np.uint32
-    np.testing.assert_array_equal(a.view(bits), b.view(bits))
 
 
 def _bf16_case(key, B, H, W, C, levels):
@@ -391,8 +548,8 @@ def test_bf16_maps_equal_the_float32_highest_program(kernel, level, grid):
             out_dtype=out)
     assert f2l.dtype == (BF16 if level == 0 else F32)
     got = fn(f1, f2l, HIGHEST)
-    # written in bfloat16, the launch gives those float32 sums rounded once
-    _assert_same_bits(fn(f1, f2l, HIGHEST, BF16), got.astype(BF16))
+    # written in bfloat16, the launch gives that float32 blend rounded once
+    _assert_rounded_once(fn(f1, f2l, HIGHEST, BF16), got)
     got = np.asarray(got)
     want = np.asarray(fn(f1.astype(F32), f2l.astype(F32), HIGHEST))
     assert np.abs(want).max() > 0.1
@@ -400,15 +557,19 @@ def test_bf16_maps_equal_the_float32_highest_program(kernel, level, grid):
                                atol=1e-6 * np.abs(want).max())
     # and the exact form is what ran: bfloat16 operands, 1 or 3 one-pass dots
     text = str(jax.make_jaxpr(lambda a, b: fn(a, b, HIGHEST))(f1, f2l))
-    n_dots = text.count("dot_general") - 2               # less a_y, a_x
+    # (the selection that follows them moves lanes and multiplies nothing:
+    # until PR 32 two more dots, a_y and a_x, interpolated every block)
+    n_dots = text.count("dot_general")
     assert n_dots == (1 if level == 0 else 3), text
     assert f"bf16[{1 if level == 0 else 3},1," in text
 
 
 def test_float32_maps_keep_the_six_pass_program():
     """float32 maps that are NOT bfloat16 values: one float32 plane, one
-    correlation dot at HIGHEST (the MXU's own six passes), nothing bfloat16
-    anywhere in the program, the dense oracle's values."""
+    correlation dot at HIGHEST (the MXU's own six passes) on float32
+    operands and the only dot of the program (the window's taps are
+    gathered, not multiplied), nothing bfloat16 anywhere in it, the dense
+    oracle's values."""
     from raft_tpu.ops.corr_pallas import _lookup_level, f2_terms
 
     B, H, W, C, radius = 1, 12, 16, 16, 3
@@ -422,8 +583,10 @@ def test_float32_maps_keep_the_six_pass_program():
         q_blk=64, p_blk_target=256, interpret=True)
     text = str(jax.make_jaxpr(fn)(fmap1, fmap2))
     assert "bf16" not in text
-    assert text.count("dot_general") == 3                # corr, a_y, a_x
-    assert text.count("Precision.HIGHEST") >= 3
+    assert text.count("dot_general") == 1        # the correlation's, alone
+    assert re.search(r"f32\[64,256\] = dot_general\[\s*dimension_numbers="
+                     r"\(\(\[1\], \[1\]\), \(\[\], \[\]\)\)\s*precision="
+                     r"\(Precision\.HIGHEST, Precision\.HIGHEST\)", text)
     want = lookup_dense(build_pyramid(fmap1, fmap2, 1), coords, radius)
     np.testing.assert_allclose(
         np.asarray(fn(fmap1, fmap2)).reshape(want.shape), np.asarray(want),
@@ -523,7 +686,7 @@ def test_float32_result_is_the_parents_and_bfloat16_its_rounding(name,
     got = run(F32)
     assert got.dtype == F32
     assert got.shape == (B, H, W, levels * (2 * radius + 1) ** 2)
-    _assert_same_bits(run(BF16), got.astype(BF16))
+    _assert_rounded_once(run(BF16), got)
     flat = np.asarray(got).ravel()
     assert flat.size == size and np.count_nonzero(flat) == nonzero
     np.testing.assert_allclose(np.abs(flat.astype(np.float64)).sum(),
@@ -569,7 +732,7 @@ def test_out_dtype_where_queries_do_not_fill_the_tiles(grid, level):
     got = run(F32)
     assert got.shape == (B, H * W, (2 * radius + 1) ** 2)
     assert np.abs(np.asarray(got)).max() > 0.1
-    _assert_same_bits(run(BF16), got.astype(BF16))
+    _assert_rounded_once(run(BF16), got)
     if H * W * h2 * w2 > 5e7:   # a dense volume of a gigabyte and more:
         return                  # the other cases hold the values
     want = lookup_dense(
@@ -591,17 +754,24 @@ def test_closures_hand_over_the_dtype_they_were_built_for():
                              out_dtype=BF16)
     got = f32(coords)
     assert got.dtype == F32
-    _assert_same_bits(bf16(coords, bf16.schedules(coords)), got.astype(BF16))
+    _assert_rounded_once(bf16(coords, bf16.schedules(coords)), got)
 
 
 @pytest.mark.parametrize("small", [False, True], ids=["things", "small"])
 def test_served_flow_is_what_the_converted_float32_lookup_gave(small,
                                                                monkeypatch):
     """The whole model in bfloat16 at 216x384, twelve iterations, pallas
-    lookup: with the kernels writing bfloat16 the flow equals, bit for bit,
-    the flow of the program that takes the kernels' float32 output and
-    converts it in ``gru_step`` (PR 29's parent: the factory forced back to
-    float32 here, the cast still in the model)."""
+    lookup: with the kernels writing bfloat16 the flow is the flow of the
+    program that takes the kernels' float32 output and converts it in
+    ``gru_step`` (PR 29's parent: the factory forced back to float32 here,
+    the cast still in the model).  On the chip the two launches are equal
+    bit for bit; here a few values in a million round apart
+    (:func:`_assert_rounded_once` has the reason), and twelve updates carry
+    such a value into every flow vector.  So the flow head is damped as the
+    benchmark damps it (``benchmark/weights.py``: updates of a fraction of
+    a pixel, the recurrence contractive, where untrained weights make two
+    chaotic orbits of any difference), and the flows agree to a bfloat16
+    ulp of their largest value."""
     from raft_tpu.config import RAFTConfig
     from raft_tpu.models import init_raft, raft_forward
     from raft_tpu.ops import corr_pallas
@@ -609,6 +779,8 @@ def test_served_flow_is_what_the_converted_float32_lookup_gave(small,
     make = RAFTConfig.small_model if small else RAFTConfig.full
     config = make(iters=12, compute_dtype="bfloat16", corr_impl="pallas")
     params = init_raft(jax.random.PRNGKey(0), config)
+    head = params["update_block"]["flow_head"]["conv2"]
+    head["w"], head["b"] = 0.005 * head["w"], 0.005 * head["b"]
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
     im1 = jax.random.uniform(k1, (1, 216, 384, 3))
     im2 = jax.random.uniform(k2, (1, 216, 384, 3))
@@ -623,5 +795,7 @@ def test_served_flow_is_what_the_converted_float32_lookup_gave(small,
     monkeypatch.setattr(corr_pallas, "make_fused_lookup", as_the_parent)
     converted = raft_forward(params, im1, im2, config)[0].flow
     assert seen == [jnp.bfloat16]          # the model states its compute dtype
-    assert float(jnp.abs(written).max()) > 1.0
-    _assert_same_bits(written, converted)
+    assert written.dtype == converted.dtype
+    largest = float(jnp.abs(converted).max())
+    assert largest > 1.0
+    assert float(jnp.abs(written - converted).max()) <= 2.0 ** -7 * largest

@@ -85,8 +85,7 @@ def dense_gather_flow():
     dict(corr_impl="dense", corr_lookup="onehot"),
     dict(corr_impl="blockwise", corr_lookup="gather"),
     dict(corr_impl="blockwise", corr_lookup="onehot"),
-    dict(corr_impl="pallas", pallas_lookup_style="matmul"),
-    dict(corr_impl="pallas", pallas_lookup_style="vpu"),
+    dict(corr_impl="pallas"),
 ], ids=lambda p: "-".join(p.values()))
 def test_corr_impls_agree(dense_gather_flow, path):
     """Every correlation path ``_iterate_flow`` has, by value, against the

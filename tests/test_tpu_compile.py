@@ -63,23 +63,24 @@ def _compile(fn, *specs):
 
 
 def _corr_specs(sd, level: int, batch: int = 1, f1=jnp.float32,
-                f2=jnp.float32, grid=(H, W)):
+                f2=jnp.float32, grid=(H, W), c=C):
     h, w = grid
     s = functools.partial(jax.ShapeDtypeStruct, sharding=sd)
-    return (s((batch, h * w, C), f1),
-            s((batch, h // 2 ** level, w // 2 ** level, C), f2),
+    return (s((batch, h * w, c), f1),
+            s((batch, h // 2 ** level, w // 2 ** level, c), f2),
             s((batch, h * w, 2), jnp.float32))
 
 
-def _scheduled_level(f1, f2_level, coords, *, level, p_blk_target, **kw):
+def _scheduled_level(f1, f2_level, coords, *, level, p_blk_target,
+                     radius=RADIUS, **kw):
     """``_lookup_level`` under the key-block schedule of its own coords."""
     h2, w2 = f2_level.shape[-3:-1]
     plan = corr_level_plan(f1.shape[1], h2, w2, q_blk=128,
                            p_blk_target=p_blk_target)
     return _lookup_level(
-        f1, f2_level, coords, RADIUS, level, q_blk=128,
+        f1, f2_level, coords, radius, level, q_blk=128,
         p_blk_target=p_blk_target, interpret=False,
-        schedule=level_schedule(coords, plan, h2, level, RADIUS), **kw)
+        schedule=level_schedule(coords, plan, h2, level, radius), **kw)
 
 
 BF16_L0 = dict(f1=jnp.bfloat16, f2=jnp.bfloat16)   # the encoder's own maps
@@ -93,10 +94,6 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
                           scheduled=True), {}),
     ("coarsest-level", 3, dict(corr_precision=P.HIGHEST,
                                p_blk_target=4096), {}),
-    # 19.18M of scoped VMEM: refused under the compiler's 16 MiB default,
-    # accepted under the limit the kernels request (kernel_plans.VMEM_BYTES)
-    ("vpu", 0, dict(corr_precision=P.DEFAULT, p_blk_target=4096,
-                    lookup_style="vpu"), {}),
     # bfloat16 maps at HIGHEST (ops/corr_pallas.corr_terms): a bfloat16 NT
     # matmul over one (16,128)-tiled f2 plane at level 0, three planes split
     # from a float32 pooled level at the others
@@ -150,32 +147,103 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
     ("crop-out-bf16-level1", 1, dict(corr_precision=P.HIGHEST,
                                      p_blk_target=4096, grid=CROP,
                                      out_dtype=jnp.bfloat16), {}),
+    # RAFT-S at 1080x1920 (PR 31's cell): a 7x7 window, so eight taps a side
+    # fill a float32 sublane tile exactly, and 128-channel maps
+    ("small-hd-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                                scheduled=True, grid=HD, radius=3, c=128,
+                                out_dtype=jnp.bfloat16), BF16_L0),
+    ("small-hd-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                                grid=HD, radius=3, c=128,
+                                out_dtype=jnp.bfloat16), BF16_X3),
 ])
 def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
     kw = dict(kw)
     grid = kw.pop("grid", (H, W))
     scheduled = kw.pop("scheduled", False)
+    radius, c = kw.pop("radius", RADIUS), kw.pop("c", C)
     if scheduled:
-        fn = functools.partial(_scheduled_level, level=level, **kw)
+        fn = functools.partial(_scheduled_level, level=level, radius=radius,
+                               **kw)
     else:
-        fn = functools.partial(_lookup_level, radius=RADIUS, level=level,
+        fn = functools.partial(_lookup_level, radius=radius, level=level,
                                q_blk=128, interpret=False, **kw)
     if grid != (H, W):  # the case is the program's: the rule gives the same
         plan = corr_level_plan(grid[0] * grid[1], grid[0] >> level,
                                grid[1] >> level, q_blk=128,
                                p_blk_target=4096)
         assert corr_level_scheduled(plan) == scheduled
-    text = _compile(fn, *_corr_specs(one_chip, level, grid=grid, **dtypes))
+    text = _compile(fn, *_corr_specs(one_chip, level, grid=grid, c=c,
+                                     **dtypes))
     assert "tpu_custom_call" in text
     # the launch itself returns the lane-dense window in the dtype asked for
     out = "bf16" if kw.get("out_dtype") == jnp.bfloat16 else "f32"
     qp = corr_level_plan(grid[0] * grid[1], grid[0] >> level,
                          grid[1] >> level, q_blk=128, p_blk_target=4096).qp
-    assert re.search(rf"= {out}\[1,{qp},81\]\S* custom-call\(", text), name
+    window = (2 * radius + 1) ** 2
+    assert re.search(rf"= {out}\[1,{qp},{window}\]\S* custom-call\(",
+                     text), name
     if dtypes:
         # the kernel was handed bfloat16 planes: nothing widened them first
         planes = 1 if dtypes is BF16_L0 else 3
         assert f"bf16[{planes},1," in text, name
+
+
+@pytest.mark.parametrize("name,model,bucket,level", [
+    ("sintel-level1", "things", (440, 1024), 1),
+    ("hd-level0", "things", (1080, 1920), 0),
+    ("hd-level1", "things", (1080, 1920), 1),
+    ("small-hd-level1", "small", (1080, 1920), 1),
+    ("crop-f32-level0", "things-f32", (368, 496), 0),
+])
+def test_corr_kernel_fits_the_envelope_the_analyzer_prices(
+        one_chip, monkeypatch, name, model, bucket, level):
+    """``lint/budget.corr_vmem_envelope`` is an upper envelope of what the
+    chip's compiler wants of scoped VMEM for a launch: handed the analyzer's
+    figure for the level as its limit, in place of the 32 MiB the kernels
+    ask for, the compiler accepts the launch.  (By bisection here, PR 32,
+    the least limit it accepts is 15.14 MiB where the analyzer prices 17.63,
+    12.93 for 15.13, 10.55 for 11.38.)  Half the figure is refused where
+    the three bfloat16 planes of a 4096 x 256 block alone take more: the
+    limit binds."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.lint import budget
+    from raft_tpu.ops import corr_pallas
+
+    config = {"things": RAFTConfig.full(corr_impl="pallas",
+                                        compute_dtype="bfloat16"),
+              "things-f32": RAFTConfig.full(corr_impl="pallas"),
+              "small": RAFTConfig.small_model(corr_impl="pallas",
+                                              compute_dtype="bfloat16"),
+              }[model]
+    priced = budget.corr_vmem_envelope(config, bucket)["levels"][level]
+    grid = (bucket[0] // 8, bucket[1] // 8)
+    maps = jnp.dtype(config.compute_dtype)
+    dtypes = dict(f1=maps, f2=maps if level == 0 else jnp.float32)
+    specs = _corr_specs(one_chip, level, grid=grid, c=config.fnet_dim,
+                        **dtypes)
+    kw = dict(level=level, p_blk_target=config.pallas_p_blk,
+              radius=config.corr_radius, corr_precision=P.HIGHEST,
+              out_dtype=maps)
+    if priced["plan"]["n_pblocks"] > 1:              # scheduled, as served
+        launch = _scheduled_level
+    else:
+        launch = functools.partial(_lookup_level, q_blk=128, interpret=False)
+
+    def compiles(limit: int) -> bool:
+        monkeypatch.setattr(corr_pallas, "_COMPILER_PARAMS",
+                            pltpu.CompilerParams(vmem_limit_bytes=limit))
+        try:        # a fresh function: the limit is read as the call traces
+            _compile(lambda *a: launch(*a, **kw), *specs)
+        except Exception as e:  # noqa: BLE001 — the compiler's refusal
+            assert "vmem" in str(e).lower(), e
+            return False
+        return True
+
+    assert compiles(priced["block_bytes"]), (name, priced["block_bytes"])
+    if name == "hd-level1":
+        assert not compiles(priced["block_bytes"] // 2)
 
 
 @pytest.mark.parametrize("level,dtypes,out_dtype", [

@@ -120,8 +120,6 @@ def main() -> int:
                    choices=["highest", "default"],
                    help="corr-matmul precision to tune for ('default' = bf16 "
                         "MXU inputs)")
-    p.add_argument("--style", default="matmul", choices=["matmul", "vpu"],
-                   help="window-lookup formulation inside the kernel")
     args = p.parse_args()
 
     from raft_tpu.compile_cache import configure_compile_cache
@@ -143,7 +141,7 @@ def main() -> int:
     prec = (jax.lax.Precision.HIGHEST if args.precision == "highest"
             else jax.lax.Precision.DEFAULT)
     print(f"# device: {dev.device_kind}  corr precision: {args.precision}  "
-          f"lookup style: {args.style}  key-block schedule: by the "
+          f"key-block schedule: by the "
           f"kernel's rule (fine p_blk targets get it)")
 
     # (label, B, full-res H, W); fmaps are at os=8, C=256 (full model)
@@ -169,8 +167,7 @@ def main() -> int:
         for q_blk, p_blk in itertools.product(q_blks, p_blks):
             fn = jax.jit(functools.partial(
                 _fused_lookup_impl, radius=args.radius, q_blk=q_blk,
-                p_blk_target=p_blk, interpret=False, corr_precision=prec,
-                lookup_style=args.style))
+                p_blk_target=p_blk, interpret=False, corr_precision=prec))
             try:
                 dt = _measure(fn, (fmap1, f2_levels, coords),
                               reps=8 if args.quick else 20)
