@@ -29,16 +29,18 @@ def readings(config: dict, seed: int, n_pairs: int, height: int, width: int,
              precision: str = "float8") -> list:
     import check
     import inputs
+    import run
     import weights as weights_mod
     mcfg = weights_mod.model_cfg(config)
     wts = weights_mod.make_weights(seed, mcfg)
     pairs = inputs.make_pairs(seed, n_pairs, height, width)
-    iters = int(config["iters"])
-    refs = check.reference_flows(wts, pairs, range(n_pairs), mcfg, iters)
-    own = check.reference_flows(wts, pairs, range(n_pairs), mcfg, iters,
-                                config["check"]["own_precision"])
-    low = check.reference_flows(wts, pairs, range(n_pairs), mcfg, iters,
-                                precision)
+    ref = run.load_named(BENCH_DIR, "references",
+                         config["check"].get("reference", "dense"),
+                         "the configuration's check.reference")
+    refs, own, low = (
+        check.reference_flows(wts, pairs, range(n_pairs), mcfg,
+                              int(config["iters"]), p, ref)
+        for p in ("float32", config["check"]["own_precision"], precision))
     out = []
     for i in range(n_pairs):
         stated = check.rel_epe(own[i], refs[i])

@@ -3,20 +3,24 @@
 
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Everything about a cell is data found by name: the cell, its configuration and
+Everything about a cell is found by name: the cell, its configuration and
 its traffic mix in ``BENCHMARK.json``; the configuration's sizes and serve
 arguments in its ``file``; the mix in ``traffic/<mix>.json``; the numbers that
 depend on both in ``workloads/<cell>.json``; each per-layer metric's reader in
 ``layer_metrics/<metric>.json`` (or ``.py``); the chip's peaks in
-``peaks.json``.  A later PR adds cells, configurations, mixes and metrics by
-adding files and appending entries, and edits nothing here.
+``peaks.json``; the mix's load driver (what is sent, in what order, and which
+input an answer belongs to) in ``drivers/<mix's "driver">.py``, absent:
+``pairs``; the configuration's plain reference in ``references/<its
+"check.reference">.py``, absent: ``dense``.  A later PR adds cells,
+configurations, mixes, metrics, drivers and references by adding files and
+appending entries, and edits nothing here.
 
-A run: make frames and weights from the seed, start the real server in this
+A run: make inputs and weights from the seed, start the real server in this
 process (system.py) and warm it (all of that is ``setup_s``, but for the
-seconds JAX takes to bring the device up), offer the mix's load over HTTP for
-``--seconds``, read the device's peak memory, stop the
-server, hold a seeded sample of the window's answers against the plain
-reference (check.py), and print one JSON object as the last line of stdout.
+seconds JAX takes to bring the device up), have the driver offer the mix's
+load for ``--seconds``, read the device's peak memory, stop the server, hold
+a seeded sample of the window's answers against the reference as the driver
+walks it (check.py), and print one JSON object as the last line of stdout.
 With ``--trace 0`` its metrics are the cell's end-to-end metrics; with
 ``--trace 1`` the last few seconds of the window are captured with the JAX
 profiler and its metrics are the cell's per-layer metrics.
@@ -31,8 +35,10 @@ import time
 T_START = time.monotonic()          # process start, for setup_s
 
 import argparse
+import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import threading
@@ -62,6 +68,21 @@ def find(entries: list, name: str, what: str) -> dict:
         if e["name"] == name:
             return e
     raise SystemExit(f"BENCHMARK.json has no {what} named {name!r}")
+
+
+def load_named(bench_dir: str, folder: str, name: str, key: str):
+    """The module ``<folder>/<name>.py`` of the benchmark, by the ``name``
+    that a data file gives under ``key``: a load driver, a reference."""
+    path = os.path.join(bench_dir, folder, name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"benchmark: {key} names {name!r}, and there is no "
+                         f"{os.path.join(folder, name + '.py')}")
+    spec = importlib.util.spec_from_file_location(
+        f"{folder}_{re.sub(r'[^0-9a-zA-Z_]', '_', name)}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def listed(metric: dict, cell: str, reporting: set) -> bool:
@@ -138,11 +159,16 @@ def main(argv=None, bench_dir: str = BENCH_DIR, manifest: str = None,
 
     import check
     import costs
-    import inputs
     import loadgen
     import readers
     import system
     import weights as weights_mod
+
+    driver = load_named(bench_dir, "drivers", traffic.get("driver", "pairs"),
+                        f"traffic mix {cell_entry['traffic']!r}'s driver")
+    ref_mod = load_named(
+        bench_dir, "references", config["check"].get("reference", "dense"),
+        f"configuration {cfg_entry['name']!r}'s check.reference")
 
     peaks = load_json(os.path.join(bench_dir, "peaks.json"))["chips"]
     kind = devices[0].device_kind
@@ -151,18 +177,11 @@ def main(argv=None, bench_dir: str = BENCH_DIR, manifest: str = None,
               file=sys.stderr)
         return 3
 
-    # ---- set-up: frames (host thread), weights and server (this thread)
-    h, w = int(traffic["height"]), int(traffic["width"])
-    made = {}
-
-    def make_inputs():
-        pairs = inputs.make_pairs(args.seed, int(traffic["distinct_pairs"]),
-                                  h, w, int(traffic.get("max_shift", 6)))
-        made["pairs"] = pairs
-        made["bodies"] = [inputs.npz_body(image1=a, image2=b)
-                          for a, b in pairs]
-
-    t_in = threading.Thread(target=make_inputs, name="make-inputs")
+    # ---- set-up: inputs (host thread), weights and server (this thread)
+    box = {}
+    t_in = threading.Thread(
+        target=lambda: box.update(made=driver.make_inputs(args.seed, traffic)),
+        name="make-inputs")
     t_in.start()
     t_dev = time.monotonic() - T_START
     mcfg = weights_mod.model_cfg(config)
@@ -171,12 +190,8 @@ def main(argv=None, bench_dir: str = BENCH_DIR, manifest: str = None,
     sut = system.start(config, wts, cache_dir, cfg_entry["name"])
     t_srv = time.monotonic() - T_START
     t_in.join()
-    pairs, bodies = made["pairs"], made["bodies"]
-    path = traffic["endpoint"]
-    loop = traffic["loop"]
-    clients = int(cell.get("clients", 2 * sut.max_batch))
-    loadgen.run_closed(sut.host, sut.port, path, bodies, args.seed, clients,
-                       WARM_SECONDS, keep=())
+    made = box["made"]
+    driver.warm_up(sut, made, args.seed, traffic, cell, WARM_SECONDS)
     t_req = time.monotonic() - T_START
     # the warm-up's last answers come after two device batches or three,
     # as its first requests happened to fall into batches (6.2 or 7.3 s, half
@@ -210,28 +225,12 @@ def main(argv=None, bench_dir: str = BENCH_DIR, manifest: str = None,
     setup_s = time.monotonic() - T_START - device_up_s
     if tracer:
         tracer.start()
-    if loop == "closed":
-        # past the ramp-up's part batches, and early enough that a window a
-        # third as long or a server a third as fast still answers them (at
-        # 8 batches' reach a 12 s window kept nothing: my chip run, PR 23)
-        keep = loadgen.sample_ordinals(args.seed, n_keep, clients,
-                                       clients + 3 * sut.max_batch)
-        records, t0, t1 = loadgen.run_closed(
-            sut.host, sut.port, path, bodies, args.seed, clients,
-            args.seconds, keep)
-    elif loop == "open":
-        due = loadgen.open_schedule(args.seed, float(cell["rate_per_s"]),
-                                    args.seconds, traffic)
-        keep = loadgen.sample_ordinals(args.seed, n_keep, 0, len(due))
-        records, t0, t1 = loadgen.run_open(
-            sut.host, sut.port, path, bodies, args.seed, due, args.seconds,
-            int(cell.get("workers", 32)), keep)
-    else:
-        raise SystemExit(f"traffic loop {loop!r}")
+    window = driver.run_window(sut, made, args.seed, traffic, cell,
+                               args.seconds, n_keep)
     if tracer:
         tracer.join()
     prom = system.diff_prom(prom0, sut.scrape())
-    summary = loadgen.summarize(records, t0, t1, loop)
+    records, summary = window.records, driver.summarize(window)
     # the allocator's peak does not count what the runtime reserves for a
     # program's temporaries (0.74 GB here against 0.2 GB of buffers; looked
     # at on the chip, PR 23): the chip's fullest moment holds both
@@ -251,27 +250,28 @@ def main(argv=None, bench_dir: str = BENCH_DIR, manifest: str = None,
 
     # ---- the output check, outside the window and outside set-up
     t_chk = time.monotonic()
-    kept = set(keep)
-    answers = [(r.ordinal, r.body,
-                inputs.npz_load(r.payload)["flow"] if r.payload else None)
-               for r in records if r.ordinal in kept]
+    answers = driver.kept_answers(window)
     which, iters = [a[1] for a in answers], int(config["iters"])
-    refs = check.reference_flows(wts, pairs, which, mcfg, iters)
-    own = check.reference_flows(wts, pairs, which, mcfg, iters,
-                                config["check"]["own_precision"])
+    refs = driver.reference_answers(
+        check.forward(ref_mod, wts, mcfg, iters), made, which)
+    own = driver.reference_answers(
+        check.forward(ref_mod, wts, mcfg, iters,
+                      config["check"]["own_precision"]), made, which)
     verdict = check.compare(answers, refs, own,
                             float(config["check"]["ratio_limit"]), log)
     misses = sum(v for k, v in prom.items() if k.split("{", 1)[0]
                  == "raft_serving_compile_cache_misses_total")
     log(f"check: compile misses in the window {misses:g} limit 0 "
         f"{'ok' if misses == 0 else 'OVER'}")
-    correct = bool(verdict["correct"] and misses == 0)
+    checks = dict(verdict["checks"], compile_misses={
+        "value": misses, "limit": 0, "ok": misses == 0})
+    correct = all(c["ok"] for c in checks.values())
     log(f"check: took {time.monotonic() - t_chk:.1f}s (not part of setup_s)")
 
     # ---- metrics
-    e2e_values = {"setup_s": setup_s,
-                  "pairs_per_s": summary["pairs_per_s"],
-                  "latency_p50_ms": summary.get("latency_p50_ms")}
+    # an end-to-end metric is the set-up time or a number of the driver's
+    # summary under the metric's own name
+    e2e_values = dict(summary, setup_s=setup_s)
     reporting = {m["name"] for m in bench["end_to_end"]
                  if listed(m, args.workload, set())}
     device = {"platform": devices[0].platform, "kind": kind,
@@ -286,6 +286,7 @@ def main(argv=None, bench_dir: str = BENCH_DIR, manifest: str = None,
     else:
         import tracered
         trace = tracered.reduce_trace(tracered.find_xplane(trace_dir))
+        h, w = int(traffic["height"]), int(traffic["width"])
         log(f"trace: {json.dumps(tbox)} window_s {trace.window_s:.3f} clipped "
             f"{trace.clipped} devices {trace.n_devices} whole program runs "
             f"{trace.module_runs()}")
@@ -305,6 +306,8 @@ def main(argv=None, bench_dir: str = BENCH_DIR, manifest: str = None,
         device["window_s"] = trace.window_s
         result["breakdown"] = {"device_ops": trace.top_ops(10),
                                "idle_gaps": trace.top_gaps(10)}
+    result["checks"] = checks
+    check.report(checks)
     print(json.dumps(result), flush=True)
     return 0
 
