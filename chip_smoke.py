@@ -58,11 +58,14 @@ class Sizes:
     # serve: the Sintel pair as committed, padded by the server's own
     # padder (data.pipeline.pad_to_shape) into the one declared bucket
     bucket: tuple = (440, 1024)
-    # the kernels' query grids: the bucket / 8 (Sintel: level 0 is two key
-    # row-blocks, the others one), and 1080x1920 / 8, whose levels 0 to 2
-    # run under the key-block schedule (nine, three and two blocks) and
-    # whose GRU rows are 244 stored columns wide (a VMEM limit of its own)
-    kernel_hws: tuple = ((55, 128), (135, 240))
+    # the kernels' query grids, with the lookup's channels and radius: the
+    # bucket / 8 (Sintel: level 0 is banded, the others one block each), and
+    # 1080x1920 / 8, whose levels 0 to 2 are banded (each query tile fetches
+    # a band of 16 key rows) and whose GRU rows are 244 stored columns wide
+    # (a VMEM limit of its own), for raft-things and for RAFT-S's lookup
+    # (the three configurations BENCHMARK.json serves)
+    kernel_cases: tuple = ((55, 128, 256, 4), (135, 240, 256, 4),
+                           (135, 240, 128, 3))
     # train: the chairs recipe's crop and global batch (config.py
     # TrainConfig.for_stage("chairs")).  One micro-batch of 10 does not fit
     # a 16 GB chip, so the fit knob is --accum, chosen from the chip
@@ -280,8 +283,9 @@ GRU_F32_TOL = 5e-3
 
 
 def phase_kernels(meter, sz: Sizes) -> None:
-    """Both Pallas kernels at every grid of ``sz.kernel_hws`` (Sintel's
-    and 1080p's: a changed kernel meets both shapes), compiled by Mosaic
+    """Both Pallas kernels at every case of ``sz.kernel_cases`` (Sintel's
+    grid and 1080p's, raft-things' lookup and RAFT-S's: a changed kernel
+    meets every served shape), compiled by Mosaic
     (``interpret=False`` / ``impl='kernel'``), executed on the chip and
     compared with their XLA oracles: the corr kernel at HIGHEST precision
     against ``lookup_dense`` at HIGHEST, 1e-4 (both exact f32, only the
@@ -299,8 +303,9 @@ def phase_kernels(meter, sz: Sizes) -> None:
     from raft_tpu.models.update import init_sep_conv_gru, precompute_gru_ctx
     from raft_tpu.ops.coords import coords_grid
     from raft_tpu.ops.corr import build_pyramid, fmap2_pyramid, lookup_dense
-    from raft_tpu.ops.corr_pallas import (_fused_lookup_impl, level_shapes,
-                                          lookup_schedules)
+    from raft_tpu.ops.corr_pallas import (_fused_lookup_impl, level_plans,
+                                          level_shapes, lookup_schedules,
+                                          schedule_keyblocks)
     from raft_tpu.ops.gru_pallas import sep_conv_gru_pallas, sep_conv_gru_xla
 
     def run(fn, *args):
@@ -312,8 +317,8 @@ def phase_kernels(meter, sz: Sizes) -> None:
                   "no tpu_custom_call in the lowered kernel program")
         return np.asarray(lowered.compile()(*args), np.float32)
 
-    def one_grid(h: int, w: int) -> dict:
-        C, levels, radius = 256, 4, 4
+    def one_grid(h: int, w: int, C: int, radius: int) -> dict:
+        levels = 4
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
         f1 = jax.random.normal(k1, (1, h, w, C), jnp.float32)
         f2 = jax.random.normal(k2, (1, h, w, C), jnp.float32)
@@ -327,8 +332,9 @@ def phase_kernels(meter, sz: Sizes) -> None:
             coords, radius))
         f2_levels = tuple(fmap2_pyramid(f2, levels))
         errs = {}
-        # the launches as the kernel's own rule schedules them at this grid
-        # and at finer blocks (every level scheduled), and every block walked
+        # the launches as the kernel's own plan bands them at this grid and
+        # at finer blocks (more levels banded, shorter bands), and every
+        # row-block walked (the parent's values, bit for bit: PRs 26-36)
         got = {}
         for name, p_blk, sched in (("rule", 4096, None),
                                    ("rule-fine", 1024, None),
@@ -344,12 +350,16 @@ def phase_kernels(meter, sz: Sizes) -> None:
         errs["corr/rule_vs_all"] = float(np.abs(got["rule"]
                                                 - got["all"]).max())
         check(errs["corr/rule_vs_all"] == 0.0,
-              f"the scheduled launches at {h}x{w} differ from the all-blocks "
-              f"ones by {errs['corr/rule_vs_all']:.3e}: a skipped block adds "
-              f"exact zeros")
-        sched = lookup_schedules(coords, level_shapes(f2_levels), radius)
+              f"the banded launches at {h}x{w} differ from the all-rows "
+              f"ones by {errs['corr/rule_vs_all']:.3e}: every tap lies in one "
+              f"band, rows left out add exact zeros")
+        shapes = level_shapes(f2_levels)
+        sched = lookup_schedules(coords, shapes, radius)
         errs["corr/scheduled_levels"] = [i for i, s in enumerate(sched)
                                          if s is not None]
+        visited, _, tiles = (int(v) for v in schedule_keyblocks(
+            sched, 1, level_plans(h * w, w, shapes, radius)))
+        errs["corr/bands_per_tile"] = round(visited / tiles, 4)
         # the window as the update block consumes it: float32 maps as above,
         # and bfloat16 maps over a float32-pooled pyramid as the served
         # program hands them over (level 0 one plane, the others three)
@@ -372,6 +382,8 @@ def phase_kernels(meter, sz: Sizes) -> None:
                   f"{errs[key]} values of the lookup written in bfloat16 at "
                   f"{h}x{w} ({name}) are not the float32 output rounded")
 
+        if radius != 4:       # the GRU kernel is raft-things' alone
+            return errs
         hid = mdim = ctxd = 128                    # full-model channel plan
         ks = jax.random.split(jax.random.PRNGKey(1), 4)
         p_gru = init_sep_conv_gru(ks[0], hid, ctxd + mdim)
@@ -398,8 +410,8 @@ def phase_kernels(meter, sz: Sizes) -> None:
         return errs
 
     with Phase(meter, "kernels") as ph:
-        ph.note(max_abs_err={f"{h}x{w}": one_grid(h, w)
-                             for h, w in sz.kernel_hws})
+        ph.note(max_abs_err={f"{h}x{w},C{c},r{r}": one_grid(h, w, c, r)
+                             for h, w, c, r in sz.kernel_cases})
 
 
 # ------------------------------------------------------------------- serve
@@ -685,12 +697,13 @@ def phase_small(meter, sz: Sizes) -> None:
                 dtype=config.compute_dtype,
                 run_seconds=round(time.monotonic() - t0, 3))
         flow = np.asarray(flow, np.float32)
-        visited, possible = (int(v) for v in np.asarray(keyblocks))
+        visited, possible, tiles = (int(v) for v in np.asarray(keyblocks))
         finite = bool(np.isfinite(flow).all())
         check(flow.shape == (b, h, w, 2) and finite,
               f"small flow: shape {flow.shape}, finite {finite}")
-        check(0 < visited <= possible,
-              f"key-block counts at radius 3: {visited} of {possible}")
+        check(0 < tiles <= visited <= possible,
+              f"band counts at radius 3: {visited} of {possible} steps, "
+              f"{tiles} (tile, level) pairs")
         row = b - 1                       # the batch's last row
         refs = bcheck.reference_flows(params, pairs, [row], mcfg, args.iters)
         own = bcheck.reference_flows(params, pairs, [row], mcfg, args.iters,
@@ -700,7 +713,8 @@ def phase_small(meter, sz: Sizes) -> None:
         verdict = bcheck.compare([(row, row, flow[row])], refs, own, limit,
                                  lines.append)
         ph.note(precision_ratio=round(verdict["worst"] or 0.0, 4),
-                limit=limit, keyblock_share=round(visited / possible, 4))
+                limit=limit, keyblock_share=round(visited / possible, 4),
+                bands_per_tile=round(visited / tiles, 4))
         check(verdict["correct"], "small program against "
               "benchmark/reference.py: " + "; ".join(lines))
 

@@ -49,6 +49,8 @@ def _apply_pads(image: np.ndarray, ph: int, pw: int,
     else:
         pads = (ph, 0, 0, pw)
     t, b, l, r = pads
+    if not (ph or pw):
+        return image, pads      # (np.pad copies even where it adds nothing)
     width = [(0, 0)] * (image.ndim - 3) + [(t, b), (l, r), (0, 0)]
     return np.pad(image, width, mode="edge"), pads
 

@@ -56,27 +56,99 @@ class CorrLevelPlan:
     t: int              # queries per program ([T, C] f1 block)
     qp: int             # padded query count (multiple of t)
     w2p: int            # stored row width, lane-padded (multiple of LANE)
-    h2_blk: int         # map rows per f2 block
+    # the map cut on fixed boundaries: the whole map where it fits one step
+    # (``n_pblocks`` 1), the all-rows walk, the ragged launch's pages
+    h2_blk: int         # map rows per f2 row-block
     rows: int           # map rows before padding
     rows_padded: int    # map rows after padding (multiple of h2_blk)
-    n_pblocks: int      # f2 row-block count (the k grid dimension)
+    n_pblocks: int      # f2 row-block count (the all-rows k grid dimension)
+    # the band a query tile fetches where the map is more than one
+    # row-block (all 0 where it is one): ``band_rows`` consecutive map rows
+    # from a multiple of ``band_granule`` (:func:`corr_band`)
+    band_granule: int = 0       # g: rows of one fetched block of the band
+    band_rows: int = 0          # R: a multiple of g, R * w2p <= p_blk_target
+    n_bands: int = 0            # K: the banded k grid dimension
+    band_rows_padded: int = 0   # map rows + the zero rows the last band reads
+
+    @property
+    def banded(self) -> bool:
+        """THE rule for the lookup's band schedule, read from the level's
+        shape alone: a level whose map does not fit one step's positions
+        (more than one row-block of ``p_blk_target``) is visited through a
+        per-tile schedule of the bands its windows touch; a level that does
+        is one whole-map block and pays for no schedule.  At the default
+        4096 positions that is levels 0-2 of 1080x1920's grid and level 0
+        of 440x1024's, for either model (TUNING.md, PR 36)."""
+        return self.n_bands > 0
+
+    @property
+    def band_granules(self) -> int:
+        """Granule blocks a band is fetched as (``R / g``)."""
+        return self.band_rows // self.band_granule if self.banded else 0
+
+
+#: Rows a band starts on a multiple of, and is fetched in blocks of.  Fitted
+#: on the v5e at both models' shapes (TUNING.md, PR 36): a finer granule
+#: wastes fewer rows on the start's rounding but hands the launch more
+#: blocks (each costs about 0.05 us a grid step whether it moves or not:
+#: g = 2 lost 9-25 % to g = 4 at the same rows), a coarser one (8) needs 24
+#: rows where 16 do.
+CORR_BAND_GRANULE_ROWS = 4
+
+#: Rows of flow a band allows for beyond what a tile's windows span at rest
+#: (its queries moving apart by that many map rows of the level), before the
+#: granule rounds the band up.  A tile that spans more takes a second band:
+#: what a straddled row-block boundary used to cost every third tile.
+CORR_BAND_FLOW_ROWS = 2
+
+
+def corr_band(t: int, w2: int, w2p: int, cap_rows: int, radius: int,
+              grid_w: int):
+    """``(g, R)`` of a banded level: the granule a band starts on a multiple
+    of and the rows of a band, from shapes alone.  A tile's ``t`` queries
+    are consecutive in raster order over a grid ``grid_w`` wide, so they lie
+    in ``1 + (t - 2) // grid_w`` query rows more than one; at a level
+    ``2^l`` coarser (``grid_w // w2``, floored to a power of two) that is
+    ``ceil(. / 2^l)`` map rows, a window is ``2r + 2`` rows, and a band
+    that starts on a multiple of ``g`` at or under the first row needs
+    ``g - 1`` more: 16 rows at either model's radius at 1080x1920 and
+    440x1024.  Both are capped by the positions of one step (``cap_rows =
+    p_blk_target // w2p``)."""
+    scale = 1 << ((grid_w // w2).bit_length() - 1)
+    span = -(-(1 + max(t - 2, 0) // grid_w) // scale)
+    g = min(CORR_BAND_GRANULE_ROWS, cap_rows)
+    need = 2 * radius + 2 + span + CORR_BAND_FLOW_ROWS + g - 1
+    return g, max(g, min(round_up(need, g), cap_rows // g * g))
 
 
 def corr_level_plan(q: int, h2: int, w2: int, *, q_blk: int,
-                    p_blk_target: int) -> CorrLevelPlan:
+                    p_blk_target: int, radius: int,
+                    grid_w: int) -> CorrLevelPlan:
     """The fused correlation kernel's block plan for one pyramid level —
-    the exact padding/blocking arithmetic ``_lookup_level`` executes."""
+    the exact padding/blocking arithmetic ``_lookup_level`` executes.
+    ``q`` queries of a grid ``grid_w`` wide look up windows of ``radius`` in
+    a map of ``h2`` x ``w2``; ``p_blk_target`` caps the key positions of one
+    grid step.  This is the only place that decides whether a level is
+    banded, and its ``R`` and ``g``."""
     if h2 <= 0 or w2 <= 0:
         raise ValueError(f"degenerate level {h2}x{w2}: the kernel "
                          f"short-circuits these to zeros before planning")
     t = q_blk if q >= q_blk else round_up(q, SUBLANE)
     qp = round_up(q, t)
     w2p = round_up(w2, LANE)
-    h2_blk = max(1, min(h2, p_blk_target // w2p))
+    cap_rows = max(1, p_blk_target // w2p)
+    h2_blk = min(h2, cap_rows)
     rows_padded = round_up(h2, h2_blk)
-    return CorrLevelPlan(t=t, qp=qp, w2p=w2p, h2_blk=h2_blk, rows=h2,
+    plan = CorrLevelPlan(t=t, qp=qp, w2p=w2p, h2_blk=h2_blk, rows=h2,
                          rows_padded=rows_padded,
                          n_pblocks=rows_padded // h2_blk)
+    if plan.n_pblocks == 1:
+        return plan
+    g, band = corr_band(t, w2, w2p, cap_rows, radius, grid_w)
+    # a band starts at or under the map's last row, on a multiple of g
+    return dataclasses.replace(
+        plan, band_granule=g, band_rows=band, n_bands=-(-h2 // band),
+        band_rows_padded=(h2 - 1) // g * g + band)
 
 
 #: Lanes a window row takes where the lookup kernel holds a query's taps: a
@@ -106,21 +178,6 @@ def corr_window_vmem(plan: CorrLevelPlan, n: int, out_itemsize: int) -> int:
     scratch = plan.t * corr_tap_tiles(n) * LANE * 4
     out_block = plan.t * round_up(n * n, LANE) * out_itemsize
     return scratch + 2 * out_block
-
-
-def corr_level_scheduled(plan: CorrLevelPlan) -> bool:
-    """THE rule for the lookup's key-block schedule, read from the level's
-    plan alone: a level whose map is cut into more than one row-block is
-    visited through a per-tile schedule of the blocks its windows touch; a
-    level of one block has nothing to leave out and pays for no schedule.
-    A (2r+2)-row window band lies in one or two blocks of a plan's 8 to 32
-    rows, so even at two blocks a tile leaves one out more often than not:
-    on the v5e one launch at batch 32 of 440x1024's level 0 (two blocks;
-    tiles visit 61 % of them) fell from 25.9 to 19.6 ms, and at 1080x1920
-    (batch 8) level 0 (nine blocks, 19.5 %) from 213 to 56, level 1 (three)
-    from 63 to 38, level 2 (two) from 46 to 31 (TUNING.md, PR 26).  No
-    level with more than one block lost."""
-    return plan.n_pblocks > 1
 
 
 @dataclasses.dataclass(frozen=True)

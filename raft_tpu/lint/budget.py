@@ -108,12 +108,17 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
                            "degenerate": True})
             continue
         plan = corr_level_plan(q, h2, w2, q_blk=config.pallas_q_blk,
-                               p_blk_target=config.pallas_p_blk)
-        pblk = plan.h2_blk * plan.w2p
+                               p_blk_target=config.pallas_p_blk,
+                               radius=config.corr_radius, grid_w=w0)
+        # the key rows of one grid step of the plan that runs: a band's R
+        # rows (its R/g granule blocks, each double-buffered: what R rows
+        # in one block take) or the one whole-map block
+        step_rows = plan.band_rows if plan.banded else plan.h2_blk
+        pblk = step_rows * plan.w2p
         # An upper envelope of the least scoped limit the chip's compiler
         # accepts for a launch (v5e, jax 0.9.0, found by bisection in the
-        # sandbox, PR 32): 15.14 MiB at 1080x1920's levels 1 and 2 (three
-        # bfloat16 planes of 4096 x 256, priced 17.4), 12.93 at 440x1024's
+        # sandbox, PR 32, for blocks of 4096 positions): 15.14 MiB at three
+        # bfloat16 planes of 4096 x 256 (priced 17.4), 12.93 at 440x1024's
         # level 1 (priced 15.1), 10.55 for RAFT-S's 128-channel planes.
         # The pipeline double-buffers every grid-indexed block; the body
         # keeps the MXU product and the term being added to it, a packed
@@ -125,12 +130,12 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
                 (1, h2, w2, c), map_dtype if level == 0 else "float32"))
         n_terms, map_bytes = planes.shape[0], planes.dtype.itemsize
         map_blocks = map_bytes * (plan.t * c             # f1 block
-                                  + n_terms * pblk * c)  # f2 row block
+                                  + n_terms * pblk * c)  # f2 key rows
         f32_blocks = plan.t * 2              # coords block
         window = corr_window_vmem(      # scratch + double-buffered output
             plan, n, 2 if config.compute_dtype == "bfloat16" else 4)
         tap_tiles = corr_tap_tiles(n)
-        lane_tiles = (-(-plan.h2_blk * TAP_LANES // LANE)   # packed rows
+        lane_tiles = (-(-step_rows * TAP_LANES // LANE)     # packed rows
                       + 2 * tap_tiles + 1    # taps and their candidates
                       + 12)                  # index and mask planes
         floats = (2 * f32_blocks             # double-buffered pipeline

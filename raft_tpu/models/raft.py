@@ -53,10 +53,11 @@ class RAFTOutput(NamedTuple):
     # repeat sample b's frozen flow — never stale intermediates — so the
     # sequence loss and --dump-flow stay correct.
     iters_used: Optional[jax.Array] = None
-    # int32 [visited, possible]: over every iteration run, the (query tile,
-    # key row-block) steps the fused correlation kernel did work in and the
-    # steps of walking every block (ops/corr_pallas.schedule_keyblocks) —
-    # None off the dense Pallas lookup.
+    # int32 [visited, possible, tiles]: over every iteration run, the (query
+    # tile, band of key rows) grid steps the fused correlation kernel did
+    # work in, its grid steps, and the (query tile, level) pairs it looked
+    # up (ops/corr_pallas.schedule_keyblocks) — None off the dense Pallas
+    # lookup.
     corr_keyblocks: Optional[jax.Array] = None
 
 
@@ -391,13 +392,13 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                                          config.hidden_dim,
                                          small=config.small)
 
-    kb0 = jnp.zeros((2,), jnp.int32)
+    kb0 = jnp.zeros((3,), jnp.int32)
 
     def gru_step(net, coords1):
         """One GRU update — shared by every loop form below.  Returns the
         updated (net, coords1, mask), the per-sample mean L2 norm of the
         flow update at the 1/8 grid (the converge-policy criterion) and the
-        lookup's key-block counts (zeros where it has none)."""
+        lookup's band counts (zeros where it has none)."""
         coords1 = jax.lax.stop_gradient(coords1)   # reference RAFT.py:93 / official
         kb = kb0
         with stage("raft/corr_lookup"):
@@ -734,7 +735,7 @@ def make_inference_fn(config: RAFTConfig, iters: Optional[int] = None,
                       keyblocks: bool = False):
     """A jittable (params, image1, image2) -> final flow function; with
     ``keyblocks`` -> (flow, ``RAFTOutput.corr_keyblocks``), for the serving
-    engine's key-block counters (dense Pallas lookup only)."""
+    engine's band counters (dense Pallas lookup only)."""
     def fn(params, image1, image2):
         out, _ = raft_forward(params, image1, image2, config, iters=iters,
                               train=False, all_flows=False)

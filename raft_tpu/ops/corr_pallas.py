@@ -9,10 +9,23 @@ full (HW)^2 volume in device memory (reference networks/model_utils.py:206-215,
 Design (flash-attention-style: the matmul on the MXU, the window on the
 vector and cross-lane units):
 
-* Grid ``(B, Q-blocks, P-blocks)``. Each program computes one correlation tile
-  ``f1_block @ f2_block^T`` on the MXU — at any instant only a ``[T, Pblk]``
-  tile lives in VMEM: 128 queries in the sublanes, a key row-block's
-  positions in the lanes.
+* Grid ``(B, Q-blocks, key steps)``. Each program computes one correlation
+  tile ``f1_block @ f2_rows^T`` on the MXU — at any instant only a ``[T,
+  rows x lanes]`` tile lives in VMEM: 128 queries in the sublanes, the
+  positions of some consecutive key rows in the lanes.
+* WHICH rows is the query tile's own choice wherever the map is more than
+  one step's positions (``kernel_plans.corr_level_plan``): a tile's 128
+  queries are consecutive in raster order, so their windows touch a band of
+  ``2r + 2`` rows and a few more, and the launch's schedule names the first
+  row of that band (a multiple of the granule ``g`` under the tile's first
+  window row).  A grid step is handed the ``R`` rows from there as ``R / g``
+  blocks of ``g`` rows — the same planes passed ``R / g`` times under plain
+  blocked indexing, block ``S + i`` — so tiles that name the same band (the
+  next tile of a query row, the static grid's repeated entries) refetch
+  nothing and a tile multiplies and selects over the rows it needs, not over
+  one or two fixed row-blocks of 32 (PRs 26-35; TUNING.md, PR 36).  A tile
+  whose windows span more than ``R`` rows takes further bands, disjoint and
+  in order, up to the whole map: exact for any flow.
 * A query's (2r+1)^2 bilinear window needs the (2r+2)^2 integer taps around
   it.  A visited block SELECTS the taps that lie in it, exactly
   (:func:`_window_taps`): each map row of the tile is gathered along its
@@ -20,8 +33,8 @@ vector and cross-lane units):
   rotation in every sublane), eight rows are packed into one 128-lane tile,
   and a second gather picks the query's own rows.  Lanes move; nothing is
   multiplied, so a tap is the float32 correlation sum bit for bit, a tap in
-  another block or off the map is an exact zero (zeros padding for free),
-  and windows that straddle a block boundary add up across the k grid
+  other rows or off the map is an exact zero (zeros padding for free),
+  and windows that reach past a step's rows add up across the k grid
   dimension in a float32 VMEM scratch of ``[T, 256]``.
 * The bilinear BLEND runs once a query tile, on its last grid step
   (:func:`_write_windows`): four products and three adds a value in float32
@@ -51,7 +64,7 @@ lo, split once where the pyramid is built), so bfloat16 maps cost one MXU
 pass at level 0 and three at the pooled levels; float32 maps take the MXU's
 own six-pass ``HIGHEST`` matmul.  Same products, same float32 sums — the
 passes left out multiplied zeros.  Selection is exact, scaling, the sums over
-key row-blocks and the blend are float32 throughout; a launch
+key steps and the blend are float32 throughout; a launch
 writes its windows once, rounded to the dtype its consumer states
 (``out_dtype``) and lane-dense (``[B, Q, n*n]``): a ``[.., 9, 9]`` float32
 array pads each query's 324 B to 8 KiB of HBM tiles, and converting and
@@ -62,6 +75,7 @@ identical code.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Sequence, Tuple
 
@@ -72,7 +86,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..kernel_plans import (LANE, TAP_LANES, VMEM_BYTES, corr_level_plan,
-                            corr_level_scheduled, corr_tap_tiles)
+                            corr_tap_tiles)
 from ..lint.contracts import contract
 from ..telemetry.trace import stage
 # corr_terms and its two readers live in ops/corr.py, which imports no
@@ -177,15 +191,17 @@ def _take(x: jax.Array, lanes: jax.Array) -> jax.Array:
     return jnp.take_along_axis(x, lanes, axis=1, mode="promise_in_bounds")
 
 
-def _window_taps(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
-                 corr_scale: float, radius: int, h2_blk: int, w2: int,
-                 corr_precision):
-    """Shared program body, the part every visited key row-block runs: the
-    corr tile against f2 row-block ``sel``, and from it the ``(n+1) x
-    (n+1)`` integer taps of each query's window that lie in this block,
+def _window_taps(first_row, f1_ref, coords_ref, f2_refs, *,
+                 level_scale: float, corr_scale: float, radius: int,
+                 w2: int, corr_precision):
+    """Shared program body, the part every visited step runs: the corr tile
+    against the key rows the step was handed — consecutive map rows from
+    ``first_row``, in one block or in the granule blocks ``f2_refs`` of a
+    band, each ``[terms, 1, rows * w2, C]`` — and from it the ``(n+1) x
+    (n+1)`` integer taps of each query's window that lie in these rows,
     SELECTED, not interpolated: ``[T, tiles * 128]`` float32, window row
     ``j`` and column ``i`` at lane ``j * 16 + i``, zero where a tap lies in
-    another block or outside the map.  Each tap is the float32 correlation
+    other rows or outside the map.  Each tap is the float32 correlation
     sum itself, bit for bit: lanes are moved, nothing is multiplied.
 
     Queries lie in the sublanes and a block's positions in the lanes, so a
@@ -201,10 +217,11 @@ def _window_taps(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
       tiles; they are chosen per query and gathered once more, which rotates
       the groups so that window row ``j`` lands in group ``j % 8``.
 
-    The cost goes with the rows of the block, and no bilinear weight enters
+    The cost goes with the rows of the step, and no bilinear weight enters
     here (TUNING.md has the us a step)."""
     T = f1_ref.shape[1]
-    corr = _corr_tile(f1_ref, f2_ref, corr_precision)           # [T, Pblk]
+    corrs = [_corr_tile(f1_ref, ref, corr_precision) for ref in f2_refs]
+    rows_each = f2_refs[0].shape[2] // w2       # [T, rows_each * w2] each
     (ix0, iy0), _ = _window_origin(coords_ref, level_scale, radius)
     grp, col = _tap_lanes(T)
     zeros = jnp.zeros((T, LANE), jnp.float32)
@@ -212,17 +229,17 @@ def _window_taps(sel, f1_ref, coords_ref, f2_ref, *, level_scale: float,
     x = ix0 + col                               # the map column a lane wants
     src, chunk_of = x & (LANE - 1), x >> _LANE_SHIFT
     packed = []
-    for y in range(h2_blk):
-        row = None
+    for y in range(rows_each * len(f2_refs)):
+        corr, row = corrs[y // rows_each], None
         for c in range(w2 // LANE):
-            at = y * w2 + c * LANE
+            at = y % rows_each * w2 + c * LANE
             got = _take(corr[:, at:at + LANE], src)
             row = got if row is None else jnp.where(chunk_of == c, got, row)
         if y % _ROWS_A_TILE == 0:
             packed.append(zeros)
         packed[-1] = jnp.where(grp == y % _ROWS_A_TILE, row, packed[-1])
 
-    r0 = iy0 - sel * h2_blk                     # first window row, in block rows
+    r0 = iy0 - first_row                # first window row, in the step's rows
     first_tile = r0 >> _ROW_SHIFT
 
     def tile_of(which):     # the packed tile each query wants, zeros past the block
@@ -335,29 +352,30 @@ def _level_body(C: int, level: int, radius: int, plan, corr_precision):
     body with the level's constants bound."""
     scale = dict(level_scale=1.0 / (2.0 ** level), radius=radius)
     return (functools.partial(_window_taps, corr_scale=1.0 / (C ** 0.5),
-                              h2_blk=plan.h2_blk, w2=plan.w2p,
-                              corr_precision=corr_precision, **scale),
+                              w2=plan.w2p, corr_precision=corr_precision,
+                              **scale),
             functools.partial(_write_windows, **scale))
 
 
 def _level_kernel(f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *, taps,
-                  write):
+                  write, h2_blk):
     """One (batch, query-block, p-block) program: the k-th grid step visits
     f2 row-block k (full pass over the map)."""
     k = pl.program_id(2)
     _accumulate(acc_ref, k, k == pl.num_programs(2) - 1, None,
-                lambda: taps(k, f1_ref, coords_ref, f2_ref),
+                lambda: taps(k * h2_blk, f1_ref, coords_ref, (f2_ref,)),
                 lambda: write(coords_ref, acc_ref, out_ref))
 
 
-def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *,
-                   taps, write):
-    """Window-scheduled program: identical math to ``_level_kernel`` but the
-    k-th grid step visits f2 row-block ``S[b, j*K + k]`` instead of row-block
-    ``k``.  The schedule repeats its last needed block to fill the static
-    grid; a repeated index means the pipeline skips the DMA refetch and this
-    body skips the compute, so only row-blocks actually overlapped by the
-    query block's bilinear windows do work."""
+def _band_kernel(S_ref, f1_ref, coords_ref, *refs, taps, write, granule):
+    """Band-scheduled program: identical math to ``_level_kernel`` but the
+    k-th grid step of a query tile visits the band of key rows that starts
+    at row ``S[b, j*K + k] * granule``, handed over as its granule blocks
+    ``refs[:-2]`` (then the output and the scratch).  The schedule repeats
+    its last needed band to fill the static grid; a repeated index means
+    the pipeline skips the DMA refetch and this body skips the compute, so
+    only bands the tile's bilinear windows overlap do work."""
+    *f2_refs, out_ref, acc_ref = refs
     b = pl.program_id(0)
     k = pl.program_id(2)
     at = pl.program_id(1) * pl.num_programs(2) + k
@@ -365,30 +383,37 @@ def _window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *,
     prev = S_ref[b, at - jnp.minimum(k, 1)]      # step 0 has no previous
     _accumulate(acc_ref, k, k == pl.num_programs(2) - 1,
                 (k == 0) | (sel != prev),
-                lambda: taps(sel, f1_ref, coords_ref, f2_ref),
+                lambda: taps(sel * granule, f1_ref, coords_ref, f2_refs),
                 lambda: write(coords_ref, acc_ref, out_ref))
 
 
-def _window_schedule(coords: jax.Array, level_scale: float, radius: int,
-                     T: int, h2_blk: int, H2: int, K: int) -> jax.Array:
-    """Per (batch, query-block) contiguous range of f2 row-blocks its bilinear
-    windows can touch, as a [B, Qb, K] block-index schedule.  Entries past
-    the needed range repeat the last needed block (skip marker).  Fully
-    out-of-map windows select nothing (no position matches their taps), so
-    pointing them at block 0 is safe."""
+def _window_rows(coords: jax.Array, level_scale: float, radius: int, T: int):
+    """Per (batch, query tile) the first and the last (inclusive) map row its
+    windows touch at a level: int32 ``[B, Qp/T]`` each, not clipped."""
     B, Qp, _ = coords.shape
-    n = 2 * radius + 1
     cy = coords[..., 1] * level_scale                     # [B, Qp]
-    iy0 = jnp.floor(cy).astype(jnp.int32) - radius
-    iyb = iy0.reshape(B, Qp // T, T)
-    lo = iyb.min(axis=2)
-    hi = iyb.max(axis=2) + n                              # inclusive last row
+    iy0 = (jnp.floor(cy).astype(jnp.int32) - radius).reshape(B, Qp // T, T)
+    return iy0.min(axis=2), iy0.max(axis=2) + 2 * radius + 1
+
+
+def _band_schedule(coords: jax.Array, level_scale: float, radius: int,
+                   T: int, plan) -> jax.Array:
+    """Per (batch, query tile) the bands of key rows its bilinear windows
+    touch, as a ``[B, Qb, K]`` schedule of band STARTS in granules: the
+    first band starts on the granule of the tile's first window row, each
+    further one ``R`` rows on (disjoint, in order), as many as reach its
+    last window row.  Entries past the needed range repeat the last needed
+    band (skip marker).  Windows wholly outside the map select nothing (no
+    row matches their taps), so pointing them at row 0 is safe."""
+    H2, g, n_g = plan.rows, plan.band_granule, plan.band_granules
+    lo, hi = _window_rows(coords, level_scale, radius, T)
     any_rows = (hi >= 0) & (lo < H2)
-    b_lo = jnp.where(any_rows, jnp.clip(lo, 0, H2 - 1) // h2_blk, 0)
-    b_hi = jnp.where(any_rows, jnp.clip(hi, 0, H2 - 1) // h2_blk, 0)
-    ks = jnp.arange(K, dtype=jnp.int32)[None, None, :]
-    return (b_lo[..., None]
-            + jnp.minimum(ks, (b_hi - b_lo)[..., None])).astype(jnp.int32)
+    s_lo = jnp.where(any_rows, jnp.clip(lo, 0, H2 - 1) // g, 0)
+    s_hi = jnp.where(any_rows, jnp.clip(hi, 0, H2 - 1) // g, 0)
+    more = (s_hi - s_lo) // n_g                 # bands after the first
+    ks = jnp.arange(plan.n_bands, dtype=jnp.int32)[None, None, :]
+    return (s_lo[..., None]
+            + n_g * jnp.minimum(ks, more[..., None])).astype(jnp.int32)
 
 
 def _pad_queries(plan, f1: Optional[jax.Array], coords: jax.Array):
@@ -404,82 +429,113 @@ def _pad_queries(plan, f1: Optional[jax.Array], coords: jax.Array):
     return f1, coords.astype(jnp.float32)
 
 
-def level_schedule(coords: jax.Array, plan, H2: int, level: int,
+def level_schedule(coords: jax.Array, plan, level: int,
                    radius: int) -> jax.Array:
-    """The ``[B, Qp/T, n_pblocks]`` key-block schedule of one level for
+    """The ``[B, Qp/T, n_bands]`` band schedule of one banded level for
     ``coords`` [B, Q, 2], as :func:`_lookup_level` takes it."""
+    if not plan.banded:
+        raise ValueError(f"level {level}: a map of {plan.rows} rows is one "
+                         f"block of this plan and takes no schedule")
     _, coords = _pad_queries(plan, None, coords)
-    return _window_schedule(coords, 1.0 / (2.0 ** level), radius, plan.t,
-                            plan.h2_blk, H2, plan.n_pblocks)
+    return _band_schedule(coords, 1.0 / (2.0 ** level), radius, plan.t, plan)
 
 
 def level_shapes(f2_levels: Sequence[jax.Array]):
-    """``[(H2, W2), ...]`` of f2 levels given as maps ``[B,H2,W2,C]`` or as
-    term planes ``[n,B,H2,W2,C]``."""
+    """``[(H2, W2), ...]`` of f2 levels given as maps ``[B,H2,W2,C]``."""
     return [tuple(lvl.shape[-3:-1]) for lvl in f2_levels]
 
 
-def _level_plans(Q: int, shapes, q_blk: int, p_blk_target: int):
-    """One block plan per pyramid level; None where the map is pooled away
-    to nothing (the kernel short-circuits those to zeros)."""
-    return [corr_level_plan(Q, h2, w2, q_blk=q_blk, p_blk_target=p_blk_target)
-            if h2 > 0 and w2 > 0 else None for h2, w2 in shapes]
+def level_plans(queries: int, grid_w: int, shapes, radius: int,
+                q_blk: int = 128, p_blk_target: int = 4096) -> Tuple:
+    """One block plan per pyramid level for ``queries`` queries of a grid
+    ``grid_w`` wide; None where the map is pooled away to nothing (the
+    kernel short-circuits those to zeros)."""
+    return tuple(
+        corr_level_plan(queries, h2, w2, q_blk=q_blk,
+                        p_blk_target=p_blk_target, radius=radius,
+                        grid_w=grid_w)
+        if h2 > 0 and w2 > 0 else None for h2, w2 in shapes)
 
 
 def lookup_schedules(coords: jax.Array, shapes, radius: int,
                      q_blk: int = 128, p_blk_target: int = 4096) -> Tuple:
-    """Per level, the key-block schedule its launch runs under, or None
-    where it walks every block: coords [B, H, W, 2], ``shapes`` the
-    ``(H2, W2)`` of each f2 level (:func:`level_shapes`).  Which levels get one is decided here
-    and nowhere else, from each level's block plan
-    (``kernel_plans.corr_level_scheduled``): no flag selects it."""
+    """Per level, the band schedule its launch runs under, or None where
+    the map is one block: coords [B, H, W, 2], ``shapes`` the ``(H2, W2)``
+    of each f2 level (:func:`level_shapes`).  Which levels get one is
+    decided by each level's block plan (``CorrLevelPlan.banded``) and
+    nowhere else: no flag selects it."""
     B, H, W, _ = coords.shape
     cf = coords.reshape(B, H * W, 2)
-    plans = _level_plans(H * W, shapes, q_blk, p_blk_target)
     return tuple(
-        level_schedule(cf, plan, h2, i, radius)
-        if plan is not None and corr_level_scheduled(plan) else None
-        for i, (plan, (h2, _)) in enumerate(zip(plans, shapes)))
+        level_schedule(cf, plan, i, radius)
+        if plan is not None and plan.banded else None
+        for i, plan in enumerate(level_plans(H * W, W, shapes, radius, q_blk,
+                                             p_blk_target)))
 
 
-def schedule_keyblocks(schedules, batch: int, queries: int, shapes,
-                       q_blk: int = 128,
-                       p_blk_target: int = 4096) -> jax.Array:
-    """int32 ``[visited, possible]``: the (query tile, key row-block) steps
-    one lookup of ``batch`` x ``queries`` does work in, and the steps of
-    walking every block.  A scheduled level's count is reduced from the
-    schedule its kernel is given (a tile's distinct blocks are its last
-    entry less its first, plus one: entries run up from the first block and
-    then repeat the last); an unscheduled level visits all it has."""
+def schedule_keyblocks(schedules, batch: int, plans) -> jax.Array:
+    """int32 ``[visited, possible, tiles]`` of one lookup of ``batch`` maps
+    under ``plans`` (:func:`level_plans`): the (query tile, band) grid steps
+    that did work, the grid steps, and the (query tile, level) pairs, so
+    that ``visited / tiles`` is the steps a tile took a level (1.0: every
+    tile's windows lay in one band).  A banded level's count is reduced
+    from the schedule its kernel is given (a tile's bands are its last
+    entry less its first, in bands, plus one: entries run up from the first
+    band and then repeat the last); a level walked without one visits all
+    the row-blocks it has, one where the map is one block."""
     visited = jnp.int32(0)
-    possible = 0
-    for plan, S in zip(_level_plans(queries, shapes, q_blk, p_blk_target),
-                       schedules):
+    possible = tiles = 0
+    for plan, S in zip(plans, schedules):
         if plan is None:
             continue
-        steps = batch * (plan.qp // plan.t) * plan.n_pblocks
-        possible += steps
-        visited = visited + (steps if S is None else
-                             jnp.sum(S[..., -1] - S[..., 0] + 1))
-    return jnp.stack([visited, jnp.int32(possible)])
+        level_tiles = batch * (plan.qp // plan.t)
+        tiles += level_tiles
+        if S is None:
+            possible += level_tiles * plan.n_pblocks
+            visited = visited + level_tiles * plan.n_pblocks
+        else:
+            possible += level_tiles * plan.n_bands
+            visited = visited + jnp.sum(
+                (S[..., -1] - S[..., 0]) // plan.band_granules + 1)
+    return jnp.stack([visited, jnp.int32(possible), jnp.int32(tiles)])
+
+
+def pad_planes(f2: jax.Array, plan) -> jax.Array:
+    """Term planes ``[n, B, H2, W2, C]`` of a level with the zero rows and
+    lanes its launch reads (``plan``): the lanes to whole vector registers,
+    the rows to what the last band (or row-block) reaches.  Zero keys
+    correlate to zero: identical to zeros padding at the map's edge.  A
+    caller that looks up many times does this once (:class:`FusedLookup`);
+    planes that already have the shape pass through."""
+    rows = plan.band_rows_padded if plan.banded else plan.rows_padded
+    H2, W2 = f2.shape[2:4]
+    if (H2, W2) == (rows, plan.w2p):
+        return f2
+    return jnp.pad(f2, ((0, 0), (0, 0), (0, rows - H2), (0, plan.w2p - W2),
+                        (0, 0)))
 
 
 def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
                   radius: int, level: int, *, q_blk: int,
-                  p_blk_target: int, interpret: bool,
+                  p_blk_target: int, interpret: bool, grid_w: int,
+                  shape: Optional[Tuple[int, int]] = None,
                   corr_precision=jax.lax.Precision.HIGHEST,
                   schedule: Optional[jax.Array] = None,
                   out_dtype=jnp.float32) -> jax.Array:
-    """f1 [B,Q,C], f2_level [B,H2,W2,C] (or its [n,B,H2,W2,C] term planes,
-    :func:`f2_terms`), coords [B,Q,2] -> [B,Q,(2r+1)^2] in ``out_dtype``:
-    the float32 sums, rounded once where the kernel writes them.
+    """f1 [B,Q,C] (queries of a grid ``grid_w`` wide, in raster order),
+    f2_level [B,H2,W2,C] (or its [n,B,H2,W2,C] term planes, :func:`f2_terms`,
+    padded by :func:`pad_planes` or not: ``shape`` is the map's own ``(H2,
+    W2)`` where they are), coords [B,Q,2] -> [B,Q,(2r+1)^2] in
+    ``out_dtype``: the float32 sums, rounded once where the kernel writes
+    them.
 
     ``schedule`` (:func:`level_schedule` of the same coords and plan): the
-    key row-blocks each query tile visits; None walks every block.  The
-    values are the same either way, bit for bit: a block left out added
-    exact zeros, and the visited ones keep their order."""
+    bands of key rows each query tile visits; None walks every row-block of
+    the map.  The values are the same either way, bit for bit: every tap
+    lies in one band or block, rows left out added exact zeros, and the
+    visited ones keep their order."""
     B, Q, C = f1.shape
-    H2, W2 = f2_level.shape[-3:-1]
+    H2, W2 = shape or f2_level.shape[-3:-1]
     n = 2 * radius + 1
     if H2 == 0 or W2 == 0:
         # degenerate pyramid level (map pooled away to nothing): every window
@@ -490,32 +546,25 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
 
     # All padding/blocking arithmetic is the plan's (kernel_plans.py); the
     # static VMEM budget analyzer prices the very plan this call executes.
-    plan = corr_level_plan(Q, H2, W2, q_blk=q_blk, p_blk_target=p_blk_target)
-    T, Qp = plan.t, plan.qp
+    plan = corr_level_plan(Q, H2, W2, q_blk=q_blk, p_blk_target=p_blk_target,
+                           radius=radius, grid_w=grid_w)
+    T, Qp, W2p = plan.t, plan.qp, plan.w2p
     f1, coords = _pad_queries(plan, f1, coords)
-
-    # pad W2 to lane width so the in-kernel [T, Pblk] -> [T, h2_blk, W2p]
-    # reshape is a supported Mosaic shape cast; padded zero columns
-    # correlate to zero, so any one-hot match on them contributes 0
-    # (= zeros padding) — and the vector unit would have padded the
-    # lanes anyway.
-    W2p, h2_blk = plan.w2p, plan.h2_blk
-    n_pblocks = plan.n_pblocks
-    H2p = plan.rows_padded
-    if H2p != H2 or W2p != W2:
-        # zero rows/cols correlate to zero -> identical to zeros padding
-        # at the image boundary.
-        f2 = jnp.pad(f2, ((0, 0), (0, 0), (0, H2p - H2), (0, W2p - W2),
-                          (0, 0)))
+    if schedule is None and plan.banded:
+        # the all-rows walk of a map of several row-blocks (what a banded
+        # launch is held against): fixed blocks, their own row padding
+        f2 = f2[:, :, :H2, :W2]
+        plan = dataclasses.replace(plan, n_bands=0)
+    # W2 is padded to the lane width (the vector unit would have padded the
+    # lanes anyway) and the rows to what the last step reads
+    f2 = pad_planes(f2, plan).reshape(n_terms, B, -1, C)
     taps, write = _level_body(C, level, radius, plan, corr_precision)
-    f2 = f2.reshape(n_terms, B, -1, C)
-
-    grid = (B, Qp // T, n_pblocks)
-    f2_block = (n_terms, 1, h2_blk * W2p, C)     # every term of one row-block
     out_shape = jax.ShapeDtypeStruct((B, Qp, n * n), out_dtype)
     acc = _sums_scratch(T, n)
 
     if schedule is not None:
+        K, g, n_g = plan.n_bands, plan.band_granule, plan.band_granules
+        grid = (B, Qp // T, K)
         if schedule.shape != grid:
             raise ValueError(f"level {level}: schedule {schedule.shape} is "
                              f"not this plan's grid {grid}")
@@ -523,32 +572,42 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
         # eight pairs' schedule of 254 tiles x 3 blocks took the whole 1 MiB
         # (the chip compiler's refusal at 1080x1920, batch 8: PR 26), as
         # [B, Qb*K] it takes 73 KB
-        K = n_pblocks
         S = schedule.reshape(B, -1)
+        # the band as its n_g granule blocks: the same planes handed over
+        # n_g times under plain blocked indexing, block S + i of g rows.
+        # Tiles that name the same band (the next tile of a query row, a
+        # repeated entry) refetch nothing.
+        def granule(i, b, j, k, S):
+            return 0, b, S[b, j * K + k] + i, 0
+
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, T, C), lambda b, j, k, S: (b, j, 0)),
                 pl.BlockSpec((1, T, 2), lambda b, j, k, S: (b, j, 0)),
-                pl.BlockSpec(f2_block,
-                             lambda b, j, k, S: (0, b, S[b, j * K + k], 0)),
+                *[pl.BlockSpec((n_terms, 1, g * W2p, C),
+                               functools.partial(granule, i))
+                  for i in range(n_g)],
             ],
             out_specs=pl.BlockSpec((1, T, n * n),
                                    lambda b, j, k, S: (b, j, 0)),
             scratch_shapes=acc,
         )
         out = pl.pallas_call(
-            functools.partial(_window_kernel, taps=taps, write=write),
+            functools.partial(_band_kernel, taps=taps, write=write,
+                              granule=g),
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,
             compiler_params=_COMPILER_PARAMS,
-        )(S, f1, coords, f2)
+        )(S, f1, coords, *[f2] * n_g)
     else:
+        f2_block = (n_terms, 1, plan.h2_blk * W2p, C)   # one row-block
         out = pl.pallas_call(
-            functools.partial(_level_kernel, taps=taps, write=write),
-            grid=grid,
+            functools.partial(_level_kernel, taps=taps, write=write,
+                              h2_blk=plan.h2_blk),
+            grid=(B, Qp // T, plan.n_pblocks),
             in_specs=[
                 pl.BlockSpec((1, T, C), lambda b, j, k: (b, j, 0)),
                 pl.BlockSpec((1, T, 2), lambda b, j, k: (b, j, 0)),
@@ -580,18 +639,24 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
                        interpret: Optional[bool] = None,
                        corr_precision=jax.lax.Precision.HIGHEST,
                        schedules: Optional[Tuple] = None,
-                       out_dtype=jnp.float32) -> jax.Array:
+                       out_dtype=jnp.float32,
+                       shapes: Optional[Sequence] = None) -> jax.Array:
+    """``f2_levels``: the maps, or their term planes, padded
+    (:func:`pad_planes`) or not; ``shapes`` the maps' own ``(H2, W2)`` where
+    the planes are padded."""
     B, H, W, C = fmap1.shape
     Q = H * W
     interp = _use_interpret() if interpret is None else interpret
+    if shapes is None:
+        shapes = level_shapes(f2_levels)
     if schedules is None:       # a caller that does not count key blocks
-        schedules = lookup_schedules(
-            coords, level_shapes(f2_levels), radius, q_blk=q_blk,
-            p_blk_target=p_blk_target)
+        schedules = lookup_schedules(coords, shapes, radius, q_blk=q_blk,
+                                     p_blk_target=p_blk_target)
     f1 = fmap1.reshape(B, Q, C)
     cf = coords.reshape(B, Q, 2)
     outs = []
-    for i, (f2l, sched) in enumerate(zip(f2_levels, schedules)):
+    for i, (f2l, shape, sched) in enumerate(zip(f2_levels, shapes,
+                                                schedules)):
         # a scope per pyramid level (.../raft/corr_lookup/l<i>/...): each
         # level is one kernel launch of its own size, and a trace reader can
         # then tell them apart through the engine's instruction -> stage
@@ -601,8 +666,8 @@ def _fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
         with stage(f"l{i}/corr_lookup"):
             outs.append(_lookup_level(
                 f1, f2l, cf, radius, i, q_blk=q_blk,
-                p_blk_target=p_blk_target, interpret=interp,
-                corr_precision=corr_precision, schedule=sched,
+                p_blk_target=p_blk_target, interpret=interp, grid_w=W,
+                shape=shape, corr_precision=corr_precision, schedule=sched,
                 out_dtype=out_dtype))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 
@@ -626,21 +691,23 @@ def fused_lookup(fmap1: jax.Array, f2_levels: Tuple[jax.Array, ...],
     the float32 array.  A caller that adds results up keeps the default.
 
     ``f2_planes`` (optional): ``f2_levels`` as the kernel multiplies them
-    (:func:`f2_terms` of each level), built once by a caller that looks up
-    many times (:func:`make_fused_lookup`).  The forward then reads only
-    these; ``f2_levels`` stay the differentiable maps the backward uses.
+    (:func:`f2_terms` of each level, padded by :func:`pad_planes`), built
+    once by a caller that looks up many times (:func:`make_fused_lookup`).
+    The forward then reads only these; ``f2_levels`` stay the differentiable
+    maps the backward uses, and give the maps' shapes.
 
     ``schedules`` (optional): :func:`lookup_schedules` of these coords, from
     a caller that also counts them (:class:`FusedLookup`); None computes the
-    same here.  Either way the rule of ``kernel_plans.corr_level_scheduled``
-    decides, per level, whether a launch walks every key row-block or only
-    those its tiles' windows touch.
+    same here.  Either way the level's plan decides
+    (``kernel_plans.CorrLevelPlan.banded``) whether a launch takes the map
+    as one block or fetches, for each query tile, the band of key rows its
+    windows touch.
     """
     return _fused_lookup_impl(
         fmap1, f2_levels if f2_planes is None else f2_planes, coords, radius,
         q_blk=q_blk, p_blk_target=p_blk_target,
         corr_precision=corr_precision, schedules=schedules,
-        out_dtype=out_dtype)
+        out_dtype=out_dtype, shapes=level_shapes(f2_levels))
 
 
 def _fused_lookup_fwd(fmap1, f2_levels, coords, radius, corr_precision,
@@ -685,9 +752,12 @@ fused_lookup.defvjp(_fused_lookup_fwd, _fused_lookup_bwd)
 class FusedLookup:
     """The per-iteration lookup of models/raft.py: ``lookup(coords)``.
 
-    Pools the fmap2 pyramid once (in float32) and splits it once into the
+    Pools the fmap2 pyramid once (in float32), splits it once into the
     planes the kernel multiplies (:func:`f2_terms`: level 0 is ``fmap2``
-    itself in the dtype it came in); each GRU iteration then runs the fused
+    itself in the dtype it came in) and pads them once with the zero rows
+    and lanes the launches read (:func:`pad_planes`: a pad inside the
+    lookup is a copy of every plane in every iteration); each GRU iteration
+    then runs the fused
     kernel — recomputing correlation tiles on the MXU instead of re-reading
     a ~254 MB volume from HBM (or, at resolutions where that volume could
     not even be allocated, running where the dense path cannot).
@@ -707,8 +777,13 @@ class FusedLookup:
         self.plan_args = dict(q_blk=q_blk, p_blk_target=p_blk_target)
         self.f2_levels = tuple(fmap2_pyramid(fmap2.astype(jnp.float32),
                                              num_levels))
-        self.f2_planes = tuple(f2_terms(fmap1.dtype, lvl, self.prec)
-                               for lvl in (fmap2,) + self.f2_levels[1:])
+        B, H, W, _ = fmap1.shape
+        self.plans = level_plans(H * W, W, level_shapes(self.f2_levels),
+                                 radius, **self.plan_args)
+        planes = (f2_terms(fmap1.dtype, lvl, self.prec)
+                  for lvl in (fmap2,) + self.f2_levels[1:])
+        self.f2_planes = tuple(f2 if plan is None else pad_planes(f2, plan)
+                               for f2, plan in zip(planes, self.plans))
         # f1 as the kernel holds it, cast here once and not in every lookup
         self.fmap1 = _kernel_operands(fmap1, self.f2_planes[0], self.prec)[0]
 
@@ -717,10 +792,7 @@ class FusedLookup:
                                 self.radius, **self.plan_args)
 
     def keyblocks(self, schedules: Tuple) -> jax.Array:
-        B, H, W, _ = self.fmap1.shape
-        return schedule_keyblocks(schedules, B, H * W,
-                                  level_shapes(self.f2_levels),
-                                  **self.plan_args)
+        return schedule_keyblocks(schedules, self.fmap1.shape[0], self.plans)
 
     def __call__(self, coords: jax.Array,
                  schedules: Optional[Tuple] = None) -> jax.Array:
@@ -759,7 +831,7 @@ def make_fused_lookup(fmap1: jax.Array, fmap2: jax.Array, num_levels: int,
 
 
 def _ragged_window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref,
-                          acc_ref, *, taps, write, n_pb):
+                          acc_ref, *, taps, write, n_pb, h2_blk):
     """Page-scheduled program over the flattened query stream: grid
     ``(B*Qp/T, K)``; step k of query block j visits absolute f2 page
     ``S[j, k]`` (= item * n_pb + relative row-block).  The body needs the
@@ -772,7 +844,8 @@ def _ragged_window_kernel(S_ref, f1_ref, coords_ref, f2_ref, out_ref,
     prev = S_ref[j, jnp.maximum(k - 1, 0)]
     _accumulate(acc_ref, k, k == pl.num_programs(1) - 1,
                 (k == 0) | (sel != prev),
-                lambda: taps(sel % n_pb, f1_ref, coords_ref, f2_ref),
+                lambda: taps((sel % n_pb) * h2_blk, f1_ref, coords_ref,
+                             (f2_ref,)),
                 lambda: write(coords_ref, acc_ref, out_ref))
 
 
@@ -807,6 +880,7 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
                          coords: jax.Array, live: jax.Array,
                          rows_crop: jax.Array, radius: int, level: int, *,
                          q_blk: int, p_blk_target: int, interpret: bool,
+                         grid_w: int,
                          corr_precision=jax.lax.Precision.HIGHEST,
                          out_dtype=jnp.float32) -> jax.Array:
     """f1 [B,Q,C] (dead rows zero), f2_level [B,H2,W2,C] (pre-masked; or its
@@ -820,9 +894,10 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
     f1, f2, corr_precision = _kernel_operands(f1, f2_level, corr_precision)
     n_terms = f2.shape[0]
 
-    # identical padding/blocking plan to the dense path (kernel_plans.py;
-    # lint/budget.py prices exactly this)
-    plan = corr_level_plan(Q, H2, W2, q_blk=q_blk, p_blk_target=p_blk_target)
+    # the dense path's plan (kernel_plans.py), of which this launch reads
+    # the fixed row-blocks: they are its pages
+    plan = corr_level_plan(Q, H2, W2, q_blk=q_blk, p_blk_target=p_blk_target,
+                           radius=radius, grid_w=grid_w)
     T, Qp = plan.t, plan.qp
     if Qp != Q:
         f1 = jnp.pad(f1, ((0, 0), (0, Qp - Q), (0, 0)))
@@ -863,7 +938,7 @@ def _ragged_lookup_level(f1: jax.Array, f2_level: jax.Array,
     )
     out = pl.pallas_call(
         functools.partial(_ragged_window_kernel, taps=taps, write=write,
-                          n_pb=n_pb),
+                          n_pb=n_pb, h2_blk=h2_blk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, B * Qp, n * n), out_dtype),
         interpret=interpret,
@@ -898,7 +973,7 @@ def _ragged_fused_lookup_impl(fmap1: jax.Array, f2_levels: Sequence[jax.Array],
         with stage(f"l{i}/corr_lookup"):      # as in _fused_lookup_impl
             outs.append(_ragged_lookup_level(
                 f1, f2l, cf, live, rows // (2 ** i), radius, i, q_blk=q_blk,
-                p_blk_target=p_blk_target, interpret=interp,
+                p_blk_target=p_blk_target, interpret=interp, grid_w=W,
                 corr_precision=corr_precision, out_dtype=out_dtype))
     return jnp.concatenate(outs, axis=-1).reshape(B, H, W, -1)
 

@@ -96,9 +96,9 @@ def make_ring_lookup_local(f1_local: jax.Array, f2_local: jax.Array,
     level at once (the shift scales with the level like the coords do, and
     out-of-slab windows one-hot-match nothing = zeros).  ``pallas_opts``
     forwards q_blk/p_blk_target; each slab's launch
-    takes the key-block schedule by the kernel's own rule
-    (``kernel_plans.corr_level_scheduled``: more than one row-block), like
-    every other caller.
+    takes the band schedule by the kernel's own plan
+    (``kernel_plans.CorrLevelPlan.banded``: a map of more than one step's
+    positions), like every other caller.
 
     ``precision=None`` means backend-default MXU precision (bf16 inputs) on
     BOTH branches: dense_corr passes it through, and the pallas branch maps

@@ -94,18 +94,24 @@ def make_serving_metrics(registry: Registry, config,
             "raft_serving_compile_cache_misses_total",
             "Device calls that had to compile (0 after warmup = the "
             "no-recompile-storm guarantee)"),
-        # the fused correlation lookup's key-block schedule (ops/corr_pallas
+        # the fused correlation lookup's band schedule (ops/corr_pallas
         # .schedule_keyblocks), reduced on the device from the schedules the
         # kernels were given and fetched with the flow: visited / possible
-        # is the share of the all-blocks walk a batch still does
+        # is the share of its grid steps a batch did work in, visited /
+        # tiles the steps a query tile took a level (1.0: one band each)
         "keyblocks_visited": registry.counter(
             "raft_serving_corr_keyblocks_visited_total",
-            "(query tile, key row-block) steps of the correlation lookup "
-            "that did work, over levels, iterations and pair batches"),
+            "(query tile, band of key rows) grid steps of the correlation "
+            "lookup that did work, over levels, iterations and pair batches"),
         "keyblocks_possible": registry.counter(
             "raft_serving_corr_keyblocks_possible_total",
-            "The same steps had every tile visited every key row-block of "
-            "every level"),
+            "The lookup's grid steps: what it would visit had every tile "
+            "taken every band of every level"),
+        "corr_tiles": registry.counter(
+            "raft_serving_corr_tiles_total",
+            "(query tile, level) pairs of the correlation lookup: visited / "
+            "tiles is the steps a tile took a level, 1.0 where every tile's "
+            "windows lay in one band"),
         "iters_used": (iters_used := registry.histogram(
             "raft_iters_used",
             "GRU iterations spent per request — fills only under "

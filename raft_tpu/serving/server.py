@@ -305,7 +305,8 @@ class FlowServer:
             self.metrics["compile_misses"].inc(
                 self.engine.compile_misses - before)
         # (an engine without the counts, a stub's say, zips to nothing)
-        for name, was, now in zip(("keyblocks_visited", "keyblocks_possible"),
+        for name, was, now in zip(("keyblocks_visited", "keyblocks_possible",
+                                   "corr_tiles"),
                                   blocks, getattr(self.engine,
                                                   "corr_keyblocks", ())):
             if now > was:
@@ -671,8 +672,12 @@ class FlowServer:
             else min(deadline_ms, self.sconfig.default_deadline_ms)
         if dl <= 0:
             raise BadRequest(f"deadline_ms must be positive, got {dl}")
-        im1p, pads = pad_to_shape(im1[None].astype(np.float32), bucket)
-        im2p, _ = pad_to_shape(im2[None].astype(np.float32), bucket)
+        # (no copy of a frame that is float32 already, as the HTTP edge's
+        # are, and none by the pad where the frame is the bucket's size)
+        im1p, pads = pad_to_shape(
+            im1[None].astype(np.float32, copy=False), bucket)
+        im2p, _ = pad_to_shape(
+            im2[None].astype(np.float32, copy=False), bucket)
         rbucket = None
         if self.sconfig.ragged:
             # ragged: zero-embed the routed-bucket pair corner-

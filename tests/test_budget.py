@@ -124,23 +124,33 @@ def test_grid_parity_with_live_warm_engine(config):
 
 def test_corr_level_plan_values():
     plan = kernel_plans.corr_level_plan(24, 4, 6, q_blk=128,
-                                        p_blk_target=4096)
+                                        p_blk_target=4096, radius=4,
+                                        grid_w=6)
     assert (plan.t, plan.qp) == (24, 24)
     assert plan.w2p == 128                       # lane padding
     assert plan.h2_blk == 4 and plan.n_pblocks == 1
+    assert not plan.banded and plan.band_granules == 0
     # full-scale level 0 at 432x1024: Q = 54*128, map 54x128
     plan = kernel_plans.corr_level_plan(54 * 128, 54, 128, q_blk=128,
-                                        p_blk_target=4096)
+                                        p_blk_target=4096, radius=4,
+                                        grid_w=128)
     assert plan.t == 128 and plan.w2p == 128
     assert plan.h2_blk == 32 and plan.rows_padded == 64
     assert plan.n_pblocks == 2
+    # more than one step's positions: a band of 16 rows from a multiple of
+    # 4, four bands to the whole map, zero rows for a band that starts on
+    # the map's last granule (row 52)
+    assert plan.banded and plan.band_granules == 4
+    assert (plan.band_granule, plan.band_rows, plan.n_bands,
+            plan.band_rows_padded) == (4, 16, 4, 68)
 
 
 def test_corr_level_plan_refuses_a_degenerate_level():
     # a map pooled away to nothing has no plan: the kernel returns zeros
     # for it before it asks for one
     with pytest.raises(ValueError, match="degenerate level 0x8"):
-        kernel_plans.corr_level_plan(64, 0, 8, q_blk=128, p_blk_target=4096)
+        kernel_plans.corr_level_plan(64, 0, 8, q_blk=128, p_blk_target=4096,
+                                     radius=4, grid_w=8)
 
 
 @pytest.mark.parametrize("w2,w2p", [(40, 128), (62, 128), (100, 128),
@@ -151,7 +161,8 @@ def test_corr_level_plan_pads_rows_to_whole_lanes(w2, w2p):
     nothing lays rows side by side."""
     h2 = 46
     plan = kernel_plans.corr_level_plan(h2 * w2, h2, w2, q_blk=128,
-                                        p_blk_target=4096)
+                                        p_blk_target=4096, radius=4,
+                                        grid_w=w2)
     assert plan.w2p == w2p and plan.w2p % kernel_plans.LANE == 0
     assert plan.rows == h2
     assert plan.h2_blk == 4096 // w2p
@@ -211,16 +222,19 @@ def test_vmem_envelopes(config):
     # (kernel_plans.corr_window_vmem: 0.25 MiB here since PR 32, whose body
     # gathers a window's taps through [T, 128] lane tiles where PRs 21-31
     # priced one-hot matrices of [T, 9, rows] and [T, 9, lanes])
+    # (level 0, 55 rows, is banded: a step holds a band of 16 rows, as four
+    # double-buffered blocks of 4, where PRs 21-35 held a block of 32; the
+    # pooled levels are one whole-map block each, as before)
     (dict(compute_dtype="float32"), [[1, "float32"]] * 4,
-     [13.81, 11.94, 6.56, 3.88]),
-    # bfloat16 maps at 'highest': level 0 holds one bfloat16 plane (4 MB of
+     [7.69, 11.94, 6.56, 3.88]),
+    # bfloat16 maps at 'highest': level 0 holds one bfloat16 plane (2 MB of
     # double-buffered f2 less), the pooled levels three (up to 4 MB more)
     (dict(compute_dtype="bfloat16"),
-     [[1, "bfloat16"]] + [[3, "bfloat16"]] * 3, [9.63, 15.13, 8.0, 4.44]),
+     [[1, "bfloat16"]] + [[3, "bfloat16"]] * 3, [5.5, 15.13, 8.0, 4.44]),
     # 'default' keeps the float32 blocks the MXU rounds itself; the output
     # block is bfloat16 all the same (the update block's dtype)
     (dict(compute_dtype="bfloat16", corr_precision="default"),
-     [[1, "float32"]] * 4, [13.75, 11.88, 6.5, 3.81]),
+     [[1, "float32"]] * 4, [7.63, 11.88, 6.5, 3.81]),
 ])
 def test_corr_envelope_prices_the_dtypes_the_kernel_holds(kw, planes, mib):
     full = RAFTConfig.full(corr_impl="pallas", **kw)

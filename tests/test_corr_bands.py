@@ -1,0 +1,151 @@
+"""The banded lookup launches at the served shapes, cut down for the CPU: a
+query tile fetches its own band of key rows (``kernel_plans.corr_level_plan``)
+and the launch gives what the walk over every row-block gives, bit for bit,
+for raft-things' and RAFT-S's radius and channels, bfloat16 and float32 maps,
+both output dtypes, and every kind of flow a band can meet; the ragged
+launch, which keeps the fixed row-blocks as its pages, gives what it gave.
+(``tests/test_corr_schedule.py`` has the plan's table, the counts and the
+model; this file is its own so that the suite's workers share the load.)
+Pallas interpret mode."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from raft_tpu.ops.coords import coords_grid
+from raft_tpu.ops.corr import fmap2_pyramid, mask_ragged_rows
+from raft_tpu.ops.corr_pallas import (_fused_lookup_impl, _lookup_level,
+                                      _ragged_lookup_level, level_plans,
+                                      level_shapes, lookup_schedules)
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+def _tile_bands(S, plan):
+    """Bands each tile takes, from its schedule."""
+    return (np.asarray(S)[..., -1] - np.asarray(S)[..., 0]) \
+        // plan.band_granules + 1
+
+
+#: name -> (radius, channels): raft-things and RAFT-S
+MODELS = {"things": (4, 256), "small": (3, 128)}
+#: name -> (grid, p_blk_target).  ``hd``: rows of 256 lanes like 1080p's
+#: level 0 (135 x 240), tiles that begin mid-row and a padded tail tile
+#: (4,896 queries); level 0 is banded at the served 4096 positions, level 1
+#: one block.  ``sintel``: 128 queries a row, a tile a row like 55 x 128;
+#: at 2048 positions levels 0 and 1 are both banded.
+GRIDS = {"hd": ((36, 136), 4096), "sintel": ((40, 128), 2048)}
+
+
+def _served_case(model, grid, kind, dtype):
+    radius, c = MODELS[model]
+    (h, w), p_blk = GRIDS[grid]
+    plans = level_plans(h * w, w, [(h, w), (h // 2, w // 2)], radius, 128,
+                        p_blk)
+    rows = plans[0].band_rows
+    base = coords_grid(1, h, w)
+    x = jnp.arange(w)[None, None, :]
+    if kind == "rest":
+        # a smooth flow of under a cell: every tile's windows in one band
+        y = jnp.arange(h)[None, :, None]
+        coords = base + jnp.stack([0.8 * jnp.sin(x / 9.0 + y / 7.0),
+                                   0.7 * jnp.cos(x / 8.0 - y / 6.0)], -1)
+    elif kind == "two-bands":
+        # (i) inside every tile the flow jumps by a band's rows: its windows
+        # span more than one band
+        jump = jnp.where(x % 2 == 0, -0.5 * rows, 0.5 * rows + 0.5)
+        coords = base.at[..., 1].add(jnp.broadcast_to(jump, (1, h, w)))
+    elif kind == "edges":
+        # (ii) a third of the columns wholly above the map, a third wholly
+        # below it, a third across its last rows
+        off = jnp.where(x < w // 3, -(h + 20.5),
+                        jnp.where(x < 2 * w // 3, h + 30.25, h - 2.75))
+        coords = (base.at[..., 1].set(jnp.broadcast_to(off, (1, h, w)))
+                  .at[..., 0].add(-3.5))
+    else:
+        # (iii) every window's first row is the map's last: the band starts
+        # on the last granule and reads the padding's zero rows after it
+        assert kind == "last-granule"
+        coords = base.at[..., 1].set(h - 1 + radius + 0.5)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(36))
+    fmap1 = jax.random.normal(k1, (1, h, w, c), dtype)
+    fmap2 = jax.random.normal(k2, (1, h, w, c), dtype)
+    f2_levels = [fmap2] + fmap2_pyramid(fmap2.astype(F32), 2)[1:]
+    return radius, p_blk, plans, fmap1, f2_levels, coords
+
+
+@pytest.mark.parametrize("out", [F32, BF16], ids=["out-f32", "out-bf16"])
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("kind", ["rest", "two-bands", "edges",
+                                  "last-granule"])
+@pytest.mark.parametrize("grid", list(GRIDS))
+@pytest.mark.parametrize("model", list(MODELS))
+def test_banded_launch_equals_the_all_rows_launch(model, grid, kind, dtype,
+                                                  out):
+    """raft-things' and RAFT-S's shapes (radius 4 / 256 channels, radius 3 /
+    128) at grids cut down from the two served ones, the two top levels,
+    bfloat16 maps (1 + 3 planes) and float32 maps, both output dtypes: the
+    launch that fetches each tile's own band of key rows equals the launch
+    that walks every row-block, bit for bit — for a flow at rest (one band a
+    tile), (i) tiles whose windows span more than one band, (ii) windows
+    wholly above, below and across the map's last rows, (iii) bands that
+    start on the map's last granule, and (iv) the padded tail tile (grid
+    ``hd`` has one in every case)."""
+    radius, p_blk, plans, fmap1, f2_levels, coords = _served_case(
+        model, grid, kind, dtype)
+    (h, w), _ = GRIDS[grid]
+    assert plans[0].banded and (plans[0].qp != h * w) == (grid == "hd")
+    assert plans[1].banded == (grid == "sintel")
+    sched = lookup_schedules(coords, level_shapes(f2_levels), radius,
+                             q_blk=128, p_blk_target=p_blk)
+    bands = _tile_bands(sched[0], plans[0])
+    s_last = (plans[0].rows - 1) // plans[0].band_granule
+    if kind == "rest":
+        assert bands.max() == 1                       # the mechanism working
+    elif kind == "two-bands":
+        assert (bands >= 2).mean() > 0.7      # (the map's edges clip some)
+    elif kind == "last-granule":
+        assert (np.asarray(sched[0]) == s_last).all()
+    run = lambda s: np.asarray(_fused_lookup_impl(        # noqa: E731
+        fmap1, f2_levels, coords, radius, q_blk=128, p_blk_target=p_blk,
+        interpret=True, schedules=s, out_dtype=out))
+    got, whole = run(sched), run((None, None))
+    bits = np.uint16 if out == BF16 else np.uint32
+    np.testing.assert_array_equal(got.view(bits), whole.view(bits))
+    n = (2 * radius + 1) ** 2
+    got = got.astype(np.float32).reshape(h, w, 2 * n)
+    if kind == "edges":
+        assert not got[:, : 2 * w // 3].any()         # wholly off the map
+        assert np.abs(got[:, 2 * w // 3:]).max() > 0.1
+    elif kind == "last-granule":
+        # window row 0 is the map's last row, the others lie under the map
+        by_row = got[..., :n].reshape(h, w, -1, 2 * radius + 1)   # [.., i, j]
+        assert np.abs(by_row[..., 0]).max() > 0.1
+        assert not by_row[..., 2:].any()
+    else:
+        assert np.abs(got).max() > 0.1
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_the_ragged_launch_keeps_its_pages(dtype):
+    """The ragged launch reads the plan's fixed row-blocks as its pages and
+    the shared body takes the page's first row: with every item at the box
+    its output is the dense all-rows launch's, bit for bit — what it was
+    before the dense launches took bands."""
+    B, H, W, C, RADIUS = 2, 30, 44, 32, 4       # 15 pages of two rows
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
+    fmap1 = jax.random.normal(k1, (B, H, W, C), dtype)
+    fmap2 = jax.random.normal(k2, (B, H, W, C), dtype)
+    coords = jax.random.uniform(k3, (B, H * W, 2), minval=-8.0,
+                                maxval=1.2 * W)
+    sizes = jnp.array([[H, W]] * B, jnp.int32)
+    kw = dict(q_blk=128, p_blk_target=256, interpret=True, grid_w=W)
+    got = _ragged_lookup_level(
+        mask_ragged_rows(fmap1, sizes).reshape(B, H * W, C),
+        mask_ragged_rows(fmap2, sizes), coords, jnp.ones((B, H * W), bool),
+        sizes[:, 0], RADIUS, 0, **kw)
+    want = _lookup_level(fmap1.reshape(B, H * W, C), fmap2, coords, RADIUS,
+                         0, **kw)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))

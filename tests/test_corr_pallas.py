@@ -16,9 +16,10 @@ from raft_tpu.ops.corr_pallas import fused_lookup, make_fused_lookup
 
 
 def _every_level_scheduled(coords, f2_levels, radius, q_blk, p_blk_target):
-    """A key-block schedule for EVERY level, also those of one block, which
-    the kernel's rule (kernel_plans.corr_level_scheduled) leaves without:
-    the schedule has to be right wherever it is used."""
+    """A band schedule for every level that can take one: the levels of more
+    than one row-block at this ``p_blk_target`` (a level of one block has no
+    band: ``kernel_plans.CorrLevelPlan.banded``), built level by level from
+    each level's own plan."""
     from raft_tpu.kernel_plans import corr_level_plan
     from raft_tpu.ops.corr_pallas import level_schedule
 
@@ -28,8 +29,11 @@ def _every_level_scheduled(coords, f2_levels, radius, q_blk, p_blk_target):
     for i, lvl in enumerate(f2_levels):
         h2, w2 = lvl.shape[-3:-1]
         plan = corr_level_plan(H * W, h2, w2, q_blk=q_blk,
-                               p_blk_target=p_blk_target)
-        out.append(level_schedule(cf, plan, h2, i, radius))
+                               p_blk_target=p_blk_target, radius=radius,
+                               grid_w=W)
+        out.append(level_schedule(cf, plan, i, radius) if plan.banded
+                   else None)
+    assert out[0] is not None
     return tuple(out)
 
 
@@ -120,10 +124,11 @@ def _one_level(fmap1, fmap2, coords, radius, *, q_blk=64, p_blk_target=256,
     B, H, W, C = fmap1.shape
     cf = coords.reshape(B, H * W, 2)
     plan = corr_level_plan(H * W, H, W, q_blk=q_blk,
-                           p_blk_target=p_blk_target)
-    sched = level_schedule(cf, plan, H, 0, radius) if scheduled else None
+                           p_blk_target=p_blk_target, radius=radius,
+                           grid_w=W)
+    sched = level_schedule(cf, plan, 0, radius) if scheduled else None
     got = _lookup_level(fmap1.reshape(B, H * W, C), fmap2, cf, radius, 0,
-                        q_blk=q_blk, p_blk_target=p_blk_target,
+                        q_blk=q_blk, p_blk_target=p_blk_target, grid_w=W,
                         interpret=True, schedule=sched, out_dtype=out_dtype)
     return got, plan
 
@@ -393,10 +398,10 @@ def test_window_schedule_model_forward():
     # relative to that
     np.testing.assert_allclose(np.asarray(out_a.flow), np.asarray(out_b.flow),
                                rtol=1e-3, atol=1e-3)
-    visited, possible = (int(v) for v in out_a.corr_keyblocks)
-    assert visited == possible            # nothing to skip at 4096
-    visited, possible = (int(v) for v in out_b.corr_keyblocks)
-    assert 0 < visited < possible
+    visited, possible, tiles = (int(v) for v in out_a.corr_keyblocks)
+    assert visited == possible == tiles   # one block a level at 4096
+    visited, possible, tiles = (int(v) for v in out_b.corr_keyblocks)
+    assert 0 < tiles <= visited < possible
 
 
 def test_model_forward_at_the_training_crop_width():
@@ -422,36 +427,43 @@ def test_model_forward_at_the_training_crop_width():
 
 
 def test_window_schedule_invariants():
-    """The prefetched schedule must (a) stay within [0, K-1], (b) be
-    non-decreasing with its active prefix strictly increasing then constant,
-    and (c) cover every row-block any query's bilinear window touches —
-    the properties the kernel's skip logic and the DMA index map rely on."""
-    from raft_tpu.ops.corr_pallas import _window_schedule
+    """The prefetched band schedule must (a) name band starts, in granules,
+    whose ``R`` rows lie inside the padded planes, (b) be non-decreasing,
+    its active prefix stepping by one band (``R / g`` granules: disjoint
+    bands, in order) and then constant, and (c) cover every map row any
+    query's bilinear window touches — the properties the kernel's skip
+    logic and the granule blocks' index maps rely on."""
+    from raft_tpu.kernel_plans import corr_level_plan
+    from raft_tpu.ops.corr_pallas import _band_schedule
 
     B, Qp, T, radius = 2, 256, 64, 4
     n = 2 * radius + 1
-    H2, h2_blk = 54, 8
-    K = -(-H2 // h2_blk)     # H2p // h2_blk, the kernel's real grid length
+    H2 = 54
+    plan = corr_level_plan(Qp, H2, 128, q_blk=T, p_blk_target=1024,
+                           radius=radius, grid_w=128)
+    g, n_g, K = plan.band_granule, plan.band_granules, plan.n_bands
+    assert plan.banded and (g, plan.band_rows, K) == (4, 8, 7)
     key = jax.random.PRNGKey(11)
     coords = jax.random.uniform(key, (B, Qp, 2), minval=-20.0, maxval=80.0)
-    S = np.asarray(_window_schedule(coords, 1.0, radius, T, h2_blk, H2, K))
+    S = np.asarray(_band_schedule(coords, 1.0, radius, T, plan))
     assert S.shape == (B, Qp // T, K)
-    assert S.min() >= 0 and S.max() <= K - 1, (S.min(), S.max())
+    assert S.min() >= 0
+    assert (S.max() + n_g) * g <= plan.band_rows_padded, S.max()
     d = np.diff(S, axis=2)
-    assert (d >= 0).all(), "schedule must be non-decreasing"
-    assert (d <= 1).all(), "schedule visits contiguous blocks"
+    assert np.isin(d, (0, n_g)).all(), "bands are disjoint and in order"
+    assert (np.diff((d > 0).astype(int), axis=2) <= 0).all(), \
+        "the active prefix, then repeats"
 
     cy = np.asarray(coords[..., 1]).reshape(B, Qp // T, T)
     iy0 = np.floor(cy).astype(int) - radius
     for b in range(B):
         for j in range(Qp // T):
-            touched = set()
-            for t in range(T):
-                for row in range(iy0[b, j, t], iy0[b, j, t] + n + 1):
-                    if 0 <= row < H2:
-                        touched.add(row // h2_blk)
-            assert touched <= set(S[b, j].tolist()), (
-                b, j, touched, S[b, j].tolist())
+            touched = {row for t in range(T)
+                       for row in range(iy0[b, j, t], iy0[b, j, t] + n + 1)
+                       if 0 <= row < H2}
+            covered = {s * g + i for s in S[b, j].tolist()
+                       for i in range(plan.band_rows)}
+            assert touched <= covered, (b, j, touched, S[b, j].tolist())
 
 
 # --- MXU passes from the operands' dtypes (corr_terms) ----------------------
@@ -535,16 +547,18 @@ def test_bf16_maps_equal_the_float32_highest_program(kernel, level, grid):
         fn = lambda a, b, prec, out=F32: _ragged_lookup_level(  # noqa: E731
             a, b, cf, live.reshape(B, H * W), sizes[:, 0] // 2 ** level,
             radius, level, q_blk=64, p_blk_target=256, interpret=True,
-            corr_precision=prec, out_dtype=out)
+            grid_w=W, corr_precision=prec, out_dtype=out)
     else:
         f2l = f2_levels[level]
         h2, w2 = f2l.shape[1:3]
-        sched = None if kernel == "all" else level_schedule(
-            cf, corr_level_plan(H * W, h2, w2, q_blk=64, p_blk_target=256),
-            h2, level, radius)
+        plan = corr_level_plan(H * W, h2, w2, q_blk=64, p_blk_target=256,
+                               radius=radius, grid_w=W)
+        # (20x28's levels 2 and 3 are one block of 256 positions: no band)
+        sched = (level_schedule(cf, plan, level, radius)
+                 if kernel == "scheduled" and plan.banded else None)
         fn = lambda a, b, prec, out=F32: _lookup_level(   # noqa: E731
             a, b, cf, radius, level, q_blk=64, p_blk_target=256,
-            interpret=True, corr_precision=prec, schedule=sched,
+            interpret=True, grid_w=W, corr_precision=prec, schedule=sched,
             out_dtype=out)
     assert f2l.dtype == (BF16 if level == 0 else F32)
     got = fn(f1, f2l, HIGHEST)
@@ -556,11 +570,14 @@ def test_bf16_maps_equal_the_float32_highest_program(kernel, level, grid):
     np.testing.assert_allclose(got, want, rtol=0,
                                atol=1e-6 * np.abs(want).max())
     # and the exact form is what ran: bfloat16 operands, 1 or 3 one-pass dots
+    # for each block of key rows a step is handed (a band's granules)
     text = str(jax.make_jaxpr(lambda a, b: fn(a, b, HIGHEST))(f1, f2l))
     # (the selection that follows them moves lanes and multiplies nothing:
     # until PR 32 two more dots, a_y and a_x, interpolated every block)
     n_dots = text.count("dot_general")
-    assert n_dots == (1 if level == 0 else 3), text
+    blocks = (plan.band_granules if kernel == "scheduled" and plan.banded
+              else 1)
+    assert n_dots == (1 if level == 0 else 3) * blocks, text
     assert f"bf16[{1 if level == 0 else 3},1," in text
 
 
@@ -580,7 +597,7 @@ def test_float32_maps_keep_the_six_pass_program():
     np.testing.assert_array_equal(np.asarray(planes[0]), np.asarray(fmap2))
     fn = lambda a, b: _lookup_level(                      # noqa: E731
         a.reshape(B, H * W, C), b, coords.reshape(B, H * W, 2), radius, 0,
-        q_blk=64, p_blk_target=256, interpret=True)
+        q_blk=64, p_blk_target=256, grid_w=W, interpret=True)
     text = str(jax.make_jaxpr(fn)(fmap1, fmap2))
     assert "bf16" not in text
     assert text.count("dot_general") == 1        # the correlation's, alone
@@ -706,7 +723,7 @@ def test_out_dtype_where_queries_do_not_fill_the_tiles(grid, level):
     """The served block plan (q_blk 128, p_blk 4096) at grids whose query
     count is no multiple of the tile: the padded tail is written and cut,
     in both dtypes, and the scheduled launches end on repeated entries."""
-    from raft_tpu.kernel_plans import corr_level_plan, corr_level_scheduled
+    from raft_tpu.kernel_plans import corr_level_plan
     from raft_tpu.ops.coords import coords_grid
     from raft_tpu.ops.corr_pallas import _lookup_level, level_schedule
 
@@ -719,16 +736,17 @@ def test_out_dtype_where_queries_do_not_fill_the_tiles(grid, level):
     coords = (coords_grid(B, H, W) + jax.random.uniform(
         k3, (B, H, W, 2), minval=-3.0, maxval=3.0)).reshape(B, H * W, 2)
     h2, w2 = f2.shape[1:3]
-    plan = corr_level_plan(H * W, h2, w2, q_blk=128, p_blk_target=4096)
+    plan = corr_level_plan(H * W, h2, w2, q_blk=128, p_blk_target=4096,
+                           radius=radius, grid_w=W)
     assert plan.qp != H * W
     sched = None
-    if corr_level_scheduled(plan):
-        sched = level_schedule(coords, plan, h2, level, radius)
+    if plan.banded:
+        sched = level_schedule(coords, plan, level, radius)
         S = np.asarray(sched)
         assert (S[..., -1] == S[..., -2]).any()     # a tile ends on a repeat
     run = lambda out: _lookup_level(                      # noqa: E731
         f1, f2, coords, radius, level, q_blk=128, p_blk_target=4096,
-        interpret=True, schedule=sched, out_dtype=out)
+        grid_w=W, interpret=True, schedule=sched, out_dtype=out)
     got = run(F32)
     assert got.shape == (B, H * W, (2 * radius + 1) ** 2)
     assert np.abs(np.asarray(got)).max() > 0.1

@@ -68,7 +68,7 @@ def test_no_import_edge(where, forbidden):
 
 MOVED = ("LANE", "SUBLANE", "round_up", "VMEM_BYTES", "VMEM_CEILING_BYTES",
          "GRU_HALO", "GRU_TAPS", "CorrLevelPlan", "corr_level_plan",
-         "corr_level_scheduled", "GruRowPlan", "gru_row_plan",
+         "corr_band", "GruRowPlan", "gru_row_plan",
          "gru_scoped_bytes", "gru_vmem_limit", "enumerate_warmup_grid",
          "resolved_policy", "Key")
 

@@ -1692,3 +1692,75 @@ def test_debug_profile_validation_busy_and_capture(tmp_path):
             "capture produced no XPlane file"
     finally:
         server.stop()
+
+
+# ------------------------------------------ the HTTP edge's frames (PR 36)
+
+@pytest.mark.parametrize("size,bucket", [((1080, 1920), (1080, 1920)),
+                                         ((436, 1024), (440, 1024)),
+                                         ((30, 44), (32, 48))])
+def test_uint8_frames_decode_to_the_same_values_with_fewer_copies(size,
+                                                                  bucket):
+    """A ``uint8`` frame's range is read before it is widened and it is
+    scaled in place: the values of ``float32(frame) / 255.0`` bit for bit;
+    a frame of the bucket's size is not copied again on its way to the queue
+    (``astype`` to the dtype it has, ``np.pad`` by nothing)."""
+    from raft_tpu.data.pipeline import pad_to_shape
+    from raft_tpu.serving.http import _decode_image
+
+    rng = np.random.default_rng(size[0])
+    frame = rng.integers(0, 256, (*size, 3), dtype=np.uint8)
+    got = _decode_image(frame, "image1")
+    want = np.asarray(frame, np.float32) / 255.0
+    assert got.dtype == np.float32 and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    padded, pads = pad_to_shape(got[None].astype(np.float32, copy=False),
+                                bucket)
+    ref, ref_pads = pad_to_shape(want[None].astype(np.float32), bucket)
+    assert pads == ref_pads and padded.shape == (1, *bucket, 3)
+    np.testing.assert_array_equal(padded, ref)
+    assert np.shares_memory(padded, got) == (size == bucket)
+
+
+def test_decode_image_keeps_its_checks():
+    """Payloads in 0..1 are not rescaled (a uint8 frame of zeros and ones
+    among them), float payloads are still checked for non-finite values,
+    shapes for three channels."""
+    from raft_tpu.serving.http import BadRequest, _decode_image
+
+    ones = np.zeros((4, 6, 3), np.uint8)
+    ones[1, 2, 0] = 1
+    assert _decode_image(ones, "image1").max() == 1.0
+    unit = np.full((4, 6, 3), 0.5, np.float32)
+    assert _decode_image(unit, "image1") is unit
+    assert _decode_image((unit * 255).tolist(), "image1").max() == 0.5
+    bad = unit.copy()
+    bad[0, 0, 0] = np.nan
+    with pytest.raises(BadRequest, match="non-finite"):
+        _decode_image(bad, "image1")
+    with pytest.raises(BadRequest, match="shape"):
+        _decode_image(np.zeros((4, 6), np.uint8), "image1")
+
+
+@pytest.mark.parametrize("crop", [False, True], ids=["whole", "cropped"])
+def test_npz_parts_is_an_npz_whose_arrays_are_not_copied(crop):
+    """The answer's body: what ``np.load`` reads back is the flow and the
+    bucket, the buffers add up to the stated size, and a contiguous flow's
+    data is a view of the array itself (a cropped one is made contiguous
+    once, as ``np.savez`` would)."""
+    from raft_tpu.serving.http import npz_parts
+
+    flow = np.random.default_rng(3).standard_normal(
+        (40, 64, 2)).astype(np.float32)
+    sent = flow[2:-2, 4:-4] if crop else flow
+    parts, size = npz_parts(flow=sent, bucket=np.asarray((40, 64), np.int32))
+    body = b"".join(bytes(p) for p in parts)
+    assert len(body) == size
+    with np.load(io.BytesIO(body)) as z:
+        assert sorted(z.files) == ["bucket", "flow"]
+        np.testing.assert_array_equal(z["flow"], sent)
+        assert tuple(z["bucket"]) == (40, 64)
+    views = [p for p in parts if isinstance(p, memoryview)]
+    assert len(views) == 2
+    assert np.shares_memory(np.frombuffer(views[0], np.float32), flow) \
+        == (not crop)

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from raft_tpu.kernel_plans import corr_level_plan, corr_level_scheduled
+from raft_tpu.kernel_plans import corr_level_plan
 from raft_tpu.ops.corr_pallas import (_lookup_level, _ragged_lookup_level,
                                       level_schedule)
 
@@ -71,16 +71,18 @@ def _corr_specs(sd, level: int, batch: int = 1, f1=jnp.float32,
             s((batch, h * w, 2), jnp.float32))
 
 
-def _scheduled_level(f1, f2_level, coords, *, level, p_blk_target,
+def _scheduled_level(f1, f2_level, coords, *, level, p_blk_target, grid_w,
                      radius=RADIUS, **kw):
-    """``_lookup_level`` under the key-block schedule of its own coords."""
+    """``_lookup_level`` under the band schedule of its own coords: each
+    query tile fetches the band of key rows its windows touch."""
     h2, w2 = f2_level.shape[-3:-1]
     plan = corr_level_plan(f1.shape[1], h2, w2, q_blk=128,
-                           p_blk_target=p_blk_target)
+                           p_blk_target=p_blk_target, radius=radius,
+                           grid_w=grid_w)
     return _lookup_level(
         f1, f2_level, coords, radius, level, q_blk=128,
-        p_blk_target=p_blk_target, interpret=False,
-        schedule=level_schedule(coords, plan, h2, level, radius), **kw)
+        p_blk_target=p_blk_target, interpret=False, grid_w=grid_w,
+        schedule=level_schedule(coords, plan, level, radius), **kw)
 
 
 BF16_L0 = dict(f1=jnp.bfloat16, f2=jnp.bfloat16)   # the encoder's own maps
@@ -105,9 +107,9 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
      BF16_X3),
     ("bf16-scheduled", 0, dict(corr_precision=P.HIGHEST, p_blk_target=1024,
                                scheduled=True), BF16_L0),
-    # 1080x1920: the levels the kernel's rule schedules at the served plan
-    # (nine blocks of 16 rows x 256 lanes at level 0, three at level 1, two
-    # at level 2), and level 3, which is one block
+    # 1080x1920: the levels the plan bands at the served 4096 positions (a
+    # band of 16 rows as four blocks of 4: 4 x 256 lanes at level 0, 4 x 128
+    # at levels 1 and 2), and level 3, which is one block
     ("hd-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
                           scheduled=True, grid=HD), BF16_L0),
     ("hd-level1", 1, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
@@ -152,6 +154,15 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
     ("small-hd-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
                                 scheduled=True, grid=HD, radius=3, c=128,
                                 out_dtype=jnp.bfloat16), BF16_L0),
+    ("small-hd-level1", 1, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                                scheduled=True, grid=HD, radius=3, c=128,
+                                out_dtype=jnp.bfloat16), BF16_X3),
+    ("small-hd-level2", 2, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                                scheduled=True, grid=HD, radius=3, c=128,
+                                out_dtype=jnp.bfloat16), BF16_X3),
+    ("hd-out-bf16-level2", 2, dict(corr_precision=P.HIGHEST,
+                                   p_blk_target=4096, scheduled=True,
+                                   grid=HD, out_dtype=jnp.bfloat16), BF16_X3),
     ("small-hd-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
                                 grid=HD, radius=3, c=128,
                                 out_dtype=jnp.bfloat16), BF16_X3),
@@ -161,26 +172,28 @@ def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
     grid = kw.pop("grid", (H, W))
     scheduled = kw.pop("scheduled", False)
     radius, c = kw.pop("radius", RADIUS), kw.pop("c", C)
+    plan = corr_level_plan(grid[0] * grid[1], grid[0] >> level,
+                           grid[1] >> level, q_blk=128,
+                           p_blk_target=kw["p_blk_target"], radius=radius,
+                           grid_w=grid[1])
+    if grid != (H, W):  # the case is the program's: the plan bands the
+        assert plan.banded == scheduled, name    # level or it does not
+    else:               # (55x128's level 0 also as the all-rows walk)
+        assert plan.banded or not scheduled, name
     if scheduled:
         fn = functools.partial(_scheduled_level, level=level, radius=radius,
-                               **kw)
+                               grid_w=grid[1], **kw)
     else:
         fn = functools.partial(_lookup_level, radius=radius, level=level,
-                               q_blk=128, interpret=False, **kw)
-    if grid != (H, W):  # the case is the program's: the rule gives the same
-        plan = corr_level_plan(grid[0] * grid[1], grid[0] >> level,
-                               grid[1] >> level, q_blk=128,
-                               p_blk_target=4096)
-        assert corr_level_scheduled(plan) == scheduled
+                               q_blk=128, interpret=False, grid_w=grid[1],
+                               **kw)
     text = _compile(fn, *_corr_specs(one_chip, level, grid=grid, c=c,
                                      **dtypes))
     assert "tpu_custom_call" in text
     # the launch itself returns the lane-dense window in the dtype asked for
     out = "bf16" if kw.get("out_dtype") == jnp.bfloat16 else "f32"
-    qp = corr_level_plan(grid[0] * grid[1], grid[0] >> level,
-                         grid[1] >> level, q_blk=128, p_blk_target=4096).qp
     window = (2 * radius + 1) ** 2
-    assert re.search(rf"= {out}\[1,{qp},{window}\]\S* custom-call\(",
+    assert re.search(rf"= {out}\[1,{plan.qp},{window}\]\S* custom-call\(",
                      text), name
     if dtypes:
         # the kernel was handed bfloat16 planes: nothing widened them first
@@ -225,8 +238,8 @@ def test_corr_kernel_fits_the_envelope_the_analyzer_prices(
                         **dtypes)
     kw = dict(level=level, p_blk_target=config.pallas_p_blk,
               radius=config.corr_radius, corr_precision=P.HIGHEST,
-              out_dtype=maps)
-    if priced["plan"]["n_pblocks"] > 1:              # scheduled, as served
+              out_dtype=maps, grid_w=grid[1])
+    if priced["plan"]["n_bands"] > 0:                # banded, as served
         launch = _scheduled_level
     else:
         launch = functools.partial(_lookup_level, q_blk=128, interpret=False)
@@ -259,7 +272,7 @@ def test_ragged_corr_kernel_compiles_for_v5e(one_chip, level, dtypes,
     f1, f2, coords = _corr_specs(one_chip, level, batch=2, **dtypes)
     fn = functools.partial(_ragged_lookup_level, radius=RADIUS, level=level,
                            q_blk=128, p_blk_target=4096, interpret=False,
-                           out_dtype=out_dtype)
+                           grid_w=W, out_dtype=out_dtype)
     text = _compile(fn, f1, f2, coords, s((2, H * W), jnp.bool_),
                     s((2,), jnp.int32))
     out = "bf16" if out_dtype == jnp.bfloat16 else "f32"

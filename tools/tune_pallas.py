@@ -141,8 +141,8 @@ def main() -> int:
     prec = (jax.lax.Precision.HIGHEST if args.precision == "highest"
             else jax.lax.Precision.DEFAULT)
     print(f"# device: {dev.device_kind}  corr precision: {args.precision}  "
-          f"key-block schedule: by the "
-          f"kernel's rule (fine p_blk targets get it)")
+          f"band schedule: by the "
+          f"kernel's plan (fine p_blk targets get it)")
 
     # (label, B, full-res H, W); fmaps are at os=8, C=256 (full model)
     shapes = [("eval 1x432x1024", 1, 432, 1024),
