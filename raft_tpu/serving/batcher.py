@@ -75,7 +75,7 @@ import numpy as np
 from ..data.pipeline import unpad
 from ..telemetry import events as tlm_events
 from ..telemetry import spans as tlm_spans
-from ..telemetry.trace import host_stage, set_batch
+from ..telemetry.trace import SENTINEL, host_stage, set_batch
 from .queue import DeadlineExceeded, RequestQueue
 
 
@@ -201,7 +201,8 @@ class MicroBatcher:
         self.on_crash = on_crash          # supervisor hook: (exception) ->
         self.batches = 0
         self.device_batches = 0           # ordinal of the last device batch
-        self._stage_children: Dict = {}   # stage label -> its counter child
+        # the stage families' recorder (trace.StageCounters), or None
+        self._stages = self.metrics.get("stages")
         self.served = 0
         self.timed_out = 0
         self._inflight_batch = None       # the popped-but-unresolved batch
@@ -241,22 +242,11 @@ class MicroBatcher:
         elif hasattr(m, "inc"):
             m.inc(*args)
 
-    def _stage_seconds(self, label: str, seconds: float) -> None:
-        """``raft_serving_stage_seconds_total{stage=label}``.  The labelled
-        child is looked up once: ``labels()`` takes the family's lock, which
-        the 64 handler threads' stages take too, and a batcher that blocks
-        on a lock gives up the GIL to all of them."""
-        child = self._stage_children.get(label)
-        if child is None:
-            family = self.metrics.get("stage_seconds")
-            if family is None:
-                return
-            child = self._stage_children[label] = family.labels(label)
-        child.inc(seconds)
-
     def _stage_done(self, st) -> None:
-        """Sink of the batcher thread's own host stages: stage seconds."""
-        self._stage_seconds(st.label, st.t1 - st.t0)
+        """Sink of the batcher thread's own host stages: the stage's wall
+        and CPU seconds."""
+        if self._stages is not None:
+            self._stages.record(st.label, st.wall, st.cpu)
 
     def _next_device_batch(self) -> None:
         """A device batch begins: its ordinal rides on every host stage this
@@ -277,8 +267,9 @@ class MicroBatcher:
         wait, fetch), counted into the stage seconds once; the callers turn
         them into child spans of ``execute`` on each traced request."""
         calls = tlm_spans.take_device_slot() or []
-        for _kind, _span, label, c0, c1 in calls:
-            self._stage_seconds(label, c1 - c0)
+        if self._stages is not None:
+            for _kind, _span, label, t0, t1, cpu in calls:
+                self._stages.record(label, t1 - t0, cpu)
         return calls
 
     def _count_stages(self, job: "_PairJob") -> None:
@@ -289,8 +280,8 @@ class MicroBatcher:
 
     @staticmethod
     def _device_spans(tr, calls, parent: str) -> None:
-        for kind, span, _label, c0, c1 in calls:
-            tr.span(span, c0, c1, parent=parent, call=kind)
+        for kind, span, _label, t0, t1, cpu in calls:
+            tr.span(span, t0, t1, parent=parent, call=kind, cpu=cpu)
 
     def _observe_waste(self, group, padded: int) -> None:
         """raft_batch_padding_waste_ratio: the fraction of one device
@@ -612,8 +603,10 @@ class MicroBatcher:
         # where its pad ends
         for r in job.traced:
             if not formed:
-                r.trace.span(form.span, r.dequeued_at, form.t1, group=job.n)
-            r.trace.span(pad.span, pad.t0, pad.t1, padded=job.padded)
+                r.trace.span(form.span, r.dequeued_at, form.t1, group=job.n,
+                             cpu=form.cpu)
+            r.trace.span(pad.span, pad.t0, pad.t1, padded=job.padded,
+                         cpu=pad.cpu)
         job.t_exec0 = pad.t1
         return job
 
@@ -787,17 +780,18 @@ class MicroBatcher:
             self._run_group(group[mid:], budget)
             return
         with host_stage("raft.batch.deliver", self._stage_done) as st:
-            served = self._deliver(group, job.out, padded, t_exec1, st.span,
+            served = self._deliver(group, job.out, padded, t_exec1, st,
                                    _exec_span)
             if served:
                 self._observe("pairs", float(served))
 
     def _deliver(self, group, out, padded: int, t_exec1: float,
-                 span: str, exec_span) -> int:
+                 st, exec_span) -> int:
         """Sentinel, unpad and resolve the rows of a finished device batch,
-        one after another; returns how many were served.  A request's
-        ``deliver`` span runs from the end of ``execute`` to its OWN resolve:
-        the rows before it are part of what it waited for."""
+        one after another, inside the deliver stage ``st``; returns how many
+        were served.  A request's ``deliver`` span runs from the end of
+        ``execute`` to its OWN resolve: the rows before it are part of what
+        it waited for."""
         n = len(group)
         # converge-policy engines return (flows, per-row iters_used); only
         # REAL rows are accounted — padding rows repeat the last request
@@ -806,11 +800,17 @@ class MicroBatcher:
         flows = out
         if isinstance(flows, tuple):
             flows, iters_used = flows
-        flows = np.asarray(flows)
         # non-finite OUTPUT sentinel: inputs were validated at the HTTP
         # edge, so a NaN/Inf row here is the engine's failure — fail that
-        # row alone, its neighbors are fine (per-sample independence)
+        # row alone, its neighbors are fine (per-sample independence).  One
+        # pass over the batch's flow, counted under a label of its own
+        # INSIDE batch.deliver (no annotation: trace.SENTINEL)
+        t0, c0 = time.monotonic(), time.thread_time()
+        flows = np.asarray(flows)
         row_ok = np.isfinite(flows[:n].reshape(n, -1)).all(axis=1)
+        if self._stages is not None:
+            self._stages.record(SENTINEL, time.monotonic() - t0,
+                                time.thread_time() - c0)
         served = 0
         for i, r in enumerate(group):
             r.batch_real, r.batch_padded = n, padded
@@ -825,7 +825,8 @@ class MicroBatcher:
                     # spans BEFORE resolve: the handler wakes on it and
                     # finishes the trace — a late span would hit it closed
                     exec_span(r.trace, tlm_spans.OK)
-                    r.trace.span(span, t_exec1, now, row=i)
+                    r.trace.span(st.span, t_exec1, now, row=i,
+                                 cpu=time.thread_time() - st.c0)
                 self._observe("requests", "ok", 1)
                 self.served += 1
                 served += 1

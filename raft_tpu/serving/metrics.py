@@ -21,6 +21,7 @@ from ..telemetry.registry import (_Metric,  # noqa: F401 — compat re-export
                                   DEFAULT_LATENCY_BUCKETS,
                                   ITERS_USED_BUCKETS, _fmt,
                                   register_process_start_time)
+from ..telemetry.trace import StageCounters
 
 
 def make_serving_metrics(registry: Registry, config,
@@ -32,7 +33,7 @@ def make_serving_metrics(registry: Registry, config,
     occ = tuple(i / 10 for i in range(1, 11))
     batch = tuple(float(s) for s in config.batch_steps)
     register_process_start_time(registry)
-    return {
+    metrics = {
         "requests": registry.counter(
             "raft_serving_requests_total",
             "Requests by terminal status",
@@ -71,7 +72,25 @@ def make_serving_metrics(registry: Registry, config,
             "profiler annotation less its prefix: http.decode, http.admit, "
             "batch.take, batch.form, batch.pad, engine.h2d, "
             "engine.dispatch, engine.wait, engine.fetch, batch.deliver, "
-            "http.encode, http.respond)",
+            "http.encode, http.respond) and, inside batch.deliver, "
+            "batch.deliver.sentinel",
+            labelnames=("stage",)),
+        # the same stages on the thread's own CPU clock (time.thread_time):
+        # wall less CPU of a stage that waits for no device and no socket is
+        # what its thread waited for the interpreter lock and the run queue
+        "stage_cpu_seconds": registry.counter(
+            "raft_serving_stage_cpu_seconds_total",
+            "CPU seconds of the stage's own thread by stage of the serving "
+            "path, same labels as raft_serving_stage_seconds_total plus "
+            "batch.deliver.sentinel (the non-finite pass inside "
+            "batch.deliver, in both families)",
+            labelnames=("stage",)),
+        "stalled_seconds": registry.counter(
+            "raft_serving_stalled_seconds_total",
+            "Wall seconds of host stages that each lasted over "
+            "trace.STALL_SECONDS (of a batch.take, the end through which "
+            "requests were open without a break); each also writes a "
+            "host_stall run-log event",
             labelnames=("stage",)),
         "device_calls": registry.counter(
             "raft_serving_device_calls_total",
@@ -126,6 +145,13 @@ def make_serving_metrics(registry: Registry, config,
             "Mean GRU iterations per request (adaptive-compute saving)",
             fn=iters_used.mean),
     }
+    # not a family: the recorder of the three stage families above, shared
+    # by the handlers' sink and the batcher's (the server gives it its
+    # log_fn and its tracer)
+    metrics["stages"] = StageCounters(metrics["stage_seconds"],
+                                      metrics["stage_cpu_seconds"],
+                                      metrics["stalled_seconds"])
+    return metrics
 
 
 def make_stream_metrics(registry: Registry, store,

@@ -32,7 +32,7 @@ from ..telemetry import events as tlm_events
 from ..telemetry import spans as tlm_spans
 from ..telemetry import watchdogs as tlm_watchdogs
 from ..telemetry.log import get_logger
-from ..telemetry.trace import HOST_STAGES, TraceWindow, host_stage, stage
+from ..telemetry.trace import TraceWindow, host_stage, stage
 from .batcher import MicroBatcher
 from .breaker import BreakerOpen, CircuitBreaker
 from .config import ServeConfig
@@ -135,12 +135,11 @@ class FlowServer:
         self.queue = RequestQueue(sconfig.queue_depth)
         self.metrics = make_serving_metrics(
             self.registry, sconfig, queue_depth_fn=lambda: len(self.queue))
-        # one labelled child per host stage, made up front: every stage
-        # shows in /metrics from the start, and no stage's increment takes
-        # the family's lock (the batcher must never queue behind handlers)
-        self._stage_children = {
-            name[len("raft."):]: self.metrics["stage_seconds"].labels(
-                name[len("raft."):]) for name in HOST_STAGES}
+        # the recorder of every host stage, the batcher's too: a stage that
+        # stood still goes to the log (a long batch.take only for as long as
+        # the tracer below has had a request open)
+        self.stages = self.metrics["stages"]
+        self.stages.log_fn = _log.warning
         self.registry.gauge("raft_serving_queue_limit",
                             "Admission queue capacity (backpressure bound)"
                             ).set(sconfig.queue_depth)
@@ -185,6 +184,7 @@ class FlowServer:
         self.tracer = tlm_spans.Tracer(sample=sconfig.trace_sample,
                                        recorder=self.flightrec,
                                        slo=self.slo)
+        self.stages.tracer = self.tracer
         # metric time-series + anomaly sentinels (telemetry/timeseries.py,
         # telemetry/anomaly.py — OBSERVABILITY.md "Time-series & anomaly
         # detection"): a background ring of registry snapshots feeding
@@ -583,11 +583,11 @@ class FlowServer:
 
     def stage_done(self, st, trace=None, **attrs) -> None:
         """Sink of the handler threads' host stages (trace.host_stage): the
-        stage's seconds, and its span on ``trace`` when the request has
-        one."""
-        self._stage_children[st.label].inc(st.t1 - st.t0)
+        stage's wall and CPU seconds, and its span on ``trace`` when the
+        request has one."""
+        self.stages.record(st.label, st.wall, st.cpu)
         if trace is not None:
-            trace.span(st.span, st.t0, st.t1, **attrs)
+            trace.span(st.span, st.t0, st.t1, cpu=st.cpu, **attrs)
 
     def infer(self, im1: np.ndarray, im2: np.ndarray,
               deadline_ms: Optional[float] = None,

@@ -115,8 +115,8 @@ def status_of(exc: BaseException) -> str:
 # * the DEVICE SLOT: the batcher opens a list before an engine call; the
 #   engine's host stages (trace.host_stage: h2d, dispatch, wait, fetch —
 #   timed at the only place that can tell them apart) land in it as
-#   (kind, span name, counter label, t0, t1), and the batcher turns them
-#   into child spans of ``execute`` and stage-seconds increments.
+#   (kind, span name, counter label, t0, t1, CPU seconds), and the batcher
+#   turns them into child spans of ``execute`` and stage-seconds increments.
 # * the CURRENT TRACE IDS: the trace ids of the batch being executed, so
 #   out-of-band diagnostics (fault_injected, lock_violation, non-finite
 #   sentinel run-log events) are joinable to their request traces.
@@ -139,7 +139,7 @@ def record_device_stage(kind: str, st) -> None:
     device call of ``kind``.  A single thread-local read outside a batch."""
     slot = getattr(_tls, "device_slot", None)
     if slot is not None:
-        slot.append((kind, st.span, st.label, st.t0, st.t1))
+        slot.append((kind, st.span, st.label, st.t0, st.t1, st.cpu))
 
 
 def set_current_trace_ids(ids: Tuple[str, ...]) -> None:
@@ -176,8 +176,9 @@ class RequestTrace:
 
     def span(self, name: str, t0: float, t1: float, status: str = OK,
              parent: Optional[str] = None, span_id: Optional[str] = None,
-             **attrs) -> Optional[str]:
-        """Record one completed span (monotonic endpoints).  Returns its
+             cpu: Optional[float] = None, **attrs) -> Optional[str]:
+        """Record one completed span (monotonic endpoints; ``cpu``: the CPU
+        seconds of a host stage's thread, kept as ``cpu_ms``).  Returns its
         span id (pass a shared ``span_id`` to join co-batched traces on
         one device span), or None if the trace already closed."""
         sid = span_id or new_span_id()
@@ -185,6 +186,8 @@ class RequestTrace:
                "start_ms": round((t0 - self.t0) * 1000.0, 3),
                "dur_ms": round((t1 - t0) * 1000.0, 3),
                "status": status}
+        if cpu is not None:
+            rec["cpu_ms"] = round(cpu * 1000.0, 3)
         if attrs:
             rec.update(attrs)
         with self._lock:
@@ -229,7 +232,7 @@ class Tracer:
     recorder/run log; error traces always do.  ``sample == 0`` disables
     tracing outright: :meth:`start` returns None.  ``open_traces`` counts
     started-but-unfinished traces — the span-leak observable the tests
-    assert back to zero."""
+    assert back to zero; ``held_s`` says for how long there has been one."""
 
     def __init__(self, sample: float = 1.0, recorder=None, slo=None):
         self.sample = float(sample)
@@ -238,6 +241,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._acc = 0.0                   # systematic-sampling accumulator
         self._open = 0
+        self._open_since = 0.0            # when _open last rose from 0
         self.finished = 0
 
     @property
@@ -245,12 +249,21 @@ class Tracer:
         with self._lock:
             return self._open
 
+    def held_s(self) -> float:
+        """Seconds for which at least one trace has been open without a
+        break: how long the handlers have held requests (0.0 with none open,
+        and always with tracing off)."""
+        with self._lock:
+            return time.monotonic() - self._open_since if self._open else 0.0
+
     def start(self, kind: str,
               trace_id: Optional[str] = None) -> Optional[RequestTrace]:
         s = self.sample
         if s <= 0.0:
             return None
         with self._lock:
+            if not self._open:
+                self._open_since = time.monotonic()
             self._open += 1
             if s >= 1.0:
                 sampled = True

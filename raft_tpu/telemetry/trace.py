@@ -18,12 +18,15 @@ covers early exits.
 Stages name *device code*; ``host_stage(name)`` names what the *host* does
 between device calls (decode, pad, h2d, fetch, deliver …): one call site
 yields the request span, a ``jax.profiler.TraceAnnotation`` on the device
-trace's clock and a stage-seconds counter.  ``instruction_stages`` turns
-the ``op_name`` metadata of a compiled executable's text into an
-instruction -> ``stage()`` path map, because the chip's trace events carry
-the instruction's name and no scope.  :mod:`spans` names *requests* —
-ID-carrying spans with parent links and status threaded through the
-serving plane (queue wait vs device execute vs respond, per request).
+trace's clock and the stage's wall and CPU seconds (``StageCounters``: a
+stage that runs and a stage that waits for the interpreter lock differ in
+the second, and one that stood still for seconds says so).
+``instruction_stages`` turns the ``op_name`` metadata of a compiled
+executable's text into an instruction -> ``stage()`` path map, because the
+chip's trace events carry the instruction's name and no scope.
+:mod:`spans` names *requests* — ID-carrying spans with parent links and
+status threaded through the serving plane (queue wait vs device execute vs
+respond, per request).
 """
 
 from __future__ import annotations
@@ -33,6 +36,8 @@ import re
 import threading
 import time
 from typing import Callable, Dict, Optional
+
+from . import events as _events
 
 _stack = threading.local()
 
@@ -102,19 +107,33 @@ def set_batch(ordinal: Optional[int]) -> None:
 
 
 class HostStage:
-    """One timed host stage; ``t0``/``t1`` are ``time.monotonic()`` at entry
-    and exit, ``span`` the request-span name of the stage (or None)."""
+    """One timed host stage; ``t0``/``t1`` are ``time.monotonic()`` and
+    ``c0``/``c1`` ``time.thread_time()`` at entry and exit, ``span`` the
+    request-span name of the stage (or None).  The thread's CPU clock counts
+    its user and system time whether or not it holds the interpreter lock
+    (numpy's ``copyto`` and ``isfinite`` release it and still run here) and
+    stands still while the thread sleeps on that lock, on a registry lock or
+    in the run queue: wall less CPU of a stage that waits for no device and
+    no socket is its lock and scheduler wait."""
 
-    __slots__ = ("name", "span", "t0", "t1")
+    __slots__ = ("name", "span", "t0", "t1", "c0", "c1")
 
     def __init__(self, name: str):
         self.name = name
         self.span = HOST_STAGES[name]
-        self.t0 = self.t1 = 0.0
+        self.t0 = self.t1 = self.c0 = self.c1 = 0.0
 
     @property
     def label(self) -> str:
         return self.name[5:]              # less 'raft.'
+
+    @property
+    def wall(self) -> float:
+        return self.t1 - self.t0
+
+    @property
+    def cpu(self) -> float:
+        return self.c1 - self.c0
 
 
 @contextlib.contextmanager
@@ -123,9 +142,10 @@ def host_stage(name: str, sink: Optional[Callable] = None, **attrs):
 
     Opens ``jax.profiler.TraceAnnotation(name, batch=<n>, **attrs)`` — with
     no profiler session that is one atomic check — and on exit, also when the
-    body raised, stamps ``t1`` and hands the :class:`HostStage` to ``sink``
-    (the caller's span-and-counter recorder), so one site yields all three
-    records.  Yields the stage for callers that place the span themselves."""
+    body raised, stamps ``t1`` and ``c1`` and hands the :class:`HostStage` to
+    ``sink`` (the caller's span-and-counter recorder), so one site yields all
+    four records.  Yields the stage for callers that place the span
+    themselves."""
     st = HostStage(name)
     batch = getattr(_stack, "batch", None)
     if batch is not None:
@@ -136,13 +156,76 @@ def host_stage(name: str, sink: Optional[Callable] = None, **attrs):
     except ImportError:
         ann = contextlib.nullcontext()
     with ann:
-        st.t0 = time.monotonic()
+        st.t0, st.c0 = time.monotonic(), time.thread_time()
         try:
             yield st
         finally:
-            st.t1 = time.monotonic()
+            st.t1, st.c1 = time.monotonic(), time.thread_time()
             if sink is not None:
                 sink(st)
+
+
+# a stage longer than this stood still: the longest device batch of any
+# benchmark cell is 0.87 s, and the longest engine.wait lies under it
+STALL_SECONDS = 2.0
+# _deliver's non-finite pass, counted inside batch.deliver under a label of
+# its own.  No annotation: benchmark/stages.py adds up every raft.batch.*
+# annotation's overlap with an idle gap, and a nested one would count twice
+SENTINEL = "batch.deliver.sentinel"
+
+
+class StageCounters:
+    """What every sink of a host stage counts, by ``stage=`` label:
+    ``wall`` (``raft_serving_stage_seconds_total``), ``cpu``
+    (``raft_serving_stage_cpu_seconds_total``) and, for a stage that lasted
+    over ``STALL_SECONDS``, ``stalled``
+    (``raft_serving_stalled_seconds_total``) with one ``host_stall`` run-log
+    event and one ``log_fn`` line (a ``batch.take`` for as long as requests
+    were open without a break, by the server's ``tracer``).  The labelled
+    children are made up front: every label shows in /metrics from the
+    start, and no increment calls ``labels()``, which takes the family's
+    lock — the handler threads' stages take it too, and a batcher that
+    blocks on a lock gives up the interpreter lock to all of them."""
+
+    def __init__(self, wall, cpu, stalled, log_fn: Optional[Callable] = None,
+                 tracer=None):
+        self._children = {
+            label: (wall.labels(label), cpu.labels(label),
+                    stalled.labels(label))
+            for label in [name[5:] for name in HOST_STAGES] + [SENTINEL]}
+        self.log_fn = log_fn
+        self.tracer = tracer              # the server's spans.Tracer
+
+    def record(self, label: str, wall: float, cpu: float) -> None:
+        """One finished stage."""
+        wall_c, cpu_c, stalled_c = self._children[label]
+        wall_c.inc(wall)
+        cpu_c.inc(cpu)
+        if wall > STALL_SECONDS:
+            self._stalled(label, stalled_c, wall, cpu)
+
+    def _stalled(self, label, child, wall, cpu) -> None:
+        held, counted = {}, wall
+        if label == "batch.take":
+            # a take with no request open is an idle server, not a stall:
+            # what counts of it is its end, through which the handlers held
+            # a request that did not reach the queue
+            held_s = self.tracer.held_s() if self.tracer is not None else 0.0
+            if held_s <= STALL_SECONDS:
+                return
+            counted = min(held_s, wall)
+            held = {"open_traces": self.tracer.open_traces,
+                    "held_s": round(counted, 3)}
+        child.inc(counted)
+        rec = dict(stage=label, wall_s=round(wall, 3), cpu_s=round(cpu, 3),
+                   batch=getattr(_stack, "batch", None),
+                   thread=threading.current_thread().name, **held)
+        log = _events.current()
+        if log is not None:
+            log.event("host_stall", **rec)
+        if self.log_fn is not None:
+            self.log_fn("host stage stood still: " + " ".join(
+                f"{k}={v}" for k, v in rec.items()))
 
 
 # -- instruction -> stage map -----------------------------------------------

@@ -33,6 +33,12 @@ SHARED_METRICS = (
     "encoders_ms", "upsample_ms", "stage_unmapped_share",
     "corr_keyblock_share", "corr_l0_ms", "corr_pooled_ms",
     "corr_window_roofline", "batch_staged_ahead_share")
+# PR 37: the host stages' CPU seconds, the stalls, and PR 36's tiles counter
+STAGE_METRICS = (
+    "batcher_cpu_ms", "batcher_offcpu_ms", "deliver_sentinel_ms",
+    "deliver_offcpu_ms", "handler_cpu_ms", "host_stall_s",
+    "corr_bands_per_tile")
+SHARED_METRICS += STAGE_METRICS
 
 
 @pytest.fixture(scope="module")
@@ -46,6 +52,7 @@ def bench_modules():
         import inputs
         import readers
         import reference
+        import stage_cpu  # noqa: F401  (the readers' ``from stage_cpu import``)
         import stages
         import system
         import tracered
@@ -185,6 +192,84 @@ def test_every_metric_that_lists_the_1080p_things_cell_lists_this_one(cell):
     for m in per_layer:
         if m["name"] in shared:
             assert m["workloads"][-1] == CELL, m["name"]      # appended
+
+
+# ------------------------------------------- the readers of the stage counters
+
+def _stage_window(with_cpu: bool) -> dict:
+    """A window of /metrics made by hand: ten device batches that answered
+    eighty requests.  ``with_cpu`` False: what the parent of PR 37 exposes,
+    the wall seconds alone and no sentinel, stall or tile counter."""
+    wall = {"batch.take": 0.3, "batch.form": 0.1, "batch.pad": 0.8,
+            "engine.h2d": 0.4, "engine.dispatch": 0.05, "engine.wait": 4.0,
+            "engine.fetch": 0.4, "batch.deliver": 2.5, "http.decode": 8.0,
+            "http.admit": 0.08, "http.encode": 1.6, "http.respond": 0.4}
+    cpu = {"batch.take": 0.01, "batch.form": 0.05, "batch.pad": 0.5,
+           "engine.h2d": 0.1, "engine.dispatch": 0.04, "engine.wait": 0.01,
+           "engine.fetch": 0.3, "batch.deliver": 1.5, "http.decode": 4.0,
+           "http.admit": 0.04, "http.encode": 0.8, "http.respond": 0.2,
+           "batch.deliver.sentinel": 0.9}
+    prom = {"raft_serving_device_calls_total": 10.0,
+            'raft_serving_requests_total{status="ok"}': 80.0,
+            'raft_serving_requests_total{status="timeout"}': 0.0,
+            "raft_serving_corr_keyblocks_visited_total": 1200.0,
+            "raft_serving_corr_keyblocks_possible_total": 5400.0}
+    for stage, v in wall.items():
+        prom[f'raft_serving_stage_seconds_total{{stage="{stage}"}}'] = v
+    if with_cpu:
+        prom['raft_serving_stage_seconds_total'
+             '{stage="batch.deliver.sentinel"}'] = 1.0
+        prom["raft_serving_corr_tiles_total"] = 1000.0
+        for stage, v in cpu.items():
+            prom[f'raft_serving_stage_cpu_seconds_total{{stage="{stage}"}}'] \
+                = v
+            prom[f'raft_serving_stalled_seconds_total{{stage="{stage}"}}'] = (
+                3.5 if stage == "engine.wait" else 0.0)
+    return prom
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("batcher_cpu_ms", 249.0),         # (.05 + .5 + .1 + .04 + .3 + 1.5) / 10
+    ("batcher_offcpu_ms", 136.0),      # (.05 + .3 + .01 + 1.0) / 10
+    ("deliver_sentinel_ms", 100.0), ("deliver_offcpu_ms", 100.0),
+    ("handler_cpu_ms", 63.0),          # (4 + .04 + .8 + .2) / 80
+    ("host_stall_s", 3.5), ("corr_bands_per_tile", 1.2),
+    # and what read the wall seconds before reads what it read: the
+    # sentinel's label is not ``stage="batch.deliver"``
+    ("batcher_serial_ms", 425.0)])
+def test_stage_counter_readers(bench_modules, metric, want):
+    readers = bench_modules["readers"]
+
+    def read(prom):
+        ctx = readers.RunContext(
+            config={}, traffic={}, cell={}, records=[], summary={},
+            prom_window=prom, max_batch=8, peak={}, memory_peak_bytes=0,
+            shapes={})
+        return readers.read_metric(BENCH, metric, ctx)
+
+    assert read(_stage_window(True)) == pytest.approx(want)
+    # the parent's window: nothing to read, and the old reader unmoved
+    assert read(_stage_window(False)) == (
+        pytest.approx(want) if metric not in STAGE_METRICS else None)
+    # a window in which nothing ran: no device call, no answer
+    idle = dict.fromkeys(_stage_window(True), 0.0)
+    assert read(idle) == (0.0 if metric == "host_stall_s" else None)
+
+
+@pytest.mark.parametrize("metric", STAGE_METRICS)
+def test_stage_metrics_are_listed_for_the_three_cells(cell, metric):
+    entry = next(m for m in cell["bench"]["per_layer"] if m["name"] == metric)
+    assert entry["workloads"] == ["things-sintel-closed",
+                                  "things-1080p-closed", CELL]
+    assert (entry["source"], entry["moves"]) == ("program_counter",
+                                                 "pairs_per_s")
+    assert entry["layer"] == {"handler_cpu_ms": "server host path",
+                              "corr_bands_per_tile": "kernels"}.get(
+                                  metric, "server")
+    with open(os.path.join(BENCH, "layer_metrics", metric + ".json")) as f:
+        spec = json.load(f)
+    # the file says what is read: stage labels, or the counters' names
+    assert spec["params"] and spec["note"]
 
 
 # ---------------------------------------------------------- the three readers

@@ -180,15 +180,51 @@ def test_device_slot_and_ambient_trace_ids():
     with host_stage("raft.engine.wait", sink) as b:
         pass
     assert spans.take_device_slot() == [
-        ("pair", "execute_dispatch", "engine.dispatch", a.t0, a.t1),
-        ("pair", "execute_block", "engine.wait", b.t0, b.t1)]
+        ("pair", "execute_dispatch", "engine.dispatch", a.t0, a.t1, a.cpu),
+        ("pair", "execute_block", "engine.wait", b.t0, b.t1, b.cpu)]
     assert a.t0 <= a.t1 <= b.t0 <= b.t1
+    assert a.c0 <= a.c1 <= b.c0 <= b.c1          # the thread's CPU clock
     assert spans.take_device_slot() is None          # take clears
     assert spans.current_trace_ids() == ()
     spans.set_current_trace_ids(("a", "b"))
     assert spans.current_trace_ids() == ("a", "b")
     spans.set_current_trace_ids(())
     assert spans.current_trace_ids() == ()
+
+
+def _sleeps():
+    time.sleep(0.05)
+
+
+def _spins():
+    a = np.ones((64, 64), np.float32)
+    end = time.thread_time() + 0.05         # 50 ms of this thread's CPU
+    while time.thread_time() < end:
+        np.isfinite(a + a).all()
+
+
+def _raises():
+    time.sleep(0.05)
+    raise RuntimeError("the body failed")
+
+
+@pytest.mark.parametrize("body,cpu_lo,cpu_hi", [
+    (_sleeps, 0.0, 0.010),            # asleep: the CPU clock stands still
+    (_spins, 0.05, None),             # at work: it runs, under the wall clock
+    (_raises, 0.0, 0.010)])           # stamped when the body raises, too
+def test_host_stage_stamps_cpu_seconds_beside_wall(body, cpu_lo, cpu_hi):
+    from raft_tpu.telemetry.trace import host_stage
+    seen = []
+    try:
+        with host_stage("raft.batch.pad", seen.append) as st:
+            body()
+    except RuntimeError:
+        assert body is _raises
+    assert seen == [st]
+    assert st.wall == st.t1 - st.t0 >= 0.05
+    assert st.cpu == st.c1 - st.c0
+    assert cpu_lo <= st.cpu <= (cpu_hi if cpu_hi is not None
+                                else st.wall + CPU_SLACK_MS / 1e3)
 
 
 # ----------------------------------------- serving integration (stubs) --
@@ -202,6 +238,10 @@ def _server(engine, **cfg):
     server.start()
     return server
 
+
+# what a stage's CPU seconds may read over its wall seconds, in ms: the two
+# clocks are read one after the other
+CPU_SLACK_MS = 5.0
 
 # what the top-level spans of an ok request may leave unaccounted, in ms:
 # the few statements between one stage's end and the next one's start, and
@@ -511,6 +551,10 @@ def test_pipelined_batches_keep_their_spans_and_their_ordinals(tmp_path):
                 assert ex["start_ms"] - 0.01 <= s["start_ms"] and \
                     s["start_ms"] + s["dur_ms"] \
                     <= ex["start_ms"] + ex["dur_ms"] + 0.01
+                # the engine's stages came through the batcher's slot with
+                # their CPU seconds: an h2d that sleeps used next to none
+                assert 0.0 <= s["cpu_ms"] <= s["dur_ms"] + CPU_SLACK_MS
+            assert kids[0]["cpu_ms"] < 10.0 <= kids[0]["dur_ms"], tid
             if tid[0] == "a":
                 assert 10.0 <= kids[0]["dur_ms"] < 60.0, tid
             else:
@@ -544,6 +588,165 @@ def test_pipelined_batches_keep_their_spans_and_their_ordinals(tmp_path):
         early = sorted(e for e in found["raft.batch.form"]
                        + found["raft.batch.take"] if e[0] < t_deliver1)
         assert [b for _, b, _ in early][-4:] == [2, 2, 2, 2], early
+        prom = _prom(server)
+        for stage in ("h2d", "dispatch", "wait", "fetch"):
+            wall, cpu = (prom[f'raft_serving_stage{c}_seconds_total'
+                              f'{{stage="engine.{stage}"}}']
+                         for c in ("", "_cpu"))
+            assert 0.0 <= cpu <= wall + CPU_SLACK_MS / 1e3, stage
+        assert prom['raft_serving_stage_seconds_total{stage="engine.h2d"}'] \
+            >= 0.07                         # 10 ms + 60 ms of sleep
+    finally:
+        server.stop()
+
+
+def _stage_family(prom, name):
+    """{stage label: value} of one of the stage families."""
+    return {k.split('stage="', 1)[1][:-2]: v for k, v in prom.items()
+            if k.startswith(name + "{")}
+
+
+def test_stage_families_share_their_labels_from_start_up():
+    """The exposition of a fresh server: wall, CPU and stalled seconds hold a
+    child for every host stage and for the sentinel inside deliver, at 0."""
+    from raft_tpu.telemetry.trace import HOST_STAGES, SENTINEL
+    server = _server(StubEngine())
+    try:
+        prom = _prom(server)
+        want = {name[len("raft."):] for name in HOST_STAGES} | {SENTINEL}
+        for family in ("raft_serving_stage_seconds_total",
+                       "raft_serving_stage_cpu_seconds_total",
+                       "raft_serving_stalled_seconds_total"):
+            assert set(_stage_family(prom, family)) == want, family
+        assert not any(
+            _stage_family(prom, "raft_serving_stalled_seconds_total").values())
+    finally:
+        server.stop()
+
+
+def test_sentinel_is_counted_inside_deliver_once_a_batch():
+    """The non-finite pass has a label of its own in both families, once a
+    device batch and inside ``batch.deliver``, whose counter, span and
+    ``X-Raft-Timings`` key stay what they were."""
+    from raft_tpu.telemetry.trace import SENTINEL
+    server = _server(DeviceStubEngine())
+    seen = []
+    record = server.stages.record
+
+    def spy(label, wall, cpu):
+        seen.append((label, wall, cpu))
+        record(label, wall, cpu)
+
+    server.stages.record = spy
+    try:
+        im = np.zeros((32, 48, 3), np.float32)
+        for tid in ("cc01", "cc02"):              # two device batches
+            status, timings, _ = _post_npz(server, im, tid)
+            assert status == 200
+            assert set(timings) == set(TOP_LEVEL) | set(DEVICE_CHILDREN)
+            rec = _finished_trace(server, tid)
+            names = [s["name"] for s in rec["spans"]]
+            assert names.count("deliver") == 1 and SENTINEL not in names
+            [deliver] = [s for s in rec["spans"] if s["name"] == "deliver"]
+            assert 0.0 <= deliver["cpu_ms"] <= deliver["dur_ms"] + CPU_SLACK_MS
+        sentinel = [(w, c) for label, w, c in seen if label == SENTINEL]
+        deliver = [(w, c) for label, w, c in seen if label == "batch.deliver"]
+        assert len(sentinel) == len(deliver) == 2
+        for (sw, sc), (dw, dc) in zip(sentinel, deliver):
+            assert 0.0 < sw <= dw and 0.0 <= sc <= dc + CPU_SLACK_MS / 1e3
+        prom = _prom(server)
+        for family, i in (("raft_serving_stage_seconds_total", 0),
+                          ("raft_serving_stage_cpu_seconds_total", 1)):
+            got = _stage_family(prom, family)
+            assert got[SENTINEL] == pytest.approx(sum(s[i] for s in sentinel))
+            assert got["batch.deliver"] == pytest.approx(
+                sum(d[i] for d in deliver))
+    finally:
+        server.stop()
+
+
+@pytest.fixture
+def run_log(tmp_path):
+    from raft_tpu.telemetry import events as tlm_events
+    log = tlm_events.RunLog(tmp_path)
+    tlm_events.set_current(log)
+    try:
+        yield lambda: [r for r in tlm_events.read_events(tmp_path)
+                       if r["event"] == "host_stall"]
+    finally:
+        tlm_events.set_current(None)
+        log.close()
+
+
+def _stalled(server):
+    return _stage_family(_prom(server), "raft_serving_stalled_seconds_total")
+
+
+def test_a_stage_that_stood_still_says_so(run_log, monkeypatch):
+    """One stage over the threshold: one ``host_stall`` event with the
+    stage, its wall and CPU seconds, the batch and the thread, its wall
+    seconds in ``raft_serving_stalled_seconds_total``, one log line."""
+    from raft_tpu.telemetry import trace
+    server = _server(DeviceStubEngine())       # h2d, dispatch, wait: 2 ms
+    lines = []
+    server.stages.log_fn = lines.append
+    try:
+        im = np.zeros((32, 48, 3), np.float32)
+        server.infer(im, im)
+        assert run_log() == [] and not any(_stalled(server).values())
+        monkeypatch.setattr(trace, "STALL_SECONDS", 0.0015)
+        server.infer(im, im)
+        monkeypatch.setattr(trace, "STALL_SECONDS", 2.0)
+        events = [e for e in run_log() if e["stage"] == "engine.wait"]
+        assert len(events) == 1
+        [ev] = events
+        assert ev["wall_s"] >= 0.002 and 0.0 <= ev["cpu_s"] <= ev["wall_s"]
+        assert ev["batch"] == 2 and ev["thread"] == "raft-serving-batcher"
+        assert "open_traces" not in ev
+        stalled = _stalled(server)
+        assert stalled["engine.wait"] == pytest.approx(ev["wall_s"], abs=1e-3)
+        for e in run_log():        # whatever else was slow enough, once each
+            assert stalled[e["stage"]] > 0.0
+        assert sum(ln.startswith("host stage stood still: stage=engine.wait")
+                   for ln in lines) == 1
+    finally:
+        server.stop()
+
+
+@pytest.mark.parametrize("sample,held_after,stalls", [
+    (1.0, None, 0),    # idle, then a request: open only as the take ends
+    (0.0, 0.1, 0),     # tracing off: nothing says a request was held
+    (1.0, 0.1, 1)])    # a handler held a request through the take's end
+def test_a_long_take_is_a_stall_only_while_a_request_is_held(
+        sample, held_after, stalls, run_log, monkeypatch):
+    """``batch.take`` with no request open waited for traffic: an idle
+    server, not a stall.  What counts of it is the end through which
+    requests were open without a break."""
+    from raft_tpu.telemetry import trace
+    monkeypatch.setattr(trace, "STALL_SECONDS", 0.05)
+    server = _server(StubEngine(), trace_sample=sample)
+    try:
+        assert server.tracer.held_s() == 0.0
+        held = None
+        if held_after is not None:
+            time.sleep(held_after)              # the batcher sits in take
+            held = server.tracer.start("pair")  # a handler reads a body ...
+        time.sleep(0.15)
+        im = np.zeros((32, 48, 3), np.float32)
+        server.infer(im, im)                    # ... the next reaches the queue
+        takes = [e for e in run_log() if e["stage"] == "batch.take"]
+        assert len(takes) == stalls
+        for ev in takes:
+            assert ev["open_traces"] == 2 and ev["batch"] == 1
+            assert ev["wall_s"] >= 0.25 and ev["cpu_s"] < 0.05
+            assert 0.15 <= ev["held_s"] <= ev["wall_s"] - 0.09
+            assert _stalled(server)["batch.take"] == pytest.approx(
+                ev["held_s"], abs=1e-3)
+        if not stalls:
+            assert _stalled(server)["batch.take"] == 0.0
+        if held is not None:
+            held.finish()
+        assert server.tracer.held_s() == 0.0
     finally:
         server.stop()
 
@@ -598,6 +801,14 @@ def test_stage_seconds_and_device_rows_add_up(clients, steps):
         for group in batches.values():
             assert max(s["dur_ms"] for r in group for s in r["spans"]
                        if s["name"] == "deliver") > 0
+        # every stage's CPU seconds lie under its wall seconds
+        wall = _stage_family(prom, "raft_serving_stage_seconds_total")
+        cpu = _stage_family(prom, "raft_serving_stage_cpu_seconds_total")
+        assert set(cpu) == set(wall)
+        for label in wall:
+            assert 0.0 <= cpu[label] <= wall[label] + CPU_SLACK_MS / 1e3, label
+        # the stub's h2d, dispatch and wait sleep: their thread was not running
+        assert cpu["engine.h2d"] < 0.5 * wall["engine.h2d"]
         real = prom['raft_serving_device_rows_total{kind="real"}']
         padded = prom['raft_serving_device_rows_total{kind="padded"}']
         assert real == clients == prom["raft_serving_batch_size_sum"]
