@@ -354,12 +354,37 @@ def phase_kernels(meter, sz: Sizes) -> None:
               f"ones by {errs['corr/rule_vs_all']:.3e}: every tap lies in one "
               f"band, rows left out add exact zeros")
         shapes = level_shapes(f2_levels)
+        plans = level_plans(h * w, w, shapes, radius)
         sched = lookup_schedules(coords, shapes, radius)
         errs["corr/scheduled_levels"] = [i for i, s in enumerate(sched)
                                          if s is not None]
-        visited, _, tiles = (int(v) for v in schedule_keyblocks(
-            sched, 1, level_plans(h * w, w, shapes, radius)))
+        visited, _, tiles, steps = (int(v) for v in schedule_keyblocks(
+            sched, 1, plans))
         errs["corr/bands_per_tile"] = round(visited / tiles, 4)
+        errs["corr/steps_per_tile"] = round(steps / tiles, 4)
+        # the flow above pulls tiles over two and three bands, so its banded
+        # launches take that many steps a tile; under a cell of smooth flow,
+        # as served, every tile keeps to one band and a launch's third grid
+        # dimension is 1 (PR 38): that launch too against the all-rows walk
+        base = coords_grid(1, h, w)
+        x, y = base[..., 0], base[..., 1]
+        smooth = base + 0.4 * jnp.stack(
+            [jnp.sin(x / 9.0 + y / 7.0), jnp.cos(x / 8.0 - y / 6.0)], -1)
+        _, _, tiles, steps = (int(v) for v in schedule_keyblocks(
+            lookup_schedules(smooth, shapes, radius), 1, plans))
+        errs["corr/steps_per_tile_smooth"] = round(steps / tiles, 4)
+        check(steps == tiles, f"a smooth flow at {h}x{w} takes {steps} grid "
+              f"steps for {tiles} (tile, level) pairs: one band a tile is "
+              f"one step a tile")
+        one_step = [run(functools.partial(
+            _fused_lookup_impl, radius=radius, q_blk=128, p_blk_target=4096,
+            interpret=sz.interpret, schedules=s), f1, f2_levels, smooth)
+            for s in (None, (None,) * levels)]
+        errs["corr/rule_vs_all_smooth"] = float(
+            np.abs(one_step[0] - one_step[1]).max())
+        check(errs["corr/rule_vs_all_smooth"] == 0.0,
+              f"the one-step launches at {h}x{w} differ from the all-rows "
+              f"ones by {errs['corr/rule_vs_all_smooth']:.3e}")
         # the window as the update block consumes it: float32 maps as above,
         # and bfloat16 maps over a float32-pooled pyramid as the served
         # program hands them over (level 0 one plane, the others three)
@@ -697,13 +722,14 @@ def phase_small(meter, sz: Sizes) -> None:
                 dtype=config.compute_dtype,
                 run_seconds=round(time.monotonic() - t0, 3))
         flow = np.asarray(flow, np.float32)
-        visited, possible, tiles = (int(v) for v in np.asarray(keyblocks))
+        visited, possible, tiles, steps = (int(v)
+                                           for v in np.asarray(keyblocks))
         finite = bool(np.isfinite(flow).all())
         check(flow.shape == (b, h, w, 2) and finite,
               f"small flow: shape {flow.shape}, finite {finite}")
-        check(0 < tiles <= visited <= possible,
-              f"band counts at radius 3: {visited} of {possible} steps, "
-              f"{tiles} (tile, level) pairs")
+        check(0 < tiles <= visited <= steps <= possible,
+              f"band counts at radius 3: {visited} of {possible} bands in "
+              f"{steps} grid steps, {tiles} (tile, level) pairs")
         row = b - 1                       # the batch's last row
         refs = bcheck.reference_flows(params, pairs, [row], mcfg, args.iters)
         own = bcheck.reference_flows(params, pairs, [row], mcfg, args.iters,
@@ -714,7 +740,8 @@ def phase_small(meter, sz: Sizes) -> None:
                                  lines.append)
         ph.note(precision_ratio=round(verdict["worst"] or 0.0, 4),
                 limit=limit, keyblock_share=round(visited / possible, 4),
-                bands_per_tile=round(visited / tiles, 4))
+                bands_per_tile=round(visited / tiles, 4),
+                steps_per_tile=round(steps / tiles, 4))
         check(verdict["correct"], "small program against "
               "benchmark/reference.py: " + "; ".join(lines))
 

@@ -67,7 +67,9 @@ class CorrLevelPlan:
     # from a multiple of ``band_granule`` (:func:`corr_band`)
     band_granule: int = 0       # g: rows of one fetched block of the band
     band_rows: int = 0          # R: a multiple of g, R * w2p <= p_blk_target
-    n_bands: int = 0            # K: the banded k grid dimension
+    # K: the bands the level has: a tile's entries in the schedule, and
+    # the most key steps a launch takes (it takes what its widest tile needs)
+    n_bands: int = 0
     band_rows_padded: int = 0   # map rows + the zero rows the last band reads
 
     @property

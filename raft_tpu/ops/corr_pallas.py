@@ -21,11 +21,15 @@ vector and cross-lane units):
   window row).  A grid step is handed the ``R`` rows from there as ``R / g``
   blocks of ``g`` rows — the same planes passed ``R / g`` times under plain
   blocked indexing, block ``S + i`` — so tiles that name the same band (the
-  next tile of a query row, the static grid's repeated entries) refetch
-  nothing and a tile multiplies and selects over the rows it needs, not over
-  one or two fixed row-blocks of 32 (PRs 26-35; TUNING.md, PR 36).  A tile
-  whose windows span more than ``R`` rows takes further bands, disjoint and
-  in order, up to the whole map: exact for any flow.
+  next tile of a query row, a repeated entry) refetch nothing and a tile
+  multiplies and selects over the rows it needs, not over one or two fixed
+  row-blocks of 32 (PRs 26-35; TUNING.md, PR 36).  A tile whose windows span
+  more than ``R`` rows takes further bands, disjoint and in order, up to the
+  whole map: exact for any flow.  The launch takes as many key steps a tile
+  as the tile of THIS lookup that needs most of them (a dynamic grid
+  dimension, :func:`schedule_steps`: one on served flow), not as many as the
+  level has bands: a step that visits nothing still cost 0.2-0.3 us of the
+  4-6 a tile (TUNING.md, PR 38).
 * A query's (2r+1)^2 bilinear window needs the (2r+2)^2 integer taps around
   it.  A visited block SELECTS the taps that lie in it, exactly
   (:func:`_window_taps`): each map row of the tile is gathered along its
@@ -367,18 +371,22 @@ def _level_kernel(f1_ref, coords_ref, f2_ref, out_ref, acc_ref, *, taps,
                 lambda: write(coords_ref, acc_ref, out_ref))
 
 
-def _band_kernel(S_ref, f1_ref, coords_ref, *refs, taps, write, granule):
+def _band_kernel(S_ref, f1_ref, coords_ref, *refs, taps, write, granule,
+                 stride):
     """Band-scheduled program: identical math to ``_level_kernel`` but the
     k-th grid step of a query tile visits the band of key rows that starts
-    at row ``S[b, j*K + k] * granule``, handed over as its granule blocks
-    ``refs[:-2]`` (then the output and the scratch).  The schedule repeats
-    its last needed band to fill the static grid; a repeated index means
-    the pipeline skips the DMA refetch and this body skips the compute, so
-    only bands the tile's bilinear windows overlap do work."""
+    at row ``S[b, j*stride + k] * granule``, handed over as its granule
+    blocks ``refs[:-2]`` (then the output and the scratch).  ``stride`` is
+    the schedule's entries a tile (the plan's ``n_bands``), which the grid's
+    third dimension (:func:`schedule_steps`) may be shorter than.  A tile
+    that needs fewer bands than the grid has steps finds its last one
+    repeated; a repeated index means the pipeline skips the DMA refetch and
+    this body skips the compute, so only bands the tile's bilinear windows
+    overlap do work."""
     *f2_refs, out_ref, acc_ref = refs
     b = pl.program_id(0)
     k = pl.program_id(2)
-    at = pl.program_id(1) * pl.num_programs(2) + k
+    at = pl.program_id(1) * stride + k
     sel = S_ref[b, at]
     prev = S_ref[b, at - jnp.minimum(k, 1)]      # step 0 has no previous
     _accumulate(acc_ref, k, k == pl.num_programs(2) - 1,
@@ -414,6 +422,23 @@ def _band_schedule(coords: jax.Array, level_scale: float, radius: int,
     ks = jnp.arange(plan.n_bands, dtype=jnp.int32)[None, None, :]
     return (s_lo[..., None]
             + n_g * jnp.minimum(ks, more[..., None])).astype(jnp.int32)
+
+
+def _tile_bands(schedule: jax.Array, plan) -> jax.Array:
+    """int32 ``[B, Qb]``: the bands each query tile visits under a
+    ``[B, Qb, K]`` band schedule — its last entry less its first, in bands,
+    plus one (entries run up from the first band and then repeat the
+    last)."""
+    return (schedule[..., -1] - schedule[..., 0]) // plan.band_granules + 1
+
+
+def schedule_steps(schedule: jax.Array, plan) -> jax.Array:
+    """int32 scalar, 1 .. ``plan.n_bands``: the key steps a query tile takes
+    in the launch of this ``[B, Qb, K]`` band schedule — the bands of the
+    tile that visits most.  The launch's third grid dimension: no tile needs
+    a later entry of its schedule, so the steps left out are ones every tile
+    would have skipped."""
+    return jnp.max(_tile_bands(schedule, plan))
 
 
 def _pad_queries(plan, f1: Optional[jax.Array], coords: jax.Array):
@@ -474,16 +499,18 @@ def lookup_schedules(coords: jax.Array, shapes, radius: int,
 
 
 def schedule_keyblocks(schedules, batch: int, plans) -> jax.Array:
-    """int32 ``[visited, possible, tiles]`` of one lookup of ``batch`` maps
-    under ``plans`` (:func:`level_plans`): the (query tile, band) grid steps
-    that did work, the grid steps, and the (query tile, level) pairs, so
-    that ``visited / tiles`` is the steps a tile took a level (1.0: every
-    tile's windows lay in one band).  A banded level's count is reduced
-    from the schedule its kernel is given (a tile's bands are its last
-    entry less its first, in bands, plus one: entries run up from the first
-    band and then repeat the last); a level walked without one visits all
-    the row-blocks it has, one where the map is one block."""
-    visited = jnp.int32(0)
+    """int32 ``[visited, possible, tiles, steps]`` of one lookup of ``batch``
+    maps under ``plans`` (:func:`level_plans`): the (query tile, band) grid
+    steps that did work, the steps a walk of every band of every level would
+    take, the (query tile, level) pairs, and the grid steps the launches
+    took, so that ``visited / tiles`` is the bands a tile visited a level
+    (1.0: every tile's windows lay in one band) and ``steps / tiles`` the
+    steps it was charged for (1.0: no launch took a step that did nothing).
+    A banded level's counts are reduced from the schedule its kernel is
+    given (:func:`_tile_bands`; its grid is :func:`schedule_steps` a tile);
+    a level walked without one visits all the row-blocks it has, one where
+    the map is one block."""
+    visited = steps = jnp.int32(0)
     possible = tiles = 0
     for plan, S in zip(plans, schedules):
         if plan is None:
@@ -491,13 +518,14 @@ def schedule_keyblocks(schedules, batch: int, plans) -> jax.Array:
         level_tiles = batch * (plan.qp // plan.t)
         tiles += level_tiles
         if S is None:
-            possible += level_tiles * plan.n_pblocks
-            visited = visited + level_tiles * plan.n_pblocks
+            blocks = level_tiles * plan.n_pblocks
+            possible += blocks
+            visited, steps = visited + blocks, steps + blocks
         else:
             possible += level_tiles * plan.n_bands
-            visited = visited + jnp.sum(
-                (S[..., -1] - S[..., 0]) // plan.band_granules + 1)
-    return jnp.stack([visited, jnp.int32(possible), jnp.int32(tiles)])
+            visited = visited + jnp.sum(_tile_bands(S, plan))
+            steps = steps + level_tiles * schedule_steps(S, plan)
+    return jnp.stack([visited, jnp.int32(possible), jnp.int32(tiles), steps])
 
 
 def pad_planes(f2: jax.Array, plan) -> jax.Array:
@@ -564,10 +592,12 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
 
     if schedule is not None:
         K, g, n_g = plan.n_bands, plan.band_granule, plan.band_granules
-        grid = (B, Qp // T, K)
-        if schedule.shape != grid:
+        if schedule.shape != (B, Qp // T, K):
             raise ValueError(f"level {level}: schedule {schedule.shape} is "
-                             f"not this plan's grid {grid}")
+                             f"not this plan's {(B, Qp // T, K)}")
+        # the key steps a tile: what the tile that needs most bands needs,
+        # known on the device alone (one on served flow, K at most)
+        grid = (B, Qp // T, schedule_steps(schedule, plan))
         # SMEM pads an array's last two dims to (8, 128) words: as [B, Qb, K]
         # eight pairs' schedule of 254 tiles x 3 blocks took the whole 1 MiB
         # (the chip compiler's refusal at 1080x1920, batch 8: PR 26), as
@@ -576,7 +606,8 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
         # the band as its n_g granule blocks: the same planes handed over
         # n_g times under plain blocked indexing, block S + i of g rows.
         # Tiles that name the same band (the next tile of a query row, a
-        # repeated entry) refetch nothing.
+        # repeated entry) refetch nothing.  K stays the schedule's stride
+        # whatever the grid's third dimension is.
         def granule(i, b, j, k, S):
             return 0, b, S[b, j * K + k] + i, 0
 
@@ -596,7 +627,7 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
         )
         out = pl.pallas_call(
             functools.partial(_band_kernel, taps=taps, write=write,
-                              granule=g),
+                              granule=g, stride=K),
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,

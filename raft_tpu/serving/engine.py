@@ -220,11 +220,12 @@ class InferenceEngine:
         self.encode_calls = 0     # fnet-pass accounting: 1 per encode call,
         self.stream_calls = 0     # 1 per stream step (the acceptance
         self.pair_calls = 0       # criterion's counters), 2 per pair row
-        # [visited, possible, tiles] of the lookup's band schedule over the
-        # pair batches run so far (RAFTOutput.corr_keyblocks; the server
-        # turns their growth into raft_serving_corr_keyblocks_*_total and
-        # raft_serving_corr_tiles_total)
-        self.corr_keyblocks = [0, 0, 0]
+        # [visited, possible, tiles, steps] of the lookup's band schedule
+        # over the pair batches run so far (RAFTOutput.corr_keyblocks; the
+        # server turns their growth into raft_serving_corr_keyblocks_*_total,
+        # raft_serving_corr_tiles_total and
+        # raft_serving_corr_grid_steps_total)
+        self.corr_keyblocks = [0, 0, 0, 0]
         self.weight_version = 1   # bumped by reload(); healthz reports it
         self.weight_tag = None
         self.warmup_seconds = 0.0
@@ -653,7 +654,8 @@ class InferenceEngine:
         outs = out if isinstance(out, (tuple, list)) else (out,)
         flow, *rest = self._fetch("pair", *outs)
         if self._counts_keyblocks:
-            counts = [int(v) for v in rest.pop()]  # visited, possible, tiles
+            # visited, possible, tiles, steps
+            counts = [int(v) for v in rest.pop()]
             with self._lock:
                 for i, v in enumerate(counts):
                     self.corr_keyblocks[i] += v

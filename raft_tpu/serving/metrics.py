@@ -116,21 +116,29 @@ def make_serving_metrics(registry: Registry, config,
         # the fused correlation lookup's band schedule (ops/corr_pallas
         # .schedule_keyblocks), reduced on the device from the schedules the
         # kernels were given and fetched with the flow: visited / possible
-        # is the share of its grid steps a batch did work in, visited /
-        # tiles the steps a query tile took a level (1.0: one band each)
+        # is the share of the bands a batch did work in, visited / tiles
+        # the bands a query tile visited a level (1.0: one band each),
+        # grid_steps / tiles the steps its launch took for it (1.0: none
+        # that did nothing)
         "keyblocks_visited": registry.counter(
             "raft_serving_corr_keyblocks_visited_total",
             "(query tile, band of key rows) grid steps of the correlation "
             "lookup that did work, over levels, iterations and pair batches"),
         "keyblocks_possible": registry.counter(
             "raft_serving_corr_keyblocks_possible_total",
-            "The lookup's grid steps: what it would visit had every tile "
-            "taken every band of every level"),
+            "What the lookup would visit had every tile taken every band of "
+            "every level"),
         "corr_tiles": registry.counter(
             "raft_serving_corr_tiles_total",
             "(query tile, level) pairs of the correlation lookup: visited / "
-            "tiles is the steps a tile took a level, 1.0 where every tile's "
-            "windows lay in one band"),
+            "tiles is the bands a tile visited a level, 1.0 where every "
+            "tile's windows lay in one band"),
+        "corr_grid_steps": registry.counter(
+            "raft_serving_corr_grid_steps_total",
+            "Grid steps the correlation lookup's launches took, visited or "
+            "skipped: a banded launch takes as many a tile as the tile of "
+            "the batch that needs most bands; grid_steps / tiles is 1.0 "
+            "where no launch took a step that did nothing"),
         "iters_used": (iters_used := registry.histogram(
             "raft_iters_used",
             "GRU iterations spent per request — fills only under "

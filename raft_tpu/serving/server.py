@@ -306,7 +306,7 @@ class FlowServer:
                 self.engine.compile_misses - before)
         # (an engine without the counts, a stub's say, zips to nothing)
         for name, was, now in zip(("keyblocks_visited", "keyblocks_possible",
-                                   "corr_tiles"),
+                                   "corr_tiles", "corr_grid_steps"),
                                   blocks, getattr(self.engine,
                                                   "corr_keyblocks", ())):
             if now > was:

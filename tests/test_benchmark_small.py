@@ -7,6 +7,7 @@ server warms four executables and the analyzer prices its chip; and the
 SERVED small program, fetched over HTTP from a ``FlowServer`` on the CPU,
 agrees with ``benchmark/reference.py`` on seeded weights."""
 
+import functools
 import http.client
 import importlib.util
 import json
@@ -33,11 +34,12 @@ SHARED_METRICS = (
     "encoders_ms", "upsample_ms", "stage_unmapped_share",
     "corr_keyblock_share", "corr_l0_ms", "corr_pooled_ms",
     "corr_window_roofline", "batch_staged_ahead_share")
-# PR 37: the host stages' CPU seconds, the stalls, and PR 36's tiles counter
+# PR 37: the host stages' CPU seconds, the stalls, and PR 36's tiles counter;
+# PR 38: the grid steps the lookup's launches took, over the same tiles
 STAGE_METRICS = (
     "batcher_cpu_ms", "batcher_offcpu_ms", "deliver_sentinel_ms",
     "deliver_offcpu_ms", "handler_cpu_ms", "host_stall_s",
-    "corr_bands_per_tile")
+    "corr_bands_per_tile", "corr_steps_per_tile")
 SHARED_METRICS += STAGE_METRICS
 
 
@@ -220,6 +222,7 @@ def _stage_window(with_cpu: bool) -> dict:
         prom['raft_serving_stage_seconds_total'
              '{stage="batch.deliver.sentinel"}'] = 1.0
         prom["raft_serving_corr_tiles_total"] = 1000.0
+        prom["raft_serving_corr_grid_steps_total"] = 1500.0
         for stage, v in cpu.items():
             prom[f'raft_serving_stage_cpu_seconds_total{{stage="{stage}"}}'] \
                 = v
@@ -228,24 +231,28 @@ def _stage_window(with_cpu: bool) -> dict:
     return prom
 
 
+def _read_counters(bench_modules, metric, prom):
+    """``metric`` read from a window that holds counters alone."""
+    readers = bench_modules["readers"]
+    ctx = readers.RunContext(
+        config={}, traffic={}, cell={}, records=[], summary={},
+        prom_window=prom, max_batch=8, peak={}, memory_peak_bytes=0,
+        shapes={})
+    return readers.read_metric(BENCH, metric, ctx)
+
+
 @pytest.mark.parametrize("metric,want", [
     ("batcher_cpu_ms", 249.0),         # (.05 + .5 + .1 + .04 + .3 + 1.5) / 10
     ("batcher_offcpu_ms", 136.0),      # (.05 + .3 + .01 + 1.0) / 10
     ("deliver_sentinel_ms", 100.0), ("deliver_offcpu_ms", 100.0),
     ("handler_cpu_ms", 63.0),          # (4 + .04 + .8 + .2) / 80
     ("host_stall_s", 3.5), ("corr_bands_per_tile", 1.2),
+    ("corr_steps_per_tile", 1.5),
     # and what read the wall seconds before reads what it read: the
     # sentinel's label is not ``stage="batch.deliver"``
     ("batcher_serial_ms", 425.0)])
 def test_stage_counter_readers(bench_modules, metric, want):
-    readers = bench_modules["readers"]
-
-    def read(prom):
-        ctx = readers.RunContext(
-            config={}, traffic={}, cell={}, records=[], summary={},
-            prom_window=prom, max_batch=8, peak={}, memory_peak_bytes=0,
-            shapes={})
-        return readers.read_metric(BENCH, metric, ctx)
+    read = functools.partial(_read_counters, bench_modules, metric)
 
     assert read(_stage_window(True)) == pytest.approx(want)
     # the parent's window: nothing to read, and the old reader unmoved
@@ -256,6 +263,25 @@ def test_stage_counter_readers(bench_modules, metric, want):
     assert read(idle) == (0.0 if metric == "host_stall_s" else None)
 
 
+@pytest.mark.parametrize("program,want", [
+    ("PR 38", 1.5),     # steps and tiles
+    ("PR 37", None),    # tiles, which corr_bands_per_tile reads, no steps
+    ("PR 35", None)])   # neither
+def test_corr_steps_per_tile_needs_its_counter(bench_modules, program, want):
+    """``corr_steps_per_tile`` is grid steps over tiles, and None (the
+    harness leaves the metric out, it does not raise) on a program that
+    exports no ``raft_serving_corr_grid_steps_total``: the parent, on which
+    the driver runs this reader too."""
+    prom = _stage_window(program != "PR 35")
+    if program != "PR 38":
+        prom.pop("raft_serving_corr_grid_steps_total", None)
+    got = _read_counters(bench_modules, "corr_steps_per_tile", prom)
+    assert got == (None if want is None else pytest.approx(want))
+    if program == "PR 37":
+        assert _read_counters(bench_modules, "corr_bands_per_tile",
+                              prom) == pytest.approx(1.2)
+
+
 @pytest.mark.parametrize("metric", STAGE_METRICS)
 def test_stage_metrics_are_listed_for_the_three_cells(cell, metric):
     entry = next(m for m in cell["bench"]["per_layer"] if m["name"] == metric)
@@ -264,7 +290,8 @@ def test_stage_metrics_are_listed_for_the_three_cells(cell, metric):
     assert (entry["source"], entry["moves"]) == ("program_counter",
                                                  "pairs_per_s")
     assert entry["layer"] == {"handler_cpu_ms": "server host path",
-                              "corr_bands_per_tile": "kernels"}.get(
+                              "corr_bands_per_tile": "kernels",
+                              "corr_steps_per_tile": "kernels"}.get(
                                   metric, "server")
     with open(os.path.join(BENCH, "layer_metrics", metric + ".json")) as f:
         spec = json.load(f)
@@ -497,6 +524,12 @@ def _served_flows(cell, bench_modules, seeded, tmp_path, dtype):
         assert prom["raft_serving_batch_size_sum"] == 2         # of two
         assert prom["raft_serving_compile_cache_misses_total"] == 0
         assert prom["raft_serving_corr_keyblocks_possible_total"] > 0
+        # the four band counts ride together: tiles <= visited <= the grid
+        # steps the launches took <= a walk of every band
+        assert (0 < prom["raft_serving_corr_tiles_total"]
+                <= prom["raft_serving_corr_keyblocks_visited_total"]
+                <= prom["raft_serving_corr_grid_steps_total"]
+                <= prom["raft_serving_corr_keyblocks_possible_total"])
         return [np.asarray(f).reshape(H, W, 2) for f in out]
     finally:
         sut.stop()

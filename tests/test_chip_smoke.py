@@ -236,6 +236,7 @@ def test_small_phase_rehearsed_on_the_cpu(capsys):
     assert rec["dtype"] == "bfloat16"
     assert 0 < rec["precision_ratio"] < rec["limit"] == 3.0
     assert 0 < rec["keyblock_share"] <= 1
+    assert 1 <= rec["bands_per_tile"] <= rec["steps_per_tile"]
     # the real sizes are the cell's
     real = chip_smoke.Sizes()
     assert (real.small_batch, real.small_hw) == (8, (1080, 1920))

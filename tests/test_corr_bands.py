@@ -2,8 +2,10 @@
 query tile fetches its own band of key rows (``kernel_plans.corr_level_plan``)
 and the launch gives what the walk over every row-block gives, bit for bit,
 for raft-things' and RAFT-S's radius and channels, bfloat16 and float32 maps,
-both output dtypes, and every kind of flow a band can meet; the ragged
-launch, which keeps the fixed row-blocks as its pages, gives what it gave.
+both output dtypes, and every kind of flow a band can meet; the launch's
+third grid dimension is the bands its widest tile needs (PR 38) and it gives
+what the launch of the plan's ``K`` steps a tile gave; the ragged launch,
+which keeps the fixed row-blocks as its pages, gives what it gave.
 (``tests/test_corr_schedule.py`` has the plan's table, the counts and the
 model; this file is its own so that the suite's workers share the load.)
 Pallas interpret mode."""
@@ -13,11 +15,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from raft_tpu.kernel_plans import corr_level_plan
+from raft_tpu.ops import corr_pallas
 from raft_tpu.ops.coords import coords_grid
 from raft_tpu.ops.corr import fmap2_pyramid, mask_ragged_rows
 from raft_tpu.ops.corr_pallas import (_fused_lookup_impl, _lookup_level,
                                       _ragged_lookup_level, level_plans,
-                                      level_shapes, lookup_schedules)
+                                      level_schedule, level_shapes,
+                                      lookup_schedules, schedule_steps)
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 
@@ -125,6 +130,95 @@ def test_banded_launch_equals_the_all_rows_launch(model, grid, kind, dtype,
         assert not by_row[..., 2:].any()
     else:
         assert np.abs(got).max() > 0.1
+
+
+# ------------------------------ the grid's third dimension: K' = 1 + max(more)
+
+#: the served grids themselves (1080x1920 and 440x1024 at 1/8): the key map
+#: whole, so level 0 has the cells' own ``K`` (9 and 4).  ``strip``: the
+#: query rows a kernel case runs (first row, rows), tile-aligned in the whole
+#: grid (64 x 240 = 120 x 128), or None for all of them: interpret mode takes
+#: 30 ms a grid step at 256 lanes, and a launch's steps go with its tiles.
+SERVED = {"135x240": ((135, 240), (64, 6)), "55x128": ((55, 128), None)}
+#: the query tile of batch row 1 that the flow singles out (its first query)
+SPECIAL = {"135x240": 124 * 128, "55x128": 27 * 128}
+SHORT_GRID_KINDS = ["smooth", "one-wide-tile", "outside", "all-bands"]
+
+
+def served_flow(kind, grid, B=2):
+    """Coordinates ``[B, h, w, 2]`` at a served grid for the four cases of
+    a launch's step count: (i) ``smooth``, under half a cell everywhere (one
+    band a tile: ``K'`` = 1); and the same with ONE tile of batch row 1
+    (``SPECIAL``) (ii) pulled 14 rows up and down (``one-wide-tile``: three
+    bands, every other tile one), (iii) wholly above the map (``outside``)
+    or (iv) from the map's first row to its last (``all-bands``: ``K'`` =
+    ``K``)."""
+    (h, w), _ = SERVED[grid]
+    base = coords_grid(B, h, w)
+    x, y = base[..., 0], base[..., 1]
+    cf = (base + 0.4 * jnp.stack([jnp.sin(x / 9.0 + y / 7.0),
+                                  jnp.cos(x / 8.0 - y / 6.0)], -1)
+          ).reshape(B, h * w, 2)
+    q = SPECIAL[grid] + jnp.arange(128)
+    if kind == "one-wide-tile":
+        cf = cf.at[1, q, 1].add(jnp.where(q % 2 == 0, -14.0, 14.5))
+    elif kind == "outside":
+        cf = cf.at[1, q, 1].set(-(h + 20.5))
+    elif kind == "all-bands":
+        cf = cf.at[1, q, 1].set(jnp.where(q % 2 == 0, 0.25, h - 1.0))
+    else:
+        assert kind == "smooth"
+    return cf.reshape(B, h, w, 2)
+
+
+@pytest.mark.parametrize("kind", SHORT_GRID_KINDS)
+@pytest.mark.parametrize("grid", list(SERVED))
+@pytest.mark.parametrize("model", list(MODELS))
+def test_short_grid_launch_equals_the_k_step_launch(model, grid, kind,
+                                                    monkeypatch):
+    """Level 0 of the served grids (``K`` = 9 and 4) at both models'
+    channels and radius, bfloat16 maps and windows as served: the launch
+    whose third grid dimension is ``schedule_steps`` (the bands of the tile
+    that needs most) equals the launch of ``K`` steps a tile (the parent's
+    grid, here by making ``schedule_steps`` say ``K``) and the all-rows
+    walk, bit for bit, with ``K'`` = 1, 3 (one wide tile in one batch row),
+    1 (a tile wholly outside the map) and ``K``."""
+    radius, c = MODELS[model]
+    (h, w), strip = SERVED[grid]
+    row0, rows = strip or (0, h)
+    B, Q = 2, rows * w
+    coords = served_flow(kind, grid, B)[:, row0:row0 + rows].reshape(B, Q, 2)
+    special = slice(SPECIAL[grid] - row0 * w, SPECIAL[grid] - row0 * w + 128)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(38))
+    f1 = jax.random.normal(k1, (B, Q, c), BF16)
+    f2 = jax.random.normal(k2, (B, h, w, c), BF16)
+    kw = dict(q_blk=128, p_blk_target=4096, grid_w=w)
+    plan = corr_level_plan(Q, h, w, radius=radius, **kw)
+    assert plan.n_bands == {"135x240": 9, "55x128": 4}[grid]
+    S = level_schedule(coords, plan, 0, radius)
+    bands = _tile_bands(S, plan)
+    wide = {"smooth": 1, "one-wide-tile": 3, "outside": 1,
+            "all-bands": plan.n_bands}[kind]
+    assert int(schedule_steps(S, plan)) == bands.max() == wide
+    # the special tile alone is wide: every other tile needs one band
+    assert (bands > 1).sum() == (wide > 1)
+    assert bands[1, special.start // 128] == wide
+
+    def launch(schedule):
+        return np.asarray(_lookup_level(
+            f1, f2, coords, radius, 0, interpret=True, schedule=schedule,
+            out_dtype=BF16, **kw)).view(np.uint16)
+
+    short, whole = launch(S), launch(None)
+    monkeypatch.setattr(corr_pallas, "schedule_steps",
+                        lambda S, plan: plan.n_bands)
+    np.testing.assert_array_equal(short, launch(S))
+    np.testing.assert_array_equal(short, whole)
+    assert short[0].any() and short[1, :special.start].any()
+    if kind == "outside":
+        assert not short[1, special].any()              # +0.0, all of it
+    else:
+        assert short[1, special].any()
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])

@@ -398,10 +398,10 @@ def test_window_schedule_model_forward():
     # relative to that
     np.testing.assert_allclose(np.asarray(out_a.flow), np.asarray(out_b.flow),
                                rtol=1e-3, atol=1e-3)
-    visited, possible, tiles = (int(v) for v in out_a.corr_keyblocks)
-    assert visited == possible == tiles   # one block a level at 4096
-    visited, possible, tiles = (int(v) for v in out_b.corr_keyblocks)
-    assert 0 < tiles <= visited < possible
+    visited, possible, tiles, steps = (int(v) for v in out_a.corr_keyblocks)
+    assert visited == possible == tiles == steps  # one block a level at 4096
+    visited, possible, tiles, steps = (int(v) for v in out_b.corr_keyblocks)
+    assert 0 < tiles <= visited <= steps <= possible and visited < possible
 
 
 def test_model_forward_at_the_training_crop_width():

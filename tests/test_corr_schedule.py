@@ -24,6 +24,9 @@ from raft_tpu.ops.corr_pallas import (_fused_lookup_impl, _lookup_level,
                                       level_shapes, lookup_schedules,
                                       schedule_keyblocks)
 
+# the served grids' four flows of a launch's step count (tests/ is on the path)
+from test_corr_bands import MODELS, SERVED, SHORT_GRID_KINDS, served_flow
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BF16, F32 = jnp.bfloat16, jnp.float32
 RADIUS = 4
@@ -207,11 +210,47 @@ def test_scheduled_lookup_equals_all_blocks_bit_for_bit(kind, dtype, out):
         assert np.abs(got[:, :, : W // 2]).max() == 0.0   # wholly outside
 
 
+def _counts_by_hand(coords, shapes, plans, sched, radius=RADIUS):
+    """``(visited, possible, tiles, steps)`` counted in numpy from the
+    coords: per tile the bands from the granule of its lowest touched row to
+    its highest (the parent's three numbers), and per banded level its tiles
+    times the bands of the tile that takes most, which is the launch's third
+    grid dimension."""
+    B, h, w, _ = coords.shape
+    cf = np.asarray(coords).reshape(B, h * w, 2)
+    want_v = want_p = want_t = want_s = 0
+    for lvl, (plan, (h2, w2)) in enumerate(zip(plans, shapes)):
+        tiles = plan.qp // plan.t
+        want_t += B * tiles
+        if sched[lvl] is None:
+            want_p += B * tiles
+            want_v += B * tiles
+            want_s += B * tiles
+            continue
+        want_p += B * tiles * plan.n_bands
+        cy = np.pad(cf[..., 1], ((0, 0), (0, plan.qp - h * w)), mode="edge")
+        top = np.floor(cy / 2 ** lvl).astype(int).reshape(B, tiles, -1) \
+            - radius
+        lo, hi = top.min(-1), top.max(-1) + 2 * radius + 1
+        most = 1
+        for b in range(B):
+            for j in range(tiles):
+                bands = 1                           # parked on row 0
+                if hi[b, j] >= 0 and lo[b, j] < h2:
+                    rows = np.clip([lo[b, j], hi[b, j]], 0, h2 - 1)
+                    start = rows[0] // plan.band_granule * plan.band_granule
+                    bands = int((rows[1] - start) // plan.band_rows + 1)
+                want_v += bands
+                most = max(most, bands)
+        want_s += B * tiles * most
+    return want_v, want_p, want_t, want_s
+
+
 def test_keyblock_counts_are_the_schedules_distinct_blocks():
-    """``schedule_keyblocks``' three numbers against a count made in numpy
+    """``schedule_keyblocks``' four numbers against a count made in numpy
     from the same coords: per tile the bands from the granule of its lowest
-    touched row to its highest, per level its grid steps, per lookup its
-    (tile, level) pairs."""
+    touched row to its highest, per level the steps of a walk of every band,
+    per lookup its (tile, level) pairs, per launch its grid steps."""
     B = 2
     coords = _flow_field("three-blocks", B)
     shapes = [(H, W), (H // 2, W // 2), (H // 4, W // 4), (H // 8, W // 8)]
@@ -220,31 +259,55 @@ def test_keyblock_counts_are_the_schedules_distinct_blocks():
                              p_blk_target=p_blk)
     plans = level_plans(H * W, W, shapes, RADIUS, 128, p_blk)
     assert [s is not None for s in sched] == [True, True, True, False]
-    visited, possible, n_tiles = (int(v) for v in schedule_keyblocks(
+    visited, possible, n_tiles, steps = (int(v) for v in schedule_keyblocks(
         sched, B, plans))
-    want_v = want_p = want_t = 0
-    cf = np.asarray(coords).reshape(B, H * W, 2)
-    for lvl, (plan, (h2, w2)) in enumerate(zip(plans, shapes)):
-        tiles = plan.qp // plan.t
-        want_t += B * tiles
-        if sched[lvl] is None:
-            want_p += B * tiles
-            want_v += B * tiles
-            continue
-        want_p += B * tiles * plan.n_bands
-        cy = np.pad(cf[..., 1], ((0, 0), (0, plan.qp - H * W)), mode="edge")
-        top = np.floor(cy / 2 ** lvl).astype(int).reshape(B, tiles, -1) - 4
-        lo, hi = top.min(-1), top.max(-1) + 9
-        for b in range(B):
-            for j in range(tiles):
-                if hi[b, j] < 0 or lo[b, j] >= h2:
-                    want_v += 1                     # parked on row 0
-                    continue
-                rows = np.clip([lo[b, j], hi[b, j]], 0, h2 - 1)
-                start = rows[0] // plan.band_granule * plan.band_granule
-                want_v += int((rows[1] - start) // plan.band_rows + 1)
-    assert (visited, possible, n_tiles) == (want_v, want_p, want_t)
-    assert n_tiles < visited < possible
+    assert (visited, possible, n_tiles, steps) == _counts_by_hand(
+        coords, shapes, plans, sched)
+    assert n_tiles < visited <= steps < possible
+
+
+@pytest.mark.parametrize("kind", SHORT_GRID_KINDS)
+@pytest.mark.parametrize("grid", list(SERVED))
+@pytest.mark.parametrize("model", list(MODELS))
+def test_grid_steps_are_counted_from_the_schedules(model, grid, kind):
+    """``tests/test_corr_bands.py``'s four flows at the served grids, all
+    four levels, both models' radius: the fourth number of
+    ``schedule_keyblocks`` is, by hand, each banded launch's tiles times the
+    bands of its widest tile (one-block levels one step a tile), and the
+    three numbers the parent counted are what it counted.  Smooth flow and a
+    tile off the map: a step a tile; one wide tile in one batch row holds
+    its level-0 launch (both rows) to three steps a tile while ``visited``
+    grows by two; a tile across the whole map brings every banded launch to
+    the walk of every band."""
+    radius, _ = MODELS[model]
+    (h, w), _ = SERVED[grid]
+    B = 2
+    coords = served_flow(kind, grid, B)
+    shapes = [(h >> i, w >> i) for i in range(4)]
+    sched = lookup_schedules(coords, shapes, radius)
+    plans = level_plans(h * w, w, shapes, radius)
+    banded = [p.banded for p in plans]
+    assert banded == [s is not None for s in sched]
+    assert banded == [True] * 3 + [False] if grid == "135x240" \
+        else [True] + [False] * 3
+    got = tuple(int(v) for v in schedule_keyblocks(sched, B, plans))
+    visited, possible, n_tiles, steps = got
+    assert got == _counts_by_hand(coords, shapes, plans, sched, radius)
+    per_level = B * plans[0].qp // plans[0].t
+    assert n_tiles == 4 * per_level
+    assert possible == per_level * sum(p.n_bands if p.banded else 1
+                                       for p in plans)
+    if kind in ("smooth", "outside"):
+        assert visited == steps == n_tiles          # K' = 1 at every level
+    elif kind == "all-bands":
+        assert steps == possible                    # K' = K at every level
+        assert visited == n_tiles + sum(p.n_bands - 1 for p in plans
+                                        if p.banded)
+    else:
+        # level 0: one tile of three bands, so three steps a tile for all
+        assert n_tiles + 2 <= visited < n_tiles + 8
+        assert steps >= n_tiles + 2 * per_level
+        assert steps < possible
 
 
 def test_a_schedule_of_another_plan_is_refused():
@@ -293,8 +356,9 @@ def test_model_under_the_schedule_agrees_with_the_benchmarks_reference():
                             / np.float32(255)) for i in (0, 1))
     out, _ = jax.jit(lambda w, a, b: raft_forward(w, a, b, pcfg))(wts, im1,
                                                                   im2)
-    visited, possible, tiles = (int(v) for v in out.corr_keyblocks)
-    assert 0 < tiles <= visited < 0.7 * possible
+    visited, possible, tiles, steps = (int(v) for v in out.corr_keyblocks)
+    assert 0 < tiles <= visited <= steps <= possible
+    assert visited < 0.7 * possible
     for i, (a, b) in enumerate(pairs):
         ref = np.asarray(reference.flow(wts, a, b, mcfg, 4))
         assert check.rel_epe(np.asarray(out.flow[i]), ref) < 1e-4

@@ -195,6 +195,14 @@ def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
     window = (2 * radius + 1) ** 2
     assert re.search(rf"= {out}\[1,{plan.qp},{window}\]\S* custom-call\(",
                      text), name
+    # a banded launch's third grid dimension is an operand, the int32 scalar
+    # ``schedule_steps`` reduced from the schedule that follows it ([B, Qb*K]
+    # with the plan's K as its stride); the all-rows walk has a static grid
+    dynamic = re.search(r"operand_layout_constraints=\{s32\[\], "
+                        r"s32\[1,(\d+)\]", text)
+    assert bool(dynamic) == scheduled, name
+    if scheduled:
+        assert int(dynamic.group(1)) == plan.qp // plan.t * plan.n_bands, name
     if dtypes:
         # the kernel was handed bfloat16 planes: nothing widened them first
         planes = 1 if dtypes is BF16_L0 else 3
