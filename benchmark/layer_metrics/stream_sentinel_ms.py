@@ -1,0 +1,3 @@
+"""Wall ms a device batch in raft.stream.sentinel (stage_ms_per_batch)."""
+
+from stream_metrics import stage_ms_per_batch as read  # noqa: F401

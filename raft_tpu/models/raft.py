@@ -655,9 +655,23 @@ def make_encode_fn(config: RAFTConfig):
     return fn
 
 
-def make_stream_step_fn(config: RAFTConfig, iters: Optional[int] = None):
+def _stream_outputs(out: RAFTOutput, fmap_cur, cnet_cur, adaptive: bool,
+                    keyblocks: bool) -> tuple:
+    """What every stream step returns: ``(flow, flow_lr, fmap_cur,
+    cnet_cur[, iters_used][, corr_keyblocks])``."""
+    res = (out.flow, out.flow_lr, fmap_cur, cnet_cur)
+    if adaptive:
+        res += (out.iters_used,)
+    if keyblocks:
+        res += (out.corr_keyblocks,)
+    return res
+
+
+def make_stream_step_fn(config: RAFTConfig, iters: Optional[int] = None,
+                        keyblocks: bool = False):
     """A jittable streaming step: ``(params, image, fmap_prev, cnet_prev,
-    flow_init) -> (flow, flow_lr, fmap_cur, cnet_cur[, iters_used])``.
+    flow_init) -> (flow, flow_lr, fmap_cur, cnet_cur[, iters_used]
+    [, corr_keyblocks])``.
 
     ONE device call advances a video session by one frame: encode the
     current frame (one fnet + one cnet pass — the previous frame's maps
@@ -665,7 +679,9 @@ def make_stream_step_fn(config: RAFTConfig, iters: Optional[int] = None):
     fmap_prev x fmap_cur and context from cnet_prev, and hand the current
     frame's maps back for the session cache.  ``iters_used`` is appended
     under an adaptive ``iters_policy`` (the serving engine's counted-
-    executable convention, engine.py)."""
+    executable convention, engine.py), and with ``keyblocks``
+    ``RAFTOutput.corr_keyblocks`` last, as :func:`make_inference_fn` has
+    it (dense Pallas lookup only)."""
     from ..config import adaptive_iters
     adaptive = adaptive_iters(config.iters_policy)
 
@@ -673,19 +689,19 @@ def make_stream_step_fn(config: RAFTConfig, iters: Optional[int] = None):
         fmap_cur, cnet_cur = encode_frame(params, image, config)
         out = forward_from_features(params, fmap_prev, fmap_cur, cnet_prev,
                                     config, iters=iters, flow_init=flow_init)
-        if adaptive:
-            return out.flow, out.flow_lr, fmap_cur, cnet_cur, out.iters_used
-        return out.flow, out.flow_lr, fmap_cur, cnet_cur
+        return _stream_outputs(out, fmap_cur, cnet_cur, adaptive, keyblocks)
     return fn
 
 
 def make_stream_batch_step_fn(config: RAFTConfig,
-                              iters: Optional[int] = None):
+                              iters: Optional[int] = None,
+                              keyblocks: bool = False):
     """A jittable CONTINUOUS-BATCHED streaming step over a device-resident
     slot pool: ``(params, images [b,H,W,3], fmap_buf [cap+1,h,w,C],
     cnet_buf [cap+1,h,w,D], flow_buf [cap+1,h,w,2], slots [b] int32,
     active [b] bool) -> (flow [b,H,W,2], flow_lr [b,h,w,2],
-    fmap_cur [b,h,w,C], cnet_cur [b,h,w,D][, iters_used [b]])``.
+    fmap_cur [b,h,w,C], cnet_cur [b,h,w,D][, iters_used [b]]
+    [, corr_keyblocks])`` (``keyblocks`` as in :func:`make_stream_step_fn`).
 
     ONE device call advances ``b`` *different* sessions by one frame
     each (LLM-continuous-batching applied to RAFT's cached maps — the
@@ -708,27 +724,25 @@ def make_stream_batch_step_fn(config: RAFTConfig,
 
     def fn(params, images, fmap_buf, cnet_buf, flow_buf, slots, active):
         fmap_cur, cnet_cur = encode_frame(params, images, config)
-        if quant:
-            # quant='int8': fmap_buf/cnet_buf arrive as (int8 vals,
-            # per-channel f32 scales) 2-leaf pytrees — dequant on gather;
-            # the flow seed buffer stays f32
-            fmap_prev = dequantize_rows(fmap_buf[0][slots],
-                                        fmap_buf[1][slots]
-                                        ).astype(fmap_cur.dtype)
-            cnet_prev = dequantize_rows(cnet_buf[0][slots],
-                                        cnet_buf[1][slots]
-                                        ).astype(cnet_cur.dtype)
-        else:
-            fmap_prev = fmap_buf[slots]
-            cnet_prev = cnet_buf[slots]
-        flow_init = flow_buf[slots]
+        with stage("raft/stream/gather"):
+            if quant:
+                # quant='int8': fmap_buf/cnet_buf arrive as (int8 vals,
+                # per-channel f32 scales) 2-leaf pytrees — dequant on
+                # gather; the flow seed buffer stays f32
+                fmap_prev = dequantize_rows(fmap_buf[0][slots],
+                                            fmap_buf[1][slots]
+                                            ).astype(fmap_cur.dtype)
+                cnet_prev = dequantize_rows(cnet_buf[0][slots],
+                                            cnet_buf[1][slots]
+                                            ).astype(cnet_cur.dtype)
+            else:
+                fmap_prev = fmap_buf[slots]
+                cnet_prev = cnet_buf[slots]
+            flow_init = flow_buf[slots]
         out = forward_from_features(params, fmap_prev, fmap_cur, cnet_prev,
                                     config, iters=iters,
                                     flow_init=flow_init, active=active)
-        if adaptive:
-            return (out.flow, out.flow_lr, fmap_cur, cnet_cur,
-                    out.iters_used)
-        return out.flow, out.flow_lr, fmap_cur, cnet_cur
+        return _stream_outputs(out, fmap_cur, cnet_cur, adaptive, keyblocks)
     return fn
 
 

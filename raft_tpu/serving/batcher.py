@@ -48,7 +48,11 @@ of them goes to ``stream_group_fn`` (the coordinator's continuous-
 batched step: one device call advances the whole group, per-row
 non-finite sentinel + degrade-to-cold heal inside).  A popped run is
 always homogeneous: all-pairwise, all-advances (one bucket), or one
-open — the keys guarantee it.
+open — the keys guarantee it.  A stream step runs under the pairwise
+path's host stages (``raft.batch.form`` here, ``raft.batch.pad`` and the
+engine's inside the executor, ``raft.batch.deliver`` here) but NOT in
+the two-deep pipeline: it begins when the running pairwise batch has
+been delivered, and the device waits through its host chain.
 
 Thread model (SERVING.md "Threading model"): the batcher deliberately
 holds **no lock of its own** — single ownership IS its synchronization.
@@ -110,6 +114,31 @@ def _fresh_error(e: BaseException) -> BaseException:
         return type(e)(*e.args)
     except Exception:
         return e
+
+
+def planar_batch(bufs: list, i: int, frames: list,
+                 padded: int) -> np.ndarray:
+    """``frames`` ([1, H, W, C] each), and the last one again up to
+    ``padded`` rows, written into the byte buffer ``bufs[i]`` (grown to the
+    largest batch seen: fresh ones of a third of a GB a batch cost more in
+    page faults than the copy); returns its [padded, H, W, C] view.
+
+    The buffer is channel-planar ([n, C, H, W]): planar is how the chip
+    keeps an image (W on the lanes, no padded channel), so the runtime only
+    tiles what it is handed.  From an interleaved array it gathers every
+    third float in chunks, and writes an event for each chunk into a
+    profiler capture: 2.3 M a batch, minutes of ``stop_trace`` (PERF.md
+    §5)."""
+    h, w, c = frames[0].shape[1:]
+    dtype = frames[0].dtype
+    nbytes = padded * c * h * w * dtype.itemsize
+    if bufs[i].size < nbytes:
+        bufs[i] = np.empty(nbytes, np.uint8)
+    buf = bufs[i][:nbytes].view(dtype).reshape(padded, c, h, w)
+    for k, f in enumerate(frames):
+        np.copyto(buf[k], f[0].transpose(2, 0, 1))
+    buf[len(frames):] = buf[len(frames) - 1]
+    return buf.transpose(0, 2, 3, 1)
 
 
 class _BlockingCall:
@@ -349,11 +378,13 @@ class MicroBatcher:
                 f"stream step {r.id} abandoned by its handler"))
             return
         tr = r.trace
+        self._next_device_batch()
+        with host_stage("raft.batch.form", self._stage_done) as form:
+            self._device_call(1, 1)
         if tr is not None:
             tr.span("queue_wait", r.enqueued_at, r.dequeued_at)
-        self._next_device_batch()
-        self._device_call(1, 1)
-        t0 = time.monotonic()
+            tr.span(form.span, r.dequeued_at, form.t1, group=1, cpu=form.cpu)
+        t0 = form.t1
         err, flow, iters_used = None, None, None
         try:
             flow, iters_used = self.stream_fn(r)
@@ -369,36 +400,40 @@ class MicroBatcher:
         self._observe("stream_step_seconds", t1 - t0)
         self._observe("stream_step_batch", 1.0)
         self._observe("stream_step_occupancy", 1.0)
-        if tr is not None:
-            # spans BEFORE resolve/fail: the handler wakes on either and
-            # finishes the trace — a late span would hit a closed trace
-            eid = tr.span("execute", t0, t1,
-                          status=(tlm_spans.OK if err is None
-                                  else tlm_spans.status_of(err)),
-                          batch_real=1, batch_padded=1)
-            self._device_spans(tr, calls, eid)
-        if err is not None:
+        with host_stage("raft.batch.deliver", self._stage_done) as st:
+            if tr is not None:
+                # spans BEFORE resolve/fail: the handler wakes on either and
+                # finishes the trace — a late span would hit a closed trace
+                eid = tr.span("execute", t0, t1,
+                              status=(tlm_spans.OK if err is None
+                                      else tlm_spans.status_of(err)),
+                              batch_real=1, batch_padded=1)
+                self._device_spans(tr, calls, eid)
+            if err is not None:
+                if self.breaker is not None:
+                    self.breaker.record(False)
+                self._observe("requests", "error", 1)
+                r.fail(err)
+                if not isinstance(err, Exception):
+                    raise err
+                return
             if self.breaker is not None:
-                self.breaker.record(False)
-            self._observe("requests", "error", 1)
-            r.fail(err)
-            if not isinstance(err, Exception):
-                raise err
-            return
-        if self.breaker is not None:
-            self.breaker.record(True)
-        r.batch_real = r.batch_padded = 1
-        if iters_used is not None:
-            r.iters_used = int(np.asarray(iters_used).reshape(-1)[0])
-            self._observe("iters_used", float(r.iters_used))
-        self._observe("request_latency", time.monotonic() - r.enqueued_at)
-        self._observe("requests", "ok", 1)
-        self.served += 1
-        if flow is None:                 # session open: no pair yet
-            r.resolve(None)
-        else:
-            self._observe("pairs", 1.0)
-            r.resolve(unpad(flow[:1], r.pads)[0])
+                self.breaker.record(True)
+            r.batch_real = r.batch_padded = 1
+            if iters_used is not None:
+                r.iters_used = int(np.asarray(iters_used).reshape(-1)[0])
+                self._observe("iters_used", float(r.iters_used))
+            if flow is not None:             # (a session open has no pair)
+                self._observe("pairs", 1.0)
+                flow = unpad(flow[:1], r.pads)[0]
+            now = time.monotonic()
+            self._observe("request_latency", now - r.enqueued_at)
+            self._observe("requests", "ok", 1)
+            self.served += 1
+            if tr is not None:
+                tr.span(st.span, t1, now, row=0,
+                        cpu=time.thread_time() - st.c0)
+            r.resolve(flow)
 
     # -- continuous-batched stream advances --------------------------------
 
@@ -423,17 +458,18 @@ class MicroBatcher:
             group.append(r)
         if not group:
             return
-        n = len(group)
-        padded = self.pad_batch_to(min(n, self.max_batch))
-        traced = [r for r in group if r.trace is not None]
-        t_form1 = time.monotonic()
-        for r in traced:
-            r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
-            r.trace.span("batch_form", r.dequeued_at, t_form1, group=n)
-        self._observe_waste(group, padded)
         self._next_device_batch()
-        self._device_call(n, padded)
-        t0 = time.monotonic()
+        with host_stage("raft.batch.form", self._stage_done) as form:
+            n = len(group)
+            padded = self.pad_batch_to(min(n, self.max_batch))
+            self._observe_waste(group, padded)
+            self._device_call(n, padded)
+        for r in group:
+            if r.trace is not None:
+                r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
+                r.trace.span(form.span, r.dequeued_at, form.t1, group=n,
+                             cpu=form.cpu)
+        t0 = form.t1
         err, outcomes = None, None
         try:
             outcomes = self.stream_group_fn(group)
@@ -485,31 +521,40 @@ class MicroBatcher:
             if not isinstance(err, Exception):
                 raise err
             return
-        now = time.monotonic()
-        served = 0
-        for r, (flow, iters_used, rerr) in zip(group, outcomes):
-            self._observe("request_latency", now - r.enqueued_at)
-            r.batch_real, r.batch_padded = n, padded
-            if rerr is not None:
-                status = ("poisoned"
-                          if getattr(rerr, "trace_status", None)
-                          == tlm_spans.POISONED else "error")
+        with host_stage("raft.batch.deliver", self._stage_done) as st:
+            served = 0
+            for i, (r, (flow, iters_used, rerr)) in enumerate(
+                    zip(group, outcomes)):
+                r.batch_real, r.batch_padded = n, padded
+                if rerr is not None:
+                    self._observe("request_latency",
+                                  time.monotonic() - r.enqueued_at)
+                    status = ("poisoned"
+                              if getattr(rerr, "trace_status", None)
+                              == tlm_spans.POISONED else "error")
+                    if r.trace is not None:
+                        _exec_span(r.trace, tlm_spans.status_of(rerr))
+                    self._observe("requests", status, 1)
+                    r.fail(rerr)
+                    continue
+                if iters_used is not None:
+                    r.iters_used = int(iters_used)
+                    self._observe("iters_used", float(r.iters_used))
+                flow = unpad(flow[:1], r.pads)[0]
+                now = time.monotonic()
+                self._observe("request_latency", now - r.enqueued_at)
                 if r.trace is not None:
-                    _exec_span(r.trace, tlm_spans.status_of(rerr))
-                self._observe("requests", status, 1)
-                r.fail(rerr)
-                continue
-            if r.trace is not None:
-                _exec_span(r.trace, tlm_spans.OK)
-            if iters_used is not None:
-                r.iters_used = int(iters_used)
-                self._observe("iters_used", float(r.iters_used))
-            self._observe("requests", "ok", 1)
-            self.served += 1
-            served += 1
-            r.resolve(unpad(flow[:1], r.pads)[0])
-        if served:
-            self._observe("pairs", float(served))
+                    # spans BEFORE resolve, and a row's deliver from the end
+                    # of execute to its OWN resolve, as _deliver has them
+                    _exec_span(r.trace, tlm_spans.OK)
+                    r.trace.span(st.span, t1, now, row=i,
+                                 cpu=time.thread_time() - st.c0)
+                self._observe("requests", "ok", 1)
+                self.served += 1
+                served += 1
+                r.resolve(flow)
+            if served:
+                self._observe("pairs", float(served))
 
     # -- pairwise execution: retry -> bisect -> sentinel -------------------
 
@@ -612,30 +657,15 @@ class MicroBatcher:
 
     def _pad(self, job: "_PairJob"):
         """Write the group's pairs, and the last one again up to the batch
-        step, into the two buffers this batcher keeps: fresh ones of a
-        third of a GB a batch cost more in page faults than the copy.  They
-        hold a batch from here until its ``place`` has returned.
-
-        The buffers are channel-planar ([n, 3, H, W]) and the engine gets
-        their [n, H, W, 3] views: planar is how the chip keeps an image
-        (W on the lanes, no padded channel), so the runtime only tiles what
-        it is handed.  From an interleaved array it gathers every third
-        float in chunks, and writes an event for each chunk into a profiler
-        capture: 2.3 M a batch, minutes of ``stop_trace`` (PERF.md §5)."""
+        step, into the two buffers this batcher keeps
+        (:func:`planar_batch`).  They hold a batch from here until its
+        ``place`` has returned."""
         with host_stage("raft.batch.pad", self._stage_done) as st:
-            h, w, c = job.group[0].image1.shape[1:]
-            dtype = job.group[0].image1.dtype
-            nbytes = job.padded * c * h * w * dtype.itemsize
-            job.images = []
-            for i, attr in enumerate(("image1", "image2")):
-                if self._pad_bytes[i].size < nbytes:
-                    self._pad_bytes[i] = np.empty(nbytes, np.uint8)
-                buf = self._pad_bytes[i][:nbytes].view(dtype).reshape(
-                    job.padded, c, h, w)
-                for k, r in enumerate(job.group):
-                    np.copyto(buf[k], getattr(r, attr)[0].transpose(2, 0, 1))
-                buf[job.n:] = buf[job.n - 1]
-                job.images.append(buf.transpose(0, 2, 3, 1))
+            job.images = [
+                planar_batch(self._pad_bytes, i,
+                             [getattr(r, attr) for r in job.group],
+                             job.padded)
+                for i, attr in enumerate(("image1", "image2"))]
         return st
 
     def _work_on(self, job: "_PairJob") -> None:

@@ -193,12 +193,19 @@ class InferenceEngine:
                                      arena=(self.max_box if self.ragged
                                             else None))
             self._encode_fn = jax.jit(make_encode_fn(config))
-            mk_stream = (make_ragged_stream_step_fn if self.ragged
-                         else make_stream_step_fn)
-            mk_sbatch = (make_ragged_stream_batch_step_fn if self.ragged
-                         else make_stream_batch_step_fn)
-            self._stream_fn = jax.jit(mk_stream(config, iters=iters))
-            self._sbatch_fn = jax.jit(mk_sbatch(config, iters=iters))
+            if self.ragged:
+                self._stream_fn = jax.jit(
+                    make_ragged_stream_step_fn(config, iters=iters))
+                self._sbatch_fn = jax.jit(
+                    make_ragged_stream_batch_step_fn(config, iters=iters))
+            else:
+                # the stream kinds hand the key-block counts out beside
+                # their outputs wherever the pair kind does
+                kb = self._counts_keyblocks
+                self._stream_fn = jax.jit(
+                    make_stream_step_fn(config, iters=iters, keyblocks=kb))
+                self._sbatch_fn = jax.jit(make_stream_batch_step_fn(
+                    config, iters=iters, keyblocks=kb))
             # the pool buffers are DONATED into the scatter executables so
             # a commit updates rows in place (off-CPU; the CPU backend has
             # no donation, so skip it there and keep warmup logs quiet)
@@ -221,7 +228,8 @@ class InferenceEngine:
         self.stream_calls = 0     # 1 per stream step (the acceptance
         self.pair_calls = 0       # criterion's counters), 2 per pair row
         # [visited, possible, tiles, steps] of the lookup's band schedule
-        # over the pair batches run so far (RAFTOutput.corr_keyblocks; the
+        # over the pair batches and stream steps run so far
+        # (RAFTOutput.corr_keyblocks; the
         # server turns their growth into raft_serving_corr_keyblocks_*_total,
         # raft_serving_corr_tiles_total and
         # raft_serving_corr_grid_steps_total)
@@ -541,7 +549,9 @@ class InferenceEngine:
                     out = ex(staged, img, img, self._sizes_arg(b, None))
                 else:
                     out = ex(staged, img, img)
-                flow = np.asarray(out[0] if self.adaptive else out)
+                # flow[, iters_used][, key-block counts]: bare when alone
+                flow = np.asarray(out[0] if isinstance(out, (tuple, list))
+                                  else out)
                 if not np.all(np.isfinite(flow)):
                     raise ReloadMismatch(
                         "probe produced non-finite flow; rejecting swap")
@@ -586,6 +596,32 @@ class InferenceEngine:
         with host_stage("raft.engine.fetch", sink, call=kind):
             return tuple(np.asarray(a) for a in arrays)
 
+    def _h2d(self, kind: str, ex, first: int, *arrays) -> list:
+        """``raft.engine.h2d``: put ``arrays`` on the device as ``ex``'s
+        arguments ``first``, ``first + 1`` ..., the runtime's host relayout
+        included, and wait for them (what the call would transfer implicitly
+        before the device can start).  Once this returns the caller may
+        rewrite the arrays.  The runtime reads them by their strides: the
+        batcher hands over views of channel-planar buffers, which the
+        relayout has only to tile."""
+        import jax
+        sink = functools.partial(tlm_spans.record_device_stage, kind)
+        with host_stage("raft.engine.h2d", sink, call=kind):
+            if jax.default_backend() == "cpu":
+                # the CPU client aliases a host array it finds aligned
+                # instead of copying it: give it one nobody rewrites
+                arrays = [np.array(a) for a in arrays]
+            shardings = ex.input_shardings[0][first:first + len(arrays)]
+            return jax.block_until_ready(
+                jax.device_put(list(arrays), list(shardings)))
+
+    def _count_keyblocks(self, counts) -> None:
+        """Add one call's [visited, possible, tiles, steps] (fetched beside
+        its outputs) to ``corr_keyblocks``."""
+        with self._lock:
+            for i, v in enumerate(counts):
+                self.corr_keyblocks[i] += int(v)
+
     def _sizes_arg(self, n: int, sizes) -> np.ndarray:
         """Per-row [n, 2] int32 live-size metadata for a ragged device
         call.  None = every row live on the full max box (direct engine
@@ -603,13 +639,8 @@ class InferenceEngine:
 
     def place(self, bucket: Tuple[int, int], im1: np.ndarray,
               im2: np.ndarray, sizes=None) -> "PairCall":
-        """``h2d``: put the padded pair on the device, the runtime's host
-        relayout included, and wait for it (what the call would transfer
-        implicitly before the device can start).  Once this returns the
-        caller may rewrite ``im1`` / ``im2``.  The runtime reads them by
-        their strides: the batcher hands over views of channel-planar
-        buffers, which the relayout has only to tile."""
-        import jax
+        """Put the padded pair on the device (:meth:`_h2d`).  Once this
+        returns the caller may rewrite ``im1`` / ``im2``."""
         h, w = bucket
         n = im1.shape[0]
         ex = self._get_executable(self._key(h, w, n))
@@ -617,15 +648,7 @@ class InferenceEngine:
             self.pair_calls += 1
         if self.faults is not None:
             self.faults.pre_engine_call()
-        sink = functools.partial(tlm_spans.record_device_stage, "pair")
-        with host_stage("raft.engine.h2d", sink, call="pair"):
-            if jax.default_backend() == "cpu":
-                # the CPU client aliases a host array it finds aligned
-                # instead of copying it: give it one nobody rewrites
-                im1, im2 = np.array(im1), np.array(im2)
-            shardings = ex.input_shardings[0]
-            im1, im2 = jax.block_until_ready(jax.device_put(
-                [im1, im2], [shardings[1], shardings[2]]))
+        im1, im2 = self._h2d("pair", ex, 1, im1, im2)
         args = (self.params, im1, im2)
         if self.ragged:
             args += (self._sizes_arg(n, sizes),)
@@ -654,11 +677,7 @@ class InferenceEngine:
         outs = out if isinstance(out, (tuple, list)) else (out,)
         flow, *rest = self._fetch("pair", *outs)
         if self._counts_keyblocks:
-            # visited, possible, tiles, steps
-            counts = [int(v) for v in rest.pop()]
-            with self._lock:
-                for i, v in enumerate(counts):
-                    self.corr_keyblocks[i] += v
+            self._count_keyblocks(rest.pop())
         iters_used = rest[0] if self.adaptive else None
         if self.faults is not None:
             flow = self.faults.corrupt_rows(flow)
@@ -693,6 +712,7 @@ class InferenceEngine:
             self.encode_calls += 1
         if self.faults is not None:
             self.faults.pre_engine_call()
+        [image] = self._h2d("encode", ex, 1, image)
         # outputs stay device-resident (they are the session cache), so
         # there is no block-until-ready here — dispatch only
         return self._call("encode", ex, (self.params, image), wait=False)
@@ -712,16 +732,26 @@ class InferenceEngine:
             self.stream_calls += 1
         if self.faults is not None:
             self.faults.pre_engine_call()
+        [image] = self._h2d("stream", ex, 1, image)
         args = (self.params, image, fmap_prev, cnet_prev, flow_init)
         if self.ragged:
             args += (self._sizes_arg(n, sizes),)
         out = self._call("stream", ex, args)
-        flow, flow_lr, fmap, cnet = out[:4]
-        flow, flow_lr, *iters = self._fetch("stream", flow, flow_lr,
-                                            *out[4:])
+        flow, flow_lr, iters_used = self._fetch_stream(out)
         if self.faults is not None:
             flow = self.faults.corrupt_rows(flow)
-        return flow, flow_lr, fmap, cnet, iters[0] if iters else None
+        return flow, flow_lr, out[2], out[3], iters_used
+
+    def _fetch_stream(self, out) -> tuple:
+        """A finished stream step's host outputs ``(flow, flow_lr,
+        iters_used or None)`` of ``(flow, flow_lr, fmap, cnet[, iters_used]
+        [, key-block counts])``; the maps stay on the device and the counts
+        go to ``corr_keyblocks``."""
+        flow, flow_lr, *rest = self._fetch("stream", out[0], out[1],
+                                           *out[4:])
+        if self._counts_keyblocks:
+            self._count_keyblocks(rest.pop())
+        return flow, flow_lr, rest[0] if rest else None
 
     # -- the continuous-batched stream path (slot pool) --------------------
 
@@ -745,16 +775,15 @@ class InferenceEngine:
             self.stream_calls += int(np.asarray(active).sum())
         if self.faults is not None:
             self.faults.pre_engine_call()
+        [images] = self._h2d("stream", ex, 1, images)
         fbuf, cbuf, flbuf = self.pool.buffers(bucket)
         args = (self.params, images, fbuf, cbuf, flbuf,
                 np.asarray(slots, np.int32), np.asarray(active, bool))
         if self.ragged:
             args += (self._sizes_arg(b, sizes),)
         out = self._call("stream", ex, args)
-        flow, flow_lr, fmap_rows, cnet_rows = out[:4]
-        flow, flow_lr, *iters = self._fetch("stream", flow, flow_lr,
-                                            *out[4:])
-        iters_used = iters[0] if iters else None
+        fmap_rows, cnet_rows = out[2], out[3]
+        flow, flow_lr, iters_used = self._fetch_stream(out)
         if self.faults is not None:
             # chaos must poison a REAL row: padding rows (the suffix, by
             # the coordinator's construction) are discarded before the

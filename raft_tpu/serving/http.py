@@ -125,7 +125,7 @@ def npz_parts(**arrays):
     sink = _Parts()
     with zipfile.ZipFile(sink, "w", zipfile.ZIP_STORED) as zf:
         for name, arr in arrays.items():
-            arr = np.ascontiguousarray(arr)
+            arr = np.asarray(arr, order="C")   # (keeps a 0-d array 0-d)
             with zf.open(zipfile.ZipInfo(name + ".npy"), "w") as f:
                 np.lib.format.write_array_header_1_0(
                     f, np.lib.format.header_data_from_array_1_0(arr))
@@ -643,64 +643,90 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _post_stream(self):
         app = self.server_app
-        body = self._read_body()
+        bad = op = None
+        with host_stage("raft.http.decode") as st:
+            body = self._read_body()
+            if body is not None:
+                try:
+                    op, sid, image, deadline_ms = parse_stream_request(
+                        body, self.headers.get("Content-Type",
+                                               "application/json"))
+                except BadRequest as e:
+                    bad = e
+        # the trace is minted once the op is read (a close is bookkeeping
+        # and never traced) and begins where the decode did
+        tr = None
+        if op in ("open", "advance"):
+            tr = app.tracer.start("stream",
+                                  self.headers.get("X-Raft-Trace-Id"),
+                                  t0=st.t0)
+        app.stage_done(st, tr)
         if body is None:
             return
-        try:
-            op, sid, image, deadline_ms = parse_stream_request(
-                body, self.headers.get("Content-Type", "application/json"))
-        except BadRequest as e:
+        if bad is not None:
             app.count_request("bad_request")
-            self._send_json(400, {"error": str(e)})
+            self._send_json(400, {"error": str(bad)})
             return
         try:
             res = app.stream_call(op, sid, image, deadline_ms,
-                                  trace_id=self.headers.get(
-                                      "X-Raft-Trace-Id"),
-                                  finish_trace=False)
-        except RejectedError as e:
-            # includes UnknownSession (404) and SessionBusy (409) — the
-            # status (and any Retry-After + trace id) rides the exception
-            self._send_rejection(e)
-            return
-        except BadRequest as e:
-            app.count_request("bad_request")
-            self._send_error(400, str(e), e)
-            return
+                                  finish_trace=False, trace=tr)
         except Exception as e:
-            self._send_error(500, f"inference failed: {e}", e)
+            if tr is not None:
+                # what failed before the coordinator's step (draining, the
+                # breaker, an unknown or busy session) is closed here; what
+                # failed in it is closed already, and finish is idempotent
+                if getattr(e, "trace_id", None) is None:
+                    e.trace_id = tr.trace_id
+                tr.finish(tlm_spans.status_of(e))
+            if isinstance(e, RejectedError):
+                # includes UnknownSession (404) and SessionBusy (409) — the
+                # status (and any Retry-After + trace id) rides the exception
+                self._send_rejection(e)
+            elif isinstance(e, BadRequest):
+                app.count_request("bad_request")
+                self._send_error(400, str(e), e)
+            else:
+                self._send_error(500, f"inference failed: {e}", e)
             return
-        tr = res.pop("_trace", None)
+        res.pop("_trace", None)
         t_resp0 = res.pop("_finished_at", None)
         flow = res.pop("flow", None)
         if tr is not None and t_resp0 is not None:
             tr.span("respond", t_resp0, time.monotonic(), part="wake")
-        with _traced_send(app, tr) as (headers, timings):
-            if timings is not None:
-                meta = res.get("meta")
-                if meta is not None:
-                    meta["timings"] = timings
-            if "application/octet-stream" in (self.headers.get("Accept")
-                                              or ""):
-                buf = io.BytesIO()
+        # encode BEFORE the timings snapshot, so it reaches the header
+        npz = "application/octet-stream" in (self.headers.get("Accept")
+                                             or "")
+        meta = res.get("meta") or {}
+        with host_stage("raft.http.encode") as st:
+            if npz:
                 arrays = {"session": np.asarray(res["session"]),
                           "frame": np.asarray(res.get("frame", 0),
                                               np.int32)}
                 if flow is not None:
                     arrays["flow"] = flow
-                meta = res.get("meta") or {}
                 if "warm" in meta:
                     arrays["warm"] = np.asarray(meta["warm"])
                 if "iters_used" in meta:
                     arrays["iters_used"] = np.asarray(meta["iters_used"],
                                                       np.int32)
-                np.savez(buf, **arrays)
-                self._send(200, buf.getvalue(), "application/octet-stream",
+                payload = npz_parts(**arrays)
+            elif flow is not None:
+                payload = json.dumps(flow.tolist())
+        app.stage_done(st, tr)
+        with _traced_send(app, tr) as (headers, timings):
+            if npz:
+                self._send(200, payload, "application/octet-stream",
                            headers=headers)
-            else:
-                if flow is not None:
-                    res["flow"] = flow.tolist()
-                self._send_json(200, res, headers=headers)
+                return
+            if timings is not None and "meta" in res:
+                res["meta"]["timings"] = timings
+            body = json.dumps(res)
+            if flow is not None:
+                # the flow was serialised in the encode stage; the rest,
+                # which holds that stage's own time, is joined on here
+                body = '{"flow": %s, %s' % (payload, body[1:])
+            self._send(200, body.encode(), "application/json",
+                       headers=headers)
 
 
 def make_http_server(app, host: str, port: int) -> ThreadingHTTPServer:

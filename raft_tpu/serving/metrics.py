@@ -72,8 +72,9 @@ def make_serving_metrics(registry: Registry, config,
             "profiler annotation less its prefix: http.decode, http.admit, "
             "batch.take, batch.form, batch.pad, engine.h2d, "
             "engine.dispatch, engine.wait, engine.fetch, batch.deliver, "
-            "http.encode, http.respond) and, inside batch.deliver, "
-            "batch.deliver.sentinel",
+            "http.encode, http.respond; of a batched /v1/stream advance "
+            "also stream.sentinel, stream.seed, stream.commit) and, inside "
+            "batch.deliver, batch.deliver.sentinel",
             labelnames=("stage",)),
         # the same stages on the thread's own CPU clock (time.thread_time):
         # wall less CPU of a stage that waits for no device and no socket is
@@ -123,7 +124,8 @@ def make_serving_metrics(registry: Registry, config,
         "keyblocks_visited": registry.counter(
             "raft_serving_corr_keyblocks_visited_total",
             "(query tile, band of key rows) grid steps of the correlation "
-            "lookup that did work, over levels, iterations and pair batches"),
+            "lookup that did work, over levels, iterations and device batches "
+            "(pair batches and stream steps)"),
         "keyblocks_possible": registry.counter(
             "raft_serving_corr_keyblocks_possible_total",
             "What the lookup would visit had every tile taken every band of "
@@ -195,6 +197,13 @@ def make_stream_metrics(registry: Registry, store,
             "raft_stream_fnet_cache_misses_total",
             "Advances that cold-restarted (features evicted: two encoder "
             "passes, pairwise cost, correct flow)"),
+        "encoder_passes": registry.counter(
+            "raft_stream_encoder_passes_total",
+            "Encoder passes (fnet + cnet of one frame) on the stream path, "
+            "by the engine's own call counters: call=encode a session open "
+            "or the previous frame of a cold restart, call=stream the "
+            "current frame of an advance (one a row of a batched step)",
+            labelnames=("call",)),
         "evictions": registry.counter(
             "raft_stream_evictions_total",
             "Session evictions by reason: lru (features demoted past "
@@ -231,6 +240,8 @@ def make_stream_metrics(registry: Registry, store,
             buckets=tuple(i / 10 for i in range(1, 11))),
     }
     store.evictions = m["evictions"]
+    for call in ("encode", "stream"):     # both children from the start
+        m["encoder_passes"].labels(call)
     if buckets:
         pool = store.pool
         in_use = registry.gauge(

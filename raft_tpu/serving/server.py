@@ -236,7 +236,8 @@ class FlowServer:
                 store, sconfig, self.queue, stream_metrics,
                 self.count_request, faults=self.faults,
                 nonfinite=self._robustness["nonfinite"],
-                breaker=self.breaker, tracer=self.tracer)
+                breaker=self.breaker, tracer=self.tracer,
+                stage_done=self.stage_done)
             # the stream-step families are observed by the batcher (the
             # thread that owns the device), so they ride its metrics dict
             for k in ("steps", "step_seconds", "step_batch",
@@ -339,16 +340,29 @@ class FlowServer:
             place=step(engine.place), dispatch=step(engine.dispatch, True),
             ready=engine.ready, wait=engine.wait, fetch=step(engine.fetch))
 
+    def _stream_step(self, fn, arg):
+        """One stream step of the batcher: a device step, and the encoder
+        passes it took by the engine's own call counters
+        (``raft_stream_encoder_passes_total``)."""
+        was = [getattr(self.engine, f"{c}_calls", 0)
+               for c in ("encode", "stream")]
+        try:
+            return self._device_step("serve/stream", fn, arg, self.engine)
+        finally:
+            for call, before in zip(("encode", "stream"), was):
+                now = getattr(self.engine, f"{call}_calls", 0)
+                if now > before:
+                    self.streams.metrics["encoder_passes"].labels(
+                        call).inc(now - before)
+
     def _run_stream(self, req):
         """One solo session step (open, or the no-group fallback)."""
-        return self._device_step("serve/stream", self.streams.execute, req,
-                                 self.engine)
+        return self._stream_step(self.streams.execute, req)
 
     def _run_stream_group(self, group):
         """Continuous-batched stream step (coalesced same-bucket
         advances): one device batch."""
-        return self._device_step("serve/stream", self.streams.execute_group,
-                                 group, self.engine)
+        return self._stream_step(self.streams.execute_group, group)
 
     def engine_executables(self) -> int:
         return getattr(self.engine, "executables", 0)
@@ -702,11 +716,14 @@ class FlowServer:
 
     def stream_call(self, op: str, session_id, image, deadline_ms,
                     trace_id: Optional[str] = None,
-                    finish_trace: bool = True):
+                    finish_trace: bool = True, trace=None):
         """/v1/stream bridge: dispatch one open/advance/close to the
         stream coordinator (http handler threads).  ``close`` is pure
         bookkeeping and is never traced; open/advance follow the same
-        trace lifecycle as :meth:`infer` (the coordinator mints it)."""
+        trace lifecycle as :meth:`infer`: the HTTP handler hands its trace
+        in (``trace``: the decode span is in it, and a failure that the
+        coordinator's step does not see is the handler's to close), a
+        direct caller's is minted by the coordinator."""
         if self.streams is None:
             raise BadRequest("streaming is disabled on this server "
                              "(--max-sessions 0); use /v1/flow")
@@ -719,11 +736,12 @@ class FlowServer:
         self._admit()                     # breaker gate: shed 503 while open
         if op == "open":
             res = self.streams.open(image, deadline_ms, trace_id=trace_id,
-                                    finish_trace=finish_trace)
+                                    finish_trace=finish_trace, trace=trace)
         else:
             res = self.streams.advance(session_id, image, deadline_ms,
                                        trace_id=trace_id,
-                                       finish_trace=finish_trace)
+                                       finish_trace=finish_trace,
+                                       trace=trace)
         if finish_trace:
             res.pop("_trace", None)       # direct callers: already closed
             res.pop("_finished_at", None)

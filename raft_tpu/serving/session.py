@@ -82,8 +82,10 @@ def make_slot_commit_fn(quant: bool = False):
     """
     import jax.numpy as jnp
 
-    def fn(fmap_buf, cnet_buf, flow_buf, slots, fmap_rows, cnet_rows,
-           seed_rows, mask):
+    # (named: the device trace calls a program by its function,
+    # ``jit_slot_commit``, and the stream programs beside it are ``jit_fn``)
+    def slot_commit(fmap_buf, cnet_buf, flow_buf, slots, fmap_rows, cnet_rows,
+                    seed_rows, mask):
         def put(buf, rows):
             keep = mask.reshape((-1,) + (1,) * (rows.ndim - 1))
             return buf.at[slots].set(jnp.where(keep, rows, buf[slots]))
@@ -100,7 +102,7 @@ def make_slot_commit_fn(quant: bool = False):
                     put(flow_buf, seed_rows))
         return (put(fmap_buf, fmap_rows), put(cnet_buf, cnet_rows),
                 put(flow_buf, seed_rows))
-    return fn
+    return slot_commit
 
 
 def make_slot_poison_fn(quant: bool = False):

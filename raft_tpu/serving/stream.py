@@ -54,6 +54,7 @@ row).  A cold attempt that faults is terminal for that frame only.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -63,21 +64,29 @@ from ..data.pipeline import embed_to_shape, pad_to_shape
 from ..ops.warmstart import warm_start_seed
 from ..telemetry import events as tlm_events
 from ..telemetry import spans as tlm_spans
-from .batcher import NonFiniteOutput
+from ..telemetry.trace import host_stage
+from .batcher import NonFiniteOutput, planar_batch
 from .queue import (DeadlineExceeded, Draining, RejectedError, Request,
                     RequestQueue)
 from .session import Session, SessionStore
 
 
+# sink of the batcher thread's stages of a stream step: children of
+# ``execute`` as the engine's stages are, counted with them by the batcher
+_batch_stage = functools.partial(tlm_spans.record_device_stage, "stream")
+
+
 class UnknownSession(RejectedError):
     """Session id never existed, was closed, or aged out (TTL) — reopen."""
     http_status = 404
+    trace_status = tlm_spans.BAD_REQUEST     # the client's, as a 400 is
 
 
 class SessionBusy(RejectedError):
     """A frame for this session is already in flight (advances are
     strictly sequential: frame t's flow seeds frame t+1)."""
     http_status = 409
+    trace_status = tlm_spans.BAD_REQUEST
 
 
 class StreamRequest(Request):
@@ -126,7 +135,7 @@ class StreamCoordinator:
 
     def __init__(self, store: SessionStore, sconfig, queue: RequestQueue,
                  metrics: Dict, count_fn, faults=None, nonfinite=None,
-                 breaker=None, tracer=None):
+                 breaker=None, tracer=None, stage_done=None):
         self.store = store
         self.pool = store.pool
         self.sconfig = sconfig
@@ -137,6 +146,12 @@ class StreamCoordinator:
         self.nonfinite = nonfinite       # raft_nonfinite_outputs_total
         self.breaker = breaker           # CircuitBreaker or None
         self.tracer = tracer             # telemetry.spans.Tracer or None
+        # sink of the handler threads' host stages (FlowServer.stage_done:
+        # stage seconds + span), or None: the span alone
+        self.stage_done = stage_done
+        # the batcher thread's padded frames (planar_batch), grown to the
+        # largest stream batch seen
+        self._frames = [np.empty(0, np.uint8)]
         # ragged mixed-resolution mode (SERVING.md "Ragged serving"):
         # every device call runs at the shared max-box arena bucket with
         # per-row live sizes; sessions keep their ROUTED bucket for
@@ -176,7 +191,7 @@ class StreamCoordinator:
 
     def open(self, image: np.ndarray, deadline_ms: Optional[float],
              trace_id: Optional[str] = None,
-             finish_trace: bool = True) -> Dict:
+             finish_trace: bool = True, trace=None) -> Dict:
         from .http import BadRequest    # circular-free: http imports us not
         self.store.sweep()
         h, w = image.shape[0], image.shape[1]
@@ -190,7 +205,7 @@ class StreamCoordinator:
             with s.lock:
                 req = self._run_step(s, "open", image, deadline_ms,
                                      trace_id=trace_id,
-                                     finish_trace=finish_trace)
+                                     finish_trace=finish_trace, trace=trace)
         except BaseException:
             # no half-open sessions — but close AFTER releasing s.lock:
             # store.close takes the store lock, which the hierarchy orders
@@ -209,7 +224,7 @@ class StreamCoordinator:
     def advance(self, sid: Optional[str], image: np.ndarray,
                 deadline_ms: Optional[float],
                 trace_id: Optional[str] = None,
-                finish_trace: bool = True) -> Dict:
+                finish_trace: bool = True, trace=None) -> Dict:
         from .http import BadRequest
         self.store.sweep()
         s = self.store.get(sid) if sid else None
@@ -232,7 +247,7 @@ class StreamCoordinator:
                     f"need a new session")
             req = self._run_step(s, "advance", image, deadline_ms,
                                  trace_id=trace_id,
-                                 finish_trace=finish_trace)
+                                 finish_trace=finish_trace, trace=trace)
         finally:
             s.lock.release()
             # a close() that raced this advance deferred the slot free
@@ -259,39 +274,49 @@ class StreamCoordinator:
     def _run_step(self, s: Session, op: str, image: np.ndarray,
                   deadline_ms: Optional[float],
                   trace_id: Optional[str] = None,
-                  finish_trace: bool = True) -> StreamRequest:
+                  finish_trace: bool = True, trace=None) -> StreamRequest:
         """Pad, enqueue, block until the batcher resolves — the stream
         twin of FlowServer.infer, same deadline/shed/drain accounting and
-        the same trace lifecycle: the trace closes HERE on every failure
-        path (status from the exception); on success the HTTP handler
-        finishes it after the respond span (``finish_trace=False``), or
-        this method does for direct callers."""
+        the same trace lifecycle: the HTTP handler mints the trace once it
+        has read the op (``trace``), a direct caller's is minted here; it
+        closes HERE on every failure path (status from the exception); on
+        success the HTTP handler finishes it after the respond span
+        (``finish_trace=False``), or this method does for direct
+        callers."""
         from .http import BadRequest
-        tr = (self.tracer.start("stream", trace_id)
-              if self.tracer is not None else None)
-        t0 = time.monotonic()
+        tr = trace
+        if tr is None and self.tracer is not None:
+            tr = self.tracer.start("stream", trace_id)
         try:
-            dl = (self.sconfig.default_deadline_ms if deadline_ms is None
-                  else min(deadline_ms, self.sconfig.default_deadline_ms))
-            if dl <= 0:
-                raise BadRequest(f"deadline_ms must be positive, got {dl}")
-            imp, pads = pad_to_shape(image[None].astype(np.float32),
-                                     s.bucket)
-            if self.dev_box is not None:
-                # ragged: zero-embed the routed-bucket frame corner-
-                # anchored into the max-box arena and fold the embedding
-                # into pads, so unpad() recovers the original resolution
-                # straight from the max-box flow
-                (bh, bw), (mh, mw) = s.bucket, self.dev_box
-                imp = embed_to_shape(imp, self.dev_box)
-                t, b_, l_, r_ = pads
-                pads = (t, b_ + mh - bh, l_, r_ + mw - bw)
-            req = StreamRequest(s, op, imp, pads,
-                                deadline=time.monotonic() + dl / 1000.0,
-                                qbucket=self.dev_box)
-            req.trace = tr
-            if tr is not None:
-                tr.span("admit", t0, time.monotonic(), op=op,
+            with host_stage("raft.http.admit") as st:
+                dl = (self.sconfig.default_deadline_ms if deadline_ms is None
+                      else min(deadline_ms,
+                               self.sconfig.default_deadline_ms))
+                if dl <= 0:
+                    raise BadRequest(
+                        f"deadline_ms must be positive, got {dl}")
+                # (no copy of a frame that is float32 already, as the HTTP
+                # edge's are, and none by the pad where the frame is the
+                # bucket's size)
+                imp, pads = pad_to_shape(
+                    image[None].astype(np.float32, copy=False), s.bucket)
+                if self.dev_box is not None:
+                    # ragged: zero-embed the routed-bucket frame corner-
+                    # anchored into the max-box arena and fold the
+                    # embedding into pads, so unpad() recovers the original
+                    # resolution straight from the max-box flow
+                    (bh, bw), (mh, mw) = s.bucket, self.dev_box
+                    imp = embed_to_shape(imp, self.dev_box)
+                    t, b_, l_, r_ = pads
+                    pads = (t, b_ + mh - bh, l_, r_ + mw - bw)
+                req = StreamRequest(s, op, imp, pads,
+                                    deadline=time.monotonic() + dl / 1000.0,
+                                    qbucket=self.dev_box)
+                req.trace = tr
+            if self.stage_done is not None:
+                self.stage_done(st, tr, op=op, session=s.id)
+            elif tr is not None:
+                tr.span(st.span, st.t0, st.t1, cpu=st.cpu, op=op,
                         session=s.id)
             try:
                 self.queue.submit(req)
@@ -334,7 +359,9 @@ class StreamCoordinator:
         that owns the device."""
         s = req.session
         if req.stream_op == "open":
-            fmap, cnet = engine.run_encode(self._dev(s), req.image1)
+            with host_stage("raft.batch.pad", _batch_stage):
+                image = planar_batch(self._frames, 0, [req.image1], 1)
+            fmap, cnet = engine.run_encode(self._dev(s), image)
             self._attach(s, engine, fmap, cnet, flow_lr=None)
             s.last_image = req.image1
             return None, None
@@ -397,8 +424,9 @@ class StreamCoordinator:
         bucket = self._dev(s0)
         n = len(reqs)
         padded = self.sconfig.pad_batch_to(min(n, self.sconfig.max_batch))
-        images = np.concatenate([r.image1 for r in reqs]
-                                + [reqs[-1].image1] * (padded - n))
+        with host_stage("raft.batch.pad", _batch_stage):
+            images = planar_batch(self._frames, 0,
+                                  [r.image1 for r in reqs], padded)
         slots = np.asarray([r.session.slot for r in reqs]
                            + [self.pool.scratch] * (padded - n), np.int32)
         active = np.asarray([True] * n + [False] * (padded - n), bool)
@@ -429,23 +457,26 @@ class StreamCoordinator:
         if self.breaker is not None:
             self.breaker.record(True)
         h, w = bucket
-        row_ok = np.array([np.isfinite(flow[i]).all()
-                           and np.isfinite(flow_lr[i]).all()
-                           for i in range(n)], bool)
+        with host_stage("raft.stream.sentinel", _batch_stage):
+            row_ok = np.array([np.isfinite(flow[i]).all()
+                               and np.isfinite(flow_lr[i]).all()
+                               for i in range(n)], bool)
         # commit BEFORE touching host state, AFTER the sentinel: finite
         # rows scatter their updated maps + next-frame warm-start seed
         # into their slots; rejected and padding rows write their old
         # values back (mask), so a poisoned output can never be cached
-        seeds = np.zeros((padded, h // 8, w // 8, 2), np.float32)
-        for i in np.flatnonzero(row_ok):
-            seeds[i] = self._mask_seed(
-                warm_start_seed(flow_lr[i:i + 1], (h // 8, w // 8))[0],
-                reqs[i].session.bucket)
+        with host_stage("raft.stream.seed", _batch_stage):
+            seeds = np.zeros((padded, h // 8, w // 8, 2), np.float32)
+            for i in np.flatnonzero(row_ok):
+                seeds[i] = self._mask_seed(
+                    warm_start_seed(flow_lr[i:i + 1], (h // 8, w // 8))[0],
+                    reqs[i].session.bucket)
         mask = active.copy()
         mask[:n] &= row_ok
         try:
-            engine.commit_stream(bucket, slots, fmap_rows, cnet_rows,
-                                 seeds, mask)
+            with host_stage("raft.stream.commit", _batch_stage):
+                engine.commit_stream(bucket, slots, fmap_rows, cnet_rows,
+                                     seeds, mask)
         except Exception:
             # a failed commit leaves the (donated) bucket buffers dead;
             # commit_stream already rebuilt them zeroed — now demote

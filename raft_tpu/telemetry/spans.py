@@ -163,12 +163,12 @@ class RequestTrace:
                  "status", "_spans", "_lock", "_closed")
 
     def __init__(self, tracer: "Tracer", trace_id: str, kind: str,
-                 sampled: bool):
+                 sampled: bool, t0: Optional[float] = None):
         self.tracer = tracer
         self.trace_id = trace_id
         self.kind = kind                 # request class: "pair" | "stream"
         self.sampled = sampled
-        self.t0 = time.monotonic()
+        self.t0 = time.monotonic() if t0 is None else t0
         self.status: Optional[str] = None
         self._spans: List[dict] = []
         self._lock = threading.Lock()
@@ -256,8 +256,11 @@ class Tracer:
         with self._lock:
             return time.monotonic() - self._open_since if self._open else 0.0
 
-    def start(self, kind: str,
-              trace_id: Optional[str] = None) -> Optional[RequestTrace]:
+    def start(self, kind: str, trace_id: Optional[str] = None,
+              t0: Optional[float] = None) -> Optional[RequestTrace]:
+        """Mint a trace; ``t0`` (monotonic) where the request began before
+        it could be minted (/v1/stream reads its body to learn whether the
+        op is one that is traced)."""
         s = self.sample
         if s <= 0.0:
             return None
@@ -272,7 +275,8 @@ class Tracer:
                 sampled = self._acc >= 1.0 - 1e-9
                 if sampled:
                     self._acc -= 1.0
-        return RequestTrace(self, clean_trace_id(trace_id), kind, sampled)
+        return RequestTrace(self, clean_trace_id(trace_id), kind, sampled,
+                            t0)
 
     def _finish(self, trace: RequestTrace,
                 status: Optional[str] = None) -> Optional[dict]:

@@ -83,7 +83,10 @@ def stage(name: str):
 # annotation name -> request span name (OBSERVABILITY.md "Host stages").
 # The counter label is the annotation name less its ``raft.`` prefix.  A
 # stage with no span (``take``: the batcher waits for requests, which belongs
-# to no request) still writes its annotation and counter.
+# to no request) still writes its annotation and counter.  ``raft.stream.*``
+# are a batched advance's host chain between its two device calls
+# (serving/stream.py ``_warm_batch``), children of ``execute`` as the
+# engine's stages are.
 HOST_STAGES: Dict[str, Optional[str]] = {
     "raft.http.decode": "decode",
     "raft.http.admit": "admit",
@@ -94,6 +97,9 @@ HOST_STAGES: Dict[str, Optional[str]] = {
     "raft.engine.dispatch": "execute_dispatch",
     "raft.engine.wait": "execute_block",
     "raft.engine.fetch": "execute_fetch",
+    "raft.stream.sentinel": "execute_sentinel",
+    "raft.stream.seed": "execute_seed",
+    "raft.stream.commit": "execute_commit",
     "raft.batch.deliver": "deliver",
     "raft.http.encode": "encode",
     "raft.http.respond": "respond",

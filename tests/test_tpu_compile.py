@@ -505,3 +505,89 @@ def test_analyzer_prices_the_small_served_programs_temporaries(
     priced = budget.pair_temp_bytes(config, 1080, 1920, 8)
     assert abs(priced - temp) / temp < 0.05, (priced, temp)
     assert temp < 12e9
+
+
+# ------------------------------- the served stream batch programs (PR 39)
+
+@pytest.fixture(scope="module")
+def served_stream_programs(one_chip):
+    """What ``things-stream-sessions`` runs a batched advance with, as
+    ``benchmark/configs/raft-things-1080p-stream.json`` serves it: the stream
+    batch program at 8 x 1080x1920 over a pool of 32 + 1 slots, key-block
+    counts beside its outputs, and the slot commit at the same width with
+    the pool donated.  About three quarters of a minute."""
+    import json
+
+    from raft_tpu import cli
+    from raft_tpu.models import init_raft
+    from raft_tpu.models.raft import make_stream_batch_step_fn
+    from raft_tpu.serving.session import make_slot_commit_fn
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "raft-things-1080p-stream.json")) as f:
+        serve_args = [str(a) for a in json.load(f)["serve_args"]]
+    args = cli.parse_args(["-m", "serve"] + serve_args)
+    config = cli._make_config(args)
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config)))
+    b, rows = args.max_batch, args.max_sessions + 1
+    maps = s((rows,) + HD + (256,), jnp.bfloat16)
+    seeds = s((rows,) + HD + (2,), jnp.float32)
+    new_maps = s((b,) + HD + (256,), jnp.bfloat16)
+    new_seeds = s((b,) + HD + (2,), jnp.float32)
+    slots, mask = s((b,), jnp.int32), s((b,), jnp.bool_)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        step = jax.jit(make_stream_batch_step_fn(
+            config, iters=args.iters, keyblocks=True)).lower(
+                params, s((b, 1080, 1920, 3), jnp.float32), maps, maps,
+                seeds, slots, mask).compile()
+        commit = jax.jit(make_slot_commit_fn(), donate_argnums=(0, 1, 2)
+                         ).lower(maps, maps, seeds, slots, new_maps,
+                                 new_maps, new_seeds, mask).compile()
+    return step, commit
+
+
+def test_stream_batch_program_fits_a_v5e_beside_its_pool(
+        served_stream_programs):
+    """Four lookup launches and the fused GRU in the loop, as the pair
+    program has them; the temporaries of 8 advances, the pool and the
+    outputs together leave the chip room (the cell reads its peak)."""
+    step, commit = served_stream_programs
+    assert step.as_text().count("tpu_custom_call") >= 5
+    m = step.memory_analysis()
+    held = m.temp_size_in_bytes + m.argument_size_in_bytes \
+        + m.output_size_in_bytes
+    assert 4 * 2 ** 30 < held < 12e9, held
+    # the pool is 33 rows of 33.4 MB: two bfloat16 maps and a float32 seed
+    pool = 33 * 135 * 240 * (2 * 256 * 2 + 2 * 4)
+    assert m.argument_size_in_bytes > pool
+    assert commit.memory_analysis().alias_size_in_bytes >= pool - 4096
+
+
+def test_slot_io_metrics_find_the_gather_and_the_commit_program(
+        served_stream_programs):
+    """What ``benchmark/stream_metrics.py::slot_io_ms`` joins on: the stream
+    batch program's own instructions (the map's ``loop`` 0) under
+    ``raft/stream/gather`` hold the compiler's row loops, none of them
+    inside the update loop; and the trace will call the commit program
+    ``jit_slot_commit``, not one more ``jit_fn``."""
+    from raft_tpu.telemetry.trace import instruction_stages
+    step, commit = served_stream_programs
+    insts = instruction_stages(step.as_text())
+    gather = {n: rec for n, rec in insts.items()
+              if re.search(r"(^|/)stream/gather(/|$)", rec["stage"] or "")}
+    own = [n for n, rec in gather.items() if rec["loop"] == 0]
+    assert own and any(n.startswith(("while", "fusion")) for n in own), own
+    assert any("135,240" in gather[n]["text"] for n in own)
+    # the lookup's launches are still found by their names, in the loop
+    launches = [n for n, rec in insts.items() if re.search(r"^corr_lookup\.",
+                n) and " custom-call(" in rec["text"]]
+    assert len(launches) == 4 and all(insts[n]["loop"] == 1
+                                      for n in launches)
+    assert [n for n in insts if re.search(r"^gru\.", n)]
+    assert re.search(r"^HloModule jit_slot_commit\b", commit.as_text())
+    assert re.search(r"^HloModule jit_fn\b", step.as_text())
