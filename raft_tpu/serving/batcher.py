@@ -8,13 +8,14 @@ independent; repetition keeps values finite for the instance norms), runs
 the warm engine, slices real rows back out, unpads each to its request's
 original resolution, and resolves the waiting handler threads.
 
-Pairwise batches go through the device two deep (SERVING.md "The batcher's
-pipeline"): with batch n running the thread takes, forms, pads and places
-n+1; when n is ready it dispatches n+1 first and then fetches and delivers
-n.  At most one batch runs and one is staged.  While a batch runs a take
-waits for a FULL bucket or for that batch to be ready; a finished batch is
-never held for a batch that is not there, so a lightly loaded server's
-requests take the path, and the time, they always took.
+Device batches go through the device two deep (SERVING.md "The batcher's
+pipeline") — pairwise batches and coalesced groups of stream advances alike:
+with batch n running the thread takes, forms, pads and places n+1; when n is
+ready it dispatches n+1 first and then fetches and delivers n.  At most one
+batch runs and one is staged.  While a batch runs a take waits for a FULL
+bucket or for that batch to be ready; a finished batch is never held for a
+batch that is not there, so a lightly loaded server's requests take the
+path, and the time, they always took.
 
 The engine is injected: an object with the phases of a device call (``place,
 dispatch, ready, wait, fetch``: serving/engine.py), or a callable
@@ -48,11 +49,19 @@ of them goes to ``stream_group_fn`` (the coordinator's continuous-
 batched step: one device call advances the whole group, per-row
 non-finite sentinel + degrade-to-cold heal inside).  A popped run is
 always homogeneous: all-pairwise, all-advances (one bucket), or one
-open — the keys guarantee it.  A stream step runs under the pairwise
-path's host stages (``raft.batch.form`` here, ``raft.batch.pad`` and the
-engine's inside the executor, ``raft.batch.deliver`` here) but NOT in
-the two-deep pipeline: it begins when the running pairwise batch has
-been delivered, and the device waits through its host chain.
+open — the keys guarantee it.  A group of advances is a job of the SAME
+two-deep pipeline (:meth:`MicroBatcher._pipeline`): the coordinator presents
+the phases the pair engine presents (``place, dispatch, ready, wait,
+fetch``) and a ``finish`` of its own (sentinel, warm-start projections, the
+commit's dispatch, the cold heals), so group n+1 is padded, placed and
+dispatched before group n is fetched and finished, and the host chain of a
+batched advance lies under the next one's run.  Its host stages are the
+pairwise path's (``raft.batch.form`` here, ``raft.batch.pad`` and the
+engine's inside the coordinator, ``raft.batch.deliver`` here).  A lone step,
+a batch of the other kind and a group whose executor is one blocking call
+begin when the running batch has been delivered; an open goes beside a
+running group (its device calls are dispatched and never fetched) and
+behind a running pairwise batch.
 
 Thread model (SERVING.md "Threading model"): the batcher deliberately
 holds **no lock of its own** — single ownership IS its synchronization.
@@ -142,10 +151,11 @@ def planar_batch(bufs: list, i: int, frames: list,
 
 
 class _BlockingCall:
-    """A plain ``run(bucket, im1, im2[, sizes]) -> flow`` callable as the
-    phases of a device call: ``dispatch`` is the whole call, so nothing is
-    ever running while the batcher looks for the next batch, and it walks
-    the serial path by itself."""
+    """A plain ``run(bucket, im1, im2[, sizes]) -> flow`` callable (or a
+    stream group's ``run(group) -> outcomes``) as the phases of a device
+    call: ``dispatch`` is the whole call, so nothing is ever running while
+    the batcher looks for the next batch, and it walks the serial path by
+    itself."""
 
     def __init__(self, run_fn: Callable):
         self.run_fn = run_fn
@@ -165,17 +175,25 @@ class _BlockingCall:
     def fetch(self, call: list):
         return call[1]
 
+    def finish(self, call: list, alone=None):
+        return call[1]
 
-class _PairJob:
-    """One pairwise device batch between its form and its deliver."""
 
-    __slots__ = ("group", "n", "padded", "ordinal", "budget", "traced",
-                 "stages", "images", "call", "out", "err", "attempts",
-                 "t_exec0", "ahead")
+class _Job:
+    """One device batch between its form and its deliver: a pairwise batch,
+    or a coalesced group of stream advances.  ``engine`` has the phases of
+    its device call, ``place`` and ``settle`` are the batcher's methods
+    that begin and end a batch of its kind."""
 
-    def __init__(self, group, ordinal: int, budget: list):
+    __slots__ = ("group", "n", "padded", "ordinal", "engine", "place",
+                 "settle", "budget", "traced", "stages", "images", "call",
+                 "out", "err", "attempts", "t_exec0", "ahead")
+
+    def __init__(self, group, ordinal: int, engine, place, settle,
+                 budget: Optional[list] = None):
         self.group, self.n = group, len(group)
         self.ordinal = ordinal            # MicroBatcher.device_batches
+        self.engine, self.place, self.settle = engine, place, settle
         self.budget = budget              # engine calls left, a 1-list
         self.traced = [r for r in group if r.trace is not None]
         self.stages: list = []            # the engine's stages of its calls
@@ -214,11 +232,18 @@ class MicroBatcher:
         # streaming steps (serving/stream.py) ride the same queue and the
         # same device-owning thread: stream_fn takes ONE StreamRequest
         # (session open / solo fallback) and returns (padded flow or
-        # None, iters_used or None); stream_group_fn takes a coalesced
-        # LIST of same-bucket advances and returns per-row
-        # (flow, iters_used, err) tuples (the continuous-batched path)
+        # None, iters_used or None); stream_group_fn advances a coalesced
+        # LIST of same-bucket sessions to per-row (flow, iters_used, err)
+        # tuples (the continuous-batched path)
         self.stream_fn = stream_fn
-        self.stream_group_fn = stream_group_fn
+        # the group executor, like the pair engine: an object with the
+        # phases of a group's batched call and its ``finish``
+        # (stream.StreamCoordinator's, behind server._stream_engine), which
+        # the loop overlaps two deep, or one blocking callable
+        self.stream_engine = (
+            stream_group_fn if stream_group_fn is None
+            or hasattr(stream_group_fn, "dispatch")
+            else _BlockingCall(stream_group_fn))
         self.pad_batch_to = pad_batch_to
         self.max_batch = max_batch
         self.max_wait = max_wait_ms / 1000.0
@@ -235,7 +260,7 @@ class MicroBatcher:
         self.served = 0
         self.timed_out = 0
         self._inflight_batch = None       # the popped-but-unresolved batch
-        self._running: Optional[_PairJob] = None   # dispatched, not fetched
+        self._running: Optional[_Job] = None   # dispatched, not fetched
         # the padded batch's two host buffers, grown to the largest batch
         # seen and viewed at each batch's shape (made on first use)
         self._pad_bytes = [np.empty(0, np.uint8), np.empty(0, np.uint8)]
@@ -301,7 +326,7 @@ class MicroBatcher:
                 self._stages.record(label, t1 - t0, cpu)
         return calls
 
-    def _count_stages(self, job: "_PairJob") -> None:
+    def _count_stages(self, job: "_Job") -> None:
         """:meth:`_take_device_stages` for a pairwise batch, whose stages
         have gathered in its own slot while another batch's interleaved."""
         tlm_spans.set_device_slot(job.stages)
@@ -363,9 +388,9 @@ class MicroBatcher:
         """One SOLO sessionful step — session opens (keyed per session:
         nothing to coalesce with) and the no-group-executor fallback.
         It observes the stream-step families at its real width (batch 1,
-        occupancy 1.0); coalesced advances go through
-        :meth:`_execute_stream_group` instead, which also folds into the
-        shared batch-size/occupancy histograms."""
+        occupancy 1.0); coalesced advances are jobs of the pipeline
+        (:meth:`_form_stream`, :meth:`_settle_stream`), which also fold into
+        the shared batch-size/occupancy histograms."""
         if self.stream_fn is None:
             r.fail(RuntimeError("stream request on a batcher without a "
                                 "stream executor"))
@@ -437,14 +462,11 @@ class MicroBatcher:
 
     # -- continuous-batched stream advances --------------------------------
 
-    def _execute_stream_group(self, batch) -> None:
-        """A coalesced run of same-bucket stream ADVANCES: one batched
-        device call for the whole group (serving/stream.py
-        ``execute_group`` — per-row sentinel and degrade-to-cold heal
-        inside), then per-row resolve/fail here.  Folds into the SAME
-        batch-size/occupancy histograms as pairwise batches (a stream
-        step is now a first-class device batch) and reports the
-        ``raft_stream_step_*`` families at the group's real width."""
+    def _form_stream(self, batch) -> Optional["_Job"]:
+        """A coalesced run of same-bucket stream ADVANCES becomes a device
+        batch: one batched device call for the whole group (serving/stream.py
+        — per-row sentinel and degrade-to-cold heal in its ``finish``),
+        walked through :meth:`_pipeline` as a pairwise batch is."""
         group = []
         for r in batch:
             if r.abandoned:
@@ -457,31 +479,52 @@ class MicroBatcher:
                 continue
             group.append(r)
         if not group:
-            return
-        self._next_device_batch()
+            return None
+        self.device_batches += 1
+        job = _Job(group, self.device_batches, self.stream_engine,
+                   self._place_stream, self._settle_stream)
+        job.padded = self.pad_batch_to(min(job.n, self.max_batch))
+        self._work_on(job)
         with host_stage("raft.batch.form", self._stage_done) as form:
-            n = len(group)
-            padded = self.pad_batch_to(min(n, self.max_batch))
-            self._observe_waste(group, padded)
-            self._device_call(n, padded)
-        for r in group:
-            if r.trace is not None:
-                r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
-                r.trace.span(form.span, r.dequeued_at, form.t1, group=n,
-                             cpu=form.cpu)
-        t0 = form.t1
-        err, outcomes = None, None
-        try:
-            outcomes = self.stream_group_fn(group)
-        except BaseException as e:
-            # the group executor contains per-row failures itself; an
-            # exception escaping it is a crash or a shutdown signal —
-            # fail every row (fresh same-type instance each: the HTTP
-            # layer stamps per-request trace ids), then let
-            # KeyboardInterrupt / SystemExit keep propagating
-            err = e
-        calls = self._take_device_stages()
-        t1 = time.monotonic()
+            self._observe_waste(group, job.padded)
+            self._device_call(job.n, job.padded)
+        for r in job.traced:
+            r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
+            r.trace.span(form.span, r.dequeued_at, form.t1, group=job.n,
+                         cpu=form.cpu)
+        job.t_exec0 = form.t1
+        return job
+
+    def _place_stream(self, job: "_Job") -> None:
+        """The group's frames go to the device (its pad stage is the
+        coordinator's, inside the place)."""
+        job.call = self._phase(job, job.engine.place, job.group)
+
+    def _settle_stream(self, job: "_Job", beside: Optional["_Job"] = None
+                       ) -> None:
+        """The end of a group of advances: its ``finish`` (sentinel,
+        warm-start projections, the commit's dispatch, the cold heals — with
+        the device to themselves: ``beside``, dispatched behind this group,
+        has run first), then per-row resolve/fail.  A row is resolved only
+        after its commit was dispatched, so its session's next frame can
+        only be gathered after it.  Folds into the SAME batch-size/occupancy
+        histograms as pairwise batches and reports the
+        ``raft_stream_step_*`` families at the group's real width."""
+        group, n, padded = job.group, job.n, job.padded
+
+        def alone():
+            if beside is not None:
+                self._wait(beside)
+                self._work_on(job)
+
+        # the group executor contains per-row failures itself; an exception
+        # escaping it is a crash — fail every row (fresh same-type instance
+        # each: the HTTP layer stamps per-request trace ids)
+        outcomes = self._phase(job, job.engine.finish, job.call, alone)
+        err = job.err
+        self._work_on(job)
+        self._count_stages(job)
+        t0, t1 = job.t_exec0, time.monotonic()
         self._observe("stream_step_seconds", t1 - t0)
         if err is None:
             # honest device-step accounting: only rows whose result came
@@ -508,18 +551,14 @@ class MicroBatcher:
         def _exec_span(tr, status):
             tr.span("execute", t0, t1, status=status, span_id=exec_sid,
                     batch_real=n, batch_padded=padded)
-            self._device_spans(tr, calls, exec_sid)
+            self._device_spans(tr, job.stages, exec_sid)
 
         if err is not None:
-            if self.breaker is not None:
-                self.breaker.record(False)
             for r in group:
                 if r.trace is not None:
                     _exec_span(r.trace, tlm_spans.status_of(err))
                 self._observe("requests", "error", 1)
                 r.fail(_fresh_error(err))
-            if not isinstance(err, Exception):
-                raise err
             return
         with host_stage("raft.batch.deliver", self._stage_done) as st:
             served = 0
@@ -529,12 +568,14 @@ class MicroBatcher:
                 if rerr is not None:
                     self._observe("request_latency",
                                   time.monotonic() - r.enqueued_at)
-                    status = ("poisoned"
-                              if getattr(rerr, "trace_status", None)
-                              == tlm_spans.POISONED else "error")
                     if r.trace is not None:
                         _exec_span(r.trace, tlm_spans.status_of(rerr))
-                    self._observe("requests", status, 1)
+                    if not isinstance(rerr, DeadlineExceeded):
+                        # (an abandoned row's handler counted its timeout)
+                        self._observe(
+                            "requests",
+                            "poisoned" if getattr(rerr, "trace_status", None)
+                            == tlm_spans.POISONED else "error", 1)
                     r.fail(rerr)
                     continue
                 if iters_used is not None:
@@ -566,14 +607,28 @@ class MicroBatcher:
 
     def _execute(self, batch) -> None:
         op = getattr(batch[0], "stream_op", None)
+        if op == "advance" and self.stream_engine is not None:
+            if isinstance(self.stream_engine, _BlockingCall):
+                # one blocking call, commit and heals inside: it begins
+                # when the running batch has been delivered
+                self._finish_running()
+            job = self._form_stream(batch)
+            if job is not None:
+                self._pipeline(job)
+            return
         if op is not None:
-            # a session's step comes after everything dequeued before it
-            self._finish_running()
-            if op == "advance" and self.stream_group_fn is not None:
-                self._execute_stream_group(batch)
-            else:
-                for r in batch:
-                    self._execute_stream(r)
+            running = self._running
+            if op != "open" or running is None \
+                    or running.engine is self.engine:
+                # a lone step (no group executor), and an open behind a
+                # pairwise batch, come after everything dequeued before them
+                self._finish_running()
+            # (an open behind a running GROUP goes beside it: its encoder
+            # pass and its row's commit are dispatched and never fetched, so
+            # they queue on the device behind the group, whose rows are other
+            # sessions' — no bubble a renewed session)
+            for r in batch:
+                self._execute_stream(r)
             return
         with host_stage("raft.batch.form", self._stage_done):
             for r in batch:
@@ -581,34 +636,38 @@ class MicroBatcher:
                     r.trace.span("queue_wait", r.enqueued_at, r.dequeued_at)
             groups = self._chunks(batch)
         for group in groups:
-            self._pipeline(group)
+            self._pipeline(
+                self._form(group, [self._bisect_budget(len(group))]))
 
-    def _pipeline(self, group) -> None:
-        """The good path of a pairwise group, two deep: form, pad and place
-        it while the batch before it runs, dispatch it the moment that one
-        is ready, and only then fetch and deliver that one.  A call that
-        failed is recovered (:meth:`_settle`) with the device to itself:
-        the batch before it before this one is dispatched, this one after
-        the batch before it is delivered."""
-        job = self._form(group, [self._bisect_budget(len(group))])
-        self._place(job)
+    def _pipeline(self, job: "_Job") -> None:
+        """The good path of a device batch — a pairwise group or a group of
+        stream advances — two deep: place it while the batch before it runs,
+        dispatch it the moment that one is ready, and only then fetch and
+        settle that one.  A call that failed is recovered (the job's
+        ``settle``) with the device to itself: the batch before it before
+        this one is dispatched, this one after the batch before it is
+        delivered.  A batch of the other kind begins when the running batch
+        has been delivered: nothing mixes the two kinds closely enough to be
+        worth an overlap."""
         running = self._running
+        if running is not None and running.engine is not job.engine:
+            self._finish_running()
+            running = None
+        job.place(job)
         if running is not None:
             job.ahead = job.err is None \
-                and not self.engine.ready(running.call)
+                and not running.engine.ready(running.call)
             self._wait(running)
             if running.err is not None:
-                self._settle(running)
+                running.settle(running)
                 self._running = running = None
         self._dispatch(job)
         if running is not None:
             self._fetch(running)
-            if running.err is not None:
-                self._wait(job)
-            self._settle(running)
+            running.settle(running, job)
         self._running = job if job.err is None else None
         if job.err is not None:
-            self._settle(job)
+            job.settle(job)
 
     def _finish_running(self) -> None:
         """Wait for the running batch, if there is one, and deliver it."""
@@ -616,7 +675,7 @@ class MicroBatcher:
         if running is not None:
             self._wait(running)
             self._fetch(running)
-            self._settle(running)
+            running.settle(running)
             self._running = None
 
     def _run_group(self, group, budget) -> None:
@@ -627,12 +686,13 @@ class MicroBatcher:
         self._call(job)
         self._settle(job)
 
-    def _form(self, group, budget, formed: bool = False) -> "_PairJob":
+    def _form(self, group, budget, formed: bool = False) -> "_Job":
         """A device batch begins: its ordinal, its form and pad stages.
         ``formed`` marks a bisection's sub-group (batch size and the
         batch_form span are recorded once, on the original group)."""
         self.device_batches += 1
-        job = _PairJob(group, self.device_batches, budget)
+        job = _Job(group, self.device_batches, self.engine, self._place,
+                   self._settle, budget)
         job.padded = self.pad_batch_to(min(job.n, self.max_batch))
         self._work_on(job)
         with host_stage("raft.batch.form", self._stage_done) as form:
@@ -655,7 +715,7 @@ class MicroBatcher:
         job.t_exec0 = pad.t1
         return job
 
-    def _pad(self, job: "_PairJob"):
+    def _pad(self, job: "_Job"):
         """Write the group's pairs, and the last one again up to the batch
         step, into the two buffers this batcher keeps
         (:func:`planar_batch`).  They hold a batch from here until its
@@ -668,14 +728,14 @@ class MicroBatcher:
                 for i, attr in enumerate(("image1", "image2"))]
         return st
 
-    def _work_on(self, job: "_PairJob") -> None:
+    def _work_on(self, job: "_Job") -> None:
         """This thread's stages are ``job``'s from here on: its ordinal
         rides on every host stage opened (``batch=<n>`` of the
         annotations), and the engine's stages land in its slot."""
         set_batch(job.ordinal)
         tlm_spans.set_device_slot(job.stages)
 
-    def _phase(self, job: "_PairJob", fn, *args):
+    def _phase(self, job: "_Job", fn, *args):
         """One phase of ``job``'s device call, skipped once the call has
         failed.  An exception fails the call, not the thread."""
         if job.err is not None:
@@ -707,7 +767,7 @@ class MicroBatcher:
             raise
         return None
 
-    def _place(self, job: "_PairJob") -> None:
+    def _place(self, job: "_Job") -> None:
         """An attempt begins: the padded pair goes to the device."""
         if job.budget[0] <= 0:
             job.err = RuntimeError("bisection budget exhausted before this "
@@ -724,34 +784,35 @@ class MicroBatcher:
             rb = ([r.rbucket for r in job.group]
                   + [job.group[-1].rbucket] * (job.padded - job.n))
             args += (np.asarray(rb, np.int32),)
-        job.call = self._phase(job, self.engine.place, *args)
+        job.call = self._phase(job, job.engine.place, *args)
 
-    def _dispatch(self, job: "_PairJob") -> None:
-        self._phase(job, self.engine.dispatch, job.call)
+    def _dispatch(self, job: "_Job") -> None:
+        self._phase(job, job.engine.dispatch, job.call)
         if job.err is None:
             self._observe("batches_staged",
                           "ahead" if job.ahead else "late", 1)
 
-    def _wait(self, job: "_PairJob") -> None:
-        self._phase(job, self.engine.wait, job.call)
+    def _wait(self, job: "_Job") -> None:
+        self._phase(job, job.engine.wait, job.call)
 
-    def _fetch(self, job: "_PairJob") -> None:
-        job.out = self._phase(job, self.engine.fetch, job.call)
-        if job.err is None and self.breaker is not None:
-            self.breaker.record(True)
+    def _fetch(self, job: "_Job") -> None:
+        job.out = self._phase(job, job.engine.fetch, job.call)
 
-    def _call(self, job: "_PairJob") -> None:
+    def _call(self, job: "_Job") -> None:
         """One attempt with nothing beside it: every phase in turn."""
-        self._place(job)
+        job.place(job)
         self._dispatch(job)
         self._wait(job)
         self._fetch(job)
 
-    def _settle(self, job: "_PairJob") -> None:
-        """The end of a device batch: deliver its rows — after the retries
+    def _settle(self, job: "_Job", beside: Optional["_Job"] = None) -> None:
+        """The end of a pairwise batch: deliver its rows — after the retries
         of a failed call, and the bisection of one that keeps failing, so
-        that only the guilty request(s) fail."""
+        that only the guilty request(s) fail; ``beside``, dispatched behind
+        this batch, has run before any of that begins."""
         group, n, padded, budget = job.group, job.n, job.padded, job.budget
+        if job.err is not None and beside is not None:
+            self._wait(beside)
         while (job.err is not None and 0 < job.attempts <= self.retries
                and budget[0] > 0):
             time.sleep(self.retry_backoff_s)
@@ -809,6 +870,8 @@ class MicroBatcher:
             self._run_group(group[:mid], budget)
             self._run_group(group[mid:], budget)
             return
+        if self.breaker is not None:
+            self.breaker.record(True)
         with host_stage("raft.batch.deliver", self._stage_done) as st:
             served = self._deliver(group, job.out, padded, t_exec1, st,
                                    _exec_span)
@@ -888,7 +951,7 @@ class MicroBatcher:
             busy = None
             if running is not None:
                 # a part batch waits for its mates while the device is busy
-                busy = lambda: not self.engine.ready(running.call)  # noqa: E731
+                busy = lambda: not running.engine.ready(running.call)  # noqa: E731
             set_batch(self.device_batches + 1)    # the batch being waited for
             with host_stage("raft.batch.take", self._stage_done):
                 batch, expired = self.queue.take_batch(

@@ -49,9 +49,23 @@ class PairCall:
     and its placed arguments until the dispatch, the outputs after it."""
 
     __slots__ = ("ex", "args", "out")
+    kind = "pair"
 
     def __init__(self, ex, args: tuple):
         self.ex, self.args, self.out = ex, args, None
+
+
+class StreamBatchCall(PairCall):
+    """One call of an ``sbatch`` executable between its phases.  ``args`` are
+    the placed frames (and, ragged, the rows' sizes): the pool's buffers,
+    the slots and ``active`` join them at the dispatch."""
+
+    __slots__ = ("bucket", "rows")
+    kind = "stream"
+
+    def __init__(self, ex, args: tuple, bucket: Tuple[int, int]):
+        super().__init__(ex, args)
+        self.bucket, self.rows = bucket, 0
 
 
 class InferenceEngine:
@@ -666,7 +680,7 @@ class InferenceEngine:
 
     def wait(self, call: "PairCall") -> None:
         """Block until a dispatched call's outputs are ready."""
-        self._block("pair", call.out)
+        self._block(call.kind, call.out)
 
     def fetch(self, call: "PairCall"):
         """A finished call's outputs on the host: the flow, or (flow,
@@ -754,45 +768,82 @@ class InferenceEngine:
         return flow, flow_lr, rest[0] if rest else None
 
     # -- the continuous-batched stream path (slot pool) --------------------
+    #
+    # ``run_stream_batch`` is place -> dispatch -> wait -> fetch, as ``run``
+    # is, and the batcher walks a group of advances through the same
+    # pipeline (serving/stream.py): ``ready`` and ``wait`` are the pair
+    # call's.
 
-    def run_stream_batch(self, bucket: Tuple[int, int], images: np.ndarray,
-                         slots: np.ndarray, active: np.ndarray,
-                         sizes=None):
-        """ONE device call advancing ``active.sum()`` different sessions:
-        ``images`` [b, BH, BW, 3] (padded to a declared batch step),
-        ``slots`` [b] int32 pool rows (padding rows aim at the scratch
-        slot), ``active`` [b] bool.  Returns ``(flow [b] np, flow_lr [b]
-        np, fmap_rows dev, cnet_rows dev, iters_used [b] np or None)`` —
-        the updated map ROWS stay device-resident until
-        :meth:`commit_stream` scatters the finite ones into the pool.
-        ``stream_calls`` counts REAL rows (per-frame fnet accounting, the
-        acceptance counters)."""
+    def place_stream_batch(self, bucket: Tuple[int, int],
+                           images: np.ndarray,
+                           sizes=None) -> "StreamBatchCall":
+        """Put a stream batch's padded frames on the device
+        (:meth:`_h2d`).  Once this returns the caller may rewrite
+        ``images``."""
         h, w = bucket
         b = images.shape[0]
         self._ensure_slot_buffers(bucket)
         ex = self._get_executable(self._key(h, w, b, "sbatch"))
-        with self._lock:
-            self.stream_calls += int(np.asarray(active).sum())
         if self.faults is not None:
             self.faults.pre_engine_call()
         [images] = self._h2d("stream", ex, 1, images)
-        fbuf, cbuf, flbuf = self.pool.buffers(bucket)
-        args = (self.params, images, fbuf, cbuf, flbuf,
-                np.asarray(slots, np.int32), np.asarray(active, bool))
+        args = (images,)
         if self.ragged:
             args += (self._sizes_arg(b, sizes),)
-        out = self._call("stream", ex, args)
-        fmap_rows, cnet_rows = out[2], out[3]
+        return StreamBatchCall(ex, args, bucket)
+
+    def dispatch_stream_batch(self, call: "StreamBatchCall",
+                              slots: np.ndarray, active: np.ndarray) -> None:
+        """Enqueue a placed stream batch over the pool's buffers AS THEY
+        STAND NOW (a commit since the place has donated and swapped them),
+        gathering ``slots`` [b] int32 (padding and dropped rows aim at the
+        scratch slot) for the rows ``active`` [b] bool; returns before the
+        device has run it.  ``stream_calls`` counts REAL rows (per-frame
+        fnet accounting, the acceptance counters)."""
+        active = np.asarray(active, bool)
+        call.rows = int(active.sum())
+        with self._lock:
+            self.stream_calls += call.rows
+        images, *sizes = call.args
+        fbuf, cbuf, flbuf = self.pool.buffers(call.bucket)
+        call.out = self._call(
+            "stream", call.ex,
+            (self.params, images, fbuf, cbuf, flbuf,
+             np.asarray(slots, np.int32), active, *sizes), wait=False)
+        call.args = None              # the device holds what it needs
+
+    def fetch_stream_batch(self, call: "StreamBatchCall") -> tuple:
+        """A finished stream batch's ``(flow [b] np, flow_lr [b] np,
+        fmap_rows dev, cnet_rows dev, iters_used [b] np or None)`` — the
+        updated map ROWS stay device-resident until :meth:`commit_stream`
+        scatters the finite ones into the pool."""
+        out = call.out
         flow, flow_lr, iters_used = self._fetch_stream(out)
         if self.faults is not None:
             # chaos must poison a REAL row: padding rows (the suffix, by
             # the coordinator's construction) are discarded before the
             # sentinel, so a roll landing there would silently test
             # nothing
-            n_real = int(np.asarray(active).sum())
             flow = np.concatenate(
-                [self.faults.corrupt_rows(flow[:n_real]), flow[n_real:]])
-        return flow, flow_lr, fmap_rows, cnet_rows, iters_used
+                [self.faults.corrupt_rows(flow[:call.rows]),
+                 flow[call.rows:]])
+        return flow, flow_lr, out[2], out[3], iters_used
+
+    def run_stream_batch(self, bucket: Tuple[int, int], images: np.ndarray,
+                         slots: np.ndarray, active: np.ndarray,
+                         sizes=None):
+        """ONE device call advancing ``active.sum()`` different sessions:
+        ``images`` [b, BH, BW, 3] (padded to a declared batch step),
+        ``slots`` [b] int32 pool rows, ``active`` [b] bool; what
+        :meth:`fetch_stream_batch` returns."""
+        call = self.place_stream_batch(bucket, images, sizes)
+        self.dispatch_stream_batch(call, slots, active)
+        self.wait(call)
+        return self.fetch_stream_batch(call)
+
+    # the server pipelines stream batches only where ``run_stream_batch`` is
+    # this composition (server.FlowServer._stream_engine)
+    run_stream_batch.composes_phases = True
 
     def commit_stream(self, bucket: Tuple[int, int], slots: np.ndarray,
                       fmap_rows, cnet_rows, seeds: np.ndarray,

@@ -104,11 +104,11 @@ def make_serving_metrics(registry: Registry, config,
             labelnames=("kind",)),
         "batches_staged": registry.counter(
             "raft_serving_batches_staged_total",
-            "Pairwise device batches by when their inputs reached the "
-            "device: when=ahead before the batch in front of them was "
-            "ready (the device did not wait for the host), when=late "
-            "otherwise (the first of a run of batches, and every one the "
-            "host was too slow for)",
+            "Device batches (pairwise batches and batched stream advances) "
+            "by when their inputs reached the device: when=ahead before "
+            "the batch in front of them was ready (the device did not "
+            "wait for the host), when=late otherwise (the first of a run "
+            "of batches, and every one the host was too slow for)",
             labelnames=("when",)),
         "compile_misses": registry.counter(
             "raft_serving_compile_cache_misses_total",

@@ -68,6 +68,11 @@ class Request:
                  "_done", "result", "error", "batch_real", "batch_padded",
                  "iters_used", "trace")
 
+    # does it share a device batch with the requests queued under its key?
+    # (a session's open never does: its key is its own, so ``take_batch``
+    # hands it over without waiting for mates that cannot come)
+    coalesces = True
+
     def __init__(self, image1: np.ndarray, image2: np.ndarray,
                  bucket: Tuple[int, int], pads: Tuple[int, int, int, int],
                  deadline: float,
@@ -202,7 +207,8 @@ class RequestQueue:
         of up to ``max_batch`` requests (None when the queue closed empty)
         and ``expired`` are requests whose deadline passed while queued —
         the caller fails those with DeadlineExceeded.  A batch is ready
-        when some bucket holds max_batch requests, when the oldest waiting
+        when some bucket holds max_batch requests (or one that coalesces
+        with nothing: ``Request.coalesces``), when the oldest waiting
         request has aged ``max_wait`` seconds, or when the queue is closed
         (drain: flush immediately, ignore max_wait).
 
@@ -230,7 +236,7 @@ class RequestQueue:
                 held = busy is not None and busy()
                 if best is not None:
                     fifo = self._by_bucket[best]
-                    full = len(fifo) >= max_batch
+                    full = len(fifo) >= max_batch or not fifo[0].coalesces
                     aged = now - best_head >= max_wait
                     if full or self._closed or (aged and busy is None):
                         batch = fifo[:max_batch]

@@ -270,7 +270,7 @@ class FlowServer:
             self.queue, self._pair_engine(), sconfig.pad_batch_to,
             sconfig.max_batch, sconfig.max_wait_ms, metrics=self.metrics,
             stream_fn=self._run_stream if self.streams else None,
-            stream_group_fn=(self._run_stream_group if self.streams
+            stream_group_fn=(self._stream_engine() if self.streams
                              else None),
             breaker=self.breaker, faults=self.faults,
             retries=sconfig.engine_retries,
@@ -340,14 +340,14 @@ class FlowServer:
             place=step(engine.place), dispatch=step(engine.dispatch, True),
             ready=engine.ready, wait=engine.wait, fetch=step(engine.fetch))
 
-    def _stream_step(self, fn, arg):
-        """One stream step of the batcher: a device step, and the encoder
-        passes it took by the engine's own call counters
-        (``raft_stream_encoder_passes_total``)."""
+    def _stream_step(self, fn, *args, tick: bool = True):
+        """One stream step of the batcher, or one phase of one: a device
+        step, and the encoder passes it took by the engine's own call
+        counters (``raft_stream_encoder_passes_total``)."""
         was = [getattr(self.engine, f"{c}_calls", 0)
                for c in ("encode", "stream")]
         try:
-            return self._device_step("serve/stream", fn, arg, self.engine)
+            return self._device_step("serve/stream", fn, *args, tick=tick)
         finally:
             for call, before in zip(("encode", "stream"), was):
                 now = getattr(self.engine, f"{call}_calls", 0)
@@ -357,12 +357,30 @@ class FlowServer:
 
     def _run_stream(self, req):
         """One solo session step (open, or the no-group fallback)."""
-        return self._stream_step(self.streams.execute, req)
+        return self._stream_step(self.streams.execute, req, self.engine)
 
-    def _run_stream_group(self, group):
-        """Continuous-batched stream step (coalesced same-bucket
-        advances): one device batch."""
-        return self._stream_step(self.streams.execute_group, group)
+    def _stream_engine(self):
+        """What the batcher runs coalesced same-bucket advances with: the
+        coordinator's phases of a group (one device batch), each behind
+        :meth:`_stream_step`, which it overlaps two deep as it does the pair
+        engine's — or ``execute_group`` as one blocking call, where the
+        engine's ``run_stream_batch`` is not its own composition of the
+        phases (a stub's: whoever put it there means it to be on the
+        path)."""
+        streams, engine = self.streams, self.engine
+        if not getattr(getattr(engine, "run_stream_batch", None),
+                       "composes_phases", False):
+            return lambda group: self._stream_step(streams.execute_group,
+                                                   group, engine)
+
+        def step(phase, tick=False):
+            return lambda *args: self._stream_step(phase, *args, tick=tick)
+
+        return types.SimpleNamespace(
+            place=step(lambda group: streams.place(group, engine)),
+            dispatch=step(streams.dispatch, True), ready=streams.ready,
+            wait=streams.wait, fetch=step(streams.fetch),
+            finish=step(streams.finish))
 
     def engine_executables(self) -> int:
         return getattr(self.engine, "executables", 0)

@@ -31,25 +31,63 @@ recurrence) — which is also why a coalesced group can never hold the
 same session twice, so the commit scatter's real slot indices are
 always unique.
 
+**The pipelined walk.**  A group of advances is a job of the batcher's
+two-deep pipeline (batcher.py ``_pipeline``), walked through the phases a
+pair batch is walked through: :meth:`StreamCoordinator.place` (pad, the
+frames' ``h2d``) under group n's run; :meth:`~StreamCoordinator.dispatch`
+the moment n is ready (never two programs' temporaries at once), over the
+pool's buffers, the slots and the live rows as they stand THEN; and only
+then n's :meth:`~StreamCoordinator.fetch` and
+:meth:`~StreamCoordinator.finish` — sentinel, warm-start projections, the
+commit's dispatch, the heals — under n+1's run.  Why the answers are the
+blocking walk's, bit for bit: a handler holds ``Session.lock`` across its
+whole advance, so a session is in at most one request in flight and the
+rows of n+1 are other sessions, in other slots, than n's; a row is resolved
+only after its commit was dispatched, its handler sends the next frame only
+after the resolve, and the device runs calls in dispatch order.  So the
+order on the device is ``sbatch(n+1)``, ``scommit(n)``, ``sbatch(n+2)``:
+every gather sees every earlier scatter of ITS slots, and ``sbatch(n+1)``
+reads rows that ``scommit(n)`` does not write (the scratch slot is never
+served).  A batch of ``/v1/flow`` pairs and an engine with
+``run_stream_batch`` alone begin when the running group has been delivered;
+a cold restart — of a row demoted before its group formed, or healing a
+faulted one — is a device call of its own and runs in ``finish`` after the
+group dispatched behind it has run.  An OPEN goes beside a running group:
+the queue hands it over at once (it coalesces with nothing), and its encoder
+pass and its row's commit are dispatched and never fetched, so they queue
+on the device behind the group, whose rows are other sessions' (an LRU
+demotion the open's promote makes skips sessions in flight); the group's own
+commit then scatters into the buffers as the open's left them.
+
 Thread model (SERVING.md "Threading model"): the handler thread holds
 ``Session.lock`` across the WHOLE advance — including ``queue.submit``
 (which takes the queue lock) and the blocking wait — which is why the
 declared hierarchy orders ``Session.lock`` OUTSIDE
 ``RequestQueue._lock``.  The coordinator itself holds no lock: session
-state is mutated only in :meth:`execute`/:meth:`execute_group` on the
-batcher thread, while the handler's session lock keeps any second frame
-of the same session out; slot transitions go through the store
-(store lock → pool lock, the declared edge).
+state is mutated only in :meth:`execute` and the phases of a group
+(:meth:`place` ... :meth:`finish`) on the batcher thread, while the
+handler's session lock keeps any second frame of the same session out;
+slot transitions go through the store (store lock → pool lock, the
+declared edge).
 
 Failure containment, per ROW of a batched step: a warm row that faults —
-the batched call raising, or that row's output failing the non-finite
-sentinel (e.g. a poisoned slot) — is demoted and healed through the SAME
-transparent cold-restart path an evicted session takes, in the same
-advance; its co-batched neighbors keep their warm results.  This is the
-stream path's form of poisoned-row isolation: the pairwise path bisects
-because it has no finer fallback, the stream path degrades straight to
-per-row cold restarts (finer blame, bounded at two engine calls per
-row).  A cold attempt that faults is terminal for that frame only.
+the batched call raising (at its place, its dispatch or its wait), or that
+row's output failing the non-finite sentinel (e.g. a poisoned slot) — is
+demoted and healed through the SAME transparent cold-restart path an
+evicted session takes, in the same advance; its co-batched neighbors keep
+their warm results.  This is the stream path's form of poisoned-row
+isolation: the pairwise path bisects because it has no finer fallback, the
+stream path degrades straight to per-row cold restarts (finer blame,
+bounded at two engine calls per row).  A cold attempt that faults is
+terminal for that frame only.  A failed commit rebuilds the pool zeroed and
+demotes the bucket; the group already in flight was gathered from the
+buffers before the rebuild, so its flows are sound and are served, but none
+of its rows commits into a slot its session no longer owns (``finish``
+compares), and nothing gathered from a rebuilt pool is served: every
+session of the bucket restarts cold on its next frame.  A row whose handler
+gave up is skipped BEFORE the device call, never after: at the form
+(batcher), again at the dispatch (a run later: the row goes inactive), and
+before a heal.
 """
 
 from __future__ import annotations
@@ -99,6 +137,10 @@ class StreamRequest(Request):
 
     __slots__ = ("session", "stream_op", "warm", "frame", "abandoned")
 
+    @property
+    def coalesces(self) -> bool:
+        return self.stream_op == "advance"
+
     def __init__(self, session: Session, op: str, image_padded, pads,
                  deadline: float,
                  qbucket: Optional[Tuple[int, int]] = None):
@@ -123,14 +165,64 @@ class StreamRequest(Request):
         self.abandoned = False
 
 
+class _WholeBatchCall:
+    """An engine with ``run_stream_batch`` alone as the phases of a stream
+    batch's device call (serving/engine.py): ``dispatch`` is the whole
+    call."""
+
+    def __init__(self, engine):
+        self.run = engine.run_stream_batch
+
+    def place_stream_batch(self, bucket, images, sizes=None) -> list:
+        return [bucket, images, sizes]
+
+    def dispatch_stream_batch(self, call: list, slots, active) -> None:
+        bucket, images, sizes = call
+        call[:] = [self.run(bucket, images, slots, active, sizes=sizes)]
+
+    def ready(self, call: list) -> bool:
+        return True
+
+    def wait(self, call: list) -> None:
+        pass
+
+    def fetch_stream_batch(self, call: list):
+        return call[0]
+
+
+class GroupCall:
+    """One coalesced group of advances between its place and its finish.
+    ``warm`` are the group's rows that ride the batched device call (their
+    sessions held a slot at the place), ``call`` that call while one is
+    placed or running, ``live`` / ``slots`` its rows as the dispatch found
+    them, ``out`` what its fetch brought."""
+
+    __slots__ = ("group", "engine", "phases", "warm", "bucket", "padded",
+                 "call", "live", "slots", "out", "failed")
+
+    def __init__(self, group: List[StreamRequest], engine):
+        self.group, self.engine = group, engine
+        self.phases = (engine if hasattr(engine, "dispatch_stream_batch")
+                       else _WholeBatchCall(engine))
+        self.warm: List[int] = []
+        self.call = self.live = self.out = None
+        self.failed = False
+
+    @property
+    def reqs(self) -> List[StreamRequest]:
+        return [self.group[i] for i in self.warm]
+
+
 class StreamCoordinator:
     """Owns the session store + slot pool policy and the stream-step
     device recipe.
 
     Handler threads call :meth:`open`/:meth:`advance`/:meth:`close`
     (validate, lock the session, enqueue, block); the batcher thread
-    calls :meth:`execute` (opens) and :meth:`execute_group` (coalesced
-    advances) — the only places device state moves.
+    calls :meth:`execute` (opens) and the phases of a coalesced group of
+    advances (:meth:`place`, :meth:`dispatch`, :meth:`ready`, :meth:`wait`,
+    :meth:`fetch`, :meth:`finish`; :meth:`execute_group` is all of them in
+    turn) — the only places device state moves.
     """
 
     def __init__(self, store: SessionStore, sconfig, queue: RequestQueue,
@@ -355,8 +447,8 @@ class StreamCoordinator:
         """Run one SOLO stream step on the device (session open, or a
         lone advance routed outside the group path).  Returns (padded
         flow or None, iters_used or None); all session/cache mutation
-        happens here or in :meth:`execute_group`, on the single thread
-        that owns the device."""
+        happens here or in a group's phases, on the single thread that
+        owns the device."""
         s = req.session
         if req.stream_op == "open":
             with host_stage("raft.batch.pad", _batch_stage):
@@ -371,32 +463,143 @@ class StreamCoordinator:
         return flow, iters_used
 
     def execute_group(self, group: List[StreamRequest], engine):
-        """Advance a coalesced same-bucket group of sessions: ONE batched
-        device call for the warm rows (gather slots → step → masked
-        commit), solo cold restarts for demoted rows and for warm rows
-        that faulted (the per-row degradation ladder — see the module
-        docstring).  Returns ``[(padded flow, iters_used, err)]`` aligned
-        with ``group``; exactly one of flow/err is set per row.  Session
-        host state (frames, last_image) moves only for rows that
-        succeeded."""
+        """Advance a coalesced same-bucket group of sessions, every phase in
+        turn with nothing beside it (the blocking walk: a lone advance, a
+        server whose engine has ``run_stream_batch`` alone).  Returns what
+        :meth:`finish` returns."""
+        call = self.place(group, engine)
+        self.dispatch(call)
+        self.wait(call)
+        self.fetch(call)
+        return self.finish(call)
+
+    # A group of advances, one phase at a time: the phases of the pair
+    # engine's device call (place, dispatch, ready, wait, fetch) and a finish
+    # of its own, which the batcher walks two deep (serving/batcher.py
+    # ``_pipeline``): group n+1 is placed while group n's batched call runs
+    # and dispatched the moment it is ready, and group n is fetched and
+    # finished under n+1's run.  A batched call that raises, in whichever
+    # phase, fails the CALL: its rows heal cold in ``finish``.
+
+    def _guard(self, call: "GroupCall", phase, *args):
+        """One phase of ``call``'s batched device call.  A fault there is
+        no retry's business: a warm step has a finer fallback than
+        re-running the whole group, the cold heal of each row, which also
+        isolates a guilty one.  The failed call still counts against the
+        breaker: it measures engine-call health, and a 100%-warm-failure
+        mode must stay visible even though every advance heals."""
+        try:
+            return phase(*args)
+        except Exception:
+            if self.breaker is not None:
+                self.breaker.record(False)
+            call.failed, call.call = True, None
+            return None
+
+    def place(self, group: List[StreamRequest], engine) -> "GroupCall":
+        """The group's warm rows (their sessions hold a slot now) padded
+        into one batch and their frames put on the device; the rest of the
+        group waits for :meth:`finish`'s cold restarts.  The pad buffer is
+        free again once this returns (``h2d`` waits for the transfer), so
+        one serves the group running and the group staged behind it."""
         if self.faults is not None:
             for r in group:
                 self.faults.corrupt_session(r.session, engine)
+        call = GroupCall(group, engine)
+        call.warm = [i for i, r in enumerate(group)
+                     if r.session.has_features]
+        if call.warm:
+            self._guard(call, self._place_warm, call)
+        return call
+
+    def _place_warm(self, call: "GroupCall") -> None:
+        reqs = call.reqs
+        call.bucket = bucket = self._dev(reqs[0].session)
+        n = len(reqs)
+        call.padded = padded = self.sconfig.pad_batch_to(
+            min(n, self.sconfig.max_batch))
+        with host_stage("raft.batch.pad", _batch_stage):
+            images = planar_batch(self._frames, 0,
+                                  [r.image1 for r in reqs], padded)
+        sizes = None
+        if self.dev_box is not None:
+            # per-row live extents: each session's ROUTED bucket (filler
+            # rows repeat the last, matching their repeated pixels)
+            sizes = np.asarray([r.session.bucket for r in reqs]
+                               + [reqs[-1].session.bucket] * (padded - n),
+                               np.int32)
+        call.call = call.phases.place_stream_batch(bucket, images, sizes)
+
+    def dispatch(self, call: "GroupCall") -> None:
+        """Enqueue the placed batch over the pool's buffers, the slots and
+        the rows AS THEY STAND NOW: the dispatch can come a whole run after
+        the place, and a commit, a cold restart's ``commit_row`` or a
+        bucket's demotion in between has moved them.  A row whose handler
+        gave up since, or whose session lost its slot, goes inactive (an
+        argument of the program, not a shape) and is left to
+        :meth:`finish`; with no row left the call is skipped — BEFORE the
+        device call, never after."""
+        if call.call is None:
+            return
+        reqs = call.reqs
+        call.live = live = [not r.abandoned and r.session.has_features
+                            for r in reqs]
+        if not any(live):
+            call.call = None
+            return
+        fill = [self.pool.scratch] * (call.padded - len(reqs))
+        call.slots = np.asarray(
+            [r.session.slot if ok else self.pool.scratch
+             for r, ok in zip(reqs, live)] + fill, np.int32)
+        self._guard(call, call.phases.dispatch_stream_batch, call.call,
+                    call.slots, np.asarray(live + [False] * len(fill), bool))
+
+    def ready(self, call: "GroupCall") -> bool:
+        """Has the batched call finished (or is there none)?  Never
+        blocks."""
+        return call.call is None or call.phases.ready(call.call)
+
+    def wait(self, call: "GroupCall") -> None:
+        if call.call is not None:
+            self._guard(call, call.phases.wait, call.call)
+
+    def fetch(self, call: "GroupCall") -> None:
+        """The finished call's flows on the host; its rows of ``fmap`` /
+        ``cnet`` stay on the device for the commit."""
+        if call.call is not None:
+            call.out = self._guard(call, call.phases.fetch_stream_batch,
+                                   call.call)
+            call.call = None
+            if call.out is not None and self.breaker is not None:
+                self.breaker.record(True)
+
+    def finish(self, call: "GroupCall", alone=None):
+        """The end of a group: sentinel, warm-start projections and commit
+        of the batched call's rows (:meth:`_finish_warm`), then a solo cold
+        restart for every row it did not serve — demoted at the place or
+        since, and warm rows that faulted (the per-row degradation ladder —
+        see the module docstring).  The restarts are device calls of their
+        own: ``alone()`` (the batcher's) first waits until the batch
+        dispatched behind this one has run.  Returns ``[(padded flow,
+        iters_used, err)]`` aligned with the group; exactly one of
+        flow/err is set per row.  Session host state (frames, last_image)
+        moves only for rows that succeeded."""
+        group, engine = call.group, call.engine
         results: List[Optional[tuple]] = [None] * len(group)
-        warm_idx = [i for i, r in enumerate(group)
-                    if r.session.has_features]
-        heal_idx: List[int] = []
-        if warm_idx:
-            rows = self._warm_batch([group[i] for i in warm_idx], engine)
-            for i, row in zip(warm_idx, rows):
-                if row is None:          # faulted warm row: degrade, heal
-                    heal_idx.append(i)
-                else:
-                    results[i] = row
-        cold_idx = [i for i, r in enumerate(group)
-                    if not r.session.has_features and i not in heal_idx]
-        for i in sorted(cold_idx + heal_idx):
+        if call.warm:
+            for i, row in zip(call.warm, self._finish_warm(call)):
+                results[i] = row
+        heal = [i for i, row in enumerate(results) if row is None]
+        if heal and alone is not None:
+            alone()
+        for i in heal:
             r = group[i]
+            if r.abandoned:
+                # given up since the form: skipped, as at the form, BEFORE
+                # the device call (the handler has counted its timeout)
+                results[i] = (None, None, DeadlineExceeded(
+                    f"stream step {r.id} abandoned by its handler"))
+                continue
             try:
                 flow, iters_used = self._cold_advance(r.session, r, engine)
                 r.warm = False
@@ -414,69 +617,57 @@ class StreamCoordinator:
                 self.metrics["frames"].inc()
         return results
 
-    def _warm_batch(self, reqs: List[StreamRequest], engine):
-        """One batched stream step over the warm rows.  Returns a list
-        aligned with ``reqs``: ``(padded flow, iters_used, None)`` for
-        rows whose output passed the sentinel (their slots are
-        committed), or None for rows that must heal cold (their slots
-        are dropped; nothing poisoned is ever cached)."""
-        s0 = reqs[0].session
-        bucket = self._dev(s0)
+    def _finish_warm(self, call: "GroupCall") -> list:
+        """What became of the batched call's rows.  Returns a list aligned
+        with ``call.reqs``: ``(padded flow, iters_used, None)`` for rows
+        whose output passed the sentinel (committed, where the session
+        still owns the slot it was gathered from), or None for rows that
+        must heal cold (their slots are dropped; nothing poisoned is ever
+        cached)."""
+        reqs = call.reqs
         n = len(reqs)
-        padded = self.sconfig.pad_batch_to(min(n, self.sconfig.max_batch))
-        with host_stage("raft.batch.pad", _batch_stage):
-            images = planar_batch(self._frames, 0,
-                                  [r.image1 for r in reqs], padded)
-        slots = np.asarray([r.session.slot for r in reqs]
-                           + [self.pool.scratch] * (padded - n), np.int32)
-        active = np.asarray([True] * n + [False] * (padded - n), bool)
-        sizes = None
-        if self.dev_box is not None:
-            # per-row live extents: each session's ROUTED bucket (filler
-            # rows repeat the last, matching their repeated pixels)
-            sizes = np.asarray([r.session.bucket for r in reqs]
-                               + [reqs[-1].session.bucket] * (padded - n),
-                               np.int32)
-        try:
-            flow, flow_lr, fmap_rows, cnet_rows, iters_used = \
-                engine.run_stream_batch(bucket, images, slots, active,
-                                        sizes=sizes)
-        except Exception:
-            # the batched call itself faulted: every row degrades to the
-            # cold-restart path (the solo semantics, batched — no retry:
-            # a warm step has a finer fallback than re-running the whole
-            # group, and the cold heal isolates the guilty row).  The
-            # failed call still counts against the breaker: it measures
-            # engine-call health, and a 100%-warm-failure mode must stay
-            # visible even though every advance heals.
-            if self.breaker is not None:
-                self.breaker.record(False)
-            for r in reqs:
-                self._degrade(r)
+        live = call.live or [True] * n
+        if call.out is None:
+            # the batched call faulted, or no row was left for it: every
+            # row it would have served degrades to the cold-restart path
+            # (the solo semantics, batched)
+            for r, ok in zip(reqs, live):
+                if ok and call.failed:
+                    self._degrade(r)
             return [None] * n
-        if self.breaker is not None:
-            self.breaker.record(True)
+        flow, flow_lr, fmap_rows, cnet_rows, iters_used = call.out
+        bucket, padded, slots = call.bucket, call.padded, call.slots
         h, w = bucket
         with host_stage("raft.stream.sentinel", _batch_stage):
-            row_ok = np.array([np.isfinite(flow[i]).all()
-                               and np.isfinite(flow_lr[i]).all()
-                               for i in range(n)], bool)
+            row_ok = np.array([ok and bool(np.isfinite(flow[i]).all()
+                                           and np.isfinite(flow_lr[i]).all())
+                               for i, ok in enumerate(live)], bool)
+        # a row commits into the slot it was gathered from, while its
+        # session still owns it: a failed commit of the batch in front
+        # (dispatched after this one was) has rebuilt the pool and demoted
+        # the bucket — this batch's flows are sound and are served, and its
+        # sessions restart cold on their next frame
+        mask = np.zeros(padded, bool)
+        mask[:n] = [ok and r.session.slot == slots[i]
+                    for i, (r, ok) in enumerate(zip(reqs, row_ok))]
         # commit BEFORE touching host state, AFTER the sentinel: finite
         # rows scatter their updated maps + next-frame warm-start seed
         # into their slots; rejected and padding rows write their old
         # values back (mask), so a poisoned output can never be cached
         with host_stage("raft.stream.seed", _batch_stage):
             seeds = np.zeros((padded, h // 8, w // 8, 2), np.float32)
-            for i in np.flatnonzero(row_ok):
+            for i in np.flatnonzero(mask):
                 seeds[i] = self._mask_seed(
                     warm_start_seed(flow_lr[i:i + 1], (h // 8, w // 8))[0],
                     reqs[i].session.bucket)
-        mask = active.copy()
-        mask[:n] &= row_ok
         try:
             with host_stage("raft.stream.commit", _batch_stage):
-                engine.commit_stream(bucket, slots, fmap_rows, cnet_rows,
-                                     seeds, mask)
+                # dispatch only: it queues on the device behind the batch
+                # dispatched after this one, whose rows are other sessions'
+                if mask.any():
+                    call.engine.commit_stream(
+                        bucket, np.where(mask, slots, self.pool.scratch),
+                        fmap_rows, cnet_rows, seeds, mask)
         except Exception:
             # a failed commit leaves the (donated) bucket buffers dead;
             # commit_stream already rebuilt them zeroed — now demote
@@ -490,11 +681,15 @@ class StreamCoordinator:
                 self._demote_shared()
             else:
                 self.store.demote_bucket(bucket)
-            for r in reqs:
-                self._degrade(r)
+            for r, ok in zip(reqs, live):
+                if ok:
+                    self._degrade(r)
             return [None] * n
         out = []
         for i, r in enumerate(reqs):
+            if not live[i]:
+                out.append(None)
+                continue
             if not row_ok[i]:
                 if self.nonfinite is not None:
                     self.nonfinite.inc()

@@ -85,7 +85,7 @@ def stage(name: str):
 # stage with no span (``take``: the batcher waits for requests, which belongs
 # to no request) still writes its annotation and counter.  ``raft.stream.*``
 # are a batched advance's host chain between its two device calls
-# (serving/stream.py ``_warm_batch``), children of ``execute`` as the
+# (serving/stream.py ``_finish_warm``), children of ``execute`` as the
 # engine's stages are.
 HOST_STAGES: Dict[str, Optional[str]] = {
     "raft.http.decode": "decode",

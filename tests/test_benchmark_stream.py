@@ -30,7 +30,9 @@ NEW_METRICS = {
     "stream_warm_share": "server", "stream_fnet_passes_per_pair": "engine",
     "stream_sentinel_ms": "server", "stream_seed_ms": "server",
     "stream_commit_ms": "server", "slot_io_ms": "kernels",
-    "slot_io_roofline": "kernels"}
+    "slot_io_roofline": "kernels",
+    # PR 40: the batched advances ride the batcher's pipeline
+    "stream_staged_ahead_share": "server"}
 # the accepted metrics whose readers, unedited, read the stream path
 SHARED_METRICS = (
     "batch_fill", "host_path_ms", "compile_misses", "device_idle_share",
@@ -494,6 +496,11 @@ def test_served_float32_session_is_the_references_walk(
     advances = FRAMES - 1
     assert prom["raft_serving_device_calls_total"] == advances + 1
     assert prom["raft_serving_batch_size_count"] == advances
+    # a batched advance is a job of the batcher's pipeline (PR 40): it
+    # counts as staged, here behind nothing (one session: never ahead)
+    assert prom['raft_serving_batches_staged_total{when="late"}'] == advances
+    assert prom.get('raft_serving_batches_staged_total{when="ahead"}',
+                    0.0) == 0.0
     assert prom["raft_stream_fnet_cache_hits_total"] == advances
     assert prom["raft_stream_fnet_cache_misses_total"] == 0
     assert prom['raft_stream_encoder_passes_total{call="encode"}'] == 1
@@ -719,6 +726,39 @@ def test_stream_counter_readers(bench_modules, metric, want, on_parent):
             "raft_stream_fnet_cache_misses_total": 10.0})
         assert _read(bench_modules, metric, cold) == pytest.approx(
             100.0 * 270 / 280)
+
+
+_STAGED = "raft_serving_batches_staged_total"
+
+
+@pytest.mark.parametrize("staged,want", [
+    # 35 batched advances of a window, three of them behind an open, a
+    # renewal or an empty queue
+    ({_STAGED + '{when="ahead"}': 32.0, _STAGED + '{when="late"}': 3.0},
+     100.0 * 32 / 35),
+    # a host that is never in time: no series of "ahead"
+    ({_STAGED + '{when="late"}': 35.0}, 0.0),
+    # the parent's window: stream batches are not counted there, and the
+    # cell sends no pair; a window in which nothing was staged
+    ({}, None),
+    ({_STAGED + '{when="ahead"}': 0.0, _STAGED + '{when="late"}': 0.0},
+     None)])
+def test_stream_staged_ahead_share_reader(bench_modules, staged, want):
+    """``batch_staged_ahead_share``'s reader under the stream cell's name:
+    the share of the window's stream batches placed before the batch in
+    front of them was ready, None where the window staged none."""
+    got = _read(bench_modules, "stream_staged_ahead_share",
+                dict(_stream_window("PR 39"), **staged))
+    assert got is None if want is None else got == pytest.approx(want)
+    # the pair cells' metric reads the same counter and keeps its own list
+    assert _read(bench_modules, "batch_staged_ahead_share",
+                 dict(_stream_window("PR 39"), **staged)) == got
+
+
+def test_the_pair_cells_ahead_share_keeps_its_list(cell):
+    entry = next(m for m in cell["bench"]["per_layer"]
+                 if m["name"] == "batch_staged_ahead_share")
+    assert CELL not in entry["workloads"] and len(entry["workloads"]) == 3
 
 
 def _slot_trace(bench_modules, tmp_path, with_gather=True, with_commit=True):
