@@ -519,8 +519,9 @@ def phase_serve(meter, sz: Sizes) -> None:
         result = {}
 
         def client():
-            """4 pairwise requests, one streaming session, healthz,
-            metrics — over HTTP, from a thread of this process."""
+            """4 pairwise requests, one streaming session (demoted by two
+            more and resumed), healthz, metrics — over HTTP, from a thread
+            of this process."""
             try:
                 url = server.url
                 body = npz_body(image1=im1, image2=im2)
@@ -546,11 +547,34 @@ def phase_serve(meter, sz: Sizes) -> None:
                           f"{payload[:200]!r}")
                     stream.append(npz_load(payload)["flow"])
                 result["stream"] = stream
-                st, payload, _ = http_call(
-                    url, "POST", "/v1/stream",
-                    npz_body(op=np.asarray("close"),
-                             session=np.asarray(sid)))
-                check(st == 200, f"/v1/stream close -> {st}")
+                # more sessions than the server's two slots: the second of
+                # these opens takes the first session's slot (LRU), so its
+                # next frame restarts cold from the frame the server kept
+                # (im1): the answer is the pair's, reported warm: false,
+                # and the frame after it is warm again (SERVING.md "Sizing
+                # the slot pool")
+                others = []
+                for _ in range(2):
+                    st, payload, _ = http_call(url, "POST", "/v1/stream",
+                                               npz_body(image=im1))
+                    check(st == 200, f"/v1/stream open -> {st}")
+                    others.append(str(npz_load(payload)["session"]))
+                resumed = []
+                for im in (im2, im1):
+                    st, payload, _ = http_call(
+                        url, "POST", "/v1/stream",
+                        npz_body(session=np.asarray(sid), image=im))
+                    check(st == 200, f"/v1/stream advance of a demoted "
+                          f"session -> {st}: {payload[:200]!r}")
+                    got = npz_load(payload)
+                    resumed.append((got["flow"], bool(got["warm"])))
+                result["resumed"] = resumed
+                for s in others + [sid]:
+                    st, payload, _ = http_call(
+                        url, "POST", "/v1/stream",
+                        npz_body(op=np.asarray("close"),
+                                 session=np.asarray(s)))
+                    check(st == 200, f"/v1/stream close -> {st}")
                 st, payload, _ = http_call(url, "GET", "/healthz")
                 check(st == 200, f"/healthz -> {st}")
                 result["health"] = json.loads(payload)
@@ -569,7 +593,8 @@ def phase_serve(meter, sz: Sizes) -> None:
 
             h, w = im1.shape[:2]
             for name, fl in ([("pair", f) for f in result["flows"]]
-                             + [("stream", f) for f in result["stream"]]):
+                             + [("stream", f) for f in result["stream"]]
+                             + [("resumed", f) for f, _ in result["resumed"]]):
                 check(fl.shape[-3:] == (h, w, 2),
                       f"{name} flow has shape {fl.shape}, want (.., {h}, "
                       f"{w}, 2)")
@@ -580,6 +605,20 @@ def phase_serve(meter, sz: Sizes) -> None:
                   f"{misses} after warm-up: a request compiled")
             check(result["health"].get("status") == "ok",
                   f"/healthz status {result['health'].get('status')!r}")
+            warm = [w for _, w in result["resumed"]]
+            check(warm == [False, True],
+                  f"a session demoted by two later opens answered warm="
+                  f"{warm}: want a cold restart, then a warm advance")
+            from raft_tpu.fleet.manager import parse_prom_text
+            prom = parse_prom_text(result["metrics"])
+            restarts = {c: prom.get('raft_stream_cold_restarts_total'
+                                    f'{{cause="{c}"}}')
+                        for c in ("demoted", "displaced", "degraded")}
+            check(restarts == {"demoted": 1, "displaced": 0, "degraded": 0},
+                  f"raft_stream_cold_restarts_total by cause: {restarts}")
+            lru = prom.get('raft_stream_evictions_total{reason="lru"}')
+            check(lru == 2, f"two slots, three sessions, one resume: want 2 "
+                  f"LRU demotions, /metrics says {lru}")
 
             # reference on the same chip, same weights, same padded pair
             ref_cfg = dc.replace(config, corr_impl="dense",
@@ -602,6 +641,12 @@ def phase_serve(meter, sz: Sizes) -> None:
 
             got = np.asarray(result["flows"][0], np.float32)
             got = got.reshape(got.shape[-3:])
+
+            def apart(a, b):
+                """Largest difference of two answers, px."""
+                return round(float(np.abs(a.reshape(got.shape)
+                                          - b.reshape(got.shape)).max()), 4)
+
             cut = min(SERVE_REF_ITERS, sz.iters)
             epe, mag, rel = rel_epe(flow_of(config, cut),
                                     flow_of(ref_cfg, cut))
@@ -617,9 +662,16 @@ def phase_serve(meter, sz: Sizes) -> None:
                     at_full_depth={"ref_mean_flow_px": round(full[1], 2),
                                    "rel": round(full[2], 4)},
                     http_vs_direct_call_px=direct,
-                    stream_vs_pair_px=round(float(np.abs(
-                        result["stream"][0].reshape(got.shape)
-                        - got).max()), 4))
+                    stream_vs_pair_px=apart(result["stream"][0], got),
+                    # the cold restart computes the same zero-seeded pair
+                    # (im1, im2) through a third program, the solo stream
+                    # step: noted beside the other, and not gated for the
+                    # reason above (at full depth the weights amplify what
+                    # another fusion rounds otherwise)
+                    cold_restart_vs_pair_px=apart(
+                        result["resumed"][0][0], got),
+                    cold_restart_vs_first_advance_px=apart(
+                        result["resumed"][0][0], result["stream"][0]))
             check(direct == 0.0,
                   f"the flow served over HTTP is {direct:.3e} px from a "
                   f"direct call of the same model function")

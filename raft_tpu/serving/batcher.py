@@ -322,7 +322,7 @@ class MicroBatcher:
         them into child spans of ``execute`` on each traced request."""
         calls = tlm_spans.take_device_slot() or []
         if self._stages is not None:
-            for _kind, _span, label, t0, t1, cpu in calls:
+            for _kind, _span, label, t0, t1, cpu, _holds in calls:
                 self._stages.record(label, t1 - t0, cpu)
         return calls
 
@@ -334,8 +334,23 @@ class MicroBatcher:
 
     @staticmethod
     def _device_spans(tr, calls, parent: str) -> None:
-        for kind, span, _label, t0, t1, cpu in calls:
-            tr.span(span, t0, t1, parent=parent, call=kind, cpu=cpu)
+        """``calls`` as child spans of ``parent``.  A stage that ``holds``
+        (``trace.host_stage``) is a container: the stages that ran inside
+        its time are children of ITS span, so every level of the tree adds
+        up to the span above it; the flat ``timings_ms`` view leaves them
+        out (``held_by``, the holding span's name)."""
+        holders = [(t0, t1, span, tr.span(span, t0, t1, parent=parent,
+                                          call=kind, cpu=cpu))
+                   for kind, span, _label, t0, t1, cpu, holds in calls
+                   if holds]
+        for kind, span, _label, t0, t1, cpu, holds in calls:
+            if holds:
+                continue
+            name, sid = next(((n, sid) for a, b, n, sid in holders
+                              if a <= t0 and t1 <= b), (None, None))
+            held = {} if sid is None else {"held_by": name}
+            tr.span(span, t0, t1, parent=sid or parent, call=kind, cpu=cpu,
+                    **held)
 
     def _observe_waste(self, group, padded: int) -> None:
         """raft_batch_padding_waste_ratio: the fraction of one device

@@ -73,7 +73,10 @@ def make_serving_metrics(registry: Registry, config,
             "batch.take, batch.form, batch.pad, engine.h2d, "
             "engine.dispatch, engine.wait, engine.fetch, batch.deliver, "
             "http.encode, http.respond; of a batched /v1/stream advance "
-            "also stream.sentinel, stream.seed, stream.commit) and, inside "
+            "also stream.sentinel, stream.seed, stream.commit, and of a "
+            "cold restart stream.cold.wait, stream.cold.encode, "
+            "stream.cold.step, stream.cold.attach, which hold the engine.* "
+            "seconds of the solo calls inside them) and, inside "
             "batch.deliver, batch.deliver.sentinel",
             labelnames=("stage",)),
         # the same stages on the thread's own CPU clock (time.thread_time):
@@ -211,6 +214,23 @@ def make_stream_metrics(registry: Registry, store,
             "(record evicted outright), degraded (breaker open / faulted "
             "step: features dropped, next advance cold-restarts)",
             labelnames=("reason",)),
+        "cold_restarts": registry.counter(
+            "raft_stream_cold_restarts_total",
+            "Cold restarts of an advance (two encoder passes and a solo "
+            "step; every one is also an fnet cache miss) by cause: demoted "
+            "(the session held no slot when its group was placed: LRU took "
+            "it while the session was parked), displaced (it lost the slot "
+            "between its group's place and dispatch), degraded (its warm "
+            "row faulted and healed cold)",
+            labelnames=("cause",)),
+        "promotions": registry.counter(
+            "raft_stream_promotions_total",
+            "Slots given to a session that held none (an open, a cold "
+            "restart's attach) by result: free (a slot was free), "
+            "demoted_other (an LRU holder was demoted for it: "
+            "raft_stream_evictions_total{reason=\"lru\"}), none (every "
+            "slot pinned by a session in flight: the session stays cold)",
+            labelnames=("result",)),
         "degraded": registry.counter(
             "raft_stream_degraded_total",
             "Stream advances whose warm step faulted (engine error or "
@@ -240,8 +260,14 @@ def make_stream_metrics(registry: Registry, store,
             buckets=tuple(i / 10 for i in range(1, 11))),
     }
     store.evictions = m["evictions"]
-    for call in ("encode", "stream"):     # both children from the start
-        m["encoder_passes"].labels(call)
+    store.promotions = m["promotions"]
+    # every child from the start
+    for family, values in (("encoder_passes", ("encode", "stream")),
+                           ("cold_restarts", ("demoted", "displaced",
+                                              "degraded")),
+                           ("promotions", ("free", "demoted_other", "none"))):
+        for value in values:
+            m[family].labels(value)
     if buckets:
         pool = store.pool
         in_use = registry.gauge(

@@ -315,6 +315,9 @@ class SessionStore:
         # lru (slot demoted), ttl (record reaped), capacity (record
         # evicted outright).  None until wired — the store works bare.
         self.evictions = None
+        # and raft_stream_promotions_total{result=}: free, demoted_other,
+        # none — counted in :meth:`promote`, where the slot is found or not
+        self.promotions = None
 
     # -- accounting (live gauge callbacks, sampled at scrape time) ---------
 
@@ -419,6 +422,7 @@ class SessionStore:
             holders = [s for s in self._sessions.values()
                        if s.has_features and s is not session]
             excess = len(holders) + 1 - self.max_sessions
+            result = "free"
             for s in holders:            # OrderedDict order = LRU first
                 if excess <= 0:
                     break
@@ -426,8 +430,12 @@ class SessionStore:
                     continue
                 self._drop_slot_locked(s)
                 self._evict("lru")
+                result = "demoted_other"
                 excess -= 1
             session.slot = self.pool.alloc(session.bucket)
+            if self.promotions is not None:
+                self.promotions.labels(
+                    "none" if session.slot is None else result).inc()
             return session.slot
 
     def demote(self, session: Session, reason: str) -> None:

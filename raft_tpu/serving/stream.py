@@ -591,7 +591,9 @@ class StreamCoordinator:
                 results[i] = row
         heal = [i for i, row in enumerate(results) if row is None]
         if heal and alone is not None:
-            alone()
+            with host_stage("raft.stream.cold.wait", _batch_stage,
+                            holds=True):
+                alone()
         for i in heal:
             r = group[i]
             if r.abandoned:
@@ -601,7 +603,8 @@ class StreamCoordinator:
                     f"stream step {r.id} abandoned by its handler"))
                 continue
             try:
-                flow, iters_used = self._cold_advance(r.session, r, engine)
+                flow, iters_used = self._cold_advance(
+                    r.session, r, engine, self._restart_cause(call, i))
                 r.warm = False
                 if iters_used is not None:
                     iters_used = int(np.asarray(iters_used).reshape(-1)[0])
@@ -710,6 +713,22 @@ class StreamCoordinator:
                         None))
         return out
 
+    @staticmethod
+    def _restart_cause(call: "GroupCall", i: int) -> str:
+        """Why row ``i`` of the group restarts cold
+        (``raft_stream_cold_restarts_total{cause=}``): ``demoted`` — its
+        session held no slot when the group was placed (LRU took it while
+        the session was parked); ``displaced`` — it held one at the place
+        and none at the dispatch, a run later (a bucket's demotion in
+        between: no policy takes a slot from a session in flight);
+        ``degraded`` — it rode the batched call and faulted."""
+        if i not in call.warm:
+            return "demoted"
+        live = call.live
+        if live is not None and not live[call.warm.index(i)]:
+            return "displaced"
+        return "degraded"
+
     def _degrade(self, req: StreamRequest) -> None:
         """Drop one faulted warm row's slot so its heal (and every later
         advance until re-promotion) runs the transparent cold-restart
@@ -720,21 +739,32 @@ class StreamCoordinator:
             # degraded outranks ok and is always recorder-retained
             req.trace.set_status(tlm_spans.DEGRADED)
 
-    def _cold_advance(self, s: Session, req: StreamRequest, engine):
+    def _cold_advance(self, s: Session, req: StreamRequest, engine,
+                      cause: str):
         """Cold two-encoder restart from the retained previous frame —
         pairwise cost, correct flow.  Session state (slot, last_image) is
         mutated only AFTER the output passes the non-finite sentinel, so
-        a faulted attempt leaves the session exactly where it was."""
+        a faulted attempt leaves the session exactly where it was.  Three
+        host stages (``raft.stream.cold.encode`` / ``.step`` / ``.attach``)
+        hold the engine's stages of the solo calls made inside them
+        (``holds=True``: the batcher nests those spans under theirs)."""
         ab = self._dev(s)
         H, W = ab
-        fmap_p, cnet_p = engine.run_encode(ab, s.last_image)
+        with host_stage("raft.stream.cold.encode", _batch_stage,
+                        holds=True):
+            fmap_p, cnet_p = engine.run_encode(ab, s.last_image)
         init = np.zeros((1, H // 8, W // 8, 2), np.float32)
         self.metrics["fnet_misses"].inc()
+        self.metrics["cold_restarts"].labels(cause).inc()
         sizes = (np.asarray([s.bucket], np.int32)
                  if self.dev_box is not None else None)
-        flow, flow_lr, fmap_c, cnet_c, iters_used = engine.run_stream(
-            ab, req.image1, fmap_p, cnet_p, init, sizes=sizes)
-        if not (np.isfinite(flow).all() and np.isfinite(flow_lr).all()):
+        with host_stage("raft.stream.cold.step", _batch_stage,
+                        holds=True):
+            flow, flow_lr, fmap_c, cnet_c, iters_used = engine.run_stream(
+                ab, req.image1, fmap_p, cnet_p, init, sizes=sizes)
+            finite = bool(np.isfinite(flow).all()
+                          and np.isfinite(flow_lr).all())
+        if not finite:
             # non-finite OUTPUT sentinel (inputs were validated at the
             # HTTP edge): never cache poisoned maps or a poisoned seed
             if self.nonfinite is not None:
@@ -747,7 +777,9 @@ class StreamCoordinator:
             raise NonFiniteOutput(
                 f"non-finite stream output for session {s.id} on a "
                 f"cold step")
-        self._attach(s, engine, fmap_c, cnet_c, flow_lr)
+        with host_stage("raft.stream.cold.attach", _batch_stage,
+                        holds=True):
+            self._attach(s, engine, fmap_c, cnet_c, flow_lr)
         s.last_image = req.image1
         return flow, iters_used
 

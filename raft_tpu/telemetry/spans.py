@@ -115,8 +115,9 @@ def status_of(exc: BaseException) -> str:
 # * the DEVICE SLOT: the batcher opens a list before an engine call; the
 #   engine's host stages (trace.host_stage: h2d, dispatch, wait, fetch —
 #   timed at the only place that can tell them apart) land in it as
-#   (kind, span name, counter label, t0, t1, CPU seconds), and the batcher
-#   turns them into child spans of ``execute`` and stage-seconds increments.
+#   (kind, span name, counter label, t0, t1, CPU seconds, holds), and the
+#   batcher turns them into child spans of ``execute`` and stage-seconds
+#   increments.
 # * the CURRENT TRACE IDS: the trace ids of the batch being executed, so
 #   out-of-band diagnostics (fault_injected, lock_violation, non-finite
 #   sentinel run-log events) are joinable to their request traces.
@@ -139,7 +140,8 @@ def record_device_stage(kind: str, st) -> None:
     device call of ``kind``.  A single thread-local read outside a batch."""
     slot = getattr(_tls, "device_slot", None)
     if slot is not None:
-        slot.append((kind, st.span, st.label, st.t0, st.t1, st.cpu))
+        slot.append((kind, st.span, st.label, st.t0, st.t1, st.cpu,
+                     st.holds))
 
 
 def set_current_trace_ids(ids: Tuple[str, ...]) -> None:
@@ -205,10 +207,15 @@ class RequestTrace:
 
     def timings_ms(self) -> Dict[str, float]:
         """{span name: total ms} — the response's ``meta.timings`` view
-        (same-name spans sum, e.g. bisection re-pads)."""
+        (same-name spans sum, e.g. bisection re-pads).  A span ``held_by``
+        another (a stage opened inside a stage that ``holds``,
+        ``trace.host_stage``) is in that span's time already and is left
+        out, so the names of one level add up to the span above them."""
         out: Dict[str, float] = {}
         with self._lock:
             for s in self._spans:
+                if "held_by" in s:
+                    continue
                 out[s["name"]] = round(out.get(s["name"], 0.0)
                                        + s["dur_ms"], 3)
         return out

@@ -86,7 +86,10 @@ def stage(name: str):
 # to no request) still writes its annotation and counter.  ``raft.stream.*``
 # are a batched advance's host chain between its two device calls
 # (serving/stream.py ``_finish_warm``), children of ``execute`` as the
-# engine's stages are.
+# engine's stages are.  ``raft.stream.cold.*`` are a cold restart's
+# (``finish``, ``_cold_advance``): the wait for the batch staged behind the
+# group, the previous frame's encoder pass, the solo step with its sentinel,
+# the projection and the row's commit; a group without a cold row opens none.
 HOST_STAGES: Dict[str, Optional[str]] = {
     "raft.http.decode": "decode",
     "raft.http.admit": "admit",
@@ -100,6 +103,10 @@ HOST_STAGES: Dict[str, Optional[str]] = {
     "raft.stream.sentinel": "execute_sentinel",
     "raft.stream.seed": "execute_seed",
     "raft.stream.commit": "execute_commit",
+    "raft.stream.cold.wait": "execute_cold_wait",
+    "raft.stream.cold.encode": "execute_cold_encode",
+    "raft.stream.cold.step": "execute_cold_step",
+    "raft.stream.cold.attach": "execute_cold_attach",
     "raft.batch.deliver": "deliver",
     "raft.http.encode": "encode",
     "raft.http.respond": "respond",
@@ -120,13 +127,16 @@ class HostStage:
     (numpy's ``copyto`` and ``isfinite`` release it and still run here) and
     stands still while the thread sleeps on that lock, on a registry lock or
     in the run queue: wall less CPU of a stage that waits for no device and
-    no socket is its lock and scheduler wait."""
+    no socket is its lock and scheduler wait.  ``holds``: the stage is a
+    container, and the stages its thread opens inside it are its parts (the
+    batcher nests their spans under its span by their times)."""
 
-    __slots__ = ("name", "span", "t0", "t1", "c0", "c1")
+    __slots__ = ("name", "span", "holds", "t0", "t1", "c0", "c1")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, holds: bool = False):
         self.name = name
         self.span = HOST_STAGES[name]
+        self.holds = holds
         self.t0 = self.t1 = self.c0 = self.c1 = 0.0
 
     @property
@@ -143,7 +153,8 @@ class HostStage:
 
 
 @contextlib.contextmanager
-def host_stage(name: str, sink: Optional[Callable] = None, **attrs):
+def host_stage(name: str, sink: Optional[Callable] = None,
+               holds: bool = False, **attrs):
     """Time one host stage of ``HOST_STAGES`` under a profiler annotation.
 
     Opens ``jax.profiler.TraceAnnotation(name, batch=<n>, **attrs)`` — with
@@ -151,8 +162,9 @@ def host_stage(name: str, sink: Optional[Callable] = None, **attrs):
     body raised, stamps ``t1`` and ``c1`` and hands the :class:`HostStage` to
     ``sink`` (the caller's span-and-counter recorder), so one site yields all
     four records.  Yields the stage for callers that place the span
-    themselves."""
-    st = HostStage(name)
+    themselves.  ``holds=True`` declares the stage a container of the stages
+    opened inside it (:class:`HostStage`)."""
+    st = HostStage(name, holds)
     batch = getattr(_stack, "batch", None)
     if batch is not None:
         attrs.setdefault("batch", batch)

@@ -26,6 +26,11 @@ BENCH = os.path.join(REPO, "benchmark")
 CELL = "things-stream-sessions"
 CONFIG = "raft-things-1080p-stream"
 MIX = "davis1080p-sessions"
+# PR 41's cell runs the same service with more live sessions than slots and
+# is appended after this one wherever a metric's reader reads right there
+CHURN = "things-stream-churn"
+CHURN_LEFT_OUT = ("gru_roofline", "corr_window_roofline",
+                  "stream_staged_ahead_share")
 NEW_METRICS = {
     "stream_warm_share": "server", "stream_fnet_passes_per_pair": "engine",
     "stream_sentinel_ms": "server", "stream_seed_ms": "server",
@@ -202,16 +207,18 @@ def test_listed_gives_the_cell_its_metrics(cell, run, metric):
     entry = run.find(bench["end_to_end"] + bench["per_layer"], metric,
                      "metric")
     assert run.listed(entry, CELL, reporting)
+    later = [] if metric in CHURN_LEFT_OUT else [CHURN]
     if metric in NEW_METRICS:
-        assert entry["workloads"] == [CELL]
+        assert entry["workloads"] == [CELL] + later
         assert entry["layer"] == NEW_METRICS[metric]
         assert entry["moves"] == "pairs_per_s"
         base = os.path.join(BENCH, "layer_metrics", metric)
         assert os.path.exists(base + ".json") and os.path.exists(base + ".py")
     elif "workloads" in entry:
-        # appended, after the cells that were there
-        assert entry["workloads"][-1] == CELL
-        assert "things-1080p-closed" in entry["workloads"][:-1]
+        # appended, after the cells that were there (and before PR 41's)
+        i = entry["workloads"].index(CELL)
+        assert entry["workloads"][i + 1:] == later
+        assert "things-1080p-closed" in entry["workloads"][:i]
 
 
 @pytest.mark.parametrize("metric", NOT_LISTED)

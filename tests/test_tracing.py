@@ -177,11 +177,13 @@ def test_device_slot_and_ambient_trace_ids():
     spans.set_device_slot([])
     with host_stage("raft.engine.dispatch", sink) as a:
         pass
-    with host_stage("raft.engine.wait", sink) as b:
+    with host_stage("raft.engine.wait", sink, holds=True) as b:
         pass
+    # (the last member: the stage holds the stages opened inside it)
     assert spans.take_device_slot() == [
-        ("pair", "execute_dispatch", "engine.dispatch", a.t0, a.t1, a.cpu),
-        ("pair", "execute_block", "engine.wait", b.t0, b.t1, b.cpu)]
+        ("pair", "execute_dispatch", "engine.dispatch", a.t0, a.t1, a.cpu,
+         False),
+        ("pair", "execute_block", "engine.wait", b.t0, b.t1, b.cpu, True)]
     assert a.t0 <= a.t1 <= b.t0 <= b.t1
     assert a.c0 <= a.c1 <= b.c0 <= b.c1          # the thread's CPU clock
     assert spans.take_device_slot() is None          # take clears
