@@ -550,9 +550,11 @@ def phase_serve(meter, sz: Sizes) -> None:
                 # more sessions than the server's two slots: the second of
                 # these opens takes the first session's slot (LRU), so its
                 # next frame restarts cold from the frame the server kept
-                # (im1): the answer is the pair's, reported warm: false,
-                # and the frame after it is warm again (SERVING.md "Sizing
-                # the slot pool")
+                # (im1): re-seated when its group is placed (the kept
+                # frame's encode and a zero-seeded commit_row), the row
+                # rides the batched step; the answer is the pair's, reported
+                # warm: false, and the frame after it is warm again
+                # (SERVING.md "Sizing the slot pool")
                 others = []
                 for _ in range(2):
                     st, payload, _ = http_call(url, "POST", "/v1/stream",
@@ -619,6 +621,23 @@ def phase_serve(meter, sz: Sizes) -> None:
             lru = prom.get('raft_stream_evictions_total{reason="lru"}')
             check(lru == 2, f"two slots, three sessions, one resume: want 2 "
                   f"LRU demotions, /metrics says {lru}")
+            # the engine calls a restart makes: one encoder pass of the kept
+            # frame beside the three opens', and its frame's as a row of a
+            # batched step like the other three advances'; no solo step and
+            # no wait for a batch staged behind
+            made = {
+                "restarts_batched": prom.get(
+                    "raft_stream_restarts_batched_total"),
+                **{f"{c}_calls": prom.get(
+                    f'raft_stream_encoder_passes_total{{call="{c}"}}')
+                   for c in ("encode", "stream")},
+                **{stage: prom.get('raft_serving_stage_seconds_total'
+                                   f'{{stage="stream.cold.{stage}"}}')
+                   for stage in ("step", "wait")}}
+            check(made == {"restarts_batched": 1, "encode_calls": 4,
+                           "stream_calls": 4, "step": 0, "wait": 0},
+                  f"a restart at its group's place makes one encode call "
+                  f"and rides the batched step: /metrics says {made}")
 
             # reference on the same chip, same weights, same padded pair
             ref_cfg = dc.replace(config, corr_impl="dense",
@@ -664,10 +683,13 @@ def phase_serve(meter, sz: Sizes) -> None:
                     http_vs_direct_call_px=direct,
                     stream_vs_pair_px=apart(result["stream"][0], got),
                     # the cold restart computes the same zero-seeded pair
-                    # (im1, im2) through a third program, the solo stream
-                    # step: noted beside the other, and not gated for the
-                    # reason above (at full depth the weights amplify what
-                    # another fusion rounds otherwise)
+                    # (im1, im2): against the pair program noted and not
+                    # gated for the reason above (at full depth the weights
+                    # amplify what another fusion rounds otherwise); against
+                    # the session's first advance it is the same two
+                    # programs on the same inputs (the encode of im1, the
+                    # batched step of one row from a zero seed): gated
+                    # below, bit for bit
                     cold_restart_vs_pair_px=apart(
                         result["resumed"][0][0], got),
                     cold_restart_vs_first_advance_px=apart(
@@ -675,6 +697,11 @@ def phase_serve(meter, sz: Sizes) -> None:
             check(direct == 0.0,
                   f"the flow served over HTTP is {direct:.3e} px from a "
                   f"direct call of the same model function")
+            again = apart(result["resumed"][0][0], result["stream"][0])
+            check(again == 0.0,
+                  f"a cold restart of (im1, im2) is {again} px from the "
+                  f"session's first advance over the same frames: it did "
+                  f"not start from the kept frame and a zero seed")
             check(rel <= SERVE_REL_TOL,
                   f"the served configuration is {epe:.4f} px (mean EPE) "
                   f"from the dense fp32 reference after {cut} iteration(s), "

@@ -543,22 +543,22 @@ class MicroBatcher:
         self._observe("stream_step_seconds", t1 - t0)
         if err is None:
             # honest device-step accounting: only rows whose result came
-            # from the batched call report its width (r.warm, set by the
-            # coordinator); demoted/healed rows ran solo cold restarts
-            # and report width-1 steps — raft_stream_step_batch and the
-            # shared batch histograms can never claim coalescing the
+            # from the batched call report its width (r.batched, set by the
+            # coordinator: warm rows and rows restarted at the place); rows
+            # healed solo report width-1 steps — raft_stream_step_batch and
+            # the shared batch histograms can never claim coalescing the
             # device didn't actually do
-            warm_rows = sum(1 for r in group if r.warm)
-            cold_rows = n - warm_rows
-            if warm_rows:
+            batched_rows = sum(1 for r in group if r.batched)
+            solo_rows = n - batched_rows
+            if batched_rows:
                 self._observe("stream_steps")
-                self._observe("stream_step_batch", float(warm_rows))
-                self._observe("stream_step_occupancy", warm_rows / padded)
-                self._observe("batch_size", float(warm_rows))
-                self._observe("batch_occupancy", warm_rows / padded)
-            if cold_rows:
-                self._observe("stream_steps", cold_rows)
-                for _ in range(cold_rows):
+                self._observe("stream_step_batch", float(batched_rows))
+                self._observe("stream_step_occupancy", batched_rows / padded)
+                self._observe("batch_size", float(batched_rows))
+                self._observe("batch_occupancy", batched_rows / padded)
+            if solo_rows:
+                self._observe("stream_steps", solo_rows)
+                for _ in range(solo_rows):
                     self._observe("stream_step_batch", 1.0)
                     self._observe("stream_step_occupancy", 1.0)
         exec_sid = tlm_spans.new_span_id()

@@ -199,7 +199,7 @@ def make_stream_metrics(registry: Registry, store,
         "fnet_misses": registry.counter(
             "raft_stream_fnet_cache_misses_total",
             "Advances that cold-restarted (features evicted: two encoder "
-            "passes, pairwise cost, correct flow)"),
+            "passes, the zero-seeded pair, correct flow)"),
         "encoder_passes": registry.counter(
             "raft_stream_encoder_passes_total",
             "Encoder passes (fnet + cnet of one frame) on the stream path, "
@@ -216,17 +216,27 @@ def make_stream_metrics(registry: Registry, store,
             labelnames=("reason",)),
         "cold_restarts": registry.counter(
             "raft_stream_cold_restarts_total",
-            "Cold restarts of an advance (two encoder passes and a solo "
-            "step; every one is also an fnet cache miss) by cause: demoted "
-            "(the session held no slot when its group was placed: LRU took "
-            "it while the session was parked), displaced (it lost the slot "
-            "between its group's place and dispatch), degraded (its warm "
-            "row faulted and healed cold)",
+            "Cold restarts of an advance (the kept frame's encoder pass and "
+            "a zero seed; every one is also an fnet cache miss) by cause: "
+            "demoted (the session held no slot when its group was placed: "
+            "LRU took it while the session was parked; re-seated at the "
+            "place, the row rides the group's batched call: "
+            "raft_stream_restarts_batched_total), displaced (it lost the "
+            "slot between its group's place and dispatch), degraded (its "
+            "row of the batched call faulted); the last two, and a demoted "
+            "row that could not be re-seated, heal through a solo step",
             labelnames=("cause",)),
+        "restarts_batched": registry.counter(
+            "raft_stream_restarts_batched_total",
+            "Cold restarts whose row was served by its group's batched call "
+            "(re-seated at the place: no wait, no solo step); over "
+            "raft_stream_cold_restarts_total the share of restarts that "
+            "took that form"),
         "promotions": registry.counter(
             "raft_stream_promotions_total",
             "Slots given to a session that held none (an open, a cold "
-            "restart's attach) by result: free (a slot was free), "
+            "restart at its group's place, a solo heal's attach) by result: "
+            "free (a slot was free), "
             "demoted_other (an LRU holder was demoted for it: "
             "raft_stream_evictions_total{reason=\"lru\"}), none (every "
             "slot pinned by a session in flight: the session stays cold)",

@@ -19,9 +19,9 @@ gathers each row's cached maps + warm-start seed from its pool slot,
 advances every session in one device call, and scatters the updated
 rows back.  Rows join and leave the batch every step as sessions open,
 advance and close; padding rows are inactive (scratch slot, converged
-from iteration 0, excluded from all metrics).  Session opens and the
-cold-restart path stay solo calls (keyed per session): they run the
-``encode`` executable, which has no batch-mates to share.
+from iteration 0, excluded from all metrics).  Session opens stay solo
+calls (keyed per session): they run the ``encode`` executable, which has no
+batch-mates to share.
 
 Stream steps ride the SAME admission queue and batcher thread as
 ``/v1/flow`` (bounded depth -> 429, deadlines -> 504, graceful drain);
@@ -49,15 +49,29 @@ order on the device is ``sbatch(n+1)``, ``scommit(n)``, ``sbatch(n+2)``:
 every gather sees every earlier scatter of ITS slots, and ``sbatch(n+1)``
 reads rows that ``scommit(n)`` does not write (the scratch slot is never
 served).  A batch of ``/v1/flow`` pairs and an engine with
-``run_stream_batch`` alone begin when the running group has been delivered;
-a cold restart — of a row demoted before its group formed, or healing a
-faulted one — is a device call of its own and runs in ``finish`` after the
-group dispatched behind it has run.  An OPEN goes beside a running group:
-the queue hands it over at once (it coalesces with nothing), and its encoder
-pass and its row's commit are dispatched and never fetched, so they queue
-on the device behind the group, whose rows are other sessions' (an LRU
-demotion the open's promote makes skips sessions in flight); the group's own
-commit then scatters into the buffers as the open's left them.
+``run_stream_batch`` alone begin when the running group has been delivered.
+An OPEN goes beside a running group: the queue hands it over at once (it
+coalesces with nothing), and its encoder pass and its row's commit are
+dispatched and never fetched, so they queue on the device behind the group,
+whose rows are other sessions' (an LRU demotion the open's promote makes
+skips sessions in flight); the group's own commit then scatters into the
+buffers as the open's left them.
+
+**A cold restart waits for nothing.**  A row whose session holds no slot
+when its group is PLACED (LRU gave it away while the session was parked) is
+re-seated there and then (:meth:`StreamCoordinator._reseat`): an open's two
+calls with the frame the service kept and a zero seed, dispatched and never
+fetched.  The row is then a row of the batch: padded, placed, dispatched,
+fetched, checked, projected and committed with its neighbours, and answered
+``warm: false`` (it is a cache miss: two encoder passes; at a fixed
+iteration count a row seeded with zeros IS the zero-seeded pair).  With a
+restart in group n+1 the order on the device is ``sbatch(n)`` (running),
+``encode``, ``scommit`` of width 1, ``sbatch(n+1)`` (gathers the slot just
+written), ``scommit(n)`` (other sessions' slots): the order an open relies
+on.  What is left of the SOLO restart (``_cold_advance``: the kept frame's
+encode and a fetched batch-1 step, device calls of their own that run in
+``finish`` after the group dispatched behind has run) is the heal of the
+fault ladder below, and of a row that could not be re-seated.
 
 Thread model (SERVING.md "Threading model"): the handler thread holds
 ``Session.lock`` across the WHOLE advance — including ``queue.submit``
@@ -70,12 +84,12 @@ handler's session lock keeps any second frame of the same session out;
 slot transitions go through the store (store lock → pool lock, the
 declared edge).
 
-Failure containment, per ROW of a batched step: a warm row that faults —
-the batched call raising (at its place, its dispatch or its wait), or that
+Failure containment, per ROW of a batched step: a row that faults — the
+batched call raising (at its place, its dispatch or its wait), or that
 row's output failing the non-finite sentinel (e.g. a poisoned slot) — is
-demoted and healed through the SAME transparent cold-restart path an
-evicted session takes, in the same advance; its co-batched neighbors keep
-their warm results.  This is the stream path's form of poisoned-row
+demoted and healed through the transparent SOLO cold restart
+(``_cold_advance``), in the same advance; its co-batched neighbors keep
+their results.  This is the stream path's form of poisoned-row
 isolation: the pairwise path bisects because it has no finer fallback, the
 stream path degrades straight to per-row cold restarts (finer blame,
 bounded at two engine calls per row).  A cold attempt that faults is
@@ -135,11 +149,20 @@ class StreamRequest(Request):
     encode executable and have nothing to coalesce with.  Neither key
     ever collides with a pairwise ``(H, W)`` bucket."""
 
-    __slots__ = ("session", "stream_op", "warm", "frame", "abandoned")
+    __slots__ = ("session", "stream_op", "frame", "abandoned", "restarted",
+                 "batched")
 
     @property
     def coalesces(self) -> bool:
         return self.stream_op == "advance"
+
+    @property
+    def warm(self) -> bool:
+        """The answer's ``warm`` member: served from the session's resident
+        slot by the batched call.  A row restarted at the place rode that
+        call too and is a cache miss all the same; a row healed solo is
+        neither."""
+        return self.batched and not self.restarted
 
     def __init__(self, session: Session, op: str, image_padded, pads,
                  deadline: float,
@@ -155,7 +178,13 @@ class StreamRequest(Request):
                          rbucket=tuple(session.bucket))
         self.session = session
         self.stream_op = op              # "open" | "advance"
-        self.warm = False                # set at execute time
+        # a demoted session's row re-seated at its group's place (the kept
+        # frame's encode and a zero-seeded commit_row): it rides the batched
+        # call and answers ``warm: false``
+        self.restarted = False
+        # the answer came from the group's batched call (warm or restarted)
+        # and not from a solo heal: the width its device step reports
+        self.batched = False             # set in the group's finish
         self.frame = 0
         # set by the handler when wait() gives up (batcher stalled past
         # the deadline margin): the batcher must then SKIP the step
@@ -193,7 +222,8 @@ class _WholeBatchCall:
 class GroupCall:
     """One coalesced group of advances between its place and its finish.
     ``warm`` are the group's rows that ride the batched device call (their
-    sessions held a slot at the place), ``call`` that call while one is
+    sessions held a slot at the place, or were given one there: a restarted
+    row), ``call`` that call while one is
     placed or running, ``live`` / ``slots`` its rows as the dispatch found
     them, ``out`` what its fetch brought."""
 
@@ -497,20 +527,62 @@ class StreamCoordinator:
             return None
 
     def place(self, group: List[StreamRequest], engine) -> "GroupCall":
-        """The group's warm rows (their sessions hold a slot now) padded
-        into one batch and their frames put on the device; the rest of the
-        group waits for :meth:`finish`'s cold restarts.  The pad buffer is
-        free again once this returns (``h2d`` waits for the transfer), so
-        one serves the group running and the group staged behind it."""
+        """The group's rows padded into one batch and their frames put on
+        the device.  A row whose session holds no slot now (LRU gave it away
+        while the session was parked) is re-seated first (:meth:`_reseat`)
+        and rides with its neighbours; one that could not be (every slot
+        pinned, or the restart's own calls faulted) waits for
+        :meth:`finish`'s solo heal.  The pad buffer is free again once this
+        returns (``h2d`` waits for the transfer), so one serves the group
+        running and the group staged behind it."""
         if self.faults is not None:
             for r in group:
                 self.faults.corrupt_session(r.session, engine)
         call = GroupCall(group, engine)
+        # (a row whose handler gave up makes no device call; one that holds
+        # a slot now and loses it to a restart's failed commit is healed)
+        for r in [r for r in group
+                  if not r.session.has_features and not r.abandoned]:
+            self._reseat(r, engine)
         call.warm = [i for i, r in enumerate(group)
                      if r.session.has_features]
         if call.warm:
             self._guard(call, self._place_warm, call)
         return call
+
+    def _reseat(self, req: StreamRequest, engine) -> None:
+        """A cold restart that waits for nothing: an open's two calls, with
+        the frame the service kept and a zero seed.  The kept frame's encoder
+        pass and the row's width-1 commit are dispatched and never fetched,
+        so they queue on the device behind the running group (whose rows are
+        other sessions': the promote's LRU demotion skips sessions in
+        flight) and ahead of this group's batched call, which then gathers
+        the slot just written: at a fixed iteration count a row seeded with
+        zeros IS the zero-seeded pair (kept frame, frame).  Session host
+        state moves only when the row has passed the sentinel
+        (:meth:`_finish_warm`).  A restart that cannot be made here leaves
+        the session without a slot, for :meth:`finish`'s solo heal: no slot
+        to be had (``promote``), its encode raising (the slot is given
+        back), its commit raising (``_attach`` demotes the bucket)."""
+        s = req.session
+        if self.store.promote(s) is None:
+            return
+        try:
+            with host_stage("raft.stream.cold.encode", _batch_stage,
+                            holds=True):
+                fmap, cnet = engine.run_encode(self._dev(s), s.last_image)
+            with host_stage("raft.stream.cold.attach", _batch_stage,
+                            holds=True):
+                self._attach(s, engine, fmap, cnet, flow_lr=None)
+        except Exception:
+            if self.breaker is not None:
+                self.breaker.record(False)
+            self.store.demote(s, "degraded")
+            return
+        if s.has_features:
+            self.metrics["fnet_misses"].inc()
+            self.metrics["cold_restarts"].labels("demoted").inc()
+            req.restarted = True
 
     def _place_warm(self, call: "GroupCall") -> None:
         reqs = call.reqs
@@ -533,8 +605,8 @@ class StreamCoordinator:
     def dispatch(self, call: "GroupCall") -> None:
         """Enqueue the placed batch over the pool's buffers, the slots and
         the rows AS THEY STAND NOW: the dispatch can come a whole run after
-        the place, and a commit, a cold restart's ``commit_row`` or a
-        bucket's demotion in between has moved them.  A row whose handler
+        the place, and a commit, an open's ``commit_row`` or a bucket's
+        demotion in between has moved them.  A row whose handler
         gave up since, or whose session lost its slot, goes inactive (an
         argument of the program, not a shape) and is left to
         :meth:`finish`; with no row left the call is skipped — BEFORE the
@@ -576,11 +648,12 @@ class StreamCoordinator:
     def finish(self, call: "GroupCall", alone=None):
         """The end of a group: sentinel, warm-start projections and commit
         of the batched call's rows (:meth:`_finish_warm`), then a solo cold
-        restart for every row it did not serve — demoted at the place or
-        since, and warm rows that faulted (the per-row degradation ladder —
-        see the module docstring).  The restarts are device calls of their
-        own: ``alone()`` (the batcher's) first waits until the batch
-        dispatched behind this one has run.  Returns ``[(padded flow,
+        restart for every row it did not serve — one that could not be
+        re-seated at the place, one displaced since, and rows of the call
+        that faulted (the per-row degradation ladder — see the module
+        docstring).  These heals are device calls of their own: ``alone()``
+        (the batcher's) first waits until the batch dispatched behind this
+        one has run.  Returns ``[(padded flow,
         iters_used, err)]`` aligned with the group; exactly one of
         flow/err is set per row.  Session host state (frames, last_image)
         moves only for rows that succeeded."""
@@ -605,7 +678,6 @@ class StreamCoordinator:
             try:
                 flow, iters_used = self._cold_advance(
                     r.session, r, engine, self._restart_cause(call, i))
-                r.warm = False
                 if iters_used is not None:
                     iters_used = int(np.asarray(iters_used).reshape(-1)[0])
                 results[i] = (flow, iters_used, None)
@@ -626,7 +698,8 @@ class StreamCoordinator:
         whose output passed the sentinel (committed, where the session
         still owns the slot it was gathered from), or None for rows that
         must heal cold (their slots are dropped; nothing poisoned is ever
-        cached)."""
+        cached).  A row restarted at the place is answered ``warm: false``
+        and counts no cache hit."""
         reqs = call.reqs
         n = len(reqs)
         live = call.live or [True] * n
@@ -706,8 +779,10 @@ class StreamCoordinator:
                 out.append(None)
                 continue
             r.session.last_image = r.image1
-            r.warm = True
-            self.metrics["fnet_hits"].inc()
+            r.batched = True
+            # (a restarted row is a miss, answered by the batched call)
+            self.metrics["restarts_batched" if r.restarted
+                         else "fnet_hits"].inc()
             out.append((flow[i:i + 1],
                         None if iters_used is None else int(iters_used[i]),
                         None))
@@ -716,10 +791,12 @@ class StreamCoordinator:
     @staticmethod
     def _restart_cause(call: "GroupCall", i: int) -> str:
         """Why row ``i`` of the group restarts cold
-        (``raft_stream_cold_restarts_total{cause=}``): ``demoted`` — its
+        through the solo heal (``raft_stream_cold_restarts_total{cause=}``;
+        a restart at the place counts ``demoted`` there): ``demoted`` — its
         session held no slot when the group was placed (LRU took it while
-        the session was parked); ``displaced`` — it held one at the place
-        and none at the dispatch, a run later (a bucket's demotion in
+        the session was parked) and could not be given one, or the
+        restart's own calls faulted; ``displaced`` — it held one at the
+        place and none at the dispatch, a run later (a bucket's demotion in
         between: no policy takes a slot from a session in flight);
         ``degraded`` — it rode the batched call and faulted."""
         if i not in call.warm:
@@ -741,8 +818,10 @@ class StreamCoordinator:
 
     def _cold_advance(self, s: Session, req: StreamRequest, engine,
                       cause: str):
-        """Cold two-encoder restart from the retained previous frame —
-        pairwise cost, correct flow.  Session state (slot, last_image) is
+        """The SOLO cold restart from the retained previous frame, the heal
+        of a row its group's batched call did not serve (a restart that can
+        ride the call is :meth:`_reseat`'s) — pairwise cost, correct flow,
+        and the device to itself.  Session state (slot, last_image) is
         mutated only AFTER the output passes the non-finite sentinel, so
         a faulted attempt leaves the session exactly where it was.  Three
         host stages (``raft.stream.cold.encode`` / ``.step`` / ``.attach``)

@@ -848,6 +848,81 @@ def test_group_session_poison_isolated_by_sentinel(chaos_group_server):
         _post_stream(server, {"op": "close", "session": sid})
 
 
+def _parked_pair(server, seed):
+    """Two sessions opened and advanced once, the first then demoted as to
+    LRU while parked: (sids, clips of three frames, its Session)."""
+    rng = np.random.RandomState(seed)
+    seqs = [[rng.rand(32, 48, 3).astype(np.float32) for _ in range(3)]
+            for _ in range(2)]
+    sids = [_post_stream(server, {"image": fr[0].tolist()})["session"]
+            for fr in seqs]
+    out = _coalesced_advance(server, sids, [fr[1] for fr in seqs])
+    assert [r["meta"]["warm"] for r in out] == [True, True]
+    parked = server.streams.store.get(sids[0])
+    server.streams.store.demote(parked, "lru")
+    return sids, seqs, parked
+
+
+def _pairwise(server, seqs, out, t=2):
+    for i, r in enumerate(out):
+        assert np.isfinite(np.asarray(r["flow"])).all()
+        pw = _post_flow(server, seqs[i][t - 1], seqs[i][t])
+        np.testing.assert_allclose(np.asarray(r["flow"], np.float32),
+                                   np.asarray(pw["flow"], np.float32),
+                                   rtol=1e-4, atol=1e-2)
+
+
+def test_group_restart_whose_encode_faults_heals_solo(chaos_group_server):
+    """Chaos ``engine_error`` on the encoder pass of a restart at its
+    group's place: the slot is given back, the row heals through the solo
+    restart in the same advance (cause ``demoted``, 200, the pairwise
+    flow), its batch-mate rides the batched call alone and stays warm, and
+    the healed session is warm on its next frame."""
+    server = chaos_group_server
+    m = server.streams.metrics
+    sids, seqs, parked = _parked_pair(server, 63)
+    before = (m["cold_restarts"].labels("demoted").value,
+              m["restarts_batched"].value, m["degraded"].value)
+    server.faults.force("engine_error", [1])    # the place's first call
+    out = _coalesced_advance(server, sids, [fr[2] for fr in seqs])
+    assert [r["meta"]["warm"] for r in out] == [False, True]
+    _pairwise(server, seqs, out)
+    assert (m["cold_restarts"].labels("demoted").value,
+            m["restarts_batched"].value, m["degraded"].value) == (
+        before[0] + 1, before[1], before[2])
+    assert parked.has_features
+    assert server.engine.compile_misses == 0
+    for sid in sids:
+        _post_stream(server, {"op": "close", "session": sid})
+
+
+def test_group_poisoned_slot_beside_a_restarted_row(chaos_group_server):
+    """Chaos ``session`` arm with a restart in the group: the roll skips the
+    session that holds no slot and poisons its batch-mate's; the sentinel
+    degrades that row alone, the restarted row is served by the batched
+    call, and no answer was gathered from the poison or from zeros."""
+    server = chaos_group_server
+    m = server.streams.metrics
+    sids, seqs, parked = _parked_pair(server, 64)
+    before = (m["cold_restarts"].labels("demoted").value,
+              m["cold_restarts"].labels("degraded").value,
+              m["restarts_batched"].value, m["degraded"].value)
+    nonfinite0 = server._robustness["nonfinite"].value
+    server.faults.force("session", [1])
+    out = _coalesced_advance(server, sids, [fr[2] for fr in seqs])
+    assert [r["meta"]["warm"] for r in out] == [False, False]
+    _pairwise(server, seqs, out)
+    assert (m["cold_restarts"].labels("demoted").value,
+            m["cold_restarts"].labels("degraded").value,
+            m["restarts_batched"].value, m["degraded"].value) == tuple(
+        v + 1 for v in before)
+    assert server._robustness["nonfinite"].value == nonfinite0 + 1
+    assert parked.has_features
+    assert server.engine.compile_misses == 0
+    for sid in sids:
+        _post_stream(server, {"op": "close", "session": sid})
+
+
 def test_session_store_demote_all_skips_inflight():
     store = SessionStore(max_sessions=4, ttl_s=60.0)
     a, b = store.open(BUCKET), store.open(BUCKET)

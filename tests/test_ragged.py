@@ -445,6 +445,57 @@ def test_ragged_stream_mixed_resolutions(ragged_server):
         _post(server, "/v1/stream", {"op": "close", "session": sid})
 
 
+def test_ragged_demoted_session_restarts_at_the_place(ragged_server):
+    """A session that lost its slot while parked comes back as a row of its
+    group's batched call at the shared max box: re-seated at the place with
+    its ROUTED bucket's extent and ``sizes``, answered ``warm: false`` with
+    the pairwise answer on the same frames, beside a warm row of another
+    resolution; no solo step, nothing compiles, and the next advance is
+    warm."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    server, _, _ = ragged_server
+    eng, streams, pool = server.engine, server.streams, server.streams.pool
+    misses = eng.compile_misses
+    rng = np.random.RandomState(14)
+    sizes = [(15, 20), (30, 44)]
+    clips = [[rng.rand(h, w, 3).astype(np.float32) for _ in range(4)]
+             for h, w in sizes]
+    sids = [streams.open(c[0], None)["session"] for c in clips]
+
+    def both(t):
+        with ThreadPoolExecutor(2) as ex:
+            return list(ex.map(lambda k: streams.advance(
+                sids[k], clips[k][t], None), (0, 1)))
+
+    assert [r["meta"]["warm"] for r in both(1)] == [True, True]
+    small = streams.store.get(sids[0])
+    assert small.bucket == (16, 24)
+    streams.store.demote(small, "lru")          # LRU, while it was parked
+    assert pool.used_pixels(small.bucket) == 32 * 48
+    calls = eng.encode_calls, eng.stream_calls
+    batched = streams.metrics["restarts_batched"].value
+    cold, warm = both(2)
+    assert (cold["meta"]["warm"], warm["meta"]["warm"]) == (False, True)
+    assert cold["meta"]["batch_real"] == warm["meta"]["batch_real"] == 2
+    # one encoder pass of the kept frame, two rows of one batched step
+    assert (eng.encode_calls - calls[0], eng.stream_calls - calls[1]) \
+        == (1, 2)
+    assert streams.metrics["restarts_batched"].value - batched == 1
+    # its page is back in the arena at its own extent
+    assert pool.extent(small.bucket, small.slot) == (16, 24)
+    assert pool.used_pixels(small.bucket) == 16 * 24 + 32 * 48
+    assert cold["flow"].shape == (15, 20, 2)
+    pair = server.infer(clips[0][1], clips[0][2]).result
+    np.testing.assert_allclose(cold["flow"], pair, rtol=1e-4, atol=1e-2)
+    after = both(3)
+    assert [r["meta"]["warm"] for r in after] == [True, True]
+    assert all(np.isfinite(r["flow"]).all() for r in after)
+    assert eng.compile_misses == misses
+    for sid in sids:
+        streams.close(sid)
+
+
 def test_ragged_metrics_waste_and_arena(ragged_server):
     """The padding-waste histogram fills from both pairwise and stream
     batches, and the arena live-pixel gauge is exposed (mixed resolutions

@@ -39,9 +39,11 @@ class SlotEngine(PhasedEngine):
     until ``finish``ed."""
 
     def __init__(self, hold=(), run_s=0.0, fail_dispatch=(), fail_wait=(),
-                 nan_rows=(), fail_commits=()):
+                 nan_rows=(), fail_commits=(), fail_encode=()):
         super().__init__(run_s, hold)
         self.pool = None                      # the store's (make_server)
+        self.fail_encode = set(fail_encode)   # frame values, once each
+        self.fail_commit_rows = 0             # the next that many fail
         self.fail_dispatch = set(fail_dispatch)
         self.fail_wait = set(fail_wait)
         self.nan_rows = set(nan_rows)         # (call, row)
@@ -118,6 +120,10 @@ class SlotEngine(PhasedEngine):
 
     def run_encode(self, bucket, image):
         v = float(image[0, 0, 0, 0])
+        if v in self.fail_encode:
+            self.fail_encode.discard(v)
+            self._note("encode_failed", v)
+            raise RuntimeError("the device refused the encoder pass")
         self._note("encode", v)
         return ("fmap", v), ("cnet", v)
 
@@ -132,6 +138,12 @@ class SlotEngine(PhasedEngine):
                 ("fmap", v), ("cnet", v), None)
 
     def commit_row(self, bucket, slot, fmap, cnet, seed):
+        if self.fail_commit_rows:
+            self.fail_commit_rows -= 1
+            self.version += 1
+            self.pool.install(bucket, (self.version, {}))   # rebuilt zeroed
+            self._note("commit_row_failed", int(slot))
+            raise RuntimeError("the width-1 commit failed")
         self._install(bucket, {int(slot): fmap[1]})
         self._note("commit_row", int(slot))
 
@@ -149,6 +161,12 @@ class SlotEngine(PhasedEngine):
         """The (phase, call) record, of ``phases`` alone."""
         with self.cv:
             return [e[:2] for e in self.log if e[0] in phases]
+
+    def saw_some(self, phase, timeout=10.0):
+        """Block until ``phase`` of any call is in the record."""
+        with self.cv:
+            assert self.cv.wait_for(
+                lambda: any(e[0] == phase for e in self.log), timeout), phase
 
     def at(self, phase, i):
         """Position of ``phase`` of call ``i`` in the record."""
@@ -206,6 +224,34 @@ class Sessions:
 
     def session(self, k):
         return self.server.streams.store.get(self.sids[k])
+
+    def demote(self, *ks):
+        """Sessions ``ks`` lose their slots, as to LRU while parked."""
+        for k in ks:
+            self.server.streams.store.demote(self.session(k), "lru")
+            assert not self.session(k).has_features
+
+    def counts(self):
+        """The counters of a restart, and (count, sum) of the step
+        histograms."""
+        m, reg = self.server.streams.metrics, self.server.registry
+        out = {k: m[k].value for k in ("fnet_hits", "fnet_misses",
+                                       "restarts_batched", "degraded")}
+        for cause in ("demoted", "displaced", "degraded"):
+            out["cold_" + cause] = m["cold_restarts"].labels(cause).value
+        out["no_slot"] = m["promotions"].labels("none").value
+        for name in ("raft_stream_step_batch", "raft_serving_batch_size"):
+            h = reg.get(name)
+            out[name] = (h.count, h.sum)
+        return out
+
+    def moved(self, before):
+        """What :meth:`counts` has moved by since ``before``."""
+        now = self.counts()
+        return {k: (tuple(a - b for a, b in zip(now[k], v))
+                    if isinstance(v, tuple) else now[k] - v)
+                for k, v in before.items()
+                if now[k] != v}
 
     def close(self):
         for i in range(len(self.eng.issued)):
@@ -443,31 +489,298 @@ def _stream_open_goes_beside_a_running_group():
     return ss
 
 
-def _stream_cold_restart_waits_for_the_group_in_flight():
-    """A row whose session lost its slot restarts cold in its group's
-    finish, after the group dispatched behind it has run."""
+def _restarts_at_the_place(eng, at=1):
+    """Positions in the record of the encoder passes and width-1 commits
+    made between call ``at - 1``'s dispatch and call ``at``'s ``h2d``."""
+    lo, hi = eng.at("dispatch", at - 1), eng.at("h2d", at)
+    with eng.cv:
+        return [e[:2] for e in eng.log[lo:hi]
+                if e[0] in ("encode", "commit_row")]
+
+
+def _stream_cold_restart_rides_its_group():
+    """A row whose session lost its slot is re-seated when its group is
+    placed: the kept frame's encoder pass and a width-1 commit, dispatched
+    before the group's own dispatch with NO wait for the group in flight in
+    between, and the row is in the placed batch at the group's full width.
+    No solo step, and nothing waits for the group staged behind."""
     eng = SlotEngine(hold=(0, 1, 2))
     ss = Sessions(eng)
-    ss.server.streams.store.demote(ss.session(2), "test")
+    ss.demote(2)
     f0 = ss.advance(0, 1)
     eng.saw("dispatch", 0)
     f1 = ss.advance(2, 3)
     eng.saw("h2d", 1)
-    assert eng.calls[1] == (BUCKET, 1)      # the warm row alone is placed
+    assert eng.calls[1] == (BUCKET, 2)      # at the group's full width
+    slot = ss.session(2).slot
+    assert _restarts_at_the_place(eng) == [("encode", 2.0),
+                                           ("commit_row", slot)]
+    assert not eng.has("wait", 0) and not any(f.done() for f in f0 + f1)
+    row = [r.session.id for r in
+           ss.server.batcher._inflight_batch].index(ss.sids[2])
     eng.finish(0)
+    eng.saw("dispatch", 1)
+    assert int(eng.issued[1].slots[row]) == slot    # gathers what it wrote
     f2 = ss.advance(4, 5)
-    eng.saw("h2d", 2)
+    eng.saw("h2d", 2)                       # staged ahead all the same
     eng.finish(1)
-    eng.saw("commit", 1)
-    time.sleep(0.1)
-    assert not any(e[0] == "cold" for e in eng.log)
-    eng.finish(2)
-    ss.served(2, f1[0], warm=False)
+    ss.served(2, f1[0], warm=False)         # answered before call 2 has run
     ss.served(3, f1[1])
+    assert not eng.has("wait", 2)
+    eng.finish(2)
     for k, f in zip((0, 1, 4, 5), f0 + f2):
         ss.served(k, f)
+    assert not any(e[0] == "cold" for e in eng.log)
+    assert ss.staged("ahead") == 2
+    # its commit went with its neighbour's: the next frame is a warm row
+    assert eng.commits[1] == (1, sorted([slot, ss.session(3).slot]))
+    for k, f in zip((2, 3), ss.advance(2, 3)):
+        ss.served(k, f)
+    return ss
+
+
+def _stream_two_restarts_in_one_group():
+    """Two demoted rows of one group are both re-seated at its place, each
+    into a slot of its own, and both ride."""
+    eng = SlotEngine(hold=(0, 1))
+    ss = Sessions(eng)
+    ss.demote(2, 3)
+    before = ss.counts()
+    f0 = ss.advance(0, 1)
+    eng.saw("dispatch", 0)
+    f1 = ss.advance(2, 3)
+    eng.saw("h2d", 1)
+    assert eng.calls[1] == (BUCKET, 2)
+    slots = [ss.session(k).slot for k in (2, 3)]
+    assert None not in slots and slots[0] != slots[1]
+    placed = _restarts_at_the_place(eng)
+    assert sorted(placed) == sorted(
+        [("encode", 2.0), ("encode", 3.0)]
+        + [("commit_row", s) for s in slots])
+    assert not eng.has("wait", 0)
+    eng.finish(0)
+    eng.finish(1)
+    for k, f in zip((0, 1), f0):
+        ss.served(k, f)
+    for k, f in zip((2, 3), f1):
+        ss.served(k, f, warm=False)
+    assert not any(e[0] == "cold" for e in eng.log)
+    moved = ss.moved(before)
+    assert (moved["fnet_misses"], moved["cold_demoted"],
+            moved["restarts_batched"], moved["fnet_hits"]) == (2, 2, 2, 2)
+    for k, f in zip(range(4), ss.advance(*range(4))):
+        ss.served(k, f)
+    return ss
+
+
+def _stream_restart_without_a_slot_heals_solo():
+    """``promote`` gives no slot (every one pinned by a session in flight):
+    no device call at the place, and the row heals through the solo restart
+    in its group's finish, cause ``demoted``."""
+    eng = SlotEngine(hold=(0,))
+    ss = Sessions(eng, n=4, max_sessions=2)     # 2 and 3 hold the slots
+    assert [ss.session(k).has_features for k in range(4)] == [
+        False, False, True, True]
+    before = ss.counts()
+    f0 = ss.advance(2, 3)
+    eng.saw("dispatch", 0)
+    f1 = ss.advance(0, 1)
+    deadline = time.monotonic() + 5
+    while ss.counts()["no_slot"] - before["no_slot"] < 2:
+        assert time.monotonic() < deadline
+        time.sleep(0.01)
+    assert len(eng.calls) == 1              # nothing placed: no row rides
+    assert len(eng.order("encode")) == 4    # the opens' alone
+    eng.finish(0)
+    for k, f in zip((2, 3), f0):
+        ss.served(k, f)
+    for k, f in zip((0, 1), f1):
+        ss.served(k, f, warm=False)
+    colds = [k for k, e in enumerate(eng.log) if e[0] == "cold"]
+    assert len(colds) == 2 and eng.at("commit", 0) < min(colds)
+    moved = ss.moved(before)
+    assert (moved["cold_demoted"], moved["fnet_misses"]) == (2, 2)
+    assert "restarts_batched" not in moved and "degraded" not in moved
+    # a solo heal is a step of width 1, the group in front one of width 2
+    assert moved["raft_stream_step_batch"] == (3, 4.0)
+    assert moved["raft_serving_batch_size"] == (1, 2.0)
+    return ss
+
+
+def _stream_restart_whose_encode_raises_heals_solo():
+    """The place-time encoder pass raises: the slot is given back, the
+    neighbour rides alone and stays warm, and the row heals solo, cause
+    ``demoted``."""
+    eng = SlotEngine(hold=(0, 1))
+    ss = Sessions(eng)
+    ss.demote(2)
+    eng.fail_encode.add(2.0)                # the kept frame's pass, once
+    in_use = ss.server.streams.pool.in_use(BUCKET)
+    before = ss.counts()
+    f0 = ss.advance(0, 1)
+    eng.saw("dispatch", 0)
+    f1 = ss.advance(2, 3)
+    eng.saw("h2d", 1)
+    assert eng.has("encode_failed", 2.0)
+    assert eng.calls[1] == (BUCKET, 1)      # the neighbour alone is placed
+    assert not ss.session(2).has_features
+    assert ss.server.streams.pool.in_use(BUCKET) == in_use
+    assert _restarts_at_the_place(eng) == []
+    eng.finish(0)
+    eng.finish(1)
+    ss.served(2, f1[0], warm=False)
+    ss.served(3, f1[1])
+    for k, f in zip((0, 1), f0):
+        ss.served(k, f)
     [cold] = [k for k, e in enumerate(eng.log) if e[0] == "cold"]
-    assert eng.at("wait", 2) < cold < eng.at("fetch", 2)
+    assert eng.at("commit", 1) < cold
+    moved = ss.moved(before)
+    assert (moved["cold_demoted"], moved["fnet_misses"], moved["fnet_hits"]) == (
+        1, 1, 3)
+    assert "restarts_batched" not in moved and "degraded" not in moved
+    for k, f in zip((2, 3), ss.advance(2, 3)):
+        ss.served(k, f)                     # healed: warm again
+    return ss
+
+
+def _stream_restart_whose_commit_fails_demotes_the_bucket():
+    """The place-time commit raises with a group in flight: the pool is
+    rebuilt zeroed and every session of the bucket demoted, as after any
+    failed commit.  The group in flight (gathered before the rebuild) is
+    served and commits nothing, this group's rows heal solo, and on their
+    next frames every session restarts from its kept frame: no answer was
+    gathered from the zeros."""
+    eng = SlotEngine(hold=(0,))
+    ss = Sessions(eng)
+    ss.demote(2)
+    eng.fail_commit_rows = 1
+    before = ss.counts()
+    f0 = ss.advance(0, 1)
+    eng.saw("dispatch", 0)
+    f1 = ss.advance(2, 3)
+    eng.saw_some("commit_row_failed")
+    deadline = time.monotonic() + 5         # (the demotion follows it)
+    while ss.session(3).has_features and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert not any(ss.session(k).has_features for k in range(6))
+    eng.finish(0)
+    for k, f in zip((0, 1), f0):
+        ss.served(k, f)                     # sound: served as warm rows
+    for k, f in zip((2, 3), f1):
+        ss.served(k, f, warm=False)
+    assert eng.commits == [] and len(eng.calls) == 1
+    moved = ss.moved(before)
+    assert (moved["cold_demoted"], moved["fnet_misses"]) == (2, 2)
+    assert "restarts_batched" not in moved and "degraded" not in moved
+    # 2 and 3 were healed into the rebuilt pool; the others restart at the
+    # place from the frames the service kept
+    futs = ss.advance(*range(6))
+    for k, f in zip(range(6), futs):
+        ss.served(k, f, warm=k in (2, 3))
+    assert ss.moved(before)["restarts_batched"] == 4
+    for k, f in zip(range(6), ss.advance(*range(6))):
+        ss.served(k, f)
+    return ss
+
+
+def _stream_restarted_row_fails_the_sentinel():
+    """A restarted row whose output is not finite is degraded as any row of
+    the batched call is: its slot dropped, healed solo with cause
+    ``degraded``; its neighbour, restarted too, is served by the call."""
+    eng = SlotEngine(hold=(0, 1), nan_rows=((1, 0),))
+    ss = Sessions(eng)
+    ss.demote(2, 3)
+    before = ss.counts()
+    f0 = ss.advance(0, 1)
+    eng.saw("dispatch", 0)
+    f1 = ss.advance(2, 3)
+    eng.saw("h2d", 1)
+    group = list(ss.server.batcher._inflight_batch)
+    eng.finish(0)
+    eng.finish(1)
+    for k, f in zip((0, 1), f0):
+        ss.served(k, f)
+    bad = ss.sids.index(group[0].session.id)
+    good = ss.sids.index(group[1].session.id)
+    by_k = dict(zip((2, 3), f1))
+    ss.served(bad, by_k[bad], warm=False)
+    ss.served(good, by_k[good], warm=False)
+    assert eng.commits[1] == (1, [ss.session(good).slot])
+    assert len([e for e in eng.log if e[0] == "cold"]) == 1
+    moved = ss.moved(before)
+    assert (moved["cold_demoted"], moved["cold_degraded"], moved["degraded"],
+            moved["restarts_batched"], moved["fnet_misses"],
+            moved["fnet_hits"]) == (2, 1, 1, 1, 3, 2)
+    assert "cold_displaced" not in moved
+    assert ss.server.registry.get("raft_nonfinite_outputs_total").value == 1
+    for k, f in zip((2, 3), ss.advance(2, 3)):
+        ss.served(k, f)
+    return ss
+
+
+def _stream_abandoned_demoted_row_makes_no_device_call():
+    """A demoted row whose handler gave up before the place is not
+    re-seated: no encoder pass, no commit, no solo step; it fails, its
+    neighbour is served and its session is where it was."""
+    from raft_tpu.serving import DeadlineExceeded
+    from raft_tpu.serving.stream import StreamRequest
+    eng = SlotEngine()
+    ss = Sessions(eng, n=4)
+    ss.demote(2)
+    before = ss.counts()
+    reqs = []
+    for k in (2, 3):
+        ss.t[k] += 1
+        reqs.append(StreamRequest(
+            ss.session(k), "advance", _frame(k + ss.t[k] / 100)[None],
+            (0, 0, 0, 0), time.monotonic() + 30.0))
+    reqs[0].abandoned = True
+    logged = len(eng.log)
+    gone, kept = ss.server.streams.execute_group(reqs, eng)
+    assert isinstance(gone[2], DeadlineExceeded) and gone[0] is None
+    assert kept[2] is None and kept[0][0, 0, 0, 0] == np.float32(3.01)
+    assert eng.calls[-1] == (BUCKET, 1)
+    assert [e[0] for e in eng.log[logged:]] == [
+        "h2d", "dispatch", "wait", "fetch", "commit"]
+    assert not ss.session(2).has_features and ss.session(2).frames == 0
+    assert ss.moved(before) == {"fnet_hits": 1}     # the neighbour's
+    ss.t[2] -= 1
+    for k, f in zip((2, 3), ss.advance(2, 3)):
+        ss.served(k, f, warm=k != 2)
+    return ss
+
+
+def _stream_restart_counts_as_a_miss_at_the_groups_width():
+    """The counters of one restart: a cache miss and no hit, one cold
+    restart of cause ``demoted``, one restart served by the batched call,
+    the answer ``warm: false`` and the next ``warm: true``; and the step
+    histograms report the group's width, not a solo step's."""
+    eng = SlotEngine(hold=(0,))
+    ss = Sessions(eng)
+    ss.demote(2)
+    f0 = ss.advance(0, 1)
+    eng.saw("dispatch", 0)
+    before = ss.counts()
+    f1 = ss.advance(2, 3)
+    eng.saw("h2d", 1)
+    eng.finish(0)
+    for k, f in zip((0, 1), f0):
+        ss.served(k, f)
+    res = ss.served(2, f1[0], warm=False)
+    assert (res["meta"]["batch_real"], res["meta"]["batch_padded"]) == (2, 2)
+    ss.served(3, f1[1])
+    moved = ss.moved(before)
+    assert {k: v for k, v in moved.items() if not k.startswith("raft_")} == {
+        "fnet_misses": 1, "cold_demoted": 1, "restarts_batched": 1,
+        "fnet_hits": 3}                     # (0, 1 and 3: the warm rows)
+    # two steps of width 2 (call 0 was observed after ``before`` was taken)
+    assert moved["raft_stream_step_batch"] == (2, 4.0)
+    assert moved["raft_serving_batch_size"] == (2, 4.0)
+    before = ss.counts()
+    [f] = ss.advance(2)
+    ss.served(2, f)
+    assert {k: v for k, v in ss.moved(before).items()
+            if not k.startswith("raft_")} == {"fnet_hits": 1}
     return ss
 
 
@@ -561,7 +874,14 @@ def _stream_crash_fails_running_and_staged_groups():
     _stream_ladder_dispatch_raises, _stream_ladder_wait_raises,
     _stream_ladder_nonfinite_row, _stream_ladder_failed_commit,
     _stream_open_goes_beside_a_running_group,
-    _stream_cold_restart_waits_for_the_group_in_flight,
+    _stream_cold_restart_rides_its_group,
+    _stream_two_restarts_in_one_group,
+    _stream_restart_without_a_slot_heals_solo,
+    _stream_restart_whose_encode_raises_heals_solo,
+    _stream_restart_whose_commit_fails_demotes_the_bucket,
+    _stream_restarted_row_fails_the_sentinel,
+    _stream_abandoned_demoted_row_makes_no_device_call,
+    _stream_restart_counts_as_a_miss_at_the_groups_width,
     _stream_and_pair_batches_drain_each_other,
     _stream_stub_without_phases_walks_blocking,
     _stream_crash_fails_running_and_staged_groups],
@@ -671,6 +991,59 @@ def test_pipelined_walk_answers_as_the_blocking_walk(live):
     # before it, so the same two frames answer differently cold
     cold = live.infer(clips[0][4], clips[0][5]).result
     assert not np.array_equal(cold, piped[0][4]["flow"])
+    assert live.engine.compile_misses == 0
+
+
+def test_restarted_row_answers_the_zero_seeded_pair(live):
+    """On the real engine: a session demoted while parked comes back as a
+    row of its group's batched call, answered ``warm: false`` with the flow
+    of the pair (kept frame, frame) from a zero seed, which is what
+    ``/v1/flow`` answers; the advance after it is warm; and the pipelined walk (groups beside each other) answers bit
+    for bit what the blocking walk (a group at a time) does."""
+    streams, reg = live.streams, live.registry
+    clips = _clips(4, 4, seed=13)
+    batched0 = streams.metrics["restarts_batched"].value
+    solo0 = live.engine.stream_calls, live.engine.encode_calls
+    steps = reg.get("raft_stream_step_batch")
+    steps0 = steps.count, steps.sum
+
+    def walk(groups):
+        sids = [streams.open(c[0], None)["session"] for c in clips]
+        out = [[] for _ in clips]
+        for t in (1, 2, 3):
+            if t == 2:                      # session 0 was parked, and LRU
+                streams.store.demote(streams.store.get(sids[0]), "lru")
+            for g in groups:
+                with ThreadPoolExecutor(len(g)) as pool:
+                    for k, res in zip(g, pool.map(
+                            lambda k: streams.advance(sids[k], clips[k][t],
+                                                      None), g)):
+                        out[k].append(res)
+        for sid in sids:
+            streams.close(sid)
+        return out
+
+    piped = walk([(0, 1, 2, 3)])
+    # 2 walks' worth below; so far: 4 opens, 12 advances, 1 restart's encode
+    assert (live.engine.stream_calls - solo0[0],
+            live.engine.encode_calls - solo0[1]) == (12, 5)
+    alone = walk([(0, 1), (2, 3)])
+    for k in range(4):
+        for t in range(3):
+            p, a = piped[k][t], alone[k][t]
+            cold = (k, t) == (0, 1)
+            assert p["meta"]["warm"] is a["meta"]["warm"] is (not cold)
+            assert p["meta"]["batch_real"] == 2     # never a solo step
+            assert np.array_equal(p["flow"], a["flow"]), (k, t)
+    pair = live.infer(clips[0][1], clips[0][2]).result
+    # (float32, another program: tests/test_ragged.py's stream-against-pair
+    # tolerance; the flows here run to a hundred pixels)
+    np.testing.assert_allclose(piped[0][1]["flow"], pair, rtol=1e-4,
+                               atol=1e-2)
+    assert streams.metrics["restarts_batched"].value - batched0 == 2
+    # twelve groups of two and the eight opens: the restarts made no step
+    # of width 1
+    assert (steps.count - steps0[0], steps.sum - steps0[1]) == (20, 32.0)
     assert live.engine.compile_misses == 0
 
 
