@@ -4,6 +4,7 @@
     python3 chip_smoke.py             # one chip: device, kernels, serve,
                                       # small, train
     python3 chip_smoke.py --chips 4   # four chips: fleet, dp=4 step, spatial-4
+    python3 chip_smoke.py --flows     # one chip: the served flows' sha256
 
 Drives the system's main path once through the entry points a user calls —
 the real ``-m serve`` server and the real ``-m train`` trainer — at the
@@ -11,7 +12,10 @@ published widths of raft-things (``RAFTConfig.full``: fnet 256, hidden 128,
 context 128, 4 levels, radius 4), with seeded random weights and the
 committed Sintel pair ``assets/frame_0016.png`` / ``frame_0017.png``; the
 ``small`` phase runs RAFT-S's served program (hidden 96, radius 3) once at the
-benchmark's 8 x 1080x1920 against ``benchmark/reference.py``.
+benchmark's 8 x 1080x1920 against ``benchmark/reference.py``.  ``--flows``
+is a tool and no part of the smoke: it prints the sha256 of the five served
+programs' flows on a fixed seed, for a change to the lookup that has to leave
+them the parent's bit for bit (run it on both trees in one call).
 
 Contract: one process holds the chip; the first device must be a TPU (no
 probe child, no retry, no CPU); a phase that fails ends the run with a
@@ -47,6 +51,19 @@ WORK = os.path.join(ROOT, ".chip_smoke")
 sys.path.insert(0, ROOT)
 
 
+#: The five served programs that look correlation up (BENCHMARK.json's five
+#: configurations: ``pair`` at each ``/v1/flow`` configuration's frame and
+#: batch, the stream configurations' batched advance and solo step; their
+#: ``encode`` has no lookup): (name, configuration file, kind, batch).
+FLOW_PROGRAMS = (
+    ("things-sintel/pair-32", "raft-things.json", "pair", 32),
+    ("things-1080p/pair-8", "raft-things-1080p.json", "pair", 8),
+    ("small-1080p/pair-8", "raft-small-1080p.json", "pair", 8),
+    ("things-stream/sbatch-8", "raft-things-1080p-stream.json", "sbatch", 8),
+    ("things-stream-churn/stream-1", "raft-things-1080p-stream-churn.json",
+     "stream", 1))
+
+
 @dataclasses.dataclass(frozen=True)
 class Sizes:
     """Every shape the smoke runs at.  ``main`` always uses ``Sizes()`` —
@@ -59,11 +76,13 @@ class Sizes:
     # padder (data.pipeline.pad_to_shape) into the one declared bucket
     bucket: tuple = (440, 1024)
     # the kernels' query grids, with the lookup's channels and radius: the
-    # bucket / 8 (Sintel: level 0 is banded, the others one block each), and
-    # 1080x1920 / 8, whose levels 0 to 2 are banded (each query tile fetches
-    # a band of 16 key rows) and whose GRU rows are 244 stored columns wide
-    # (a VMEM limit of its own), for raft-things and for RAFT-S's lookup
-    # (the three configurations BENCHMARK.json serves)
+    # bucket / 8 (Sintel: levels 0 and 1 are banded, level 1's rows two to a
+    # 128-lane row; levels 2 and 3 one block each, four and eight to a row),
+    # and 1080x1920 / 8, whose levels 0 to 2 are banded (each query tile
+    # fetches a band of 16 key rows; level 2's lie two to a 128-lane row,
+    # level 3's, one block, four) and whose GRU rows are 244 stored columns
+    # wide (a VMEM limit of its own), for raft-things and for RAFT-S's
+    # lookup (the three configurations BENCHMARK.json serves)
     kernel_cases: tuple = ((55, 128, 256, 4), (135, 240, 256, 4),
                            (135, 240, 128, 3))
     # train: the chairs recipe's crop and global batch (config.py
@@ -79,6 +98,12 @@ class Sizes:
     # at the batch and frame size the cell small-1080p-b8-closed times
     small_batch: int = 8
     small_hw: tuple = (1080, 1920)
+    # flows: the five served programs (FLOW_PROGRAMS) at their own frames,
+    # batches and iteration counts; the CPU rehearsal cuts all three
+    flow_programs: tuple = FLOW_PROGRAMS
+    flow_hw: tuple = ()
+    flow_batch: int = 0
+    flow_iters: int = 0
     # --chips 4
     dp_batch: int = 8                 # global batch of the dp=4 comparison
     # the pjit step cannot carry the Pallas kernel ("Mosaic kernels cannot
@@ -358,8 +383,17 @@ def phase_kernels(meter, sz: Sizes) -> None:
         sched = lookup_schedules(coords, shapes, radius)
         errs["corr/scheduled_levels"] = [i for i, s in enumerate(sched)
                                          if s is not None]
+        # rows that share their 128 lanes (PR 43): level -> [map rows to a
+        # 128-lane row, banded]; every served grid has a packed level that
+        # is banded and one that is one block, for either model
+        packed = {i: [p.pack, p.banded] for i, p in enumerate(plans)
+                  if p.pack > 1}
+        errs["corr/packed_levels"] = packed
+        check(sz.interpret or {True, False} <= {b for _, b in packed.values()},
+              f"the levels of {h}x{w} that lay rows side by side, {packed}, "
+              f"do not hold a banded one and a one-block one")
         visited, _, tiles, steps = (int(v) for v in schedule_keyblocks(
-            sched, 1, plans))
+            sched, 1, plans)[:4])
         errs["corr/bands_per_tile"] = round(visited / tiles, 4)
         errs["corr/steps_per_tile"] = round(steps / tiles, 4)
         # the flow above pulls tiles over two and three bands, so its banded
@@ -371,7 +405,7 @@ def phase_kernels(meter, sz: Sizes) -> None:
         smooth = base + 0.4 * jnp.stack(
             [jnp.sin(x / 9.0 + y / 7.0), jnp.cos(x / 8.0 - y / 6.0)], -1)
         _, _, tiles, steps = (int(v) for v in schedule_keyblocks(
-            lookup_schedules(smooth, shapes, radius), 1, plans))
+            lookup_schedules(smooth, shapes, radius), 1, plans)[:4])
         errs["corr/steps_per_tile_smooth"] = round(steps / tiles, 4)
         check(steps == tiles, f"a smooth flow at {h}x{w} takes {steps} grid "
               f"steps for {tiles} (tile, level) pairs: one band a tile is "
@@ -802,7 +836,7 @@ def phase_small(meter, sz: Sizes) -> None:
                 run_seconds=round(time.monotonic() - t0, 3))
         flow = np.asarray(flow, np.float32)
         visited, possible, tiles, steps = (int(v)
-                                           for v in np.asarray(keyblocks))
+                                           for v in np.asarray(keyblocks)[:4])
         finite = bool(np.isfinite(flow).all())
         check(flow.shape == (b, h, w, 2) and finite,
               f"small flow: shape {flow.shape}, finite {finite}")
@@ -823,6 +857,97 @@ def phase_small(meter, sz: Sizes) -> None:
                 steps_per_tile=round(steps / tiles, 4))
         check(verdict["correct"], "small program against "
               "benchmark/reference.py: " + "; ".join(lines))
+
+
+# ------------------------------------------------------------------- flows
+
+FLOW_SEED = 4_300_000_043
+
+
+def served_flow_hashes(sz: Sizes, note=lambda **kv: None) -> dict:
+    """name -> sha256 of the flow each program of ``sz.flow_programs``
+    answers on ``FLOW_SEED``: the configuration's own serve arguments
+    through ``cli.parse_args`` / ``_make_config``, the engine's functions
+    (key-block counts beside the outputs), the benchmark's seeded weights
+    and frames.  The stream kinds advance frame 1's maps (one ``encode``
+    pass, a zero seed) by frame 2; the batched advance gathers them from a
+    pool of ``--max-sessions`` + 1 rows."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from raft_tpu import cli
+    from raft_tpu.models.raft import (make_encode_fn, make_inference_fn,
+                                      make_stream_batch_step_fn,
+                                      make_stream_step_fn)
+
+    bench = os.path.join(ROOT, "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import inputs as binputs
+        import weights as bweights
+    finally:
+        sys.path.remove(bench)
+    out = {}
+    for name, file, kind, batch in sz.flow_programs:
+        with open(os.path.join(bench, "configs", file)) as f:
+            conf = json.load(f)
+        args = cli.parse_args(["-m", "serve"]
+                              + [str(a) for a in conf["serve_args"]])
+        config = cli._make_config(args)
+        h, w = sz.flow_hw or tuple(
+            int(v) for v in args.buckets.split(",")[0].split("x"))
+        b = min(batch, sz.flow_batch or batch)
+        iters = sz.flow_iters or args.iters
+        params = bweights.make_weights(FLOW_SEED, bweights.model_cfg(conf))
+        pairs = binputs.make_pairs(FLOW_SEED, b, h, w, 12)
+        im1, im2 = (jnp.asarray(np.stack([p[i] for p in pairs])
+                                .astype(np.float32) / 255.0) for i in (0, 1))
+        t0 = time.monotonic()
+        if kind == "pair":
+            flow = jax.jit(make_inference_fn(
+                config, iters=iters, keyblocks=True))(params, im1, im2)[0]
+        else:
+            fmap, cnet = jax.jit(make_encode_fn(config))(params, im1)
+            seed = jnp.zeros((b, h // 8, w // 8, 2), jnp.float32)
+            if kind == "stream":
+                flow = jax.jit(make_stream_step_fn(
+                    config, iters=iters, keyblocks=True))(
+                        params, im2, fmap, cnet, seed)[0]
+            else:
+                rows = args.max_sessions + 1
+
+                def pool(x):
+                    return jnp.zeros((rows,) + x.shape[1:],
+                                     x.dtype).at[:b].set(x)
+
+                flow = jax.jit(make_stream_batch_step_fn(
+                    config, iters=iters, keyblocks=True))(
+                        params, im2, pool(fmap), pool(cnet), pool(seed),
+                        jnp.arange(b, dtype=jnp.int32),
+                        jnp.ones((b,), bool))[0]
+        flow = np.asarray(jax.block_until_ready(flow), np.float32)
+        check(flow.shape == (b, h, w, 2) and bool(np.isfinite(flow).all()),
+              f"{name}: flow of shape {flow.shape}, or not finite")
+        out[name] = hashlib.sha256(flow.tobytes()).hexdigest()
+        note(**{name: {"sha256": out[name], "program": f"{b}x{h}x{w}",
+                       "iters": iters,
+                       "mean_abs_flow": round(float(np.abs(flow).mean()), 4),
+                       "seconds": round(time.monotonic() - t0, 2)}})
+    return out
+
+
+def phase_flows(meter, sz: Sizes) -> None:
+    """``--flows``: the five served programs' flows on a fixed seed, hashed.
+    A change to the lookup that moves lanes and multiplies the same products
+    gives the same float32 sums, so the same bytes as its parent on the same
+    chip and the same jax and libtpu: PR 43 held its five to the parent's
+    that way (TUNING.md), as PRs 36 and 38 held their launches.  Nothing is
+    compared here: hashes of another build are not comparable."""
+    with Phase(meter, "flows") as ph:
+        served_flow_hashes(sz, ph.note)
 
 
 # ------------------------------------------------------------------- train
@@ -1201,7 +1326,7 @@ def phase_spatial(meter, sz: Sizes, n: int = 4) -> None:
 
 # -------------------------------------------------------------------- main
 
-def run(chips: int, sz: Sizes) -> dict:
+def run(chips: int, sz: Sizes, flows: bool = False) -> dict:
     """All phases for ``chips``; returns the device record.  Raises on the
     first failure."""
     shutil.rmtree(WORK, ignore_errors=True)
@@ -1220,7 +1345,9 @@ def run(chips: int, sz: Sizes) -> dict:
         emit(**fleet_before_jax(sz))      # before any backend exists here
     meter = CompileMeter()
     device = phase_device(meter, chips) if not sz.interpret else None
-    if chips == 4:
+    if flows:
+        phase_flows(meter, sz)
+    elif chips == 4:
         phase_dp(meter, sz)
         phase_spatial(meter, sz)
     else:
@@ -1241,9 +1368,13 @@ def main(argv=None) -> int:
                         "one chip.  4: only the cross-chip phase — fleet of "
                         "four one-chip replicas, dp=4 train step, spatial-4 "
                         "forward — and what each is compared with")
+    p.add_argument("--flows", action="store_true",
+                   help="no smoke: print the sha256 of the five served "
+                        "programs' flows on a fixed seed (one chip), to hold "
+                        "a change to the lookup to its parent bit for bit")
     args = p.parse_args(argv)
     try:
-        device = run(args.chips, Sizes())
+        device = run(1 if args.flows else args.chips, Sizes(), args.flows)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr, flush=True)
         return 1

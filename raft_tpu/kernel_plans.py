@@ -55,15 +55,16 @@ class CorrLevelPlan:
 
     t: int              # queries per program ([T, C] f1 block)
     qp: int             # padded query count (multiple of t)
-    w2p: int            # stored row width, lane-padded (multiple of LANE)
+    w2: int             # map columns
+    w2p: int            # stored row width (:func:`corr_row_lanes`)
     # the map cut on fixed boundaries: the whole map where it fits one step
     # (``n_pblocks`` 1), the all-rows walk, the ragged launch's pages
-    h2_blk: int         # map rows per f2 row-block
+    h2_blk: int         # map rows per f2 row-block (a multiple of ``pack``)
     rows: int           # map rows before padding
     rows_padded: int    # map rows after padding (multiple of h2_blk)
     n_pblocks: int      # f2 row-block count (the all-rows k grid dimension)
-    # the band a query tile fetches where the map is more than one
-    # row-block (all 0 where it is one): ``band_rows`` consecutive map rows
+    # the band a query tile fetches where the map holds more rows than a
+    # band (all 0 where it does not): ``band_rows`` consecutive map rows
     # from a multiple of ``band_granule`` (:func:`corr_band`)
     band_granule: int = 0       # g: rows of one fetched block of the band
     band_rows: int = 0          # R: a multiple of g, R * w2p <= p_blk_target
@@ -73,15 +74,25 @@ class CorrLevelPlan:
     band_rows_padded: int = 0   # map rows + the zero rows the last band reads
 
     @property
+    def pack(self) -> int:
+        """Map rows that share one 128-lane row of the stored planes: 1
+        where a row fills its lanes, 2, 4 or 8 where it is stored 64, 32 or
+        16 lanes wide."""
+        return max(1, LANE // self.w2p)
+
+    @property
     def banded(self) -> bool:
-        """THE rule for the lookup's band schedule, read from the level's
-        shape alone: a level whose map does not fit one step's positions
-        (more than one row-block of ``p_blk_target``) is visited through a
-        per-tile schedule of the bands its windows touch; a level that does
-        is one whole-map block and pays for no schedule.  At the default
-        4096 positions that is levels 0-2 of 1080x1920's grid and level 0
-        of 440x1024's, for either model (TUNING.md, PR 36)."""
+        """Whether the level is visited through a per-tile schedule of the
+        bands its windows touch (:func:`corr_level_plan` has THE rule, read
+        from the level's shape alone); a level that is not is one whole-map
+        block and pays for no schedule."""
         return self.n_bands > 0
+
+    @property
+    def step_rows(self) -> int:
+        """Map rows a grid step of the launch that runs multiplies and
+        selects over: a band's, or the one block's."""
+        return self.band_rows if self.banded else self.h2_blk
 
     @property
     def band_granules(self) -> int:
@@ -104,6 +115,20 @@ CORR_BAND_GRANULE_ROWS = 4
 CORR_BAND_FLOW_ROWS = 2
 
 
+def corr_row_lanes(w2: int) -> int:
+    """Lanes a map row of ``w2`` columns is stored in: the smallest of 16,
+    32, 64 and 128 that holds them, a multiple of 128 above that.  Under 128
+    lanes ``128 // lanes`` consecutive map rows share one 128-lane row of
+    the planes (a contiguous ``[H2, 64, C]`` map IS ``[H2 / 2, 128, C]``),
+    so a pooled level multiplies and selects over its columns and not over
+    the zeros that padded each row to a vector register: at 1080x1920
+    level 2 is 60 columns in 64 lanes and level 3 is 30 in 32, at 440x1024
+    levels 1-3 are 64, 32 and 16 (level 1 at 1080x1920 is 120 of 128 and
+    level 0 240 of 256: as they were)."""
+    return (round_up(w2, LANE) if w2 > LANE // 2
+            else max(TAP_LANES, 1 << (w2 - 1).bit_length()))
+
+
 def corr_band(t: int, w2: int, w2p: int, cap_rows: int, radius: int,
               grid_w: int):
     """``(g, R)`` of a banded level: the granule a band starts on a multiple
@@ -115,10 +140,11 @@ def corr_band(t: int, w2: int, w2p: int, cap_rows: int, radius: int,
     that starts on a multiple of ``g`` at or under the first row needs
     ``g - 1`` more: 16 rows at either model's radius at 1080x1920 and
     440x1024.  Both are capped by the positions of one step (``cap_rows =
-    p_blk_target // w2p``)."""
-    scale = 1 << ((grid_w // w2).bit_length() - 1)
+    p_blk_target // w2p``), and the granule is whole 128-lane rows of the
+    planes (a multiple of the rows that share one)."""
+    scale = 1 << (max(1, grid_w // w2).bit_length() - 1)
     span = -(-(1 + max(t - 2, 0) // grid_w) // scale)
-    g = min(CORR_BAND_GRANULE_ROWS, cap_rows)
+    g = max(min(CORR_BAND_GRANULE_ROWS, cap_rows), LANE // w2p)
     need = 2 * radius + 2 + span + CORR_BAND_FLOW_ROWS + g - 1
     return g, max(g, min(round_up(need, g), cap_rows // g * g))
 
@@ -130,23 +156,39 @@ def corr_level_plan(q: int, h2: int, w2: int, *, q_blk: int,
     the exact padding/blocking arithmetic ``_lookup_level`` executes.
     ``q`` queries of a grid ``grid_w`` wide look up windows of ``radius`` in
     a map of ``h2`` x ``w2``; ``p_blk_target`` caps the key positions of one
-    grid step.  This is the only place that decides whether a level is
-    banded, and its ``R`` and ``g``."""
+    grid step.  This is the only place that decides how wide a row is
+    stored (:func:`corr_row_lanes`), whether a level is banded, and its
+    ``R`` and ``g``.
+
+    THE rule for the band schedule, from the level's shape alone: a level
+    is banded where its map holds more rows than a band needs (``h2 > R``,
+    :func:`corr_band`), so that no tile multiplies over more map rows than
+    its windows can touch; a level of ``R`` rows or fewer is one whole-map
+    block and pays for no schedule.  At the default 4096 positions ``R`` is
+    16 at either model's radius (3 and 4), so that is levels 0-2 of
+    1080x1920's grid (135, 67 and 33 rows; level 3 is 16) and levels 0-1 of
+    440x1024's (55 and 27 rows; levels 2 and 3 are 13 and 6).  Until PR 43
+    the rule was "more than one row-block of ``p_blk_target``", which is
+    the same levels at 1080x1920 while rows were stored 128 lanes wide, and
+    left 440x1024's level 1 (27 rows) one block of 3456 positions
+    (TUNING.md, PR 36 and PR 43)."""
     if h2 <= 0 or w2 <= 0:
         raise ValueError(f"degenerate level {h2}x{w2}: the kernel "
                          f"short-circuits these to zeros before planning")
     t = q_blk if q >= q_blk else round_up(q, SUBLANE)
     qp = round_up(q, t)
-    w2p = round_up(w2, LANE)
-    cap_rows = max(1, p_blk_target // w2p)
-    h2_blk = min(h2, cap_rows)
+    w2p = corr_row_lanes(w2)
+    pack = max(1, LANE // w2p)
+    # a block is whole 128-lane rows of the planes: a multiple of ``pack``
+    cap_rows = max(pack, p_blk_target // w2p // pack * pack)
+    h2_blk = min(round_up(h2, pack), cap_rows)
     rows_padded = round_up(h2, h2_blk)
-    plan = CorrLevelPlan(t=t, qp=qp, w2p=w2p, h2_blk=h2_blk, rows=h2,
+    plan = CorrLevelPlan(t=t, qp=qp, w2=w2, w2p=w2p, h2_blk=h2_blk, rows=h2,
                          rows_padded=rows_padded,
                          n_pblocks=rows_padded // h2_blk)
-    if plan.n_pblocks == 1:
-        return plan
     g, band = corr_band(t, w2, w2p, cap_rows, radius, grid_w)
+    if h2 <= band:
+        return plan
     # a band starts at or under the map's last row, on a multiple of g
     return dataclasses.replace(
         plan, band_granule=g, band_rows=band, n_bands=-(-h2 // band),

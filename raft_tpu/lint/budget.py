@@ -112,8 +112,9 @@ def corr_vmem_envelope(config, bucket: Tuple[int, int],
                                radius=config.corr_radius, grid_w=w0)
         # the key rows of one grid step of the plan that runs: a band's R
         # rows (its R/g granule blocks, each double-buffered: what R rows
-        # in one block take) or the one whole-map block
-        step_rows = plan.band_rows if plan.banded else plan.h2_blk
+        # in one block take) or the one whole-map block, each row in the
+        # lanes it is stored in (16 to 128, or a multiple of 128)
+        step_rows = plan.step_rows
         pblk = step_rows * plan.w2p
         # An upper envelope of the least scoped limit the chip's compiler
         # accepts for a launch (v5e, jax 0.9.0, found by bisection in the

@@ -53,10 +53,12 @@ class RAFTOutput(NamedTuple):
     # repeat sample b's frozen flow — never stale intermediates — so the
     # sequence loss and --dump-flow stay correct.
     iters_used: Optional[jax.Array] = None
-    # int32 [visited, possible, tiles, steps]: over every iteration run, the
-    # (query tile, band of key rows) grid steps the fused correlation kernel
-    # did work in, what a walk of every band would take, the (query tile,
-    # level) pairs it looked up and the grid steps its launches took
+    # int32 [visited, possible, tiles, steps, stored, live]: over every
+    # iteration run, the (query tile, band of key rows) grid steps the fused
+    # correlation kernel did work in, what a walk of every band would take,
+    # the (query tile, level) pairs it looked up, the grid steps its launches
+    # took, and the key positions those steps multiplied over as stored and
+    # as the maps hold them
     # (ops/corr_pallas.schedule_keyblocks) — None off the dense Pallas
     # lookup.
     corr_keyblocks: Optional[jax.Array] = None
@@ -393,7 +395,7 @@ def _iterate_flow(params, fmap1: jax.Array, fmap2: jax.Array,
                                          config.hidden_dim,
                                          small=config.small)
 
-    kb0 = jnp.zeros((4,), jnp.int32)
+    kb0 = jnp.zeros((6,), jnp.int32)
 
     def gru_step(net, coords1):
         """One GRU update — shared by every loop form below.  Returns the

@@ -30,12 +30,22 @@ vector and cross-lane units):
   dimension, :func:`schedule_steps`: one on served flow), not as many as the
   level has bands: a step that visits nothing still cost 0.2-0.3 us of the
   4-6 a tile (TUNING.md, PR 38).
+* WHERE a row lies in the lanes is the level's width
+  (``kernel_plans.corr_row_lanes``): a row of 64, 32 or 16 columns or fewer
+  is stored that many lanes wide, so 2, 4 or 8 map rows share one 128-lane
+  row of the planes and of the tile (a contiguous ``[H2, 64, C]`` map IS
+  ``[H2 / 2, 128, C]``: :func:`pad_planes` pads, nothing is relaid).  The
+  MXU multiplies, and the selection walks, a pooled level's columns and not
+  the zeros that padded each row to a vector register: half, three quarters
+  and seven eighths of levels 2 and 3 at 1080x1920 and of levels 1-3 at
+  440x1024 until PR 43 (TUNING.md).
 * A query's (2r+1)^2 bilinear window needs the (2r+2)^2 integer taps around
   it.  A visited block SELECTS the taps that lie in it, exactly
-  (:func:`_window_taps`): each map row of the tile is gathered along its
-  lanes at the query's own columns (``tpu.dynamic_gather``, a different
-  rotation in every sublane), eight rows are packed into one 128-lane tile,
-  and a second gather picks the query's own rows.  Lanes move; nothing is
+  (:func:`_window_taps`): each 128-lane chunk of the tile (a map row, a
+  half of one, or 2, 4 or 8 narrow ones) is gathered along its lanes at the
+  query's own columns (``tpu.dynamic_gather``, a different rotation in
+  every sublane), eight map rows are packed into one 128-lane tile, and a
+  second gather picks the query's own rows.  Lanes move; nothing is
   multiplied, so a tap is the float32 correlation sum bit for bit, a tap in
   other rows or off the map is an exact zero (zeros padding for free),
   and windows that reach past a step's rows add up across the k grid
@@ -212,17 +222,24 @@ def _window_taps(first_row, f1_ref, coords_ref, f2_refs, *,
     query's window is a different set of lanes for every sublane: that is a
     lane gather (``tpu.dynamic_gather``), not a matmul.  Three moves:
 
-    * columns: each map row of the block (a 128-lane chunk of the tile, two
-      where a row takes 256 lanes) is gathered at ``ix0 + i``, the sixteen
-      window columns repeated in every group of sixteen lanes;
+    * columns: each 128-lane chunk of the tile is gathered at the query's
+      columns ``ix0 + i``, the sixteen window columns repeated in every
+      group of sixteen lanes.  Where a map row is stored ``w2`` >= 128 lanes
+      wide a chunk is (a part of) one map row (two gathers and a select
+      where a row takes 256 lanes); where it is stored 64, 32 or 16 lanes
+      wide (``kernel_plans.corr_row_lanes``) a chunk is ``p`` = 2, 4 or 8
+      map rows side by side and ONE gather serves them all: lane group
+      ``g`` reads map row ``g % p`` of the chunk, at lanes ``(g % p) * w2 +
+      (ix0 + i) % w2``;
     * pack: eight map rows share one 128-lane tile, row ``y`` keeping group
-      ``y % 8`` (a select on a static lane mask);
+      ``y % 8`` (a select on a static lane mask: of one group a map row, of
+      ``p`` groups a chunk, none where a chunk is a whole tile);
     * rows: a query's window rows ``iy0 + j`` lie in at most three such
       tiles; they are chosen per query and gathered once more, which rotates
       the groups so that window row ``j`` lands in group ``j % 8``.
 
-    The cost goes with the rows of the step, and no bilinear weight enters
-    here (TUNING.md has the us a step)."""
+    The cost goes with the 128-lane chunks of the step, and no bilinear
+    weight enters here (TUNING.md has the us a step)."""
     T = f1_ref.shape[1]
     corrs = [_corr_tile(f1_ref, ref, corr_precision) for ref in f2_refs]
     rows_each = f2_refs[0].shape[2] // w2       # [T, rows_each * w2] each
@@ -231,17 +248,27 @@ def _window_taps(first_row, f1_ref, coords_ref, f2_refs, *,
     zeros = jnp.zeros((T, LANE), jnp.float32)
 
     x = ix0 + col                               # the map column a lane wants
-    src, chunk_of = x & (LANE - 1), x >> _LANE_SHIFT
+    chunks, p = max(1, w2 // LANE), max(1, LANE // w2)
+    chunk_of = x >> _LANE_SHIFT
+    src = x & (min(w2, LANE) - 1)
+    keeps = grp             # the chunk, of a tile's 8 / p, a lane group keeps
+    if p > 1:               # and the lanes of its map row within the chunk
+        src = src | ((grp & (p - 1)) << (w2.bit_length() - 1))
+        keeps = grp >> (p.bit_length() - 1)
     packed = []
-    for y in range(rows_each * len(f2_refs)):
+    for y in range(0, rows_each * len(f2_refs), p):
         corr, row = corrs[y // rows_each], None
-        for c in range(w2 // LANE):
+        for c in range(chunks):
             at = y % rows_each * w2 + c * LANE
             got = _take(corr[:, at:at + LANE], src)
             row = got if row is None else jnp.where(chunk_of == c, got, row)
+        if p == _ROWS_A_TILE:                   # the chunk is a packed tile
+            packed.append(row)
+            continue
         if y % _ROWS_A_TILE == 0:
             packed.append(zeros)
-        packed[-1] = jnp.where(grp == y % _ROWS_A_TILE, row, packed[-1])
+        packed[-1] = jnp.where(keeps == y % _ROWS_A_TILE // p, row,
+                               packed[-1])
 
     r0 = iy0 - first_row                # first window row, in the step's rows
     first_tile = r0 >> _ROW_SHIFT
@@ -499,18 +526,24 @@ def lookup_schedules(coords: jax.Array, shapes, radius: int,
 
 
 def schedule_keyblocks(schedules, batch: int, plans) -> jax.Array:
-    """int32 ``[visited, possible, tiles, steps]`` of one lookup of ``batch``
-    maps under ``plans`` (:func:`level_plans`): the (query tile, band) grid
-    steps that did work, the steps a walk of every band of every level would
-    take, the (query tile, level) pairs, and the grid steps the launches
-    took, so that ``visited / tiles`` is the bands a tile visited a level
-    (1.0: every tile's windows lay in one band) and ``steps / tiles`` the
-    steps it was charged for (1.0: no launch took a step that did nothing).
+    """int32 ``[visited, possible, tiles, steps, stored, live]`` of one
+    lookup of ``batch`` maps under ``plans`` (:func:`level_plans`): the
+    (query tile, band) grid steps that did work, the steps a walk of every
+    band of every level would take, the (query tile, level) pairs, and the
+    grid steps the launches took, so that ``visited / tiles`` is the bands a
+    tile visited a level (1.0: every tile's windows lay in one band) and
+    ``steps / tiles`` the steps it was charged for (1.0: no launch took a
+    step that did nothing); then the key positions those steps multiplied
+    and selected over, per level the steps x the map rows of a step x the
+    lanes a row is stored in (``stored``) and x the map's own columns
+    (``live``): ``live / stored`` is the share of the MXU's and the
+    selection's lanes that held a key.  (int32: eight 1080p maps are 16e6
+    stored positions a lookup, 0.19e9 over a call's twelve iterations.)
     A banded level's counts are reduced from the schedule its kernel is
     given (:func:`_tile_bands`; its grid is :func:`schedule_steps` a tile);
     a level walked without one visits all the row-blocks it has, one where
     the map is one block."""
-    visited = steps = jnp.int32(0)
+    visited = steps = stored = live = jnp.int32(0)
     possible = tiles = 0
     for plan, S in zip(plans, schedules):
         if plan is None:
@@ -518,23 +551,34 @@ def schedule_keyblocks(schedules, batch: int, plans) -> jax.Array:
         level_tiles = batch * (plan.qp // plan.t)
         tiles += level_tiles
         if S is None:
-            blocks = level_tiles * plan.n_pblocks
-            possible += blocks
-            visited, steps = visited + blocks, steps + blocks
+            rows = plan.h2_blk
+            level_steps = level_tiles * plan.n_pblocks
+            possible += level_steps
+            visited = visited + level_steps
         else:
+            rows = plan.band_rows
+            level_steps = level_tiles * schedule_steps(S, plan)
             possible += level_tiles * plan.n_bands
             visited = visited + jnp.sum(_tile_bands(S, plan))
-            steps = steps + level_tiles * schedule_steps(S, plan)
-    return jnp.stack([visited, jnp.int32(possible), jnp.int32(tiles), steps])
+        steps = steps + level_steps
+        stored = stored + level_steps * (rows * plan.w2p)
+        live = live + level_steps * (rows * plan.w2)
+    return jnp.stack([visited, jnp.int32(possible), jnp.int32(tiles), steps,
+                      stored, live])
 
 
 def pad_planes(f2: jax.Array, plan) -> jax.Array:
     """Term planes ``[n, B, H2, W2, C]`` of a level with the zero rows and
-    lanes its launch reads (``plan``): the lanes to whole vector registers,
-    the rows to what the last band (or row-block) reaches.  Zero keys
-    correlate to zero: identical to zeros padding at the map's edge.  A
-    caller that looks up many times does this once (:class:`FusedLookup`);
-    planes that already have the shape pass through."""
+    lanes its launch reads (``plan``): the lanes to the width a row is
+    stored in (``kernel_plans.corr_row_lanes``: a vector register or more,
+    or the half, quarter or eighth of one that holds a pooled level's
+    columns), the rows to what the last band (or row-block) reaches.  Zero
+    keys correlate to zero: identical to zeros padding at the map's edge.
+    Flattened to positions, as every launch takes them, 2, 4 or 8 narrow
+    rows then lie side by side in each 128 lanes: the packed layout is this
+    pad and no relayout.  A caller that looks up many times does this once
+    (:class:`FusedLookup`); planes that already have the shape pass
+    through."""
     rows = plan.band_rows_padded if plan.banded else plan.rows_padded
     H2, W2 = f2.shape[2:4]
     if (H2, W2) == (rows, plan.w2p):
@@ -583,8 +627,8 @@ def _lookup_level(f1: jax.Array, f2_level: jax.Array, coords: jax.Array,
         # launch is held against): fixed blocks, their own row padding
         f2 = f2[:, :, :H2, :W2]
         plan = dataclasses.replace(plan, n_bands=0)
-    # W2 is padded to the lane width (the vector unit would have padded the
-    # lanes anyway) and the rows to what the last step reads
+    # W2 is padded to the width a row is stored in and the rows to what the
+    # last step reads; as positions, narrow rows share their 128 lanes
     f2 = pad_planes(f2, plan).reshape(n_terms, B, -1, C)
     taps, write = _level_body(C, level, radius, plan, corr_precision)
     out_shape = jax.ShapeDtypeStruct((B, Qp, n * n), out_dtype)

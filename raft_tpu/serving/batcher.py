@@ -125,6 +125,22 @@ def _fresh_error(e: BaseException) -> BaseException:
         return e
 
 
+def finite_rows(n: int, *outputs, live=None) -> np.ndarray:
+    """The non-finite OUTPUT sentinel, row by row: bool ``[n]``, True where
+    row ``i`` of every array of ``outputs`` (a device call's flow, and its
+    ``flow_lr`` on the stream path) is finite throughout, and ``live[i]``
+    where given.  Rows past ``n`` (padding) are not looked at.  One pass and
+    one boolean temporary of a ROW at a time: the form the stream path
+    always had.  ``_deliver``'s own, ``np.isfinite(flows[:n].reshape(n,
+    -1)).all(axis=1)`` with a boolean temporary of the whole batch (33 MB at
+    eight 1080p rows), read 216-269 ms a batch on the benchmark's host where
+    this form read 8.8-9.0 over the same bytes (ledger, PR 42:
+    ``deliver_sentinel_ms`` / ``stream_sentinel_ms``)."""
+    return np.array([(live is None or bool(live[i]))
+                     and all(bool(np.isfinite(out[i]).all())
+                             for out in outputs) for i in range(n)], bool)
+
+
 def planar_batch(bufs: list, i: int, frames: list,
                  padded: int) -> np.ndarray:
     """``frames`` ([1, H, W, C] each), and the last one again up to
@@ -915,7 +931,7 @@ class MicroBatcher:
         # INSIDE batch.deliver (no annotation: trace.SENTINEL)
         t0, c0 = time.monotonic(), time.thread_time()
         flows = np.asarray(flows)
-        row_ok = np.isfinite(flows[:n].reshape(n, -1)).all(axis=1)
+        row_ok = finite_rows(n, flows)
         if self._stages is not None:
             self._stages.record(SENTINEL, time.monotonic() - t0,
                                 time.thread_time() - c0)

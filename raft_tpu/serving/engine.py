@@ -241,13 +241,14 @@ class InferenceEngine:
         self.encode_calls = 0     # fnet-pass accounting: 1 per encode call,
         self.stream_calls = 0     # 1 per stream step (the acceptance
         self.pair_calls = 0       # criterion's counters), 2 per pair row
-        # [visited, possible, tiles, steps] of the lookup's band schedule
-        # over the pair batches and stream steps run so far
+        # [visited, possible, tiles, steps, stored, live] of the lookup's
+        # band schedule over the pair batches and stream steps run so far
         # (RAFTOutput.corr_keyblocks; the
         # server turns their growth into raft_serving_corr_keyblocks_*_total,
-        # raft_serving_corr_tiles_total and
-        # raft_serving_corr_grid_steps_total)
-        self.corr_keyblocks = [0, 0, 0, 0]
+        # raft_serving_corr_tiles_total,
+        # raft_serving_corr_grid_steps_total and
+        # raft_serving_corr_key_positions_total)
+        self.corr_keyblocks = [0, 0, 0, 0, 0, 0]
         self.weight_version = 1   # bumped by reload(); healthz reports it
         self.weight_tag = None
         self.warmup_seconds = 0.0
@@ -630,8 +631,8 @@ class InferenceEngine:
                 jax.device_put(list(arrays), list(shardings)))
 
     def _count_keyblocks(self, counts) -> None:
-        """Add one call's [visited, possible, tiles, steps] (fetched beside
-        its outputs) to ``corr_keyblocks``."""
+        """Add one call's [visited, possible, tiles, steps, stored, live]
+        (fetched beside its outputs) to ``corr_keyblocks``."""
         with self._lock:
             for i, v in enumerate(counts):
                 self.corr_keyblocks[i] += int(v)

@@ -144,6 +144,15 @@ def make_serving_metrics(registry: Registry, config,
             "skipped: a banded launch takes as many a tile as the tile of "
             "the batch that needs most bands; grid_steps / tiles is 1.0 "
             "where no launch took a step that did nothing"),
+        "corr_key_positions": registry.counter(
+            "raft_serving_corr_key_positions_total",
+            "Key positions the correlation lookup's grid steps multiplied "
+            "and selected over, counted beside them by the levels' block "
+            "plans (ops/corr_pallas.schedule_keyblocks): stored (a step's "
+            "map rows x the lanes a row is stored in) and live (x the map's "
+            "own columns); live / stored is the share of the lanes that held "
+            "a key",
+            labelnames=("kind",)),
         "iters_used": (iters_used := registry.histogram(
             "raft_iters_used",
             "GRU iterations spent per request — fills only under "

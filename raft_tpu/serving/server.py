@@ -48,6 +48,13 @@ from .stream import StreamCoordinator
 
 _log = get_logger("serve")
 
+#: (metric, label or None) of each of the engine's ``corr_keyblocks``.
+_KEYBLOCK_COUNTERS = (("keyblocks_visited", None),
+                      ("keyblocks_possible", None), ("corr_tiles", None),
+                      ("corr_grid_steps", None),
+                      ("corr_key_positions", "stored"),
+                      ("corr_key_positions", "live"))
+
 
 class BatcherSupervisor:
     """Restart-on-crash policy for the batcher daemon (the device-owning
@@ -306,12 +313,12 @@ class FlowServer:
             self.metrics["compile_misses"].inc(
                 self.engine.compile_misses - before)
         # (an engine without the counts, a stub's say, zips to nothing)
-        for name, was, now in zip(("keyblocks_visited", "keyblocks_possible",
-                                   "corr_tiles", "corr_grid_steps"),
-                                  blocks, getattr(self.engine,
-                                                  "corr_keyblocks", ())):
+        for (name, label), was, now in zip(
+                _KEYBLOCK_COUNTERS, blocks,
+                getattr(self.engine, "corr_keyblocks", ())):
             if now > was:
-                self.metrics[name].inc(now - was)
+                counter = self.metrics[name]
+                (counter.labels(label) if label else counter).inc(now - was)
         return out
 
     def _run_engine(self, bucket, im1, im2, sizes=None):

@@ -117,7 +117,7 @@ from ..ops.warmstart import warm_start_seed
 from ..telemetry import events as tlm_events
 from ..telemetry import spans as tlm_spans
 from ..telemetry.trace import host_stage
-from .batcher import NonFiniteOutput, planar_batch
+from .batcher import NonFiniteOutput, finite_rows, planar_batch
 from .queue import (DeadlineExceeded, Draining, RejectedError, Request,
                     RequestQueue)
 from .session import Session, SessionStore
@@ -715,9 +715,7 @@ class StreamCoordinator:
         bucket, padded, slots = call.bucket, call.padded, call.slots
         h, w = bucket
         with host_stage("raft.stream.sentinel", _batch_stage):
-            row_ok = np.array([ok and bool(np.isfinite(flow[i]).all()
-                                           and np.isfinite(flow_lr[i]).all())
-                               for i, ok in enumerate(live)], bool)
+            row_ok = finite_rows(n, flow, flow_lr, live=live)
         # a row commits into the slot it was gathered from, while its
         # session still owns it: a failed commit of the batch in front
         # (dispatched after this one was) has rebuilt the pool and demoted
@@ -841,8 +839,7 @@ class StreamCoordinator:
                         holds=True):
             flow, flow_lr, fmap_c, cnet_c, iters_used = engine.run_stream(
                 ab, req.image1, fmap_p, cnet_p, init, sizes=sizes)
-            finite = bool(np.isfinite(flow).all()
-                          and np.isfinite(flow_lr).all())
+            finite = bool(finite_rows(1, flow, flow_lr)[0])
         if not finite:
             # non-finite OUTPUT sentinel (inputs were validated at the
             # HTTP edge): never cache poisoned maps or a poisoned seed

@@ -248,11 +248,12 @@ def test_a_metric_whose_reader_or_pin_does_not_hold_here_is_not_given(
 
 
 def test_the_new_metrics_are_the_manifests_last(cell):
-    """PR 41's five, then PR 42's one: each appended, none moved."""
-    assert [m["name"] for m in cell["bench"]["per_layer"][-6:]] == [
+    """PR 41's five, then PR 42's one (then PR 43's ``corr_lane_fill``, for
+    all five cells): each appended, none moved."""
+    assert [m["name"] for m in cell["bench"]["per_layer"][-7:]] == [
         "stream_cold_ms", "stream_cold_wait_ms", "stream_cold_device_share",
         "stream_lru_demotions_per_advance", "stream_restart_cause_share",
-        "stream_restart_batched_share"]
+        "stream_restart_batched_share", "corr_lane_fill"]
     by_name = {m["name"]: m for m in cell["bench"]["per_layer"]}
     assert by_name["stream_restart_batched_share"]["better"] == "higher"
     assert by_name["stream_restart_batched_share"]["source"] \

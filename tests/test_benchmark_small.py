@@ -41,6 +41,8 @@ STAGE_METRICS = (
     "deliver_offcpu_ms", "handler_cpu_ms", "host_stall_s",
     "corr_bands_per_tile", "corr_steps_per_tile")
 SHARED_METRICS += STAGE_METRICS
+# PR 43: the share of the lookup's key lanes that held a key
+SHARED_METRICS += ("corr_lane_fill",)
 
 
 @pytest.fixture(scope="module")
@@ -284,6 +286,42 @@ def test_corr_steps_per_tile_needs_its_counter(bench_modules, program, want):
     if program == "PR 37":
         assert _read_counters(bench_modules, "corr_bands_per_tile",
                               prom) == pytest.approx(1.2)
+
+
+@pytest.mark.parametrize("program,want", [
+    ("PR 43", 93.75),       # stored and live key positions
+    ("PR 42", None),        # no such counter: the parent
+    ("stored-alone", 0.0),  # a window in which no live position was added
+    ("idle", None)])        # the counter at rest: no lookup ran
+def test_corr_lane_fill_reads_the_key_positions(bench_modules, program,
+                                                want):
+    """``corr_lane_fill`` (PR 43) is 100 x live / stored of
+    ``raft_serving_corr_key_positions_total`` over the window, whatever
+    other labels a series carries, and None (the harness leaves the metric
+    out, it does not raise) on a program that exports no such counter: the
+    parent, on which the driver runs this reader too."""
+    prom = _stage_window(True)
+    name = "raft_serving_corr_key_positions_total"
+    if program != "PR 42":
+        stored, live = {"PR 43": (2.56e9, 2.4e9), "stored-alone": (4096.0, 0.0),
+                        "idle": (0.0, 0.0)}[program]
+        prom[name + '{kind="stored"}'] = stored
+        prom[name + '{kind="live"}'] = live
+    got = _read_counters(bench_modules, "corr_lane_fill", prom)
+    assert got == (None if want is None else pytest.approx(want))
+    # the counters its neighbours read are untouched by it
+    assert _read_counters(bench_modules, "corr_steps_per_tile",
+                          prom) == pytest.approx(1.5)
+
+
+def test_corr_lane_fill_is_listed_for_all_five_cells(cell):
+    entry = cell["bench"]["per_layer"][-1]
+    assert entry == {
+        "name": "corr_lane_fill", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "pairs_per_s",
+        "workloads": [w["name"] for w in cell["bench"]["workloads"]]}
+    assert len(entry["workloads"]) == 5
 
 
 @pytest.mark.parametrize("metric", STAGE_METRICS)

@@ -516,6 +516,12 @@ def test_served_float32_session_is_the_references_walk(
             <= prom["raft_serving_corr_keyblocks_visited_total"]
             <= prom["raft_serving_corr_grid_steps_total"]
             <= prom["raft_serving_corr_keyblocks_possible_total"])
+    # and the key positions those steps multiplied over (PR 43), from the
+    # levels' plans: no more live than stored, and a multiple of 128 stored
+    positions = "raft_serving_corr_key_positions_total"
+    stored = prom[positions + '{kind="stored"}']
+    assert 0 < prom[positions + '{kind="live"}'] <= stored
+    assert stored % 128 == 0
 
 
 def test_served_bfloat16_session_is_under_the_cells_limit(
@@ -892,6 +898,10 @@ def test_reload_probes_a_program_that_returns_key_block_counts():
     assert engine._counts_keyblocks
     out = engine.run((32, 48), *(np.zeros((1, 32, 48, 3), np.float32),) * 2)
     assert out.shape == (1, 32, 48, 2) and engine.corr_keyblocks[2] > 0
+    # a 4x6 grid: every level is one block of eight map rows to a 128-lane
+    # row (columns 6, 3, 1 and 0 of 16 lanes: level 3 is pooled away), one
+    # tile a level, two updates
+    assert engine.corr_keyblocks[4:] == [2 * 3 * 128, 2 * 8 * (6 + 3 + 1)]
     info = engine.reload(jax.tree.map(lambda a: a * 0.5, params), tag="half")
     assert info == {"version": 2, "tag": "half", "probed": True}
     assert engine.compile_misses == 0

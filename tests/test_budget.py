@@ -127,8 +127,9 @@ def test_corr_level_plan_values():
                                         p_blk_target=4096, radius=4,
                                         grid_w=6)
     assert (plan.t, plan.qp) == (24, 24)
-    assert plan.w2p == 128                       # lane padding
-    assert plan.h2_blk == 4 and plan.n_pblocks == 1
+    # six columns in sixteen lanes, eight map rows to a 128-lane row
+    assert (plan.w2, plan.w2p, plan.pack) == (6, 16, 8)
+    assert plan.h2_blk == 8 == plan.rows_padded and plan.n_pblocks == 1
     assert not plan.banded and plan.band_granules == 0
     # full-scale level 0 at 432x1024: Q = 54*128, map 54x128
     plan = kernel_plans.corr_level_plan(54 * 128, 54, 128, q_blk=128,
@@ -137,7 +138,7 @@ def test_corr_level_plan_values():
     assert plan.t == 128 and plan.w2p == 128
     assert plan.h2_blk == 32 and plan.rows_padded == 64
     assert plan.n_pblocks == 2
-    # more than one step's positions: a band of 16 rows from a multiple of
+    # more rows than a band: a band of 16 rows from a multiple of
     # 4, four bands to the whole map, zero rows for a band that starts on
     # the map's last granule (row 52)
     assert plan.banded and plan.band_granules == 4
@@ -153,19 +154,22 @@ def test_corr_level_plan_refuses_a_degenerate_level():
                                      radius=4, grid_w=8)
 
 
-@pytest.mark.parametrize("w2,w2p", [(40, 128), (62, 128), (100, 128),
+@pytest.mark.parametrize("w2,w2p", [(40, 64), (62, 64), (100, 128),
                                     (240, 256)])
 def test_corr_level_plan_pads_rows_to_whole_lanes(w2, w2p):
     """A width that is no multiple of 128 (320, 496, 800 and 1920 pixels at
-    the 1/8 grid) is stored lane-padded, and ``rows`` is the map's rows:
-    nothing lays rows side by side."""
+    the 1/8 grid) is stored padded to the lanes that hold it, and ``rows``
+    is the map's rows: 100 and 240 columns to whole vector registers, 40
+    and 62 to 64 lanes, two map rows to a 128-lane row of the planes
+    (PR 43)."""
     h2 = 46
     plan = kernel_plans.corr_level_plan(h2 * w2, h2, w2, q_blk=128,
                                         p_blk_target=4096, radius=4,
                                         grid_w=w2)
-    assert plan.w2p == w2p and plan.w2p % kernel_plans.LANE == 0
+    assert plan.w2p == w2p == kernel_plans.corr_row_lanes(w2)
+    assert plan.w2p * plan.pack % kernel_plans.LANE == 0
     assert plan.rows == h2
-    assert plan.h2_blk == 4096 // w2p
+    assert plan.h2_blk == min(h2, 4096 // w2p) and plan.h2_blk % plan.pack == 0
     assert plan.rows_padded == plan.n_pblocks * plan.h2_blk >= h2
 
 
@@ -223,18 +227,22 @@ def test_vmem_envelopes(config):
     # gathers a window's taps through [T, 128] lane tiles where PRs 21-31
     # priced one-hot matrices of [T, 9, rows] and [T, 9, lanes])
     # (level 0, 55 rows, is banded: a step holds a band of 16 rows, as four
-    # double-buffered blocks of 4, where PRs 21-35 held a block of 32; the
-    # pooled levels are one whole-map block each, as before)
+    # double-buffered blocks of 4, where PRs 21-35 held a block of 32; since
+    # PR 43 the pooled levels' rows lie 2, 4 and 8 to a 128-lane row: level
+    # 1 a band of 16 rows of 64 lanes where it held 27 rows of 128 (11.94 /
+    # 15.13 / 11.88 MiB), levels 2 and 3 one block of 16 x 32 and 8 x 16
+    # where they held 13 x 128 and 6 x 128 (6.56 / 8.0 / 6.5 and 3.88 / 4.44
+    # / 3.81))
     (dict(compute_dtype="float32"), [[1, "float32"]] * 4,
-     [7.69, 11.94, 6.56, 3.88]),
+     [7.69, 4.69, 3.19, 2.0]),
     # bfloat16 maps at 'highest': level 0 holds one bfloat16 plane (2 MB of
-    # double-buffered f2 less), the pooled levels three (up to 4 MB more)
+    # double-buffered f2 less), the pooled levels three
     (dict(compute_dtype="bfloat16"),
-     [[1, "bfloat16"]] + [[3, "bfloat16"]] * 3, [5.5, 15.13, 8.0, 4.44]),
+     [[1, "bfloat16"]] + [[3, "bfloat16"]] * 3, [5.5, 5.5, 3.5, 1.94]),
     # 'default' keeps the float32 blocks the MXU rounds itself; the output
     # block is bfloat16 all the same (the update block's dtype)
     (dict(compute_dtype="bfloat16", corr_precision="default"),
-     [[1, "float32"]] * 4, [7.63, 11.88, 6.5, 3.81]),
+     [[1, "float32"]] * 4, [7.63, 4.63, 3.13, 1.94]),
 ])
 def test_corr_envelope_prices_the_dtypes_the_kernel_holds(kw, planes, mib):
     full = RAFTConfig.full(corr_impl="pallas", **kw)

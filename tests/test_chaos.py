@@ -79,6 +79,55 @@ def test_injector_corrupt_rows_poisons_exactly_one_row():
     assert inj.injected["nan"] == 1
 
 
+@pytest.mark.parametrize("case", ["clean", "nan-row", "inf-row",
+                                  "padded-rows", "flow-lr", "live"])
+def test_sentinel_helper_gives_the_old_expressions_verdict(case):
+    """``batcher.finite_rows``, the one output sentinel of ``_deliver`` and
+    the stream path (PR 43), against the expressions it replaced: the
+    batcher's whole-batch ``np.isfinite(flows[:n].reshape(n, -1)).all(axis=
+    1)`` and the stream path's per-row test of ``flow`` and ``flow_lr``
+    under ``live``.  The same verdict row for row: a NaN row, an Inf row,
+    poison in the padding rows past ``n`` (not looked at), poison in
+    ``flow_lr`` alone, a row its handler gave up on."""
+    from raft_tpu.serving.batcher import finite_rows
+    rng = np.random.default_rng(43)
+    padded, n = 8, 5
+    flow = rng.standard_normal((padded, 16, 24, 2)).astype(np.float32)
+    flow_lr = rng.standard_normal((padded, 2, 3, 2)).astype(np.float32)
+    live = None
+    if case == "nan-row":
+        flow[2, 7, 5, 1] = np.nan
+    elif case == "inf-row":
+        flow[0, 0, 0, 0] = np.inf
+        flow[4, 15, 23, 1] = -np.inf
+    elif case == "padded-rows":
+        flow[n:] = np.nan
+        flow_lr[n + 1] = np.inf
+    elif case == "flow-lr":
+        flow_lr[3, 1, 2, 0] = np.nan
+    elif case == "live":
+        live = [True, False, True, True, False]
+        flow[2, 3, 3, 0] = np.nan
+    old_deliver = np.isfinite(flow[:n].reshape(n, -1)).all(axis=1)
+    got = finite_rows(n, flow)
+    assert got.dtype == bool and got.shape == (n,)
+    np.testing.assert_array_equal(got, old_deliver)
+    old_stream = np.array(
+        [ok and bool(np.isfinite(flow[i]).all()
+                     and np.isfinite(flow_lr[i]).all())
+         for i, ok in enumerate(live or [True] * n)], bool)
+    np.testing.assert_array_equal(
+        finite_rows(n, flow, flow_lr, live=live), old_stream)
+    want = {"clean": [1, 1, 1, 1, 1], "nan-row": [1, 1, 0, 1, 1],
+            "inf-row": [0, 1, 1, 1, 0], "padded-rows": [1, 1, 1, 1, 1],
+            "flow-lr": [1, 1, 1, 0, 1], "live": [1, 0, 0, 1, 0]}[case]
+    assert finite_rows(n, flow, flow_lr, live=live).tolist() == [
+        bool(v) for v in want]
+    # the solo step's one row
+    assert bool(finite_rows(1, flow[1:2], flow_lr[1:2])[0])
+    assert not finite_rows(1, flow[1:2], np.full((1, 2, 2, 2), np.nan))[0]
+
+
 def test_injector_engine_error_and_latency_arms():
     inj = make_injector("seed=1,latency_ms=30")
     inj.force("latency", [1])

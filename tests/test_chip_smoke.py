@@ -240,3 +240,36 @@ def test_small_phase_rehearsed_on_the_cpu(capsys):
     # the real sizes are the cell's
     real = chip_smoke.Sizes()
     assert (real.small_batch, real.small_hw) == (8, (1080, 1920))
+
+
+def test_flows_phase_rehearsed_on_the_cpu(capsys):
+    """``phase_flows`` through its own code at a tiny size (2 x 64x96, two
+    updates, the lookup in interpret mode): the five served programs of
+    ``chip_smoke.FLOW_PROGRAMS`` built from their configurations' serve
+    arguments, run on the fixed seed and hashed (``chip_smoke.py --flows``:
+    a tool, no phase of the smoke)."""
+    import json
+
+    import chip_smoke
+    sz = chip_smoke.Sizes(interpret=True, flow_hw=(64, 96), flow_batch=2,
+                          flow_iters=2)
+    chip_smoke.phase_flows(chip_smoke.CompileMeter(), sz)
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["phase"] == "flows" and rec["ok"] is True
+    names = [name for name, *_ in chip_smoke.FLOW_PROGRAMS]
+    assert len(names) == 5 and all(n in rec for n in names)
+    for name, _, kind, _ in chip_smoke.FLOW_PROGRAMS:
+        assert len(rec[name]["sha256"]) == 64 and rec[name]["iters"] == 2
+        assert rec[name]["program"] == ("1x64x96" if kind == "stream"
+                                        else "2x64x96")
+        assert rec[name]["mean_abs_flow"] > 0
+    # one model, one seed, one frame size: the two raft-things pair
+    # programs answer the same flow; the stream kinds (a zero seed over
+    # cached maps) and RAFT-S do not
+    shas = [rec[n]["sha256"] for n in names]
+    assert shas[0] == shas[1] and len(set(shas)) == 4
+    # the real sizes are the cells'
+    real = chip_smoke.Sizes()
+    assert real.flow_programs == chip_smoke.FLOW_PROGRAMS
+    assert not (real.flow_hw or real.flow_batch or real.flow_iters)
+    assert [b for *_, b in real.flow_programs] == [32, 8, 8, 8, 1]

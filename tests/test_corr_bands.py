@@ -37,9 +37,11 @@ def _tile_bands(S, plan):
 MODELS = {"things": (4, 256), "small": (3, 128)}
 #: name -> (grid, p_blk_target).  ``hd``: rows of 256 lanes like 1080p's
 #: level 0 (135 x 240), tiles that begin mid-row and a padded tail tile
-#: (4,896 queries); level 0 is banded at the served 4096 positions, level 1
-#: one block.  ``sintel``: 128 queries a row, a tile a row like 55 x 128;
-#: at 2048 positions levels 0 and 1 are both banded.
+#: (4,896 queries); levels 0 and 1 (18 rows of 68 in 128 lanes) hold more
+#: rows than a band of 16 and are banded at the served 4096 positions.
+#: ``sintel``: 128 queries a row, a tile a row like 55 x 128; at 2048
+#: positions levels 0 and 1 are both banded, and level 1's rows (64 columns)
+#: lie two to a 128-lane row of the planes, as 440x1024's do (PR 43).
 GRIDS = {"hd": ((36, 136), 4096), "sintel": ((40, 128), 2048)}
 
 
@@ -101,7 +103,7 @@ def test_banded_launch_equals_the_all_rows_launch(model, grid, kind, dtype,
         model, grid, kind, dtype)
     (h, w), _ = GRIDS[grid]
     assert plans[0].banded and (plans[0].qp != h * w) == (grid == "hd")
-    assert plans[1].banded == (grid == "sintel")
+    assert plans[1].banded and plans[1].pack == (2 if grid == "sintel" else 1)
     sched = lookup_schedules(coords, level_shapes(f2_levels), radius,
                              q_blk=128, p_blk_target=p_blk)
     bands = _tile_bands(sched[0], plans[0])
@@ -130,6 +132,75 @@ def test_banded_launch_equals_the_all_rows_launch(model, grid, kind, dtype,
         assert not by_row[..., 2:].any()
     else:
         assert np.abs(got).max() > 0.1
+
+
+# --------------------- rows that share their 128 lanes (PR 43), one launch
+
+#: map columns -> (stored lanes, map rows to a 128-lane row): every width the
+#: served grids' levels have (240, 120, 60, 30 at 1080x1920; 128, 64, 32, 16
+#: at 440x1024)
+PACKED_WIDTHS = {16: (16, 8), 30: (32, 4), 32: (32, 4), 60: (64, 2),
+                 64: (64, 2), 120: (128, 1), 128: (128, 1), 240: (256, 1)}
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("w2", list(PACKED_WIDTHS))
+@pytest.mark.parametrize("model", list(MODELS))
+def test_packed_rows_equal_the_all_rows_walk_and_the_dense_lookup(model, w2,
+                                                                  dtype):
+    """One launch over a map of 37 rows of ``w2`` columns, for raft-things'
+    and RAFT-S's radius and channels, float32 maps and bfloat16 ones (three
+    planes, as a pooled level's): rows of 16, 32 and 64 lanes lie eight,
+    four and two to a 128-lane row of the planes and one gather a row
+    serves them all.  Queries lie over the whole map and past each of its
+    four sides, and their rows are rough enough for a second and a third
+    band.  The banded launch equals the walk over every row-block bit for
+    bit (every tap lies in one band), and ``ops.corr.lookup_dense`` on the
+    same values to float32 round-off."""
+    from raft_tpu.ops.corr import dense_corr, lookup_dense
+
+    radius, c = MODELS[model]
+    lanes, pack = PACKED_WIDTHS[w2]
+    h2, (H, W) = 37, (4, 128)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(43), 3)
+    fmap1 = jax.random.normal(k1, (1, H, W, c), dtype)
+    f2 = jax.random.normal(k2, (1, h2, w2, c), F32)
+    # columns from 8 left of the map to 8 right of it, rows from 8 above to
+    # 8 below; every second query a further 0-19 rows down: a tile's
+    # windows span up to three bands of 16
+    x = jnp.linspace(-8.0, w2 + 8.0, W)[None, :] + jnp.zeros((H, 1))
+    y = jnp.linspace(-8.0, h2 + 8.0, H)[:, None] + jnp.zeros((1, W))
+    rough = jax.random.uniform(k3, (H, W, 2), minval=0.0, maxval=1.0)
+    y = y + jnp.where(jnp.arange(W)[None, :] % 2 == 0, 0.0,
+                      19.0 * rough[..., 1])
+    coords = jnp.stack([x + rough[..., 0], y], -1)[None]
+    cf = coords.reshape(1, H * W, 2)
+    kw = dict(q_blk=128, p_blk_target=4096, grid_w=W)
+    plan = corr_level_plan(H * W, h2, w2, radius=radius, **kw)
+    assert (plan.w2p, plan.pack) == (lanes, pack)
+    # a band starts on whole 128-lane rows: a granule of 8 map rows at 16
+    # lanes, where 7 rows of rounding make the band 24 rows
+    assert plan.band_granule == max(4, pack)
+    assert plan.banded and (plan.band_rows, plan.n_bands) == (
+        (24, 2) if pack == 8 else (16, 3))
+    S = level_schedule(cf, plan, 0, radius)
+    assert _tile_bands(S, plan).max() >= 2            # a second band
+    run = lambda s: np.asarray(_lookup_level(         # noqa: E731
+        fmap1.reshape(1, H * W, c), f2, cf, radius, 0, interpret=True,
+        schedule=s, **kw))
+    got, whole = run(S), run(None)
+    np.testing.assert_array_equal(got.view(np.uint32), whole.view(np.uint32))
+    want = np.asarray(lookup_dense(
+        [dense_corr(fmap1.astype(F32), f2,
+                    precision=jax.lax.Precision.HIGHEST)], coords, radius))
+    np.testing.assert_allclose(got.reshape(want.shape), want, rtol=0,
+                               atol=1.5e-6 * np.abs(want).max())
+    n = 2 * radius + 1
+    by_q = got.reshape(H, W, n, n)                    # [.., x offset, y]
+    assert not by_q[:, 0, :radius].any()      # columns left of the map
+    assert not by_q[:, -1, -radius:].any()    # and right of it
+    assert not by_q[0, ::2, :, :radius].any()         # rows above it
+    assert np.abs(by_q[1:3, 8:-8]).max() > 0.1
 
 
 # ------------------------------ the grid's third dimension: K' = 1 + max(more)
@@ -227,7 +298,7 @@ def test_the_ragged_launch_keeps_its_pages(dtype):
     the shared body takes the page's first row: with every item at the box
     its output is the dense all-rows launch's, bit for bit — what it was
     before the dense launches took bands."""
-    B, H, W, C, RADIUS = 2, 30, 44, 32, 4       # 15 pages of two rows
+    B, H, W, C, RADIUS = 2, 30, 44, 32, 4       # 8 pages of four rows of 64
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
     fmap1 = jax.random.normal(k1, (B, H, W, C), dtype)
     fmap2 = jax.random.normal(k2, (B, H, W, C), dtype)

@@ -17,7 +17,7 @@ from raft_tpu.ops.corr_pallas import fused_lookup, make_fused_lookup
 
 def _every_level_scheduled(coords, f2_levels, radius, q_blk, p_blk_target):
     """A band schedule for every level that can take one: the levels of more
-    than one row-block at this ``p_blk_target`` (a level of one block has no
+    rows than a band at this ``p_blk_target`` (a level of one block has no
     band: ``kernel_plans.CorrLevelPlan.banded``), built level by level from
     each level's own plan."""
     from raft_tpu.kernel_plans import corr_level_plan
@@ -158,9 +158,11 @@ def test_integer_coordinates_give_the_correlation_sums_bit_for_bit(
     xs = jnp.linspace(-6, W + 6, W).round()
     ys = jnp.linspace(-6, H + 6, H).round()
     coords = jnp.stack(jnp.meshgrid(xs, ys, indexing="xy"), -1)[None]
-    got, plan = _one_level(fmap1, fmap2, coords, radius, p_blk_target=2048,
+    # (rows of 20 columns are stored 32 lanes wide, four to a 128-lane row)
+    got, plan = _one_level(fmap1, fmap2, coords, radius, p_blk_target=512,
                            scheduled=scheduled)
-    assert plan.h2_blk == 16 and plan.n_pblocks == 3
+    assert plan.h2_blk == 16 and plan.n_pblocks == 3 and plan.pack == 4
+    assert (plan.band_rows, plan.n_bands) == (16, 3)
     # the volume in integers, then the scale (a power of two at C = 16)
     exact = i1.reshape(H * W, C).astype(np.int64) @ i2.reshape(H * W, C).T
     assert np.abs(exact).max() < 2 ** 24
@@ -193,8 +195,10 @@ def test_subpixel_windows_within_two_ulp_of_the_gather_lookup(radius):
     B, H, W, C = 1, 16, 24, 32
     fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(70 + radius),
                                         B, H, W, C, coord_span=1.1 * W)
-    got, plan = _one_level(fmap1, fmap2, coords, radius, scheduled=True)
-    assert plan.n_pblocks >= 8 and plan.h2_blk == 2   # every window straddles
+    got, plan = _one_level(fmap1, fmap2, coords, radius, p_blk_target=128,
+                           scheduled=True)
+    # bands of four rows, one 128-lane row of the planes: every window
+    assert plan.n_bands == 4 and plan.band_rows == 4 == plan.pack  # straddles
     cy = np.asarray(coords[0, ..., 1])
     assert (cy < radius).any() and (cy > H - radius).any()    # map's edges
     vol = dense_corr(fmap1, fmap2, precision=HIGHEST)
@@ -218,8 +222,9 @@ def test_tiles_whose_windows_lie_off_the_map_write_zeros():
         jnp.where(off, far, coords[0, ..., 1]))
     for scheduled in (False, True):
         got, plan = _one_level(fmap1, fmap2, coords, radius, q_blk=32,
-                               p_blk_target=256, scheduled=scheduled)
-        assert plan.t == 32 and plan.n_pblocks > 1
+                               p_blk_target=128, scheduled=scheduled)
+        # (eight rows of eight columns to a 128-lane row: two such blocks)
+        assert plan.t == 32 and plan.n_pblocks == 2 == plan.n_bands
         got = np.asarray(got).reshape(H, W, -1)
         assert not got[np.asarray(off)].any()
         assert np.abs(got[~np.asarray(off)]).max() > 0.1
@@ -241,7 +246,7 @@ def test_new_body_by_value(name, H, W, C, radius, p_blk):
     coords = coords.at[..., 1].multiply(H / W)
     got, plan = _one_level(fmap1, fmap2, coords, radius, q_blk=128,
                            p_blk_target=p_blk, scheduled=True)
-    assert plan.w2p == (256 if W == 240 else 128) and plan.n_pblocks > 1
+    assert plan.w2p == (256 if W == 240 else 64) and plan.n_pblocks > 1
     want = np.asarray(lookup_dense(
         [dense_corr(fmap1.astype(F32), fmap2.astype(F32),
                     precision=HIGHEST)], coords, radius))
@@ -367,21 +372,22 @@ def test_window_schedule_matches_dense_oracle(B, H, W, C, levels, radius):
     fmap1, fmap2, coords = _random_case(jax.random.PRNGKey(5), B, H, W, C)
     want = lookup_dense(build_pyramid(fmap1, fmap2, levels), coords, radius)
     f2_levels = tuple(fmap2_pyramid(fmap2, levels))
-    sched = _every_level_scheduled(coords, f2_levels, radius, 64, 1024)
+    sched = _every_level_scheduled(coords, f2_levels, radius, 64, 128)
     got = _fused_lookup_impl(fmap1, f2_levels, coords, radius,
-                             q_blk=64, p_blk_target=1024, schedules=sched)
+                             q_blk=64, p_blk_target=128, schedules=sched)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     # a schedule made for another block plan is refused, not misread
     with pytest.raises(ValueError, match="schedule"):
         _fused_lookup_impl(fmap1, f2_levels, coords, radius,
-                           q_blk=64, p_blk_target=128, schedules=sched)
+                           q_blk=64, p_blk_target=256, schedules=sched)
 
 
 def test_window_schedule_model_forward():
     """End-to-end: at blocks fine enough for the kernel's rule to schedule
-    every level of a 24x40 grid, the model matches the default plan, under
-    which each level is one block — and counts fewer key blocks visited."""
+    the two top levels of a 20x40 grid, the model matches the default plan,
+    under which each level is one block — and counts fewer key blocks
+    visited."""
     from raft_tpu.config import RAFTConfig
     from raft_tpu.models import init_raft, raft_forward
 
@@ -389,8 +395,8 @@ def test_window_schedule_model_forward():
     win = RAFTConfig.full(iters=2, corr_impl="pallas", pallas_p_blk=256)
     params = init_raft(jax.random.PRNGKey(0), base)
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
-    im1 = jax.random.uniform(k1, (1, 192, 320, 3))
-    im2 = jax.random.uniform(k2, (1, 192, 320, 3))
+    im1 = jax.random.uniform(k1, (1, 160, 320, 3))
+    im2 = jax.random.uniform(k2, (1, 160, 320, 3))
     out_a, _ = raft_forward(params, im1, im2, base)
     out_b, _ = raft_forward(params, im1, im2, win)
     # other blocks, another order of the float32 sums across them: the GRU
@@ -398,10 +404,17 @@ def test_window_schedule_model_forward():
     # relative to that
     np.testing.assert_allclose(np.asarray(out_a.flow), np.asarray(out_b.flow),
                                rtol=1e-3, atol=1e-3)
-    visited, possible, tiles, steps = (int(v) for v in out_a.corr_keyblocks)
+    visited, possible, tiles, steps, stored, live = (
+        int(v) for v in out_a.corr_keyblocks)
     assert visited == possible == tiles == steps  # one block a level at 4096
-    visited, possible, tiles, steps = (int(v) for v in out_b.corr_keyblocks)
+    # a 20x40 grid: rows of 40, 20, 10 and 5 columns stored 64, 32, 16 and
+    # 16 lanes wide, blocks of 20, 12, 8 and 8 map rows
+    assert stored == tiles // 4 * (20 * 64 + 12 * 32 + 8 * 16 + 8 * 16)
+    assert live == tiles // 4 * (20 * 40 + 12 * 20 + 8 * 10 + 8 * 5)
+    visited, possible, tiles, steps, stored, live = (
+        int(v) for v in out_b.corr_keyblocks)
     assert 0 < tiles <= visited <= steps <= possible and visited < possible
+    assert 0 < live < stored and stored % 128 == 0
 
 
 def test_model_forward_at_the_training_crop_width():

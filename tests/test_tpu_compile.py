@@ -119,12 +119,13 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
     ("hd-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
                           grid=HD), BF16_X3),
     # 368x496, float32 as the train step holds its maps: rows of 62 and 31
-    # queries, neither a multiple of the 128 lanes they are stored in; two
-    # row-blocks at level 0, one at level 1
+    # columns stored in 64 and 32 lanes, two and four map rows to a 128-lane
+    # row of the planes (PR 43); both levels hold more rows (46, 23) than a
+    # band of 20
     ("crop-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
                             scheduled=True, grid=CROP), {}),
     ("crop-level1", 1, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
-                            grid=CROP), {}),
+                            scheduled=True, grid=CROP), {}),
     # the window written in the update block's dtype (every case above
     # writes float32): a bfloat16 [T, 81] block, (16,128)-tiled, filled by
     # nine masked stores at static lane offsets; scheduled and not, at the
@@ -147,8 +148,8 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
                                      p_blk_target=4096, scheduled=True,
                                      grid=CROP, out_dtype=jnp.bfloat16), {}),
     ("crop-out-bf16-level1", 1, dict(corr_precision=P.HIGHEST,
-                                     p_blk_target=4096, grid=CROP,
-                                     out_dtype=jnp.bfloat16), {}),
+                                     p_blk_target=4096, scheduled=True,
+                                     grid=CROP, out_dtype=jnp.bfloat16), {}),
     # RAFT-S at 1080x1920 (PR 31's cell): a 7x7 window, so eight taps a side
     # fill a float32 sublane tile exactly, and 128-channel maps
     ("small-hd-level0", 0, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
@@ -166,6 +167,22 @@ BF16_X3 = dict(f1=jnp.bfloat16, f2=jnp.float32)    # a level pooled in float32
     ("small-hd-level3", 3, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
                                 grid=HD, radius=3, c=128,
                                 out_dtype=jnp.bfloat16), BF16_X3),
+    # rows that share their 128 lanes (PR 43), as served at 440x1024: level
+    # 1 (27 x 64, two to a row, banded: four blocks of [4 x 64] positions),
+    # level 2 (13 x 32, four to a row, one block), level 3 (6 x 16, eight to
+    # a row, one block: "coarsest-level" above, in float32); and a level
+    # whose eight-to-a-row map is banded, which no served grid has
+    ("packed-level1-banded", 1, dict(corr_precision=P.HIGHEST,
+                                     p_blk_target=4096, scheduled=True,
+                                     out_dtype=jnp.bfloat16), BF16_X3),
+    ("packed-level2", 2, dict(corr_precision=P.HIGHEST, p_blk_target=4096,
+                              out_dtype=jnp.bfloat16), BF16_X3),
+    ("small-packed-level1-banded", 1, dict(
+        corr_precision=P.HIGHEST, p_blk_target=4096, scheduled=True,
+        radius=3, c=128, out_dtype=jnp.bfloat16), BF16_X3),
+    ("packed-by-eight-banded", 3, dict(
+        corr_precision=P.HIGHEST, p_blk_target=4096, scheduled=True,
+        grid=(320, 128), out_dtype=jnp.bfloat16), BF16_X3),
 ])
 def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
     kw = dict(kw)
@@ -178,8 +195,10 @@ def test_corr_kernel_compiles_for_v5e(one_chip, name, level, kw, dtypes):
                            grid_w=grid[1])
     if grid != (H, W):  # the case is the program's: the plan bands the
         assert plan.banded == scheduled, name    # level or it does not
-    else:               # (55x128's level 0 also as the all-rows walk)
+    else:               # (55x128's levels also as the all-rows walk)
         assert plan.banded or not scheduled, name
+    if name.startswith(("packed", "small-packed")):
+        assert plan.pack == (8 if "eight" in name else 1 << level), name
     if scheduled:
         fn = functools.partial(_scheduled_level, level=level, radius=radius,
                                grid_w=grid[1], **kw)
@@ -508,6 +527,58 @@ def test_analyzer_prices_the_small_served_programs_temporaries(
 
 
 # ------------------------------- the served stream batch programs (PR 39)
+
+@pytest.mark.parametrize("name,file,key_lanes", [
+    # the packed planes the launches are handed (PR 43): positions x
+    # channels of a band's granule block or of the one block, by level
+    ("things-sintel-b32", "raft-things.json",
+     ["bf16[1,32,8704,256]", "bf16[3,32,2560,256]", "bf16[3,32,512,256]",
+      "bf16[3,32,128,256]"]),
+    ("things-1080p-b8", "raft-things-1080p.json",
+     ["bf16[1,8,37888,256]", "bf16[3,8,10240,256]", "bf16[3,8,3072,256]",
+      "bf16[3,8,512,256]"]),
+])
+def test_pair_programs_compile_at_every_configurations_shape(
+        one_chip, name, file, key_lanes):
+    """The other two ``/v1/flow`` configurations' pair programs at their
+    served frame and batch (``served_small_program`` is RAFT-S's,
+    ``served_stream_programs`` the stream configurations'): the
+    configuration's own serve arguments, key-block counts beside the flow.
+    Mosaic takes the four launches, and each is handed its level's planes
+    as the plan stores them: 2, 4 or 8 map rows to a 128-lane row where the
+    level is 64, 32 or 16 columns wide (PR 43).  About a minute each."""
+    import json
+
+    from raft_tpu import cli
+    from raft_tpu.models import init_raft
+    from raft_tpu.models.raft import make_inference_fn
+    from raft_tpu.telemetry.trace import instruction_stages
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs", file)) as f:
+        serve_args = [str(a) for a in json.load(f)["serve_args"]]
+    args = cli.parse_args(["-m", "serve"] + serve_args)
+    config = cli._make_config(args)
+    h, w = (int(v) for v in args.buckets.split(",")[0].split("x"))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config)))
+    img = jax.ShapeDtypeStruct((args.max_batch, h, w, 3), jnp.float32,
+                               sharding=one_chip)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(make_inference_fn(
+            config, iters=args.iters, keyblocks=True)).lower(
+                params, img, img).compile().as_text()
+    insts = instruction_stages(text)
+    launches = sorted((rec["stage"], rec["text"]) for n, rec in insts.items()
+                      if re.search(r"^corr_lookup\.", n)
+                      and " custom-call(" in rec["text"])
+    assert len(launches) == 4, launches
+    for level, (stage_name, launch) in enumerate(launches):
+        assert stage_name == f"raft/corr_lookup/l{level}/corr_lookup"
+        assert key_lanes[level] in text, (name, level, key_lanes[level])
+
 
 @pytest.fixture(scope="module")
 def served_stream_programs(one_chip):

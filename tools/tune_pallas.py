@@ -15,8 +15,16 @@ and the (368,496)-crop batch-6 training shape.  Prints a markdown table +
 JSON; the winners are recorded in TUNING.md and wired into RAFTConfig
 defaults.
 
+* ``corr-levels`` — TUNING.md's per-tile table: one lookup launch a pyramid
+  level, standalone, as the served programs run it (bfloat16 maps at
+  ``highest``: one plane at level 0, three at the pooled levels; planes
+  padded and the schedule made outside the timed launch; ``bf16`` out) at
+  the served shapes of both models, under a smooth and a rough flow: ms a
+  launch, us a query tile, the steps a tile and the sha256 of the output,
+  so that a parent and a change run in one call can be held bit for bit.
+
 Usage (needs the TPU; refuses to 'tune' on CPU interpret mode):
-    python tools/tune_pallas.py [--quick] [--kernel corr|gru]
+    python tools/tune_pallas.py [--quick] [--kernel corr|gru|corr-levels]
 """
 
 from __future__ import annotations
@@ -104,12 +112,120 @@ def _sweep_gru(args) -> int:
     return 0
 
 
+#: label -> (grid h, w, batch, channels, radius): the served programs, and
+#: the training forward at the chairs recipe's crop and batch
+LEVEL_CASES = {"things-135x240": (135, 240, 8, 256, 4),
+               "small-135x240": (135, 240, 8, 128, 3),
+               "things-55x128": (55, 128, 32, 256, 4),
+               "train-46x62": (46, 62, 10, 256, 4)}
+
+
+def _sweep_levels(args) -> int:
+    """One launch a level at every case of ``LEVEL_CASES``, smooth and
+    rough flow; a JSON list last on stdout."""
+    import dataclasses
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.kernel_plans import corr_level_plan
+    from raft_tpu.ops import corr_pallas
+    from raft_tpu.ops.coords import coords_grid
+    from raft_tpu.ops.corr import fmap2_pyramid
+    from raft_tpu.ops.corr_pallas import (_lookup_level, f2_terms,
+                                          level_schedule, pad_planes,
+                                          schedule_steps)
+
+    print(f"# device: {jax.devices()[0].device_kind}  kernel: corr-levels")
+    prec, bf = jax.lax.Precision.HIGHEST, jnp.bfloat16
+    reps = 6 if args.quick else 12
+    results = []
+    for label, (h, w, B, C, radius) in LEVEL_CASES.items():
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(43), 3)
+        f1 = jax.random.normal(k1, (B, h * w, C), jnp.float32).astype(bf)
+        fmap2 = jax.random.normal(k2, (B, h, w, C), jnp.float32).astype(bf)
+        levels = [fmap2] + fmap2_pyramid(fmap2.astype(jnp.float32),
+                                         args.levels)[1:]
+        base = coords_grid(B, h, w)
+        x, y = base[..., 0], base[..., 1]
+        flows = {
+            # a cell of amplitude at wavelengths of 50-70 cells: what served
+            # traffic looks like to a tile
+            "smooth": base + jnp.stack([jnp.sin(x / 9.0 + y / 11.0),
+                                        jnp.cos(x / 10.0 - y / 8.0)], -1),
+            # +-2.5 cells, every query its own: a tile's windows spread 5
+            # rows more
+            "rough": base + jax.random.uniform(k3, (B, h, w, 2),
+                                               minval=-2.5, maxval=2.5)}
+        print(f"\n## {label}  (batch {B}, C {C}, radius {radius})")
+        print("| level | map, stored lanes | plan | flow | ms | us a tile | "
+              "steps a tile | sha256 |")
+        print("|---|---|---|---|---|---|---|---|")
+        for level, f2 in enumerate(levels):
+            h2, w2 = f2.shape[1:3]
+            kw = dict(q_blk=128, p_blk_target=4096, grid_w=w)
+            plan = corr_level_plan(h * w, h2, w2, radius=radius, **kw)
+            planes = f2_terms(bf, f2, prec)
+            tiles = B * plan.qp // plan.t
+            forms = [("band" if plan.banded else "block", plan)]
+            if plan.banded and plan.n_pblocks == 1:
+                # the same map as ONE block, had the plan not banded it
+                forms.append(("whole", dataclasses.replace(
+                    plan, band_granule=0, band_rows=0, n_bands=0,
+                    band_rows_padded=0)))
+            for form, plan in forms:
+                banded = plan.banded
+                rows = plan.band_rows if banded else plan.h2_blk
+                # the launch plans itself: hand it the form's plan
+                corr_pallas.corr_level_plan = (
+                    lambda *a, _plan=plan, **k: _plan)
+                padded = jax.block_until_ready(pad_planes(planes, plan))
+                fn = jax.jit(functools.partial(
+                    _lookup_level, radius=radius, level=level,
+                    interpret=False, shape=(h2, w2), corr_precision=prec,
+                    out_dtype=bf, **kw))
+                for name, coords in flows.items():
+                    cf = coords.reshape(B, h * w, 2)
+                    sched = (jax.block_until_ready(
+                        level_schedule(cf, plan, level, radius))
+                        if banded else None)
+                    steps = (int(schedule_steps(sched, plan)) if banded
+                             else plan.n_pblocks)
+                    run = lambda: fn(f1, padded, cf,        # noqa: E731
+                                     schedule=sched)
+                    out = jax.block_until_ready(run())
+                    jax.block_until_ready(run())
+                    best = float("inf")
+                    for _ in range(reps):
+                        t0 = time.perf_counter()
+                        jax.block_until_ready(run())
+                        best = min(best, time.perf_counter() - t0)
+                    sha = hashlib.sha256(
+                        np.asarray(out).view(np.uint16).tobytes()).hexdigest()
+                    rec = {"case": label, "level": level, "map": [h2, w2],
+                           "w2p": plan.w2p, "form": form, "flow": name,
+                           "ms": round(best * 1e3, 4),
+                           "us_per_tile": round(best * 1e6 / tiles, 3),
+                           "steps_per_tile": steps, "sha256": sha}
+                    results.append(rec)
+                    print(f"| {level} | {h2}x{w2}, {plan.w2p} | {form} of "
+                          f"{rows} rows | {name} | "
+                          f"{rec['ms']:.3f} | {rec['us_per_tile']:.2f} | "
+                          f"{steps} | {sha[:12]} |", flush=True)
+            corr_pallas.corr_level_plan = corr_level_plan
+    print(json.dumps(results))
+    return 0
+
+
 def main() -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--quick", action="store_true", help="fewer combos/reps")
-    p.add_argument("--kernel", default="corr", choices=["corr", "gru"],
+    p.add_argument("--kernel", default="corr",
+                   choices=["corr", "gru", "corr-levels"],
                    help="which fused kernel to sweep (gru = the update-block "
-                        "kernel's block_rows)")
+                        "kernel's block_rows; corr-levels = one launch a "
+                        "pyramid level at the served shapes)")
     p.add_argument("--dtype", default="bfloat16",
                    choices=["float32", "bfloat16"],
                    help="--kernel gru: I/O dtype of the swept iteration "
@@ -132,6 +248,8 @@ def main() -> int:
         return 2
     if args.kernel == "gru":
         return _sweep_gru(args)
+    if args.kernel == "corr-levels":
+        return _sweep_levels(args)
 
     from raft_tpu.ops.coords import coords_grid
     from raft_tpu.ops.corr import fmap2_pyramid
