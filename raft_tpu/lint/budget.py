@@ -6,8 +6,11 @@ boots:
 
 * **What will the engine compile?**  It reads the warm-up grid where the
   engine keeps it (``serving/config.enumerate_warmup_grid``, the list
-  ``InferenceEngine.warmup`` itself iterates), so the report's key list is
-  the engine's, not a second enumeration that could drift.
+  ``InferenceEngine.warmup`` itself iterates), and what each key's program
+  takes and returns from the engine's table of kinds
+  (``serving/engine.Programs``, built here over abstract params), so the
+  report's key list and signatures are the engine's, not second copies that
+  could drift.
 * **Does the config fit HBM, and how many sessions per chip?**
   :func:`analyze` computes per-executable and aggregate footprints via
   ``jax.eval_shape`` abstract evaluation (params, per-bucket SlotPool
@@ -33,7 +36,7 @@ optimistic by design and say "cannot fit", never "will surely fit".
 from __future__ import annotations
 
 import dataclasses
-from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 from ..kernel_plans import (GRU_HALO, GRU_TAPS, LANE, SUBLANE, TAP_LANES,
                             VMEM_BYTES, VMEM_CEILING_BYTES, corr_level_plan,
@@ -273,132 +276,44 @@ def _motion_dim(pspecs, config) -> int:
     return int(conv["w"].shape[2]) - config.hidden_dim - config.context_dim
 
 
-def feature_specs(config, pspecs, h: int, w: int, b: int = 1):
-    """(fmap, cnet) abstract specs for a [b, h, w, 3] frame — the same
-    eval_shape the engine's ``_feature_shapes`` runs."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..models.raft import make_encode_fn
-    img = jax.ShapeDtypeStruct((b, h, w, 3), jnp.float32)
-    return jax.eval_shape(make_encode_fn(config), pspecs, img)
-
-
-def slot_specs(config, pspecs, h: int, w: int, capacity: int):
-    """The per-bucket SlotPool buffer specs ([capacity+1, …] — the extra
-    row is the scratch slot), mirroring ``engine._slot_specs``: under
-    ``quant='int8'`` the fmap/cnet entries are 2-leaf (int8 vals, f32
-    per-channel scales) pytrees (parity-tested against the engine)."""
-    import jax
-    import jax.numpy as jnp
-    fs, cs = feature_specs(config, pspecs, h, w, 1)
-    cap1 = capacity + 1
-    flow = jax.ShapeDtypeStruct((cap1, h // 8, w // 8, 2), jnp.float32)
-    if config.quant_slots:
-        def q(s):
-            return (jax.ShapeDtypeStruct((cap1,) + s.shape[1:], jnp.int8),
-                    jax.ShapeDtypeStruct((cap1, s.shape[-1]), jnp.float32))
-        return (q(fs), q(cs), flow)
-    return (jax.ShapeDtypeStruct((cap1,) + fs.shape[1:], fs.dtype),
-            jax.ShapeDtypeStruct((cap1,) + cs.shape[1:], cs.dtype),
-            flow)
-
-
-def kind_footprint(config, pspecs, key: Key, capacity: int,
-                   donation: bool = True, ragged: bool = False) -> dict:
-    """Per-executable device-memory footprint, mirroring the input/output
-    signature ``engine._compile`` lowers for this key.
+def kind_footprint(programs, key: Key) -> dict:
+    """Per-executable device-memory footprint of ``key``, priced from the
+    engine's own table of kinds (``programs``, a ``serving.engine.Programs``
+    built over abstract params): the arguments ``engine._compile_traced``
+    lowers and the outputs ``jax.eval_shape`` gives them, counters and all.
 
     ``transient_bytes`` is what one call of this executable holds LIVE
     beyond the steady-state residents (params + pool buffers): its
     non-resident inputs plus its outputs, with donated buffers aliased
     away (a scommit's output pool buffers reuse the donated inputs'
     memory off-CPU; on the CPU backend donation is off and the scatter
-    really is a copy — pass ``donation=False`` to model that).  A ``pair``
-    call is priced with a second set of inputs and outputs
+    really is a copy — a table built with ``donate=False`` models that).
+    A ``pair`` call is priced with a second set of inputs and outputs
     (``staged_bytes``) beside the running one: the batcher places the next
     batch while this one runs, and dispatches it before it has fetched
     this one's flow (serving/batcher.py).
     """
     import jax
-    import jax.numpy as jnp
 
-    from ..config import adaptive_iters
-    from ..models.raft import (make_counted_inference_fn, make_encode_fn,
-                               make_inference_fn, make_stream_batch_step_fn,
-                               make_stream_step_fn)
-    from ..serving.session import make_slot_commit_fn, make_slot_poison_fn
-
-    kind, h, w, b, _policy = key
-    img = jax.ShapeDtypeStruct((b, h, w, 3), jnp.float32)
-    flow = jax.ShapeDtypeStruct((b, h // 8, w // 8, 2), jnp.float32)
-    idx = jax.ShapeDtypeStruct((b,), jnp.int32)
-    mask = jax.ShapeDtypeStruct((b,), jnp.bool_)
-    pool = slot_specs(config, pspecs, h, w, capacity)
-    pool_b = tree_bytes(pool)       # leaf-wise: quant entries are nested
-    donated: Sequence = ()
-    resident_inputs: Sequence = ()
-
-    if kind == "pair":
-        make = (make_counted_inference_fn
-                if adaptive_iters(config.iters_policy) else make_inference_fn)
-        out = jax.eval_shape(make(config), pspecs, img, img)
-        inputs = (img, img)
-    elif kind == "encode":
-        out = jax.eval_shape(make_encode_fn(config), pspecs, img)
-        inputs = (img,)
-    elif kind == "stream":
-        fs, cs = feature_specs(config, pspecs, h, w, b)
-        out = jax.eval_shape(make_stream_step_fn(config), pspecs, img, fs,
-                             cs, flow)
-        inputs = (img, fs, cs, flow)
-    elif kind == "sbatch":
-        out = jax.eval_shape(make_stream_batch_step_fn(config), pspecs,
-                             img, *pool, idx, mask)
-        inputs = (img, idx, mask)
-        resident_inputs = pool
-    elif kind == "scommit":
-        fs, cs = feature_specs(config, pspecs, h, w, b)
-        out = jax.eval_shape(make_slot_commit_fn(quant=config.quant_slots),
-                             *pool, idx, fs, cs, flow, mask)
-        inputs = (idx, fs, cs, flow, mask)
-        resident_inputs = pool
-        if donation:
-            donated = pool               # outputs alias the donated buffers
-    elif kind == "spoison":
-        out = jax.eval_shape(make_slot_poison_fn(quant=config.quant_slots),
-                             pool[0], idx)
-        inputs = (idx,)
-        resident_inputs = (pool[0],)
-        if donation:
-            donated = (pool[0],)
-    elif kind == "szero":
-        # builds the resident pool buffers themselves: nothing transient
-        out = pool
-        inputs = ()
-    else:
-        raise ValueError(f"unknown executable kind {kind!r}")
-
-    if ragged and kind in ("pair", "stream", "sbatch"):
-        # ragged flow-producing kinds take a per-row [b, 2] int32 live-
-        # size arg; the dense eval_shape above still prices the outputs
-        # correctly (the ragged factories return identical shapes —
-        # sizes only gates which rows carry live data)
-        inputs = tuple(inputs) + (
-            jax.ShapeDtypeStruct((b, 2), jnp.int32),)
-    in_b = sum(bytes_of(s) for s in jax.tree.leaves(list(inputs)))
+    kind, h, w = key[:3]
+    prog = programs.program(key)
+    out = jax.eval_shape(prog.fn, *prog.specs)
+    in_b = tree_bytes([s for i, s in enumerate(prog.specs)
+                       if i not in prog.resident
+                       and s is not programs.params])
     out_b = tree_bytes(out)
-    don_b = tree_bytes(list(donated))
+    don_b = tree_bytes([prog.specs[i] for i in prog.donated])
     staged = in_b + out_b if kind == "pair" else 0
     if kind == "szero":
+        # builds the resident pool buffers themselves: nothing transient
         transient = 0
     else:
         transient = in_b + max(0, out_b - don_b) + staged
     return {"key": list(key), "input_bytes": in_b, "output_bytes": out_b,
             "donated_bytes": don_b, "staged_bytes": staged,
             "transient_bytes": transient,
-            "pool_bytes": pool_b if resident_inputs or kind == "szero"
-            else 0}
+            "pool_bytes": tree_bytes(programs.slot_specs(h, w))
+            if prog.resident or kind == "szero" else 0}
 
 
 #: Temporaries of one pair executable per input pixel and pair: what the
@@ -492,6 +407,7 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
     import jax  # fail here, loudly, if jax is unavailable
 
     from ..serving.config import enumerate_warmup_grid
+    from ..serving.engine import Programs
 
     if device_kind is None:
         device_kind = budget_key(jax.devices()[0].device_kind)
@@ -511,6 +427,8 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
                                  chaos=chaos)
     capacity = max(1, sconfig.max_sessions)
     pspecs = param_specs(rconfig)
+    programs = Programs(rconfig, pspecs, capacity, ragged=ragged,
+                        donate=donation)
     params_b = tree_bytes(pspecs)
     motion = _motion_dim(pspecs, rconfig)
 
@@ -530,12 +448,11 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
     a_buckets = ([tuple(sconfig.max_box)] if ragged
                  else [tuple(b) for b in sconfig.buckets])
     for (bh, bw) in a_buckets:
-        pool = slot_specs(rconfig, pspecs, bh, bw, capacity)
+        pool = programs.slot_specs(bh, bw)
         pool_b = tree_bytes(pool)
         row_b = sum(bytes_of(s) // (capacity + 1)
                     for s in jax.tree.leaves(pool))
-        kinds = [kind_footprint(rconfig, pspecs, k, capacity,
-                                donation=donation, ragged=ragged)
+        kinds = [kind_footprint(programs, k)
                  for k in keys if (k[1], k[2]) == (bh, bw)]
         bucket_peak = max((f["transient_bytes"] for f in kinds), default=0)
         peak_transient = max(peak_transient, bucket_peak)

@@ -94,8 +94,6 @@ SERVING_LOCK_HIERARCHY: Tuple[str, ...] = (
     "Session.lock",               # handler holds it across a whole advance
     "RequestQueue._lock",         # submit() runs under the session lock
     "InferenceEngine._lock",      # leaf: executable-cache bookkeeping
-    "InferenceEngine._spec_lock", # leaf: feature-spec cache (under _lock on
-                                  # the serve-time miss path)
     "FaultInjector._lock",        # leaf: chaos roll state
     "SlotPool._lock",             # leaf: slot free-list + buffer refs,
                                   # taken under the store lock on the

@@ -649,6 +649,17 @@ def forward_from_features(params: Dict[str, dict], fmap1: jax.Array,
                          sizes8=sizes8)
 
 
+# The four factories below are the functions of the served programs
+# (serving/engine.py's table of kinds gives each its arguments and names its
+# outputs).  The three that run the lookup take a trailing ``sizes`` ([B, 2]
+# int32 full-res live extents): left None — a Python-level branch while the
+# program is traced — the program is the dense one; given, it is the RAGGED
+# mixed-resolution one, whose images are corner-anchored crops zero-embedded
+# in one max box and re-masked in-graph (deterministic dead regions), so one
+# executable serves every declared resolution and row b's outputs are valid
+# on ``[:sizes[b,0], :sizes[b,1]]``.
+
+
 def make_encode_fn(config: RAFTConfig):
     """A jittable (params, image) -> (fmap, cnet) single-frame encoder —
     the session-open / cold-restart half of the streaming serving path."""
@@ -669,17 +680,23 @@ def _stream_outputs(out: RAFTOutput, fmap_cur, cnet_cur, adaptive: bool,
     return res
 
 
+def _sizes8(sizes):
+    """A ragged step's live extents at the 1/8 grid (None for a dense one)."""
+    return None if sizes is None else sizes.astype(jnp.int32) // 8
+
+
 def make_stream_step_fn(config: RAFTConfig, iters: Optional[int] = None,
                         keyblocks: bool = False):
     """A jittable streaming step: ``(params, image, fmap_prev, cnet_prev,
-    flow_init) -> (flow, flow_lr, fmap_cur, cnet_cur[, iters_used]
-    [, corr_keyblocks])``.
+    flow_init, sizes=None) -> (flow, flow_lr, fmap_cur, cnet_cur
+    [, iters_used][, corr_keyblocks])``.
 
     ONE device call advances a video session by one frame: encode the
     current frame (one fnet + one cnet pass — the previous frame's maps
     arrive cached), run the recurrent core with correlation
     fmap_prev x fmap_cur and context from cnet_prev, and hand the current
-    frame's maps back for the session cache.  ``iters_used`` is appended
+    frame's maps back for the session cache (with ``sizes``, max-box rows a
+    ragged arena stores verbatim).  ``iters_used`` is appended
     under an adaptive ``iters_policy`` (the serving engine's counted-
     executable convention, engine.py), and with ``keyblocks``
     ``RAFTOutput.corr_keyblocks`` last, as :func:`make_inference_fn` has
@@ -687,10 +704,13 @@ def make_stream_step_fn(config: RAFTConfig, iters: Optional[int] = None,
     from ..config import adaptive_iters
     adaptive = adaptive_iters(config.iters_policy)
 
-    def fn(params, image, fmap_prev, cnet_prev, flow_init):
+    def fn(params, image, fmap_prev, cnet_prev, flow_init, sizes=None):
+        if sizes is not None:
+            image = mask_ragged_rows(image, sizes)
         fmap_cur, cnet_cur = encode_frame(params, image, config)
         out = forward_from_features(params, fmap_prev, fmap_cur, cnet_prev,
-                                    config, iters=iters, flow_init=flow_init)
+                                    config, iters=iters, flow_init=flow_init,
+                                    sizes8=_sizes8(sizes))
         return _stream_outputs(out, fmap_cur, cnet_cur, adaptive, keyblocks)
     return fn
 
@@ -701,7 +721,7 @@ def make_stream_batch_step_fn(config: RAFTConfig,
     """A jittable CONTINUOUS-BATCHED streaming step over a device-resident
     slot pool: ``(params, images [b,H,W,3], fmap_buf [cap+1,h,w,C],
     cnet_buf [cap+1,h,w,D], flow_buf [cap+1,h,w,2], slots [b] int32,
-    active [b] bool) -> (flow [b,H,W,2], flow_lr [b,h,w,2],
+    active [b] bool, sizes=None) -> (flow [b,H,W,2], flow_lr [b,h,w,2],
     fmap_cur [b,h,w,C], cnet_cur [b,h,w,D][, iters_used [b]]
     [, corr_keyblocks])`` (``keyblocks`` as in :func:`make_stream_step_fn`).
 
@@ -718,13 +738,19 @@ def make_stream_batch_step_fn(config: RAFTConfig,
     — the caller commits the finite ones into the pool with the
     scatter executable (serving/session.py ``make_slot_commit_fn``)
     AFTER the host-side non-finite sentinel, so a poisoned row can
-    never be cached.
+    never be cached.  With ``sizes`` the buffers are a single max-box arena
+    (every slot row max-box shaped, each session live only on its
+    corner-anchored crop), so sessions of DIFFERENT resolutions share one
+    stream batch and one executable per batch step.
     """
     from ..config import adaptive_iters
     adaptive = adaptive_iters(config.iters_policy)
     quant = config.quant_slots
 
-    def fn(params, images, fmap_buf, cnet_buf, flow_buf, slots, active):
+    def fn(params, images, fmap_buf, cnet_buf, flow_buf, slots, active,
+           sizes=None):
+        if sizes is not None:
+            images = mask_ragged_rows(images, sizes)
         fmap_cur, cnet_cur = encode_frame(params, images, config)
         with stage("raft/stream/gather"):
             if quant:
@@ -743,126 +769,28 @@ def make_stream_batch_step_fn(config: RAFTConfig,
             flow_init = flow_buf[slots]
         out = forward_from_features(params, fmap_prev, fmap_cur, cnet_prev,
                                     config, iters=iters,
-                                    flow_init=flow_init, active=active)
+                                    flow_init=flow_init, active=active,
+                                    sizes8=_sizes8(sizes))
         return _stream_outputs(out, fmap_cur, cnet_cur, adaptive, keyblocks)
     return fn
 
 
 def make_inference_fn(config: RAFTConfig, iters: Optional[int] = None,
-                      keyblocks: bool = False):
-    """A jittable (params, image1, image2) -> final flow function; with
-    ``keyblocks`` -> (flow, ``RAFTOutput.corr_keyblocks``), for the serving
-    engine's band counters (dense Pallas lookup only)."""
-    def fn(params, image1, image2):
+                      counted: bool = False, keyblocks: bool = False):
+    """A jittable ``(params, image1, image2, sizes=None)`` -> final flow
+    function.  ``counted`` -> (flow, iters_used): the per-sample GRU
+    iteration count ([B] int32), the adaptive-compute observable behind the
+    ``raft_iters_used`` histogram.  ``keyblocks`` appends
+    ``RAFTOutput.corr_keyblocks`` last, for the serving engine's band
+    counters (dense Pallas lookup only).  With neither the flow comes
+    back bare, not in a tuple."""
+    def fn(params, image1, image2, sizes=None):
         out, _ = raft_forward(params, image1, image2, config, iters=iters,
-                              train=False, all_flows=False)
-        return (out.flow, out.corr_keyblocks) if keyblocks else out.flow
-    return fn
-
-
-def make_counted_inference_fn(config: RAFTConfig,
-                              iters: Optional[int] = None,
-                              keyblocks: bool = False):
-    """A jittable (params, image1, image2) -> (flow, iters_used) function —
-    the serving/bench twin of :func:`make_inference_fn` that also returns
-    the per-sample GRU iteration count ([B] int32), the adaptive-compute
-    observable behind the ``raft_iters_used`` histogram.  ``keyblocks``
-    appends ``RAFTOutput.corr_keyblocks`` as in :func:`make_inference_fn`."""
-    def fn(params, image1, image2):
-        out, _ = raft_forward(params, image1, image2, config, iters=iters,
-                              train=False, all_flows=False)
+                              train=False, all_flows=False, sizes=sizes)
+        res = (out.flow,)
+        if counted:
+            res += (out.iters_used,)
         if keyblocks:
-            return out.flow, out.iters_used, out.corr_keyblocks
-        return out.flow, out.iters_used
-    return fn
-
-
-def make_ragged_inference_fn(config: RAFTConfig,
-                             iters: Optional[int] = None):
-    """A jittable ``(params, image1, image2, sizes) -> flow`` function for
-    RAGGED mixed-resolution batches: images are corner-anchored crops
-    zero-embedded in one max box, ``sizes`` [B, 2] int32 the full-res live
-    extents.  One executable serves every declared resolution; row b's flow
-    is valid on ``[:sizes[b,0], :sizes[b,1]]``."""
-    def fn(params, image1, image2, sizes):
-        out, _ = raft_forward(params, image1, image2, config, iters=iters,
-                              train=False, all_flows=False, sizes=sizes)
-        return out.flow
-    return fn
-
-
-def make_ragged_counted_inference_fn(config: RAFTConfig,
-                                     iters: Optional[int] = None):
-    """Ragged twin of :func:`make_counted_inference_fn`:
-    ``(params, image1, image2, sizes) -> (flow, iters_used)``."""
-    def fn(params, image1, image2, sizes):
-        out, _ = raft_forward(params, image1, image2, config, iters=iters,
-                              train=False, all_flows=False, sizes=sizes)
-        return out.flow, out.iters_used
-    return fn
-
-
-def make_ragged_stream_step_fn(config: RAFTConfig,
-                               iters: Optional[int] = None):
-    """Ragged twin of :func:`make_stream_step_fn`: ``(params, image,
-    fmap_prev, cnet_prev, flow_init, sizes) -> (flow, flow_lr, fmap_cur,
-    cnet_cur[, iters_used])`` with every array at the max box and ``sizes``
-    [B, 2] int32 full-res live extents.  The current frame is re-masked
-    in-graph before encoding (deterministic dead regions), and the cached
-    maps handed back are max-box rows a ragged arena stores verbatim."""
-    from ..config import adaptive_iters
-    adaptive = adaptive_iters(config.iters_policy)
-
-    def fn(params, image, fmap_prev, cnet_prev, flow_init, sizes):
-        image = mask_ragged_rows(image, sizes)
-        fmap_cur, cnet_cur = encode_frame(params, image, config)
-        out = forward_from_features(params, fmap_prev, fmap_cur, cnet_prev,
-                                    config, iters=iters, flow_init=flow_init,
-                                    sizes8=sizes.astype(jnp.int32) // 8)
-        if adaptive:
-            return out.flow, out.flow_lr, fmap_cur, cnet_cur, out.iters_used
-        return out.flow, out.flow_lr, fmap_cur, cnet_cur
-    return fn
-
-
-def make_ragged_stream_batch_step_fn(config: RAFTConfig,
-                                     iters: Optional[int] = None):
-    """Ragged twin of :func:`make_stream_batch_step_fn`: ``(params, images,
-    fmap_buf, cnet_buf, flow_buf, slots, active, sizes) -> (flow, flow_lr,
-    fmap_cur, cnet_cur[, iters_used])``.
-
-    ONE device call advances ``b`` sessions of DIFFERENT resolutions by one
-    frame each: buffers are a single max-box arena (every slot row is
-    max-box shaped, each session live only on its corner-anchored crop),
-    ``sizes`` [b, 2] int32 carries per-row full-res extents, and the
-    recurrent core runs the ragged correlation path — so mixed-resolution
-    sessions share one stream batch and one executable per batch step.
-    """
-    from ..config import adaptive_iters
-    adaptive = adaptive_iters(config.iters_policy)
-    quant = config.quant_slots
-
-    def fn(params, images, fmap_buf, cnet_buf, flow_buf, slots, active,
-           sizes):
-        images = mask_ragged_rows(images, sizes)
-        fmap_cur, cnet_cur = encode_frame(params, images, config)
-        if quant:
-            fmap_prev = dequantize_rows(fmap_buf[0][slots],
-                                        fmap_buf[1][slots]
-                                        ).astype(fmap_cur.dtype)
-            cnet_prev = dequantize_rows(cnet_buf[0][slots],
-                                        cnet_buf[1][slots]
-                                        ).astype(cnet_cur.dtype)
-        else:
-            fmap_prev = fmap_buf[slots]
-            cnet_prev = cnet_buf[slots]
-        flow_init = flow_buf[slots]
-        out = forward_from_features(params, fmap_prev, fmap_cur, cnet_prev,
-                                    config, iters=iters,
-                                    flow_init=flow_init, active=active,
-                                    sizes8=sizes.astype(jnp.int32) // 8)
-        if adaptive:
-            return (out.flow, out.flow_lr, fmap_cur, cnet_cur,
-                    out.iters_used)
-        return out.flow, out.flow_lr, fmap_cur, cnet_cur
+            res += (out.corr_keyblocks,)
+        return res if len(res) > 1 else out.flow
     return fn

@@ -38,6 +38,174 @@ _log = get_logger("serve")
 _LOOKUP_KINDS = ("pair", "stream", "sbatch")
 
 
+@dataclasses.dataclass(frozen=True)
+class Program:
+    """One executable as the engine compiles and calls it."""
+
+    fn: object                       # the jitted function
+    specs: tuple                     # its arguments in order, as their specs
+    outputs: Tuple[str, ...]         # what it returns, in order
+    resident: Tuple[int, ...] = ()   # arguments that are the pool's buffers
+    donated: Tuple[int, ...] = ()    # arguments the outputs take the place of
+
+
+class Programs:
+    """The table of the engine's kinds: for a key, WHICH function over WHICH
+    arguments returning WHAT (:meth:`program`).  Its readers are the compile
+    (``InferenceEngine._compile_traced``), the engine's call sites and
+    fetches (:meth:`takes_sizes`, :meth:`named`), the reload probe, and the
+    static budget (``lint/budget.kind_footprint``, which builds one from
+    abstract params and no device), so a program's signature is written
+    down here and nowhere else.
+
+    ``params`` are the weights or their specs; ``capacity`` the pool's
+    slots.  A ragged table's lookup kinds take the rows' ``sizes`` last
+    and hand no key-block counts out (the ragged launch has no band
+    schedule to count); ``mesh`` makes ``pair`` the data-parallel program.
+    ``donate``: the pool's buffers are DONATED into the scatter programs so
+    a commit updates rows in place (the CPU backend has no donation)."""
+
+    def __init__(self, config: RAFTConfig, params, capacity: int, *,
+                 iters: Optional[int] = None, ragged: bool = False,
+                 stream: bool = True, mesh=None, donate: bool = False):
+        import jax
+
+        from ..models.raft import (make_encode_fn, make_inference_fn,
+                                   make_stream_batch_step_fn,
+                                   make_stream_step_fn)
+        from .session import make_slot_commit_fn, make_slot_poison_fn
+
+        self.config, self.capacity, self.ragged = config, capacity, ragged
+        self.params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
+        adaptive = adaptive_iters(config.iters_policy)
+        # the dense Pallas lookup's key-block counts ride out of the lookup
+        # kinds beside their outputs, in the one fetch
+        self.keyblocks = kb = (config.corr_impl == "pallas" and not ragged
+                               and mesh is None)
+        tail = ("iters_used",) * adaptive + ("corr_keyblocks",) * kb
+        if mesh is not None:
+            from ..parallel import make_dp_eval_fn
+            pair = make_dp_eval_fn(config, mesh, iters=iters,
+                                   with_iters=adaptive)
+        else:
+            pair = jax.jit(make_inference_fn(config, iters=iters,
+                                             counted=adaptive, keyblocks=kb))
+        # kind -> (function, outputs, donated arguments); the function of
+        # ``szero`` is its shapes, so :meth:`program` makes it with its key
+        self._kinds = {"pair": (pair, ("flow",) + tail, ())}
+        if stream:
+            # plain single-device jits even under --serve-dp (batch-1
+            # session steps and slot scatters cannot shard over the data
+            # axis)
+            step = ("flow", "flow_lr", "fmap", "cnet") + tail
+            bufs = ("fmap_buf", "cnet_buf", "flow_buf")
+            pool = (0, 1, 2) if donate else ()
+            quant = config.quant_slots
+            self._kinds.update(
+                encode=(jax.jit(make_encode_fn(config)), ("fmap", "cnet"),
+                        ()),
+                stream=(jax.jit(make_stream_step_fn(
+                    config, iters=iters, keyblocks=kb)), step, ()),
+                sbatch=(jax.jit(make_stream_batch_step_fn(
+                    config, iters=iters, keyblocks=kb)), step, ()),
+                scommit=(jax.jit(make_slot_commit_fn(quant=quant),
+                                 donate_argnums=pool), bufs, pool),
+                spoison=(jax.jit(make_slot_poison_fn(quant=quant),
+                                 donate_argnums=pool[:1]), bufs[:1],
+                         pool[:1]),
+                szero=(None, bufs, ()))
+        # eval_shape is pure, so threads that race a miss only recompute
+        self.feature_specs = functools.lru_cache(maxsize=None)(
+            self._eval_features)
+
+    def _eval_features(self, h: int, w: int, b: int):
+        """(fmap, cnet) specs of ``b`` frames — derived from the model
+        itself (jax.eval_shape over the encode function), never hardcoded,
+        so bf16 compute or a variant change flows through automatically."""
+        import jax
+        import jax.numpy as jnp
+        img = jax.ShapeDtypeStruct((b, h, w, 3), jnp.float32)
+        return jax.eval_shape(self._kinds["encode"][0], self.params, img)
+
+    def slot_specs(self, h: int, w: int) -> tuple:
+        """ShapeDtypeStructs of a bucket's pool buffers ([capacity+1, …] —
+        the extra row is the scratch slot padding rows aim at).  Under
+        ``quant='int8'`` the fmap/cnet entries are 2-leaf pytrees
+        ``((cap+1, …) int8 vals, (cap+1, C) f32 per-channel scales)`` —
+        positional signatures everywhere stay at three buffer args (jit
+        handles pytree args), only the leaves change."""
+        import jax
+        import jax.numpy as jnp
+        fs, cs = self.feature_specs(h, w, 1)
+        cap1 = self.capacity + 1
+        flow = jax.ShapeDtypeStruct((cap1, h // 8, w // 8, 2), jnp.float32)
+        if self.config.quant_slots:
+            def q(s):
+                return (jax.ShapeDtypeStruct((cap1,) + s.shape[1:],
+                                             jnp.int8),
+                        jax.ShapeDtypeStruct((cap1, s.shape[-1]),
+                                             jnp.float32))
+            return (q(fs), q(cs), flow)
+        return (jax.ShapeDtypeStruct((cap1,) + fs.shape[1:], fs.dtype),
+                jax.ShapeDtypeStruct((cap1,) + cs.shape[1:], cs.dtype),
+                flow)
+
+    def takes_sizes(self, kind: str) -> bool:
+        """Does a ``kind`` call end with the rows' [b, 2] int32 live sizes
+        (the only shape-bearing metadata of a ragged program: a runtime
+        argument, so one executable serves every declared resolution)?"""
+        return self.ragged and kind in _LOOKUP_KINDS
+
+    def named(self, kind: str, out) -> Dict[str, object]:
+        """A ``kind`` call's outputs by name, in the program's order (a
+        program with one output returns it bare)."""
+        names = self._kinds[kind][1]
+        return dict(zip(names, out if len(names) > 1 else (out,)))
+
+    def program(self, key: Tuple) -> Program:
+        """The program of ``key`` = (kind, h, w, b[, policy])."""
+        import jax
+        import jax.numpy as jnp
+
+        kind, h, w, b = key[:4]
+        if kind not in self._kinds:
+            raise ValueError(f"unknown executable kind {kind!r}")
+        fn, outputs, donated = self._kinds[kind]
+        img = jax.ShapeDtypeStruct((b, h, w, 3), jnp.float32)
+        flow = jax.ShapeDtypeStruct((b, h // 8, w // 8, 2), jnp.float32)
+        idx = jax.ShapeDtypeStruct((b,), jnp.int32)
+        mask = jax.ShapeDtypeStruct((b,), jnp.bool_)
+        resident = ()
+        if kind == "pair":
+            specs = (self.params, img, img)
+        elif kind == "encode":
+            specs = (self.params, img)
+        elif kind == "stream":
+            specs = (self.params, img, *self.feature_specs(h, w, b), flow)
+        elif kind == "sbatch":
+            specs = (self.params, img, *self.slot_specs(h, w), idx, mask)
+            resident = (2, 3, 4)
+        elif kind == "scommit":
+            specs = (*self.slot_specs(h, w), idx,
+                     *self.feature_specs(h, w, b), flow, mask)
+            resident = (0, 1, 2)
+        elif kind == "spoison":
+            specs = (self.slot_specs(h, w)[0], idx)
+            resident = (0,)
+        else:
+            shapes = self.slot_specs(h, w)
+            # tree.map (not a flat tuple comprehension): under quant the
+            # fmap/cnet entries are nested (vals, scales) pytrees and the
+            # zeroed buffers must mirror that structure
+            fn = jax.jit(lambda: jax.tree.map(
+                lambda s: jnp.zeros(s.shape, s.dtype), shapes))
+            specs = ()
+        if self.takes_sizes(kind):
+            specs += (jax.ShapeDtypeStruct((b, 2), jnp.int32),)
+        return Program(fn, specs, outputs, resident, donated)
+
+
 class ReloadMismatch(ValueError):
     """New params don't match the serving template (tree structure or a
     leaf's shape/dtype differs, or the probe produced non-finite flow) —
@@ -92,10 +260,10 @@ class InferenceEngine:
     thread and tests/tools call the engine directly, so every mutable
     member is annotated and guarded — ``_lock`` for the executable cache
     and the call counters (the 1-fnet-per-frame acceptance observables:
-    a dropped increment is a wrong benchmark), ``_spec_lock`` for the
-    feature-spec cache (separate lock because the serve-time miss path
-    compiles while holding ``_lock``, and a nested re-take of one
-    non-reentrant lock would deadlock — raftlint C3).  The slot pool is
+    a dropped increment is a wrong benchmark).  What each kind IS — its
+    function, arguments and outputs — is :class:`Programs`' to say; its
+    feature-spec cache needs no lock (pure, and the serve-time miss path
+    compiles while holding ``_lock``).  The slot pool is
     only ever touched OUTSIDE the engine locks (pool._lock is a leaf of
     the hierarchy)."""
 
@@ -108,7 +276,6 @@ class InferenceEngine:
     stream_calls = guarded_by("_lock")
     weight_version = guarded_by("_lock")
     weight_tag = guarded_by("_lock")
-    _feature_specs = guarded_by("_spec_lock")
 
     def __init__(self, config: RAFTConfig, params, sconfig: ServeConfig,
                  iters: Optional[int] = None, stream: bool = False,
@@ -131,7 +298,6 @@ class InferenceEngine:
         self.sconfig = sconfig
         self.iters = iters
         self.iters_policy = config.iters_policy
-        self.adaptive = adaptive_iters(config.iters_policy)
         # ragged mixed-resolution serving: every flow-producing executable
         # takes a per-row [b, 2] int32 sizes argument and runs at the max
         # box, so ONE (kind, b, policy) executable serves every declared
@@ -148,7 +314,6 @@ class InferenceEngine:
         # decides them while a program is traced, from the dtype of the
         # encoder's maps: 1/3/3/3 for bfloat16 maps, 6 for float32 ones
         self.corr_mxu_terms = None
-        self._counts_keyblocks = False
         if config.corr_impl == "pallas":
             from ..ops.corr import level_mxu_passes
             self.corr_mxu_terms = level_mxu_passes(
@@ -161,77 +326,27 @@ class InferenceEngine:
             from ..models.raft import cast_encoder_weights
             params = cast_encoder_weights(params, config)
         self.params = jax.tree.map(jax.numpy.asarray, params)
-        self._mesh = None
+        mesh = None
         if sconfig.dp_devices > 1:
-            from ..parallel import make_dp_eval_fn
             from ..parallel.mesh import make_mesh
             if len(jax.devices()) < sconfig.dp_devices:
                 raise ValueError(
                     f"dp_devices={sconfig.dp_devices} but only "
                     f"{len(jax.devices())} device(s) visible")
-            self._mesh = make_mesh(sconfig.dp_devices)
-            self._fn = make_dp_eval_fn(config, self._mesh, iters=iters,
-                                       with_iters=self.adaptive)
-        elif self.ragged:
-            from ..models.raft import (make_ragged_counted_inference_fn,
-                                       make_ragged_inference_fn)
-            make = (make_ragged_counted_inference_fn if self.adaptive
-                    else make_ragged_inference_fn)
-            self._fn = jax.jit(make(config, iters=iters))
-        else:
-            from ..models.raft import (make_counted_inference_fn,
-                                       make_inference_fn)
-            make = (make_counted_inference_fn if self.adaptive
-                    else make_inference_fn)
-            # the dense Pallas lookup's key-block counts ride out of the
-            # pair executable beside the flow, in the one fetch
-            self._counts_keyblocks = config.corr_impl == "pallas"
-            self._fn = jax.jit(make(config, iters=iters,
-                                    keyblocks=self._counts_keyblocks))
+            mesh = make_mesh(sconfig.dp_devices)
         self.stream = stream
         self.pool = pool                  # session.SlotPool (stream servers)
-        if stream:
-            # the streaming executables are plain single-device jits even
-            # under --serve-dp (batch-1 session steps / slot scatters
-            # cannot shard over the data axis); they live in the same
-            # cache and warm grid
-            from ..models.raft import (make_encode_fn,
-                                       make_ragged_stream_batch_step_fn,
-                                       make_ragged_stream_step_fn,
-                                       make_stream_batch_step_fn,
-                                       make_stream_step_fn)
-            from .session import (SlotPool, make_slot_commit_fn,
-                                  make_slot_poison_fn)
-            if self.pool is None:
-                self.pool = SlotPool(max(1, sconfig.max_sessions),
-                                     arena=(self.max_box if self.ragged
-                                            else None))
-            self._encode_fn = jax.jit(make_encode_fn(config))
-            if self.ragged:
-                self._stream_fn = jax.jit(
-                    make_ragged_stream_step_fn(config, iters=iters))
-                self._sbatch_fn = jax.jit(
-                    make_ragged_stream_batch_step_fn(config, iters=iters))
-            else:
-                # the stream kinds hand the key-block counts out beside
-                # their outputs wherever the pair kind does
-                kb = self._counts_keyblocks
-                self._stream_fn = jax.jit(
-                    make_stream_step_fn(config, iters=iters, keyblocks=kb))
-                self._sbatch_fn = jax.jit(make_stream_batch_step_fn(
-                    config, iters=iters, keyblocks=kb))
-            # the pool buffers are DONATED into the scatter executables so
-            # a commit updates rows in place (off-CPU; the CPU backend has
-            # no donation, so skip it there and keep warmup logs quiet)
-            donate = (() if jax.default_backend() == "cpu" else (0, 1, 2))
-            self._scommit_fn = jax.jit(
-                make_slot_commit_fn(quant=config.quant_slots),
-                donate_argnums=donate)
-            self._spoison_fn = jax.jit(
-                make_slot_poison_fn(quant=config.quant_slots),
-                donate_argnums=donate[:1])
-            self._feature_specs: Dict[Tuple[int, int, int], tuple] = {}
-            self._spec_lock = watched_lock("InferenceEngine._spec_lock")
+        if stream and self.pool is None:
+            from .session import SlotPool
+            self.pool = SlotPool(max(1, sconfig.max_sessions),
+                                 arena=(self.max_box if self.ragged
+                                        else None))
+        # what every kind of executable is (the streaming kinds live in the
+        # same cache and warm grid as the pair ones)
+        self.programs = Programs(
+            config, self.params, self.pool.capacity if stream else 0,
+            iters=iters, ragged=self.ragged, stream=stream, mesh=mesh,
+            donate=jax.default_backend() != "cpu")
         # budget None: a cold cache miss compiles while holding the lock
         # (deliberate — see _get_executable), which busts any hold budget
         self._lock = watched_lock("InferenceEngine._lock", budget_s=None)
@@ -265,54 +380,6 @@ class InferenceEngine:
         executable)."""
         return (kind, h, w, b, self.iters_policy)
 
-    def _feature_shapes(self, h: int, w: int, b: int):
-        """Shape/dtype of the per-frame feature maps — derived from the
-        model itself (jax.eval_shape over the encode fn), never hardcoded,
-        so bf16 compute or a variant change flows through automatically.
-
-        The old bare ``if key not in ...: ... = ...`` here was the
-        check-then-act race raftlint C5 exists for: warmup (start thread)
-        and a first stream step (batcher thread) could both pass the
-        check.  eval_shape is pure and cheap, so losers just recompute;
-        ``setdefault`` under the lock keeps one canonical entry."""
-        import jax
-        import jax.numpy as jnp
-        key = (h, w, b)
-        with self._spec_lock:
-            spec = self._feature_specs.get(key)
-        if spec is None:
-            img = jax.ShapeDtypeStruct((b, h, w, 3), jnp.float32)
-            spec = jax.eval_shape(self._encode_fn, self.params, img)
-            with self._spec_lock:
-                spec = self._feature_specs.setdefault(key, spec)
-        return spec
-
-    def _slot_specs(self, h: int, w: int):
-        """ShapeDtypeStructs of this bucket's pool buffers ([cap+1, …] —
-        the extra row is the scratch slot padding rows aim at), derived
-        from the same eval_shape'd feature specs as the stream kinds.
-
-        Under ``quant='int8'`` the fmap/cnet entries are 2-leaf pytrees
-        ``((cap+1, …) int8 vals, (cap+1, C) f32 per-channel scales)`` —
-        positional signatures everywhere stay at three buffer args (jit
-        handles pytree args), only the leaves change.  lint/budget's
-        ``slot_specs`` mirrors this shape math exactly (parity-tested)."""
-        import jax
-        import jax.numpy as jnp
-        fs, cs = self._feature_shapes(h, w, 1)
-        cap1 = self.pool.capacity + 1
-        flow = jax.ShapeDtypeStruct((cap1, h // 8, w // 8, 2), jnp.float32)
-        if self.config.quant_slots:
-            def q(s):
-                return (jax.ShapeDtypeStruct((cap1,) + s.shape[1:],
-                                             jnp.int8),
-                        jax.ShapeDtypeStruct((cap1, s.shape[-1]),
-                                             jnp.float32))
-            return (q(fs), q(cs), flow)
-        return (jax.ShapeDtypeStruct((cap1,) + fs.shape[1:], fs.dtype),
-                jax.ShapeDtypeStruct((cap1,) + cs.shape[1:], cs.dtype),
-                flow)
-
     def _compile(self, key: Tuple[str, int, int, int, str]):
         if self.cache is not None:
             # serialized executables cannot carry host callbacks — the
@@ -327,55 +394,8 @@ class InferenceEngine:
         return self._compile_traced(key)
 
     def _compile_traced(self, key: Tuple[str, int, int, int, str]):
-        import jax
-        import jax.numpy as jnp
-
-        kind, h, w, b = key[:4]
-        img = jax.ShapeDtypeStruct((b, h, w, 3), jnp.float32)
-        # ragged: flow-producing kinds take per-row [b, 2] int32 live sizes
-        # (the only shape-bearing metadata — it is a runtime argument, so
-        # one executable serves every declared resolution)
-        sz = jax.ShapeDtypeStruct((b, 2), jnp.int32)
-        if kind == "pair":
-            if self.ragged:
-                return self._fn.lower(self.params, img, img, sz).compile()
-            return self._fn.lower(self.params, img, img).compile()
-        if kind == "encode":
-            return self._encode_fn.lower(self.params, img).compile()
-        if kind == "stream":
-            fmap_s, cnet_s = self._feature_shapes(h, w, b)
-            flow_s = jax.ShapeDtypeStruct((b, h // 8, w // 8, 2),
-                                          jnp.float32)
-            if self.ragged:
-                return self._stream_fn.lower(self.params, img, fmap_s,
-                                             cnet_s, flow_s, sz).compile()
-            return self._stream_fn.lower(self.params, img, fmap_s, cnet_s,
-                                         flow_s).compile()
-        fbuf, cbuf, flbuf = self._slot_specs(h, w)
-        idx = jax.ShapeDtypeStruct((b,), jnp.int32)
-        mask = jax.ShapeDtypeStruct((b,), jnp.bool_)
-        if kind == "sbatch":
-            if self.ragged:
-                return self._sbatch_fn.lower(self.params, img, fbuf, cbuf,
-                                             flbuf, idx, mask, sz).compile()
-            return self._sbatch_fn.lower(self.params, img, fbuf, cbuf,
-                                         flbuf, idx, mask).compile()
-        if kind == "scommit":
-            fs, cs = self._feature_shapes(h, w, b)
-            seeds = jax.ShapeDtypeStruct((b, h // 8, w // 8, 2),
-                                         jnp.float32)
-            return self._scommit_fn.lower(fbuf, cbuf, flbuf, idx, fs, cs,
-                                          seeds, mask).compile()
-        if kind == "spoison":
-            return self._spoison_fn.lower(fbuf, idx).compile()
-        assert kind == "szero", kind
-        shapes = self._slot_specs(h, w)
-        # tree.map (not a flat tuple comprehension): under quant the
-        # fmap/cnet entries are nested (vals, scales) pytrees and the
-        # zeroed buffers must mirror that structure
-        zero = jax.jit(lambda: jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), shapes))
-        return zero.lower().compile()
+        prog = self.programs.program(key)
+        return prog.fn.lower(*prog.specs).compile()
 
     def _get_executable(self, key: Tuple[int, int, int, str]):
         with self._lock:
@@ -560,13 +580,8 @@ class InferenceEngine:
                     pair_keys, key=lambda k: k[1] * k[2] * k[3])
                 ex = self._get_executable(self._key(h, w, b, kind))
                 img = np.zeros((b, h, w, 3), np.float32)
-                if self.ragged:
-                    out = ex(staged, img, img, self._sizes_arg(b, None))
-                else:
-                    out = ex(staged, img, img)
-                # flow[, iters_used][, key-block counts]: bare when alone
-                flow = np.asarray(out[0] if isinstance(out, (tuple, list))
-                                  else out)
+                out = ex(*self._with_sizes("pair", b, None, staged, img, img))
+                flow = np.asarray(self.programs.named("pair", out)["flow"])
                 if not np.all(np.isfinite(flow)):
                     raise ReloadMismatch(
                         "probe produced non-finite flow; rejecting swap")
@@ -646,6 +661,27 @@ class InferenceEngine:
             return np.tile(np.asarray([[h, w]], np.int32), (n, 1))
         return np.asarray(sizes, np.int32)
 
+    def _with_sizes(self, kind: str, n: int, sizes, *args) -> tuple:
+        """``args`` of a ``kind`` call over ``n`` rows, their ``sizes`` last
+        where the kind takes them."""
+        if self.programs.takes_sizes(kind):
+            args += (self._sizes_arg(n, sizes),)
+        return args
+
+    def _fetch_outputs(self, kind: str, out) -> Dict[str, object]:
+        """A finished ``kind`` call's outputs by name
+        (:meth:`Programs.named`): on the host all but the maps, which stay
+        on the device; the key-block counts that ride beside them go to
+        ``corr_keyblocks``."""
+        res = self.programs.named(kind, out)
+        host = [n for n in res if n not in ("fmap", "cnet")]
+        res.update(zip(host, self._fetch(
+            "pair" if kind == "pair" else "stream",
+            *(res[n] for n in host))))
+        if "corr_keyblocks" in res:
+            self._count_keyblocks(res.pop("corr_keyblocks"))
+        return res
+
     # -- a pair call, one phase at a time ----------------------------------
     #
     # ``run`` is place -> dispatch -> wait -> fetch.  The batcher calls the
@@ -664,10 +700,8 @@ class InferenceEngine:
         if self.faults is not None:
             self.faults.pre_engine_call()
         im1, im2 = self._h2d("pair", ex, 1, im1, im2)
-        args = (self.params, im1, im2)
-        if self.ragged:
-            args += (self._sizes_arg(n, sizes),)
-        return PairCall(ex, args)
+        return PairCall(ex, self._with_sizes("pair", n, sizes, self.params,
+                                             im1, im2))
 
     def dispatch(self, call: "PairCall") -> None:
         """Enqueue a placed call; returns before the device has run it."""
@@ -687,13 +721,8 @@ class InferenceEngine:
         """A finished call's outputs on the host: the flow, or (flow,
         iters_used [n] int32) under a converge policy; the key-block counts
         that ride beside them go to ``corr_keyblocks``."""
-        out = call.out
-        # flow[, iters_used][, key-block counts]: a bare array when alone
-        outs = out if isinstance(out, (tuple, list)) else (out,)
-        flow, *rest = self._fetch("pair", *outs)
-        if self._counts_keyblocks:
-            self._count_keyblocks(rest.pop())
-        iters_used = rest[0] if self.adaptive else None
+        res = self._fetch_outputs("pair", call.out)
+        flow, iters_used = res["flow"], res.get("iters_used")
         if self.faults is not None:
             flow = self.faults.corrupt_rows(flow)
         return flow if iters_used is None else (flow, iters_used)
@@ -748,25 +777,15 @@ class InferenceEngine:
         if self.faults is not None:
             self.faults.pre_engine_call()
         [image] = self._h2d("stream", ex, 1, image)
-        args = (self.params, image, fmap_prev, cnet_prev, flow_init)
-        if self.ragged:
-            args += (self._sizes_arg(n, sizes),)
-        out = self._call("stream", ex, args)
-        flow, flow_lr, iters_used = self._fetch_stream(out)
+        out = self._call("stream", ex, self._with_sizes(
+            "stream", n, sizes, self.params, image, fmap_prev, cnet_prev,
+            flow_init))
+        res = self._fetch_outputs("stream", out)
+        flow = res["flow"]
         if self.faults is not None:
             flow = self.faults.corrupt_rows(flow)
-        return flow, flow_lr, out[2], out[3], iters_used
-
-    def _fetch_stream(self, out) -> tuple:
-        """A finished stream step's host outputs ``(flow, flow_lr,
-        iters_used or None)`` of ``(flow, flow_lr, fmap, cnet[, iters_used]
-        [, key-block counts])``; the maps stay on the device and the counts
-        go to ``corr_keyblocks``."""
-        flow, flow_lr, *rest = self._fetch("stream", out[0], out[1],
-                                           *out[4:])
-        if self._counts_keyblocks:
-            self._count_keyblocks(rest.pop())
-        return flow, flow_lr, rest[0] if rest else None
+        return (flow, res["flow_lr"], res["fmap"], res["cnet"],
+                res.get("iters_used"))
 
     # -- the continuous-batched stream path (slot pool) --------------------
     #
@@ -788,10 +807,8 @@ class InferenceEngine:
         if self.faults is not None:
             self.faults.pre_engine_call()
         [images] = self._h2d("stream", ex, 1, images)
-        args = (images,)
-        if self.ragged:
-            args += (self._sizes_arg(b, sizes),)
-        return StreamBatchCall(ex, args, bucket)
+        return StreamBatchCall(
+            ex, self._with_sizes("sbatch", b, sizes, images), bucket)
 
     def dispatch_stream_batch(self, call: "StreamBatchCall",
                               slots: np.ndarray, active: np.ndarray) -> None:
@@ -818,8 +835,8 @@ class InferenceEngine:
         fmap_rows dev, cnet_rows dev, iters_used [b] np or None)`` — the
         updated map ROWS stay device-resident until :meth:`commit_stream`
         scatters the finite ones into the pool."""
-        out = call.out
-        flow, flow_lr, iters_used = self._fetch_stream(out)
+        res = self._fetch_outputs("sbatch", call.out)
+        flow = res["flow"]
         if self.faults is not None:
             # chaos must poison a REAL row: padding rows (the suffix, by
             # the coordinator's construction) are discarded before the
@@ -828,7 +845,8 @@ class InferenceEngine:
             flow = np.concatenate(
                 [self.faults.corrupt_rows(flow[:call.rows]),
                  flow[call.rows:]])
-        return flow, flow_lr, out[2], out[3], iters_used
+        return (flow, res["flow_lr"], res["fmap"], res["cnet"],
+                res.get("iters_used"))
 
     def run_stream_batch(self, bucket: Tuple[int, int], images: np.ndarray,
                          slots: np.ndarray, active: np.ndarray,

@@ -895,7 +895,7 @@ def test_reload_probes_a_program_that_returns_key_block_counts():
                           max_sessions=0)
     engine = InferenceEngine(config, params, sconfig)
     engine.warmup(verbose=False)
-    assert engine._counts_keyblocks
+    assert engine.programs.keyblocks
     out = engine.run((32, 48), *(np.zeros((1, 32, 48, 3), np.float32),) * 2)
     assert out.shape == (1, 32, 48, 2) and engine.corr_keyblocks[2] > 0
     # a 4x6 grid: every level is one block of eight map rows to a 128-lane

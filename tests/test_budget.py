@@ -49,6 +49,13 @@ def pspecs(config):
     return budget.param_specs(config)
 
 
+def programs(config, pspecs, capacity=4, **kw):
+    """The engine's table of kinds over abstract params, as
+    ``budget.analyze`` builds it."""
+    from raft_tpu.serving.engine import Programs
+    return Programs(config, pspecs, capacity, **kw)
+
+
 # ---------------------------------------------------------------- bytes
 
 
@@ -69,7 +76,7 @@ def test_param_specs_match_real_init(config, pspecs):
 
 def test_slot_specs_shapes(config, pspecs):
     h, w = BUCKET
-    fs, cs, flow = budget.slot_specs(config, pspecs, h, w, capacity=4)
+    fs, cs, flow = programs(config, pspecs).slot_specs(h, w)
     assert fs.shape[0] == cs.shape[0] == flow.shape[0] == 5  # cap + scratch
     assert flow.shape == (5, h // 8, w // 8, 2)
     assert fs.shape[1:3] == cs.shape[1:3] == (h // 8, w // 8)
@@ -117,6 +124,65 @@ def test_grid_parity_with_live_warm_engine(config):
                                      chaos=False)
     assert sorted(expected) == list(eng.keys())
     assert len(expected) == eng.executables
+
+
+PALLAS = RAFTConfig.small_model(iters=2, corr_impl="pallas")
+
+
+@pytest.fixture(scope="module")
+def stream_engines():
+    """A dense and a ragged stream engine of one configuration whose
+    lookup is the Pallas kernel (its key-block counts ride out of the dense
+    lookup kinds), nothing warmed."""
+    from raft_tpu.models.raft import init_raft
+    from raft_tpu.serving.engine import InferenceEngine
+    params = init_raft(init_rng(0), PALLAS)
+    return {ragged: InferenceEngine(PALLAS, params,
+                                    small_serve(ragged=ragged), stream=True)
+            for ragged in (False, True)}
+
+
+def _avals(tree):
+    return jax.tree.map(lambda a: (tuple(a.shape), np.dtype(a.dtype).name),
+                        tree)
+
+
+@pytest.mark.parametrize("ragged,kind", [
+    (False, "pair"), (False, "encode"), (False, "stream"), (False, "szero"),
+    (False, "scommit"), (False, "sbatch"), (False, "spoison"),
+    (True, "pair"), (True, "stream"), (True, "sbatch")])
+def test_budget_prices_what_the_engine_lowers(stream_engines, monkeypatch,
+                                              ragged, kind):
+    """The two readers of the engine's table of kinds, held to it: for every
+    key of a kind in the warm-up grid, the argument and output trees
+    ``kind_footprint`` prices (its ``jax.eval_shape``) are the ones
+    ``_compile_traced`` lowers — the key-block counts of the dense lookup
+    kinds and ``sizes`` of the ragged ones included."""
+    eng = stream_engines[ragged]
+    keys = [k for k in enumerate_warmup_grid(PALLAS, eng.sconfig,
+                                             stream=True, chaos=True)
+            if k[0] == kind]
+    assert keys
+    table = programs(PALLAS, budget.param_specs(PALLAS), ragged=ragged)
+    priced = []
+    real = jax.eval_shape
+
+    def spy(fn, *specs):
+        priced.append((specs, real(fn, *specs)))
+        return priced[-1][1]
+    monkeypatch.setattr(jax, "eval_shape", spy)
+    # lowering is what is compared: leave the compile out
+    monkeypatch.setattr(jax.stages.Lowered, "compile", lambda self: self)
+    for key in keys:
+        fp = budget.kind_footprint(table, key)
+        specs, out = priced[-1]
+        lowered = eng._compile_traced(key)
+        assert _avals(lowered.in_avals) == _avals((specs, {}))
+        assert _avals(lowered.out_info) == _avals(out)
+        assert fp["output_bytes"] == budget.tree_bytes(lowered.out_info)
+        names = eng.programs.named(kind, out)
+        assert ("corr_keyblocks" in names) == (
+            kind in ("pair", "stream", "sbatch") and not ragged)
 
 
 # ------------------------------------------------------- kernel planning
@@ -268,12 +334,12 @@ def test_gru_vmem_envelope_scales_with_block_rows():
 def test_donation_accounting_scommit(config, pspecs):
     h, w = BUCKET
     key = ("scommit", h, w, 1, "fixed")
-    donated = budget.kind_footprint(config, pspecs, key, capacity=4,
-                                    donation=True)
-    copied = budget.kind_footprint(config, pspecs, key, capacity=4,
-                                   donation=False)
+    donated = budget.kind_footprint(programs(config, pspecs, donate=True),
+                                    key)
+    copied = budget.kind_footprint(programs(config, pspecs, donate=False),
+                                   key)
     pool_bytes = sum(budget.bytes_of(s) for s in
-                     budget.slot_specs(config, pspecs, h, w, 4))
+                     programs(config, pspecs).slot_specs(h, w))
     # donated outputs alias the input pool buffers; without donation the
     # scatter materializes a full second copy of the pool
     assert donated["donated_bytes"] == pool_bytes
@@ -284,18 +350,17 @@ def test_donation_accounting_scommit(config, pspecs):
 
 def test_szero_builds_residents_not_transients(config, pspecs):
     h, w = BUCKET
-    fp = budget.kind_footprint(config, pspecs, ("szero", h, w, 1, "fixed"),
-                               capacity=4)
+    fp = budget.kind_footprint(programs(config, pspecs),
+                               ("szero", h, w, 1, "fixed"))
     assert fp["transient_bytes"] == 0
     assert fp["output_bytes"] == fp["pool_bytes"] > 0
 
 
 def test_pair_footprint_scales_with_batch(config, pspecs):
     h, w = BUCKET
-    f1 = budget.kind_footprint(config, pspecs, ("pair", h, w, 1, "fixed"),
-                               capacity=1)
-    f2 = budget.kind_footprint(config, pspecs, ("pair", h, w, 2, "fixed"),
-                               capacity=1)
+    table = programs(config, pspecs, capacity=1)
+    f1 = budget.kind_footprint(table, ("pair", h, w, 1, "fixed"))
+    f2 = budget.kind_footprint(table, ("pair", h, w, 2, "fixed"))
     assert f2["input_bytes"] == 2 * f1["input_bytes"]
     assert f2["transient_bytes"] > f1["transient_bytes"]
     # the batcher keeps a second batch's inputs on the device beside the
@@ -303,8 +368,7 @@ def test_pair_footprint_scales_with_batch(config, pspecs):
     for f in (f1, f2):
         assert f["staged_bytes"] == f["input_bytes"] + f["output_bytes"]
         assert f["transient_bytes"] == 2 * f["staged_bytes"]
-    enc = budget.kind_footprint(config, pspecs, ("encode", h, w, 1, "fixed"),
-                                capacity=1)
+    enc = budget.kind_footprint(table, ("encode", h, w, 1, "fixed"))
     assert enc["staged_bytes"] == 0       # only pair calls are pipelined
 
 

@@ -340,16 +340,16 @@ def test_converge_gradients_flow_through_masked_scan_remat():
 def test_converge_jit_and_counted_fn():
     """The counted inference fn jits, and under jit the early exit still
     reports per-sample counts (static shapes, data-dependent trip count)."""
-    from raft_tpu.models import make_counted_inference_fn
     cfg = RAFTConfig.small_model(iters=4, iters_policy="converge:1e9:2")
     params, im1, im2 = _params_and_images(cfg, B=2, H=32, W=48)
-    flow, used = jax.jit(make_counted_inference_fn(cfg))(params, im1, im2)
+    flow, used = jax.jit(make_inference_fn(cfg, counted=True))(
+        params, im1, im2)
     assert flow.shape == (2, 32, 48, 2)
     assert used.dtype == jnp.int32
     assert np.asarray(used).tolist() == [2, 2]
     # fixed policy reports the declared count
-    flowf, usedf = make_counted_inference_fn(
-        RAFTConfig.small_model(iters=4))(params, im1, im2)
+    flowf, usedf = make_inference_fn(
+        RAFTConfig.small_model(iters=4), counted=True)(params, im1, im2)
     assert np.asarray(usedf).tolist() == [4, 4]
 
 

@@ -137,11 +137,11 @@ def test_ragged_model_solo_vs_mixed_and_garbage_embed():
     dead embedding must not change outputs AT ALL — same executable, so
     the in-graph re-mask is a bitwise determinism contract."""
     from raft_tpu.config import RAFTConfig, init_rng
-    from raft_tpu.models.raft import init_raft, make_ragged_inference_fn
+    from raft_tpu.models.raft import init_raft, make_inference_fn
 
     config = RAFTConfig.small_model(iters=2, corr_impl="pallas")
     params = init_raft(init_rng(0), config)
-    fn = jax.jit(make_ragged_inference_fn(config, iters=2))
+    fn = jax.jit(make_inference_fn(config, iters=2))
 
     Hm, Wm = 32, 48
     rng = np.random.RandomState(1)
@@ -172,6 +172,50 @@ def test_ragged_model_solo_vs_mixed_and_garbage_embed():
     for b, (h, w) in enumerate(sizes):
         err = np.abs(flow_g[b, :h, :w] - flow[b, :h, :w]).max()
         assert err == 0.0, (b, err)
+
+
+@pytest.mark.parametrize("kind", ["pair", "stream", "sbatch"])
+def test_full_box_sizes_agree_with_the_dense_program(kind):
+    """``sizes`` is what makes a served program the ragged one: with every
+    row live on the whole box, the ragged program of each lookup kind and
+    the dense program of the same factory give the same flow (and the same
+    maps back), to the tolerance solo and mixed rows are held to."""
+    from raft_tpu.config import RAFTConfig, init_rng
+    from raft_tpu.models.raft import (encode_frame, init_raft,
+                                      make_inference_fn,
+                                      make_stream_batch_step_fn,
+                                      make_stream_step_fn)
+
+    config = RAFTConfig.small_model(iters=2, corr_impl="pallas")
+    params = init_raft(init_rng(0), config)
+    b, (h, w) = 2, (32, 48)
+    rng = np.random.RandomState(3)
+    im1, im2 = (jnp.asarray(rng.rand(b, h, w, 3).astype(np.float32))
+                for _ in range(2))
+    full = jnp.tile(jnp.asarray([[h, w]], jnp.int32), (b, 1))
+    if kind == "pair":
+        fn, args = make_inference_fn(config), (im1, im2)
+    else:
+        fmap, cnet = encode_frame(params, im1, config)
+        seed = jnp.asarray(rng.randn(b, h // 8, w // 8, 2)
+                           .astype(np.float32))
+        if kind == "stream":
+            fn, args = make_stream_step_fn(config), (im2, fmap, cnet, seed)
+        else:
+            # a pool of b slots and the scratch row, the rows in slots 1, 0
+            pool = [jnp.concatenate([x, jnp.zeros_like(x[:1])])
+                    for x in (fmap, cnet, seed)]
+            fn = make_stream_batch_step_fn(config)
+            args = (im2[::-1], *pool, jnp.asarray([1, 0], jnp.int32),
+                    jnp.ones((b,), bool))
+    fn = jax.jit(fn)
+    dense = fn(params, *args)
+    ragged = fn(params, *args, full)
+    assert jax.tree.structure(dense) == jax.tree.structure(ragged)
+    for d, r in zip(jax.tree.leaves(dense), jax.tree.leaves(ragged)):
+        np.testing.assert_allclose(np.asarray(r, np.float32),
+                                   np.asarray(d, np.float32),
+                                   rtol=1e-3, atol=1e-3)
 
 
 # --------------------------------------------------- embed + slot arena --
