@@ -2,7 +2,8 @@
 """chip_smoke.py — the quickest proof that raft-tpu still starts on the chip.
 
     python3 chip_smoke.py             # one chip: device, kernels, serve,
-                                      # small, train
+                                      # small, int8, train
+    python3 chip_smoke.py --int8      # one chip: the int8 phase alone
     python3 chip_smoke.py --chips 4   # four chips: fleet, dp=4 step, spatial-4
     python3 chip_smoke.py --flows     # one chip: the served flows' sha256
 
@@ -12,7 +13,10 @@ published widths of raft-things (``RAFTConfig.full``: fnet 256, hidden 128,
 context 128, 4 levels, radius 4), with seeded random weights and the
 committed Sintel pair ``assets/frame_0016.png`` / ``frame_0017.png``; the
 ``small`` phase runs RAFT-S's served program (hidden 96, radius 3) once at the
-benchmark's 8 x 1080x1920 against ``benchmark/reference.py``.  ``--flows``
+benchmark's 8 x 1080x1920 against ``benchmark/reference.py``; the ``int8``
+phase opens, advances, demotes and restarts a session on the 256-slot 1080p
+int8 pool of ``benchmark/configs/raft-things-1080p-stream-int8.json`` (PR 45:
+flows finite, the pool's bytes printed).  ``--flows``
 is a tool and no part of the smoke: it prints the sha256 of the five served
 programs' flows on a fixed seed, for a change to the lookup that has to leave
 them the parent's bit for bit (run it on both trees in one call).
@@ -98,6 +102,11 @@ class Sizes:
     # at the batch and frame size the cell small-1080p-b8-closed times
     small_batch: int = 8
     small_hw: tuple = (1080, 1920)
+    # int8: the slot pool of benchmark/configs/raft-things-1080p-stream-
+    # int8.json (its serve arguments: the bucket and --max-sessions are
+    # exchanged for these in the CPU rehearsal alone)
+    int8_hw: tuple = (1080, 1920)
+    int8_slots: int = 256
     # flows: the five served programs (FLOW_PROGRAMS) at their own frames,
     # batches and iteration counts; the CPU rehearsal cuts all three
     flow_programs: tuple = FLOW_PROGRAMS
@@ -864,6 +873,126 @@ def phase_small(meter, sz: Sizes) -> None:
 FLOW_SEED = 4_300_000_043
 
 
+def phase_int8(meter, sz: Sizes) -> None:
+    """The int8 slot pool at the size a deployment holds it, through the
+    real server: ``benchmark/configs/raft-things-1080p-stream-int8.json``'s
+    serve arguments (``--quant int8 --max-sessions 256`` at 1080x1920)
+    without the warm-up, so that only what the walk needs is compiled
+    (``encode``, ``szero``, the one-row ``sbatch`` and ``scommit``).  A
+    session is opened and advanced; ``int8_slots`` more opens fill the pool
+    and take its slot (LRU); its next frame restarts cold, re-seated where
+    its group is placed, and the frame after that is warm.  Every flow is
+    finite, the pool is full, its bytes are the gauges' and the leaves'
+    dtypes are int8 and float32, and every committed row was quantised.  A
+    changed pool format is seen here before a benchmark run meets it."""
+    import numpy as np
+
+    from raft_tpu.fleet.manager import parse_prom_text
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "raft-things-1080p-stream-int8.json")) as f:
+        argv = [str(a) for a in json.load(f)["serve_args"]]
+    h, w = sz.int8_hw
+    argv[argv.index("--buckets") + 1] = f"{h}x{w}"
+    argv[argv.index("--max-sessions") + 1] = str(sz.int8_slots)
+    if sz.interpret:                  # the CPU rehearsal: float32, no kernels
+        argv[argv.index("--dtype") + 1] = "float32"
+        argv[argv.index("--gru-impl") + 1] = "xla"
+        argv[argv.index("--iters") + 1] = str(min(sz.iters, 2))
+    out_dir = os.path.join(WORK, "int8")
+    argv = ["-m", "serve"] + argv + ["--no-warmup", "--port", "0",
+                                     "--out", out_dir]
+    rng = np.random.default_rng(45)
+    base = rng.integers(0, 255, (h + 16, w + 16, 3), dtype=np.uint8)
+    frames = [base[k:k + h, 2 * k:2 * k + w].astype(np.float32) / 255.0
+              for k in range(4)]      # a scene that pans: a real flow field
+
+    with Phase(meter, "int8") as ph:
+        server, config = build_cli_server(argv)
+        check(config.quant_slots, f"quant={config.quant!r}: not int8 slots")
+        server.start()
+        result = {}
+
+        def post(**arrays):
+            st, payload, _ = http_call(server.url, "POST", "/v1/stream",
+                                       npz_body(**arrays))
+            check(st == 200, f"/v1/stream {sorted(arrays)} -> {st}: "
+                  f"{payload[:200]!r}")
+            return npz_load(payload)
+
+        def client():
+            try:
+                sid = str(post(image=frames[0])["session"])
+                answers = [post(session=np.asarray(sid), image=frames[1])]
+                t0 = time.monotonic()
+                for _ in range(sz.int8_slots):
+                    post(image=frames[0])
+                result["fill_seconds"] = time.monotonic() - t0
+                for im in frames[2:]:
+                    answers.append(post(session=np.asarray(sid), image=im))
+                result["answers"] = answers
+                st, payload, _ = http_call(server.url, "GET", "/metrics")
+                check(st == 200, f"/metrics -> {st}")
+                result["prom"] = parse_prom_text(payload.decode())
+            except BaseException as e:  # noqa: BLE001 — re-raised below
+                result["error"] = e
+
+        try:
+            t = threading.Thread(target=client, name="smoke-int8")
+            t.start()
+            t.join()
+            if "error" in result:
+                raise result["error"]
+            warm = [bool(a["warm"]) for a in result["answers"]]
+            check(warm == [True, False, True],
+                  f"open, advance, {sz.int8_slots} opens, two advances "
+                  f"answered warm={warm}: want warm, a cold restart, warm")
+            for a in result["answers"]:
+                check(a["flow"].shape[-3:] == (h, w, 2)
+                      and np.isfinite(a["flow"]).all(),
+                      f"a flow of shape {a['flow'].shape} is not finite")
+            prom = result["prom"]
+            bufs = server.engine.pool.buffers((h, w))
+            dtypes = [str(leaf.dtype) for leaf in (*bufs[0], *bufs[1],
+                                                   bufs[2])]
+            check(dtypes == ["int8", "float32", "int8", "float32",
+                             "float32"], f"the pool's leaves are {dtypes}")
+            pool = {leaf: int(prom[f'raft_stream_pool_bytes{{leaf="{leaf}"}}'])
+                    for leaf in ("vals", "scales", "seed")}
+            rows, q = sz.int8_slots + 1, (h // 8) * (w // 8)
+            want = {"vals": rows * q * (bufs[0][0].shape[-1]
+                                        + bufs[1][0].shape[-1]),
+                    "scales": rows * 4 * (bufs[0][1].shape[-1]
+                                          + bufs[1][1].shape[-1]),
+                    "seed": rows * q * 2 * 4}
+            check(pool == want, f"raft_stream_pool_bytes {pool}, the "
+                  f"arrays' shapes give {want}")
+            bucket = f'{{bucket="{h}x{w}"}}'
+            fill = (prom["raft_stream_slots_in_use" + bucket],
+                    prom["raft_stream_slot_capacity" + bucket])
+            check(fill == (sz.int8_slots, sz.int8_slots),
+                  f"slots in use / capacity = {fill}")
+            counted = {k: int(prom[f"raft_stream_{k}_total"]) for k in
+                       ("rows_quantized", "frames", "opens",
+                        "restarts_batched")}
+            check(counted["rows_quantized"] == counted["frames"]
+                  + counted["opens"] + counted["restarts_batched"]
+                  == 3 + (sz.int8_slots + 1) + 1,
+                  f"rows quantised = frames + opens + restarts: {counted}")
+            lru = prom['raft_stream_evictions_total{reason="lru"}']
+            check(lru == 2, f"one open too many and one resume: want 2 LRU "
+                  f"demotions, /metrics says {lru}")
+            ph.note(argv=" ".join(argv), dtype=config.compute_dtype,
+                    executables=server.engine.executables,
+                    slots=sz.int8_slots, pool_bytes=pool,
+                    pool_gb=round(sum(pool.values()) / 1e9, 4),
+                    fill_seconds=round(result["fill_seconds"], 2),
+                    mean_abs_flow=[round(float(np.abs(a["flow"]).mean()), 4)
+                                   for a in result["answers"]], **counted)
+        finally:
+            server.stop()
+
+
 def served_flow_hashes(sz: Sizes, note=lambda **kv: None) -> dict:
     """name -> sha256 of the flow each program of ``sz.flow_programs``
     answers on ``FLOW_SEED``: the configuration's own serve arguments
@@ -1326,7 +1455,8 @@ def phase_spatial(meter, sz: Sizes, n: int = 4) -> None:
 
 # -------------------------------------------------------------------- main
 
-def run(chips: int, sz: Sizes, flows: bool = False) -> dict:
+def run(chips: int, sz: Sizes, flows: bool = False,
+        int8: bool = False) -> dict:
     """All phases for ``chips``; returns the device record.  Raises on the
     first failure."""
     shutil.rmtree(WORK, ignore_errors=True)
@@ -1347,6 +1477,8 @@ def run(chips: int, sz: Sizes, flows: bool = False) -> dict:
     device = phase_device(meter, chips) if not sz.interpret else None
     if flows:
         phase_flows(meter, sz)
+    elif int8:
+        phase_int8(meter, sz)
     elif chips == 4:
         phase_dp(meter, sz)
         phase_spatial(meter, sz)
@@ -1354,6 +1486,7 @@ def run(chips: int, sz: Sizes, flows: bool = False) -> dict:
         phase_kernels(meter, sz)
         phase_serve(meter, sz)
         phase_small(meter, sz)
+        phase_int8(meter, sz)
         phase_train(meter, sz)
     emit(phase="end", compile_seconds=round(meter.seconds, 2),
          cache_hits=meter.hits, cache_misses=meter.misses,
@@ -1364,7 +1497,7 @@ def run(chips: int, sz: Sizes, flows: bool = False) -> dict:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                   help="1 (default): device, kernels, serve, small, train on "
+                   help="1 (default): device, kernels, serve, small, int8, train on "
                         "one chip.  4: only the cross-chip phase — fleet of "
                         "four one-chip replicas, dp=4 train step, spatial-4 "
                         "forward — and what each is compared with")
@@ -1372,9 +1505,16 @@ def main(argv=None) -> int:
                    help="no smoke: print the sha256 of the five served "
                         "programs' flows on a fixed seed (one chip), to hold "
                         "a change to the lookup to its parent bit for bit")
+    p.add_argument("--int8", action="store_true",
+                   help="the int8 phase alone (one chip): open, advance, "
+                        "demote, restart on the 256-slot 1080p int8 pool of "
+                        "benchmark/configs/raft-things-1080p-stream-int8."
+                        "json, for the bring-up of a changed pool format")
     args = p.parse_args(argv)
+    alone = args.flows or args.int8
     try:
-        device = run(1 if args.flows else args.chips, Sizes(), args.flows)
+        device = run(1 if alone else args.chips, Sizes(), args.flows,
+                     args.int8)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr, flush=True)
         return 1

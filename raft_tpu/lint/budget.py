@@ -375,6 +375,18 @@ def pair_temp_bytes(config, h: int, w: int, b: int) -> Optional[int]:
     return PAIR_TEMP_BYTES_PER_PIXEL * b * h * w
 
 
+def stream_limit(device_key: str) -> int:
+    """The bytes the server's own admission (``serving/admission.py``: the
+    server decides, the analyzer reads what it decided) lets the stream
+    path reach on a chip of ``DEVICE_BUDGETS``: ``STREAM_BOUND_SHARE`` of
+    what its runtime hands out (of the table's figure where that has not
+    been read)."""
+    from ..serving.admission import STREAM_BOUND_SHARE, USABLE_HBM_BYTES
+    usable = USABLE_HBM_BYTES.get(device_key,
+                                  DEVICE_BUDGETS[device_key]["hbm_bytes"])
+    return int(STREAM_BOUND_SHARE * usable)
+
+
 def config_signature(config, sconfig, stream: bool, chaos: bool) -> dict:
     """What the committed-baseline comparison keys on: every knob that
     changes the compile surface or the footprint model."""
@@ -483,6 +495,7 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
 
     peak = resident + peak_transient
     headroom = budget["hbm_bytes"] - peak
+    stream_peak_b = stream_sessions_fit = None
     max_sessions_fit = None
     if stream and session_row_b > 0:
         # resident(S) = params + sum_b (S+1) * row_b; solve the largest S
@@ -491,6 +504,28 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
         free = (budget["hbm_bytes"] - params_b - peak_transient
                 - session_row_b)                       # the scratch rows
         max_sessions_fit = max(0, free // session_row_b)
+        # and with the stream path's fullest moment (stream_peak), whose
+        # reserved region is the larger of the step's temporaries and the
+        # commit's copy of a pool leaf, which grows with the pool
+        from ..serving.admission import (STREAM_BOUND_SHARE, stream_peak,
+                                         stream_temp_bytes)
+        limit = stream_limit(device_kind)
+        stream_peak_b, pool_b, worst = stream_peak(programs, sconfig)
+        leaf_row = worst["commit_copy_row_bytes"]
+        rest = stream_peak_b - pool_b - worst["reserved_bytes"]
+        step = stream_temp_bytes(rconfig, *worst["bucket"],
+                                 max(sconfig.batch_steps)) or 0
+        stream_sessions_fit = max(0, min(
+            (limit - rest - step) // session_row_b,
+            (limit - rest - max(sconfig.batch_steps) * leaf_row)
+            // (session_row_b + leaf_row)) - 1)
+        if stream_peak_b > limit:
+            violations.append(
+                f"the slot pool ({pool_b} B) and the stream programs beside "
+                f"it hold {stream_peak_b} B, over {limit} B "
+                f"({100 * STREAM_BOUND_SHARE:.0f} % of the {device_kind}'s "
+                f"usable memory): at most {stream_sessions_fit} session(s) "
+                f"fit beside them")
         if sconfig.max_sessions > max_sessions_fit:
             violations.append(
                 f"max_sessions={sconfig.max_sessions} does not fit "
@@ -521,6 +556,11 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
             # 6.54-6.58 at 440x1024, batch 32: PERF.md, PR 27)
             "peak_with_pair_temps_bytes": (peak + peak_pair_temp
                                            if peak_pair_temp else None),
+            # the stream path's fullest moment: the pool, a batched step
+            # with its temporaries, the frames staged behind it and the
+            # commit's copy of a pool leaf (stream_footprint)
+            "peak_with_stream_temps_bytes": stream_peak_b,
+            "max_sessions_fit_stream": stream_sessions_fit,
             "hbm_budget_bytes": budget["hbm_bytes"],
             "headroom_bytes": headroom,
             "per_session_bytes": session_row_b or None,

@@ -274,6 +274,7 @@ class InferenceEngine:
     corr_keyblocks = guarded_by("_lock")
     encode_calls = guarded_by("_lock")
     stream_calls = guarded_by("_lock")
+    rows_quantized = guarded_by("_lock")
     weight_version = guarded_by("_lock")
     weight_tag = guarded_by("_lock")
 
@@ -347,6 +348,12 @@ class InferenceEngine:
             config, self.params, self.pool.capacity if stream else 0,
             iters=iters, ragged=self.ragged, stream=stream, mesh=mesh,
             donate=jax.default_backend() != "cpu")
+        if stream:
+            # does the pool fit this chip beside the stream programs?  Asked
+            # of shapes, before anything is compiled or allocated
+            from .admission import admit_stream
+            admit_stream(self.programs, sconfig,
+                         jax.devices()[0].device_kind)
         # budget None: a cold cache miss compiles while holding the lock
         # (deliberate — see _get_executable), which busts any hold budget
         self._lock = watched_lock("InferenceEngine._lock", budget_s=None)
@@ -356,6 +363,8 @@ class InferenceEngine:
         self.encode_calls = 0     # fnet-pass accounting: 1 per encode call,
         self.stream_calls = 0     # 1 per stream step (the acceptance
         self.pair_calls = 0       # criterion's counters), 2 per pair row
+        # rows a commit wrote through the int8 quantiser (quant='int8')
+        self.rows_quantized = 0
         # [visited, possible, tiles, steps, stored, live] of the lookup's
         # band schedule over the pair batches and stream steps run so far
         # (RAFTOutput.corr_keyblocks; the
@@ -892,6 +901,9 @@ class InferenceEngine:
             self.reset_slots(bucket)
             raise
         self.pool.install(bucket, out)
+        if self.config.quant_slots:
+            with self._lock:
+                self.rows_quantized += int(np.count_nonzero(mask))
 
     def commit_row(self, bucket: Tuple[int, int], slot: int, fmap, cnet,
                    seed: np.ndarray) -> None:

@@ -250,6 +250,13 @@ def make_stream_metrics(registry: Registry, store,
             "raft_stream_evictions_total{reason=\"lru\"}), none (every "
             "slot pinned by a session in flight: the session stays cold)",
             labelnames=("result",)),
+        "rows_quantized": registry.counter(
+            "raft_stream_rows_quantized_total",
+            "Slot rows committed through the int8 quantiser (--quant int8; "
+            "0 without it), by the engine's own count of the commits' "
+            "masked-in rows: one an advance served from the batched call, "
+            "one an open, one more a cold restart's re-seat (the kept "
+            "frame's maps) — frames + opens + restarts in a sound window"),
         "degraded": registry.counter(
             "raft_stream_degraded_total",
             "Stream advances whose warm step faulted (engine error or "
@@ -303,6 +310,19 @@ def make_stream_metrics(registry: Registry, store,
                 functools.partial(pool.in_use, (h, w)))
             cap.labels(f"{h}x{w}").set(pool.capacity)
         m["slots_in_use"], m["slot_capacity"] = in_use, cap
+        # what the pool's buffers hold on the device, leaf by leaf, from
+        # the arrays themselves (0 until a bucket's first stream call
+        # installs them)
+        m["pool_bytes"] = pool_bytes = registry.gauge(
+            "raft_stream_pool_bytes",
+            "Device bytes of the slot pool's buffers by leaf, every bucket "
+            "together: vals (the fmap/cnet maps: int8 codes under --quant "
+            "int8), scales (their float32 scales a channel and row; 0 "
+            "without quant), seed (the float32 warm-start seeds)",
+            labelnames=("leaf",))
+        for leaf in ("vals", "scales", "seed"):
+            pool_bytes.labels(leaf).set_fn(
+                lambda leaf=leaf: pool.leaf_bytes()[leaf])
         if getattr(pool, "arena", None) is not None:
             # ragged arena (SERVING.md "Ragged serving"): the buckets all
             # map onto one max-box arena, so per-bucket in_use gauges

@@ -250,6 +250,13 @@ class FlowServer:
             for k in ("steps", "step_seconds", "step_batch",
                       "step_occupancy"):
                 self.metrics[f"stream_{k}"] = stream_metrics[k]
+            # the engine's own counters a stream step moves, and where each
+            # is exported (``_stream_step``)
+            passes = stream_metrics["encoder_passes"]
+            self._stream_counts = (
+                ("encode_calls", passes.labels("encode")),
+                ("stream_calls", passes.labels("stream")),
+                ("rows_quantized", stream_metrics["rows_quantized"]))
         # AOT executable cache (serving/aot_cache.py): keyed by the
         # RESOLVED config (the engine applies the sconfig iters-policy
         # override, so the cache identity must match the warmed keys)
@@ -349,18 +356,18 @@ class FlowServer:
 
     def _stream_step(self, fn, *args, tick: bool = True):
         """One stream step of the batcher, or one phase of one: a device
-        step, and the encoder passes it took by the engine's own call
-        counters (``raft_stream_encoder_passes_total``)."""
-        was = [getattr(self.engine, f"{c}_calls", 0)
-               for c in ("encode", "stream")]
+        step, and what it took by the engine's own counters: the encoder
+        passes (``raft_stream_encoder_passes_total``) and the rows its
+        commits quantised (``raft_stream_rows_quantized_total``)."""
+        counts = self._stream_counts
+        was = [getattr(self.engine, name, 0) for name, _ in counts]
         try:
             return self._device_step("serve/stream", fn, *args, tick=tick)
         finally:
-            for call, before in zip(("encode", "stream"), was):
-                now = getattr(self.engine, f"{call}_calls", 0)
+            for (name, counter), before in zip(counts, was):
+                now = getattr(self.engine, name, 0)
                 if now > before:
-                    self.streams.metrics["encoder_passes"].labels(
-                        call).inc(now - before)
+                    counter.inc(now - before)
 
     def _run_stream(self, req):
         """One solo session step (open, or the no-group fallback)."""
@@ -605,6 +612,9 @@ class FlowServer:
         self._flight_dump("shutdown")
         if self.history is not None:
             self.history.stop()           # final sample + spill close
+        if self.streams is not None:
+            # (after the final sample: the pool's gauges read the buffers)
+            self.streams.pool.release()
         self._trace_window.stop()
         if self._recompile_watch is not None:
             self._recompile_watch.remove()

@@ -58,6 +58,12 @@ from ..telemetry.watchdogs import watched_lock
 RECORD_CAP_FACTOR = 4
 
 
+def _nbytes(a) -> int:
+    """Bytes of a device array, from its shape and dtype (a donated array
+    still has both); 0 for what a stub engine installs in their place."""
+    return int(getattr(a, "nbytes", 0))
+
+
 def make_slot_commit_fn(quant: bool = False):
     """The slot-pool scatter: ``(fmap_buf, cnet_buf, flow_buf, slots [b],
     fmap_rows [b,...], cnet_rows [b,...], seed_rows [b,...], mask [b])
@@ -82,6 +88,8 @@ def make_slot_commit_fn(quant: bool = False):
     """
     import jax.numpy as jnp
 
+    from ..telemetry.trace import stage
+
     # (named: the device trace calls a program by its function,
     # ``jit_slot_commit``, and the stream programs beside it are ``jit_fn``)
     def slot_commit(fmap_buf, cnet_buf, flow_buf, slots, fmap_rows, cnet_rows,
@@ -90,18 +98,19 @@ def make_slot_commit_fn(quant: bool = False):
             keep = mask.reshape((-1,) + (1,) * (rows.ndim - 1))
             return buf.at[slots].set(jnp.where(keep, rows, buf[slots]))
 
-        if quant:
+        def put_q(buf, rows):
+            # each row quantised by its own absmax (the reduction is over a
+            # row's positions, never over the batch), then BOTH leaves under
+            # the one mask: a padding row writes back what it gathered
             from ..models.raft import quantize_rows
-
-            def put_q(buf, rows):
-                vals_buf, scale_buf = buf
+            with stage("quant"):
                 vals, scales = quantize_rows(rows)
-                return (put(vals_buf, vals), put(scale_buf, scales))
+            return (put(buf[0], vals), put(buf[1], scales))
 
-            return (put_q(fmap_buf, fmap_rows), put_q(cnet_buf, cnet_rows),
-                    put(flow_buf, seed_rows))
-        return (put(fmap_buf, fmap_rows), put(cnet_buf, cnet_rows),
-                put(flow_buf, seed_rows))
+        put_maps = put_q if quant else put
+        with stage("raft/stream/commit"):
+            return (put_maps(fmap_buf, fmap_rows),
+                    put_maps(cnet_buf, cnet_rows), put(flow_buf, seed_rows))
     return slot_commit
 
 
@@ -245,6 +254,35 @@ class SlotPool:
         with self._lock:
             self._bucket_locked(bucket)
             self._bufs[self._b(bucket)] = tuple(bufs)
+
+    def release(self) -> None:
+        """Drop the device buffers (a stopped server's: the pool is a third
+        of the chip, and what runs after the server in its process, the
+        benchmark's reference, needs the room).  The free-lists stay; a
+        later stream call would rebuild the buffers zeroed."""
+        with self._lock:
+            for bucket in self._bufs:
+                self._bufs[bucket] = None
+
+    def leaf_bytes(self) -> Dict[str, int]:
+        """Device bytes of the installed buffers as they stand, every bucket
+        together, by leaf: ``vals`` (the fmap and cnet maps: int8 codes under
+        ``quant='int8'``, else the compute dtype), ``scales`` (their float32
+        scales; 0 without quant) and ``seed`` (the float32 warm-start seeds)
+        — read from the arrays' own shapes and dtypes, 0 before the first
+        install (the ``raft_stream_pool_bytes{leaf=}`` gauges; scrape-time
+        callback)."""
+        out = {"vals": 0, "scales": 0, "seed": 0}
+        with self._lock:
+            installed = [b for b in self._bufs.values() if b is not None]
+        for bufs in installed:
+            for buf, leaf in zip(bufs, ("vals", "vals", "seed")):
+                if isinstance(buf, tuple):          # (codes, scales)
+                    out["vals"] += _nbytes(buf[0])
+                    out["scales"] += _nbytes(buf[1])
+                else:
+                    out[leaf] += _nbytes(buf)
+        return out
 
     def seed_row(self, bucket: Tuple[int, int],
                  slot: int) -> Optional[np.ndarray]:

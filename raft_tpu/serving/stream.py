@@ -819,7 +819,14 @@ class StreamCoordinator:
         """The SOLO cold restart from the retained previous frame, the heal
         of a row its group's batched call did not serve (a restart that can
         ride the call is :meth:`_reseat`'s) — pairwise cost, correct flow,
-        and the device to itself.  Session state (slot, last_image) is
+        and the device to itself.  It BYPASSES the pool: the kept frame's
+        maps go from the encoder straight into the solo step, so under
+        ``--quant int8`` this one answer is computed from unquantised maps
+        (every answer of a sound window passes through a slot: an open's
+        ``commit_row``, a warm advance's gather, a re-seated restart's
+        ``commit_row`` and gather; the heal is what a faulted row gets, and
+        what it computed is stored, quantised, by the ``_attach`` below).
+        Session state (slot, last_image) is
         mutated only AFTER the output passes the non-finite sentinel, so
         a faulted attempt leaves the session exactly where it was.  Three
         host stages (``raft.stream.cold.encode`` / ``.step`` / ``.attach``)

@@ -30,6 +30,9 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 CELL, STREAM_CELL = "things-stream-churn", "things-stream-sessions"
+# the cell a later PR appended after this one (PR 45), to every list this
+# one is on but ``slot_io_roofline``
+INT8_CELL = "things-stream-int8-pool"
 CONFIG = "raft-things-1080p-stream-churn"
 MIX = "davis1080p-sessions-churn"
 NEW_METRICS = {
@@ -132,18 +135,20 @@ def _serve_args(config, **replace):
 # ------------------------------------------------------------ the cell's data
 
 def test_the_cell_is_in_the_benchmark_and_only_appended_to_it():
-    """The manifest gains one configuration, one cell and five metrics, each
+    """The manifest gained one configuration, one cell and five metrics, each
     last in its list, and the cell's name at the end of the lists that take
-    it; every file the entries name is the benchmark's own."""
+    it (PR 45's configuration and cell come after them); every file the
+    entries name is the benchmark's own."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     assert [c["name"] for c in bench["configs"]].count(CONFIG) == 1
-    assert bench["configs"][-1]["name"] == CONFIG
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["run_seconds"] == 40 and len(bench["workloads"]) == 5
+    assert bench["configs"][4]["name"] == CONFIG
+    assert [w["name"] for w in bench["workloads"][4:]] == [CELL, INT8_CELL]
+    assert bench["run_seconds"] == 40 and len(bench["workloads"]) == 6
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", ()):
-            assert m["workloads"].index(CELL) == len(m["workloads"]) - 1
+            assert m["workloads"][m["workloads"].index(CELL) + 1:] in (
+                [], [INT8_CELL])
     for rel in ("configs/" + CONFIG + ".json", "traffic/" + MIX + ".json",
                 "workloads/" + CELL + ".json", "drivers/sessions_churn.py",
                 "references/warm_restart.py"):
@@ -157,8 +162,8 @@ def test_the_cell_is_in_the_benchmark_and_only_appended_to_it():
 def test_the_cell_is_the_one_the_issue_names(cell, what, want):
     assert cell["entry"][what] == want
     assert len(cell["entry"]["why"]) <= 200
-    assert cell["bench"]["workloads"][-1] is cell["entry"]
-    assert cell["bench"]["configs"][-1] is cell["cfg_entry"]
+    assert cell["bench"]["workloads"][4] is cell["entry"]
+    assert cell["bench"]["configs"][4] is cell["cfg_entry"]
 
 
 @pytest.mark.parametrize("key,want", [
@@ -228,15 +233,17 @@ def test_listed_gives_the_cell_its_metrics(cell, run, metric):
     entry = run.find(bench["end_to_end"] + bench["per_layer"], metric,
                      "metric")
     assert run.listed(entry, CELL, reporting)
+    later = [] if metric == "slot_io_roofline" else [INT8_CELL]
     if metric in NEW_METRICS:
-        assert entry["workloads"] == [CELL]
+        assert entry["workloads"] == [CELL] + later
         assert entry["layer"] == NEW_METRICS[metric]
         assert entry["moves"] == "pairs_per_s"
         base = os.path.join(BENCH, "layer_metrics", metric)
         assert os.path.exists(base + ".json") and os.path.exists(base + ".py")
     elif "workloads" in entry:
         # appended, right after the cell it shares the stream path with
-        assert entry["workloads"][-2:] == [STREAM_CELL, CELL]
+        assert entry["workloads"][-2 - len(later):] == [STREAM_CELL,
+                                                        CELL] + later
 
 
 @pytest.mark.parametrize("metric", NOT_LISTED)
@@ -249,8 +256,9 @@ def test_a_metric_whose_reader_or_pin_does_not_hold_here_is_not_given(
 
 def test_the_new_metrics_are_the_manifests_last(cell):
     """PR 41's five, then PR 42's one (then PR 43's ``corr_lane_fill``, for
-    all five cells): each appended, none moved."""
-    assert [m["name"] for m in cell["bench"]["per_layer"][-7:]] == [
+    every cell, and PR 45's five of the int8 pool): each appended, none
+    moved."""
+    assert [m["name"] for m in cell["bench"]["per_layer"][-12:-5]] == [
         "stream_cold_ms", "stream_cold_wait_ms", "stream_cold_device_share",
         "stream_lru_demotions_per_advance", "stream_restart_cause_share",
         "stream_restart_batched_share", "corr_lane_fill"]

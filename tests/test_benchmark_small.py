@@ -315,13 +315,15 @@ def test_corr_lane_fill_reads_the_key_positions(bench_modules, program,
 
 
 def test_corr_lane_fill_is_listed_for_all_five_cells(cell):
-    entry = cell["bench"]["per_layer"][-1]
+    """(And for the sixth, which PR 45 appended; its five metrics come after
+    this one.)"""
+    entry = cell["bench"]["per_layer"][-6]
     assert entry == {
         "name": "corr_lane_fill", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
         "moves": "pairs_per_s",
         "workloads": [w["name"] for w in cell["bench"]["workloads"]]}
-    assert len(entry["workloads"]) == 5
+    assert len(entry["workloads"]) == 6
 
 
 @pytest.mark.parametrize("metric", STAGE_METRICS)

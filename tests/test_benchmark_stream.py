@@ -29,6 +29,7 @@ MIX = "davis1080p-sessions"
 # PR 41's cell runs the same service with more live sessions than slots and
 # is appended after this one wherever a metric's reader reads right there
 CHURN = "things-stream-churn"
+INT8 = "things-stream-int8-pool"         # PR 45's, after the churn cell
 CHURN_LEFT_OUT = ("gru_roofline", "corr_window_roofline",
                   "stream_staged_ahead_share")
 NEW_METRICS = {
@@ -207,7 +208,8 @@ def test_listed_gives_the_cell_its_metrics(cell, run, metric):
     entry = run.find(bench["end_to_end"] + bench["per_layer"], metric,
                      "metric")
     assert run.listed(entry, CELL, reporting)
-    later = [] if metric in CHURN_LEFT_OUT else [CHURN]
+    later = [] if metric in CHURN_LEFT_OUT else (
+        [CHURN] if metric == "slot_io_roofline" else [CHURN, INT8])
     if metric in NEW_METRICS:
         assert entry["workloads"] == [CELL] + later
         assert entry["layer"] == NEW_METRICS[metric]

@@ -273,3 +273,29 @@ def test_flows_phase_rehearsed_on_the_cpu(capsys):
     assert real.flow_programs == chip_smoke.FLOW_PROGRAMS
     assert not (real.flow_hw or real.flow_batch or real.flow_iters)
     assert [b for *_, b in real.flow_programs] == [32, 8, 8, 8, 1]
+
+
+def test_int8_phase_rehearsed_on_the_cpu(capsys):
+    """``phase_int8`` through its own code at a tiny size (64x96, FOUR
+    slots, float32): the int8 configuration's serve arguments through the
+    real server, an open and an advance, four more opens that take the
+    session's slot, the cold restart and the warm advance after it; the
+    pool's bytes are its leaves', every committed row was quantised."""
+    import json
+
+    import chip_smoke
+    sz = chip_smoke.Sizes(interpret=True, int8_hw=(64, 96), int8_slots=4)
+    chip_smoke.phase_int8(chip_smoke.CompileMeter(), sz)
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["phase"] == "int8" and rec["ok"] is True
+    assert "--quant int8" in rec["argv"] and "--no-warmup" in rec["argv"]
+    rows, q = 5, 8 * 12
+    assert rec["pool_bytes"] == {"vals": rows * q * 512,
+                                 "scales": rows * 4 * 512,
+                                 "seed": rows * q * 8}
+    assert (rec["rows_quantized"], rec["frames"], rec["opens"],
+            rec["restarts_batched"]) == (9, 3, 5, 1)
+    assert all(m > 0 for m in rec["mean_abs_flow"])
+    # the real sizes are the configuration's
+    real = chip_smoke.Sizes()
+    assert (real.int8_hw, real.int8_slots) == ((1080, 1920), 256)

@@ -662,3 +662,84 @@ def test_slot_io_metrics_find_the_gather_and_the_commit_program(
     assert [n for n in insts if re.search(r"^gru\.", n)]
     assert re.search(r"^HloModule jit_slot_commit\b", commit.as_text())
     assert re.search(r"^HloModule jit_fn\b", step.as_text())
+
+
+# ------------------------------------ the int8 slot pool's programs (PR 45)
+
+@pytest.fixture(scope="module")
+def int8_pool_programs(one_chip):
+    """The commit programs of ``raft-things-1080p-stream-int8`` (bfloat16
+    rows in, int8 slots) at 1080x1920 over a pool of FIVE rows (four slots
+    and the scratch row; the deployment holds 257): the engine's own table
+    of kinds with donation on, compiled for the described chip.  A second or
+    two each."""
+    from raft_tpu.config import RAFTConfig
+    from raft_tpu.models import init_raft
+    from raft_tpu.serving.engine import Programs
+
+    config = RAFTConfig.full(iters=12, compute_dtype="bfloat16",
+                             corr_impl="pallas", gru_impl="pallas",
+                             quant="int8")
+    params = jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config))
+    programs = Programs(config, params, 4, iters=12, donate=True)
+
+    def compiled(kind, b):
+        prog = programs.program((kind, 1080, 1920, b, "fixed"))
+        specs = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), prog.specs)
+        return prog.fn.lower(*specs).compile()
+
+    return programs, {(k, b): compiled(k, b) for k, b in (
+        ("scommit", 8), ("scommit", 1), ("spoison", 1))}
+
+
+@pytest.mark.parametrize("kind,b", [("scommit", 8), ("scommit", 1),
+                                    ("spoison", 1)])
+def test_int8_scatter_programs_update_the_pool_in_place_on_the_chip(
+        int8_pool_programs, kind, b):
+    """Every leaf of the pool that goes in is aliased to the output that
+    takes its place (``alias_size_in_bytes`` is the outputs' size to the
+    runtime's alignment): at 4.33 GB a second copy of the pool would not fit
+    beside the batched step."""
+    from raft_tpu.lint.budget import tree_bytes
+    programs, compiled = int8_pool_programs
+    ma = compiled[(kind, b)].memory_analysis()
+    pool = programs.slot_specs(1080, 1920)
+    want = tree_bytes(pool if kind == "scommit" else pool[0])
+    # (a [5, 256] float32 leaf is laid out in tiles of eight rows)
+    assert want <= ma.alias_size_in_bytes <= want + 256 * 1024
+    assert ma.output_size_in_bytes - ma.alias_size_in_bytes < 64 * 1024
+
+
+def test_the_batchs_int8_commit_copies_a_pool_leaf_and_the_one_row_commit_does_not(
+        int8_pool_programs):
+    """The finding ``serving/admission.stream_footprint`` prices (PR 45): the
+    chip's compiler turns the batch's row gather (the masked write-back reads
+    ``buf[slots]``) into slices of the WHOLE leaf by 128-channel halves, so
+    the program's temporaries hold a copy of a pool leaf; the one-row commit
+    (an open, a restart's re-seat) is an in-place ``dynamic-update-slice``.
+    A commit that moves 8 rows and not the pool makes the first assertion
+    fail: that is the next ``perf_opt`` PR's, and this test and the
+    footprint's leaf copy are then its to change."""
+    from raft_tpu.lint.budget import bytes_of
+    programs, compiled = int8_pool_programs
+    leaf = bytes_of(programs.slot_specs(1080, 1920)[0][0])
+    assert leaf == 5 * 135 * 240 * 256
+    batch = compiled[("scommit", 8)]
+    assert batch.memory_analysis().temp_size_in_bytes >= leaf
+    assert "mini-gather-slice" in batch.as_text()
+    one = compiled[("scommit", 1)]
+    assert one.memory_analysis().temp_size_in_bytes < leaf // 16
+    assert "dynamic-update-slice" in one.as_text()
+
+
+def test_int8_commit_on_the_chip_files_its_quantiser_under_its_scope(
+        int8_pool_programs):
+    from raft_tpu.telemetry.trace import instruction_stages
+    _, compiled = int8_pool_programs
+    for key in (("scommit", 8), ("scommit", 1)):
+        insts = instruction_stages(compiled[key].as_text())
+        stages = {rec["stage"] for rec in insts.values()}
+        assert "raft/stream/commit/quant" in stages, key
+        assert all(st.startswith("raft/stream/commit")
+                   for st in stages if st), key
