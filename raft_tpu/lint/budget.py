@@ -504,21 +504,13 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
         free = (budget["hbm_bytes"] - params_b - peak_transient
                 - session_row_b)                       # the scratch rows
         max_sessions_fit = max(0, free // session_row_b)
-        # and with the stream path's fullest moment (stream_peak), whose
-        # reserved region is the larger of the step's temporaries and the
-        # commit's copy of a pool leaf, which grows with the pool
-        from ..serving.admission import (STREAM_BOUND_SHARE, stream_peak,
-                                         stream_temp_bytes)
+        # and with the stream path's fullest moment (stream_peak): beside
+        # the pool everything it holds is the same for every capacity
+        from ..serving.admission import STREAM_BOUND_SHARE, stream_peak
         limit = stream_limit(device_kind)
-        stream_peak_b, pool_b, worst = stream_peak(programs, sconfig)
-        leaf_row = worst["commit_copy_row_bytes"]
-        rest = stream_peak_b - pool_b - worst["reserved_bytes"]
-        step = stream_temp_bytes(rconfig, *worst["bucket"],
-                                 max(sconfig.batch_steps)) or 0
-        stream_sessions_fit = max(0, min(
-            (limit - rest - step) // session_row_b,
-            (limit - rest - max(sconfig.batch_steps) * leaf_row)
-            // (session_row_b + leaf_row)) - 1)
+        stream_peak_b, pool_b, _ = stream_peak(programs, sconfig)
+        stream_sessions_fit = max(
+            0, (limit - (stream_peak_b - pool_b)) // session_row_b - 1)
         if stream_peak_b > limit:
             violations.append(
                 f"the slot pool ({pool_b} B) and the stream programs beside "
@@ -558,7 +550,7 @@ def analyze(config, sconfig, device_kind: Optional[str] = None,
                                            if peak_pair_temp else None),
             # the stream path's fullest moment: the pool, a batched step
             # with its temporaries, the frames staged behind it and the
-            # commit's copy of a pool leaf (stream_footprint)
+            # outputs being committed (stream_footprint)
             "peak_with_stream_temps_bytes": stream_peak_b,
             "max_sessions_fit_stream": stream_sessions_fit,
             "hbm_budget_bytes": budget["hbm_bytes"],

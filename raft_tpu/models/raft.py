@@ -584,6 +584,26 @@ def dequantize_rows(vals: jax.Array, scales: jax.Array) -> jax.Array:
     return vals.astype(jnp.float32) * scales[..., None, None, :]
 
 
+def slot_row(buf: jax.Array, slot) -> jax.Array:
+    """Row ``slot`` of a slot-pool leaf ``[cap+1, ...]``, as ``[1, ...]``:
+    ONE ``dynamic_slice``.  ``slot`` lies in ``[0, cap]`` by construction
+    (a session's slot or the scratch row), so nothing normalises it: an index
+    outside the leaf is CLAMPED to its nearest row, where ``buf[slot]``
+    wrapped a negative one."""
+    return jax.lax.dynamic_slice_in_dim(buf, slot, 1, axis=0,
+                                        allow_negative_indices=False)
+
+
+def gather_slot_rows(buf: jax.Array, slots: jax.Array) -> jax.Array:
+    """``buf[slots]`` a row at a time: ``b`` one-row slices (``b`` is static:
+    the engine compiles a width) joined in order.  The program then moves
+    ``b`` rows whatever the pool holds: the chip's compiler turns the general
+    gather into slices of the WHOLE leaf by 128-channel halves, and
+    cross-program-prefetches a leaf that fits (PERF.md §6, PR 46)."""
+    return jnp.concatenate([slot_row(buf, slots[i])
+                            for i in range(slots.shape[0])])
+
+
 @contract(image="*[B,H,W,3]")
 def encode_frame(params: Dict[str, dict], image: jax.Array,
                  config: RAFTConfig) -> Tuple[jax.Array, jax.Array]:
@@ -732,7 +752,8 @@ def make_stream_batch_step_fn(config: RAFTConfig,
     each (LLM-continuous-batching applied to RAFT's cached maps — the
     Ragged-Paged-Attention recipe from PAPERS.md): each row gathers its
     session's cached previous-frame maps and warm-start seed from its
-    batch slot (``buf[slots]``), the current frames encode at batch
+    batch slot (:func:`gather_slot_rows`: ``b`` one-row slices, ``slots`` in
+    ``[0, cap]``), the current frames encode at batch
     width ``b`` (one fnet pass per frame, exactly as the solo step), and
     the recurrent core runs once for the whole batch.  Padding rows
     carry ``active=False``: they point at the pool's scratch slot, start
@@ -760,17 +781,17 @@ def make_stream_batch_step_fn(config: RAFTConfig,
                 # quant='int8': fmap_buf/cnet_buf arrive as (int8 vals,
                 # per-channel f32 scales) 2-leaf pytrees — dequant on
                 # gather; the flow seed buffer stays f32
-                fmap_q = (fmap_buf[0][slots], fmap_buf[1][slots])
-                cnet_q = (cnet_buf[0][slots], cnet_buf[1][slots])
+                fmap_q = [gather_slot_rows(leaf, slots) for leaf in fmap_buf]
+                cnet_q = [gather_slot_rows(leaf, slots) for leaf in cnet_buf]
                 with stage("dequant"):
                     fmap_prev = dequantize_rows(*fmap_q).astype(
                         fmap_cur.dtype)
                     cnet_prev = dequantize_rows(*cnet_q).astype(
                         cnet_cur.dtype)
             else:
-                fmap_prev = fmap_buf[slots]
-                cnet_prev = cnet_buf[slots]
-            flow_init = flow_buf[slots]
+                fmap_prev = gather_slot_rows(fmap_buf, slots)
+                cnet_prev = gather_slot_rows(cnet_buf, slots)
+            flow_init = gather_slot_rows(flow_buf, slots)
         out = forward_from_features(params, fmap_prev, fmap_cur, cnet_prev,
                                     config, iters=iters,
                                     flow_init=flow_init, active=active,

@@ -74,14 +74,14 @@ def stream_footprint(programs, h: int, w: int, b: int) -> dict:
     the runtime RESERVES one region for whichever program runs, as large as
     the largest program's temporaries (``peak_bytes_reserved`` read
     6,374,899,712 B on the chip, the batched step's; my chip run, PR 45):
-    the step's where :func:`stream_temp_bytes` prices them, or the commit's,
-    which are a COPY OF A POOL LEAF: the chip's compiler turns a row gather
-    (``buf[slots]``, which the masked write-back reads) into slices of the
-    whole leaf by 128-channel halves and a loop over the rows (sandbox
-    compiles for a described v5e, PR 45: ``temp_size_in_bytes`` 2,199,671,296
-    beside a 2,131,660,800 B leaf of 257 int8 rows, 4,463,500,800 beside
-    4,263,321,600 for 257 bfloat16 rows), priced as the largest leaf and
-    ``b`` rows of it."""
+    the step's where :func:`stream_temp_bytes` prices them, and never under
+    the ``b`` rows of the widest leaf that the step gathers and the commit
+    quantises.  The pool's size does not enter: both programs address the
+    pool a row at a time (PR 46; sandbox compiles for a described v5e:
+    ``scommit-1080-1920-8`` holds 580,608 B of temporaries beside 33
+    bfloat16 rows and 67,226,112 B, 8 rows of codes, beside 257 int8 rows,
+    where the general scatter held a copy of a leaf, 747,835,392 and
+    2,199,671,296 B)."""
     import jax
 
     pool = programs.slot_specs(h, w)
@@ -89,13 +89,11 @@ def stream_footprint(programs, h: int, w: int, b: int) -> dict:
                + b * (h * w + (h // 8) * (w // 8)) * 2 * 4)
     frames = b * h * w * 3 * 4
     widest = max(jax.tree.leaves(pool), key=bytes_of)
-    leaf_row = bytes_of(widest) // widest.shape[0]
     pool_b = tree_bytes(pool)
     heap = tree_bytes(programs.params) + pool_b + 2 * frames + 2 * outputs
     reserved = max(stream_temp_bytes(programs.config, h, w, b) or 0,
-                   bytes_of(widest) + b * leaf_row)
+                   b * (bytes_of(widest) // widest.shape[0]))
     return {"bucket": [h, w], "pool_bytes": pool_b,
-            "commit_copy_row_bytes": leaf_row,
             "reserved_bytes": reserved, "peak_bytes": heap + reserved}
 
 

@@ -10,8 +10,10 @@ A *session* is one client's video stream.  Its state has two tiers:
   exit early under a ``converge`` policy — and, because every session's
   maps live *in batch slots* of one buffer, what lets the batcher
   advance many sessions in ONE device call (the continuous-batching
-  stream step, models/raft.make_stream_batch_step_fn): gather rows by
-  slot index in, scatter updated rows back.
+  stream step, models/raft.make_stream_batch_step_fn): a batch of 8 reads
+  its rows as 8 one-row slices of each leaf and the commit writes 8 rows
+  back in place, in row order — the programs move the batch's rows, never
+  the pool (:func:`make_slot_commit_fn`).
 * **host tier** — the previous frame's pixels plus bookkeeping.  Cheap,
   and exactly what a cold two-encoder restart needs.
 
@@ -65,29 +67,44 @@ def _nbytes(a) -> int:
 
 
 def make_slot_commit_fn(quant: bool = False):
-    """The slot-pool scatter: ``(fmap_buf, cnet_buf, flow_buf, slots [b],
+    """The slot-pool commit: ``(fmap_buf, cnet_buf, flow_buf, slots [b],
     fmap_rows [b,...], cnet_rows [b,...], seed_rows [b,...], mask [b])
     -> (fmap_buf, cnet_buf, flow_buf)`` — rows with ``mask=True`` replace
     their slot, everything else (padding rows aimed at the scratch slot,
     rows the non-finite sentinel rejected) writes its OLD value back.
 
-    Scatter-duplicate discipline: real rows carry unique slot indices
-    (one frame in flight per session), and every masked row writes the
-    value it gathered — so duplicate indices (padding rows all share the
-    scratch slot) always write identical data and the scatter is
-    deterministic.  The serving engine compiles this per (bucket, width)
-    with the buffers DONATED (off-CPU), so a commit is an in-place row
-    update of the pool, not a buffer copy.
+    A ROW at a time (``b`` is static: the engine compiles a width): ``b``
+    one-row ``dynamic_update_slice``s into the leaf, in row order, row ``i``
+    writing ``where(mask[i], new_i, old_i)`` with ``old_i`` that row's own
+    one-row slice (models/raft.slot_row), so the program moves ``b`` rows
+    whatever the pool holds (the general ``.at[slots].set`` over
+    ``buf[slots]`` made the chip's compiler copy a whole leaf: PERF.md §6,
+    PR 46).  ``slots`` lie in ``[0, capacity]`` by construction, so nothing
+    normalises them: a slice CLAMPS an index outside the leaf to its nearest
+    row, where ``buf[slots]`` wrapped a negative one and the scatter dropped
+    a row past the end.
+
+    Duplicate discipline: real rows carry unique slot indices (one frame
+    in flight per session), and every masked row writes the value its slot
+    holds — so duplicate indices (padding rows all share the scratch slot)
+    always write identical data and the order of the writes does not show.
+    A masked row is NOT aimed at the scratch slot instead: a rejected row's
+    NaNs would then sit in the row every padding row reads.  The serving
+    engine compiles this per (bucket, width) with the buffers DONATED
+    (off-CPU), so a commit is an in-place row update of the pool, not a
+    buffer copy.
 
     With ``quant=True`` (``RAFTConfig.quant='int8'``) the fmap/cnet
     buffers arrive as ``(int8 vals, per-channel f32 scales)`` 2-leaf
-    pytrees; the incoming f32 rows are quantized ON SCATTER
+    pytrees; the incoming f32 rows are quantized ON COMMIT
     (models/raft.quantize_rows) and both leaves are masked-written.  The
     flow seed buffer stays f32.  Call-site signatures are unchanged —
     jit handles the pytree args.
     """
+    import jax
     import jax.numpy as jnp
 
+    from ..models.raft import quantize_rows, slot_row
     from ..telemetry.trace import stage
 
     # (named: the device trace calls a program by its function,
@@ -95,14 +112,18 @@ def make_slot_commit_fn(quant: bool = False):
     def slot_commit(fmap_buf, cnet_buf, flow_buf, slots, fmap_rows, cnet_rows,
                     seed_rows, mask):
         def put(buf, rows):
-            keep = mask.reshape((-1,) + (1,) * (rows.ndim - 1))
-            return buf.at[slots].set(jnp.where(keep, rows, buf[slots]))
+            for i in range(rows.shape[0]):
+                row = jnp.where(mask[i], rows[i:i + 1],
+                                slot_row(buf, slots[i]))
+                buf = jax.lax.dynamic_update_slice_in_dim(
+                    buf, row.astype(buf.dtype), slots[i], axis=0,
+                    allow_negative_indices=False)
+            return buf
 
         def put_q(buf, rows):
             # each row quantised by its own absmax (the reduction is over a
             # row's positions, never over the batch), then BOTH leaves under
-            # the one mask: a padding row writes back what it gathered
-            from ..models.raft import quantize_rows
+            # the one mask: a padding row writes back what its slot holds
             with stage("quant"):
                 vals, scales = quantize_rows(rows)
             return (put(buf[0], vals), put(buf[1], scales))

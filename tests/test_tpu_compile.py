@@ -580,46 +580,47 @@ def test_pair_programs_compile_at_every_configurations_shape(
         assert key_lanes[level] in text, (name, level, key_lanes[level])
 
 
-@pytest.fixture(scope="module")
-def served_stream_programs(one_chip):
-    """What ``things-stream-sessions`` runs a batched advance with, as
-    ``benchmark/configs/raft-things-1080p-stream.json`` serves it: the stream
-    batch program at 8 x 1080x1920 over a pool of 32 + 1 slots, key-block
-    counts beside its outputs, and the slot commit at the same width with
-    the pool donated.  About three quarters of a minute."""
+def _stream_programs(one_chip, name: str, kinds):
+    """``kinds`` of the stream configuration ``benchmark/configs/<name>.json``
+    at 1080x1920 as its ``serve_args`` serve them: the engine's own table of
+    kinds (key-block counts beside the step's outputs, the pool donated into
+    the commit) over a pool of ``--max-sessions`` + 1 rows, the kernels
+    really in the step.  About 25 s for ``sbatch``, a second a commit."""
     import json
 
     from raft_tpu import cli
     from raft_tpu.models import init_raft
-    from raft_tpu.models.raft import make_stream_batch_step_fn
-    from raft_tpu.serving.session import make_slot_commit_fn
+    from raft_tpu.serving.engine import Programs
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(repo, "benchmark", "configs",
-                           "raft-things-1080p-stream.json")) as f:
+    with open(os.path.join(repo, "benchmark", "configs", name + ".json")) as f:
         serve_args = [str(a) for a in json.load(f)["serve_args"]]
     args = cli.parse_args(["-m", "serve"] + serve_args)
     config = cli._make_config(args)
-    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    params = jax.tree.map(
-        lambda a: s(a.shape, a.dtype),
-        jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config)))
-    b, rows = args.max_batch, args.max_sessions + 1
-    maps = s((rows,) + HD + (256,), jnp.bfloat16)
-    seeds = s((rows,) + HD + (2,), jnp.float32)
-    new_maps = s((b,) + HD + (256,), jnp.bfloat16)
-    new_seeds = s((b,) + HD + (2,), jnp.float32)
-    slots, mask = s((b,), jnp.int32), s((b,), jnp.bool_)
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(jax, "default_backend", lambda: "tpu")
-        step = jax.jit(make_stream_batch_step_fn(
-            config, iters=args.iters, keyblocks=True)).lower(
-                params, s((b, 1080, 1920, 3), jnp.float32), maps, maps,
-                seeds, slots, mask).compile()
-        commit = jax.jit(make_slot_commit_fn(), donate_argnums=(0, 1, 2)
-                         ).lower(maps, maps, seeds, slots, new_maps,
-                                 new_maps, new_seeds, mask).compile()
-    return step, commit
+    params = jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config))
+    programs = Programs(config, params, args.max_sessions, iters=args.iters,
+                        donate=True)
+
+    def compiled(kind, b):
+        prog = programs.program((kind, 1080, 1920, b, "fixed"))
+        specs = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), prog.specs)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            return prog.fn.lower(*specs).compile()
+
+    return programs, {key: compiled(*key) for key in kinds}
+
+
+@pytest.fixture(scope="module")
+def served_stream_programs(one_chip):
+    """What ``things-stream-sessions`` runs a batched advance with, as
+    ``benchmark/configs/raft-things-1080p-stream.json`` serves it: the stream
+    batch program at 8 x 1080x1920 over a pool of 32 + 1 bfloat16 slots and
+    the slot commit at the same width."""
+    _, compiled = _stream_programs(one_chip, "raft-things-1080p-stream",
+                                   (("sbatch", 8), ("scommit", 8)))
+    return compiled[("sbatch", 8)], compiled[("scommit", 8)]
 
 
 def test_stream_batch_program_fits_a_v5e_beside_its_pool(
@@ -643,17 +644,23 @@ def test_slot_io_metrics_find_the_gather_and_the_commit_program(
         served_stream_programs):
     """What ``benchmark/stream_metrics.py::slot_io_ms`` joins on: the stream
     batch program's own instructions (the map's ``loop`` 0) under
-    ``raft/stream/gather`` hold the compiler's row loops, none of them
-    inside the update loop; and the trace will call the commit program
-    ``jit_slot_commit``, not one more ``jit_fn``."""
+    ``raft/stream/gather`` hold the rows' slices and what joins them (the
+    compiler's ``dynamic-slice`` fusions, the copies and the
+    ``concatenate``s: the rows are MATERIALISED under the scope, 8 of them a
+    leaf), none of them inside the update loop; and the trace will call the
+    commit program ``jit_slot_commit``, not one more ``jit_fn``."""
     from raft_tpu.telemetry.trace import instruction_stages
     step, commit = served_stream_programs
     insts = instruction_stages(step.as_text())
     gather = {n: rec for n, rec in insts.items()
               if re.search(r"(^|/)stream/gather(/|$)", rec["stage"] or "")}
-    own = [n for n, rec in gather.items() if rec["loop"] == 0]
-    assert own and any(n.startswith(("while", "fusion")) for n in own), own
-    assert any("135,240" in gather[n]["text"] for n in own)
+    assert gather and all(rec["loop"] == 0 for rec in gather.values())
+    slices = [n for n in gather if "dynamic-slice" in n]
+    assert slices and not [n for n in gather if n.startswith("while")], gather
+    # each leaf's 8 rows are written under the scope, whole
+    for leaf in ("bf16[8,135,240,256]", "f32[8,135,240,2]"):
+        assert [n for n, rec in gather.items()
+                if f" = {leaf}" in rec["text"]], (leaf, sorted(gather))
     # the lookup's launches are still found by their names, in the loop
     launches = [n for n, rec in insts.items() if re.search(r"^corr_lookup\.",
                 n) and " custom-call(" in rec["text"]]
@@ -668,29 +675,13 @@ def test_slot_io_metrics_find_the_gather_and_the_commit_program(
 
 @pytest.fixture(scope="module")
 def int8_pool_programs(one_chip):
-    """The commit programs of ``raft-things-1080p-stream-int8`` (bfloat16
-    rows in, int8 slots) at 1080x1920 over a pool of FIVE rows (four slots
-    and the scratch row; the deployment holds 257): the engine's own table
-    of kinds with donation on, compiled for the described chip.  A second or
-    two each."""
-    from raft_tpu.config import RAFTConfig
-    from raft_tpu.models import init_raft
-    from raft_tpu.serving.engine import Programs
-
-    config = RAFTConfig.full(iters=12, compute_dtype="bfloat16",
-                             corr_impl="pallas", gru_impl="pallas",
-                             quant="int8")
-    params = jax.eval_shape(lambda: init_raft(jax.random.PRNGKey(0), config))
-    programs = Programs(config, params, 4, iters=12, donate=True)
-
-    def compiled(kind, b):
-        prog = programs.program((kind, 1080, 1920, b, "fixed"))
-        specs = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), prog.specs)
-        return prog.fn.lower(*specs).compile()
-
-    return programs, {(k, b): compiled(k, b) for k, b in (
-        ("scommit", 8), ("scommit", 1), ("spoison", 1))}
+    """The programs of ``raft-things-1080p-stream-int8`` (bfloat16 rows in,
+    int8 slots) at 1080x1920 over its pool of 256 slots and the scratch row,
+    as ``things-stream-int8-pool`` runs them: the batched step, the commits
+    and the chaos arm's poison."""
+    return _stream_programs(
+        one_chip, "raft-things-1080p-stream-int8",
+        (("sbatch", 8), ("scommit", 8), ("scommit", 1), ("spoison", 1)))
 
 
 @pytest.mark.parametrize("kind,b", [("scommit", 8), ("scommit", 1),
@@ -706,31 +697,117 @@ def test_int8_scatter_programs_update_the_pool_in_place_on_the_chip(
     ma = compiled[(kind, b)].memory_analysis()
     pool = programs.slot_specs(1080, 1920)
     want = tree_bytes(pool if kind == "scommit" else pool[0])
-    # (a [5, 256] float32 leaf is laid out in tiles of eight rows)
-    assert want <= ma.alias_size_in_bytes <= want + 256 * 1024
+    # (a seed row's [240, 2] is laid out in tiles of 128 lanes, a [257, 256]
+    # float32 leaf in tiles of eight rows: 0.1 % over the shapes' bytes)
+    assert want <= ma.alias_size_in_bytes <= want + want // 500
     assert ma.output_size_in_bytes - ma.alias_size_in_bytes < 64 * 1024
 
 
-def test_the_batchs_int8_commit_copies_a_pool_leaf_and_the_one_row_commit_does_not(
-        int8_pool_programs):
-    """The finding ``serving/admission.stream_footprint`` prices (PR 45): the
-    chip's compiler turns the batch's row gather (the masked write-back reads
-    ``buf[slots]``) into slices of the WHOLE leaf by 128-channel halves, so
-    the program's temporaries hold a copy of a pool leaf; the one-row commit
-    (an open, a restart's re-seat) is an in-place ``dynamic-update-slice``.
-    A commit that moves 8 rows and not the pool makes the first assertion
-    fail: that is the next ``perf_opt`` PR's, and this test and the
-    footprint's leaf copy are then its to change."""
-    from raft_tpu.lint.budget import bytes_of
+@pytest.mark.parametrize("kind,b", [("scommit", 8), ("scommit", 1)])
+def test_int8_commits_move_their_rows_and_not_the_pool(int8_pool_programs,
+                                                       kind, b):
+    """A commit is ``b`` one-row ``dynamic-update-slice``s into the donated
+    leaf (PR 46): its temporaries are the batch's quantised rows (8 rows of
+    codes, 66 MB, beside a leaf of 2.13 GB), nothing slices a whole leaf
+    (PR 45 pinned the general scatter's ``mini-gather-slice`` and its copy of
+    a leaf here), and the pool is still updated in place."""
+    from raft_tpu.lint.budget import bytes_of, tree_bytes
     programs, compiled = int8_pool_programs
-    leaf = bytes_of(programs.slot_specs(1080, 1920)[0][0])
-    assert leaf == 5 * 135 * 240 * 256
-    batch = compiled[("scommit", 8)]
-    assert batch.memory_analysis().temp_size_in_bytes >= leaf
-    assert "mini-gather-slice" in batch.as_text()
-    one = compiled[("scommit", 1)]
-    assert one.memory_analysis().temp_size_in_bytes < leaf // 16
-    assert "dynamic-update-slice" in one.as_text()
+    pool = programs.slot_specs(1080, 1920)
+    leaf = bytes_of(pool[0][0])
+    assert leaf == 257 * 135 * 240 * 256
+    ma, text = compiled[(kind, b)].memory_analysis(), compiled[
+        (kind, b)].as_text()
+    assert ma.temp_size_in_bytes < leaf // 16
+    assert "dynamic-update-slice" in text
+    assert "mini-gather-slice" not in text
+    assert not re.search(r"\[257,135,240,128\]", text)
+    assert ma.alias_size_in_bytes >= tree_bytes(pool)
+
+
+def _configs(text: str):
+    """name -> (result shape, op_name, backend_config) of every instruction
+    of a compiled text that carries the TPU compiler's ``backend_config``."""
+    import json
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) ", line)
+        if not m or "backend_config={" not in line:
+            continue
+        try:
+            cfg, _ = json.JSONDecoder().raw_decode(
+                line[line.index("backend_config=") + len("backend_config="):])
+        except ValueError:
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        out[m.group(1)] = (m.group(2), op.group(1) if op else "", cfg)
+    return out
+
+
+def _encoder_windows(text: str) -> list:
+    """The encoders' windowed instructions in program order: (op_name,
+    result shape, the window the compiler chose, its estimate)."""
+    keys = ("kernel_window_bounds", "output_window_bounds",
+            "input_window_bounds", "iteration_bounds", "estimated_cycles")
+    return [(op, shape.split("{")[0]) + tuple(
+        str(cfg["window_config"].get(k)) for k in keys)
+        for shape, op, cfg in _configs(text).values()
+        if re.search(r"/raft/(fnet|cnet)/", op) and "window_config" in cfg]
+
+
+@pytest.fixture(scope="module")
+def stream_batch_texts(served_stream_programs, int8_pool_programs):
+    """The batched step's compiled text beside 33 bfloat16 rows (sessions,
+    churn) and beside 257 int8 rows (the int8 cell)."""
+    return {33: served_stream_programs[0].as_text(),
+            257: int8_pool_programs[1][("sbatch", 8)].as_text()}
+
+
+@pytest.mark.parametrize("rows", [33, 257])
+def test_no_pool_leaf_is_parked_in_the_batched_steps_scoped_memory(
+        stream_batch_texts, rows):
+    """PR 46's finding.  The general gather ``buf[slots]`` made its operand a
+    cross-program-prefetch candidate: at 33 rows the seed leaf (9.1 MB in its
+    tiling) was parked at the bottom of memory space 1 for the program's
+    life, every fusion's ``scoped_memory_configs`` began at offset 9,125,888
+    of 16,777,216, and the half-resolution 3x3 convolution 96->96 fell to
+    output windows of 3x6 (``estimated_cycles`` 259.6 M for 4.0 M; two
+    ``convert_reduce`` fusions 214.9 M each).  Row-wise slices are no
+    candidate at any pool size: nothing of the pool is prefetched, every
+    fusion plans in the whole scoped memory, and no instruction has a whole
+    leaf's shape."""
+    text = stream_batch_texts[rows]
+    parked = [ln.strip()[:120] for ln in text.splitlines()
+              if "cross_program_prefetch_index" in ln
+              and re.search(rf"\[{rows},", ln)]
+    assert not parked, parked
+    configs = _configs(text)
+    offsets = {int(c["offset"]) for _, _, cfg in configs.values()
+               for c in cfg.get("scoped_memory_configs", [])}
+    assert offsets and max(offsets) < 2 ** 20, sorted(offsets)
+    assert not re.search(rf"\[{rows},135,240,128\]", text)
+    assert "mini-gather" not in text
+    convs = {n: int(cfg["window_config"]["estimated_cycles"])
+             for n, (shape, op, cfg) in configs.items()
+             if shape.startswith("bf16[8,270,480,96]")
+             and op.endswith("/layer2/conv_general_dilated")
+             and "window_config" in cfg}
+    assert len(convs) >= 3 and max(convs.values()) < 10e6, convs
+    slow = {n: int(cfg["window_config"]["estimated_cycles"])
+            for n, (_, op, cfg) in configs.items()
+            if re.search(r"/raft/(fnet|cnet)/", op) and "window_config" in cfg
+            and int(cfg["window_config"]["estimated_cycles"]) > 25e6}
+    assert not slow, slow
+
+
+def test_the_encoder_compiles_the_same_beside_either_pool(stream_batch_texts):
+    """The two 33-row cells and the int8 cell run one encoder: the same
+    instructions with the same windows, window for window (before PR 46 the
+    33-row program's were the squeezed ones)."""
+    small, large = (_encoder_windows(stream_batch_texts[r])
+                    for r in (33, 257))
+    assert len(small) > 40
+    assert small == large
 
 
 def test_int8_commit_on_the_chip_files_its_quantiser_under_its_scope(
